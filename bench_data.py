@@ -101,8 +101,7 @@ def run_skew_peak(streaming: bool, n_blocks: int,
 def main(quick: bool = False):
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import ray_tpu as ray
-    from bench import flight_report, repin_jax_platforms, trace_arg
-    repin_jax_platforms()
+    from bench import flight_report, trace_arg
 
     reps = 2 if quick else 4
     n_rows = 40_000 if quick else 400_000

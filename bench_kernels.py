@@ -28,7 +28,7 @@ import time
 
 import numpy as np
 
-from bench import repin_jax_platforms
+from bench import require_accelerator
 
 
 def _timed_ms(fn, reps):
@@ -43,9 +43,11 @@ def _timed_ms(fn, reps):
 
 
 def _emit(metric, value, unit, vs=None):
+    from bench import device_info
     print(json.dumps({"metric": metric, "value": round(float(value), 4),
                       "unit": unit, "vs_baseline":
-                      None if vs is None else round(float(vs), 4)}))
+                      None if vs is None else round(float(vs), 4),
+                      "device": device_info()}))
 
 
 def _family_benches(quick: bool, on_tpu: bool):
@@ -162,9 +164,7 @@ def _engine_ttft(quick: bool):
 
 
 def main(quick: bool = False):
-    repin_jax_platforms()
-    import jax
-    on_tpu = jax.devices()[0].platform == "tpu"
+    on_tpu = require_accelerator()["platform"] == "tpu"
     _family_benches(quick, on_tpu)
     _engine_ttft(quick)
 

@@ -35,8 +35,12 @@ def init(address: Optional[str] = None,
 
     Reference: ray.init (python/ray/_private/worker.py:1336). TPU-specific:
     `num_tpus` declares how many TPU chips this host exposes as schedulable
-    "TPU" resources; auto-detected from the JAX runtime when None and
-    detection is cheap (env var, never imports jax here).
+    "TPU" resources. Nothing is detected: None reads ``$RTPU_NUM_TPUS``
+    (default 0), and the head never imports jax — a head that touched the
+    chip would take it from the worker that is granted it. Work that asks
+    for a "TPU" resource checks at start that JAX sees one
+    (util/tpu.py require_granted_tpu); work that asks for none is pinned
+    to the CPU.
 
     ``address``: "auto" resolves the newest local cluster (or
     ``$RTPU_ADDRESS``, which job drivers inherit); otherwise a path to a

@@ -1,8 +1,9 @@
 """Lazy build of the native object store shared library.
 
 The reference ships prebuilt bazel binaries (src/ray/object_manager/plasma);
-here we compile on first import and cache next to the source. g++ is in the
-image; the build takes <2s.
+here we compile on first import and cache next to the source (git-ignored:
+no binary is committed, a fresh checkout builds its own). g++ is part of
+the installation; the build takes <2s.
 
 Sanitizer mode (the reference runs its C++ store tests under ASan/TSan in
 CI): set ``RTPU_OBJSTORE_SANITIZE=address,undefined`` (any comma-joined
@@ -49,7 +50,9 @@ def _compile_and_swap(mode: str) -> None:
     Caller holds _lock. Raises CalledProcessError on compile errors and
     OSError when the compiler is missing / checkout is read-only."""
     lib = _lib_path(mode)
-    tmp = lib + ".tmp"
+    # per-process name: two processes building at once (a driver and a
+    # node agent on a fresh checkout) must not write through each other
+    tmp = f"{lib}.{os.getpid()}.tmp"
     if mode:
         # debug-grade opt level + frame pointers: sanitizer reports with
         # usable stacks beat a fast binary nobody profiles
@@ -96,10 +99,10 @@ def ensure_built() -> str:
                     "objstore.cc failed to compile:\n"
                     + e.stderr.decode(errors="replace")) from e
             except OSError:
-                # no compiler / read-only checkout: a shipped .so is still
-                # usable (it may just predate the latest source). Only the
-                # production variant ships — a sanitizer build with no
-                # compiler has nothing to fall back to.
+                # no compiler / read-only checkout: a .so built earlier is
+                # still usable (it may just predate the latest source). A
+                # sanitizer build with no compiler has nothing to fall
+                # back to.
                 if mode or not os.path.exists(lib):
                     raise
     return lib

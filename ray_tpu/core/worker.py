@@ -41,6 +41,7 @@ from .object_store import ObjectStoreFullError as StoreFull
 from .object_store import SharedObjectStore, SpillStore
 from .ref import ObjectRef
 from .task_spec import ActorSpec, TaskSpec
+from ..util.tpu import require_granted_tpu
 from . import flight
 from . import stacks
 from . import runtime as rt_mod
@@ -881,6 +882,7 @@ class WorkerLoop:
         try:
             if self._renv_error is not None:
                 raise self._renv_error
+            require_granted_tpu(spec.resources)
             fn = self.rt.func_registry[spec.func_id]
             args, kwargs = self._resolve_args(spec.args_blob)
             tctx = getattr(spec, "trace_ctx", None)
@@ -954,6 +956,7 @@ class WorkerLoop:
         try:
             if self._renv_error is not None:
                 raise self._renv_error
+            require_granted_tpu(spec.resources)
             cls = self.rt.func_registry[spec.class_id]
             args, kwargs = self._resolve_args(spec.args_blob)
             self.actor_instance = cls(*args, **kwargs)

@@ -14,7 +14,7 @@ copies or transfers; a consumer elsewhere fetches the host representation
 from the owner over the control plane and re-places it on its own device.
 On a multi-host pod the cross-process path is where an ICI/DCN collective
 transport slots in (jax.experimental transfer — the single-chip image has
-no second device to exercise it, so host relay is the fallback the way
+no second device to exercise it, so a host copy is the fallback the way
 the reference falls back to object-store copies for non-NCCL-able pairs).
 
     @ray_tpu.remote

@@ -4,8 +4,9 @@ vLLM-analog re-designed for XLA (reference role:
 llm/_internal/serve/deployments/llm/vllm/vllm_engine.py:180): the KV cache
 is a pool of fixed-size pages shared by all sequences; each request owns a
 block table of page ids, so cache capacity is bounded by TOKENS IN FLIGHT,
-not max_batch x max_seq_len, and decode attention (Pallas,
-ops/paged_attention.py) reads only the pages a sequence actually uses.
+not max_batch x max_seq_len, and attention (Pallas,
+ops/ragged_paged_attention.py) reads only the pages a sequence actually
+uses.
 
 Two families of jitted programs with static shapes, keyed by unroll factor:
   - chunked prefill: up to `prefill_rows` page-aligned chunk-rows per
@@ -18,9 +19,9 @@ Two families of jitted programs with static shapes, keyed by unroll factor:
 
 Sampling is fused into both programs (sample_logits_batch), so one engine
 step is ONE device dispatch and the only device->host traffic is the
-sampled token block — dispatch latency, not math, dominates a serving step
-on remote-attached accelerators. The Python loop does admission, page
-allocation and retirement; all math stays compiled. Cache buffers are
+sampled token block (what share of a serving step on a v5e is host time
+between dispatches: not measured, ROADMAP S3). The Python loop does
+admission, page allocation and retirement; all math stays compiled. Cache buffers are
 donated through every program so XLA updates pages in place.
 """
 from __future__ import annotations
@@ -38,6 +39,7 @@ import numpy as np
 
 from ..core import flight
 from ..models import llama
+from ..util.compile_cache import enable_compile_cache
 from .engine import (  # noqa: F401 — SamplingParams re-exported
     SamplingParams, _EngineBase, _Request, sample_logits_batch,
 )
@@ -55,8 +57,8 @@ class PagedEngineConfig:
     chunk_size: int = 128
     # dispatch batching: chunk-rows prefetched per prefill dispatch and
     # decode steps unrolled (lax.scan) per decode dispatch. Each dispatch
-    # costs a host->device round trip; on remote-attached accelerators
-    # that latency dominates a serving step, so both paths amortize it.
+    # costs a host->device round trip, which both paths amortize (how
+    # much it costs on a locally attached v5e: not measured, ROADMAP S3).
     # decode_window only applies when no prefill is pending (window 1
     # keeps TTFT low while prompts are still entering the batch).
     prefill_rows: int = 4
@@ -172,6 +174,9 @@ class PagedInferenceEngine(_EngineBase):
                  rng_seed: int = 0, interpret: bool = False):
         self.cfg = cfg
         mc = cfg.model
+        # every program family below goes through the persistent compile
+        # cache, wherever this engine runs (replica worker or driver)
+        enable_compile_cache()
         self.tokenizer = get_tokenizer(cfg.tokenizer)
         if params is None:
             params = llama.init(jax.random.PRNGKey(rng_seed), mc)
@@ -322,34 +327,47 @@ class PagedInferenceEngine(_EngineBase):
         # estimate_flops() has run
         from ..util.profiling import StepProfiler
         self.profiler = StepProfiler("paged_engine")
+        # programs compiled by warmup(): profiler.compiles beyond this
+        # count compiled under traffic (profile_summary)
+        self.warm_programs = 0
 
     # -- mesh-parallel placement (cfg.mesh) --------------------------------
 
     def _init_mesh(self):
         """Build the device mesh and commit weights, KV pool and the
-        adapter slot table onto it with explicit NamedShardings: KV
-        pages shard over kv-heads on tp, weights follow
-        llama.logical_axes, block tables / token ids stay replicated.
-        The pinned tuples cached here are what every program family
-        compiles with (in == out for the donated caches, so page updates
-        keep aliasing in place — an unconstrained output sharding breaks
-        donation, the way it once did for sharded opt_state)."""
-        from ..parallel import sharding as shardlib
-        from ..parallel.mesh import MeshSpec, build_mesh, use_mesh
-        cfg, mc = self.cfg, self.cfg.model
-        spec = cfg.mesh
+        adapter slot table onto it at the shardings _mesh_shardings
+        pins."""
+        import math
+        from ..parallel.mesh import MeshSpec, build_mesh
+        spec = self.cfg.mesh
         if isinstance(spec, dict):
             spec = MeshSpec(**spec)
         # an engine's mesh spec names how many chips it WANTS, not how
         # many the process sees: take the leading slice so tp=2 works on
-        # an 8-device host (replicas each build their own sub-mesh)
+        # an 8-device host
         devices = jax.devices()
-        import math as _math
-        want = _math.prod(
+        want = math.prod(
             getattr(spec, a) for a in ("pp", "dp", "fsdp", "ep", "sp", "tp"))
         if 0 < want <= len(devices):
             devices = devices[:want]
         self.mesh = build_mesh(spec, devices=devices)
+        sh = self._shardings = self._mesh_shardings()
+        self.params = jax.device_put(self.params, sh["params"])
+        self.caches = jax.device_put(self.caches, sh["caches"])
+        if self.lora is not None:
+            self.lora.shard(self.mesh, sh["lora"])
+
+    def _mesh_shardings(self) -> dict:
+        """The explicit NamedShardings of everything committed to
+        self.mesh: KV pages shard over kv-heads on tp, weights follow
+        llama.logical_axes, block tables / token ids stay replicated.
+        These are what every program family compiles with (in == out for
+        the donated caches, so page updates keep aliasing in place — an
+        unconstrained output sharding breaks donation, the way it once
+        did for sharded opt_state)."""
+        from ..parallel import sharding as shardlib
+        from ..parallel.mesh import use_mesh
+        mc = self.cfg.model
         sizes = dict(zip(self.mesh.axis_names, self.mesh.devices.shape))
         tp = sizes.get("tp", 1)
         if mc.n_kv_heads % tp or mc.n_heads % tp or mc.mlp_dim % tp:
@@ -372,12 +390,8 @@ class PagedInferenceEngine(_EngineBase):
             if self.lora is not None:
                 lshard = shardlib.logical_sharding(
                     self.lora.logical_axes())
-        self.params = jax.device_put(self.params, pshard)
-        self.caches = jax.device_put(self.caches, cshard)
-        if self.lora is not None:
-            self.lora.shard(self.mesh, lshard)
-        self._shardings = {"params": pshard, "caches": cshard,
-                           "lora": lshard, "repl": repl}
+        return {"params": pshard, "caches": cshard,
+                "lora": lshard, "repl": repl}
 
     def _mesh_scope(self):
         """Context manager making self.mesh the current mesh for jax work
@@ -622,9 +636,10 @@ class PagedInferenceEngine(_EngineBase):
         The reference's serving engine does the same at deployment time
         (vLLM profiles and captures its execution graphs during engine
         init, before the server admits requests — vllm_engine.py:180's
-        engine start path). Here the stakes are higher: one mid-burst XLA
-        compile on a remote-attached TPU is tens of requests' worth of
-        latency, landing exactly when the first burst does.
+        engine start path). A program compiled mid-burst lands in some
+        request's latency, exactly when the first burst does (seconds
+        per program at 8B widths on a v5e: chip_smoke.py prints the
+        warm-up's compile time).
 
         Families: prefill rows over the power-of-two buckets, decode
         windows {1, decode_window}, and — when speculation is on — the
@@ -641,8 +656,10 @@ class PagedInferenceEngine(_EngineBase):
         """
         import time as _time
         with self._mesh_scope():
-            return self._warmup_traced(sample_modes, families,
+            took = self._warmup_traced(sample_modes, families,
                                        _time.perf_counter())
+        self.warm_programs = self.profiler.compiles
+        return took
 
     def _warmup_traced(self, sample_modes, families, t0) -> float:
         import time as _time
@@ -1110,8 +1127,8 @@ class PagedInferenceEngine(_EngineBase):
         # programs instead of one per packed-row count. Pad rows carry
         # true_len 0, so the kernel routes all their writes to sink page
         # 0 (prefill_paged_rows docstring) — they cost compute but no
-        # fresh XLA compile, and a mid-burst compile costs tens of
-        # requests' worth of latency on a remote-attached accelerator.
+        # fresh XLA compile, and a mid-burst compile lands in some
+        # request's latency.
         r = len(rows)
         rb = min(1 << max(r - 1, 0).bit_length(), cfg.prefill_rows)
         # block-table width bucket: widest logical page any row reads or
@@ -1936,10 +1953,14 @@ class PagedInferenceEngine(_EngineBase):
     def profile_summary(self) -> dict:
         """Step-profiler view (util/profiling.py): compile/execute wall
         split, per-step wall, and MFU when estimate_flops() has run."""
-        return {**self.profiler.summary(), "dispatches": {
-            "prefill": self.stats["prefill_dispatches"],
-            "decode": self.stats["decode_dispatches"],
-            "spec": self.stats["spec_dispatches"]}}
+        return {**self.profiler.summary(),
+                # zero when warm-up covered every shape traffic reached
+                "in_window_compiles":
+                    self.profiler.compiles - self.warm_programs,
+                "dispatches": {
+                    "prefill": self.stats["prefill_dispatches"],
+                    "decode": self.stats["decode_dispatches"],
+                    "spec": self.stats["spec_dispatches"]}}
 
     def prefix_accounting(self) -> dict:
         """THE accounting source for prefix-cache counters. pool_stats(),

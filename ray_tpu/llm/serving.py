@@ -321,6 +321,10 @@ class LLMServer:
                     else eng.tokenizer.decode(list(prompt))) + text
         choice = {
             "text": text,
+            # ids beside the text: a prompt may be given as token ids, and
+            # decoding is lossy where the tokenizer's vocabulary is not
+            # the model's (the default ByteTokenizer)
+            "token_ids": out["token_ids"],
             "finish_reason": out["finish_reason"],
             "index": 0,
         }
@@ -396,10 +400,22 @@ class LLMServer:
         On a mesh, ``mesh_reshard_bytes`` staying 0 IS the steady-state
         zero-involuntary-reshard invariant — a nonzero value means some
         dispatch committed a buffer off its pinned sharding."""
+        import jax
+
+        from ..util.compile_cache import compile_cache_stats
         st = dict(getattr(self.engine, "stats", {}) or {})
         mesh = getattr(self.engine, "mesh", None)
         st["mesh"] = None if mesh is None else {
             k: int(v) for k, v in mesh.shape.items()}
+        # what this replica's process really runs on, as JAX reports it:
+        # the one way a caller can tell a chip from a silent CPU
+        devs = jax.devices()
+        st["device"] = {"platform": devs[0].platform,
+                        "kind": devs[0].device_kind, "count": len(devs)}
+        st["memory"] = [d.memory_stats() for d in devs]
+        st["compile_cache"] = compile_cache_stats()
+        if hasattr(self.engine, "profile_summary"):
+            st["profile"] = self.engine.profile_summary()
         return st
 
     def loaded_loras(self) -> list:

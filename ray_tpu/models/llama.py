@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.flash_attention import _on_tpu, flash_attention, mha_reference
-from ..parallel.sharding import constrain
+from ..parallel.sharding import constrain, shard_kernel
 
 
 @dataclasses.dataclass(frozen=True)
@@ -221,8 +221,13 @@ def _attention(q, k, v, cfg: LlamaConfig, causal: bool, attn_impl):
     if attn_impl is not None:
         return attn_impl(q, k, v)
     if cfg.use_flash:
-        return flash_attention(q, k, v, causal, None,
-                               cfg.attn_block_q, cfg.attn_block_k)
+        def flash(q, k, v):
+            return flash_attention(q, k, v, causal, None,
+                                   cfg.attn_block_q, cfg.attn_block_k)
+        q_axes = ("batch", None, "heads", None)
+        kv_axes = ("batch", None, "kv_heads", None)
+        return shard_kernel(flash, (q_axes, kv_axes, kv_axes),
+                            q_axes)(q, k, v)
     return mha_reference(q, k, v, causal=causal)
 
 
@@ -632,6 +637,30 @@ def _layer_params(params: dict, layer: int) -> dict:
     return jax.tree.map(lambda a: a[layer], params["layers"])
 
 
+# logical axes of a page pool [P, page, KVH, D] (the engine commits it so)
+_PAGES_AXES = (None, None, "kv_heads", None)
+
+
+def _window_attend(scale: float, interpret: bool):
+    """Attention of query windows [R, Q, H, D] over pages that already
+    hold the windows' own K/V (prefill chunks, verify windows): the
+    ragged Pallas kernel on TPU or under ``interpret`` — per shard under
+    a mesh — and elsewhere its jnp oracle, which IS the fallback (one
+    copy of the gather/mask/grouped-GQA math to keep in sync with the
+    kernel). Call as attend(q, k_pages, v_pages, block_tables, starts,
+    q_lens)."""
+    from ..ops.ragged_paged_attention import (
+        ragged_paged_attention, ragged_paged_reference,
+    )
+    if not (interpret or _on_tpu()):
+        return functools.partial(ragged_paged_reference, scale=scale)
+    q_axes = (None, None, "heads", None)
+    return shard_kernel(
+        functools.partial(ragged_paged_attention, scale=scale,
+                          interpret=interpret),
+        (q_axes, _PAGES_AXES, _PAGES_AXES, (), (), ()), q_axes)
+
+
 def decode_paged(params: dict, tokens: jax.Array, caches: list[dict],
                  block_tables: jax.Array, lengths: jax.Array,
                  cfg: LlamaConfig, *, page_size: int,
@@ -659,8 +688,13 @@ def decode_paged(params: dict, tokens: jax.Array, caches: list[dict],
     offsets = lengths % page_size                          # [B]
     # hoisted: the platform probe + partial are trace-time constants, so
     # selecting per layer just re-evaluated them n_layers times per step
-    attend = (functools.partial(ragged_decode_attention, interpret=interpret)
-              if (interpret or _on_tpu()) else paged_decode_reference)
+    if interpret or _on_tpu():
+        q_axes = (None, "heads", None)
+        attend = shard_kernel(
+            functools.partial(ragged_decode_attention, interpret=interpret),
+            (q_axes, _PAGES_AXES, _PAGES_AXES, (), ()), q_axes)
+    else:
+        attend = paged_decode_reference
 
     new_caches = []
     for layer in range(cfg.n_layers):
@@ -723,17 +757,12 @@ def prefill_paged_chunk(params: dict, chunk: jax.Array, caches: list[dict],
     Chunked prefill exists so admission never stalls decode: the engine
     interleaves one bounded chunk per step (vLLM's chunked-prefill role).
     """
-    from ..ops.ragged_paged_attention import (
-        ragged_paged_attention, ragged_paged_reference,
-    )
-
     c = chunk.shape[1]
     n_chunk_pages = c // page_size
     max_pages = block_table_row.shape[0]
     positions = start_pos + jnp.arange(c)[None, :]        # [1, C]
     cos, sin = rope_freqs(cfg, positions)
-    scale = cfg.head_dim ** -0.5
-    use_kernel = interpret or _on_tpu()
+    attend = _window_attend(cfg.head_dim ** -0.5, interpret)
     if true_chunk_len is None:
         true_chunk_len = jnp.int32(c)
     # gather (not dynamic_slice: it clamps at the row end and would silently
@@ -763,22 +792,14 @@ def prefill_paged_chunk(params: dict, chunk: jax.Array, caches: list[dict],
         v_pages = cache["v"].at[chunk_page_ids].set(
             v_w.astype(cache["v"].dtype))
 
-        # the scatter above already placed the window's K/V, so both
-        # paths attend pages only (prefix + causal window in one
-        # predicate); the jnp oracle IS the fallback — one copy of the
-        # gather/mask/grouped-GQA math to keep in sync with the kernel.
-        # Real queries (q < true_chunk_len) read only real pages; pad
-        # queries read sink-routed garbage the caller discards.
+        # the scatter above already placed the window's K/V, so
+        # attention reads pages only (prefix + causal window in one
+        # predicate). Real queries (q < true_chunk_len) read only real
+        # pages; pad queries read sink-routed garbage the caller discards.
         starts1 = jnp.reshape(start_pos, (1,)).astype(jnp.int32)
         qlens1 = jnp.reshape(true_chunk_len, (1,)).astype(jnp.int32)
-        if use_kernel:
-            attn = ragged_paged_attention(
-                q, k_pages, v_pages, block_table_row[None], starts1,
-                qlens1, scale=scale, interpret=interpret).astype(cfg.dtype)
-        else:
-            attn = ragged_paged_reference(
-                q, k_pages, v_pages, block_table_row[None], starts1,
-                qlens1, scale=scale).astype(cfg.dtype)
+        attn = attend(q, k_pages, v_pages, block_table_row[None], starts1,
+                      qlens1).astype(cfg.dtype)
         proj = attn.reshape(1, c, -1)
         y = proj @ p["wo"]
         if ll is not None:
@@ -864,14 +885,9 @@ def verify_paged_rows(params: dict, tokens: jax.Array, caches: list[dict],
     Rows run under one lax.scan carrying the caches (same shape
     discipline as prefill_paged_rows; R and S1 are static).
     """
-    from ..ops.ragged_paged_attention import (
-        ragged_paged_attention, ragged_paged_reference,
-    )
-
     maxp = bt_rows.shape[1]
     s1 = tokens.shape[1]
-    scale = cfg.head_dim ** -0.5
-    use_kernel = interpret or _on_tpu()
+    attend = _window_attend(cfg.head_dim ** -0.5, interpret)
     if lora is not None and slots is None:
         slots = jnp.zeros((tokens.shape[0],), jnp.int32)
 
@@ -896,25 +912,11 @@ def verify_paged_rows(params: dict, tokens: jax.Array, caches: list[dict],
                 k[0].astype(cache["k"].dtype))
             v_pages = cache["v"].at[page_ids, offsets].set(
                 v[0].astype(cache["v"].dtype))
-            if use_kernel:
-                # the scatter above already placed the window's K/V, so
-                # the ragged kernel attends pages only
-                attn = ragged_paged_attention(
-                    q, k_pages, v_pages, bt[None],
-                    jnp.reshape(start, (1,)).astype(jnp.int32),
-                    jnp.full((1,), s1, jnp.int32),
-                    scale=scale, interpret=interpret).astype(cfg.dtype)
-            else:
-                # the gather happens AFTER the scatter, so the window's
-                # own K/V is already in place — exactly the ragged
-                # oracle's contract, so the fallback IS the oracle (one
-                # copy of the gather/mask/grouped-GQA math to keep in
-                # sync with the kernel)
-                attn = ragged_paged_reference(
-                    q, k_pages, v_pages, bt[None],
-                    jnp.reshape(start, (1,)).astype(jnp.int32),
-                    jnp.full((1,), s1, jnp.int32),
-                    scale=scale).astype(cfg.dtype)
+            # the scatter above already placed the window's K/V, so
+            # attention reads pages only
+            attn = attend(q, k_pages, v_pages, bt[None],
+                          jnp.reshape(start, (1,)).astype(jnp.int32),
+                          jnp.full((1,), s1, jnp.int32)).astype(cfg.dtype)
             proj = attn.reshape(1, s1, -1)
             y = proj @ p["wo"]
             if ll is not None:

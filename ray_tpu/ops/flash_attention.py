@@ -515,19 +515,13 @@ def flash_attention(q, k, v, causal: bool = False,
     return out
 
 
-_ON_TPU: Optional[bool] = None
-
-
+@functools.cache
 def _on_tpu() -> bool:
     """Cached platform probe shared by every kernel-vs-reference dispatch
-    (flash fwd/bwd, paged decode)."""
-    global _ON_TPU
-    if _ON_TPU is None:
-        try:
-            _ON_TPU = jax.devices()[0].platform == "tpu"
-        except Exception:
-            return False  # don't cache a failed probe
-    return _ON_TPU
+    (flash fwd/bwd, paged prefill/verify/decode). A backend that fails to
+    initialise raises here: answering "not a TPU" would run the jnp
+    reference on whatever is left and hide the device."""
+    return jax.devices()[0].platform == "tpu"
 
 
 def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):

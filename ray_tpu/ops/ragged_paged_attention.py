@@ -10,15 +10,22 @@ scattered into the pages (the engine writes K/V before attention on every
 path, so the kernel never needs a separate in-window concat). HBM traffic
 is proportional to each row's TRUE length, not the pool capacity:
 
-* grid ``(row, kv_pages)`` with the block table scalar-prefetched so the
-  K/V page BlockSpec index maps select each row's physical pages;
-* the index map CLAMPS the logical page to the row's last live page, so
+* grid ``(row, q_tile, kv_pages)`` with the block table scalar-prefetched
+  so the K/V page BlockSpec index maps select each row's physical pages;
+* the query window is TILED over the middle grid axis (`_q_tile`): the
+  q/out blocks and the f32 accumulators are per query row, so a tile is
+  an independent page sweep, and what one grid step keeps in VMEM is
+  bounded by ``tile * heads * head_dim`` instead of by the engine's
+  chunk_size (a whole 128-token window of 32 heads x 128 is refused by
+  the v5e compiler: over the 16 MiB scoped-VMEM limit);
+* the index map CLAMPS the logical page to the tile's last live page, so
   grid steps at/beyond the live page count re-request the block already
-  resident and the pipeline elides the fetch — pages a row doesn't own
-  are neither read nor computed (`pl.when` skips the body);
+  resident and the pipeline elides the fetch — pages a row doesn't own,
+  and pages wholly in a tile's causal future, are neither read nor
+  computed (`pl.when` skips the body);
 * a streaming-softmax accumulator in VMEM scratch carries across the
   page sweep (TPU grids iterate the last dimension fastest, so scratch
-  persists across one row's sweep — same contract as
+  persists across one tile's sweep — same contract as
   `ops/paged_attention._paged_decode_kernel`);
 * causal masking INSIDE the query window: key position ``k_pos`` is
   attended by query position ``q_pos = start + i`` iff ``k_pos <= q_pos``
@@ -28,10 +35,11 @@ is proportional to each row's TRUE length, not the pool capacity:
   group of ``groups`` query heads runs a [Q*G, page] MXU tile against its
   kv head's [page, D] block — no jnp.repeat materialization anywhere.
 
-Row layout convention (everything else follows from it): the flattened
-score/accumulator row index is ``h_kv * (Q * G) + q * G + g`` — per-kv-head
-blocks, query-major within a block — because per-kv-head q slices
-``q[:, h*G:(h+1)*G, :]`` reshape contiguously to [Q*G, D].
+Row layout convention (everything else follows from it): with Q the
+query TILE, the flattened score/accumulator row index is
+``h_kv * (Q * G) + q * G + g`` — per-kv-head blocks, query-major within
+a block — because per-kv-head q slices ``q[:, h*G:(h+1)*G, :]`` reshape
+contiguously to [Q*G, D].
 
 The pure-jnp oracle (`ragged_paged_reference`) uses the same
 grouped-einsum GQA form and is the CPU fallback's numerical contract;
@@ -55,11 +63,12 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
                    o_ref,                             # output
                    acc_ref, m_ref, l_ref,             # VMEM scratch
                    *, scale: float, page_size: int, num_kv_heads: int,
-                   groups: int, q_window: int, max_pages: int):
+                   groups: int, q_tile: int, max_pages: int):
     from jax.experimental import pallas as pl
 
     r = pl.program_id(0)
-    p = pl.program_id(1)
+    t = pl.program_id(1)
+    p = pl.program_id(2)
 
     @pl.when(p == 0)
     def _init():
@@ -70,11 +79,12 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
     start = start_ref[r]
     q_len = qlen_ref[r]
     kv_len = start + q_len                 # positions < kv_len are live
-    n_pages = (kv_len + page_size - 1) // page_size
+    tile_start = start + t * q_tile        # position of the tile's query 0
+    n_pages = _tile_pages(start, q_len, t, q_tile, page_size)
 
     @pl.when(p < n_pages)
     def _compute():
-        qg = q_window * groups
+        qg = q_tile * groups
         q = q_ref[...]                                    # [Q, H, D]
         rows = []
         for h in range(num_kv_heads):
@@ -87,8 +97,8 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
         n_rows = num_kv_heads * qg
         # row index -> query index (row layout: h*(Q*G) + q*G + g)
         q_idx = (jax.lax.broadcasted_iota(
-            jnp.int32, (n_rows, page_size), 0) // groups) % q_window
-        q_pos = start + q_idx
+            jnp.int32, (n_rows, page_size), 0) // groups) % q_tile
+        q_pos = tile_start + q_idx
         k_pos = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (n_rows, page_size), 1)
         # one predicate covers prefix (k_pos < start <= q_pos) and the
@@ -116,14 +126,42 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
 
     @pl.when(p == max_pages - 1)
     def _finalize():
-        qg = q_window * groups
+        qg = q_tile * groups
         l = jnp.maximum(l_ref[:], 1e-30)                  # noqa: E741
         o = acc_ref[:] / l                                # [KVH*Q*G, D]
         for h in range(num_kv_heads):
             blk = o[h * qg:(h + 1) * qg, :].reshape(
-                q_window, groups, -1)
+                q_tile, groups, -1)
             o_ref[:, h * groups:(h + 1) * groups, :] = blk.astype(
                 o_ref.dtype)
+
+
+# Most query elements (tile * heads * head_dim) one grid step may hold:
+# the q and out blocks (double-buffered) and the f32 accumulators all
+# scale with it. 64 x 32 x 128 is the widest tile the v5e compiler
+# accepts inside its 16 MiB scoped-VMEM limit at pages of 16, 64 and 128
+# (tests/test_chip_compile.py holds that); the next power of two is
+# refused.
+_Q_TILE_ELEMS = 64 * 32 * 128
+
+
+def _q_tile(q_window: int, heads: int, head_dim: int) -> int:
+    """Query rows per grid step: the whole window when it fits the VMEM
+    budget (decode, verify windows, small models), else the largest
+    multiple of 8 that does."""
+    fit = max(8, _Q_TILE_ELEMS // (heads * head_dim) // 8 * 8)
+    return q_window if q_window <= fit else fit
+
+
+def _tile_pages(start, q_len, t, q_tile: int, page_size: int):
+    """Live KV pages of query tile ``t`` of one row: those holding keys
+    below min(kv_len, end of the tile) — later keys are in the causal
+    future of every query of the tile — and none for a tile wholly past
+    q_len (padding). Shared by the kernel body and the K/V index map so
+    the fetch clamp and the compute skip can never disagree."""
+    live = jnp.minimum(start + q_len, start + (t + 1) * q_tile)
+    return jnp.where(t * q_tile < q_len,
+                     (live + page_size - 1) // page_size, 0)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
@@ -150,41 +188,49 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
     max_pages = block_tables.shape[1]
     if scale is None:
         scale = d ** -0.5
+    tq = _q_tile(qw, h, d)
+    n_tiles = -(-qw // tq)
+    if n_tiles * tq != qw:
+        # pad queries sit past q_len: garbage by contract, sliced off below
+        q = jnp.pad(q, ((0, 0), (0, n_tiles * tq - qw), (0, 0), (0, 0)))
 
     kernel = functools.partial(
         _ragged_kernel, scale=scale, page_size=page_size,
-        num_kv_heads=kvh, groups=groups, q_window=qw, max_pages=max_pages)
+        num_kv_heads=kvh, groups=groups, q_tile=tq, max_pages=max_pages)
 
-    def _kv_index(ri, p, bt, start, qlen):
-        # clamp to the row's last live page: grid steps beyond the live
+    def _q_index(ri, t, p, bt, start, qlen):
+        return (ri, t, 0, 0)
+
+    def _kv_index(ri, t, p, bt, start, qlen):
+        # clamp to the tile's last live page: grid steps beyond the live
         # count re-request the resident block (fetch elided), so HBM
         # traffic tracks true length even when the table tail is stale
-        n = (start[ri] + qlen[ri] + page_size - 1) // page_size
+        n = _tile_pages(start[ri], qlen[ri], t, tq, page_size)
         return (bt[ri, jnp.minimum(p, jnp.maximum(n - 1, 0))], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(r, max_pages),
+        grid=(r, n_tiles, max_pages),
         in_specs=[
-            pl.BlockSpec((None, qw, h, d),
-                         lambda ri, p, bt, st, ql: (ri, 0, 0, 0)),
+            pl.BlockSpec((None, tq, h, d), _q_index),
             pl.BlockSpec((None, page_size, kvh, d), _kv_index),
             pl.BlockSpec((None, page_size, kvh, d), _kv_index),
         ],
-        out_specs=pl.BlockSpec((None, qw, h, d),
-                               lambda ri, p, bt, st, ql: (ri, 0, 0, 0)),
+        out_specs=pl.BlockSpec((None, tq, h, d), _q_index),
         scratch_shapes=[
-            pltpu.VMEM((kvh * qw * groups, d), jnp.float32),
-            pltpu.VMEM((kvh * qw * groups, 1), jnp.float32),
-            pltpu.VMEM((kvh * qw * groups, 1), jnp.float32),
+            pltpu.VMEM((kvh * tq * groups, d), jnp.float32),
+            pltpu.VMEM((kvh * tq * groups, 1), jnp.float32),
+            pltpu.VMEM((kvh * tq * groups, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, qw, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(block_tables, starts, q_lens, q, k_pages, v_pages)
+    return out[:, :qw] if n_tiles * tq != qw else out
 
 
 def ragged_decode_attention(q, k_pages, v_pages, block_table, lengths,

@@ -101,18 +101,11 @@ def build_mesh(spec: MeshSpec | dict | None = None,
     shape = tuple(sizes[a] for a in axis_names)
 
     if devices[0].platform == "tpu":
+        # raises when the shape does not map onto the ICI topology: a
+        # naive device order would run, slower, and nothing would say so
         from jax.experimental import mesh_utils
-        try:
-            dev_array = mesh_utils.create_device_mesh(
-                shape, devices=devices, allow_split_physical_axes=True)
-        except Exception as e:
-            import warnings
-            warnings.warn(
-                f"mesh_utils.create_device_mesh failed ({e!r}); falling back "
-                f"to naive device order — collective bandwidth may suffer "
-                f"because mesh axes no longer follow ICI topology",
-                RuntimeWarning, stacklevel=2)
-            dev_array = np.asarray(devices).reshape(shape)
+        dev_array = mesh_utils.create_device_mesh(
+            shape, devices=devices, allow_split_physical_axes=True)
     else:
         dev_array = np.asarray(devices).reshape(shape)
     return Mesh(dev_array, tuple(axis_names))
@@ -167,25 +160,36 @@ class TpuTopology:
         return self.peak_flops_bf16 * self.num_devices
 
 
-# Per-chip peak bf16 FLOP/s (public spec-sheet numbers).
-_PEAK_BF16 = {
-    "v2": 45e12 / 2,   # per chip (2 cores @ 22.5e12)
-    "v3": 123e12 / 2,
-    "v4": 275e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v6e": 918e12,
-    "cpu": 1e11,       # nominal, keeps MFU math defined in tests
+# THE peaks table: jax `device_kind` -> (generation, peak bf16 FLOP/s per
+# chip), from Google Cloud's TPU documentation (v5e: 197 TFLOP/s). A TPU
+# that is not listed is an error, never a default: an MFU against the
+# wrong peak is a wrong number. The "cpu" row is nominal and keeps MFU
+# math defined in CPU tests.
+DEVICE_PEAKS = {
+    "TPU v4": ("v4", 275e12),
+    "TPU v5 lite": ("v5e", 197e12),
+    "TPU v5e": ("v5e", 197e12),
+    "TPU v5": ("v5p", 459e12),
+    "TPU v5p": ("v5p", 459e12),
+    "TPU v6 lite": ("v6e", 918e12),
+    "TPU v6e": ("v6e", 918e12),
+    "cpu": ("cpu", 1e11),
 }
 
 
-def _generation_of(device) -> str:
-    kind = getattr(device, "device_kind", "").lower()
-    for gen in ("v6e", "v5p", "v5e", "v4", "v3", "v2"):
-        if gen in kind.replace(" ", "").replace("lite", "e").replace(
-                "tpu", "").replace("-", ""):
-            return gen
-    return "cpu" if device.platform != "tpu" else "v5e"
+def device_peak(device) -> tuple[str, float]:
+    """(generation, peak bf16 FLOP/s) of a jax device, from DEVICE_PEAKS.
+    Any non-TPU platform reads the nominal "cpu" row; an unlisted TPU
+    kind raises."""
+    if device.platform != "tpu":
+        return DEVICE_PEAKS["cpu"]
+    try:
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s known for device_kind "
+            f"{device.device_kind!r}; add it to parallel.mesh.DEVICE_PEAKS "
+            f"with its source") from None
 
 
 def tpu_topology(devices: Optional[Sequence] = None) -> TpuTopology:
@@ -199,8 +203,7 @@ def tpu_topology(devices: Optional[Sequence] = None) -> TpuTopology:
     if devices is None:
         devices = jax.devices()
     devices = list(devices)
-    d0 = devices[0]
-    gen = _generation_of(d0)
+    gen, peak = device_peak(devices[0])
     slice_ids = {getattr(d, "slice_index", 0) for d in devices}
     num_slices = max(1, len(slice_ids))
     hosts = {getattr(d, "process_index", 0) for d in devices}
@@ -210,5 +213,5 @@ def tpu_topology(devices: Optional[Sequence] = None) -> TpuTopology:
         num_slices=num_slices,
         devices_per_slice=len(devices) // num_slices,
         chips_per_host=max(1, len(devices) // max(1, len(hosts))),
-        peak_flops_bf16=_PEAK_BF16.get(gen, _PEAK_BF16["v5e"]),
+        peak_flops_bf16=peak,
     )

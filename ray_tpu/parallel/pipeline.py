@@ -57,8 +57,6 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: jax.Array,
     The per-shard batch must divide into num_microbatches equal
     microbatches; interleaving additionally needs num_microbatches % S == 0.
     """
-    from ._compat import shard_map  # current API on old/new jax alike
-
     S = mesh.shape.get("pp", 1)
     V = num_chunks
     if S == 1:
@@ -137,7 +135,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x: jax.Array,
     grouped = jax.tree.map(
         lambda a: a.reshape(S, V, *a.shape[1:]), stage_params)
 
-    return shard_map(
+    return jax.shard_map(
         inner, mesh=mesh,
         in_specs=(P("pp"), x_spec),
         out_specs=x_spec,

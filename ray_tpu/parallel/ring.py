@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from . import _compat
-
 NEG_INF = -1e30
 
 
@@ -76,7 +74,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     shard). K/V blocks rotate ring-wise via ppermute; `causal` masks with
     *global* positions derived from each block's ring offset.
     """
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     s_local = q.shape[1]
     if scale is None:
@@ -118,7 +116,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     H/n heads (any `attn_fn(q, k, v, causal, scale)`, default streaming-exact
     jnp), re-shards back.
     """
-    n = _compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if q.shape[2] % n:
         raise ValueError(f"heads {q.shape[2]} % sp size {n} != 0")
 
@@ -144,9 +142,8 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 
 def _sharded(fn, mesh, q_specs):
-    from ._compat import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=q_specs, out_specs=q_specs[0],
-                     check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=q_specs,
+                         out_specs=q_specs[0], check_vma=False)
 
 
 def _seq_spec(mesh, axis_name, batch_axes, head_axis) -> P:

@@ -122,6 +122,30 @@ def constrain(x, logical_axes: Sequence[Optional[str]], mesh=None,
         x, NamedSharding(mesh, logical_spec(logical_axes, mesh, rules)))
 
 
+def shard_kernel(fn, in_axes: Sequence[Sequence[Optional[str]]],
+                 out_axes: Sequence[Optional[str]]):
+    """Wrap a Pallas kernel call in `jax.shard_map` over the current mesh,
+    its operands and result laid out by logical axes.
+
+    Mosaic kernels cannot be partitioned automatically: a jitted program
+    whose kernel operands carry NamedShardings fails to compile for real
+    chips ("wrap the call in a shard_map"). Under shard_map each device
+    runs the kernel on its own shard — heads and kv_heads on tp, batch on
+    dp/fsdp — and an operand laid out otherwise is moved by XLA outside
+    the kernel. Returns `fn` unchanged with no mesh, on one device, or
+    when tracing already happens inside a shard_map (pipeline stages,
+    ring attention), where the kernel sees per-shard operands as it is.
+    """
+    mesh = get_mesh()
+    if mesh is None or mesh.size == 1 or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return fn
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(logical_spec(a, mesh) for a in in_axes),
+        out_specs=logical_spec(out_axes, mesh), check_vma=False)
+
+
 def batch_spec(mesh=None) -> P:
     """PartitionSpec for a [batch, ...] array: batch over dp+fsdp."""
     mesh = mesh or get_mesh()

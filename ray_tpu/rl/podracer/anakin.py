@@ -98,7 +98,6 @@ class AnakinTrainer:
         import jax
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from ...parallel._compat import shard_map
         from .. import module as module_lib
         from ..impala import vtrace
         env, cfg = self.env, self.config.impala
@@ -168,7 +167,7 @@ class AnakinTrainer:
             return (params, opt_state, env_state, obs, key[None],
                     ep_ret, metrics)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             device_fn, mesh=self.mesh,
             in_specs=(P(), P(), P("dp"), P("dp"), P("dp"), P("dp")),
             out_specs=(P(), P(), P("dp"), P("dp"), P("dp"), P("dp"),

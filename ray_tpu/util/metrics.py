@@ -32,8 +32,8 @@ _flusher_started = False
 _metric_cache: dict = {}
 
 # shared latency boundaries (seconds) for serving histograms: sub-ms
-# through 60s covers in-process CPU smoke engines and remote-attached-TPU
-# serving alike. llm/telemetry.py and serve/metrics.py both bucket with
+# through 60s covers in-process CPU smoke engines and TPU serving
+# alike. llm/telemetry.py and serve/metrics.py both bucket with
 # these so rtpu_llm_* / rtpu_serve_* quantiles stay comparable.
 LATENCY_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
                    0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0)

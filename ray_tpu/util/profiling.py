@@ -16,8 +16,9 @@ whose methodology ships with the system). Three pieces:
 - FLOPs estimation — ``compiled_flops(fn, *args)`` lowers+compiles a
   jitted function out of band and reads XLA's ``cost_analysis()``;
   :func:`mfu` divides by wall time and the device's peak. Peak FLOPs
-  come from a device-kind table (TPU generations; CPU/unknown -> None,
-  MFU then reports None rather than a made-up number).
+  come from the one device-kind table (parallel.mesh.DEVICE_PEAKS; off
+  TPU -> None, MFU then reports None rather than a made-up number; an
+  unlisted TPU kind is an error).
 - Optional ``jax.profiler`` capture — :func:`trace` wraps a block in a
   TensorBoard-loadable trace when a directory is given, and is a no-op
   otherwise, so call sites can leave the hook in place unconditionally.
@@ -34,37 +35,21 @@ from typing import Any, Optional
 
 from ..core import flight
 
-# bf16 peak FLOP/s per chip by device_kind substring (public spec
-# sheets); looked up longest-match-first so "TPU v5p" beats "TPU v5"
-_PEAK_FLOPS = (
-    ("TPU v6e", 918e12),
-    ("TPU v6", 918e12),
-    ("TPU v5p", 459e12),
-    ("TPU v5e", 197e12),
-    ("TPU v5", 197e12),
-    ("TPU v4", 275e12),
-    ("TPU v3", 123e12),
-    ("TPU v2", 45e12),
-)
-
 # StepProfiler kind codes for the flight ring (exported by name)
 STEP_KINDS = {"prefill": 0, "decode": 1, "verify": 2, "update": 3,
               "train": 4, "other": 5}
 
 
 def device_peak_flops(device=None) -> Optional[float]:
-    """Per-device peak bf16 FLOP/s, or None when unknown (CPU, new TPU
-    generations not in the table): MFU must be honest, not guessed."""
-    try:
-        import jax
-        device = device or jax.devices()[0]
-        kind = getattr(device, "device_kind", "") or ""
-    except Exception:
-        return None  # no jax / no devices: peak unknown, MFU stays None
-    for prefix, peak in _PEAK_FLOPS:
-        if prefix.lower() in kind.lower():
-            return peak
-    return None
+    """Per-device peak bf16 FLOP/s from THE peaks table
+    (parallel.mesh.DEVICE_PEAKS). None off-TPU — MFU then reports None
+    rather than a share of a nominal number; an unlisted TPU kind
+    raises."""
+    import jax
+
+    from ..parallel.mesh import device_peak
+    device = device or jax.devices()[0]
+    return device_peak(device)[1] if device.platform == "tpu" else None
 
 
 def _flops_of(compiled) -> Optional[float]:

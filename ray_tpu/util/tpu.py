@@ -43,6 +43,26 @@ def discover_tpu_labels(env=None) -> dict[str, str]:
     return labels
 
 
+def require_granted_tpu(resources: Optional[dict]) -> None:
+    """Raise when a task or actor that was granted a "TPU" resource runs
+    where JAX sees no TPU: it would otherwise compute on the CPU and
+    nothing would say so. Called by the worker before it runs the task or
+    builds the actor. ``JAX_PLATFORMS=cpu`` set explicitly (CPU tests,
+    rehearsals on virtual devices) is the one way to run such work
+    without a chip. Initialises the JAX backend — the grant is what
+    entitles this process to the chip."""
+    if not (resources or {}).get("TPU", 0) or \
+            os.environ.get("JAX_PLATFORMS") == "cpu":
+        return
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise RuntimeError(
+            f"granted a TPU resource but JAX runs on {dev.platform!r} "
+            f"({dev.device_kind}): no TPU is attached to this process; "
+            f"set JAX_PLATFORMS=cpu to run it on the CPU on purpose")
+
+
 def accelerator_generation(accelerator_type: str) -> str:
     """"v5litepod-16" -> "v5e", "v4-8" -> "v4" (reference tpu.py:58-76
     keeps the same family table)."""

@@ -1,0 +1,147 @@
+"""AOT compiles of the serving and training kernels for a DESCRIBED v5e
+(no chip attached): the TPU compiler is installed in the sandbox and
+refuses here what it would refuse on the machine — VMEM over the scoped
+limit, misaligned slices, a Mosaic kernel it is asked to partition.
+Interpret-mode parity tests cannot see any of that. Shapes are the
+published Llama-3-8B widths (32 Q / 8 KV heads of 128) at every
+chunk_size x page_size the engine's defaults and bench_serve.py use.
+A compile that passes is not a chip run: nothing here measures anything.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+
+from ray_tpu.ops.flash_attention import _flash_bwd, _flash_fwd
+from ray_tpu.ops.ragged_paged_attention import (
+    ragged_decode_attention, ragged_paged_attention,
+)
+from ray_tpu.parallel.mesh import use_mesh
+from ray_tpu.parallel.sharding import logical_spec
+
+H, KVH, D = 32, 8, 128
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The described 2x2 v5e topology; skips where libtpu cannot describe
+    it. The persistent compile cache (conftest turns it on) is off around
+    these compiles: an entry written for a described chip cannot be read
+    back without one, and the next run would warn and compile again."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
+        pytest.skip(f"cannot describe a v5e topology here: {e!r}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+def _compile(fn, *shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sh)
+            for (s, dt), sh in zip(shapes, sharding)]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _paged_shapes(rows, q_window, page, max_pages, pool=256):
+    pages = ((pool, page, KVH, D), BF16)
+    return [((rows, q_window, H, D), BF16), pages, pages,
+            ((rows, max_pages), jnp.int32), ((rows,), jnp.int32),
+            ((rows,), jnp.int32)]
+
+
+@pytest.mark.parametrize("rows,q_window,page,max_pages", [
+    (4, 128, 16, 64),      # engine defaults: chunk 128, page 16
+    (4, 128, 128, 16),
+    (1, 256, 16, 128),     # bench_serve.py's TPU chunk
+    (1, 256, 128, 16),
+    (8, 5, 16, 64),        # verify window: 1 + spec_tokens=4 drafts
+])
+def test_ragged_window_compiles_at_8b_widths(v5e, rows, q_window, page,
+                                             max_pages):
+    one = SingleDeviceSharding(v5e.devices[0])
+    text = _compile(
+        ragged_paged_attention,
+        *_paged_shapes(rows, q_window, page, max_pages),
+        sharding=[one] * 6).as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_ragged_decode_compiles_at_8b_widths(v5e):
+    one = SingleDeviceSharding(v5e.devices[0])
+    shapes = _paged_shapes(8, 1, 16, 128)
+    shapes[0] = ((8, H, D), BF16)
+    text = _compile(ragged_decode_attention, *shapes[:5],
+                    sharding=[one] * 5).as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_flash_fwd_bwd_compile_at_8b_widths(v5e):
+    one = SingleDeviceSharding(v5e.devices[0])
+    q, kv = ((2, 1024, H, D), BF16), ((2, 1024, KVH, D), BF16)
+    kw = dict(causal=True, scale=D ** -0.5, block_q=512, block_k=512,
+              interpret=False)
+
+    def fwd_bwd(q, k, v, g):
+        out, lse = _flash_fwd(q, k, v, kw["causal"], kw["scale"],
+                              kw["block_q"], kw["block_k"], False)
+        return _flash_bwd(q, k, v, out, lse, g, **kw)
+
+    text = _compile(fwd_bwd, q, kv, kv, q, sharding=[one] * 4).as_text()
+    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+
+
+def test_tp4_decode_step_keeps_its_kernels_in_shard_map(v5e, monkeypatch):
+    """The engine's tp layout on the 2x2 topology: weights by
+    llama.logical_axes, KV pages sharded over kv_heads. The bare kernel
+    on such operands is refused ("Mosaic kernels cannot be automatically
+    partitioned"); the model's decode step — where every kernel call
+    goes through shard_kernel — compiles, keeps one per-shard custom
+    call per layer, and gathers no page pool."""
+    from ray_tpu.models import llama
+    from ray_tpu.parallel.sharding import logical_sharding
+
+    mesh = Mesh(np.asarray(v5e.devices).reshape(4), ("tp",))
+    mc = llama.llama3_8b(n_layers=2)
+    rows, page, max_pages, pool = 8, 16, 128, 256
+    # this process sees the CPU: steer the kernel-vs-reference dispatch
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+    with use_mesh(mesh):
+        repl = NamedSharding(mesh, logical_spec(()))
+        kv = NamedSharding(mesh, logical_spec((None, None, "kv_heads")))
+        heads = NamedSharding(mesh, logical_spec((None, "heads")))
+        bare = _paged_shapes(rows, 1, page, max_pages, pool)
+        bare[0] = ((rows, H, D), BF16)
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            _compile(ragged_decode_attention, *bare[:5],
+                     sharding=[heads, kv, kv, repl, repl])
+
+        def sds(shape, dtype, sharding):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+        params = jax.tree.map(
+            lambda s, sh: sds(s.shape, s.dtype, sh),
+            jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), mc)),
+            logical_sharding(llama.logical_axes(mc)))
+        caches = [{n: sds((pool, page, KVH, D), BF16, kv) for n in "kv"}
+                  for _ in range(mc.n_layers)]
+        text = jax.jit(functools.partial(
+            llama.decode_paged, cfg=mc, page_size=page)).lower(
+            params, sds((rows, 1), jnp.int32, repl), caches,
+            sds((rows, max_pages), jnp.int32, repl),
+            sds((rows,), jnp.int32, repl)).compile().as_text()
+    assert text.count("tpu_custom_call") == mc.n_layers
+    gathered_pool = f"bf16[{pool},{page},{KVH},{D}]"
+    assert not [ln for ln in text.splitlines()
+                if "all-gather" in ln and gathered_pool in ln]

@@ -87,6 +87,27 @@ def test_tp4_greedy_bit_identical():
     assert eng.stats["mesh_reshard_bytes"] == 0, eng.stats
 
 
+@pytest.mark.skipif(len(jax.devices()) < 4,
+                    reason="needs >=4 (virtual) devices")
+def test_tp4_kernels_run_per_shard_under_shard_map():
+    """With the Pallas kernels on (interpret mode here, Mosaic on the
+    chip) every kernel call under the mesh sits inside shard_map —
+    Mosaic kernels cannot be partitioned automatically
+    (tests/test_chip_compile.py holds the compile) — and the per-shard
+    result is token-identical to the one-device kernel engine."""
+    model = llama.llama_tiny(vocab_size=256, max_seq_len=256,
+                             n_kv_heads=4)
+    over = dict(model=model, chunk_size=32)
+    prompts = _prompts(np.random.RandomState(6), (16, 70, 24))
+    ref = PagedInferenceEngine(_cfg(**over), rng_seed=0,
+                               interpret=True).generate(prompts, GREEDY)
+    eng = PagedInferenceEngine(_cfg(mesh={"tp": 4}, **over), rng_seed=0,
+                               interpret=True)
+    out = eng.generate(prompts, GREEDY)
+    assert [o["token_ids"] for o in out] == [o["token_ids"] for o in ref]
+    assert eng.stats["mesh_reshard_bytes"] == 0, eng.stats
+
+
 def test_tp2_dispatch_shardings_are_pinned():
     """Every compiled family carries the engine's pinned shardings:
     params/caches enter sharded, plain operands replicated — compiled

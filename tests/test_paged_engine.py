@@ -217,10 +217,10 @@ def test_spec_decode_beats_window_on_repetitive_text():
 def test_warmup_covers_every_burst_program():
     """After warmup(), a mixed burst (several prompt lengths, partial
     final prefill pack, window-1 and full-window decodes, spec verify)
-    must trigger ZERO new jit entries: on a remote-attached accelerator
-    one mid-burst compile costs tens of requests' worth of TTFT, so the
-    row-bucketing + warmup contract is exactly 'no compiles after
-    deploy' (reference analog: vLLM's deploy-time graph capture,
+    must trigger ZERO new jit entries: a mid-burst compile lands in some
+    request's TTFT (seconds per program on a v5e — chip_smoke.py prints
+    the warm-up's compile time), so the row-bucketing + warmup contract
+    is exactly 'no compiles after deploy' (reference analog: vLLM's deploy-time graph capture,
     vllm_engine.py:180)."""
     rng = np.random.RandomState(3)
     cfg = PagedEngineConfig(

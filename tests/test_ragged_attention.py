@@ -58,6 +58,32 @@ def test_kernel_matches_oracle_ragged_rows(groups, page):
     assert np.isfinite(np.asarray(got)).all()
 
 
+@pytest.mark.parametrize("q_window", [32, 20])
+def test_kernel_tiles_the_query_window(monkeypatch, q_window):
+    """A window wider than the VMEM budget is swept tile by tile (what
+    lets the 8B-width chunk compile for the chip — test_chip_compile.py):
+    shrink the budget so a tiny window needs several tiles, one of them
+    padded (20 = 2.5 tiles of 8), with rows that end mid-tile, start
+    off a page boundary, and leave whole tiles as padding."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    rng = np.random.RandomState(3)
+    page, kvh, groups, d, P, maxp = 8, 2, 2, 32, 24, 8
+    h = kvh * groups
+    monkeypatch.setattr(rpa, "_Q_TILE_ELEMS", 8 * h * d)
+    assert rpa._q_tile(q_window, h, d) == 8
+    q = jnp.asarray(rng.randn(4, q_window, h, d), jnp.float32)
+    kp, vp = _pools(rng, P, page, kvh, d)
+    bt = jnp.asarray(rng.randint(1, P, (4, maxp)), jnp.int32)
+    starts = jnp.asarray([0, 13, 2 * page + 3, 0], jnp.int32)
+    q_lens = jnp.asarray([q_window, 11, 3, 0], jnp.int32)
+    ref = ragged_paged_reference(q, kp, vp, bt, starts, q_lens)
+    got = ragged_paged_attention(q, kp, vp, bt, starts, q_lens,
+                                 interpret=True)
+    assert got.shape == q.shape
+    _assert_rows_close(got, ref, q_lens)
+    assert np.isfinite(np.asarray(got)).all()
+
+
 def test_kernel_skips_pages_beyond_live_count():
     """Sink-page-0 safety: block-table entries at/beyond a row's live
     page count point at a POISONED page; `pl.when` + the clamped index
