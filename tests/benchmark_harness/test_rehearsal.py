@@ -1,0 +1,43 @@
+"""The exact command of BENCHMARK.json, rehearsed on the CPU at toy sizes
+(the program takes its jnp path off the chip): the control flow of the
+serving cell (traced), of the training cell, of the pending open-loop cell,
+and of the pending four-chip cell's mesh on four forced host devices. A
+rehearsal's metrics are all null and its device says cpu."""
+import pytest
+from bh_util import LAST_LINE_KEYS, add_pending, copy_benchmark, rehearse
+
+
+def _check(line: dict, names: set) -> None:
+    assert LAST_LINE_KEYS <= set(line)
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
+    assert line["device"]["platform"] == "cpu"
+    assert names <= set(line["metrics"])
+    # a number from a CPU run is never written under a device metric's name
+    assert all(m["value"] is None for m in line["metrics"].values())
+
+
+@pytest.mark.parametrize("workload,trace,names", [
+    ("docqa-sessions-1chip", 1, {"prefix_hit_tok_share",
+                                 "docqa_ttft_cold_ms",
+                                 "docqa_decode_tok_per_dispatch"}),
+    ("pretrain-4k-1chip", 0, {"train_tok_s", "setup_s"}),
+])
+def test_cell_rehearses(workload, trace, names):
+    _check(rehearse(workload, trace=trace), names)
+
+
+def test_pending_chat_cell_rehearses(tmp_path):
+    root = copy_benchmark(tmp_path)
+    add_pending(root, "chat-open-1chip")
+    _check(rehearse("chat-open-1chip", root=root),
+           {"ttft_p90_ms", "tpot_p90_ms", "setup_s"})
+
+
+def test_tp4_cell_rehearses_on_four_host_devices(tmp_path):
+    root = copy_benchmark(tmp_path)
+    add_pending(root, "chat-open-1chip")
+    add_pending(root, "chat-open-tp4")
+    line = rehearse("chat-open-tp4", root=root, trace=1)
+    _check(line, {"mesh_reshard_bytes", "decode_tok_per_dispatch"})
+    assert line["device"]["count"] >= 4
