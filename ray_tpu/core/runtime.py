@@ -3822,10 +3822,11 @@ class Runtime:
                 "ph": "X", "pid": rec.get("task_id", "driver"),
                 "ts": rec["start_s"] * 1e6,
                 "dur": rec.get("dur_s", 0.0) * 1e6,
-                "args": {k: rec[k] for k in
-                         ("trace_id", "span_id", "parent_id",
-                          "request_id")
-                         if rec.get(k) is not None}})
+                "args": {**rec.get("args", {}),
+                         **{k: rec[k] for k in
+                            ("trace_id", "span_id", "parent_id",
+                             "request_id")
+                            if rec.get(k) is not None}}})
 
     def timeline(self) -> list[dict]:
         with self.lock:

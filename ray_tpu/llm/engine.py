@@ -61,6 +61,9 @@ class _Request:
     slot: int = -1
     pages: list[int] = dataclasses.field(default_factory=list)
     prefill_pos: int = 0          # prompt tokens already prefilled (paged)
+    # prompt tokens the prefix cache served (paged; the request's share of
+    # stats["prefix_tokens_saved"], an argument of its llm.prefill span)
+    prefix_tokens_saved: int = 0
     # multi-LoRA (paged engine, cfg.max_adapters): the slot-table row
     # this request's dispatches gather — 0 = base model. Pinned for the
     # request's whole life: a hot-swap to a newer adapter version lands

@@ -19,10 +19,14 @@ Two families of jitted programs with static shapes, keyed by unroll factor:
 
 Sampling is fused into both programs (sample_logits_batch), so one engine
 step is ONE device dispatch and the only device->host traffic is the
-sampled token block (what share of a serving step on a v5e is host time
-between dispatches: not measured, ROADMAP S3). The Python loop does
-admission, page allocation and retirement; all math stays compiled. Cache buffers are
-donated through every program so XLA updates pages in place.
+sampled token block. The Python loop does admission, page allocation and
+retirement; all math stays compiled. Cache buffers are donated through
+every program so XLA updates pages in place.
+
+Where the stepping thread's time goes is counted in ``stats["ns_*"]``
+(PHASES below) and, under a profiler session, drawn as ``rtpu.engine.*``
+spans on the device trace's clock; the share of it in which no dispatch
+was outstanding is the benchmark's ``engine_host_share`` (PERF.md §3).
 """
 from __future__ import annotations
 
@@ -40,6 +44,8 @@ import numpy as np
 from ..core import flight
 from ..models import llama
 from ..util.compile_cache import enable_compile_cache
+from ..util.profiling import StepProfiler, phase
+from . import telemetry
 from .engine import (  # noqa: F401 — SamplingParams re-exported
     SamplingParams, _EngineBase, _Request, sample_logits_batch,
 )
@@ -57,8 +63,9 @@ class PagedEngineConfig:
     chunk_size: int = 128
     # dispatch batching: chunk-rows prefetched per prefill dispatch and
     # decode steps unrolled (lax.scan) per decode dispatch. Each dispatch
-    # costs a host->device round trip, which both paths amortize (how
-    # much it costs on a locally attached v5e: not measured, ROADMAP S3).
+    # costs a host->device round trip, which both paths amortize (on a
+    # locally attached v5e the host phases between two dispatches take
+    # ~1 ms against programs of 57-515 ms: PERF.md §5).
     # decode_window only applies when no prefill is pending (window 1
     # keeps TTFT low while prompts are still entering the batch).
     prefill_rows: int = 4
@@ -163,6 +170,26 @@ class PagedEngineConfig:
     @property
     def max_seq_len(self) -> int:
         return self.max_pages_per_seq * self.page_size
+
+
+# Host phases of the stepping thread: engine.stats key -> span name
+# (util/profiling.phase). The rtpu.engine.* phases partition step();
+# the rtpu.loop.* phases are LLMServer._loop's time outside step():
+# idle is the wait for work alone, other is everything else. The ten
+# sum to the thread's wall time where the server steps this engine
+# only (a per-LoRA engine's step() is booked to that engine's stats).
+PHASES = {
+    "ns_admit": "rtpu.engine.admit",
+    "ns_prefill_build": "rtpu.engine.prefill.build",
+    "ns_prefill_device": "rtpu.engine.prefill.device",
+    "ns_prefill_post": "rtpu.engine.prefill.post",
+    "ns_decode_build": "rtpu.engine.decode.build",
+    "ns_decode_device": "rtpu.engine.decode.device",
+    "ns_decode_post": "rtpu.engine.decode.post",
+    "ns_telemetry": "rtpu.engine.telemetry",
+    "ns_loop_other": "rtpu.loop.other",
+    "ns_loop_idle": "rtpu.loop.idle",
+}
 
 
 class PagedInferenceEngine(_EngineBase):
@@ -315,17 +342,39 @@ class PagedInferenceEngine(_EngineBase):
                       # counter staying 0 IS the zero-involuntary-reshard
                       # contract; all permanently 0 while mesh is off.
                       "mesh_dispatches": 0, "mesh_input_bytes": 0,
-                      "mesh_output_bytes": 0, "mesh_reshard_bytes": 0}
+                      "mesh_output_bytes": 0, "mesh_reshard_bytes": 0,
+                      # work decided at the dispatch, summed over
+                      # dispatches: slots and KV pages the decode program
+                      # had live (of max_batch_size it runs), device
+                      # steps (the window w), prefill rows live / run
+                      # (the power-of-two bucket), prompt tokens
+                      # prefilled, the pages their rows attend and the
+                      # causal (query, key) pairs they score. Each is
+                      # read by a per-layer metric of the benchmark
+                      # (PERF.md §3)
+                      "decode_live_slots": 0, "decode_live_pages": 0,
+                      "decode_steps": 0, "prefill_rows_live": 0,
+                      "prefill_rows_padded": 0, "prefill_tokens": 0,
+                      "prefill_ctx_pages": 0, "prefill_attn_pairs": 0,
+                      # request stamps, exact: submit -> admit and
+                      # admit -> first token, summed over requests
+                      "admitted": 0, "queue_wait_ns": 0,
+                      "first_tokens": 0, "prefill_span_ns": 0}
+        # the stepping thread's time by phase (util/profiling.phase):
+        # ns_<phase> sums, max_ns_<phase> keeps the longest occurrence.
+        # The eight engine phases partition step(); the two loop phases
+        # are LLMServer._loop's time outside it. Over any window their
+        # sum is the thread's wall time.
+        for key in PHASES:
+            self.stats[key] = self.stats["max_" + key] = 0
         # speculation controller: EMA of tokens-per-slot-per-spec-dispatch
         # (starts optimistic), plus a cooldown of windowed dispatches
         # before re-probing once the EMA drops below the window
         self._spec_gain = float(cfg.spec_tokens + 1)
         self._spec_cooldown = 0
         self._spec_cooldown_len = 8    # doubles per failed probe, to 256
-        # step profiler (util/profiling.py): compile-vs-execute wall
-        # split per program family; feeds profile_summary()'s MFU when
-        # estimate_flops() has run
-        from ..util.profiling import StepProfiler
+        # step profiler (util/profiling.py): counts the programs
+        # compiled, per family and static key (profile_summary)
         self.profiler = StepProfiler("paged_engine")
         # programs compiled by warmup(): profiler.compiles beyond this
         # count compiled under traffic (profile_summary)
@@ -403,8 +452,12 @@ class PagedInferenceEngine(_EngineBase):
         from ..parallel.mesh import use_mesh
         return use_mesh(self.mesh)
 
-    def _family_jit(self, run, n_plain: int):
-        """jit a dispatch family with the donated caches at arg 1. With a
+    def _family_jit(self, run, n_plain: int, name: str):
+        """jit a dispatch family with the donated caches at arg 1, under
+        ``name`` (family and static window / rows: ``rtpu_decode_w8``,
+        ``rtpu_prefill_r4``, ``rtpu_verify_r2``), which the profiler's
+        ``XLA Modules`` line shows as ``jit_<name>``: a trace then says
+        which program ran on either side of an idle gap. With a
         mesh: every in/out sharding pinned — params/caches/lora at their
         committed placements, the n_plain host-array args (token ids,
         block tables, lengths, rng, temps) replicated, outputs (sampled
@@ -413,6 +466,7 @@ class PagedInferenceEngine(_EngineBase):
         compiled program never inserts an involuntary reshard of a
         committed buffer: any transfer beyond the declared host arrays
         would need an in/out sharding this signature forbids."""
+        run.__name__ = run.__qualname__ = name
         if self.mesh is None:
             return jax.jit(run, donate_argnums=(1,))
         sh = self._shardings
@@ -534,7 +588,7 @@ class PagedInferenceEngine(_EngineBase):
                     return out.T, lps.T, c          # [B, w] each
                 return ys.T, None, c
 
-            fn = self._family_jit(run, n_plain=7)
+            fn = self._family_jit(run, 7, f"rtpu_decode_w{w}")
             self._decode_win_fns[(w, mode, pages)] = fn
         return fn
 
@@ -560,7 +614,7 @@ class PagedInferenceEngine(_EngineBase):
                     want_logp=want_logp)
                 return toks, lps, c
 
-            fn = self._family_jit(run, n_plain=8)
+            fn = self._family_jit(run, 8, f"rtpu_prefill_r{r}")
             self._prefill_rows_fns[(r, mode, pages)] = fn
         return fn
 
@@ -588,7 +642,7 @@ class PagedInferenceEngine(_EngineBase):
                     axis=-1)[..., 0]
                 return y, lp, c
 
-            fn = self._family_jit(run, n_plain=3)
+            fn = self._family_jit(run, 3, f"rtpu_verify_r{r}")
             self._verify_fns[(r, s1, pages, want_logp)] = fn
         return fn
 
@@ -987,6 +1041,7 @@ class PagedInferenceEngine(_EngineBase):
             pos += c
             self.stats["prefix_hits"] += len(pids)
             self.stats["prefix_tokens_saved"] += c
+            req.prefix_tokens_saved += c
             if self.chains is not None and req.chain_slot >= 0:
                 self.chains.hit(req.chain_slot, len(pids), c)
         if pos != req.prefill_pos:
@@ -1044,16 +1099,23 @@ class PagedInferenceEngine(_EngineBase):
 
     # -- engine loop -------------------------------------------------------
 
+    def _phase(self, key: str):
+        return phase(self.stats, key, PHASES[key])
+
     def step(self):
-        """One iteration: admit, one prefill chunk (bounded), one decode."""
-        self._admit()
+        """One iteration: admit, one prefill chunk (bounded), one decode.
+        All of it falls in one of the eight rtpu.engine.* phases
+        (PHASES): admit, {prefill, decode} x {build, device, post},
+        telemetry."""
+        with self._phase("ns_admit"):
+            self._admit()
         # the mesh scope pins trace-time constrain() resolution for any
         # program a dispatch compiles below (a no-op off-mesh)
         with self._mesh_scope():
             self._prefill_step()
             self._decode_step()
-        from . import telemetry
-        telemetry.on_step(self)
+        with self._phase("ns_telemetry"):
+            telemetry.on_step(self)
 
     def _admit(self):
         with self._lock:
@@ -1092,129 +1154,158 @@ class PagedInferenceEngine(_EngineBase):
                     # chunked prefill starts at the first uncached chunk
                     # boundary
                     req.prefill_pos = len(matched) * self.cfg.page_size
+                    req.prefix_tokens_saved = req.prefill_pos
                     self.stats["prefix_hits"] += len(matched)
                     self.stats["prefix_tokens_saved"] += req.prefill_pos
                     if self.chains is not None:
                         self.chains.hit(req.chain_slot, len(matched),
                                         req.prefill_pos)
                 self._prefilling.append(req)
-                from . import telemetry
                 telemetry.on_admit(self, req)
+                self.stats["admitted"] += 1
+                self.stats["queue_wait_ns"] += int(
+                    (req.admit_t - req.submit_t) * 1e9)
 
     def _prefill_step(self):
-        import time
         if not self._prefilling:
             return
         cfg = self.cfg
         c = cfg.chunk_size
-        # pack up to prefill_rows chunk-rows, queue order; a request with
-        # several remaining chunks occupies consecutive rows (the scan
-        # carries caches, so later rows see earlier rows' page writes)
-        rows: list[tuple] = []              # (req, start, n_tokens)
-        for req in self._prefilling:
-            # skip ahead over chunks published since the last step (an
-            # identical-prefix burst: request 1 computes, the rest map)
-            self._try_reuse(req)
-            pos = req.prefill_pos
-            while pos < len(req.prompt_ids) and len(rows) < cfg.prefill_rows:
-                n = min(c, len(req.prompt_ids) - pos)
-                rows.append((req, pos, n))
-                pos += n
-            if len(rows) >= cfg.prefill_rows:
-                break
-        # bucket the row count to a power of two (same trick as
-        # _spec_step): the jit cache holds O(log prefill_rows) prefill
-        # programs instead of one per packed-row count. Pad rows carry
-        # true_len 0, so the kernel routes all their writes to sink page
-        # 0 (prefill_paged_rows docstring) — they cost compute but no
-        # fresh XLA compile, and a mid-burst compile lands in some
-        # request's latency.
-        r = len(rows)
-        rb = min(1 << max(r - 1, 0).bit_length(), cfg.prefill_rows)
-        # block-table width bucket: widest logical page any row reads or
-        # writes this dispatch (prefix + chunk = pos + n tokens)
-        pg = cfg.page_size
-        W = self._page_bucket(max(
-            (pos + n + pg - 1) // pg for _, pos, n in rows))
-        chunks = np.zeros((rb, c), np.int32)
-        bts = np.zeros((rb, W), np.int32)
-        sps = np.zeros((rb,), np.int32)
-        tls = np.zeros((rb,), np.int32)
-        temps = np.zeros((rb,), np.float32)
-        topks = np.zeros((rb,), np.int32)
-        lslots = np.zeros((rb,), np.int32)
-        for i, (req, pos, n) in enumerate(rows):
-            chunks[i, :n] = req.prompt_ids[pos:pos + n]
-            bts[i] = self._block_tables[req.slot][:W]
-            sps[i], tls[i] = pos, n
-            temps[i] = req.params.temperature
-            topks[i] = req.params.top_k
-            lslots[i] = req.adapter_slot
-        mode = self._sampling_mode([q for q, _, _ in rows])
-        with self.profiler.step("prefill", (rb, mode, W)):
-            toks, lps, self.caches = self._prefill_rows_fn(rb, mode, W)(
+        with self._phase("ns_prefill_build"):
+            # pack up to prefill_rows chunk-rows, queue order; a request
+            # with several remaining chunks occupies consecutive rows (the
+            # scan carries caches, so later rows see earlier rows' page
+            # writes)
+            rows: list[tuple] = []              # (req, start, n_tokens)
+            for req in self._prefilling:
+                # skip ahead over chunks published since the last step (an
+                # identical-prefix burst: request 1 computes, the rest map)
+                self._try_reuse(req)
+                pos = req.prefill_pos
+                while pos < len(req.prompt_ids) and \
+                        len(rows) < cfg.prefill_rows:
+                    n = min(c, len(req.prompt_ids) - pos)
+                    rows.append((req, pos, n))
+                    pos += n
+                if len(rows) >= cfg.prefill_rows:
+                    break
+            # bucket the row count to a power of two (same trick as
+            # _spec_step): the jit cache holds O(log prefill_rows) prefill
+            # programs instead of one per packed-row count. Pad rows carry
+            # true_len 0, so the kernel routes all their writes to sink
+            # page 0 (prefill_paged_rows docstring) — they cost compute
+            # but no fresh XLA compile, and a mid-burst compile lands in
+            # some request's latency.
+            r = len(rows)
+            rb = min(1 << max(r - 1, 0).bit_length(), cfg.prefill_rows)
+            # block-table width bucket: widest logical page any row reads
+            # or writes this dispatch (prefix + chunk = pos + n tokens)
+            pg = cfg.page_size
+            ctx_pages = [(pos + n + pg - 1) // pg for _, pos, n in rows]
+            W = self._page_bucket(max(ctx_pages))
+            chunks = np.zeros((rb, c), np.int32)
+            bts = np.zeros((rb, W), np.int32)
+            sps = np.zeros((rb,), np.int32)
+            tls = np.zeros((rb,), np.int32)
+            temps = np.zeros((rb,), np.float32)
+            topks = np.zeros((rb,), np.int32)
+            lslots = np.zeros((rb,), np.int32)
+            for i, (req, pos, n) in enumerate(rows):
+                chunks[i, :n] = req.prompt_ids[pos:pos + n]
+                bts[i] = self._block_tables[req.slot][:W]
+                sps[i], tls[i] = pos, n
+                temps[i] = req.params.temperature
+                topks[i] = req.params.top_k
+                lslots[i] = req.adapter_slot
+            mode = self._sampling_mode([q for q, _, _ in rows])
+            fn = self._prefill_rows_fn(rb, mode, W)
+        with self._phase("ns_prefill_device"), \
+                self.profiler.step("prefill", (rb, mode, W)):
+            toks, lps, self.caches = fn(
                 self.params, self.caches, chunks, bts, sps, tls,
                 self._rng_base, np.int32(self._rng_ctr), temps, topks,
                 *self._lora_args(lslots))
             toks = np.asarray(toks)     # block: the step must measure
             lps = None if lps is None else np.asarray(lps)
-        self._rng_ctr += 1
-        self.stats["prefill_dispatches"] += 1
-        self._mesh_account(
-            chunks.nbytes + bts.nbytes + sps.nbytes + tls.nbytes
-            + temps.nbytes + topks.nbytes + lslots.nbytes,
-            toks.nbytes + (0 if lps is None else lps.nbytes))
-        if self._prefix_on:
-            page = cfg.page_size
-            for req, pos, n in rows:
-                # full prompt pages this row computed are misses; publish
-                # them immediately so the rest of the burst can reuse
-                # (their K/V is fully written once this dispatch returns)
-                lo, hi = pos // page, (pos + n) // page
-                self.stats["prefix_misses"] += hi - lo
-                if self.chains is not None and hi > lo \
-                        and req.chain_slot >= 0:
-                    self.chains.miss(req.chain_slot, hi - lo)
-                hashes = self._prompt_hashes(req)
-                for j in range(lo, hi):
-                    self._register_page(req.pages[j], hashes[j],
-                                        chain=req.chain_slot)
-        for i, (req, pos, n) in enumerate(rows):
-            req.prefill_pos = pos + n
-            if req.prefill_pos < len(req.prompt_ids):
-                continue
-            # prompt done: the row's in-jit sampled token is the first
-            # generated token
-            tok = int(toks[i])
-            req.out_ids.append(tok)
-            if lps is not None:
-                req.out_logps.append(float(lps[i]))
-            self.stats["tokens_out"] += 1
-            req.first_token_t = time.perf_counter()
-            from . import telemetry
-            telemetry.on_first_token(self, req)
-            self._lengths[req.slot] = len(req.prompt_ids)
-            self._prefilling.remove(req)
-            if getattr(req, "prefill_only", False):
-                # disaggregated prefill: export the KV pages + first token
-                # instead of decoding here (llm/pd_disagg.py). Under the
-                # pool lock: _release mutates _free_slots/_page_refs,
-                # which a concurrent submit/import_prefill (replica
-                # threads) also touches — and the export must not observe
-                # a cache swap mid-gather. _finish_request stays OUTSIDE
-                # it: the span emit can write a pipe, and blocking I/O
-                # under the admission lock stalls every replica thread
-                # (the GL002 bug class).
-                with self._lock:
-                    req.export_payload = self._export_kv_locked(req, tok)
-                    self._release(req)
-                self._finish_request(req, "export")
-                continue
-            self._active[req.slot] = req
-            self._maybe_finish(req, tok)
+        with self._phase("ns_prefill_post"):
+            self._rng_ctr += 1
+            st = self.stats
+            st["prefill_dispatches"] += 1
+            st["prefill_rows_live"] += r
+            st["prefill_rows_padded"] += rb
+            st["prefill_tokens"] += int(tls.sum())
+            st["prefill_ctx_pages"] += sum(ctx_pages)
+            # causal (query, key) pairs: token q of a row attends the
+            # pos cached tokens and the row's first q + 1
+            st["prefill_attn_pairs"] += sum(
+                n * pos + n * (n + 1) // 2 for _, pos, n in rows)
+            self._mesh_account(
+                chunks.nbytes + bts.nbytes + sps.nbytes + tls.nbytes
+                + temps.nbytes + topks.nbytes + lslots.nbytes,
+                toks.nbytes + (0 if lps is None else lps.nbytes))
+            if self._prefix_on:
+                self._publish_prefilled(rows)
+            for i, (req, pos, n) in enumerate(rows):
+                req.prefill_pos = pos + n
+                if req.prefill_pos >= len(req.prompt_ids):
+                    # prompt done: the row's in-jit sampled token is the
+                    # first generated token
+                    self._first_token(
+                        req, int(toks[i]),
+                        None if lps is None else float(lps[i]))
         # NOTE: pad positions of the final chunk were written into the
         # sequence's own pages beyond its true length; decode masks
         # positions >= length so they are never attended.
+
+    def _publish_prefilled(self, rows):
+        """Full prompt pages the dispatch's rows computed are misses;
+        publish them immediately so the rest of a burst can reuse them
+        (their K/V is fully written once the dispatch returns)."""
+        page = self.cfg.page_size
+        for req, pos, n in rows:
+            lo, hi = pos // page, (pos + n) // page
+            self.stats["prefix_misses"] += hi - lo
+            if self.chains is not None and hi > lo \
+                    and req.chain_slot >= 0:
+                self.chains.miss(req.chain_slot, hi - lo)
+            hashes = self._prompt_hashes(req)
+            for j in range(lo, hi):
+                self._register_page(req.pages[j], hashes[j],
+                                    chain=req.chain_slot)
+
+    def _first_token(self, req: _Request, tok: int, lp: Optional[float]):
+        """A prompt's last chunk returned: book its first generated
+        token and move the request into the decode set (or export it,
+        on a disaggregated prefill replica)."""
+        req.out_ids.append(tok)
+        if lp is not None:
+            req.out_logps.append(lp)
+        st = self.stats
+        st["tokens_out"] += 1
+        req.first_token_t = time.perf_counter()
+        telemetry.on_first_token(self, req)
+        st["first_tokens"] += 1
+        st["prefill_span_ns"] += int(
+            (req.first_token_t - req.admit_t) * 1e9)
+        self._lengths[req.slot] = len(req.prompt_ids)
+        self._prefilling.remove(req)
+        if getattr(req, "prefill_only", False):
+            # disaggregated prefill: export the KV pages + first token
+            # instead of decoding here (llm/pd_disagg.py). Under the
+            # pool lock: _release mutates _free_slots/_page_refs,
+            # which a concurrent submit/import_prefill (replica
+            # threads) also touches — and the export must not observe
+            # a cache swap mid-gather. _finish_request stays OUTSIDE
+            # it: the span emit can write a pipe, and blocking I/O
+            # under the admission lock stalls every replica thread
+            # (the GL002 bug class).
+            with self._lock:
+                req.export_payload = self._export_kv_locked(req, tok)
+                self._release(req)
+            self._finish_request(req, "export")
+            return
+        self._active[req.slot] = req
+        self._maybe_finish(req, tok)
 
     @staticmethod
     def _propose_draft(ctx: np.ndarray, n: int, s: int) -> list[int]:
@@ -1238,107 +1329,123 @@ class PagedInferenceEngine(_EngineBase):
         start = int(viable[-1] if len(viable) else hits[0]) + n
         return [int(t) for t in ctx[start:start + s]]
 
+    def _live_pages(self, slots) -> int:
+        """KV pages that hold the given slots' tokens: what one decode
+        step's attention has to stream."""
+        page = self.cfg.page_size
+        return int(sum(-(-int(self._lengths[sl]) // page) for sl in slots))
+
     def _spec_step(self) -> bool:
         """One speculative verify dispatch over every active slot. Only
         runs when every slot is greedy (the accept rule reproduces exact
         greedy; sampled rows fall back to the windowed path) and at least
-        one slot has a draft. Returns False to fall through."""
+        one slot has a draft. Returns False to fall through. Its phases
+        are the decode ones: the verify dispatch is this step's decode."""
         cfg = self.cfg
         s, page = cfg.spec_tokens, cfg.page_size
-        slots = sorted(self._active)
-        drafts = {}
-        for slot in slots:
-            req = self._active[slot]
-            ctx = np.asarray(req.prompt_ids + req.out_ids, np.int32)
-            drafts[slot] = self._propose_draft(ctx, cfg.spec_ngram, s)
-        # every slot must carry a draft: in a spec dispatch a draft-less
-        # slot emits exactly ONE token, strictly worse than its share of
-        # a decode window. A no-draft round costs the same backed-off
-        # cooldown as a failed probe, so non-repetitive text doesn't pay
-        # the O(context) n-gram scan on every step.
-        if not all(drafts.values()):
-            self._spec_cooldown = self._spec_cooldown_len
-            self._spec_cooldown_len = min(self._spec_cooldown_len * 2, 256)
-            return False
-        # bucket the row count to a power of two so the jit cache holds
-        # O(log max_batch) verify programs, not one per active-set size;
-        # pad rows write only to sink page 0 and are discarded
-        r, s1 = len(slots), s + 1
-        rb = min(1 << max(r - 1, 0).bit_length(), cfg.max_batch_size)
-        # table-width bucket: every row writes positions start..start+s1-1,
-        # so the width must cover their pages (beyond-allocation writes
-        # then hit the row's zero entries = sink page, never a clamp)
-        W = self._page_bucket(max(
-            (self._lengths[sl] + s1 - 1) // page + 1 for sl in slots))
-        toks = np.zeros((rb, s1), np.int32)
-        bts = np.zeros((rb, W), np.int32)
-        starts = np.zeros((rb,), np.int32)
-        lslots = np.zeros((rb,), np.int32)
-        allow: dict[int, int] = {}
-        for i, slot in enumerate(slots):
-            req = self._active[slot]
-            allow[slot] = self._reserve(req, s1)
-            toks[i, 0] = req.out_ids[-1]
-            toks[i, 1:1 + len(drafts[slot])] = drafts[slot]
-            bts[i] = self._block_tables[slot][:W]
-            starts[i] = self._lengths[slot]
-            lslots[i] = req.adapter_slot
-        want_lp = any(self._active[sl].params.logprobs for sl in slots)
-        with self.profiler.step("verify", (rb, s1, W, want_lp)):
-            y, ylp, self.caches = self._verify_fn(rb, s1, W, want_lp)(
+        with self._phase("ns_decode_build"):
+            slots = sorted(self._active)
+            drafts = {}
+            for slot in slots:
+                req = self._active[slot]
+                ctx = np.asarray(req.prompt_ids + req.out_ids, np.int32)
+                drafts[slot] = self._propose_draft(ctx, cfg.spec_ngram, s)
+            # every slot must carry a draft: in a spec dispatch a
+            # draft-less slot emits exactly ONE token, strictly worse than
+            # its share of a decode window. A no-draft round costs the
+            # same backed-off cooldown as a failed probe, so
+            # non-repetitive text doesn't pay the O(context) n-gram scan
+            # on every step.
+            if not all(drafts.values()):
+                self._spec_cooldown = self._spec_cooldown_len
+                self._spec_cooldown_len = min(
+                    self._spec_cooldown_len * 2, 256)
+                return False
+            # bucket the row count to a power of two so the jit cache
+            # holds O(log max_batch) verify programs, not one per
+            # active-set size; pad rows write only to sink page 0 and are
+            # discarded
+            r, s1 = len(slots), s + 1
+            rb = min(1 << max(r - 1, 0).bit_length(), cfg.max_batch_size)
+            # table-width bucket: every row writes positions
+            # start..start+s1-1, so the width must cover their pages
+            # (beyond-allocation writes then hit the row's zero entries =
+            # sink page, never a clamp)
+            W = self._page_bucket(max(
+                (self._lengths[sl] + s1 - 1) // page + 1 for sl in slots))
+            toks = np.zeros((rb, s1), np.int32)
+            bts = np.zeros((rb, W), np.int32)
+            starts = np.zeros((rb,), np.int32)
+            lslots = np.zeros((rb,), np.int32)
+            allow: dict[int, int] = {}
+            for i, slot in enumerate(slots):
+                req = self._active[slot]
+                allow[slot] = self._reserve(req, s1)
+                toks[i, 0] = req.out_ids[-1]
+                toks[i, 1:1 + len(drafts[slot])] = drafts[slot]
+                bts[i] = self._block_tables[slot][:W]
+                starts[i] = self._lengths[slot]
+                lslots[i] = req.adapter_slot
+            want_lp = any(self._active[sl].params.logprobs for sl in slots)
+            fn = self._verify_fn(rb, s1, W, want_lp)
+        with self._phase("ns_decode_device"), \
+                self.profiler.step("verify", (rb, s1, W, want_lp)):
+            y, ylp, self.caches = fn(
                 self.params, self.caches, toks, bts, starts,
                 *self._lora_args(lslots))
             y = np.asarray(y)               # [r, s1]; block: measure
             ylp = None if ylp is None else np.asarray(ylp)
-        self.stats["spec_dispatches"] += 1
-        self._mesh_account(
-            toks.nbytes + bts.nbytes + starts.nbytes + lslots.nbytes,
-            y.nbytes + (0 if ylp is None else ylp.nbytes))
-        emitted = 0
-        for i, slot in enumerate(slots):
-            req = self._active[slot]
-            d = drafts[slot]
-            self.stats["spec_proposed"] += len(d)
-            # accept: token j's prediction y[i, j] is the true next token
-            # only while every earlier draft matched the model's choice
-            def _lp(row, col):
-                return None if ylp is None else float(ylp[row, col])
-            out = [(int(y[i, 0]), _lp(i, 0))]
-            for j in range(len(d)):
-                if d[j] != out[-1][0]:
-                    break
-                out.append((int(y[i, j + 1]), _lp(i, j + 1)))
-                self.stats["spec_accepted"] += 1
-            consumed = 0
-            for tok, lp in out:
-                if consumed >= allow[slot]:
-                    from . import telemetry
-                    telemetry.on_preempted(self)
-                    self._retire(req)
-                    break
-                req.out_ids.append(tok)
-                if lp is not None:
-                    req.out_logps.append(lp)
-                self._lengths[slot] += 1
-                consumed += 1
-                self.stats["tokens_out"] += 1
-                if self._stop_after(req, tok):
-                    self._retire(req)
-                    break
-            emitted += consumed
-        # controller: keep speculating only while it beats the window;
-        # on fallback, re-probe optimistically after a cooldown that
-        # doubles per consecutive failed probe (text that never accepts
-        # pays a vanishing probe tax, text that turns repetitive is
-        # rediscovered within ~cooldown windows)
-        self._spec_gain = 0.5 * self._spec_gain + 0.5 * (emitted / r)
-        if self._spec_gain <= self.cfg.decode_window and \
-                self.cfg.decode_window > 1:
-            self._spec_cooldown = self._spec_cooldown_len
-            self._spec_cooldown_len = min(self._spec_cooldown_len * 2, 256)
-            self._spec_gain = float(s + 1)
-        else:
-            self._spec_cooldown_len = 8
+        with self._phase("ns_decode_post"):
+            self.stats["spec_dispatches"] += 1
+            self._mesh_account(
+                toks.nbytes + bts.nbytes + starts.nbytes + lslots.nbytes,
+                y.nbytes + (0 if ylp is None else ylp.nbytes))
+            emitted = 0
+            for i, slot in enumerate(slots):
+                req = self._active[slot]
+                d = drafts[slot]
+                self.stats["spec_proposed"] += len(d)
+                # accept: token j's prediction y[i, j] is the true next
+                # token only while every earlier draft matched the
+                # model's choice
+                def _lp(row, col):
+                    return None if ylp is None else float(ylp[row, col])
+                out = [(int(y[i, 0]), _lp(i, 0))]
+                for j in range(len(d)):
+                    if d[j] != out[-1][0]:
+                        break
+                    out.append((int(y[i, j + 1]), _lp(i, j + 1)))
+                    self.stats["spec_accepted"] += 1
+                consumed = 0
+                for tok, lp in out:
+                    if consumed >= allow[slot]:
+                        telemetry.on_preempted(self)
+                        self._retire(req)
+                        break
+                    req.out_ids.append(tok)
+                    if lp is not None:
+                        req.out_logps.append(lp)
+                    self._lengths[slot] += 1
+                    consumed += 1
+                    self.stats["tokens_out"] += 1
+                    if self._stop_after(req, tok):
+                        self._retire(req)
+                        break
+                emitted += consumed
+            # controller: keep speculating only while it beats the window;
+            # on fallback, re-probe optimistically after a cooldown that
+            # doubles per consecutive failed probe (text that never
+            # accepts pays a vanishing probe tax, text that turns
+            # repetitive is rediscovered within ~cooldown windows)
+            self._spec_gain = 0.5 * self._spec_gain + 0.5 * (emitted / r)
+            if self._spec_gain <= self.cfg.decode_window and \
+                    self.cfg.decode_window > 1:
+                self._spec_cooldown = self._spec_cooldown_len
+                self._spec_cooldown_len = min(
+                    self._spec_cooldown_len * 2, 256)
+                self._spec_gain = float(s + 1)
+            else:
+                self._spec_cooldown_len = 8
         return True
 
     def _decode_step(self):
@@ -1354,66 +1461,78 @@ class PagedInferenceEngine(_EngineBase):
                 self._spec_cooldown -= 1
             elif self._spec_step():
                 return
-        # full window only when no prompt is waiting: a pending prefill
-        # gets interleaved every step, keeping TTFT low under bursts
-        w = 1 if not quiet else cfg.decode_window
-        # table-width bucket: the window writes positions len..len+w-1
-        # per slot, so the width covers every such page (beyond-allocation
-        # writes then hit zero entries = sink page, never a clamp)
-        W = self._page_bucket(max(
-            (self._lengths[sl] + w - 1) // page + 1 for sl in self._active))
-        tokens = np.zeros((bs,), np.int32)
-        lengths = np.zeros((bs,), np.int32)
-        temps = np.zeros((bs,), np.float32)
-        topks = np.zeros((bs,), np.int32)
-        lslots = np.zeros((bs,), np.int32)
-        # slots not decoding this step get a zeroed block-table row: their
-        # dummy writes go to sink page 0 instead of a live (possibly
-        # reused) page
-        bt = np.zeros((bs, W), np.int32)
-        allow: dict[int, int] = {}          # valid tokens per slot this window
-        for slot, req in self._active.items():
-            allow[slot] = self._reserve(req, w)
-            tokens[slot] = req.out_ids[-1]
-            lengths[slot] = self._lengths[slot]
-            temps[slot] = req.params.temperature
-            topks[slot] = req.params.top_k
-            bt[slot] = self._block_tables[slot][:W]
-            lslots[slot] = req.adapter_slot
-        mode = self._sampling_mode(self._active.values())
-        with self.profiler.step("decode", (w, mode, W)):
-            out, lps, self.caches = self._decode_window_fn(w, mode, W)(
+        with self._phase("ns_decode_build"):
+            # full window only when no prompt is waiting: a pending
+            # prefill gets interleaved every step, keeping TTFT low under
+            # bursts
+            w = 1 if not quiet else cfg.decode_window
+            # table-width bucket: the window writes positions
+            # len..len+w-1 per slot, so the width covers every such page
+            # (beyond-allocation writes then hit zero entries = sink page,
+            # never a clamp)
+            W = self._page_bucket(max(
+                (self._lengths[sl] + w - 1) // page + 1
+                for sl in self._active))
+            live_slots = len(self._active)
+            live_pages = self._live_pages(self._active)
+            tokens = np.zeros((bs,), np.int32)
+            lengths = np.zeros((bs,), np.int32)
+            temps = np.zeros((bs,), np.float32)
+            topks = np.zeros((bs,), np.int32)
+            lslots = np.zeros((bs,), np.int32)
+            # slots not decoding this step get a zeroed block-table row:
+            # their dummy writes go to sink page 0 instead of a live
+            # (possibly reused) page
+            bt = np.zeros((bs, W), np.int32)
+            allow: dict[int, int] = {}      # valid tokens per slot
+            for slot, req in self._active.items():
+                allow[slot] = self._reserve(req, w)
+                tokens[slot] = req.out_ids[-1]
+                lengths[slot] = self._lengths[slot]
+                temps[slot] = req.params.temperature
+                topks[slot] = req.params.top_k
+                bt[slot] = self._block_tables[slot][:W]
+                lslots[slot] = req.adapter_slot
+            mode = self._sampling_mode(self._active.values())
+            fn = self._decode_window_fn(w, mode, W)
+        with self._phase("ns_decode_device"), \
+                self.profiler.step("decode", (w, mode, W)):
+            out, lps, self.caches = fn(
                 self.params, self.caches, tokens, bt, lengths,
                 self._rng_base, np.int32(self._rng_ctr), temps, topks,
                 *self._lora_args(lslots))
             out = np.asarray(out)           # [bs, w]; block to measure
             lps = None if lps is None else np.asarray(lps)
-        self._rng_ctr += 1
-        self.stats["decode_dispatches"] += 1
-        self._mesh_account(
-            tokens.nbytes + bt.nbytes + lengths.nbytes + temps.nbytes
-            + topks.nbytes + lslots.nbytes,
-            out.nbytes + (0 if lps is None else lps.nbytes))
-        for slot in list(self._active):
-            req = self._active[slot]
-            for j in range(w):
-                if j >= allow[slot]:
-                    # page pool exhausted mid-window: finish early rather
-                    # than wedge (tokens past the allocation wrote to the
-                    # sink page and are not trustworthy)
-                    from . import telemetry
-                    telemetry.on_preempted(self)
-                    self._retire(req)
-                    break
-                tok = int(out[slot, j])
-                req.out_ids.append(tok)
-                if lps is not None:
-                    req.out_logps.append(float(lps[slot, j]))
-                self._lengths[slot] += 1
-                self.stats["tokens_out"] += 1
-                if self._stop_after(req, tok):
-                    self._retire(req)
-                    break
+        with self._phase("ns_decode_post"):
+            self._rng_ctr += 1
+            st = self.stats
+            st["decode_dispatches"] += 1
+            st["decode_live_slots"] += live_slots
+            st["decode_live_pages"] += live_pages
+            st["decode_steps"] += w
+            self._mesh_account(
+                tokens.nbytes + bt.nbytes + lengths.nbytes + temps.nbytes
+                + topks.nbytes + lslots.nbytes,
+                out.nbytes + (0 if lps is None else lps.nbytes))
+            for slot in list(self._active):
+                req = self._active[slot]
+                for j in range(w):
+                    if j >= allow[slot]:
+                        # page pool exhausted mid-window: finish early
+                        # rather than wedge (tokens past the allocation
+                        # wrote to the sink page and are not trustworthy)
+                        telemetry.on_preempted(self)
+                        self._retire(req)
+                        break
+                    tok = int(out[slot, j])
+                    req.out_ids.append(tok)
+                    if lps is not None:
+                        req.out_logps.append(float(lps[slot, j]))
+                    self._lengths[slot] += 1
+                    st["tokens_out"] += 1
+                    if self._stop_after(req, tok):
+                        self._retire(req)
+                        break
 
     def _reserve(self, req: _Request, width: int) -> int:
         """Pre-allocate pages for up to `width` new tokens and return how
@@ -1455,7 +1574,6 @@ class PagedInferenceEngine(_EngineBase):
             total = len(req.prompt_ids) + len(req.out_ids)
             if not self._ensure_pages(req, total + 1):
                 stop = True  # pool exhausted: finish early rather than wedge
-                from . import telemetry
                 telemetry.on_preempted(self)
         if stop:
             self._retire(req)
@@ -1512,7 +1630,6 @@ class PagedInferenceEngine(_EngineBase):
             req.prefix_salt = payload.get("prefix_salt", b"")
             req.submit_t = time.perf_counter()
             req.admit_t = req.submit_t
-            from . import telemetry
             telemetry.on_submit(self, req)
             self._next_rid += 1
             if not self._free_slots:
@@ -1887,72 +2004,10 @@ class PagedInferenceEngine(_EngineBase):
 
     # -- stats -------------------------------------------------------------
 
-    def estimate_flops(self) -> dict:
-        """FLOPs per dispatch for the program families via XLA
-        cost_analysis (one extra out-of-band compile per estimated
-        program — run once, after traffic or warmup, not per step).
-
-        Length-aware: estimates are taken PER static program key —
-        (rows/window, sampling mode, block-table page bucket) — for
-        every key the profiler has executed steps under, so a dispatch
-        that ran at a short page bucket is credited its true
-        bucket-proportional attention FLOPs instead of a
-        max_pages-sized estimate (which would leave short-sequence
-        steps uncredited and profile_summary() MFU understating).
-        Before any traffic, falls back to the full-width greedy decode
-        and prefill programs. Returns {family: {key: flops}}."""
-        from ..util.profiling import compiled_flops
-        cfg = self.cfg
-        bs, maxp = cfg.max_batch_size, cfg.max_pages_per_seq
-        mode = (False, False, False)
-        tags = [t for t in self.profiler.executed_tags()
-                if t[0] in ("prefill", "decode", "verify")]
-        if not tags:
-            tags = [("decode", (cfg.decode_window, mode, maxp)),
-                    ("prefill", (cfg.prefill_rows, mode, maxp))]
-        out: dict[str, dict] = {}
-        for kind, k in tags:
-            fl = compiled_flops(*self._dispatch_for_key(kind, k))
-            if fl:
-                out.setdefault(kind, {})[k] = fl
-                # credited only to steps at this EXACT static key:
-                # dispatches at other shapes/modes stay uncredited
-                # (MFU must understate, never inflate)
-                self.profiler.attach_flops(kind, fl, key=k)
-        return out
-
-    def _dispatch_for_key(self, kind: str, key: tuple):
-        """(fn, *dummy_args) reproducing the static shapes of the
-        program behind a profiler step tag — used by estimate_flops to
-        cost exactly the programs that dispatched."""
-        cfg = self.cfg
-        bs, c = cfg.max_batch_size, cfg.chunk_size
-        rkey, ctr = self._rng_base, np.int32(0)
-        if kind == "decode":
-            w, mode, W = key
-            return (self._decode_window_fn(w, mode, W),
-                    self.params, self.caches, np.zeros((bs,), np.int32),
-                    np.zeros((bs, W), np.int32), np.zeros((bs,), np.int32),
-                    rkey, ctr, np.zeros((bs,), np.float32),
-                    np.zeros((bs,), np.int32),
-                    *self._lora_args(np.zeros((bs,), np.int32)))
-        if kind == "prefill":
-            rb, mode, W = key
-            return (self._prefill_rows_fn(rb, mode, W),
-                    self.params, self.caches, np.zeros((rb, c), np.int32),
-                    np.zeros((rb, W), np.int32), np.zeros((rb,), np.int32),
-                    np.zeros((rb,), np.int32), rkey, ctr,
-                    np.zeros((rb,), np.float32), np.zeros((rb,), np.int32),
-                    *self._lora_args(np.zeros((rb,), np.int32)))
-        rb, s1, W, want_lp = key                      # verify
-        return (self._verify_fn(rb, s1, W, want_lp),
-                self.params, self.caches, np.zeros((rb, s1), np.int32),
-                np.zeros((rb, W), np.int32), np.zeros((rb,), np.int32),
-                *self._lora_args(np.zeros((rb,), np.int32)))
-
     def profile_summary(self) -> dict:
-        """Step-profiler view (util/profiling.py): compile/execute wall
-        split, per-step wall, and MFU when estimate_flops() has run."""
+        """Step-profiler view (util/profiling.py): programs compiled
+        (in warm-up and after it) and dispatches per family. Where the
+        stepping thread's time goes is in ``stats["ns_*"]`` (PHASES)."""
         return {**self.profiler.summary(),
                 # zero when warm-up covered every shape traffic reached
                 "in_window_compiles":

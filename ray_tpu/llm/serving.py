@@ -18,8 +18,9 @@ import threading
 import time as _time
 from typing import Any, Optional
 
+from ..util.profiling import phase
 from .engine import EngineConfig, InferenceEngine, SamplingParams
-from .paged_engine import PagedEngineConfig, PagedInferenceEngine
+from .paged_engine import PHASES, PagedEngineConfig, PagedInferenceEngine
 
 
 @dataclasses.dataclass
@@ -188,33 +189,51 @@ class LLMServer:
         return eng
 
     def _loop(self):
+        # this thread's time outside step() goes to the base engine's
+        # stats beside step()'s own phases (paged_engine.PHASES), so
+        # that the ten sum to the thread's wall time: rtpu.loop.idle is
+        # the wait for work and nothing else, rtpu.loop.other the rest
+        # (steplock, prefix-directory publish, rewarm). A per-LoRA
+        # engine books its step() to its own stats, so the sum holds
+        # for a server whose only engine is the base one.
+        st = self.engine.stats
+        other, idle = (
+            (key, PHASES[key]) for key in ("ns_loop_other", "ns_loop_idle"))
         try:
             while not self._stop:
                 worked = False
                 for eng in self._engines():
                     if eng.has_work():
-                        with self._steplock:
+                        with phase(st, *other):
+                            self._steplock.acquire()
+                        try:
                             eng.step()
+                        finally:
+                            self._steplock.release()
                         worked = True
-                if self._prefix_dir is not None:
-                    # drain newly published/evicted page hashes to the
-                    # cluster directory (rate-limited inside; this IS
-                    # the stepping thread, per the drain contract)
-                    self._prefix_dir.maybe_publish(self.engine)
-                if getattr(self.engine, "spill", None) is not None:
-                    now = _time.monotonic()
-                    if now - self._last_rewarm >= 0.25:
-                        # proactive promote of the hottest spilled
-                        # chain into idle pool headroom; bounded pages
-                        # per tick so the scatter never stalls a step.
-                        # Under the steplock: the scatter donates the
-                        # cache pools (import_prefix contract).
-                        self._last_rewarm = now
-                        with self._steplock:
-                            self.engine.maybe_rewarm(max_pages=32)
+                with phase(st, *other):
+                    if self._prefix_dir is not None:
+                        # drain newly published/evicted page hashes to
+                        # the cluster directory (rate-limited inside;
+                        # this IS the stepping thread, per the drain
+                        # contract)
+                        self._prefix_dir.maybe_publish(self.engine)
+                    if getattr(self.engine, "spill", None) is not None:
+                        now = _time.monotonic()
+                        if now - self._last_rewarm >= 0.25:
+                            # proactive promote of the hottest spilled
+                            # chain into idle pool headroom; bounded
+                            # pages per tick so the scatter never stalls
+                            # a step. Under the steplock: the scatter
+                            # donates the cache pools (import_prefix
+                            # contract).
+                            self._last_rewarm = now
+                            with self._steplock:
+                                self.engine.maybe_rewarm(max_pages=32)
                 if not worked:
-                    self._wake.wait(timeout=0.05)
-                    self._wake.clear()
+                    with phase(st, *idle):
+                        self._wake.wait(timeout=0.05)
+                        self._wake.clear()
         except BaseException as e:  # noqa: BLE001 — engine died: fail fast
             self._error = e
             # unblock every waiter; completions() re-raises the error, and
@@ -396,7 +415,8 @@ class LLMServer:
 
     def engine_stats(self) -> dict:
         """Counter snapshot for ops introspection: the base engine's
-        stats dict plus the resolved mesh axis sizes (None single-chip).
+        stats dict (the stepping thread's ``ns_*`` phase times among
+        them) plus the resolved mesh axis sizes (None single-chip).
         On a mesh, ``mesh_reshard_bytes`` staying 0 IS the steady-state
         zero-involuntary-reshard invariant — a nonzero value means some
         dispatch committed a buffer off its pinned sharding."""
@@ -404,6 +424,9 @@ class LLMServer:
 
         from ..util.compile_cache import compile_cache_stats
         st = dict(getattr(self.engine, "stats", {}) or {})
+        # the ns_* counters' clock at this snapshot: between two
+        # snapshots their deltas sum to this one's
+        st["clock_ns"] = _time.perf_counter_ns()
         mesh = getattr(self.engine, "mesh", None)
         st["mesh"] = None if mesh is None else {
             k: int(v) for k, v in mesh.shape.items()}

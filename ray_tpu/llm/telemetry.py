@@ -15,7 +15,11 @@ it, replicas of the same engine kind would overwrite each other. When tracing is
 emits one ``llm.request`` span parented to whatever span submitted it
 (the serve replica's task span when the request came through Serve), so
 a proxy -> replica -> engine request renders as one stitched tree in
-``ray_tpu.timeline()``.
+``ray_tpu.timeline()``; under it, with the same ``trace_id`` and
+``request_id``, three children end to end: ``llm.queue`` (submit ->
+admit), ``llm.prefill`` (admit -> first token) and ``llm.decode`` (first
+token -> retire), with ``prompt_tokens``, ``prefix_tokens_saved`` and
+``out_tokens`` as arguments.
 
 Metric names (all prefixed ``rtpu_llm_``):
   ttft_seconds           histogram  submit -> first generated token
@@ -33,6 +37,15 @@ Metric names (all prefixed ``rtpu_llm_``):
   spec_proposed_total    counter    speculative tokens proposed
   spec_accepted_total    counter    speculative tokens accepted
   dispatches_total       counter    device dispatches, by program family
+  decode_live_slots_total counter   slots live, summed over decode
+      dispatches (over dispatches_total{family="decode"} x max_batch_size:
+      the share of the decode program's rows doing useful work)
+  loop_seconds_total     counter    the stepping thread's seconds, by
+      ``phase``: admit, prefill_build / _device / _post, decode_build /
+      _device / _post, telemetry, loop_other, loop_idle (the ``ns_*`` keys
+      of engine.stats, paged_engine.PHASES); they sum to wall time, and
+      all but ``*_device`` and ``loop_idle`` is host time with no dispatch
+      outstanding
   prefix_cache_hits_total      counter  full prompt pages served from cache
   prefix_cache_misses_total    counter  full prompt pages computed by prefill
   prefix_cache_evictions_total counter  cached pages reclaimed under pressure
@@ -271,11 +284,13 @@ _STAT_COUNTERS = (
     ("spec_accepted", "rtpu_llm_spec_accepted_total",
      "speculative draft tokens accepted", None),
     ("prefill_dispatches", "rtpu_llm_dispatches_total",
-     "device dispatches by program family", "prefill"),
+     "device dispatches by program family", ("family", "prefill")),
     ("decode_dispatches", "rtpu_llm_dispatches_total",
-     "device dispatches by program family", "decode"),
+     "device dispatches by program family", ("family", "decode")),
     ("spec_dispatches", "rtpu_llm_dispatches_total",
-     "device dispatches by program family", "verify"),
+     "device dispatches by program family", ("family", "verify")),
+    ("decode_live_slots", "rtpu_llm_decode_live_slots_total",
+     "slots live, summed over decode dispatches", None),
     ("prefix_hits", "rtpu_llm_prefix_cache_hits_total",
      "full prompt pages served from the prefix cache", None),
     ("prefix_misses", "rtpu_llm_prefix_cache_misses_total",
@@ -326,19 +341,30 @@ def _ship_stat_deltas(engine, stats: dict, tags: dict) -> None:
     last = getattr(engine, "_telem_shipped", None)
     if last is None:
         last = engine._telem_shipped = {}
-    for key, name, desc, family in _STAT_COUNTERS:
-        cur = stats.get(key)
-        if cur is None:
-            continue
-        delta = cur - last.get(key, 0)
-        if delta <= 0:
-            continue
-        last[key] = cur
-        if family is None:
-            _counter(name, desc).inc(float(delta), tags=tags)
-        else:
-            _counter(name, desc, tag_keys=("engine", "family")).inc(
-                float(delta), tags={**tags, "family": family})
+    for key, name, desc, label in _STAT_COUNTERS:
+        _ship_delta(stats, last, key, name, desc, label, tags)
+    # the stepping thread's time by phase (paged_engine.PHASES): every
+    # ns_<phase> key, in seconds, under one family
+    for key in stats:
+        if key.startswith("ns_"):
+            _ship_delta(stats, last, key, "rtpu_llm_loop_seconds_total",
+                        "the engine loop thread's seconds by phase",
+                        ("phase", key[3:]), tags, scale=1e-9)
+
+
+def _ship_delta(stats, last, key, name, desc, label, tags, scale=1.0):
+    cur = stats.get(key)
+    if cur is None:
+        return
+    delta = cur - last.get(key, 0)
+    if delta <= 0:
+        return
+    last[key] = cur
+    if label is None:
+        _counter(name, desc).inc(delta * scale, tags=tags)
+    else:
+        _counter(name, desc, tag_keys=("engine", label[0])).inc(
+            delta * scale, tags={**tags, label[0]: label[1]})
 
 
 def _chain_gauge(name, desc):
@@ -454,5 +480,23 @@ def _emit_request_span(req) -> None:
         if getattr(req, "request_id", ""):
             rec["request_id"] = req.request_id
         tracing.record_span(rec)
+        # its three children, end to end, from the stamps the request
+        # carries: seconds from submit at which it was admitted and got
+        # its first token. A stamp never set (retired before admission,
+        # an imported prefill) collapses its span to nothing.
+        total = rec["dur_s"]
+        admit = min(max(req.admit_t - req.submit_t, 0.0), total)
+        first = min(max(req.first_token_t - req.submit_t, admit), total)
+        args = {"prompt_tokens": len(req.prompt_ids),
+                "prefix_tokens_saved": req.prefix_tokens_saved,
+                "out_tokens": len(req.out_ids)}
+        for name, t0, t1 in (("llm.queue", 0.0, admit),
+                             ("llm.prefill", admit, first),
+                             ("llm.decode", first, total)):
+            tracing.record_span({
+                **rec, "span_id": tracing.new_span_id(),
+                "parent_id": rec["span_id"], "name": name,
+                "start_s": req.submit_wall + t0, "dur_s": t1 - t0,
+                "args": args})
     except Exception:
         pass  # span loss must never break retire

@@ -210,6 +210,7 @@ def _flash_fwd(q, k, v, causal: bool, scale: float,
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qt, kt, vt)
     out = jnp.swapaxes(out, 1, 2)
     lse = lse[..., 0]
@@ -399,6 +400,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, causal: bool, scale: float,
         out_shape=[jax.ShapeDtypeStruct(qt.shape, q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(qt, kt, vt, gt, lse4, delta)[0]
 
     # dkv sweep: q innermost. Note the index maps take (bi, hi, ik, iq).
@@ -423,6 +425,7 @@ def _flash_bwd(q, k, v, out, lse, g, *, causal: bool, scale: float,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(qt, kt, vt, gt, lse4, delta)
 
     dq = jnp.swapaxes(dq, 1, 2)                                # [B,Sq,H,D]

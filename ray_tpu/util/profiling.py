@@ -19,9 +19,12 @@ whose methodology ships with the system). Three pieces:
   come from the one device-kind table (parallel.mesh.DEVICE_PEAKS; off
   TPU -> None, MFU then reports None rather than a made-up number; an
   unlisted TPU kind is an error).
-- Optional ``jax.profiler`` capture — :func:`trace` wraps a block in a
-  TensorBoard-loadable trace when a directory is given, and is a no-op
-  otherwise, so call sites can leave the hook in place unconditionally.
+- :class:`phase` — the one primitive the serving loop's host phases are
+  timed with (llm/paged_engine.py ``step()``, llm/serving.py ``_loop``):
+  a ``jax.profiler.TraceAnnotation`` (a span on the host plane of the
+  profiler's trace, on the device operations' clock, when a profiler
+  session is active; a branch when none is) plus the elapsed
+  nanoseconds added to a counter. Always on: no flag, no config field.
 
 Profilers are cheap enough to leave attached (two perf_counter reads and
 two flight events per step); FLOPs estimation triggers an extra XLA
@@ -202,13 +205,47 @@ class StepProfiler:
         }
 
 
-@contextlib.contextmanager
-def trace(log_dir: Optional[str]):
-    """``jax.profiler`` capture around a block when ``log_dir`` is set;
-    a no-op otherwise (leave the hook unconditional at call sites)."""
-    if not log_dir:
-        yield
-        return
-    import jax
-    with jax.profiler.trace(log_dir):
-        yield
+_annotation = None     # jax.profiler.TraceAnnotation, resolved on first use
+
+
+class phase:
+    """``with phase(stats, "ns_admit", "rtpu.engine.admit"):`` — one
+    host phase of a loop, recorded into two sinks at once:
+
+    - a ``jax.profiler.TraceAnnotation(name)``: with a profiler session
+      active the span lands on the host plane of the same xplane as the
+      device operations, on their clock, so an idle gap on the device
+      can be named by the phase the host was in; with none active it
+      costs a branch;
+    - ``stats[key]`` grows by the elapsed ``perf_counter_ns``, and
+      ``stats["max_" + key]`` keeps the longest single occurrence (a
+      stall names its phase; the ``max_`` prefix keeps a sum over every
+      ``ns_*`` key from adding a maximum).
+
+    The clock is read first on entry and last on exit, so consecutive
+    phases leave only the interpreter's own call overhead between them.
+    jax is imported on first use (``import ray_tpu`` stays light)."""
+
+    __slots__ = ("_stats", "_key", "_name", "_ann", "_t0")
+
+    def __init__(self, stats: dict, key: str, name: str):
+        self._stats, self._key, self._name = stats, key, name
+
+    def __enter__(self):
+        global _annotation
+        self._t0 = time.perf_counter_ns()
+        if _annotation is None:
+            import jax
+            _annotation = jax.profiler.TraceAnnotation
+        self._ann = _annotation(self._name)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        stats, key = self._stats, self._key
+        dt = time.perf_counter_ns() - self._t0
+        stats[key] = stats.get(key, 0) + dt
+        if dt > stats.get("max_" + key, 0):
+            stats["max_" + key] = dt
+        return False

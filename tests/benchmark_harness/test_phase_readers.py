@@ -1,0 +1,140 @@
+"""The nine per-layer metrics that read the engine's phase times and
+dispatch counters (PR 24), each on a hand-made ``ctx`` with a known answer,
+and None where the program has no such counter (every earlier commit) or
+the run was not traced."""
+import importlib
+
+import pytest
+
+BEFORE = {"ns_admit": 1_000, "ns_prefill_build": 0, "ns_prefill_device": 0,
+          "ns_decode_device": 5_000, "ns_telemetry": 0, "ns_loop_other": 0,
+          "ns_loop_idle": 7_000, "max_ns_admit": 900,
+          "admitted": 2, "queue_wait_ns": 0, "first_tokens": 1,
+          "prefill_span_ns": 1_000_000, "decode_dispatches": 10,
+          "decode_live_slots": 40, "decode_live_pages": 1_000,
+          "decode_steps": 25, "prefill_rows_live": 3,
+          "prefill_rows_padded": 4, "prefill_tokens": 300,
+          "prefill_ctx_pages": 100, "prefill_attn_pairs": 10_000,
+          "clock_ns": 5, "mesh": None}
+# deltas: admit 4 ms, prefill build 6 ms, prefill device 30 ms, decode
+# device 50 ms, telemetry 2 ms, loop other 8 ms, idle 900 ms;
+# 4 admitted, 12 ms queued, 5 first tokens, 250 ms admit -> first token;
+# 20 decode dispatches with 160 live slots and 38,400 live pages over 100
+# device steps; 30 prefill rows live of 40 run, with 3,600 tokens (120 a
+# row), 3,000 context pages (100 a row) and 6,000,000 pairs (200,000 a row)
+AFTER = {"ns_admit": 4_001_000, "ns_prefill_build": 6_000_000,
+         "ns_prefill_device": 30_000_000, "ns_decode_device": 50_005_000,
+         "ns_telemetry": 2_000_000, "ns_loop_other": 8_000_000,
+         "ns_loop_idle": 900_007_000, "max_ns_admit": 3_000_000,
+         "admitted": 6, "queue_wait_ns": 12_000_000, "first_tokens": 6,
+         "prefill_span_ns": 251_000_000, "decode_dispatches": 30,
+         "decode_live_slots": 200, "decode_live_pages": 39_400,
+         "decode_steps": 125, "prefill_rows_live": 33,
+         "prefill_rows_padded": 44, "prefill_tokens": 3_900,
+         "prefill_ctx_pages": 3_100, "prefill_attn_pairs": 6_010_000,
+         "clock_ns": 1_000_000_005, "mesh": None}
+CONFIG = {"num_hidden_layers": 20, "num_key_value_heads": 8, "head_dim": 128,
+          "hidden_size": 4096, "num_attention_heads": 32,
+          "engine": {"max_batch_size": 32, "page_size": 16}}
+# the decode shape (query window 1) ran 400 calls in 0.8 s: 2 ms a call, 40
+# ms a step of 20 layers; the prefill shape (a window above 1) ran 60 calls
+# in 0.3 s: 5 ms a call; each reader must leave the other's shape out
+TRACE = {"devices": 1, "busy_s": 1.0, "window_s": 1.1, "ops": [
+    ["ragged_paged_attention:bf16[32,1,32,128]", 0.8, 400, "tpu_custom_call"],
+    ["ragged_paged_attention:bf16[1,128,32,128]", 0.3, 60, "tpu_custom_call"],
+    ["fusion:bf16[32,14336]", 0.1, 400, ""]]}
+
+
+def _ctx(**over):
+    ctx = {"stats_before": BEFORE, "stats_after": AFTER, "config": CONFIG,
+           "trace": TRACE, "device": {"kind": "TPU v5 lite"},
+           "rehearse": False}
+    ctx.update(over)
+    return ctx
+
+
+def _read(name: str, ctx: dict):
+    return importlib.import_module(
+        f"benchmarks.layer_metrics.docqa_{name}").read(ctx)
+
+
+# 1920 live pages a dispatch x 16 tokens x 81,920 B = 2.5166 GB, 3.0728 ms at
+# 819 GB/s, over 40 ms a step
+ROOFLINE = 100.0 * (1920 * 16 * 81_920 / 819e9) / 0.040
+
+# a live row and layer: 4 x 32 x 128 x 200,000 pairs = 3.2768 GFLOP = 16.63 us
+# at 197 TFLOP/s; 100 pages x 16 x 4,096 B of keys and values + 120 tokens x
+# 32 x 128 x 2 B read and written = 8.5197 MB = 10.40 us at 819 GB/s: the
+# compute bound, over 5 ms a call x 40 / 30 rows run per live row
+PREFILL_ROOFLINE = 100.0 * (4 * 32 * 128 * 200_000 / 197e12) / (0.005 * 40 / 30)
+
+EXPECTED = {
+    # host: 4 + 6 + 2 + 8 = 20 ms of 100 ms worked; the idle 900 ms and
+    # the max_ns_* keys stay out
+    "engine_host_share": 20.0,
+    "admit_ms_per_request": 1.0,
+    "queue_wait_ms": 3.0,
+    "prefill_span_ms": 50.0,
+    "decode_slot_occupancy": 25.0,      # 8 of 32 slots a dispatch
+    "ragged_decode_roofline": ROOFLINE,
+    "prefill_row_fill": 75.0,           # 30 of 40 rows
+    "decode_step_ms": 0.5,              # 50 ms in 100 device steps
+    "ragged_prefill_roofline": PREFILL_ROOFLINE,
+}
+TRACED = ("ragged_decode_roofline", "ragged_prefill_roofline")
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_reader_gives_the_hand_computed_value(name):
+    assert _read(name, _ctx()) == pytest.approx(EXPECTED[name], rel=1e-9)
+    assert 7.6 < ROOFLINE < 7.8
+    assert 0.24 < PREFILL_ROOFLINE < 0.26
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_reader_gives_none_on_a_program_without_the_counters(name):
+    """The parent commit's ``engine.stats`` has none of these keys: the
+    reader returns None, it does not raise."""
+    old = {"decode_dispatches": 10, "tokens_out": 7, "mesh": None}
+    assert _read(name, _ctx(stats_before=old, stats_after=dict(
+        old, decode_dispatches=30))) is None
+    assert _read(name, _ctx(stats_before=None, stats_after=None)) is None
+
+
+@pytest.mark.parametrize("name", sorted(EXPECTED))
+def test_reader_gives_none_when_nothing_was_counted(name):
+    assert _read(name, _ctx(stats_after=BEFORE)) is None
+
+
+@pytest.mark.parametrize("name", TRACED)
+def test_roofline_needs_the_trace_and_its_own_shape(name):
+    assert _read(name, _ctx(trace=None)) is None
+    assert _read(name, _ctx(trace={"devices": 0})) is None
+    other = [op for op in TRACE["ops"] if (",1,32," in op[0]) ==
+             (name == "ragged_prefill_roofline")]
+    assert _read(name, _ctx(trace=dict(TRACE, ops=other))) is None
+    assert _read(name, _ctx(rehearse=True)) is None
+
+
+def test_the_others_read_counters_alone():
+    for name in sorted(set(EXPECTED) - set(TRACED)):
+        assert _read(name, _ctx(trace=None)) == pytest.approx(EXPECTED[name])
+
+
+def test_prefill_roofline_takes_the_memory_bound_where_it_is_larger():
+    """Rows of a few tokens over a long cache stream more than they
+    compute: 20,000 pairs a row is 1.66 us of FLOPs against 10.40 us of
+    bytes."""
+    after = dict(AFTER, prefill_attn_pairs=BEFORE["prefill_attn_pairs"]
+                 + 30 * 20_000)
+    least = (100 * 16 * 4096 + 2 * 120 * 32 * 128 * 2) / 819e9
+    assert _read("ragged_prefill_roofline", _ctx(stats_after=after)) == \
+        pytest.approx(100.0 * least / (0.005 * 40 / 30), rel=1e-9)
+
+
+def test_twins_share_one_reader():
+    for name in EXPECTED:
+        twin = importlib.import_module(
+            f"benchmarks.layer_metrics.docqa_{name}")
+        base = importlib.import_module(f"benchmarks.layer_metrics.{name}")
+        assert twin.read is base.read

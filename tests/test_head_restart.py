@@ -13,7 +13,9 @@ import time
 import pytest
 
 AUTHKEY = "ab" * 16
-PORT = 18431
+# not 18431: test_serve_frontdoor.py binds it (and 18432), and under xdist
+# the two files run at once and one hangs on the taken port
+PORT = 18433
 
 HEAD_SCRIPT = """
 import json, os, sys, time

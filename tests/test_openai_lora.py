@@ -108,9 +108,10 @@ def test_openai_http_path_routing(ray):
                               num_pages=64, max_pages_per_seq=8,
                               chunk_size=32)
     app = build_openai_app([LLMConfig(model_id="tiny", engine=econf)])
-    serve.run(app, name="oai", http_port=18123)
+    # not 18123: test_serve.py binds it, and under xdist both run at once
+    serve.run(app, name="oai", http_port=18127)
     req = urllib.request.Request(
-        "http://127.0.0.1:18123/oai/v1/completions",
+        "http://127.0.0.1:18127/oai/v1/completions",
         data=json.dumps({"model": "tiny", "prompt": "xy",
                          "max_tokens": 4}).encode(),
         headers={"Content-Type": "application/json"})
@@ -118,7 +119,7 @@ def test_openai_http_path_routing(ray):
         out = json.loads(r.read())
     assert out["object"] == "text_completion"
     with urllib.request.urlopen(
-            "http://127.0.0.1:18123/oai/v1/models", timeout=60) as r:
+            "http://127.0.0.1:18127/oai/v1/models", timeout=60) as r:
         models = json.loads(r.read())
     assert models["data"][0]["id"] == "tiny"
 

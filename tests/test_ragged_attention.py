@@ -257,19 +257,6 @@ def test_engine_page_bucketing_changes_nothing_but_width():
         ids.append(nxt)
     assert a[1]["token_ids"] == want
 
-    # length-aware estimate_flops: costs the EXECUTED program keys
-    # (page bucket included), so short-bucket dispatches are credited
-    # their own FLOPs — and attach targets exactly those tags
-    out = on.estimate_flops()
-    assert out, "no flops estimated"
-    for kind, per_key in out.items():
-        for key, fl in per_key.items():
-            assert fl > 0
-            assert (kind, key) in on.profiler._flops_by_tag
-            if kind == "decode":
-                _w, _mode, W = key
-                assert W in on._page_bucket_ladder()
-
 
 @pytest.mark.slow  # ~20s: ladder warmup compiles prefill+decode x 4 buckets
 def test_bucketed_warmup_covers_every_bucket_program():

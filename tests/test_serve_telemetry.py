@@ -62,6 +62,29 @@ def test_engine_metrics_and_summary(fresh_registry, engine):
     assert 'rtpu_llm_requests_total{engine="paged",finish=' in text
 
 
+def test_loop_phase_seconds_and_live_slots_reach_metrics(fresh_registry,
+                                                         engine):
+    """The two /metrics families of the engine loop: one counter of
+    seconds with a ``phase`` label for every ns_* key, and the live
+    slots summed over decode dispatches."""
+    from ray_tpu.llm.paged_engine import PHASES
+    engine._telem_shipped = None    # ship the whole history once more
+    _drive(engine)
+    text = "\n".join(um.prometheus_lines(um.local_store()))
+    for key in PHASES:
+        line = (f'rtpu_llm_loop_seconds_total{{engine="paged",'
+                f'phase="{key[3:]}"}}')
+        # the loop phases belong to LLMServer: a bare engine has none
+        assert (line in text) == ("loop" not in key), key
+    shipped = float(next(
+        ln for ln in text.splitlines() if ln.startswith(
+            'rtpu_llm_loop_seconds_total{engine="paged",'
+            'phase="decode_device"}')).split()[-1])
+    assert shipped == pytest.approx(
+        engine.stats["ns_decode_device"] * 1e-9, rel=1e-6)
+    assert 'rtpu_llm_decode_live_slots_total{engine="paged"}' in text
+
+
 def test_engine_request_span_parents_to_submitter(fresh_registry, engine):
     from ray_tpu.core import runtime as rt_mod
     from ray_tpu.core.config import cfg
@@ -106,6 +129,62 @@ def test_engine_request_span_parents_to_submitter(fresh_registry, engine):
     assert llm["parent_id"] == replica["span_id"]
     assert llm["request_id"] == "req-abc"
     assert llm["dur_s"] >= 0.0
+
+
+@pytest.mark.parametrize("traced", [True, False])
+def test_engine_request_span_has_its_three_children(fresh_registry, engine,
+                                                    traced):
+    """With tracing on, one request yields llm.request and under it
+    exactly llm.queue, llm.prefill and llm.decode: end to end in time,
+    on the request's trace and request id, with the token counts as
+    arguments. With tracing off the engine emits no span at all."""
+    from ray_tpu.core import runtime as rt_mod
+    from ray_tpu.core.config import cfg
+    from ray_tpu.serve.context import (reset_request_context,
+                                       set_request_context)
+
+    class _StubRT:
+        def __init__(self):
+            self.spans = []
+
+        def record_trace_span(self, rec):
+            self.spans.append(rec)
+
+    stub = _StubRT()
+    prev_rt = rt_mod.get_runtime_if_exists()
+    cfg.override(tracing_enabled=traced)
+    rt_mod.set_runtime(stub)
+    token = set_request_context(request_id="req-kids")
+    try:
+        (req,) = _drive(engine, n_requests=1, max_tokens=5)
+    finally:
+        reset_request_context(token)
+        rt_mod.set_runtime(prev_rt)
+        cfg.reset("tracing_enabled")
+    mine = [s for s in stub.spans if s.get("request_id") == "req-kids"]
+    if not traced:
+        assert req.trace_ctx is None and stub.spans == []
+        return
+    (root,) = [s for s in mine if s["name"] == "llm.request"]
+    kids = [s for s in mine if s.get("parent_id") == root["span_id"]]
+    assert [k["name"] for k in kids] == \
+        ["llm.queue", "llm.prefill", "llm.decode"]
+    assert len(mine) == 4
+    assert {k["trace_id"] for k in kids} == {root["trace_id"]}
+    assert len({k["span_id"] for k in mine}) == 4
+    # contiguous: each starts where the one before ended, and together
+    # they cover the request's span
+    at = root["start_s"]
+    for k in kids:
+        assert k["start_s"] == pytest.approx(at, abs=1e-6)
+        assert k["dur_s"] >= 0.0
+        at = k["start_s"] + k["dur_s"]
+    assert at == pytest.approx(root["start_s"] + root["dur_s"], abs=1e-6)
+    assert kids[1]["dur_s"] > 0.0 and kids[2]["dur_s"] > 0.0
+    for k in kids:
+        assert k["args"] == {"prompt_tokens": len(req.prompt_ids),
+                             "prefix_tokens_saved": req.prefix_tokens_saved,
+                             "out_tokens": 5}
 
 
 def test_proxy_root_span_ignores_ambient_context(fresh_registry):
