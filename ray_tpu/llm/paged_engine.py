@@ -345,7 +345,8 @@ class PagedInferenceEngine(_EngineBase):
                       "mesh_output_bytes": 0, "mesh_reshard_bytes": 0,
                       # work decided at the dispatch, summed over
                       # dispatches: slots and KV pages the decode program
-                      # had live (of max_batch_size it runs), device
+                      # had live (of max_batch_size rows x the table's
+                      # width it runs: decode_table_pages), device
                       # steps (the window w), prefill rows live / run
                       # (the power-of-two bucket), prompt tokens
                       # prefilled, the pages their rows attend and the
@@ -353,7 +354,8 @@ class PagedInferenceEngine(_EngineBase):
                       # read by a per-layer metric of the benchmark
                       # (PERF.md §3)
                       "decode_live_slots": 0, "decode_live_pages": 0,
-                      "decode_steps": 0, "prefill_rows_live": 0,
+                      "decode_table_pages": 0, "decode_steps": 0,
+                      "prefill_rows_live": 0,
                       "prefill_rows_padded": 0, "prefill_tokens": 0,
                       "prefill_ctx_pages": 0, "prefill_attn_pairs": 0,
                       # request stamps, exact: submit -> admit and
@@ -1509,6 +1511,7 @@ class PagedInferenceEngine(_EngineBase):
             st["decode_dispatches"] += 1
             st["decode_live_slots"] += live_slots
             st["decode_live_pages"] += live_pages
+            st["decode_table_pages"] += bt.size
             st["decode_steps"] += w
             self._mesh_account(
                 tokens.nbytes + bt.nbytes + lengths.nbytes + temps.nbytes

@@ -8,38 +8,55 @@ is a variable-length query window `[start, start + q_len)` attending over
 that row's paged prefix PLUS itself, with the window's own K/V already
 scattered into the pages (the engine writes K/V before attention on every
 path, so the kernel never needs a separate in-window concat). HBM traffic
-is proportional to each row's TRUE length, not the pool capacity:
+is proportional to each row's TRUE length (rounded up to a block), not
+the pool capacity:
 
-* grid ``(row, q_tile, kv_pages)`` with the block table scalar-prefetched
-  so the K/V page BlockSpec index maps select each row's physical pages;
+* grid ``(row, q_tile, kv_block)``. One grid step of the page sweep
+  covers a BLOCK of ``N = max(1, 128 // page_size)`` consecutive logical
+  pages — 8 pages = 128 keys at pages of 16 — so the key axis of a step
+  is as wide as a vreg's lanes and the MXU, and the streaming softmax
+  runs once per 128 keys, not once per page (at pages of 16 a step of one
+  page used 16 of 128 lanes and paid a grid step per 16 keys: PERF.md §6,
+  PR 25). ``N`` comes from the pool's page shape; at pages of 128 a block
+  is one page;
+* the pools stay in HBM (``memory_space=ANY``) and the kernel gathers a
+  block's pages itself: the block table is scalar-prefetched, each page
+  is one `make_async_copy` into a double-buffered ``[2, N * page, KVH,
+  D]`` VMEM scratch, and a live step starts the NEXT block's copies
+  before it waits for its own. (N pipelined BlockSpec operands per pool
+  were measured first: their bookkeeping costs 1.3 us on every grid
+  step, live or dead, against 0.07 us for a dead step here.) Every copy
+  started is waited for inside the same sweep;
 * the query window is TILED over the middle grid axis (`_q_tile`): the
   q/out blocks and the f32 accumulators are per query row, so a tile is
-  an independent page sweep, and what one grid step keeps in VMEM is
-  bounded by ``tile * heads * head_dim`` instead of by the engine's
-  chunk_size (a whole 128-token window of 32 heads x 128 is refused by
-  the v5e compiler: over the 16 MiB scoped-VMEM limit);
-* the index map CLAMPS the logical page to the tile's last live page, so
-  grid steps at/beyond the live page count re-request the block already
-  resident and the pipeline elides the fetch — pages a row doesn't own,
-  and pages wholly in a tile's causal future, are neither read nor
-  computed (`pl.when` skips the body);
+  an independent sweep, and what one grid step keeps in VMEM is bounded
+  by ``tile * heads * head_dim`` instead of by the engine's chunk_size;
+* a page's logical index is CLAMPED to the tile's last live page
+  (`_tile_pages`, the one source for the fetch clamp and the compute
+  skip): pages a row doesn't own — the table's stale tail, the sink
+  page — and pages wholly in a tile's causal future are neither read
+  nor computed (`pl.when` skips a dead block's body: nothing is copied
+  and a dead step costs its launch alone). In a partly live block the
+  clamp repeats the last live page, at key positions the mask hides;
 * a streaming-softmax accumulator in VMEM scratch carries across the
-  page sweep (TPU grids iterate the last dimension fastest, so scratch
+  sweep (TPU grids iterate the last dimension fastest, so scratch
   persists across one tile's sweep — same contract as
   `ops/paged_attention._paged_decode_kernel`);
-* causal masking INSIDE the query window: key position ``k_pos`` is
-  attended by query position ``q_pos = start + i`` iff ``k_pos <= q_pos``
-  — which covers the prefix (always attended) and the window (causal)
-  with one predicate;
-* GQA by the static per-kv-head loop proven in the decode kernel: each
-  group of ``groups`` query heads runs a [Q*G, page] MXU tile against its
-  kv head's [page, D] block — no jnp.repeat materialization anywhere.
+* causal masking INSIDE the query window: key position ``k_pos`` (from
+  the LOGICAL page index) is attended by query position ``q_pos = start
+  + i`` iff ``k_pos <= q_pos`` and ``k_pos < kv_len`` — which covers the
+  prefix (always attended), the window (causal), a partly live block and
+  its clamped duplicates with one predicate;
+* GQA by a static per-kv-head loop: each group of ``groups`` query heads
+  runs a [Q*G, N*page] MXU tile against its kv head's [N*page, D] block,
+  and the softmax state is kept per kv head (``[KVH, Q*G, ...]``), so
+  only one head's scores are live at a time — no jnp.repeat
+  materialization anywhere.
 
 Row layout convention (everything else follows from it): with Q the
-query TILE, the flattened score/accumulator row index is
-``h_kv * (Q * G) + q * G + g`` — per-kv-head blocks, query-major within
-a block — because per-kv-head q slices ``q[:, h*G:(h+1)*G, :]`` reshape
-contiguously to [Q*G, D].
+query TILE, a kv head's score/accumulator row index is ``q * G + g`` —
+query-major — because per-kv-head q slices ``q[:, h*G:(h+1)*G, :]``
+reshape contiguously to [Q*G, D].
 
 The pure-jnp oracle (`ragged_paged_reference`) uses the same
 grouped-einsum GQA form and is the CPU fallback's numerical contract;
@@ -59,18 +76,20 @@ from .paged_attention import NEG_INF
 
 
 def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
-                   q_ref, k_ref, v_ref,               # blocks
-                   o_ref,                             # output
-                   acc_ref, m_ref, l_ref,             # VMEM scratch
+                   q_ref, k_hbm, v_hbm,               # q block; pools in HBM
+                   o_ref,                             # output block
+                   k_buf, v_buf, sems,                # block buffers, DMA
+                   acc_ref, m_ref, l_ref,             # softmax state
                    *, scale: float, page_size: int, num_kv_heads: int,
-                   groups: int, q_tile: int, max_pages: int):
+                   groups: int, q_tile: int, block_pages: int):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     r = pl.program_id(0)
     t = pl.program_id(1)
-    p = pl.program_id(2)
+    b = pl.program_id(2)
 
-    @pl.when(p == 0)
+    @pl.when(b == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -81,67 +100,94 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
     kv_len = start + q_len                 # positions < kv_len are live
     tile_start = start + t * q_tile        # position of the tile's query 0
     n_pages = _tile_pages(start, q_len, t, q_tile, page_size)
+    # live blocks of this sweep; never more than the grid has steps, so
+    # no copy is started that no step waits for
+    n_blocks = jnp.minimum((n_pages + block_pages - 1) // block_pages,
+                           pl.num_programs(2))
+    last_page = jnp.maximum(jnp.minimum(n_pages, bt_ref.shape[1]) - 1, 0)
+    block_keys = block_pages * page_size
+    qg = q_tile * groups
 
-    @pl.when(p < n_pages)
+    def _copies(block, slot):
+        """The 2 x block_pages page copies that fill buffer ``slot`` with
+        logical pages [block * block_pages, ...) of row r, clamped to
+        the tile's last live page: a page the row does not own is never
+        read (the table's tail is stale, or the poisoned sink), and the
+        duplicates in a partly live block sit at key positions the
+        predicate below masks."""
+        out = []
+        for j in range(block_pages):
+            phys = bt_ref[r, jnp.minimum(block * block_pages + j, last_page)]
+            rows = pl.ds(j * page_size, page_size)
+            out.append(pltpu.make_async_copy(
+                k_hbm.at[phys], k_buf.at[slot, rows], sems.at[0, slot]))
+            out.append(pltpu.make_async_copy(
+                v_hbm.at[phys], v_buf.at[slot, rows], sems.at[1, slot]))
+        return out
+
+    @pl.when(b < n_blocks)
     def _compute():
-        qg = q_tile * groups
+        slot = b % 2
+
+        @pl.when(b == 0)
+        def _first():
+            for c in _copies(0, 0):
+                c.start()
+
+        @pl.when(b + 1 < n_blocks)
+        def _prefetch():
+            for c in _copies(b + 1, 1 - slot):
+                c.start()
+
+        for c in _copies(b, slot):
+            c.wait()
         q = q_ref[...]                                    # [Q, H, D]
-        rows = []
+        # key positions come from the LOGICAL page index: one predicate
+        # covers the prefix (k_pos < start <= q_pos), the causal window
+        # and a partly live block; k_pos < kv_len hides stale K/V of the
+        # tail page from PAD queries whose q_pos exceeds the row
+        q_pos = tile_start + jax.lax.broadcasted_iota(
+            jnp.int32, (qg, block_keys), 0) // groups
+        k_pos = b * block_keys + jax.lax.broadcasted_iota(
+            jnp.int32, (qg, block_keys), 1)
+        keep = (k_pos <= q_pos) & (k_pos < kv_len)
+        # per kv head: [Q*G, block_keys] scores live at a time, not
+        # [KVH*Q*G, block_keys] (2 x 1 MiB of f32 at the prefill tile)
         for h in range(num_kv_heads):
             q_sub = q[:, h * groups:(h + 1) * groups, :].reshape(qg, -1)
-            k_sub = k_ref[:, h, :]                        # [page, D]
-            rows.append(jax.lax.dot_general(
-                q_sub, k_sub, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale)
-        s = jnp.concatenate(rows, axis=0)                 # [KVH*Q*G, page]
-        n_rows = num_kv_heads * qg
-        # row index -> query index (row layout: h*(Q*G) + q*G + g)
-        q_idx = (jax.lax.broadcasted_iota(
-            jnp.int32, (n_rows, page_size), 0) // groups) % q_tile
-        q_pos = tile_start + q_idx
-        k_pos = p * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (n_rows, page_size), 1)
-        # one predicate covers prefix (k_pos < start <= q_pos) and the
-        # causal window; k_pos < kv_len additionally hides stale K/V in
-        # the tail page for PAD queries whose q_pos exceeds the row
-        keep = (k_pos <= q_pos) & (k_pos < kv_len)
-        s = jnp.where(keep, s, NEG_INF)
-        m_prev, l_prev = m_ref[:], l_ref[:]
-        m_cur = jnp.max(s, axis=-1)[:, None]
-        m_new = jnp.maximum(m_prev, m_cur)
-        pexp = jnp.exp(s - m_new)
-        pexp = jnp.where(keep, pexp, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = l_prev * alpha + jnp.sum(pexp, axis=-1)[:, None]
-        m_ref[:] = m_new
-        pvs = []
-        for h in range(num_kv_heads):
-            p_sub = pexp[h * qg:(h + 1) * qg, :]          # [Q*G, page]
-            v_sub = v_ref[:, h, :]                        # [page, D]
-            pvs.append(jax.lax.dot_general(
-                p_sub.astype(v_sub.dtype), v_sub, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))      # [Q*G, D]
-        pv = jnp.concatenate(pvs, axis=0)                 # [KVH*Q*G, D]
-        acc_ref[:] = acc_ref[:] * alpha + pv
+            s = jax.lax.dot_general(
+                q_sub, k_buf[slot, :, h, :], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev, l_prev = m_ref[h], l_ref[h]           # [Q*G, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
+            pexp = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_prev * alpha + jnp.sum(pexp, axis=-1)[:, None]
+            m_ref[h] = m_new
+            v_blk = v_buf[slot, :, h, :]                  # [block_keys, D]
+            pv = jax.lax.dot_general(
+                pexp.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [Q*G, D]
+            acc_ref[h] = acc_ref[h] * alpha + pv
 
-    @pl.when(p == max_pages - 1)
+    @pl.when(b == pl.num_programs(2) - 1)
     def _finalize():
-        qg = q_tile * groups
-        l = jnp.maximum(l_ref[:], 1e-30)                  # noqa: E741
-        o = acc_ref[:] / l                                # [KVH*Q*G, D]
         for h in range(num_kv_heads):
-            blk = o[h * qg:(h + 1) * qg, :].reshape(
-                q_tile, groups, -1)
-            o_ref[:, h * groups:(h + 1) * groups, :] = blk.astype(
-                o_ref.dtype)
+            o = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)  # [Q*G, D]
+            o_ref[:, h * groups:(h + 1) * groups, :] = o.reshape(
+                q_tile, groups, -1).astype(o_ref.dtype)
 
 
 # Most query elements (tile * heads * head_dim) one grid step may hold:
 # the q and out blocks (double-buffered) and the f32 accumulators all
-# scale with it. 64 x 32 x 128 is the widest tile the v5e compiler
-# accepts inside its 16 MiB scoped-VMEM limit at pages of 16, 64 and 128
-# (tests/test_chip_compile.py holds that); the next power of two is
-# refused.
+# scale with it. A compile-fit constant, not a tuned one: at 64 x 32 x
+# 128 the v5e compiler accepts every shape tests/test_chip_compile.py
+# holds (pages of 16 and 128, tables of 4 to 256 pages, 32/8 and the
+# tp=4 shard's 8/2 heads) inside its 16 MiB scoped-VMEM limit. Since the
+# softmax runs per kv head (PR 25) it also accepts 128 and 256 rows at
+# these widths (sandbox compiles, PR 25); widening is left to a PR that
+# measures it.
 _Q_TILE_ELEMS = 64 * 32 * 128
 
 
@@ -157,11 +203,19 @@ def _tile_pages(start, q_len, t, q_tile: int, page_size: int):
     """Live KV pages of query tile ``t`` of one row: those holding keys
     below min(kv_len, end of the tile) — later keys are in the causal
     future of every query of the tile — and none for a tile wholly past
-    q_len (padding). Shared by the kernel body and the K/V index map so
-    the fetch clamp and the compute skip can never disagree."""
+    q_len (padding). The one source for the page copies' clamp and for
+    the compute skip (in blocks: ceil(pages / block_pages)), so the two
+    can never disagree."""
     live = jnp.minimum(start + q_len, start + (t + 1) * q_tile)
     return jnp.where(t * q_tile < q_len,
                      (live + page_size - 1) // page_size, 0)
+
+
+def _block_pages(page_size: int) -> int:
+    """Logical pages one grid step of the sweep covers: as many as make
+    its key axis 128 wide (a vreg's lanes, the MXU's width), one where a
+    page already is. Derived from the pool's page shape alone."""
+    return max(1, 128 // page_size)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
@@ -179,48 +233,54 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
     discards (their compute is bounded by the row's live pages). Returns
     [R, Q, H, D].
     """
+    _, qw, h, d = q.shape
+    return _ragged_call(
+        q, k_pages, v_pages, block_tables, starts, q_lens,
+        scale=float(d ** -0.5 if scale is None else scale),
+        q_tile=_q_tile(qw, h, d), interpret=interpret)
+
+
+# Jitted so that a program traces and lowers the kernel body once per
+# shape, not once per layer: models/llama.py unrolls its layers in
+# Python, and 20 lowerings of this body are 3.7-5.6 s of every program's
+# set-up where one shared function is 0.1-0.2 s (sandbox, PR 25).
+@functools.partial(jax.jit, static_argnames=("scale", "q_tile", "interpret"))
+def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
+                 scale: float, q_tile: int, interpret: bool):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     r, qw, h, d = q.shape
     _, page_size, kvh, _ = k_pages.shape
     groups = h // kvh
-    max_pages = block_tables.shape[1]
-    if scale is None:
-        scale = d ** -0.5
-    tq = _q_tile(qw, h, d)
-    n_tiles = -(-qw // tq)
-    if n_tiles * tq != qw:
+    nb = _block_pages(page_size)
+    n_tiles = -(-qw // q_tile)
+    padded = n_tiles * q_tile
+    if padded != qw:
         # pad queries sit past q_len: garbage by contract, sliced off below
-        q = jnp.pad(q, ((0, 0), (0, n_tiles * tq - qw), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, padded - qw), (0, 0), (0, 0)))
 
     kernel = functools.partial(
         _ragged_kernel, scale=scale, page_size=page_size,
-        num_kv_heads=kvh, groups=groups, q_tile=tq, max_pages=max_pages)
+        num_kv_heads=kvh, groups=groups, q_tile=q_tile, block_pages=nb)
 
-    def _q_index(ri, t, p, bt, start, qlen):
+    def _q_index(ri, t, b, bt, start, qlen):
         return (ri, t, 0, 0)
 
-    def _kv_index(ri, t, p, bt, start, qlen):
-        # clamp to the tile's last live page: grid steps beyond the live
-        # count re-request the resident block (fetch elided), so HBM
-        # traffic tracks true length even when the table tail is stale
-        n = _tile_pages(start[ri], qlen[ri], t, tq, page_size)
-        return (bt[ri, jnp.minimum(p, jnp.maximum(n - 1, 0))], 0, 0, 0)
-
+    pool_in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    block_buf = pltpu.VMEM((2, nb * page_size, kvh, d), k_pages.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(r, n_tiles, max_pages),
-        in_specs=[
-            pl.BlockSpec((None, tq, h, d), _q_index),
-            pl.BlockSpec((None, page_size, kvh, d), _kv_index),
-            pl.BlockSpec((None, page_size, kvh, d), _kv_index),
-        ],
-        out_specs=pl.BlockSpec((None, tq, h, d), _q_index),
+        grid=(r, n_tiles, -(-block_tables.shape[1] // nb)),
+        in_specs=[pl.BlockSpec((None, q_tile, h, d), _q_index),
+                  pool_in_hbm, pool_in_hbm],
+        out_specs=pl.BlockSpec((None, q_tile, h, d), _q_index),
         scratch_shapes=[
-            pltpu.VMEM((kvh * tq * groups, d), jnp.float32),
-            pltpu.VMEM((kvh * tq * groups, 1), jnp.float32),
-            pltpu.VMEM((kvh * tq * groups, 1), jnp.float32),
+            block_buf, block_buf,                   # K, V: two slots each
+            pltpu.SemaphoreType.DMA((2, 2)),        # [K | V, slot]
+            pltpu.VMEM((kvh, q_tile * groups, d), jnp.float32),
+            pltpu.VMEM((kvh, q_tile * groups, 1), jnp.float32),
+            pltpu.VMEM((kvh, q_tile * groups, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -230,7 +290,7 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
         interpret=interpret,
         name="ragged_paged_attention",
     )(block_tables, starts, q_lens, q, k_pages, v_pages)
-    return out[:, :qw] if n_tiles * tq != qw else out
+    return out[:, :qw] if padded != qw else out
 
 
 def ragged_decode_attention(q, k_pages, v_pages, block_table, lengths,

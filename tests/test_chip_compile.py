@@ -9,6 +9,7 @@ A compile that passes is not a chip run: nothing here measures anything.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,9 +56,9 @@ def _compile(fn, *shapes, sharding):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _paged_shapes(rows, q_window, page, max_pages, pool=256):
-    pages = ((pool, page, KVH, D), BF16)
-    return [((rows, q_window, H, D), BF16), pages, pages,
+def _paged_shapes(rows, q_window, page, max_pages, pool=256, h=H, kvh=KVH):
+    pages = ((pool, page, kvh, D), BF16)
+    return [((rows, q_window, h, D), BF16), pages, pages,
             ((rows, max_pages), jnp.int32), ((rows,), jnp.int32),
             ((rows,), jnp.int32)]
 
@@ -86,6 +87,32 @@ def test_ragged_decode_compiles_at_8b_widths(v5e):
     text = _compile(ragged_decode_attention, *shapes[:5],
                     sharding=[one] * 5).as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("h,kvh", [(H, KVH), (H // 4, KVH // 4)],
+                         ids=["1chip", "tp4_shard"])
+@pytest.mark.parametrize("rows,q_window,max_pages", [
+    (32, 1, 256),          # decode: max_batch_size rows, the doc-QA bucket
+    (1, 128, 256),         # prefill: one chunk_size row, two 64-query tiles
+    (32, 1, 4),            # the bucket floor: a table under one 8-page block
+], ids=["decode", "prefill", "decode_floor"])
+def test_ragged_compiles_at_the_benchmark_cells_shapes(v5e, rows, q_window,
+                                                       max_pages, h, kvh):
+    """The shapes `docqa-sessions-1chip` and the pending chat cells run
+    (BENCHMARK.json; page 16, so a grid step gathers 8 pages = 128 keys),
+    whole and as one tp=4 shard sees them (8 Q / 2 KV heads)."""
+    one = SingleDeviceSharding(v5e.devices[0])
+    text = _compile(
+        ragged_paged_attention,
+        *_paged_shapes(rows, q_window, 16, max_pages, pool=3328, h=h,
+                       kvh=kvh),
+        sharding=[one] * 6).as_text()
+    # the custom call's name and result shape are what the benchmark's
+    # trace reduction tells the kernel and its two shapes by, and they
+    # survive the kernel entry's inner jit
+    assert re.search(rf"%ragged_paged_attention[.\d]* = "
+                     rf"bf16\[{rows},{q_window},{h},{D}\].*tpu_custom_call",
+                     text)
 
 
 def test_flash_fwd_bwd_compile_at_8b_widths(v5e):

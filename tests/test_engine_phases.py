@@ -120,6 +120,9 @@ def test_dispatch_and_request_counters(engine):
     bs = engine.cfg.max_batch_size
     assert 0 < d["decode_live_slots"] <= d["decode_dispatches"] * bs
     assert d["decode_live_pages"] >= d["decode_live_slots"]
+    # the table the program sweeps: every row, live or not, x its width
+    assert d["decode_table_pages"] >= d["decode_live_pages"]
+    assert d["decode_table_pages"] % bs == 0
     assert d["decode_steps"] >= d["decode_dispatches"] > 0
     assert 0 < d["prefill_rows_live"] <= d["prefill_rows_padded"]
     assert d["prefill_rows_padded"] <= \
@@ -144,6 +147,9 @@ def test_decode_live_pages_by_hand():
     assert st["decode_dispatches"] == 3 == st["decode_steps"]
     assert st["decode_live_slots"] == 6
     assert st["decode_live_pages"] == 5 + 6 + 7
+    # 4 rows x the whole 16-wide table (no bucketing under 48 pages),
+    # three times
+    assert st["decode_table_pages"] == 3 * 4 * 16
     assert st["prefill_tokens"] == 15 + 24
     # rows of 16 tokens: [0,15) | [0,16) [16,24): 2, 2 and 3 pages
     assert st["prefill_rows_live"] == 3

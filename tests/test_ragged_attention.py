@@ -110,6 +110,102 @@ def test_kernel_skips_pages_beyond_live_count():
     assert np.abs(np.asarray(got)).max() < 1e3   # poison never attended
 
 
+def _poisoned_case(rng, rows, q_window, h, kvh, d, page, maxp, starts,
+                   q_lens, P=40):
+    """Random q and pools, a random table whose entries at/beyond each
+    row's live page count point at poisoned page 0 (what the engine's
+    zeroed table tail aliases)."""
+    q = jnp.asarray(rng.randn(rows, q_window, h, d), jnp.float32)
+    kp, vp = _pools(rng, P, page, kvh, d)
+    kp, vp = kp.at[0].set(1e9), vp.at[0].set(1e9)
+    bt = rng.randint(1, P, (rows, maxp)).astype(np.int32)
+    for r in range(rows):
+        bt[r, -(-(starts[r] + q_lens[r]) // page):] = 0
+    return (q, kp, vp, jnp.asarray(bt), jnp.asarray(starts, jnp.int32),
+            jnp.asarray(q_lens, jnp.int32))
+
+
+# What the 128-key block sweep adds (N = max(1, 128 // page) logical
+# pages a grid step). Each case: page, max_pages, starts, q_lens, with a
+# query window of 8 over 2 KV heads x 2 groups (the tp=4 shard's KV head
+# count).
+_BLOCK_CASES = {
+    # N = 8: a row of 19 live pages (its third block holds 3), one that
+    # ends on a block's edge (128 keys), a q_len = 0 row BETWEEN live
+    # rows, and two shorter than one block
+    "page16_rows_end_inside_a_block": (
+        16, 24, [291, 120, 0, 0, 37], [8, 8, 0, 5, 3]),
+    # max_pages = 11 is not a multiple of N = 8: the second block's last
+    # five operands clamp inside the table
+    "page16_table_not_a_multiple_of_the_block": (
+        16, 11, [160, 0, 100], [8, 0, 7]),
+    # the bucket floor: a 4-wide table under one 8-page block
+    "page16_table_narrower_than_one_block": (
+        16, 4, [50, 0, 3], [8, 0, 4]),
+    # N = 16, and a table of 20 pages = 1.25 blocks
+    "page8_sixteen_pages_a_block": (
+        8, 20, [130, 0, 0, 99], [8, 6, 0, 2]),
+    # N = 1: today's program, one page a step
+    "page128_one_page_a_block": (
+        128, 3, [250, 0, 128], [8, 8, 1]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_CASES))
+def test_block_sweep_matches_oracle(case):
+    page, maxp, starts, q_lens = _BLOCK_CASES[case]
+    args = _poisoned_case(np.random.RandomState(11), len(starts), 8, 4, 2,
+                          32, page, maxp, starts, q_lens)
+    ref = ragged_paged_reference(*args)
+    got = ragged_paged_attention(*args, interpret=True)
+    _assert_rows_close(got, ref, q_lens)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got)).max() < 1e3   # poison never attended
+
+
+@pytest.mark.parametrize("page,maxp", [(16, 24), (16, 11), (16, 4),
+                                       (8, 20), (128, 2)])
+def test_block_sweep_decode_matches_oracle(page, maxp):
+    """Decode (query window 1) over the same table shapes: lengths that
+    end inside a block, on a block's edge, inside the first block, and
+    padding rows (length 0) between live ones."""
+    cap = maxp * page
+    lengths = np.asarray([cap, 0, min(cap, 128), 0, 1, cap - page - 3, 17])
+    rng = np.random.RandomState(12)
+    q, kp, vp, bt, _, _ = _poisoned_case(
+        rng, len(lengths), 1, 8, 2, 32, page, maxp,
+        np.zeros_like(lengths), lengths)
+    from ray_tpu.ops.paged_attention import paged_decode_reference
+    lengths = jnp.asarray(lengths, jnp.int32)
+    ref = paged_decode_reference(q[:, 0], kp, vp, bt, lengths)
+    got = ragged_decode_attention(q[:, 0], kp, vp, bt, lengths,
+                                  interpret=True)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(ref)[live],
+                               atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got)).max() < 1e3
+
+
+@pytest.mark.parametrize("page", [8, 16])
+def test_block_sweep_two_tile_window(monkeypatch, page):
+    """A 16-query window swept as two tiles of 8 over blocks of 128 keys:
+    the first tile's causal end falls inside a block the second tile
+    needs whole, one row's queries end mid-tile, one row is padding."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    h, kvh, d = 4, 2, 32
+    monkeypatch.setattr(rpa, "_Q_TILE_ELEMS", 8 * h * d)
+    assert rpa._q_tile(16, h, d) == 8
+    starts, q_lens = [250, 0, 117, 0], [16, 0, 11, 16]
+    args = _poisoned_case(np.random.RandomState(13), 4, 16, h, kvh, d,
+                          page, 272 // page, starts, q_lens)
+    ref = ragged_paged_reference(*args)
+    got = ragged_paged_attention(*args, interpret=True)
+    _assert_rows_close(got, ref, q_lens)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got)).max() < 1e3
+
+
 def test_decode_is_qlen1_of_ragged_kernel():
     """Decode equivalence: the ragged kernel at q_len=1 must match BOTH
     the original specialized decode kernel and the jnp decode oracle on
