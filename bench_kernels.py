@@ -78,7 +78,7 @@ def _family_benches(quick: bool, on_tpu: bool):
 
             @jax.jit
             def fn(c):
-                lg, _ = llama.prefill_paged_chunk(
+                lg, _, _ = llama.prefill_paged_chunk(
                     params, chunk, c, bt[0], start, cfg, page_size=page)
                 return lg
         elif family == "verify":
@@ -87,7 +87,7 @@ def _family_benches(quick: bool, on_tpu: bool):
 
             @jax.jit
             def fn(c):
-                lg, _ = llama.verify_paged_rows(
+                lg, _, _ = llama.verify_paged_rows(
                     params, toks, c, bt, starts, cfg, page_size=page)
                 return lg
         else:                            # decode
@@ -96,7 +96,7 @@ def _family_benches(quick: bool, on_tpu: bool):
 
             @jax.jit
             def fn(c):
-                lg, _ = llama.decode_paged(
+                lg, _, _ = llama.decode_paged(
                     params, toks, c, bt, lens, cfg, page_size=page)
                 return lg
         np.asarray(fn(caches))           # compile outside the timed region

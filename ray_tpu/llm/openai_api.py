@@ -118,5 +118,12 @@ def build_openai_app(configs: list[LLMConfig], params_refs=None):
     params_refs = params_refs or [None] * len(configs)
     children = [build_llm_deployment(cfg, ref)
                 for cfg, ref in zip(configs, params_refs)]
-    router = serve.deployment(OpenAIRouter, name="openai-router")
+    # the router holds a request for as long as the model deployment it
+    # forwards to does, and the front door's admission budget is the
+    # INGRESS deployment's max_ongoing_requests: admit what the children
+    # together admit (at the default of 16, 64 concurrent streams for a
+    # 64-row engine were queued for 2 s and shed with 429)
+    router = serve.deployment(
+        OpenAIRouter, name="openai-router",
+        max_ongoing_requests=sum(c.max_ongoing_requests for c in configs))
     return router.bind([c.model_id for c in configs], *children)

@@ -291,6 +291,10 @@ class PagedInferenceEngine(_EngineBase):
         self._rng_base = jax.random.PRNGKey(rng_seed ^ 0x5EED)
         self._rng_ctr = 0
         self._lock = threading.Lock()
+        # notified when a dispatch has been launched (_notify_launch):
+        # what a stream of tokens sleeps on (llm/serving.py)
+        self.launched = threading.Condition()
+        self.launch_gen = 0
         self._interpret = interpret
         # block-table width bucketing (cfg.page_buckets): "auto" engages
         # only when the table is long enough that gathering max_pages on
@@ -369,6 +373,12 @@ class PagedInferenceEngine(_EngineBase):
         # sum is the thread's wall time.
         for key in PHASES:
             self.stats[key] = self.stats["max_" + key] = 0
+        # an MoE config's expert layer computes every row and token a
+        # program runs, live or not (_moe_account); a dense config has no
+        # such keys
+        if mc.moe_experts:
+            self.stats.update(moe_assign_live=0, moe_assign_run=0,
+                              moe_expert_load_sum=0, moe_expert_load_max=0)
         # speculation controller: EMA of tokens-per-slot-per-spec-dispatch
         # (starts optimistic), plus a cooldown of windowed dispatches
         # before re-probing once the EMA drops below the window
@@ -463,7 +473,8 @@ class PagedInferenceEngine(_EngineBase):
         mesh: every in/out sharding pinned — params/caches/lora at their
         committed placements, the n_plain host-array args (token ids,
         block tables, lengths, rng, temps) replicated, outputs (sampled
-        tokens, logprobs) replicated and the cache outputs bit-matching
+        tokens, logprobs, an MoE config's per-expert load) replicated and
+        the cache outputs bit-matching
         their inputs so donation aliases. Pinning is what guarantees the
         compiled program never inserts an involuntary reshard of a
         committed buffer: any transfer beyond the declared host arrays
@@ -474,7 +485,7 @@ class PagedInferenceEngine(_EngineBase):
         sh = self._shardings
         ins = (sh["params"], sh["caches"]) + (sh["repl"],) * n_plain + (
             sh["lora"], sh["repl"])
-        outs = (sh["repl"], sh["repl"], sh["caches"])
+        outs = (sh["repl"], sh["repl"], sh["repl"], sh["caches"])
         return jax.jit(run, donate_argnums=(1,), in_shardings=ins,
                        out_shardings=outs)
 
@@ -557,7 +568,9 @@ class PagedInferenceEngine(_EngineBase):
     def _decode_window_fn(self, w: int, mode: tuple, pages: int):
         """One dispatch = w decode steps for every slot: lax.scan unrolls
         decode+sample, feeding each step's sampled tokens straight back in
-        on-device. Only the [B, w] token block crosses back to the host.
+        on-device. Only the [B, w] token block crosses back to the host
+        (with an MoE config, also the [E] per-expert assignment counts of
+        the dispatch, summed over layers and steps: _moe_account).
         ``pages`` is the block-table width this program was built for
         (_page_bucket): part of the static key, like w and the mode."""
         fn = self._decode_win_fns.get((w, mode, pages))
@@ -570,7 +583,7 @@ class PagedInferenceEngine(_EngineBase):
                     lora=None, slots=None):
                 def body(carry, i):
                     toks, lens, caches = carry
-                    logits, caches = llama.decode_paged(
+                    logits, caches, load = llama.decode_paged(
                         p, toks[:, None], caches, bt, lens, mc,
                         page_size=page, interpret=interpret,
                         lora=lora, slots=slots)
@@ -581,14 +594,13 @@ class PagedInferenceEngine(_EngineBase):
                         any_sampled=any_sampled, any_topk=any_topk,
                         want_logp=want_logp)
                     return (nxt, lens + 1, caches), (
-                        (nxt, lp) if want_logp else nxt)
+                        nxt, lp if want_logp else None, load)
 
-                (_, _, c), ys = jax.lax.scan(
+                (_, _, c), (out, lps, load) = jax.lax.scan(
                     body, (tok0, ln0, c), jnp.arange(w))
-                if want_logp:
-                    out, lps = ys
-                    return out.T, lps.T, c          # [B, w] each
-                return ys.T, None, c
+                # [B, w] tokens (and logprobs); the steps' loads summed
+                return (out.T, None if lps is None else lps.T,
+                        None if load is None else load.sum(0), c)
 
             fn = self._family_jit(run, 7, f"rtpu_decode_w{w}")
             self._decode_win_fns[(w, mode, pages)] = fn
@@ -607,14 +619,14 @@ class PagedInferenceEngine(_EngineBase):
 
             def run(p, c, chunks, bts, sps, tls, key, ctr, temps, top_ks,
                     lora=None, slots=None):
-                last, c = llama.prefill_paged_rows(
+                last, c, load = llama.prefill_paged_rows(
                     p, chunks, c, bts, sps, tls, mc, page_size=page,
                     interpret=interpret, lora=lora, slots=slots)
                 toks, lps = sample_logits_batch(
                     last, jax.random.fold_in(key, ctr), temps, top_ks,
                     any_sampled=any_sampled, any_topk=any_topk,
                     want_logp=want_logp)
-                return toks, lps, c
+                return toks, lps, load, c
 
             fn = self._family_jit(run, 8, f"rtpu_prefill_r{r}")
             self._prefill_rows_fns[(r, mode, pages)] = fn
@@ -633,16 +645,16 @@ class PagedInferenceEngine(_EngineBase):
             interpret = self._interpret
 
             def run(p, c, toks, bts, starts, lora=None, slots=None):
-                logits, c = llama.verify_paged_rows(
+                logits, c, load = llama.verify_paged_rows(
                     p, toks, c, bts, starts, mc, page_size=page,
                     interpret=interpret, lora=lora, slots=slots)
                 y = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 if not want_logp:
-                    return y, None, c
+                    return y, None, load, c
                 lp = jnp.take_along_axis(
                     jax.nn.log_softmax(logits, axis=-1), y[..., None],
                     axis=-1)[..., 0]
-                return y, lp, c
+                return y, lp, load, c
 
             fn = self._family_jit(run, 3, f"rtpu_verify_r{r}")
             self._verify_fns[(r, s1, pages, want_logp)] = fn
@@ -730,7 +742,7 @@ class PagedInferenceEngine(_EngineBase):
                 while True:
                     rb = min(rb, cfg.prefill_rows)
                     tw = _time.perf_counter()
-                    toks, _lps, self.caches = self._prefill_rows_fn(
+                    toks, _lps, _load, self.caches = self._prefill_rows_fn(
                         rb, mode, maxp)(
                         self.params, self.caches,
                         np.zeros((rb, c), np.int32),
@@ -751,7 +763,7 @@ class PagedInferenceEngine(_EngineBase):
             for maxp in (buckets if "decode" in families else ()):
                 for w in sorted({1, cfg.decode_window}):
                     tw = _time.perf_counter()
-                    out, _lps, self.caches = self._decode_window_fn(
+                    out, _lps, _load, self.caches = self._decode_window_fn(
                         w, mode, maxp)(
                         self.params, self.caches, np.zeros((bs,), np.int32),
                         np.zeros((bs, maxp), np.int32),
@@ -769,7 +781,8 @@ class PagedInferenceEngine(_EngineBase):
                 while True:
                     rb = min(rb, bs)
                     tw = _time.perf_counter()
-                    y, _ylp, self.caches = self._verify_fn(rb, s1, maxp)(
+                    y, _ylp, _load, self.caches = self._verify_fn(
+                        rb, s1, maxp)(
                         self.params, self.caches,
                         np.zeros((rb, s1), np.int32),
                         np.zeros((rb, maxp), np.int32),
@@ -1223,12 +1236,14 @@ class PagedInferenceEngine(_EngineBase):
             fn = self._prefill_rows_fn(rb, mode, W)
         with self._phase("ns_prefill_device"), \
                 self.profiler.step("prefill", (rb, mode, W)):
-            toks, lps, self.caches = fn(
+            toks, lps, load, self.caches = fn(
                 self.params, self.caches, chunks, bts, sps, tls,
                 self._rng_base, np.int32(self._rng_ctr), temps, topks,
                 *self._lora_args(lslots))
+            self._notify_launch()
             toks = np.asarray(toks)     # block: the step must measure
             lps = None if lps is None else np.asarray(lps)
+            load = None if load is None else np.asarray(load)
         with self._phase("ns_prefill_post"):
             self._rng_ctr += 1
             st = self.stats
@@ -1241,10 +1256,12 @@ class PagedInferenceEngine(_EngineBase):
             # pos cached tokens and the row's first q + 1
             st["prefill_attn_pairs"] += sum(
                 n * pos + n * (n + 1) // 2 for _, pos, n in rows)
+            self._moe_account(load, int(tls.sum()), rb * c)
             self._mesh_account(
                 chunks.nbytes + bts.nbytes + sps.nbytes + tls.nbytes
                 + temps.nbytes + topks.nbytes + lslots.nbytes,
-                toks.nbytes + (0 if lps is None else lps.nbytes))
+                toks.nbytes + sum(x.nbytes for x in (lps, load)
+                                  if x is not None))
             if self._prefix_on:
                 self._publish_prefilled(rows)
             for i, (req, pos, n) in enumerate(rows):
@@ -1331,6 +1348,35 @@ class PagedInferenceEngine(_EngineBase):
         start = int(viable[-1] if len(viable) else hits[0]) + n
         return [int(t) for t in ctx[start:start + s]]
 
+    def _notify_launch(self):
+        """Wake whoever waits for new tokens (serving's streams), called
+        with a program just launched and not yet awaited: their Python —
+        decoding, one reply a stream — then runs beside the program. Woken
+        when the tokens are booked instead, 64 streams hold the GIL for
+        tens of ms exactly when this thread needs it to launch the next
+        program (PERF.md §6, PR 27). The tokens they find are the
+        previous dispatch's."""
+        with self.launched:
+            self.launch_gen += 1
+            self.launched.notify_all()
+
+    def _moe_account(self, load, live_tokens: int, run_tokens: int):
+        """Book one dispatch of an MoE config: token-expert assignments
+        that belonged to live rows / real prompt tokens (known here) and
+        that the program routed (idle rows and padding included: each
+        token x top_k x layers), and the sum and the maximum over experts
+        of ``load``, the [E] assignment counts the program handed back
+        with its tokens. max / (sum / E) over a window says how uneven
+        the routing was. ``load`` is None for a dense config."""
+        if load is None:
+            return
+        mc, st = self.cfg.model, self.stats
+        per_token = mc.moe_top_k * mc.n_layers
+        st["moe_assign_live"] += live_tokens * per_token
+        st["moe_assign_run"] += run_tokens * per_token
+        st["moe_expert_load_sum"] += int(load.sum())
+        st["moe_expert_load_max"] += int(load.max())
+
     def _live_pages(self, slots) -> int:
         """KV pages that hold the given slots' tokens: what one decode
         step's attention has to stream."""
@@ -1392,16 +1438,20 @@ class PagedInferenceEngine(_EngineBase):
             fn = self._verify_fn(rb, s1, W, want_lp)
         with self._phase("ns_decode_device"), \
                 self.profiler.step("verify", (rb, s1, W, want_lp)):
-            y, ylp, self.caches = fn(
+            y, ylp, load, self.caches = fn(
                 self.params, self.caches, toks, bts, starts,
                 *self._lora_args(lslots))
+            self._notify_launch()
             y = np.asarray(y)               # [r, s1]; block: measure
             ylp = None if ylp is None else np.asarray(ylp)
+            load = None if load is None else np.asarray(load)
         with self._phase("ns_decode_post"):
             self.stats["spec_dispatches"] += 1
+            self._moe_account(load, r * s1, rb * s1)
             self._mesh_account(
                 toks.nbytes + bts.nbytes + starts.nbytes + lslots.nbytes,
-                y.nbytes + (0 if ylp is None else ylp.nbytes))
+                y.nbytes + sum(x.nbytes for x in (ylp, load)
+                               if x is not None))
             emitted = 0
             for i, slot in enumerate(slots):
                 req = self._active[slot]
@@ -1499,12 +1549,14 @@ class PagedInferenceEngine(_EngineBase):
             fn = self._decode_window_fn(w, mode, W)
         with self._phase("ns_decode_device"), \
                 self.profiler.step("decode", (w, mode, W)):
-            out, lps, self.caches = fn(
+            out, lps, load, self.caches = fn(
                 self.params, self.caches, tokens, bt, lengths,
                 self._rng_base, np.int32(self._rng_ctr), temps, topks,
                 *self._lora_args(lslots))
+            self._notify_launch()
             out = np.asarray(out)           # [bs, w]; block to measure
             lps = None if lps is None else np.asarray(lps)
+            load = None if load is None else np.asarray(load)
         with self._phase("ns_decode_post"):
             self._rng_ctr += 1
             st = self.stats
@@ -1513,10 +1565,12 @@ class PagedInferenceEngine(_EngineBase):
             st["decode_live_pages"] += live_pages
             st["decode_table_pages"] += bt.size
             st["decode_steps"] += w
+            self._moe_account(load, live_slots * w, bs * w)
             self._mesh_account(
                 tokens.nbytes + bt.nbytes + lengths.nbytes + temps.nbytes
                 + topks.nbytes + lslots.nbytes,
-                out.nbytes + (0 if lps is None else lps.nbytes))
+                out.nbytes + sum(x.nbytes for x in (lps, load)
+                                 if x is not None))
             for slot in list(self._active):
                 req = self._active[slot]
                 for j in range(w):

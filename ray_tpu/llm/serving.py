@@ -231,6 +231,12 @@ class LLMServer:
                             with self._steplock:
                                 self.engine.maybe_rewarm(max_pages=32)
                 if not worked:
+                    with phase(st, *other):
+                        # nothing follows the last dispatch: its tokens
+                        # are sent now
+                        for eng in self._engines():
+                            if hasattr(eng, "_notify_launch"):
+                                eng._notify_launch()
                     with phase(st, *idle):
                         self._wake.wait(timeout=0.05)
                         self._wake.clear()
@@ -374,9 +380,14 @@ class LLMServer:
         eng, req = self._submit(request)
         sent = 0
         last_text = ""
+        # a paged engine says when it has launched a dispatch: sleep on
+        # that, not on a 50 Hz poll, so that this thread's work runs
+        # beside the device's and not in the stepping thread's way
+        launched = getattr(eng, "launched", None)
         while True:
             if self._error is not None and not req.done:
                 raise RuntimeError("llm engine loop died") from self._error
+            gen = getattr(eng, "launch_gen", 0)
             n = len(req.out_ids)
             if n > sent:
                 text = eng.tokenizer.decode(list(req.out_ids))
@@ -389,7 +400,14 @@ class LLMServer:
                                         "finish_reason": None}]}
             if req.done:
                 break
-            req.event.wait(timeout=0.02)
+            if launched is None:
+                req.event.wait(timeout=0.02)
+                continue
+            with launched:
+                # the timeout bounds the wait when no dispatch follows
+                # (the engine went idle, or its loop died)
+                if eng.launch_gen == gen and not req.done:
+                    launched.wait(timeout=0.05)
         out = eng._result(req)
         tail = out["text"][len(last_text):]
         yield {"object": "text_completion.chunk", "model": self.model_id,

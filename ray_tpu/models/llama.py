@@ -11,7 +11,12 @@ on v5e). Design choices that are TPU-idiomatic rather than ports:
   boundaries so XLA inserts exactly the Megatron-style collectives;
 * attention = ops.flash_attention (Pallas on TPU); with an "sp" mesh axis the
   trainer swaps in parallel.ring.ring_attention for long context;
-* bf16 params/activations, f32 RMSNorm accumulation and logits.
+* bf16 params/activations, f32 RMSNorm accumulation and logits;
+* two optional departures from the Llama block, each one config field and
+  one branch at the block's single call site: QK-norm before RoPE (`_qkv`)
+  and a dropless top-k mixture of SwiGLU experts in the MLP's place
+  (`_moe_ffn`, over ops.grouped_matmul). OLMoE-1B-7B is LlamaConfig with
+  both (benchmarks/models/olmoe.py).
 
 Decode-time KV caching lives here too (used by the serving engine).
 """
@@ -51,12 +56,19 @@ class LlamaConfig:
     use_flash: bool = True            # Pallas flash attention (vs reference)
     attn_block_q: int = 512
     attn_block_k: int = 512
-    # mixture-of-experts (0 = dense MLP). Experts shard over the ep mesh
-    # axis ("expert" logical axis); dispatch/combine einsums induce the
-    # all-to-all when tokens are dp/sp-sharded (SURVEY §2.4 EP row).
+    # RMSNorm with a learned weight over the WHOLE q and k projections
+    # (all heads together), before the reshape into heads and RoPE (OLMoE)
+    qk_norm: bool = False
+    # mixture-of-experts (0 = dense MLP): every token gets all top_k of
+    # its experts, whoever else is in the batch (dropless, _moe_ffn).
+    # Experts shard over the ep mesh axis ("expert" logical axis): each
+    # shard runs its own experts over the tokens it sees and the shards'
+    # results are summed.
     moe_experts: int = 0
     moe_top_k: int = 2
-    moe_capacity: float = 2.0         # slots per expert = cap*k*T/E
+    # divide the top_k router probabilities by their sum (Mixtral: yes;
+    # OLMoE's norm_topk_prob is false)
+    moe_renormalize: bool = True
     moe_aux_weight: float = 0.01      # load-balance loss weight
 
     @property
@@ -76,10 +88,12 @@ class LlamaConfig:
                    + d * self.moe_experts)                           # + router
         else:
             mlp = 3 * d * self.mlp_dim
+        kvd = self.n_kv_heads * self.head_dim
         per_layer = (
-            d * d + 2 * d * self.n_kv_heads * self.head_dim + d * d  # qkvo
+            d * d + 2 * d * kvd + d * d                              # qkvo
             + mlp                                                    # (swi)glu
-            + 2 * d)                                                 # norms
+            + 2 * d                                                  # norms
+            + (d + kvd if self.qk_norm else 0))
         return v * d + self.n_layers * per_layer + d + d * v
 
 
@@ -143,6 +157,8 @@ def init(rng: jax.Array, cfg: LlamaConfig) -> dict:
             "w_up": dense_init(ks[5], (L, d, cfg.mlp_dim), d),
             "w_down": dense_init(ks[6], (L, cfg.mlp_dim, d), cfg.mlp_dim),
         }
+    qk = {"q_norm": norm_init(L, cfg.n_heads * hd),
+          "k_norm": norm_init(L, kvd)} if cfg.qk_norm else {}
     return {
         "embed": dense_init(k_emb, (cfg.vocab_size, d), d),
         "layers": {
@@ -151,6 +167,7 @@ def init(rng: jax.Array, cfg: LlamaConfig) -> dict:
             "wk": dense_init(ks[1], (L, d, kvd), d),
             "wv": dense_init(ks[2], (L, d, kvd), d),
             "wo": dense_init(ks[3], (L, cfg.n_heads * hd, d), cfg.dim),
+            **qk,
             **mlp,
         },
         "final_norm": norm_init(d),
@@ -176,6 +193,10 @@ def logical_axes(cfg: LlamaConfig) -> dict:
             "w_up": (None, "embed", "mlp"),
             "w_down": (None, "mlp", "embed"),
         }
+    # the QK-norm weights span all heads: replicated ("norm"), and _qkv
+    # normalises the whole projection before it constrains q and k by head
+    qk = {"q_norm": (None, "norm"),
+          "k_norm": (None, "norm")} if cfg.qk_norm else {}
     return {
         "embed": ("vocab", "embed"),
         "layers": {
@@ -184,6 +205,7 @@ def logical_axes(cfg: LlamaConfig) -> dict:
             "wk": (None, "embed", "heads"),
             "wv": (None, "embed", "heads"),
             "wo": (None, "heads", "embed"),
+            **qk,
             **mlp,
         },
         "final_norm": ("norm",),
@@ -232,7 +254,8 @@ def _attention(q, k, v, cfg: LlamaConfig, causal: bool, attn_impl):
 
 
 def _qkv(h, p, cfg: LlamaConfig, cos, sin, lora=None, slots=None):
-    """Projections + RoPE, shared by every forward mode. h [B, S, D].
+    """Projections (+ QK-norm over the whole projected vector when
+    cfg.qk_norm) + RoPE, shared by every forward mode. h [B, S, D].
 
     ``lora``/``slots``: optional per-layer adapter slot table
     (_lora_at_layer) and per-row slot ids — the batched multi-LoRA
@@ -246,6 +269,9 @@ def _qkv(h, p, cfg: LlamaConfig, cos, sin, lora=None, slots=None):
         q = _lora_add(q, h, lora, "wq", slots)
         k = _lora_add(k, h, lora, "wk", slots)
         v = _lora_add(v, h, lora, "wv", slots)
+    if cfg.qk_norm:
+        q = rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, p["k_norm"], cfg.norm_eps)
     q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
@@ -310,60 +336,110 @@ def _lora_add(y, x, lora, target: str, slots):
 
 
 def _mlp_block(x, p, cfg: LlamaConfig):
-    """Post-attention MLP with residual: dense SwiGLU, or top-k MoE when
-    cfg.moe_experts > 0 (returns aux=0.0 / load-balance loss)."""
+    """Post-attention MLP with residual: dense SwiGLU, or the top-k
+    mixture of experts when cfg.moe_experts > 0. Returns (x, aux, load):
+    the load-balance loss (0.0 when dense) and the [E] int32 count of
+    assignments each expert got (None when dense)."""
     h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     if cfg.moe_experts:
-        y, aux = _moe_ffn(h, p, cfg)
+        y, aux, load = _moe_ffn(h, p, cfg)
         x = x + y
-        return constrain(x, ("batch", "sequence", "embed")), aux
+        return constrain(x, ("batch", "sequence", "embed")), aux, load
     gate = jax.nn.silu(h @ p["w_gate"])
     x = x + (gate * (h @ p["w_up"])) @ p["w_down"]
-    return constrain(x, ("batch", "sequence", "embed")), jnp.float32(0.0)
+    return (constrain(x, ("batch", "sequence", "embed")), jnp.float32(0.0),
+            None)
 
 
-def _moe_ffn(h, p, cfg: LlamaConfig):
-    """Top-k expert SwiGLU over capacity-bounded slots (GShard-style dense
-    dispatch/combine einsums — static shapes, MXU-friendly; with experts
-    sharded over ep and tokens over dp, XLA lowers the dispatch einsum to
-    the expert all-to-all). h [B, S, D] -> (out [B, S, D], aux_loss)."""
-    b, s, d = h.shape
+def _moe_ffn(h, p, cfg: LlamaConfig, interpret: bool = False):
+    """Dropless top-k mixture of SwiGLU experts, the one algorithm behind
+    training, prefill, decode and verify. h [B, S, D] ->
+    (out [B, S, D], aux_loss, load [E] int32).
+
+    p = softmax(h Wr) in float32 over all experts, the top_k values and
+    indices (divided by their sum when cfg.moe_renormalize), and
+    out = sum_j p_j * expert_{e_j}(h): every token gets all of its
+    experts, so a token's output does not depend on which other tokens
+    (or idle decode rows, or pad tokens) share its dispatch. The T x k
+    assignments are laid out by expert in whole row tiles
+    (ops.grouped_matmul.group_layout), the three expert matmuls run as
+    grouped matmuls over that layout, and each token gathers its k rows
+    back, weighted. ``load`` counts the assignments per expert, the
+    padding's included: what the dispatch actually routed.
+
+    Under a mesh the expert part runs per shard (shard_kernel): a shard
+    holds E / ep experts (and mlp_dim / tp of each), computes their part
+    for the tokens it sees, and the parts are summed over ep and tp."""
     E, k = cfg.moe_experts, cfg.moe_top_k
-    T = b * s
-    C = max(1, int(cfg.moe_capacity * k * T / E))
-    ht = h.reshape(T, d)
-
-    logits = ht.astype(jnp.float32) @ p["w_router"]            # [T, E]
+    logits = jnp.einsum("bsd,de->bse", h.astype(jnp.float32), p["w_router"],
+                        precision=jax.lax.Precision.HIGHEST)
     probs = jax.nn.softmax(logits, axis=-1)
-    gate_k, idx_k = jax.lax.top_k(probs, k)                    # [T, k]
-    gate_k = gate_k / jnp.maximum(
-        gate_k.sum(axis=-1, keepdims=True), 1e-9)
+    gate_k, idx_k = jax.lax.top_k(probs, k)                    # [B, S, k]
+    if cfg.moe_renormalize:
+        gate_k = gate_k / jnp.maximum(
+            gate_k.sum(axis=-1, keepdims=True), 1e-9)
 
     # Switch-style load-balance aux: E * sum(frac_routed * mean_prob)
-    me = probs.mean(axis=0)                                    # [E]
-    ce = jax.nn.one_hot(idx_k[:, 0], E).mean(axis=0)           # [E]
+    me = probs.mean(axis=(0, 1))                               # [E]
+    ce = jax.nn.one_hot(idx_k[..., 0], E).mean(axis=(0, 1))    # [E]
     aux = E * jnp.sum(me * ce)
+    load = (idx_k.reshape(-1, 1) == jnp.arange(E)).sum(0, dtype=jnp.int32)
 
-    combine = jnp.zeros((T, E, C), jnp.float32)
-    prev_counts = jnp.zeros((E,), jnp.int32)
-    for j in range(k):                                         # k is tiny
-        oh = jax.nn.one_hot(idx_k[:, j], E, dtype=jnp.int32)   # [T, E]
-        pos = jnp.cumsum(oh, axis=0) - 1 + prev_counts         # [T, E]
-        prev_counts = prev_counts + oh.sum(axis=0)
-        in_cap = (pos < C) & (oh > 0)                          # [T, E]
-        slot = jax.nn.one_hot(jnp.clip(pos, 0, C - 1), C)      # [T, E, C]
-        combine = combine + (gate_k[:, j][:, None, None]
-                             * in_cap[..., None] * slot)
-    dispatch = (combine > 0).astype(h.dtype)                   # [T, E, C]
+    tok = ("batch", "sequence", None)
+    # the serving paths hand over the layers' stacks and a layer index
+    # (_layer_params): one more leading, unsharded axis
+    layer = p.get("expert_layer")
+    stack = () if layer is None else (None,)
+    ffn = shard_kernel(
+        functools.partial(_expert_ffn, n_experts=E, mlp_dim=cfg.mlp_dim,
+                          layer=layer, kernel=interpret or _on_tpu(),
+                          interpret=interpret),
+        (tok, tok, tok, stack + ("expert", None, "mlp"),
+         stack + ("expert", None, "mlp"), stack + ("expert", "mlp", None)),
+        tok)
+    out = ffn(h, idx_k, gate_k, p["w_gate"], p["w_up"], p["w_down"])
+    return out, aux, load
 
-    xe = jnp.einsum("tec,td->ecd", dispatch, ht)               # [E, C, D]
-    xe = constrain(xe, ("expert", None, "embed"))
-    ge = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, p["w_gate"]))
-    ue = jnp.einsum("ecd,edf->ecf", xe, p["w_up"])
-    ye = jnp.einsum("ecf,efd->ecd", ge * ue, p["w_down"])
-    ye = constrain(ye, ("expert", None, "embed"))
-    out = jnp.einsum("tec,ecd->td", combine.astype(ye.dtype), ye)
-    return out.reshape(b, s, d), aux
+
+def _expert_ffn(h, idx, gate, w_gate, w_up, w_down, *, n_experts: int,
+                mlp_dim: int, layer: Optional[int], kernel: bool,
+                interpret: bool):
+    """The experts' part of _moe_ffn on one shard: h [B, S, D], idx and
+    gate [B, S, k], weights [E_here, D, F_here] / [E_here, F_here, D], or
+    the layers' stacks of them with ``layer`` naming the one to use."""
+    from ..ops import grouped_matmul as gmm
+
+    b, s, d = h.shape
+    k = idx.shape[-1]
+    t, e_here = b * s, w_gate.shape[-3]
+    over_ep, over_tp = e_here != n_experts, w_gate.shape[-1] != mlp_dim
+    flat = idx.reshape(t * k)
+    owned = None
+    if over_ep:
+        flat = flat - jax.lax.axis_index("ep") * e_here
+        owned = (flat >= 0) & (flat < e_here)
+    tm = gmm.tile_rows(t * k, e_here)
+    row_of, padded, tile_expert, n_live = gmm.group_layout(
+        flat, owned, e_here, tm)
+    rows = gmm.num_tiles(t * k, e_here, tm) * tm
+    # the token each padded row holds (t, out of range, for padding: it
+    # reads as zeros and its gradient is dropped)
+    tok_of_row = jnp.full((rows,), t, jnp.int32).at[row_of].set(
+        jnp.arange(t * k, dtype=jnp.int32) // k, mode="drop")
+    x = h.reshape(t, d).at[tok_of_row].get(mode="fill", fill_value=0)
+    if kernel:
+        y = gmm.grouped_ffn(x, w_gate, w_up, w_down, padded, tile_expert,
+                            n_live, tm, layer, interpret)
+    else:
+        y = gmm.grouped_ffn_reference(x, w_gate, w_up, w_down, padded,
+                                      layer)
+    y = y.at[row_of].get(mode="fill", fill_value=0)            # [T*k, D]
+    out = (y.reshape(t, k, d).astype(jnp.float32)
+           * gate.reshape(t, k, 1)).sum(1).astype(h.dtype)
+    axes = (("ep",) if over_ep else ()) + (("tp",) if over_tp else ())
+    if axes:
+        out = jax.lax.psum(out, axes)
+    return out.reshape(b, s, d)
 
 
 def _layer(x, layer_params, cfg: LlamaConfig, cos, sin, attn_impl,
@@ -402,7 +478,7 @@ def _layer(x, layer_params, cfg: LlamaConfig, cos, sin, attn_impl,
     attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
     x = x + attn @ p["wo"]
     x = constrain(x, ("batch", "sequence", "embed"))
-    x, aux = _mlp_block(x, p, cfg)
+    x, aux, _ = _mlp_block(x, p, cfg)
     return x, aux, new_kv
 
 
@@ -510,7 +586,7 @@ def apply_with_kv(params: dict, tokens: jax.Array, cfg: LlamaConfig):
         attn = _attention(q, k, v, cfg, causal=True, attn_impl=None)
         x = x + attn.reshape(b, s, -1) @ p["wo"]
         x = constrain(x, ("batch", "sequence", "embed"))
-        x, _ = _mlp_block(x, p, cfg)
+        x, _, _ = _mlp_block(x, p, cfg)
         return x, (k, v)
 
     x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
@@ -552,7 +628,7 @@ def decode_batched(params: dict, tokens: jax.Array, cache: dict,
         probs = jax.nn.softmax(scores, axis=-1).astype(vr.dtype)
         attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
         x = x + attn.reshape(b, 1, -1) @ p["wo"]
-        x, _ = _mlp_block(x, p, cfg)
+        x, _, _ = _mlp_block(x, p, cfg)
         return x, (ck, cv)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -633,8 +709,30 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int,
             for _ in range(cfg.n_layers)]
 
 
+_EXPERT_WEIGHTS = ("w_gate", "w_up", "w_down")
+
+
 def _layer_params(params: dict, layer: int) -> dict:
-    return jax.tree.map(lambda a: a[layer], params["layers"])
+    """One layer's parameters out of the stacks, for the serving paths,
+    which unroll their layers. The experts' weights stay stacked, with
+    the layer's index beside them as ``expert_layer``: the grouped-matmul
+    kernels read that layer's blocks in place, where a slice handed to a
+    Pallas call is first copied whole (805 MB a layer a step at OLMoE's
+    widths — as much as the expert matmuls themselves read)."""
+    stacks = params["layers"]
+    moe = "w_router" in stacks
+    p = {k: a if moe and k in _EXPERT_WEIGHTS else a[layer]
+         for k, a in stacks.items()}
+    if moe:
+        p["expert_layer"] = layer
+    return p
+
+
+def _add_load(total, routed):
+    """Sum of the layers' per-expert assignment counts (_mlp_block's
+    ``load``); None all the way for a dense config, which routes nothing
+    and whose programs get no such output."""
+    return routed if total is None or routed is None else total + routed
 
 
 # logical axes of a page pool [P, page, KVH, D] (the engine commits it so)
@@ -669,7 +767,9 @@ def decode_paged(params: dict, tokens: jax.Array, caches: list[dict],
 
     tokens [B, 1]; block_tables [B, max_pages]; lengths [B] = tokens already
     WRITTEN (current token goes at position `lengths`). Returns
-    (logits [B, V], updated caches). Inactive rows: pass length 0 and mask
+    (logits [B, V], updated caches, load) — load is the [E] int32 count of
+    expert assignments over all layers, None for a dense config (the same
+    third result on every paged forward below). Inactive rows: pass length 0 and mask
     the output — their token writes land in page block_tables[b, 0] slot 0
     and are overwritten on real use.
 
@@ -696,7 +796,7 @@ def decode_paged(params: dict, tokens: jax.Array, caches: list[dict],
     else:
         attend = paged_decode_reference
 
-    new_caches = []
+    new_caches, load = [], None
     for layer in range(cfg.n_layers):
         p = _layer_params(params, layer)
         ll = _lora_at_layer(lora, layer)
@@ -714,7 +814,8 @@ def decode_paged(params: dict, tokens: jax.Array, caches: list[dict],
         if ll is not None:
             y = _lora_add(y, proj, ll, "wo", slots)
         x = x + y
-        x, _ = _mlp_block(x, p, cfg)
+        x, _, routed = _mlp_block(x, p, cfg)
+        load = _add_load(load, routed)
         new_caches.append({"k": k_pages, "v": v_pages})
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -722,7 +823,7 @@ def decode_paged(params: dict, tokens: jax.Array, caches: list[dict],
                         preferred_element_type=jnp.float32)
     if lora is not None and "lm_head.A" in lora:
         logits = _lora_add(logits, x, lora, "lm_head", slots)
-    return logits[:, 0], new_caches
+    return logits[:, 0], new_caches, load
 
 
 def prefill_paged_chunk(params: dict, chunk: jax.Array, caches: list[dict],
@@ -737,7 +838,7 @@ def prefill_paged_chunk(params: dict, chunk: jax.Array, caches: list[dict],
     (page-aligned); true_chunk_len = real tokens in this chunk (defaults to
     C). Attends over the already-written paged prefix plus causally within
     the chunk, writes the chunk's K/V into its pages, and returns
-    (logits [C, V], updated caches) — caller picks the logit at the
+    (logits [C, V], updated caches, load) — caller picks the logit at the
     prompt's true last position.
 
     Attention dispatch: the chunk's K/V is scattered into its pages
@@ -774,7 +875,7 @@ def prefill_paged_chunk(params: dict, chunk: jax.Array, caches: list[dict],
         valid, block_table_row[jnp.clip(logical, 0, max_pages - 1)], 0)
 
     x = params["embed"][chunk].astype(cfg.dtype)          # [1, C, D]
-    new_caches = []
+    new_caches, load = [], None
     for layer in range(cfg.n_layers):
         p = _layer_params(params, layer)
         ll = _lora_at_layer(lora, layer)
@@ -805,7 +906,8 @@ def prefill_paged_chunk(params: dict, chunk: jax.Array, caches: list[dict],
         if ll is not None:
             y = _lora_add(y, proj, ll, "wo", slot)
         x = x + y
-        x, _ = _mlp_block(x, p, cfg)
+        x, _, routed = _mlp_block(x, p, cfg)
+        load = _add_load(load, routed)
         new_caches.append({"k": k_pages, "v": v_pages})
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
@@ -813,7 +915,7 @@ def prefill_paged_chunk(params: dict, chunk: jax.Array, caches: list[dict],
                         preferred_element_type=jnp.float32)
     if lora is not None and "lm_head.A" in lora:
         logits = _lora_add(logits, x, lora, "lm_head", slot)
-    return logits[0], new_caches
+    return logits[0], new_caches, load
 
 
 def prefill_paged_rows(params: dict, chunks: jax.Array, caches: list[dict],
@@ -829,7 +931,7 @@ def prefill_paged_rows(params: dict, chunks: jax.Array, caches: list[dict],
     consecutive chunks of the SAME sequence — row i+1 sees row i's page
     writes. Rows with true_lens == 0 are padding: all their page writes
     route to sink page 0. Returns (last_logits [R, V] — the logit at each
-    row's last real token — and updated caches).
+    row's last real token — updated caches, and the rows' summed load).
 
     Exists to cut engine-step dispatch count: a burst of prompts prefills
     in ceil(n_chunks / R) dispatches instead of one dispatch per chunk
@@ -845,18 +947,18 @@ def prefill_paged_rows(params: dict, chunks: jax.Array, caches: list[dict],
     def body(carry, row):
         chunk, bt, sp, tl = row[:4]
         sl = row[4] if lora is not None else None
-        logits, carry = prefill_paged_chunk(
+        logits, carry, load = prefill_paged_chunk(
             params, chunk[None, :], carry, bt, sp, cfg,
             page_size=page_size, true_chunk_len=tl, interpret=interpret,
             lora=lora, slot=sl)
         last = logits[jnp.clip(tl - 1, 0, c - 1)]
-        return carry, last
+        return carry, (last, load)
 
     xs = (chunks, bt_rows, start_pos, true_lens)
     if lora is not None:
         xs = xs + (slots,)
-    caches, last = jax.lax.scan(body, caches, xs)
-    return last, caches
+    caches, (last, load) = jax.lax.scan(body, caches, xs)
+    return last, caches, None if load is None else load.sum(0)
 
 
 def verify_paged_rows(params: dict, tokens: jax.Array, caches: list[dict],
@@ -867,7 +969,8 @@ def verify_paged_rows(params: dict, tokens: jax.Array, caches: list[dict],
     speculative decoding in the reference's serving engine): for each of
     R rows feed S1 = 1 + n_draft tokens at positions
     starts[r] .. starts[r]+S1-1 over that row's paged KV, writing their
-    K/V in place, and return logits [R, S1, V] for every fed position —
+    K/V in place, and return (logits [R, S1, V] for every fed position,
+    updated caches, load) —
     the engine accepts the longest draft prefix the model agrees with,
     so one dispatch can emit up to S1 tokens.
 
@@ -901,7 +1004,7 @@ def verify_paged_rows(params: dict, tokens: jax.Array, caches: list[dict],
                              bt[jnp.clip(pidx, 0, maxp - 1)], 0)
         offsets = positions % page_size
         x = params["embed"][toks][None].astype(cfg.dtype)  # [1, S1, D]
-        new_caches = []
+        new_caches, load = [], None
         for layer in range(cfg.n_layers):
             p = _layer_params(params, layer)
             ll = _lora_at_layer(lora, layer)
@@ -922,20 +1025,22 @@ def verify_paged_rows(params: dict, tokens: jax.Array, caches: list[dict],
             if ll is not None:
                 y = _lora_add(y, proj, ll, "wo", sl)
             x = x + y
-            x, _ = _mlp_block(x, p, cfg)
+            x, _, routed = _mlp_block(x, p, cfg)
+            load = _add_load(load, routed)
             new_caches.append({"k": k_pages, "v": v_pages})
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
                             preferred_element_type=jnp.float32)
         if lora is not None and "lm_head.A" in lora:
             logits = _lora_add(logits, x, lora, "lm_head", sl)
-        return new_caches, logits[0]
+        return new_caches, (logits[0], load)
 
     xs = (tokens, bt_rows, starts)
     if lora is not None:
         xs = xs + (slots,)
-    caches, logits = jax.lax.scan(body, caches, xs)
-    return logits, caches                                  # [R, S1, V]
+    caches, (logits, load) = jax.lax.scan(body, caches, xs)
+    # logits [R, S1, V]
+    return logits, caches, None if load is None else load.sum(0)
 
 
 def cross_entropy_loss(logits: jax.Array, targets: jax.Array,

@@ -1,0 +1,223 @@
+"""Grouped matmul for dropless mixture-of-experts (Pallas, TPU): rows that
+are grouped by expert, each group multiplied by its own expert's weights.
+
+The layout is what makes the kernel plain. `group_layout` gives every
+expert a run of whole ROW TILES (``tile_rows`` rows; a group's last tile is
+padded), so a tile belongs to exactly one expert and the kernel is an
+ordinary tiled matmul whose weight block is picked per tile from a
+scalar-prefetched ``tile_expert`` table — no group boundary ever falls
+inside a tile, so there is no in-tile masking (the megablox kernel's
+compact layout needs it):
+
+* grid ``(column tile, row tile)``, row tiles fastest. Consecutive row
+  tiles of one expert name the same weight block, which Pallas does not
+  fetch again, so a column sweep reads each expert's ``[K, tn]`` block
+  once however many rows the expert got: HBM traffic is the weights of
+  the experts hit plus the (small) activations. At serving shapes that is
+  the whole cost — 64 experts x 3 x [2048, 1024] are 805 MB a layer
+  against a few MB of rows — so tiles are small (16-32 rows) and the
+  padding rows they bring cost nothing that matters;
+* the table is as long as the worst case (every group wasting a tile),
+  and the tiles past the last live one are DEAD: their block indices are
+  clamped to the last live tile's (nothing is fetched or written) and
+  `pl.when` skips their body, so a dead step costs its launch alone;
+* `grouped_swiglu` fuses the gate and up projections and the activation
+  (``silu(x Wg) * (x Wu)``): both weight blocks stream in one sweep and
+  the ``[rows, F]`` intermediates never touch HBM.
+
+Off the TPU (and for every backward pass) the same layout runs through
+``jax.lax.ragged_dot`` with the padded group sizes: that is the numerical
+contract, the CPU fallback and the ``custom_vjp`` backward (a training
+step's expert gradients are ragged_dot's own transposes; a Pallas
+backward is not written yet — PERF.md §7).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+# weight block budget: K x tn elements a block (2 MiB in bf16, so gate +
+# up, double-buffered, stay under half of the 16 MiB scoped VMEM default)
+_W_BLOCK_ELEMS = 1 << 20
+
+
+def tile_rows(assignments: int, experts: int) -> int:
+    """Rows per tile: the power of two at or above twice the mean group
+    (most groups then fit one tile, and the padding stays under the
+    weights' cost), from 16 (a bf16 vreg's sublanes) to 128."""
+    mean = max(1, assignments // max(experts, 1))
+    return min(128, max(16, 1 << (2 * mean - 1).bit_length()))
+
+
+def num_tiles(assignments: int, experts: int, tm: int) -> int:
+    """Static bound on the row tiles a layout can need: every non-empty
+    group may waste up to ``tm - 1`` rows."""
+    return max(1, (assignments + min(assignments, experts) * (tm - 1)) // tm)
+
+
+def group_layout(expert_of, owned, experts: int, tm: int):
+    """The tile-aligned layout of ``A`` assignments over ``experts`` groups.
+
+    expert_of [A] int32 (values outside [0, experts) must have ``owned``
+    false); owned [A] bool or None. Returns ``(row_of [A], padded_sizes
+    [E], tile_expert [n_tiles], n_live [1])``: the padded row each
+    assignment lands in (``n_tiles * tm``, out of range, when not owned),
+    each group's size rounded up to whole tiles, the expert of each row
+    tile, and how many tiles are live. Stable: assignments keep their
+    order inside a group. No sort: the ranks are a cumulative sum of the
+    one-hot assignment matrix, which at A x E of 512 x 64 is nothing."""
+    a = expert_of.shape[0]
+    n_tiles = num_tiles(a, experts, tm)
+    safe = jnp.clip(expert_of, 0, experts - 1)
+    onehot = expert_of[:, None] == jnp.arange(experts, dtype=jnp.int32)
+    if owned is not None:
+        onehot = onehot & owned[:, None]
+    onehot = onehot.astype(jnp.int32)
+    counts = onehot.sum(0)
+    rank = jnp.take_along_axis(jnp.cumsum(onehot, 0), safe[:, None],
+                               1)[:, 0] - 1
+    padded = -(-counts // tm) * tm
+    ends = jnp.cumsum(padded)
+    row_of = (ends - padded)[safe] + rank
+    if owned is not None:
+        row_of = jnp.where(owned, row_of, n_tiles * tm)
+    n_live = ends[-1] // tm
+    first_row = jnp.arange(n_tiles, dtype=jnp.int32) * tm
+    tile_expert = (ends[None, :] <= first_row[:, None]).sum(1)
+    # dead tiles name the last live tile's expert: no weight block moves
+    last = tile_expert[jnp.maximum(n_live - 1, 0)]
+    tile_expert = jnp.where(first_row < ends[-1], tile_expert, last)
+    return (row_of.astype(jnp.int32), padded.astype(jnp.int32),
+            jnp.minimum(tile_expert, experts - 1).astype(jnp.int32),
+            jnp.reshape(n_live, (1,)).astype(jnp.int32))
+
+
+def _col_tile(k: int, n: int) -> int:
+    """Columns of a weight block: the widest multiple of 128 that divides
+    N and keeps K x tn inside the block budget; all of N where N has no
+    such divisor (toy shapes)."""
+    best = None
+    for tn in range(128, n + 1, 128):
+        if n % tn == 0 and k * tn <= _W_BLOCK_ELEMS:
+            best = tn
+    return best or n
+
+
+def _gmm_kernel(te_ref, live_ref, layer_ref, x_ref, w_ref, o_ref):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) < live_ref[0])
+    def _():
+        o_ref[...] = jnp.dot(
+            x_ref[...], w_ref[...],
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+
+def _swiglu_kernel(te_ref, live_ref, layer_ref, x_ref, wg_ref, wu_ref,
+                   o_ref):
+    from jax.experimental import pallas as pl
+
+    @pl.when(pl.program_id(1) < live_ref[0])
+    def _():
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+        o_ref[...] = (jax.nn.silu(g) * u).astype(o_ref.dtype)
+
+
+# Jitted for the reason ops/ragged_paged_attention._ragged_call is: the
+# serving paths unroll their layers in Python, and one shared function
+# lowers the kernel body once per shape, not once per layer (the layer is
+# a prefetched scalar, so ten layers share one lowering).
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def _gmm_call(x, weights, tile_expert, n_live, layer, *, tm: int,
+              interpret: bool):
+    """x [M, K] in the tile-aligned layout; weights: one [L, E, K, N]
+    stack (x @ W[layer, e]) or two (silu(x @ Wg[layer, e]) * (x @
+    Wu[layer, e])); layer [1] int32. Returns [M, N]; the rows of dead
+    tiles are never written."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    m, k = x.shape
+    n = weights[0].shape[-1]
+    tn = _col_tile(k, n)
+
+    def live(i, live_ref):
+        return jnp.minimum(i, jnp.maximum(live_ref[0] - 1, 0))
+
+    w_spec = pl.BlockSpec((None, None, k, tn),
+                          lambda j, i, te, lv, ly: (ly[0], te[i], 0, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n // tn, m // tm),
+        in_specs=[pl.BlockSpec((tm, k),
+                               lambda j, i, te, lv, ly: (live(i, lv), 0)),
+                  *[w_spec] * len(weights)],
+        out_specs=pl.BlockSpec((tm, tn),
+                               lambda j, i, te, lv, ly: (live(i, lv), j)),
+    )
+    fused = len(weights) == 2
+    return pl.pallas_call(
+        _swiglu_kernel if fused else _gmm_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        interpret=interpret,
+        name="grouped_swiglu" if fused else "grouped_matmul",
+    )(tile_expert, n_live, layer, x, *weights)
+
+
+def grouped_ffn_reference(x, w_gate, w_up, w_down, padded_sizes,
+                          layer: int | None = None):
+    """The expert SwiGLU on the tile-aligned layout through
+    ``jax.lax.ragged_dot``: rows past the groups' total come out zero."""
+    if layer is not None:
+        w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
+    dot = functools.partial(jax.lax.ragged_dot, group_sizes=padded_sizes)
+    return dot(jax.nn.silu(dot(x, w_gate)) * dot(x, w_up), w_down)
+
+
+def _ffn_kernels(x, w_gate, w_up, w_down, tile_expert, n_live, tm, layer,
+                 interpret):
+    if layer is None:           # one layer's weights: a stack of one
+        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+    call = functools.partial(
+        _gmm_call, tile_expert=tile_expert, n_live=n_live,
+        layer=jnp.full((1,), layer or 0, jnp.int32), tm=tm,
+        interpret=interpret)
+    return call(call(x, (w_gate, w_up)), (w_down,))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def grouped_ffn(x, w_gate, w_up, w_down, padded_sizes, tile_expert, n_live,
+                tm: int, layer: int | None, interpret: bool):
+    """Every expert's SwiGLU over its own rows: x [M, D] in `group_layout`'s
+    order, weights [E, D, F] / [E, D, F] / [E, F, D] — or, with ``layer``
+    (a Python int), the layers' stacks [L, E, ...], of which the kernels
+    read that layer's blocks in place: a sliced stack cannot be a Pallas
+    operand without XLA copying it first, 805 MB a layer at OLMoE's widths.
+    Returns [M, D]; only the rows of live tiles mean anything.
+    ``padded_sizes`` is what the backward's ragged_dot groups by."""
+    return _ffn_kernels(x, w_gate, w_up, w_down, tile_expert, n_live, tm,
+                        layer, interpret)
+
+
+def _ffn_fwd(x, w_gate, w_up, w_down, padded_sizes, tile_expert, n_live,
+             tm, layer, interpret):
+    out = _ffn_kernels(x, w_gate, w_up, w_down, tile_expert, n_live, tm,
+                       layer, interpret)
+    return out, (x, w_gate, w_up, w_down, padded_sizes)
+
+
+def _ffn_bwd(tm, layer, interpret, res, g):
+    x, w_gate, w_up, w_down, padded_sizes = res
+    _, vjp = jax.vjp(functools.partial(grouped_ffn_reference,
+                                       padded_sizes=padded_sizes,
+                                       layer=layer),
+                     x, w_gate, w_up, w_down)
+    return (*vjp(g), None, None, None)
+
+
+grouped_ffn.defvjp(_ffn_fwd, _ffn_bwd)

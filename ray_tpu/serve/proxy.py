@@ -55,6 +55,16 @@ class ProxyActor:
         self._routes: dict = {}
         self._routes_ts = 0.0
         self._admission = AdmissionController(f"proxy-{index}")
+        # every open stream parks one thread in next() until its next
+        # chunk. The loop's default executor has min(32, cores + 4)
+        # threads (17 on a 13-core host): 64 streams took turns on them
+        # and every chunk, the first and the last included, reached its
+        # client ~1 s late (PERF.md §6, PR 27). Threads are made on
+        # demand, and admission bounds the streams in flight long
+        # before this ceiling does
+        from concurrent.futures import ThreadPoolExecutor
+        self._stream_pool = ThreadPoolExecutor(
+            max_workers=1024, thread_name_prefix="rtpu-proxy-stream")
 
     def _handle_for(self, ingress, app_name, stream, model_id,
                     method="__call__"):
@@ -398,7 +408,8 @@ class ProxyActor:
                 while True:
                     try:
                         chunk = await loop.run_in_executor(
-                            None, lambda: next(it, _STREAM_END))
+                            self._stream_pool,
+                            lambda: next(it, _STREAM_END))
                     except (ActorDiedError, WorkerCrashedError,
                             GetTimeoutError) as e:
                         # mid-stream replica loss: the status line is
@@ -430,3 +441,4 @@ class ProxyActor:
     async def stop(self):
         if self._runner is not None:
             await self._runner.cleanup()
+        self._stream_pool.shutdown(wait=False, cancel_futures=True)

@@ -172,3 +172,77 @@ def test_tp4_decode_step_keeps_its_kernels_in_shard_map(v5e, monkeypatch):
     gathered_pool = f"bf16[{pool},{page},{KVH},{D}]"
     assert not [ln for ln in text.splitlines()
                 if "all-gather" in ln and gathered_pool in ln]
+
+
+# -- OLMoE-1B-7B widths (BENCHMARK.json olmoe-gen-sessions-1chip): MHA with
+# 16 KV heads (groups = 1), 64 experts of 1024, top-8 ----------------------
+
+OLMOE = dict(vocab_size=50304, dim=2048, n_heads=16, n_kv_heads=16,
+             mlp_dim=1024, max_seq_len=4096, rope_theta=1e4, qk_norm=True,
+             moe_experts=64, moe_top_k=8, moe_renormalize=False)
+
+
+@pytest.mark.parametrize("rows,q_window", [(64, 1), (1, 128)],
+                         ids=["decode", "prefill"])
+def test_ragged_compiles_at_olmoe_shapes(v5e, rows, q_window):
+    """groups = 1: a kv head's MXU tile has one query row at decode, the
+    kernel keeps 16 heads' softmax state and a 128-key block is 1 MiB of
+    K + V. max_batch_size 64 rows, the cell's 128-page table (2048-token
+    contexts), its 3456-page pool."""
+    one = SingleDeviceSharding(v5e.devices[0])
+    text = _compile(
+        ragged_paged_attention,
+        *_paged_shapes(rows, q_window, 16, 128, pool=3456, h=16, kvh=16),
+        sharding=[one] * 6).as_text()
+    assert re.search(rf"%ragged_paged_attention[.\d]* = "
+                     rf"bf16\[{rows},{q_window},16,{D}\].*tpu_custom_call",
+                     text)
+
+
+@pytest.mark.parametrize("assignments", [512, 4096])
+def test_grouped_ffn_compiles_at_olmoe_widths(v5e, assignments):
+    """The expert SwiGLU's two kernels over 64 groups: 64 decode rows x
+    top-8, and 4 x 128 prefill tokens x top-8, reading one layer of the
+    layers' weight stacks in place as the serving paths do. Their names are
+    what the benchmark's trace reduction finds them by."""
+    from ray_tpu.ops import grouped_matmul as gmm
+    one = SingleDeviceSharding(v5e.devices[0])
+    e, d, f = 64, 2048, 1024
+    tm = gmm.tile_rows(assignments, e)
+    tiles = gmm.num_tiles(assignments, e, tm)
+    text = _compile(
+        functools.partial(gmm.grouped_ffn, tm=tm, layer=1, interpret=False),
+        ((tiles * tm, d), BF16), ((2, e, d, f), BF16), ((2, e, d, f), BF16),
+        ((2, e, f, d), BF16), ((e,), jnp.int32), ((tiles,), jnp.int32),
+        ((1,), jnp.int32), sharding=[one] * 7).as_text()
+    assert not re.search(r"= bf16\[(1,)?64,2048,1024\]", text)   # no copy
+    assert re.search(r"%grouped_swiglu[.\d]* = .*tpu_custom_call", text)
+    assert re.search(r"%grouped_matmul[.\d]* = .*tpu_custom_call", text)
+
+
+def test_olmoe_decode_layer_compiles_at_64_rows(v5e, monkeypatch):
+    """One decode layer body of the OLMoE config at max_batch_size 64:
+    QK-norm, the ragged kernel at groups = 1, the float32 router, the
+    layout's one-hot / cumulative sum / gathers and both grouped kernels,
+    with the per-expert load as the program's third result."""
+    from ray_tpu.models import llama
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    mc = llama.LlamaConfig(n_layers=1, **OLMOE)
+    rows, page, max_pages, pool = 64, 16, 128, 3456
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = jax.tree.map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), mc)))
+    caches = [{n: sds((pool, page, 16, D), BF16) for n in "kv"}]
+    compiled = jax.jit(functools.partial(
+        llama.decode_paged, cfg=mc, page_size=page)).lower(
+        params, sds((rows, 1), jnp.int32), caches,
+        sds((rows, max_pages), jnp.int32),
+        sds((rows,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3      # attention, two expert
+    assert jax.tree.leaves(compiled.out_info)[-1].shape == (64,)

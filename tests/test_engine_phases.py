@@ -135,6 +135,30 @@ def test_dispatch_and_request_counters(engine):
     assert d["queue_wait_ns"] > 0 and d["prefill_span_ns"] > 0
 
 
+def test_launch_is_notified_once_a_dispatch(engine):
+    """What serving's token streams sleep on: the engine bumps
+    ``launch_gen`` and notifies ``launched`` after every launch, before
+    it blocks on the result, so the streams' Python runs beside the
+    program and not in the way of the next launch."""
+    import threading
+    before = dict(engine.stats)
+    gen0, woken = engine.launch_gen, []
+
+    def waiter():
+        with engine.launched:
+            woken.append(engine.launched.wait_for(
+                lambda: engine.launch_gen > gen0, timeout=30))
+    t = threading.Thread(target=waiter)
+    t.start()
+    engine.generate([list(range(5, 30))],
+                    SamplingParams(max_tokens=5, temperature=0.0))
+    t.join(30)
+    assert woken == [True]
+    d = {k: engine.stats[k] - before[k] for k in (
+        "prefill_dispatches", "decode_dispatches", "spec_dispatches")}
+    assert engine.launch_gen - gen0 == sum(d.values()) > 0
+
+
 def test_decode_live_pages_by_hand():
     """Two requests, decode window 1, pages of 8 tokens: a prompt of 15
     and one of 24 tokens decode three times after their first token, at

@@ -54,6 +54,19 @@ def test_lora_merge_changes_outputs():
                                np.asarray(merged2["layers"]["wq"]))
 
 
+def test_router_admits_what_its_model_deployments_admit():
+    """The front door's admission budget is the ingress deployment's
+    max_ongoing_requests: at the default of 16 the router shed a 64-row
+    engine's 64 streams with 429 (PERF.md §6, PR 27)."""
+    econf = PagedEngineConfig(model=_tiny_cfg(), max_batch_size=2)
+    app = build_openai_app([
+        LLMConfig(model_id="a", engine=econf, max_ongoing_requests=128),
+        LLMConfig(model_id="b", engine=econf, max_ongoing_requests=40)])
+    assert app.ingress.spec.max_ongoing_requests == 168
+    assert [c.spec.max_ongoing_requests
+            for c in app.ingress.children()] == [128, 40]
+
+
 @pytest.mark.slow
 def test_openai_completions_and_models(ray, tmp_path):
     cfg = _tiny_cfg()

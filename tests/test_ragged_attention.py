@@ -242,9 +242,9 @@ def test_prefill_and_verify_kernel_vs_fallback():
     btj = jnp.asarray(bt)
 
     chunk0 = jnp.asarray(rng.randint(1, 60, (1, 16)), jnp.int32)
-    lg_fb, c_fb = llama.prefill_paged_chunk(
+    lg_fb, c_fb, _ = llama.prefill_paged_chunk(
         params, chunk0, caches, btj, jnp.int32(0), cfg, page_size=page)
-    lg_k, c_k = llama.prefill_paged_chunk(
+    lg_k, c_k, _ = llama.prefill_paged_chunk(
         params, chunk0, caches, btj, jnp.int32(0), cfg, page_size=page,
         interpret=True)
     np.testing.assert_allclose(np.asarray(lg_fb), np.asarray(lg_k),
@@ -261,10 +261,10 @@ def test_prefill_and_verify_kernel_vs_fallback():
 
     # ragged tail: 11 of 16 tokens real; pad-page writes route to sink
     chunk1 = jnp.asarray(rng.randint(1, 60, (1, 16)), jnp.int32)
-    lg_fb2, c_fb2 = llama.prefill_paged_chunk(
+    lg_fb2, c_fb2, _ = llama.prefill_paged_chunk(
         params, chunk1, c_fb, btj, jnp.int32(16), cfg, page_size=page,
         true_chunk_len=jnp.int32(11))
-    lg_k2, c_k2 = llama.prefill_paged_chunk(
+    lg_k2, c_k2, _ = llama.prefill_paged_chunk(
         params, chunk1, c_k, btj, jnp.int32(16), cfg, page_size=page,
         true_chunk_len=jnp.int32(11), interpret=True)
     np.testing.assert_allclose(np.asarray(lg_fb2)[:11],
@@ -277,9 +277,9 @@ def test_prefill_and_verify_kernel_vs_fallback():
     bt2[0, :4] = [1, 2, 3, 4]
     bt2[1, :2] = [5, 6]
     starts = jnp.asarray([27, 5], jnp.int32)
-    lv_fb, _ = llama.verify_paged_rows(
+    lv_fb, _, _ = llama.verify_paged_rows(
         params, toks, c_fb2, jnp.asarray(bt2), starts, cfg, page_size=page)
-    lv_k, _ = llama.verify_paged_rows(
+    lv_k, _, _ = llama.verify_paged_rows(
         params, toks, c_k2, jnp.asarray(bt2), starts, cfg, page_size=page,
         interpret=True)
     np.testing.assert_allclose(np.asarray(lv_fb), np.asarray(lv_k),
