@@ -18,23 +18,23 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Optional
 
-from .engine import EngineConfig, InferenceEngine, SamplingParams
+from .engine import SamplingParams
+from .paged_engine import PagedEngineConfig, PagedInferenceEngine
 
-_ENGINE_CACHE: dict[str, InferenceEngine] = {}
+_ENGINE_CACHE: dict[str, PagedInferenceEngine] = {}
 
 
-def _get_engine(cfg: EngineConfig) -> InferenceEngine:
-    key = repr((cfg.model, cfg.max_batch_size, cfg.max_seq_len,
-                cfg.prefill_buckets))
+def _get_engine(cfg: PagedEngineConfig) -> PagedInferenceEngine:
+    key = repr(cfg)
     if key not in _ENGINE_CACHE:
-        _ENGINE_CACHE[key] = InferenceEngine(cfg)
+        _ENGINE_CACHE[key] = PagedInferenceEngine(cfg)
     return _ENGINE_CACHE[key]
 
 
 @dataclasses.dataclass
 class ProcessorConfig:
     """(reference: processor/base.py:21 + OfflineProcessorConfig:55)"""
-    engine: Optional[EngineConfig] = None
+    engine: Optional[PagedEngineConfig] = None
     sampling: SamplingParams = dataclasses.field(
         default_factory=SamplingParams)
     prompt_column: str = "prompt"
@@ -136,10 +136,10 @@ class DetokenizeStage(Stage):
         return ds.map_batches(apply_batch)
 
 
-def _default_engine_cfg(cfg: ProcessorConfig) -> EngineConfig:
+def _default_engine_cfg(cfg: ProcessorConfig) -> PagedEngineConfig:
     from ..models import llama
-    return cfg.engine or EngineConfig(model=llama.llama_tiny(),
-                                      max_batch_size=cfg.batch_size)
+    return cfg.engine or PagedEngineConfig(model=llama.llama_tiny(),
+                                           max_batch_size=cfg.batch_size)
 
 
 def _engine_batch(engine, sampling, prompt_column, output_column,
@@ -176,7 +176,7 @@ class LLMPredictor:
                  output_column: str = "generated_text"):
         if engine_cfg is None:
             engine_cfg = _default_engine_cfg(ProcessorConfig())
-        self.engine = InferenceEngine(engine_cfg)
+        self.engine = PagedInferenceEngine(engine_cfg)
         self.sampling = sampling if sampling is not None \
             else SamplingParams()
         self.pc = prompt_column

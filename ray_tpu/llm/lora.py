@@ -2,22 +2,21 @@
 
 Reference parity: the multi-LoRA multiplexing surface of ray.llm
 (llm/_internal/serve — LoRA adapters resolved per request and multiplexed
-across replicas; vLLM applies them in-kernel). TPU-first difference: XLA
-pre-compiles the serving programs for fixed weight shapes, so adapters
-are MERGED into a param copy at load time (W' = W + (alpha/r)·A@B) and
-multiplexing picks the engine built for that merged copy — zero per-token
-overhead, at the cost of one weight copy per resident adapter (bounded by
-the server's adapter LRU).
+across replicas; vLLM applies them in-kernel). Serving applies adapters
+per row from the engine's slot table (llm/multilora/, every tenant in one
+dispatch). This module holds the adapter format and `merge`
+(W' = W + (alpha/r)·A@B into a param copy): the single-tenant oracle the
+slot-table path is tested against, and what LoRA training differentiates
+through.
 
-Adapter format: npz with arrays ``<path>.A`` [L, d_in, r] and ``<path>.B``
-[L, r, d_out] for each target in ("wq", "wk", "wv", "wo", "lm_head"),
-plus scalars ``rank`` and ``alpha``.
+Adapter format: a dict of arrays ``<path>.A`` [L, d_in, r] and
+``<path>.B`` [L, r, d_out] for each target in ("wq", "wk", "wv", "wo",
+"lm_head"), plus scalars ``rank`` and ``alpha``; adapter_to_bytes /
+adapter_from_bytes give its npz form.
 """
 from __future__ import annotations
 
 import io
-import os
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -57,18 +56,6 @@ def random_adapter(rng: jax.Array, cfg: llama.LlamaConfig, rank: int = 4,
         out[f"{t}.B"] = np.asarray(jax.random.normal(
             kb, lead + (rank, shapes[1])) * 0.05, np.float32)
     return out
-
-
-def save_adapter(adapter: dict, path: str) -> None:
-    os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-    np.savez(path, **adapter)
-
-
-def load_adapter(path: str) -> dict:
-    if not path.endswith(".npz"):
-        path += ".npz"
-    with np.load(path) as z:
-        return {k: z[k] for k in z.files}
 
 
 def adapter_to_bytes(adapter: dict) -> bytes:

@@ -51,7 +51,7 @@ class MultiLoraManager:
     def __init__(self, engine, registry: Optional[AdapterRegistry] = None,
                  namespace: str = "default",
                  refresh_s: Optional[float] = None):
-        if getattr(engine, "lora", None) is None:
+        if engine.lora is None:
             raise ValueError("engine has no adapter slot table "
                              "(PagedEngineConfig.max_adapters == 0)")
         self.engine = engine

@@ -148,9 +148,8 @@ def _run_loop(tcfg: LoRATrainConfig, base_params, data_fn,
 
 
 def _as_published(tcfg: LoRATrainConfig, adapter_arrays: dict) -> dict:
-    """Trained factors -> the llm/lora.py npz adapter format (what the
-    registry stores, the merged engine merges, and the slot table
-    loads)."""
+    """Trained factors -> the llm/lora.py adapter format (what the
+    registry stores, lora.merge merges, and the slot table loads)."""
     return {"rank": np.int32(tcfg.rank), "alpha": np.float32(tcfg.alpha),
             **{k: np.asarray(v, np.float32)
                for k, v in adapter_arrays.items()}}

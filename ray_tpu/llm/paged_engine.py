@@ -1,4 +1,5 @@
-"""Paged-KV continuous-batching engine (the production serving path).
+"""Paged-KV continuous-batching engine: the one engine of the serving,
+batch-inference and PD paths.
 
 vLLM-analog re-designed for XLA (reference role:
 llm/_internal/serve/deployments/llm/vllm/vllm_engine.py:180): the KV cache
@@ -48,7 +49,7 @@ from ..util.compile_cache import enable_compile_cache
 from ..util.profiling import StepProfiler, phase
 from . import telemetry
 from .engine import (  # noqa: F401 — SamplingParams re-exported
-    SamplingParams, _EngineBase, _Request, sample_logits_batch,
+    SamplingParams, _Request, sample_logits_batch,
 )
 from .tokenizer import get_tokenizer
 
@@ -177,8 +178,7 @@ class PagedEngineConfig:
 # (util/profiling.phase). The rtpu.engine.* phases partition step();
 # the rtpu.loop.* phases are LLMServer._loop's time outside step():
 # idle is the wait for work alone, other is everything else. The ten
-# sum to the thread's wall time where the server steps this engine
-# only (a per-LoRA engine's step() is booked to that engine's stats).
+# sum to the thread's wall time.
 PHASES = {
     "ns_admit": "rtpu.engine.admit",
     "ns_prefill_build": "rtpu.engine.prefill.build",
@@ -193,8 +193,9 @@ PHASES = {
 }
 
 
-class PagedInferenceEngine(_EngineBase):
-    """Synchronous paged engine; serving runs it on a background thread."""
+class PagedInferenceEngine:
+    """Synchronous paged engine; serving runs it on a background thread
+    (reference: the engine-loop surface of VLLMEngine)."""
 
     telemetry_kind = "paged"
 
@@ -800,8 +801,80 @@ class PagedInferenceEngine(_EngineBase):
                     rb <<= 1
         return _time.perf_counter() - t0
 
+    # -- request intake and results -----------------------------------------
+
+    def generate(self, prompts, params=None) -> list[dict]:
+        """Blocking batch generation; returns [{text, token_ids,
+        prompt_tokens, ttft_s, finish_reason}] in prompt order."""
+        if params is None:
+            params = SamplingParams()
+        plist = params if isinstance(params, list) else \
+            [params] * len(prompts)
+        reqs = [self.submit(p, sp) for p, sp in zip(prompts, plist)]
+        self.run_until_done(reqs)
+        return [self._result(r) for r in reqs]
+
+    def submit(self, prompt, params: SamplingParams,
+               adapter_slot: int = 0,
+               prefix_salt: bytes = b"") -> _Request:
+        ids = (self.tokenizer.encode(prompt) if isinstance(prompt, str)
+               else list(prompt))
+        # keep the prompt (up to the cache capacity) and clamp max_tokens
+        # to the remaining room — never silently discard the prompt
+        ids = ids[: self.cfg.max_seq_len - 2]
+        if not ids:
+            raise ValueError("empty prompt")
+        if adapter_slot:
+            if self.lora is None:
+                raise ValueError(
+                    "adapter_slot requires "
+                    "PagedEngineConfig.max_adapters > 0")
+            if not 0 < adapter_slot < self.lora.max_adapters:
+                raise ValueError(
+                    f"adapter_slot {adapter_slot} outside the slot "
+                    f"table [1, {self.lora.max_adapters})")
+        capacity = self.cfg.max_seq_len - 1 - len(ids)
+        if params.max_tokens > capacity:
+            params = dataclasses.replace(params,
+                                         max_tokens=max(1, capacity))
+        with self._lock:
+            req = _Request(self._next_rid, ids, params)
+            req.adapter_slot = int(adapter_slot)
+            req.prefix_salt = bytes(prefix_salt)
+            req.submit_t = time.perf_counter()
+            self._next_rid += 1
+            # stamp trace/request identity BEFORE publishing: once req is
+            # in _pending a concurrently stepping engine thread can retire
+            # a short request and emit its span/metrics immediately
+            telemetry.on_submit(self, req)
+            self._pending.append(req)
+        return req
+
     def has_work(self) -> bool:
         return bool(self._pending or self._prefilling or self._active)
+
+    def run_until_done(self, reqs: list[_Request]):
+        while not all(r.done for r in reqs):
+            self.step()
+
+    def _eos_id(self):
+        return getattr(self.tokenizer, "eos_id",
+                       getattr(self.tokenizer, "eos_token_id", None))
+
+    def _result(self, req: _Request) -> dict:
+        eos = getattr(self.tokenizer, "eos_id", None)
+        trimmed = [t for t in req.out_ids if t != eos]
+        return {
+            "text": self.tokenizer.decode(trimmed),
+            "token_ids": req.out_ids,
+            "prompt_tokens": len(req.prompt_ids),
+            "ttft_s": (req.first_token_t - req.submit_t
+                       if req.first_token_t else None),
+            "finish_reason": ("stop" if eos is not None and eos in req.out_ids
+                              else "length"),
+            "logprobs": (list(req.out_logps) if req.params.logprobs
+                         and req.out_logps else None),
+        }
 
     # -- page allocation ---------------------------------------------------
 
@@ -1617,6 +1690,15 @@ class PagedInferenceEngine(_EngineBase):
         return (len(req.out_ids) >= req.params.max_tokens
                 or tok == self._eos_id() or tok in req.params.stop_token_ids
                 or total >= self.cfg.max_seq_len - 1)
+
+    def _finish_request(self, req: _Request, finish=None):
+        """Retire a request: mark done, wake waiters, emit telemetry
+        (TTFT/ITL/e2e observations + the request's trace span)."""
+        if req.done:
+            return
+        req.done = True
+        req.event.set()
+        telemetry.on_finish(self, req, finish)
 
     def _retire(self, req: _Request):
         self._finish_request(req)

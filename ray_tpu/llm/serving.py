@@ -16,10 +16,10 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time as _time
-from typing import Any, Optional
+from typing import Optional
 
 from ..util.profiling import phase
-from .engine import EngineConfig, InferenceEngine, SamplingParams
+from .engine import SamplingParams
 from .paged_engine import PHASES, PagedEngineConfig, PagedInferenceEngine
 
 
@@ -27,34 +27,25 @@ from .paged_engine import PHASES, PagedEngineConfig, PagedInferenceEngine
 class LLMConfig:
     """(reference: llm/_internal/serve/configs/server_models.py LLMConfig)
 
-    `engine` may be an EngineConfig (dense slot cache) or a
-    PagedEngineConfig (paged-KV continuous batching — the production path);
-    the default is paged.
+    A replica runs ONE engine, built from ``engine`` (default: a paged
+    engine over ``llama_tiny``).
 
-    LoRA, two modes:
-
-    - **batched multi-LoRA** (production multi-tenant path): a
-      PagedEngineConfig with ``max_adapters > 0`` serves every adapter
-      from ONE engine — a request carrying ``"lora": "<id>"`` (or
-      ``model="<model_id>:<id>"``) resolves the adapter's latest
-      version in the AdapterRegistry (namespace ``lora_namespace``,
-      default the model_id) at admission, rides a resident slot-table
-      row, and shares the decode dispatch with every other tenant.
-      Hot-swap: a newly published version starts serving within
-      cfg.llm_lora_refresh_s, in-flight requests finish on their
-      admitted version. Prefix-cache keys are salted per
-      (adapter_id, version), so warmed prefixes never cross tenants.
-    - **merged engines** (legacy / single-tenant): ``lora_dir`` holds
-      ``<adapter_id>.npz`` adapters (llm/lora.py format) merged into a
-      full param copy each, one engine per resident adapter, LRU up to
-      ``max_loras``. Also the parity oracle for the batched path."""
+    LoRA: a PagedEngineConfig with ``max_adapters > 0`` serves every
+    adapter from that one engine — a request carrying ``"lora": "<id>"``
+    (or ``model="<model_id>:<id>"``) resolves the adapter's latest
+    version in the AdapterRegistry (namespace ``lora_namespace``,
+    default the model_id) at admission, rides a resident slot-table
+    row, and shares the decode dispatch with every other tenant.
+    Hot-swap: a newly published version starts serving within
+    cfg.llm_lora_refresh_s, in-flight requests finish on their admitted
+    version. Prefix-cache keys are salted per (adapter_id, version), so
+    warmed prefixes never cross tenants. With ``max_adapters == 0`` a
+    request that names a LoRA is refused."""
     model_id: str = "llama-tiny"
-    engine: Optional[EngineConfig | PagedEngineConfig] = None
+    engine: Optional[PagedEngineConfig] = None
     num_replicas: int = 1
     max_ongoing_requests: int = 64
     tpus_per_replica: float = 0.0
-    lora_dir: Optional[str] = None
-    max_loras: int = 2
     # registry namespace for batched multi-LoRA (None -> model_id)
     lora_namespace: Optional[str] = None
     # compile every engine program family at replica init, before the
@@ -70,8 +61,6 @@ class LLMServer:
     (reference: llm_server.py:409)."""
 
     def __init__(self, cfg: LLMConfig, params_ref=None):
-        from collections import OrderedDict
-
         from ..core.usage import record_library_usage
         record_library_usage("llm")
 
@@ -84,14 +73,7 @@ class LLMServer:
             import ray_tpu
             params = ray_tpu.get(params_ref)
         self.engine = self._build_engine(params)
-        self.base_params = self.engine.params
         self.model_id = cfg.model_id
-        # adapter-id -> engine over merged weights (lora.py docstring);
-        # OrderedDict is the LRU. _lora_lock guards every mutation AND the
-        # loop's snapshot: request threads (max_concurrency) race the
-        # engine thread here
-        self._lora_engines: "OrderedDict[str, Any]" = OrderedDict()
-        self._lora_lock = threading.Lock()
         self._wake = threading.Event()
         self._stop = False
         self._last_rewarm = 0.0   # spill-tier re-warm cadence (loop)
@@ -101,15 +83,12 @@ class LLMServer:
         # concurrent scatter/gather would read deleted buffers — same
         # contract as pd_disagg's _steplock around import_prefill)
         self._steplock = threading.Lock()
-        # cluster prefix directory (serve/frontdoor/prefix.py): base
-        # paged engine only — LoRA-merged engines produce different KV
-        # for the same tokens and must stay out of the shared-by-model
-        # directory. The controller injects this replica's own handle
-        # via set_replica_handle; publishing starts then.
+        # cluster prefix directory (serve/frontdoor/prefix.py). The
+        # controller injects this replica's own handle via
+        # set_replica_handle; publishing starts then.
         self._prefix_dir = None
         from ..core.config import cfg as rcfg
-        if rcfg.serve_prefix_directory and \
-                getattr(self.engine, "_prefix_on", False):
+        if rcfg.serve_prefix_directory and self.engine._prefix_on:
             from ..serve.frontdoor.prefix import PrefixDirectoryClient
             self._prefix_dir = PrefixDirectoryClient(cfg.model_id)
             self.engine.track_page_publish = True
@@ -117,7 +96,7 @@ class LLMServer:
         # The manager resolves adapter ids to resident slot-table rows
         # at admission; version pinning, LRU and hot-swap live there.
         self._multilora = None
-        if getattr(self.engine, "lora", None) is not None:
+        if self.engine.lora is not None:
             from .multilora import AdapterRegistry, MultiLoraManager
             self._multilora = MultiLoraManager(
                 self.engine,
@@ -126,19 +105,13 @@ class LLMServer:
         self._thread.start()
 
     def _build_engine(self, params):
-        if isinstance(self.engine_cfg, PagedEngineConfig):
-            eng = PagedInferenceEngine(self.engine_cfg, params)
-            if self.cfg.warmup:
-                modes = [(False, False)]
-                if self.cfg.warmup_sampled:
-                    modes += [(True, False), (True, True)]
-                eng.warmup(sample_modes=tuple(modes))
-            return eng
-        return InferenceEngine(self.engine_cfg, params)
-
-    def _engines(self):
-        with self._lora_lock:
-            return [self.engine, *self._lora_engines.values()]
+        eng = PagedInferenceEngine(self.engine_cfg, params)
+        if self.cfg.warmup:
+            modes = [(False, False)]
+            if self.cfg.warmup_sampled:
+                modes += [(True, False), (True, True)]
+            eng.warmup(sample_modes=tuple(modes))
+        return eng
 
     @staticmethod
     def _lora_id(request: dict) -> Optional[str]:
@@ -148,77 +121,34 @@ class LLMServer:
             lora_id = model.split(":", 1)[1]
         return lora_id or None
 
-    def _engine_for(self, request: dict):
-        """Pick the engine for a request's LoRA id (None -> base)."""
-        lora_id = self._lora_id(request)
-        if not lora_id:
-            return self.engine
-        with self._lora_lock:
-            eng = self._lora_engines.get(lora_id)
-            if eng is not None:
-                self._lora_engines.move_to_end(lora_id)
-                return eng
-        if not self.cfg.lora_dir:
-            raise ValueError(
-                f"request names LoRA {lora_id!r} but this deployment has "
-                f"no lora_dir configured")
-        import os
-
-        from . import lora
-        path = os.path.join(self.cfg.lora_dir, lora_id)
-        adapter = lora.load_adapter(path)
-        merged = lora.merge(self.base_params, adapter)
-        eng = self._build_engine(merged)
-        with self._lora_lock:
-            raced = self._lora_engines.get(lora_id)
-            if raced is not None:  # another thread built it concurrently
-                return raced
-            self._lora_engines[lora_id] = eng
-            # evict only IDLE engines: evicting one with in-flight
-            # requests would orphan them (their events never fire); if
-            # everything is busy, temporarily exceed the cap and retry on
-            # the next load
-            if len(self._lora_engines) > self.cfg.max_loras:
-                for lid in list(self._lora_engines):
-                    if lid == lora_id:
-                        continue
-                    if not self._lora_engines[lid].has_work():
-                        del self._lora_engines[lid]  # KV pool freed
-                        if len(self._lora_engines) <= self.cfg.max_loras:
-                            break
-        return eng
-
     def _loop(self):
-        # this thread's time outside step() goes to the base engine's
-        # stats beside step()'s own phases (paged_engine.PHASES), so
-        # that the ten sum to the thread's wall time: rtpu.loop.idle is
-        # the wait for work and nothing else, rtpu.loop.other the rest
-        # (steplock, prefix-directory publish, rewarm). A per-LoRA
-        # engine books its step() to its own stats, so the sum holds
-        # for a server whose only engine is the base one.
-        st = self.engine.stats
+        # this thread's time outside step() goes to the engine's stats
+        # beside step()'s own phases (paged_engine.PHASES), so that the
+        # ten sum to the thread's wall time: rtpu.loop.idle is the wait
+        # for work and nothing else, rtpu.loop.other the rest (steplock,
+        # prefix-directory publish, rewarm).
+        eng = self.engine
+        st = eng.stats
         other, idle = (
             (key, PHASES[key]) for key in ("ns_loop_other", "ns_loop_idle"))
         try:
             while not self._stop:
-                worked = False
-                for eng in self._engines():
-                    if eng.has_work():
-                        with phase(st, *other):
-                            self._steplock.acquire()
-                        try:
-                            eng.step()
-                        finally:
-                            self._steplock.release()
-                        worked = True
+                worked = eng.has_work()
+                if worked:
+                    with phase(st, *other):
+                        self._steplock.acquire()
+                    try:
+                        eng.step()
+                    finally:
+                        self._steplock.release()
                 with phase(st, *other):
                     if self._prefix_dir is not None:
                         # drain newly published/evicted page hashes to
                         # the cluster directory (rate-limited inside;
                         # this IS the stepping thread, per the drain
                         # contract)
-                        self._prefix_dir.maybe_publish(self.engine)
-                    if getattr(self.engine, "spill", None) is not None:
+                        self._prefix_dir.maybe_publish(eng)
+                    if eng.spill is not None:
                         now = _time.monotonic()
                         if now - self._last_rewarm >= 0.25:
                             # proactive promote of the hottest spilled
@@ -229,14 +159,12 @@ class LLMServer:
                             # contract).
                             self._last_rewarm = now
                             with self._steplock:
-                                self.engine.maybe_rewarm(max_pages=32)
+                                eng.maybe_rewarm(max_pages=32)
                 if not worked:
                     with phase(st, *other):
                         # nothing follows the last dispatch: its tokens
                         # are sent now
-                        for eng in self._engines():
-                            if hasattr(eng, "_notify_launch"):
-                                eng._notify_launch()
+                        eng._notify_launch()
                     with phase(st, *idle):
                         self._wake.wait(timeout=0.05)
                         self._wake.clear()
@@ -244,11 +172,9 @@ class LLMServer:
             self._error = e
             # unblock every waiter; completions() re-raises the error, and
             # check_health makes the controller replace this replica
-            for eng in self._engines():
-                for req in (list(eng._active.values())
-                            + list(eng._pending)
-                            + list(getattr(eng, "_prefilling", []))):
-                    req.event.set()
+            for req in (list(eng._active.values()) + list(eng._pending)
+                        + list(eng._prefilling)):
+                req.event.set()
 
     # -- OpenAI-ish surface ------------------------------------------------
 
@@ -260,18 +186,32 @@ class LLMServer:
             top_k=int(request.get("top_k", 0)),
             logprobs=int(request.get("logprobs") or 0),
         )
+        eng = self.engine
+        # tokenize ONCE: the prefix-directory lookup and submit share
+        # the ids (a second encode of a long system prompt would tax
+        # exactly the workloads the directory accelerates)
+        prompt = (eng.tokenizer.encode(prompt)
+                  if isinstance(prompt, str) else list(prompt))
+        # base traffic: row 0 of the slot table (a no-op), unsalted
+        # prefix-cache keys
+        slot, salt = 0, b""
         lora_id = self._lora_id(request)
-        if lora_id and self._multilora is not None:
+        if lora_id is not None:
+            if self._multilora is None:
+                raise ValueError(
+                    f"request names LoRA {lora_id!r} but this deployment's "
+                    f"engine has no adapter slot table: set "
+                    f"PagedEngineConfig.max_adapters > 0")
             # batched multi-LoRA: resolve the adapter's latest version
             # at ADMISSION (in-flight requests stay pinned to it), ride
             # a slot-table row on the shared engine, and salt every
             # prefix-cache key with (adapter_id, version). pin=True
-            # holds the slot against eviction across the tokenize +
-            # prefix-import window below — the engine's own in-flight
-            # accounting starts only at submit(). Errors stay TYPED:
-            # unknown adapter -> ValueError (client error), all slots
-            # live -> RuntimeError("overloaded: ...") the proxy turns
-            # into a retryable 503, never a bare 500.
+            # holds the slot against eviction across the prefix-import
+            # window below — the engine's own in-flight accounting
+            # starts only at submit(). Errors stay TYPED: unknown
+            # adapter -> ValueError (client error), all slots live ->
+            # RuntimeError("overloaded: ...") the proxy turns into a
+            # retryable 503, never a bare 500.
             try:
                 slot, _version, salt = self._multilora.resolve(
                     lora_id, self._steplock, pin=True)
@@ -279,59 +219,28 @@ class LLMServer:
                 raise ValueError(
                     f"unknown LoRA adapter {lora_id!r} for model "
                     f"{self.model_id!r}: {e}") from e
-            eng = self.engine
-            try:
-                prompt = (eng.tokenizer.encode(prompt)
-                          if isinstance(prompt, str) else list(prompt))
-                if self._prefix_dir is not None:
-                    # tenant-salted hashes: directory entries for this
-                    # (adapter_id, version) can only match its own pages
-                    self._prefix_dir.maybe_import(eng, self._steplock,
-                                                  prompt, salt=salt)
-                req = eng.submit(prompt, sp, adapter_slot=slot,
-                                 prefix_salt=salt)
-            finally:
+        try:
+            if self._prefix_dir is not None:
+                # cluster prefix directory: admission-match a prefix
+                # warmed on ANY replica by importing its KV pages before
+                # submit — best effort, a miss/failure just means a cold
+                # prefill. The hashes carry the tenant's salt: an entry
+                # for this (adapter_id, version) can only match its own
+                # pages
+                self._prefix_dir.maybe_import(eng, self._steplock,
+                                              prompt, salt=salt)
+            req = eng.submit(prompt, sp, adapter_slot=slot,
+                             prefix_salt=salt)
+        finally:
+            if lora_id is not None:
                 self._multilora.unpin(slot)
-            self._wake.set()
-            return eng, req
-        eng = self._engine_for(request)
-        # tokenize ONCE: the prefix-directory lookup and submit share
-        # the ids (a second encode of a long system prompt would tax
-        # exactly the workloads the directory accelerates)
-        prompt = (eng.tokenizer.encode(prompt)
-                  if isinstance(prompt, str) else list(prompt))
-        if self._prefix_dir is not None and eng is self.engine:
-            # cluster prefix directory: admission-match a prefix warmed
-            # on ANY replica by importing its KV pages before submit —
-            # best effort, a miss/failure just means a cold prefill
-            self._prefix_dir.maybe_import(eng, self._steplock, prompt)
-        if sp.logprobs and not hasattr(eng, "_prefill_rows_fns"):
-            # dense InferenceEngine never fills out_logps: refuse loudly
-            # instead of returning a well-formed response missing the
-            # requested field (paged engine is the production path)
-            raise ValueError(
-                "logprobs requires the paged engine "
-                "(LLMConfig(engine=PagedEngineConfig(...)))")
-        # submit UNDER the lora lock: eviction (also lock-guarded) only
-        # removes idle engines, so once submit lands the engine has work
-        # and cannot be evicted out from under this request; re-insert if
-        # an eviction won the race between selection and here
-        with self._lora_lock:
-            if eng is not self.engine:
-                lora_id = next((lid for lid, e in self._lora_engines.items()
-                                if e is eng), None)
-                if lora_id is None:
-                    rid = request.get("lora") or request.get(
-                        "model", ":").split(":", 1)[1]
-                    self._lora_engines[rid] = eng
-            req = eng.submit(prompt, sp)
         self._wake.set()
-        return eng, req
+        return req
 
     def completions(self, request: dict) -> dict:
         """{"prompt": str, "max_tokens": int, "temperature": float,
         "lora": str, ...} -> completions response."""
-        eng, req = self._submit(request)
+        eng, req = self.engine, self._submit(request)
         while not req.event.wait(timeout=1.0):
             if self._error is not None:
                 raise RuntimeError("llm engine loop died") from self._error
@@ -376,18 +285,17 @@ class LLMServer:
         """Generator of token-delta dicts while the engine decodes
         (reference: the streaming response path of llm_server.py; pairs
         with handle.options(stream=True) / the SSE proxy path)."""
-        import time as _time
-        eng, req = self._submit(request)
+        eng, req = self.engine, self._submit(request)
         sent = 0
         last_text = ""
-        # a paged engine says when it has launched a dispatch: sleep on
+        # the engine says when it has launched a dispatch: sleep on
         # that, not on a 50 Hz poll, so that this thread's work runs
         # beside the device's and not in the stepping thread's way
-        launched = getattr(eng, "launched", None)
+        launched = eng.launched
         while True:
             if self._error is not None and not req.done:
                 raise RuntimeError("llm engine loop died") from self._error
-            gen = getattr(eng, "launch_gen", 0)
+            gen = eng.launch_gen
             n = len(req.out_ids)
             if n > sent:
                 text = eng.tokenizer.decode(list(req.out_ids))
@@ -400,9 +308,6 @@ class LLMServer:
                                         "finish_reason": None}]}
             if req.done:
                 break
-            if launched is None:
-                req.event.wait(timeout=0.02)
-                continue
             with launched:
                 # the timeout bounds the wait when no dispatch follows
                 # (the engine went idle, or its loop died)
@@ -426,13 +331,13 @@ class LLMServer:
         the cached KV pages for `hashes` (a chain run) to host arrays.
         None when nothing is cached any more — the caller treats the
         directory entry as stale and prefills cold."""
-        if not getattr(self.engine, "_prefix_on", False):
+        if not self.engine._prefix_on:
             return None
         with self._steplock:
             return self.engine.export_prefix(list(hashes))
 
     def engine_stats(self) -> dict:
-        """Counter snapshot for ops introspection: the base engine's
+        """Counter snapshot for ops introspection: the engine's
         stats dict (the stepping thread's ``ns_*`` phase times among
         them) plus the resolved mesh axis sizes (None single-chip).
         On a mesh, ``mesh_reshard_bytes`` staying 0 IS the steady-state
@@ -441,11 +346,11 @@ class LLMServer:
         import jax
 
         from ..util.compile_cache import compile_cache_stats
-        st = dict(getattr(self.engine, "stats", {}) or {})
+        st = dict(self.engine.stats)
         # the ns_* counters' clock at this snapshot: between two
         # snapshots their deltas sum to this one's
         st["clock_ns"] = _time.perf_counter_ns()
-        mesh = getattr(self.engine, "mesh", None)
+        mesh = self.engine.mesh
         st["mesh"] = None if mesh is None else {
             k: int(v) for k, v in mesh.shape.items()}
         # what this replica's process really runs on, as JAX reports it:
@@ -455,18 +360,16 @@ class LLMServer:
                         "kind": devs[0].device_kind, "count": len(devs)}
         st["memory"] = [d.memory_stats() for d in devs]
         st["compile_cache"] = compile_cache_stats()
-        if hasattr(self.engine, "profile_summary"):
-            st["profile"] = self.engine.profile_summary()
+        st["profile"] = self.engine.profile_summary()
         return st
 
     def loaded_loras(self) -> list:
-        """Resident adapters: merged-engine ids plus the slot table's
-        (adapter_id, version) pairs."""
-        out = list(self._lora_engines)
-        if self._multilora is not None:
-            out.extend(f"{aid}@{v}" for aid, v in
-                       self._multilora.resident().values())
-        return out
+        """Resident adapters: the slot table's ``<adapter_id>@<version>``
+        pairs (empty without a slot table)."""
+        if self._multilora is None:
+            return []
+        return [f"{aid}@{v}" for aid, v in
+                self._multilora.resident().values()]
 
     def __call__(self, request: dict) -> dict:
         return self.completions(request or {})

@@ -8,13 +8,13 @@ hooks put those series on the head's `/metrics` via the existing
 util/metrics.py delta-flush — zero new transport, and a no-op overhead of
 a few dict updates per engine step.
 
-Every metric carries an ``engine`` label ("paged" / "dense") so mixed
-deployments stay separable; gauges additionally carry a ``proc``
-(host:pid) label because they are last-write-wins on the head — without
-it, replicas of the same engine kind would overwrite each other. When tracing is enabled each request also
-emits one ``llm.request`` span parented to whatever span submitted it
-(the serve replica's task span when the request came through Serve), so
-a proxy -> replica -> engine request renders as one stitched tree in
+Every metric carries an ``engine`` label (``telemetry_kind``, "paged");
+gauges additionally carry a ``proc`` (host:pid) label because they are
+last-write-wins on the head — without it, replicas would overwrite each
+other. When tracing is enabled each request also emits one
+``llm.request`` span parented to whatever span submitted it (the serve
+replica's task span when the request came through Serve), so a proxy
+-> replica -> engine request renders as one stitched tree in
 ``ray_tpu.timeline()``; under it, with the same ``trace_id`` and
 ``request_id``, three children end to end: ``llm.queue`` (submit ->
 admit), ``llm.prefill`` (admit -> first token) and ``llm.decode`` (first
@@ -27,7 +27,7 @@ Metric names (all prefixed ``rtpu_llm_``):
   queue_wait_seconds     histogram  submit -> admission into the batch
   e2e_seconds            histogram  submit -> request retired
   batch_occupancy        gauge      active slots / max_batch_size
-  kv_utilization         gauge      KV pages in use / pool size (paged)
+  kv_utilization         gauge      KV pages in use / pool size
   pending_requests       gauge      submitted, not yet admitted
   prefilling_requests    gauge      admitted, prompt not fully prefilled
   decoding_requests      gauge      in the decode set
@@ -143,7 +143,7 @@ def _never_raise(fn):
 
 
 # --------------------------------------------------------------------- #
-# hooks (called by engine.py / paged_engine.py)
+# hooks (called by paged_engine.py)
 # --------------------------------------------------------------------- #
 
 @_never_raise
@@ -232,47 +232,41 @@ def on_step(engine) -> None:
         len(engine._pending), tags=gtags)
     _gauge("rtpu_llm_decoding_requests", "requests in the decode set").set(
         len(engine._active), tags=gtags)
-    prefilling = getattr(engine, "_prefilling", None)
-    if prefilling is not None:
-        _gauge("rtpu_llm_prefilling_requests",
-               "admitted, prompt not fully prefilled").set(
-            len(prefilling), tags=gtags)
-    free = getattr(engine, "_free_pages", None)
-    if free is not None:
-        pool = cfg.num_pages - 1  # page 0 is the write sink
-        # cached (unreferenced, prefix-reusable) pages are reclaimable on
-        # demand: they count as capacity, not utilization — a warm cache
-        # must not read as a saturated pool
-        cached = len(getattr(engine, "_cached_lru", ()))
-        _gauge("rtpu_llm_kv_utilization",
-               "KV pages in use / pool size").set(
-            (pool - len(free) - cached) / max(pool, 1), tags=gtags)
-        if getattr(engine, "_prefix_on", False):
-            # single accounting source (paged_engine.prefix_accounting):
-            # the gauges here, pool_stats() and metrics_summary() must
-            # agree by construction, not by parallel bookkeeping
-            acct = engine.prefix_accounting()
-            _gauge("rtpu_llm_prefix_cached_pages",
-                   "unreferenced KV pages retained for prefix reuse").set(
-                acct["cached_pages"], tags=gtags)
-            if acct["hits"] + acct["misses"]:
-                _gauge("rtpu_llm_prefix_cache_hit_rate",
-                       "prefix cache hits / (hits + misses)").set(
-                    acct["hit_rate"], tags=gtags)
-            if getattr(engine, "spill", None) is not None:
-                # tier-resident gauges: what the host tier holds NOW
-                # (same accounting snapshot as the counters above)
-                _gauge("rtpu_llm_prefix_spill_resident_pages",
-                       "prefix pages resident in the host spill "
-                       "tier").set(
-                    acct["spill_resident_pages"], tags=gtags)
-                _gauge("rtpu_llm_prefix_spill_resident_bytes",
-                       "bytes resident in the host spill tier").set(
-                    acct["spill_resident_bytes"], tags=gtags)
-    stats = getattr(engine, "stats", None)
-    if stats:
-        _ship_stat_deltas(engine, stats, tags)
-    if getattr(engine, "chains", None) is not None:
+    _gauge("rtpu_llm_prefilling_requests",
+           "admitted, prompt not fully prefilled").set(
+        len(engine._prefilling), tags=gtags)
+    pool = cfg.num_pages - 1  # page 0 is the write sink
+    # cached (unreferenced, prefix-reusable) pages are reclaimable on
+    # demand: they count as capacity, not utilization — a warm cache
+    # must not read as a saturated pool
+    _gauge("rtpu_llm_kv_utilization",
+           "KV pages in use / pool size").set(
+        (pool - len(engine._free_pages) - len(engine._cached_lru))
+        / max(pool, 1), tags=gtags)
+    if engine._prefix_on:
+        # single accounting source (paged_engine.prefix_accounting):
+        # the gauges here, pool_stats() and metrics_summary() must
+        # agree by construction, not by parallel bookkeeping
+        acct = engine.prefix_accounting()
+        _gauge("rtpu_llm_prefix_cached_pages",
+               "unreferenced KV pages retained for prefix reuse").set(
+            acct["cached_pages"], tags=gtags)
+        if acct["hits"] + acct["misses"]:
+            _gauge("rtpu_llm_prefix_cache_hit_rate",
+                   "prefix cache hits / (hits + misses)").set(
+                acct["hit_rate"], tags=gtags)
+        if engine.spill is not None:
+            # tier-resident gauges: what the host tier holds NOW
+            # (same accounting snapshot as the counters above)
+            _gauge("rtpu_llm_prefix_spill_resident_pages",
+                   "prefix pages resident in the host spill "
+                   "tier").set(
+                acct["spill_resident_pages"], tags=gtags)
+            _gauge("rtpu_llm_prefix_spill_resident_bytes",
+                   "bytes resident in the host spill tier").set(
+                acct["spill_resident_bytes"], tags=gtags)
+    _ship_stat_deltas(engine, engine.stats, tags)
+    if engine.chains is not None:
         _ship_chain_stats(engine, gtags)
 
 

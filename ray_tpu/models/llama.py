@@ -18,7 +18,8 @@ on v5e). Design choices that are TPU-idiomatic rather than ports:
   (`_moe_ffn`, over ops.grouped_matmul). OLMoE-1B-7B is LlamaConfig with
   both (benchmarks/models/olmoe.py).
 
-Decode-time KV caching lives here too (used by the serving engine).
+The serving engine's forwards live here too: one cached family, over
+the paged KV pool (decode_paged, prefill_paged_rows, verify_paged_rows).
 """
 from __future__ import annotations
 
@@ -442,44 +443,19 @@ def _expert_ffn(h, idx, gate, w_gate, w_up, w_down, *, n_experts: int,
     return out.reshape(b, s, d)
 
 
-def _layer(x, layer_params, cfg: LlamaConfig, cos, sin, attn_impl,
-           kv_cache=None, cache_idx=None):
-    """One transformer block. x [B, S, D]. Returns (x, new_kv) where new_kv
-    is None in training mode."""
+def _layer(x, layer_params, cfg: LlamaConfig, cos, sin, attn_impl):
+    """One transformer block of the training forward. x [B, S, D].
+    Returns (x, aux)."""
     p = layer_params
     h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
     b, s, _ = h.shape
     q, k, v = _qkv(h, p, cfg, cos, sin)
-
-    new_kv = None
-    if kv_cache is not None:
-        ck, cv = kv_cache
-        ck = jax.lax.dynamic_update_slice_in_dim(ck, k, cache_idx, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(cv, v, cache_idx, axis=1)
-        new_kv = (ck, cv)
-        # decode: attend over the cache prefix. The causal mask k_pos <=
-        # q_pos also hides the not-yet-written cache tail (its positions
-        # exceed every query position).
-        k_pos = jnp.arange(ck.shape[1])                        # [K]
-        q_pos = cache_idx + jnp.arange(s)                      # [S]
-        mask = k_pos[None, :] <= q_pos[:, None]                # [S, K]
-        groups = cfg.n_heads // cfg.n_kv_heads
-        kr = jnp.repeat(ck, groups, axis=2)
-        vr = jnp.repeat(cv, groups, axis=2)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
-                            preferred_element_type=jnp.float32)
-        scores = scores * (cfg.head_dim ** -0.5)
-        scores = jnp.where(mask[None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(vr.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
-    else:
-        attn = _attention(q, k, v, cfg, causal=True, attn_impl=attn_impl)
-
+    attn = _attention(q, k, v, cfg, causal=True, attn_impl=attn_impl)
     attn = attn.reshape(b, s, cfg.n_heads * cfg.head_dim)
     x = x + attn @ p["wo"]
     x = constrain(x, ("batch", "sequence", "embed"))
     x, aux, _ = _mlp_block(x, p, cfg)
-    return x, aux, new_kv
+    return x, aux
 
 
 # ---------------------------------------------------------------------------
@@ -508,7 +484,7 @@ def apply_with_aux(params: dict, tokens: jax.Array, cfg: LlamaConfig,
 
     def body(carry, layer_params):
         x, aux = carry
-        y, a, _ = _layer(x, layer_params, cfg, cos, sin, attn_impl)
+        y, a = _layer(x, layer_params, cfg, cos, sin, attn_impl)
         return (y, aux + a), None
 
     if cfg.remat:
@@ -524,120 +500,6 @@ def apply_with_aux(params: dict, tokens: jax.Array, cfg: LlamaConfig,
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
     return logits, aux / cfg.n_layers
-
-
-def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int) -> dict:
-    shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
-            "idx": jnp.zeros((), jnp.int32)}
-
-
-def apply_decode(params: dict, tokens: jax.Array, cache: dict,
-                 cfg: LlamaConfig) -> tuple[jax.Array, dict]:
-    """Incremental forward with KV cache: tokens [B, S_step] appended at
-    cache['idx']. Returns (logits [B, S_step, V], updated cache)."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    positions = cache["idx"] + jnp.broadcast_to(
-        jnp.arange(tokens.shape[1]), tokens.shape)
-    cos, sin = rope_freqs(cfg, positions)
-
-    def body(x, scanned):
-        layer_params, kv = scanned
-        y, _, new_kv = _layer(x, layer_params, cfg, cos, sin, None,
-                              kv_cache=kv, cache_idx=cache["idx"])
-        return y, new_kv
-
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], (cache["k"], cache["v"])))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    new_cache = {"k": new_k, "v": new_v,
-                 "idx": cache["idx"] + tokens.shape[1]}
-    return logits, new_cache
-
-
-# ---------------------------------------------------------------------------
-# Continuous-batching cache (slot-based; used by the llm engine)
-# ---------------------------------------------------------------------------
-
-def init_slot_cache(cfg: LlamaConfig, max_batch: int, max_len: int) -> dict:
-    """Per-slot KV cache: each batch row is an independent request with its
-    own length (unlike init_kv_cache's single shared position)."""
-    shape = (cfg.n_layers, max_batch, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype),
-            "v": jnp.zeros(shape, cfg.dtype),
-            "lengths": jnp.zeros((max_batch,), jnp.int32)}
-
-
-def apply_with_kv(params: dict, tokens: jax.Array, cfg: LlamaConfig):
-    """Prefill forward returning per-layer rope'd K/V for cache seeding:
-    tokens [B, S] -> (logits [B, S, V], k/v [L, B, S, KVH, D])."""
-    x = params["embed"][tokens].astype(cfg.dtype)
-    positions = jnp.broadcast_to(jnp.arange(tokens.shape[1]), tokens.shape)
-    cos, sin = rope_freqs(cfg, positions)
-
-    def body(x, layer_params):
-        p = layer_params
-        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        b, s, _ = h.shape
-        q, k, v = _qkv(h, p, cfg, cos, sin)
-        attn = _attention(q, k, v, cfg, causal=True, attn_impl=None)
-        x = x + attn.reshape(b, s, -1) @ p["wo"]
-        x = constrain(x, ("batch", "sequence", "embed"))
-        x, _, _ = _mlp_block(x, p, cfg)
-        return x, (k, v)
-
-    x, (ks, vs) = jax.lax.scan(body, x, params["layers"])
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    return logits, ks, vs
-
-
-def decode_batched(params: dict, tokens: jax.Array, cache: dict,
-                   cfg: LlamaConfig) -> tuple[jax.Array, dict]:
-    """One decode step for a batch of independent slots.
-
-    tokens [B, 1] — next token per slot; cache rows advance at their own
-    `lengths`. Returns (logits [B, V], updated cache). Inactive slots should
-    carry any token; caller masks their outputs.
-    """
-    b = tokens.shape[0]
-    rows = jnp.arange(b)
-    x = params["embed"][tokens].astype(cfg.dtype)         # [B, 1, D]
-    positions = cache["lengths"][:, None]                 # [B, 1]
-    cos, sin = rope_freqs(cfg, positions)
-    k_pos = jnp.arange(cache["k"].shape[2])[None, :]      # [1, S]
-    mask = k_pos <= positions                             # [B, S]
-
-    def body(x, scanned):
-        p, (ck, cv) = scanned
-        h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(h, p, cfg, cos, sin)
-        ck = ck.at[rows, cache["lengths"]].set(k[:, 0].astype(ck.dtype))
-        cv = cv.at[rows, cache["lengths"]].set(v[:, 0].astype(cv.dtype))
-        groups = cfg.n_heads // cfg.n_kv_heads
-        kr = jnp.repeat(ck, groups, axis=2)
-        vr = jnp.repeat(cv, groups, axis=2)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kr,
-                            preferred_element_type=jnp.float32)
-        scores = scores * (cfg.head_dim ** -0.5)
-        scores = jnp.where(mask[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(vr.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vr)
-        x = x + attn.reshape(b, 1, -1) @ p["wo"]
-        x, _, _ = _mlp_block(x, p, cfg)
-        return x, (ck, cv)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        body, x, (params["layers"], (cache["k"], cache["v"])))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)[:, 0]
-    new_cache = {"k": new_k, "v": new_v, "lengths": cache["lengths"] + 1}
-    return logits, new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -671,7 +533,7 @@ def apply_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
 
     def stage_fn(stage_layers, h):
         def body(h, layer_params):
-            y, _, _ = _layer(h, layer_params, cfg, cos, sin, attn_impl)
+            y, _ = _layer(h, layer_params, cfg, cos, sin, attn_impl)
             return y, None
         h, _ = jax.lax.scan(body, h, stage_layers)
         return h
@@ -777,8 +639,9 @@ def decode_paged(params: dict, tokens: jax.Array, caches: list[dict],
     (and logits, for lm_head adapters) get its slot's low-rank delta, so
     ONE dispatch serves a mixed-tenant batch (see _lora_add).
     """
-    from ..ops.paged_attention import paged_decode_reference
-    from ..ops.ragged_paged_attention import ragged_decode_attention
+    from ..ops.ragged_paged_attention import (
+        paged_decode_reference, ragged_decode_attention,
+    )
 
     b = tokens.shape[0]
     rows = jnp.arange(b)

@@ -11,7 +11,7 @@ _EXPORTS = {
     "flash_attention": "flash_attention",
     "mha_reference": "flash_attention",
 }
-_MODULES = ("flash_attention", "paged_attention", "ragged_paged_attention")
+_MODULES = ("flash_attention", "ragged_paged_attention")
 
 __all__ = list(_EXPORTS) + list(_MODULES)
 

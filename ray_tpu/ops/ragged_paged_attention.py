@@ -40,8 +40,7 @@ the pool capacity:
   clamp repeats the last live page, at key positions the mask hides;
 * a streaming-softmax accumulator in VMEM scratch carries across the
   sweep (TPU grids iterate the last dimension fastest, so scratch
-  persists across one tile's sweep — same contract as
-  `ops/paged_attention._paged_decode_kernel`);
+  persists across one tile's sweep);
 * causal masking INSIDE the query window: key position ``k_pos`` (from
   the LOGICAL page index) is attended by query position ``q_pos = start
   + i`` iff ``k_pos <= q_pos`` and ``k_pos < kv_len`` — which covers the
@@ -70,9 +69,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# one masking constant for the whole paged family: the q_len=1
-# equivalence baseline (ops/paged_attention) must mask identically
-from .paged_attention import NEG_INF
+# one masking constant for the whole paged family: the kernel and both
+# jnp oracles below must mask identically
+NEG_INF = -1e30
 
 
 def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
@@ -296,8 +295,8 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
 def ragged_decode_attention(q, k_pages, v_pages, block_table, lengths,
                             *, scale: float | None = None,
                             interpret: bool = False):
-    """Decode as the q_len=1 degenerate case. Same contract as
-    ``ops.paged_attention.paged_decode_attention``: q [B, H, D],
+    """Decode as the q_len=1 degenerate case: q [B, H, D], k_pages /
+    v_pages [num_pages, page_size, KVH, D], block_table [B, max_pages],
     lengths [B] = tokens in cache INCLUDING the current step's (attend
     positions < length). Returns [B, H, D]."""
     lengths = lengths.astype(jnp.int32)
@@ -335,3 +334,32 @@ def ragged_paged_reference(q, k_pages, v_pages, block_tables, starts,
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("rhgqk,rkhd->rqhgd", w, v.astype(jnp.float32))
     return out.reshape(r, qw, h, d).astype(q.dtype)
+
+
+def paged_decode_reference(q, k_pages, v_pages, block_table, lengths,
+                           scale: float | None = None):
+    """Numerical oracle of the decode shape (jnp gather) and
+    llama.decode_paged's fallback off the TPU. Same contract as
+    ragged_decode_attention.
+
+    GQA runs as a grouped einsum against the ungathered-head K/V
+    (q reshaped [B, KVH, G, D]) — the head axes line up by construction
+    (query head h attends kv head h // G), so no O(groups) jnp.repeat
+    materialization of the gathered cache is ever built."""
+    b, h, d = q.shape
+    p_total, page_size, kvh, _ = k_pages.shape
+    groups = h // kvh
+    max_pages = block_table.shape[1]
+    if scale is None:
+        scale = d ** -0.5
+    # gather each sequence's pages -> [B, max_pages*page, KVH, D]
+    k = k_pages[block_table].reshape(b, max_pages * page_size, kvh, d)
+    v = v_pages[block_table].reshape(b, max_pages * page_size, kvh, d)
+    qg = q.reshape(b, kvh, groups, d).astype(jnp.float32)
+    s = jnp.einsum("bhgd,bkhd->bhgk", qg,
+                   k.astype(jnp.float32)) * scale
+    pos = jnp.arange(max_pages * page_size)[None, :]
+    s = jnp.where((pos < lengths[:, None])[:, None, None], s, NEG_INF)
+    w = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhgk,bkhd->bhgd", w,
+                      v.astype(jnp.float32)).reshape(b, h, d).astype(q.dtype)

@@ -58,11 +58,9 @@ from typing import Any, Optional
 
 
 class PrefixDirectoryClient:
-    """One per LLMServer replica, on the replica's PRIMARY paged engine.
+    """One per LLMServer replica, on the replica's engine.
 
-    LoRA-merged side engines stay out (different KV for the same
-    tokens, unsalted chains would collide). The batched multi-LoRA
-    path shares the primary engine safely: its requests hash with a
+    Adapter requests share that engine safely: they hash with a
     per-(adapter_id, version) salt (llm/multilora/manager.prefix_salt),
     so directory keys are tenant-scoped by construction — a hit can
     only come from the same adapter at the same version."""
@@ -127,7 +125,7 @@ class PrefixDirectoryClient:
         effort end to end — a store/put failure leaves pages staged
         and locally promotable; they re-register on a later cadence
         via materialize's already-stored reporting."""
-        tier = getattr(engine, "spill", None)
+        tier = engine.spill
         if tier is None:
             return {}, []
         try:

@@ -361,14 +361,15 @@ def test_offline_inference_token_parity(cluster, ctx):
     """The flagship driver: Dataset.map_batches(LLMPredictor, pool)
     through the streaming executor produces the EXACT tokens of direct
     engine calls (slow: builds a llama_tiny engine twice)."""
-    from ray_tpu.llm import EngineConfig, InferenceEngine, SamplingParams
+    from ray_tpu.llm import (PagedEngineConfig, PagedInferenceEngine,
+                             SamplingParams)
     from ray_tpu.llm.batch import LLMPredictor
     from ray_tpu.models import llama
 
     def ecfg():
-        return EngineConfig(model=llama.llama_tiny(max_seq_len=64),
-                            max_batch_size=2, max_seq_len=64,
-                            prefill_buckets=(16, 32))
+        return PagedEngineConfig(
+            model=llama.llama_tiny(max_seq_len=64), max_batch_size=2,
+            page_size=16, num_pages=32, max_pages_per_seq=4, chunk_size=32)
 
     prompts = [f"hello world {i}" for i in range(6)]
     sampling = SamplingParams(max_tokens=4)
@@ -379,7 +380,7 @@ def test_offline_inference_token_parity(cluster, ctx):
         fn_constructor_args=(ecfg(), sampling))
     rows = sorted(ds.take_all(), key=lambda r: r["prompt"])
 
-    engine = InferenceEngine(ecfg())
+    engine = PagedInferenceEngine(ecfg())
     direct = engine.generate(prompts, sampling)
     expect = {p: list(o["token_ids"]) for p, o in zip(prompts, direct)}
     for r in rows:

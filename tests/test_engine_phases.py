@@ -6,6 +6,7 @@ profiler session the phases are spans on the trace's host plane."""
 import re
 import time
 
+import jax
 import pytest
 
 from ray_tpu.llm import SamplingParams
@@ -76,10 +77,17 @@ def test_engine_phases_partition_the_step(engine):
     assert total >= 0.98 * wall, (total, wall)
 
 
-def test_loop_phases_through_a_local_server():
+@pytest.mark.parametrize("max_adapters", [0, 2])
+def test_loop_phases_through_a_local_server(max_adapters):
+    """The ten phases sum to the stepping thread's wall time on every
+    server: a replica steps one engine, and an adapter's request is a
+    row of that engine's dispatches (max_adapters > 0), not an engine
+    of its own."""
     from ray_tpu.llm.serving import LLMConfig, LLMServer
-    srv = LLMServer(LLMConfig(model_id="tiny-phases", engine=_cfg(),
-                              warmup=False))
+    model_id = f"tiny-phases-{max_adapters}"
+    srv = LLMServer(LLMConfig(
+        model_id=model_id, warmup=False,
+        engine=_cfg(max_adapters=max_adapters, lora_rank=4)))
     try:
         first = srv.engine_stats()
         assert "ns_loop_other" in first and "clock_ns" in first
@@ -96,6 +104,19 @@ def test_loop_phases_through_a_local_server():
         out = srv.completions({"prompt": list(range(1, 30)),
                                "max_tokens": 4})
         assert len(out["choices"][0]["token_ids"]) == 4
+        if max_adapters:
+            from ray_tpu.llm import lora
+            from ray_tpu.llm.multilora import AdapterRegistry
+            adapter = lora.random_adapter(
+                jax.random.PRNGKey(7), srv.engine.cfg.model, rank=4,
+                alpha=64.0, targets=("wq", "wv", "lm_head"))
+            AdapterRegistry(model_id).publish("tenant", adapter)
+            tuned = srv.completions({"prompt": list(range(1, 30)),
+                                     "max_tokens": 4, "lora": "tenant"})
+            assert len(tuned["choices"][0]["token_ids"]) == 4
+            assert tuned["choices"][0]["token_ids"] != \
+                out["choices"][0]["token_ids"]
+            assert srv.loaded_loras() == ["tenant@0"]
         after = srv.engine_stats()
         assert after["ns_loop_other"] > idle["ns_loop_other"]
         assert after["ns_decode_device"] > 0
@@ -206,8 +227,6 @@ def test_programs_carry_their_family_name():
 
 def test_phases_are_spans_on_the_profilers_host_plane(engine, tmp_path,
                                                       monkeypatch):
-    import jax
-
     from benchmarks.reduce import xplane
     # ``load`` keeps only the benchmark's own annotations; widening its
     # pattern by ``rtpu\.`` is a ``benchmark`` issue's edit (PERF.md §7).

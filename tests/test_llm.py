@@ -6,17 +6,22 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm import (
-    ByteTokenizer, EngineConfig, InferenceEngine, SamplingParams,
+    ByteTokenizer, PagedEngineConfig, PagedInferenceEngine, SamplingParams,
 )
 from ray_tpu.models import llama
 
 
+def _engine_cfg(max_seq_len: int, max_batch_size: int) -> PagedEngineConfig:
+    """A tiny paged engine: pages of 16, a sequence of max_seq_len."""
+    return PagedEngineConfig(
+        model=llama.llama_tiny(vocab_size=258, max_seq_len=max_seq_len),
+        max_batch_size=max_batch_size, page_size=16, num_pages=64,
+        max_pages_per_seq=max_seq_len // 16, chunk_size=32)
+
+
 @pytest.fixture(scope="module")
 def engine():
-    cfg = EngineConfig(
-        model=llama.llama_tiny(vocab_size=258, max_seq_len=128),
-        max_batch_size=4, max_seq_len=128, prefill_buckets=(16, 32, 64))
-    return InferenceEngine(cfg, rng_seed=0)
+    return PagedInferenceEngine(_engine_cfg(128, 4), rng_seed=0)
 
 
 @pytest.mark.slow
@@ -33,7 +38,7 @@ def test_greedy_matches_full_forward(engine):
     for _ in range(8):
         logits = llama.apply(engine.params,
                              np.asarray([ids], np.int32)[..., :],
-                             engine.model_cfg)
+                             engine.cfg.model)
         nxt = int(np.argmax(np.asarray(logits[0, -1])))
         want.append(nxt)
         ids.append(nxt)
@@ -76,10 +81,7 @@ def test_llm_serve_deployment(ray_start_regular):
 
     cfg = LLMConfig(
         model_id="tiny",
-        engine=EngineConfig(model=llama.llama_tiny(vocab_size=258,
-                                                   max_seq_len=64),
-                            max_batch_size=2, max_seq_len=64,
-                            prefill_buckets=(16, 32)))
+        engine=_engine_cfg(64, 2))
     app = build_llm_deployment(cfg)
     try:
         handle = serve.run(app, name="llm")
@@ -98,10 +100,7 @@ def test_batch_processor(ray_start_regular):
     from ray_tpu.llm.batch import ProcessorConfig, build_llm_processor
 
     proc = build_llm_processor(ProcessorConfig(
-        engine=EngineConfig(model=llama.llama_tiny(vocab_size=258,
-                                                   max_seq_len=64),
-                            max_batch_size=2, max_seq_len=64,
-                            prefill_buckets=(16, 32)),
+        engine=_engine_cfg(64, 2),
         sampling=SamplingParams(max_tokens=4)))
     ds = rd.from_items([{"prompt": "a"}, {"prompt": "b"}])
     out = proc(ds).take_all()

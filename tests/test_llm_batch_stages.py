@@ -5,7 +5,7 @@ import pytest
 
 import ray_tpu
 from ray_tpu import data as rdata
-from ray_tpu.llm import EngineConfig, SamplingParams
+from ray_tpu.llm import PagedEngineConfig, SamplingParams
 from ray_tpu.llm.batch import (ChatTemplateStage, DetokenizeStage,
                                EngineStage, HttpRequestStage,
                                ProcessorConfig, TokenizeStage,
@@ -27,9 +27,9 @@ def ray4():
 
 
 def _ecfg():
-    return EngineConfig(model=llama.llama_tiny(max_seq_len=64),
-                        max_batch_size=2, max_seq_len=64,
-                        prefill_buckets=(16, 32))
+    return PagedEngineConfig(model=llama.llama_tiny(max_seq_len=64),
+                             max_batch_size=2, page_size=16, num_pages=32,
+                             max_pages_per_seq=4, chunk_size=32)
 
 
 @pytest.mark.slow

@@ -1,4 +1,4 @@
-"""Model-zoo tests: llama (training fwd, decode-cache consistency, grads,
+"""Model-zoo tests: llama (training fwd, paged-cache consistency, grads,
 sharded pjit forward) and resnet."""
 import jax
 import jax.numpy as jnp
@@ -27,20 +27,26 @@ def test_llama_forward_shapes(tiny):
 
 
 def test_llama_decode_matches_full_forward(tiny):
-    """Prefill+decode through the KV cache must equal the full forward."""
+    """Prefill+decode through the paged KV cache must equal the full
+    forward: prefill_paged_chunk over the first 8 tokens, decode_paged
+    one token at a time for the rest."""
     cfg, params = tiny
-    b, s = 1, 12
+    s, page = 12, 8
     tokens = jnp.asarray(
-        np.random.RandomState(0).randint(0, cfg.vocab_size, (b, s)))
+        np.random.RandomState(0).randint(0, cfg.vocab_size, (1, s)))
     full = llama.apply(params, tokens, cfg)
 
-    cache = llama.init_kv_cache(cfg, b, max_len=32)
-    # prefill first 8, then decode one token at a time
-    logits_p, cache = llama.apply_decode(params, tokens[:, :8], cache, cfg)
-    step_logits = [logits_p]
+    caches = llama.init_paged_cache(cfg, num_pages=5, page_size=page)
+    bt = jnp.asarray([1, 2, 3, 4], jnp.int32)      # page 0 is the sink
+    logits_p, caches, _ = llama.prefill_paged_chunk(
+        params, tokens[:, :8], caches, bt, jnp.int32(0), cfg,
+        page_size=page)
+    step_logits = [logits_p[None]]
     for i in range(8, s):
-        lg, cache = llama.apply_decode(params, tokens[:, i:i + 1], cache, cfg)
-        step_logits.append(lg)
+        lg, caches, _ = llama.decode_paged(
+            params, tokens[:, i:i + 1], caches, bt[None],
+            jnp.asarray([i], jnp.int32), cfg, page_size=page)
+        step_logits.append(lg[:, None])
     stitched = jnp.concatenate(step_logits, axis=1)
     np.testing.assert_allclose(np.asarray(stitched), np.asarray(full),
                                rtol=2e-4, atol=2e-4)

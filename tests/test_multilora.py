@@ -335,8 +335,8 @@ def test_e2e_train_publish_serve_hot_swap():
     base = llama.init(jax.random.PRNGKey(0), cfg)
     reg = AdapterRegistry("t-e2e")
     tcfg = dict(model=cfg, rank=4, alpha=8.0,
-                targets=("wq", "wv", "lm_head"), steps=25,
-                learning_rate=0.1, checkpoint_every=25)
+                targets=("wq", "wv", "lm_head"), steps=100,
+                learning_rate=0.1, checkpoint_every=100)
     tr_a = LoRATrainer(LoRATrainConfig(seed=1, **tcfg), "tenant-a",
                        base_params=base, data_fn=_teach(cfg, 7),
                        registry=reg)

@@ -138,21 +138,20 @@ def test_openai_http_path_routing(ray):
 
 
 @pytest.mark.slow
-def test_lora_multiplexed_serving(ray, tmp_path):
+def test_lora_multiplexed_serving(ray):
+    from ray_tpu.llm.multilora import AdapterRegistry
     cfg = _tiny_cfg()
     # strong adapter incl. lm_head: random untrained weights sit in an
     # attractor that weak deltas don't dislodge under greedy decode
     adapter = lora.random_adapter(jax.random.PRNGKey(7), cfg, rank=4,
                                   alpha=64.0,
                                   targets=("wq", "wv", "lm_head"))
-    lora.save_adapter(adapter, str(tmp_path / "myadapter.npz"))
+    assert AdapterRegistry("tiny").publish("myadapter", adapter) == 0
 
     econf = PagedEngineConfig(model=cfg, max_batch_size=2, page_size=16,
                               num_pages=64, max_pages_per_seq=8,
-                              chunk_size=32)
-    app = build_openai_app([LLMConfig(model_id="tiny", engine=econf,
-                                      lora_dir=str(tmp_path),
-                                      max_loras=2)])
+                              chunk_size=32, max_adapters=2, lora_rank=4)
+    app = build_openai_app([LLMConfig(model_id="tiny", engine=econf)])
     h = serve.run(app, name="llm-lora")
 
     base = h.options(method_name="v1_completions").remote(
@@ -161,10 +160,37 @@ def test_lora_multiplexed_serving(ray, tmp_path):
     tuned = h.options(method_name="v1_completions").remote(
         {"model": "tiny:myadapter", "prompt": "hello world",
          "max_tokens": 8, "temperature": 0.0}).result(timeout_s=300)
-    # greedy decode over merged weights must differ from base
+    # greedy decode through the adapter's slot must differ from base
     assert base["choices"][0]["text"] != tuned["choices"][0]["text"]
 
     with pytest.raises(Exception):
         h.options(method_name="v1_completions").remote(
             {"model": "tiny:missing", "prompt": "x",
              "max_tokens": 2}).result(timeout_s=120)
+
+
+def test_lora_request_without_slot_table_is_refused():
+    """One engine a replica: a deployment whose engine has no adapter
+    slot table (max_adapters == 0) refuses a request that names a LoRA,
+    and says which setting would serve it."""
+    from ray_tpu.llm.serving import LLMServer
+    econf = PagedEngineConfig(model=_tiny_cfg(), max_batch_size=2,
+                              page_size=16, num_pages=16,
+                              max_pages_per_seq=4, chunk_size=32)
+    srv = LLMServer(LLMConfig(model_id="tiny", engine=econf, warmup=False))
+    try:
+        assert srv.engine.lora is None and srv.loaded_loras() == []
+        for request in ({"model": "tiny:myadapter", "prompt": "x"},
+                        {"lora": "myadapter", "prompt": "x"}):
+            with pytest.raises(ValueError,
+                               match="PagedEngineConfig.max_adapters"):
+                srv.completions(request)
+        assert not srv.engine.has_work()       # nothing was admitted
+        out = srv.completions({"model": "tiny", "prompt": "x",
+                               "max_tokens": 2})
+        assert out["usage"]["completion_tokens"] == 2
+    finally:
+        srv._stop = True
+        srv._wake.set()
+        srv._thread.join(timeout=10)
+    assert not srv._thread.is_alive()
