@@ -203,7 +203,7 @@ def child_kernels(a) -> None:
     # ragged paged attention: the engine's three dispatch shapes
     page, maxp, c = ec["page_size"], ec["max_pages_per_seq"], ec["chunk_size"]
     pool = 4 * maxp
-    kp, vp = rand(pool, page, kvh, d), rand(pool, page, kvh, d)
+    kp, vp = rand(pool, page, kvh * d), rand(pool, page, kvh * d)
     ctx = maxp * page
 
     def ragged(name, rows, q_window, starts, q_lens):

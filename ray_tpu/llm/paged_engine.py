@@ -446,8 +446,7 @@ class PagedInferenceEngine:
         with use_mesh(self.mesh):
             repl = shardlib.named_sharding(())
             pshard = shardlib.logical_sharding(llama.logical_axes(mc))
-            kv = shardlib.named_sharding(
-                (None, None, "kv_heads", "head_dim"))
+            kv = shardlib.named_sharding((None, None, "kv_heads"))
             cshard = [{"k": kv, "v": kv} for _ in self.caches]
             lshard = repl
             if self.lora is not None:

@@ -554,7 +554,11 @@ def apply_pipelined(params: dict, tokens: jax.Array, cfg: LlamaConfig,
 
 def init_paged_cache(cfg: LlamaConfig, num_pages: int,
                      page_size: int) -> list[dict]:
-    """Per-layer page pools: [{'k','v': [P, page, KVH, D]}] * n_layers.
+    """Per-layer page pools: [{'k','v': [P, page, KVH * D]}] * n_layers.
+    A page holds its tokens' kv heads side by side on the last axis,
+    head h in lanes [h * D, (h + 1) * D): what a [.., KVH, D] K/V
+    reshapes to for free, and what lets the ragged kernel read one head's
+    keys of a 128-key block as whole tiles (ops/ragged_paged_attention.py).
 
     Kept as SEPARATE per-layer arrays (not a stacked [L, ...] tensor): the
     decode step is unrolled over layers so each Pallas paged-attention call
@@ -565,7 +569,7 @@ def init_paged_cache(cfg: LlamaConfig, num_pages: int,
     hand it to a sequence. decode_paged (idle rows) and the prefill rows
     (pad pages, pad rows) dump never-attended writes there.
     """
-    shape = (num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    shape = (num_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
     return [{"k": jnp.zeros(shape, cfg.dtype),
              "v": jnp.zeros(shape, cfg.dtype)}
             for _ in range(cfg.n_layers)]
@@ -597,8 +601,9 @@ def _add_load(total, routed):
     return routed if total is None or routed is None else total + routed
 
 
-# logical axes of a page pool [P, page, KVH, D] (the engine commits it so)
-_PAGES_AXES = (None, None, "kv_heads", None)
+# logical axes of a page pool [P, page, KVH * D] (the engine commits it
+# so): head-major lanes, so a tp shard holds its own heads, contiguous
+_PAGES_AXES = (None, None, "kv_heads")
 
 
 def _window_attend(scale: float, interpret: bool):
@@ -667,9 +672,9 @@ def decode_paged(params: dict, tokens: jax.Array, caches: list[dict],
         h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
         q, k, v = _qkv(h, p, cfg, cos, sin, ll, slots)     # q [B,1,H,D]
         k_pages = cache["k"].at[page_ids, offsets].set(
-            k[:, 0].astype(cache["k"].dtype))
+            k.reshape(b, -1).astype(cache["k"].dtype))
         v_pages = cache["v"].at[page_ids, offsets].set(
-            v[:, 0].astype(cache["v"].dtype))
+            v.reshape(b, -1).astype(cache["v"].dtype))
         attn = attend(q[:, 0], k_pages, v_pages, block_tables,
                       lengths + 1)                         # [B, H, D]
         proj = attn.reshape(b, 1, -1)
@@ -741,7 +746,7 @@ def _prefill_rows(params: dict, chunks: jax.Array, caches: list[dict],
     valid = (page_no < valid_pages) & (logical < max_pages)
     chunk_page_ids = jnp.where(valid, jnp.take_along_axis(
         bt_rows, jnp.clip(logical, 0, max_pages - 1), axis=1), 0)
-    paged = (r, n_chunk_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    paged = (r, n_chunk_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
 
     x = params["embed"][chunks].astype(cfg.dtype)              # [R, C, D]
     new_caches, load = [], None
@@ -897,9 +902,9 @@ def verify_paged_rows(params: dict, tokens: jax.Array, caches: list[dict],
             h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
             q, k, v = _qkv(h, p, cfg, cos, sin, ll, sl)    # [1,S1,H/KVH,D]
             k_pages = cache["k"].at[page_ids, offsets].set(
-                k[0].astype(cache["k"].dtype))
+                k.reshape(s1, -1).astype(cache["k"].dtype))
             v_pages = cache["v"].at[page_ids, offsets].set(
-                v[0].astype(cache["v"].dtype))
+                v.reshape(s1, -1).astype(cache["v"].dtype))
             # the scatter above already placed the window's K/V, so
             # attention reads pages only
             attn = attend(q, k_pages, v_pages, bt[None],

@@ -19,14 +19,20 @@ the pool capacity:
   page used 16 of 128 lanes and paid a grid step per 16 keys: PERF.md §6,
   PR 25). ``N`` comes from the pool's page shape; at pages of 128 a block
   is one page;
-* the pools stay in HBM (``memory_space=ANY``) and the kernel gathers a
+* a pool is ``[P, page, KVH * D]``: one token's kv heads side by side
+  on the last axis, head ``h`` in lanes ``[h * D, (h + 1) * D)``. The
+  pools stay in HBM (``memory_space=ANY``) and the kernel gathers a
   block's pages itself: the block table is scalar-prefetched, each page
-  is one `make_async_copy` into a double-buffered ``[2, N * page, KVH,
-  D]`` VMEM scratch, and a live step starts the NEXT block's copies
-  before it waits for its own. (N pipelined BlockSpec operands per pool
-  were measured first: their bookkeeping costs 1.3 us on every grid
-  step, live or dead, against 0.07 us for a dead step here.) Every copy
-  started is waited for inside the same sweep;
+  is one `make_async_copy` (contiguous on both sides) into a
+  double-buffered ``[2, N * page, KVH * D]`` VMEM scratch, and a live
+  step starts the NEXT block's copies before it waits for its own. (N
+  pipelined BlockSpec operands per pool were measured first: their
+  bookkeeping costs 1.3 us on every grid step, live or dead, against
+  0.07 us for a dead step here.) Every copy started is waited for inside
+  the same sweep. In that buffer a kv head's ``[N * page, D]`` keys are
+  a static, lane-aligned slice — whole tiles — where a ``[.., KVH, D]``
+  buffer would hold one head's keys as one sublane out of each key's
+  tile (a strided load a key, twice a head: PERF.md §6, PR 30);
 * the query window is TILED over the middle grid axis (`_q_tile`): the
   q/out blocks and the f32 accumulators are per query row, so a tile is
   an independent sweep, and what one grid step keeps in VMEM is bounded
@@ -46,16 +52,33 @@ the pool capacity:
   + i`` iff ``k_pos <= q_pos`` and ``k_pos < kv_len`` — which covers the
   prefix (always attended), the window (causal), a partly live block and
   its clamped duplicates with one predicate;
-* GQA by a static per-kv-head loop: each group of ``groups`` query heads
-  runs a [Q*G, N*page] MXU tile against its kv head's [N*page, D] block,
-  and the softmax state is kept per kv head (``[KVH, Q*G, ...]``), so
-  only one head's scores are live at a time — no jnp.repeat
-  materialization anywhere.
+* GQA without any jnp.repeat, in one of two forms chosen by the static
+  shape ``Q * G`` (Q the query TILE, G query heads a kv head) alone:
 
-Row layout convention (everything else follows from it): with Q the
-query TILE, a kv head's score/accumulator row index is ``q * G + g`` —
-query-major — because per-kv-head q slices ``q[:, h*G:(h+1)*G, :]``
-reshape contiguously to [Q*G, D].
+  - ``Q * G > 8`` (prefill and verify tiles): a static per-kv-head loop.
+    Each group of G query heads runs a [Q*G, N*page] MXU tile against
+    its kv head's [N*page, D] block, and the softmax state is kept per
+    kv head (``[KVH, Q*G, ...]``), so only one head's scores are live at
+    a time. A head's score/accumulator row index is ``q * G + g`` —
+    query-major — because per-kv-head q slices ``q[:, h*G:(h+1)*G, :]``
+    reshape contiguously to [Q*G, D];
+  - ``Q * G <= 8`` (decode: 4 rows a kv head at 32/8 heads, 1 at 16/16;
+    verify windows of 2 at groups 4, of up to 8 without groups):
+    per head the MXU would take a fresh [N*page, D] K and V block as its
+    stationary operand for a handful of rows, 2 x KVH times a step. All
+    heads go through it together instead: the queries become one
+    block-diagonal ``[Q*H, KVH*D]`` operand (row ``q * H + head`` keeps
+    its own kv head's D lanes, zeros elsewhere), scores are ONE
+    ``[Q*H, KVH*D] x [N*page, KVH*D]^T`` product, PV is ONE ``[Q*H,
+    N*page] x [N*page, KVH*D]`` product of which each row keeps its own
+    head's D lanes. The extra terms are exact zeros (scores) or are
+    dropped by a select (PV): the same mathematics. Measured a live
+    step, us (PR 30): 1.48 -> 0.93 at 32/8 heads, 2.59 -> 1.47 at 16/16;
+    a VPU form (broadcast-multiply, lane reduce) gave 2.49 and 1.65.
+    Verify windows under the line: 1.50 -> 0.98 (window 2, 32/8), 2.60 ->
+    1.49 / 1.55 / 1.70 (windows 2 / 5 / 8, 16/16). Forced above it the
+    one product still leads at ``Q * H`` = 256 rows and trails at 512
+    (PERF.md §6): the line is on the safe side of the crossover.
 
 The pure-jnp oracle (`ragged_paged_reference`) uses the same
 grouped-einsum GQA form and is the CPU fallback's numerical contract;
@@ -72,6 +95,32 @@ import jax.numpy as jnp
 # one masking constant for the whole paged family: the kernel and both
 # jnp oracles below must mask identically
 NEG_INF = -1e30
+
+
+# A kv head's score tile of at most this many rows (one f32 sublane
+# tile) leaves the MXU all but empty: such tiles — decode, the smallest
+# verify windows — take all heads through it in one product. Measured
+# on both sides (module docstring): faster or level at every shape at
+# or under it.
+_ALL_HEADS_ROWS = 8
+
+
+def _all_heads(q_tile: int, groups: int) -> bool:
+    return q_tile * groups <= _ALL_HEADS_ROWS
+
+
+def _softmax_step(s, keep, m_ref, l_ref, i):
+    """Fold one block's scores ``s`` [rows, keys] into the streaming
+    softmax state of slot ``i``; returns the block's probabilities and
+    the factor the accumulator of slot ``i`` is rescaled by."""
+    s = jnp.where(keep, s, NEG_INF)
+    m_prev, l_prev = m_ref[i], l_ref[i]                   # [rows, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
+    pexp = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[i] = l_prev * alpha + jnp.sum(pexp, axis=-1)[:, None]
+    m_ref[i] = m_new
+    return pexp, alpha
 
 
 def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
@@ -105,7 +154,9 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
                            pl.num_programs(2))
     last_page = jnp.maximum(jnp.minimum(n_pages, bt_ref.shape[1]) - 1, 0)
     block_keys = block_pages * page_size
-    qg = q_tile * groups
+    heads = num_kv_heads * groups
+    d = q_ref.shape[-1]
+    all_heads = _all_heads(q_tile, groups)
 
     def _copies(block, slot):
         """The 2 x block_pages page copies that fill buffer ``slot`` with
@@ -124,6 +175,18 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
                 v_hbm.at[phys], v_buf.at[slot, rows], sems.at[1, slot]))
         return out
 
+    def _keep(rows, rows_per_query):
+        """Live-key predicate [rows, block_keys], row = query-major.
+        Key positions come from the LOGICAL page index: one predicate
+        covers the prefix (k_pos < start <= q_pos), the causal window
+        and a partly live block; k_pos < kv_len hides stale K/V of the
+        tail page from PAD queries whose q_pos exceeds the row."""
+        q_pos = tile_start + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_keys), 0) // rows_per_query
+        k_pos = b * block_keys + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block_keys), 1)
+        return (k_pos <= q_pos) & (k_pos < kv_len)
+
     @pl.when(b < n_blocks)
     def _compute():
         slot = b % 2
@@ -141,30 +204,44 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
         for c in _copies(b, slot):
             c.wait()
         q = q_ref[...]                                    # [Q, H, D]
-        # key positions come from the LOGICAL page index: one predicate
-        # covers the prefix (k_pos < start <= q_pos), the causal window
-        # and a partly live block; k_pos < kv_len hides stale K/V of the
-        # tail page from PAD queries whose q_pos exceeds the row
-        q_pos = tile_start + jax.lax.broadcasted_iota(
-            jnp.int32, (qg, block_keys), 0) // groups
-        k_pos = b * block_keys + jax.lax.broadcasted_iota(
-            jnp.int32, (qg, block_keys), 1)
-        keep = (k_pos <= q_pos) & (k_pos < kv_len)
+        if all_heads:
+            # every head in one product: row q * H + head of the
+            # block-diagonal operand keeps its own kv head's D lanes
+            rows = q_tile * heads
+            row_head = jax.lax.broadcasted_iota(
+                jnp.int32, (rows, 1), 0) % heads // groups
+            lane_head = jax.lax.broadcasted_iota(
+                jnp.int32, (1, num_kv_heads * d), 1) // d
+            wide = jnp.concatenate(
+                [q.reshape(rows, d)] * num_kv_heads, axis=1)
+            s = jax.lax.dot_general(
+                jnp.where(row_head == lane_head, wide, jnp.zeros_like(wide)),
+                k_buf[slot], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            pexp, alpha = _softmax_step(s, _keep(rows, heads),
+                                        m_ref, l_ref, 0)
+            v_blk = v_buf[slot]                           # [keys, KVH*D]
+            pv = jax.lax.dot_general(
+                pexp.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)       # [Q*H, KVH*D]
+            acc = acc_ref[0] * alpha
+            for h in range(num_kv_heads):
+                acc += jnp.where(row_head == h,
+                                 pv[:, h * d:(h + 1) * d], 0.0)
+            acc_ref[0] = acc
+            return
         # per kv head: [Q*G, block_keys] scores live at a time, not
         # [KVH*Q*G, block_keys] (2 x 1 MiB of f32 at the prefill tile)
+        qg = q_tile * groups
+        keep = _keep(qg, groups)
         for h in range(num_kv_heads):
-            q_sub = q[:, h * groups:(h + 1) * groups, :].reshape(qg, -1)
+            lanes = slice(h * d, (h + 1) * d)
+            q_sub = q[:, h * groups:(h + 1) * groups, :].reshape(qg, d)
             s = jax.lax.dot_general(
-                q_sub, k_buf[slot, :, h, :], (((1,), (1,)), ((), ())),
+                q_sub, k_buf[slot, :, lanes], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            s = jnp.where(keep, s, NEG_INF)
-            m_prev, l_prev = m_ref[h], l_ref[h]           # [Q*G, 1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
-            pexp = jnp.where(keep, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[h] = l_prev * alpha + jnp.sum(pexp, axis=-1)[:, None]
-            m_ref[h] = m_new
-            v_blk = v_buf[slot, :, h, :]                  # [block_keys, D]
+            pexp, alpha = _softmax_step(s, keep, m_ref, l_ref, h)
+            v_blk = v_buf[slot, :, lanes]                 # [block_keys, D]
             pv = jax.lax.dot_general(
                 pexp.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)       # [Q*G, D]
@@ -172,10 +249,14 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
 
     @pl.when(b == pl.num_programs(2) - 1)
     def _finalize():
+        if all_heads:
+            o = acc_ref[0] / jnp.maximum(l_ref[0], 1e-30)  # [Q*H, D]
+            o_ref[...] = o.reshape(q_tile, heads, d).astype(o_ref.dtype)
+            return
         for h in range(num_kv_heads):
             o = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)  # [Q*G, D]
             o_ref[:, h * groups:(h + 1) * groups, :] = o.reshape(
-                q_tile, groups, -1).astype(o_ref.dtype)
+                q_tile, groups, d).astype(o_ref.dtype)
 
 
 # Most query elements (tile * heads * head_dim) one grid step may hold:
@@ -220,7 +301,8 @@ def _block_pages(page_size: int) -> int:
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
                            q_lens, *, scale: float | None = None,
                            interpret: bool = False):
-    """q [R, Q, H, D]; k_pages/v_pages [P, page, KVH, D];
+    """q [R, Q, H, D]; k_pages/v_pages [P, page, KVH * D] (head ``h`` in
+    lanes ``[h * D, (h + 1) * D)`` of the last axis);
     block_tables [R, max_pages] int32 (physical page per logical page);
     starts [R] int32 (position of each row's first query token);
     q_lens [R] int32 (true query tokens this row, <= Q; 0 = padding row).
@@ -250,7 +332,8 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
     from jax.experimental.pallas import tpu as pltpu
 
     r, qw, h, d = q.shape
-    _, page_size, kvh, _ = k_pages.shape
+    _, page_size, kv_lanes = k_pages.shape
+    kvh = kv_lanes // d
     groups = h // kvh
     nb = _block_pages(page_size)
     n_tiles = -(-qw // q_tile)
@@ -267,7 +350,10 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
         return (ri, t, 0, 0)
 
     pool_in_hbm = pl.BlockSpec(memory_space=pl.ANY)
-    block_buf = pltpu.VMEM((2, nb * page_size, kvh, d), k_pages.dtype)
+    block_buf = pltpu.VMEM((2, nb * page_size, kv_lanes), k_pages.dtype)
+    # softmax state: a slot a kv head, or one slot for all heads
+    slots, rows = (1, q_tile * h) if _all_heads(q_tile, groups) else (
+        kvh, q_tile * groups)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(r, n_tiles, -(-block_tables.shape[1] // nb)),
@@ -277,9 +363,9 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
         scratch_shapes=[
             block_buf, block_buf,                   # K, V: two slots each
             pltpu.SemaphoreType.DMA((2, 2)),        # [K | V, slot]
-            pltpu.VMEM((kvh, q_tile * groups, d), jnp.float32),
-            pltpu.VMEM((kvh, q_tile * groups, 1), jnp.float32),
-            pltpu.VMEM((kvh, q_tile * groups, 1), jnp.float32),
+            pltpu.VMEM((slots, rows, d), jnp.float32),
+            pltpu.VMEM((slots, rows, 1), jnp.float32),
+            pltpu.VMEM((slots, rows, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -296,7 +382,7 @@ def ragged_decode_attention(q, k_pages, v_pages, block_table, lengths,
                             *, scale: float | None = None,
                             interpret: bool = False):
     """Decode as the q_len=1 degenerate case: q [B, H, D], k_pages /
-    v_pages [num_pages, page_size, KVH, D], block_table [B, max_pages],
+    v_pages [num_pages, page_size, KVH * D], block_table [B, max_pages],
     lengths [B] = tokens in cache INCLUDING the current step's (attend
     positions < length). Returns [B, H, D]."""
     lengths = lengths.astype(jnp.int32)
@@ -314,7 +400,8 @@ def ragged_paged_reference(q, k_pages, v_pages, block_tables, starts,
     Same contract as the kernel; masks exactly the kernel's live-key
     predicate, so outputs match at every query position i < q_lens[r]."""
     r, qw, h, d = q.shape
-    _, page_size, kvh, _ = k_pages.shape
+    _, page_size, kv_lanes = k_pages.shape
+    kvh = kv_lanes // d
     groups = h // kvh
     max_pages = block_tables.shape[1]
     klen = max_pages * page_size
@@ -347,12 +434,13 @@ def paged_decode_reference(q, k_pages, v_pages, block_table, lengths,
     (query head h attends kv head h // G), so no O(groups) jnp.repeat
     materialization of the gathered cache is ever built."""
     b, h, d = q.shape
-    p_total, page_size, kvh, _ = k_pages.shape
+    _, page_size, kv_lanes = k_pages.shape
+    kvh = kv_lanes // d
     groups = h // kvh
     max_pages = block_table.shape[1]
     if scale is None:
         scale = d ** -0.5
-    # gather each sequence's pages -> [B, max_pages*page, KVH, D]
+    # gather each sequence's pages, split the lanes -> [B, keys, KVH, D]
     k = k_pages[block_table].reshape(b, max_pages * page_size, kvh, d)
     v = v_pages[block_table].reshape(b, max_pages * page_size, kvh, d)
     qg = q.reshape(b, kvh, groups, d).astype(jnp.float32)

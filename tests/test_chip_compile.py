@@ -57,7 +57,7 @@ def _compile(fn, *shapes, sharding):
 
 
 def _paged_shapes(rows, q_window, page, max_pages, pool=256, h=H, kvh=KVH):
-    pages = ((pool, page, kvh, D), BF16)
+    pages = ((pool, page, kvh * D), BF16)      # as the engine stores them
     return [((rows, q_window, h, D), BF16), pages, pages,
             ((rows, max_pages), jnp.int32), ((rows,), jnp.int32),
             ((rows,), jnp.int32)]
@@ -161,7 +161,7 @@ def test_tp4_decode_step_keeps_its_kernels_in_shard_map(v5e, monkeypatch):
             lambda s, sh: sds(s.shape, s.dtype, sh),
             jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), mc)),
             logical_sharding(llama.logical_axes(mc)))
-        caches = [{n: sds((pool, page, KVH, D), BF16, kv) for n in "kv"}
+        caches = [{n: sds((pool, page, KVH * D), BF16, kv) for n in "kv"}
                   for _ in range(mc.n_layers)]
         text = jax.jit(functools.partial(
             llama.decode_paged, cfg=mc, page_size=page)).lower(
@@ -169,7 +169,7 @@ def test_tp4_decode_step_keeps_its_kernels_in_shard_map(v5e, monkeypatch):
             sds((rows, max_pages), jnp.int32, repl),
             sds((rows,), jnp.int32, repl)).compile().as_text()
     assert text.count("tpu_custom_call") == mc.n_layers
-    gathered_pool = f"bf16[{pool},{page},{KVH},{D}]"
+    gathered_pool = f"bf16[{pool},{page},{KVH * D}]"
     assert not [ln for ln in text.splitlines()
                 if "all-gather" in ln and gathered_pool in ln]
 
@@ -182,13 +182,15 @@ OLMOE = dict(vocab_size=50304, dim=2048, n_heads=16, n_kv_heads=16,
              moe_experts=64, moe_top_k=8, moe_renormalize=False)
 
 
-@pytest.mark.parametrize("rows,q_window", [(64, 1), (1, 128)],
-                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("rows,q_window", [(64, 1), (1, 128), (8, 5)],
+                         ids=["decode", "prefill", "verify"])
 def test_ragged_compiles_at_olmoe_shapes(v5e, rows, q_window):
-    """groups = 1: a kv head's MXU tile has one query row at decode, the
-    kernel keeps 16 heads' softmax state and a 128-key block is 1 MiB of
-    K + V. max_batch_size 64 rows, the cell's 128-page table (2048-token
-    contexts), its 3456-page pool."""
+    """groups = 1: a kv head's score tile would have one query row at
+    decode (five at a verify window), so all 16 heads go through one
+    block-diagonal product there and through their own 128-row tiles at
+    prefill; a 128-key block is 1 MiB of K + V. max_batch_size 64 rows,
+    the cell's 128-page table (2048-token contexts), its 3456-page
+    pool."""
     one = SingleDeviceSharding(v5e.devices[0])
     text = _compile(
         ragged_paged_attention,
@@ -237,14 +239,22 @@ def test_olmoe_decode_layer_compiles_at_64_rows(v5e, monkeypatch):
     params = jax.tree.map(
         lambda s: sds(s.shape, s.dtype),
         jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), mc)))
-    caches = [{n: sds((pool, page, 16, D), BF16) for n in "kv"}]
-    compiled = jax.jit(functools.partial(
-        llama.decode_paged, cfg=mc, page_size=page)).lower(
-        params, sds((rows, 1), jnp.int32), caches,
-        sds((rows, max_pages), jnp.int32),
-        sds((rows,), jnp.int32)).compile()
+
+    def compile_with(num_pages):
+        caches = [{n: sds((num_pages, page, 16 * D), BF16) for n in "kv"}]
+        return jax.jit(functools.partial(
+            llama.decode_paged, cfg=mc, page_size=page)).lower(
+            params, sds((rows, 1), jnp.int32), caches,
+            sds((rows, max_pages), jnp.int32),
+            sds((rows,), jnp.int32)).compile()
+    compiled = compile_with(pool)
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 3      # attention, two expert
+    # the pools are not padded in HBM: a page more costs the program's
+    # arguments exactly page x KVH x D bf16 values for K and for V
+    more = compile_with(pool + 128).memory_analysis().argument_size_in_bytes
+    assert more - compiled.memory_analysis().argument_size_in_bytes == \
+        2 * 128 * page * 16 * D * 2
     assert jax.tree.leaves(compiled.out_info)[-1].shape == (64,)
 
 
@@ -279,7 +289,7 @@ def test_prefill_r4_program_compiles_at_the_cells_widths(
     params = jax.tree.map(
         lambda s: sds(s.shape, s.dtype),
         jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), mc)))
-    caches = [{n: sds((pool, page, kvh, D), BF16) for n in "kv"}
+    caches = [{n: sds((pool, page, kvh * D), BF16) for n in "kv"}
               for _ in range(mc.n_layers)]
     compiled = jax.jit(functools.partial(
         llama.prefill_paged_rows, cfg=mc, page_size=page),

@@ -449,3 +449,57 @@ def test_prefill_rows_is_one_forward_not_a_loop_over_rows():
         lhs, rhs = (v.aval.shape for v in e.invars)
         assert rhs == (cfg.dim, cfg.mlp_dim)
         assert lhs == (r, _CHUNK, cfg.dim)        # R x C rows, one matmul
+
+
+@pytest.mark.parametrize("path", ["spill_tier", "pd_transfer"])
+def test_pages_that_leave_the_pool_come_back_in_its_layout(path):
+    """The paths that carry pages out of the pools and back index them by
+    the leading axis alone, so a change of the page's layout breaks them
+    in silence: a prefix demoted to the spill tier and promoted back, and
+    a prefill exported by `_export_kv_locked` and imported by `_import_fn`
+    on another engine, continue with exactly the tokens of an engine whose
+    pages never left. Two kv heads of 8 lanes each: a page is [page,
+    KVH * D] and a head order lost on the way would change the tokens."""
+    model = llama.llama_tiny(vocab_size=258, max_seq_len=256, dim=32,
+                             n_layers=2, n_heads=4, n_kv_heads=2,
+                             mlp_dim=64)
+    cfg = dict(model=model, max_batch_size=4, page_size=8, num_pages=32,
+               max_pages_per_seq=16, chunk_size=16,
+               enable_prefix_caching=True)
+    sp = SamplingParams(max_tokens=10, temperature=0.0)
+    rng = np.random.RandomState(21)
+    shared = list(rng.randint(1, 250, (64,)))
+    ask = shared + list(rng.randint(1, 250, (13,)))
+    page = (cfg["page_size"], model.n_kv_heads * model.head_dim)
+
+    def run(eng, ids, params=sp):
+        req = eng.submit(ids, params)
+        while not req.done:
+            eng.step()
+        return list(req.out_ids)
+
+    stay = PagedInferenceEngine(PagedEngineConfig(**cfg), rng_seed=0)
+    assert stay.caches[0]["k"].shape == (cfg["num_pages"],) + page
+    want = run(stay, ask)
+
+    if path == "spill_tier":
+        eng = PagedInferenceEngine(
+            PagedEngineConfig(kv_spill=True, **cfg), rng_seed=0)
+        run(eng, shared + [7], SamplingParams(max_tokens=2))
+        hashes = eng.hash_prompt(shared)
+        for i in range(8):                  # push the prefix off the LRU
+            run(eng, list(np.random.RandomState(900 + i).randint(
+                1, 250, (96,))), SamplingParams(max_tokens=2))
+        assert eng.cached_prefix_len(hashes) == 0
+        assert eng.spill.covered_run(hashes) == len(hashes)
+        got = run(eng, ask)                 # admission promotes it back
+        assert eng.stats["spill_promotions"] >= len(hashes)
+    else:
+        pre = PagedInferenceEngine(PagedEngineConfig(**cfg), rng_seed=0)
+        eng = PagedInferenceEngine(PagedEngineConfig(**cfg), rng_seed=0)
+        payload = pre.prefill_export(ask, sp)
+        assert payload["pages"][0]["k"].shape[1:] == page
+        req = eng.import_prefill(payload, sp)
+        eng.run_until_done([req])
+        got = eng._result(req)["token_ids"]
+    assert got == want

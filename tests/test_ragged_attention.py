@@ -20,9 +20,12 @@ from ray_tpu.ops.ragged_paged_attention import (
 
 
 def _pools(rng, P, page, kvh, d):
+    """Pools as the engine stores them, [P, page, KVH * D] (head h in
+    lanes [h * D, (h + 1) * D)), from per-head test data [P, page, KVH,
+    D]: the one place these tests know the storage layout."""
     k = jnp.asarray(rng.randn(P, page, kvh, d), jnp.float32)
     v = jnp.asarray(rng.randn(P, page, kvh, d), jnp.float32)
-    return k, v
+    return k.reshape(P, page, kvh * d), v.reshape(P, page, kvh * d)
 
 
 def _assert_rows_close(got, ref, q_lens, atol=2e-5):
@@ -185,6 +188,61 @@ def test_block_sweep_decode_matches_oracle(page, maxp):
                                atol=2e-5)
     assert np.isfinite(np.asarray(got)).all()
     assert np.abs(np.asarray(got)).max() < 1e3
+
+
+@pytest.mark.parametrize("rows,page,maxp", [(64, 16, 16), (5, 128, 2)])
+def test_decode_of_sixteen_kv_heads_without_groups(rows, page, maxp):
+    """OLMoE's form at the decode shape: 16 query heads on 16 kv heads
+    (groups = 1, one query row a kv head), 64 rows whose lengths end
+    inside a block, on a block's edge and on a page's edge, idle rows
+    between them, the poisoned sink behind every table's tail."""
+    cap = maxp * page
+    lengths = np.random.RandomState(14).randint(1, cap + 1, rows)
+    lengths[:5] = [cap, 0, 128, page, 0]
+    q, kp, vp, bt, _, _ = _poisoned_case(
+        np.random.RandomState(15), rows, 1, 16, 16, 32, page, maxp,
+        np.zeros_like(lengths), lengths, P=96)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    ref = paged_decode_reference(q[:, 0], kp, vp, bt, lengths)
+    got = ragged_decode_attention(q[:, 0], kp, vp, bt, lengths,
+                                  interpret=True)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(ref)[live],
+                               atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got)).max() < 1e3
+
+
+# Both sides of the kernel's one static branch, `q_tile * groups`
+# against `_ALL_HEADS_ROWS` = 8: at or under it every head goes through
+# one block-diagonal product, over it each kv head has its own. Each
+# case: query window, query heads, kv heads.
+_HEAD_FORM_CASES = {
+    "decode_groups4_all_heads": (1, 8, 2),
+    "window2_groups4_all_heads_at_the_threshold": (2, 8, 2),
+    "window3_groups4_per_head_over_it": (3, 8, 2),
+    "window8_groups1_all_heads_at_the_threshold": (8, 4, 4),
+    "window9_groups1_per_head_over_it": (9, 4, 4),
+    "window5_groups1_sixteen_heads_all_heads": (5, 16, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_HEAD_FORM_CASES))
+def test_both_head_forms_match_oracle(case):
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    qw, h, kvh = _HEAD_FORM_CASES[case]
+    assert rpa._all_heads(rpa._q_tile(qw, h, 32), h // kvh) == \
+        ("all_heads" in case)
+    # windows that start off a page's edge, end inside a block, a
+    # q_len = 0 row between live ones, one shorter than its window
+    starts, q_lens = [291, 0, 120, 37], [qw, 0, qw, max(1, qw - 1)]
+    args = _poisoned_case(np.random.RandomState(16), 4, qw, h, kvh, 32, 16,
+                          24, starts, q_lens)
+    ref = ragged_paged_reference(*args)
+    got = ragged_paged_attention(*args, interpret=True)
+    _assert_rows_close(got, ref, q_lens)
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(got)).max() < 1e3   # poison never attended
 
 
 @pytest.mark.parametrize("page", [8, 16])
