@@ -1,0 +1,2 @@
+"""``prefix_hit_tok_share`` where it moves this cell's own end-to-end metric."""
+from .prefix_hit_tok_share import read  # noqa: F401

@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
@@ -44,7 +45,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import flight
-from ..models import llama
 from ..util.compile_cache import enable_compile_cache
 from ..util.profiling import StepProfiler, phase
 from . import telemetry
@@ -54,9 +54,22 @@ from .engine import (  # noqa: F401 — SamplingParams re-exported
 from .tokenizer import get_tokenizer
 
 
+def model_module(model_cfg):
+    """The module that defines a model config's class: the engine's one
+    seam to a model. It calls, by these names and nothing else of the
+    module: ``init``, ``init_paged_cache`` (a list, one dict of named page
+    pools [P, page, ...] a layer, which the engine never interprets),
+    ``decode_paged``, ``prefill_paged_rows``, ``verify_paged_rows``,
+    ``routed_per_token``, ``lora_targets`` and, under a mesh,
+    ``check_mesh`` (which may refuse) and then ``logical_axes`` and
+    ``cache_logical_axes`` (models/llama.py, models/mla_moe.py)."""
+    return sys.modules[type(model_cfg).__module__]
+
+
 @dataclasses.dataclass
 class PagedEngineConfig:
-    model: llama.LlamaConfig
+    # any model config whose module is a model_module (above)
+    model: Any
     max_batch_size: int = 8
     page_size: int = 16
     num_pages: int = 512
@@ -207,11 +220,12 @@ class PagedInferenceEngine:
         # cache, wherever this engine runs (replica worker or driver)
         enable_compile_cache()
         self.tokenizer = get_tokenizer(cfg.tokenizer)
+        self.model = model_module(mc)
         if params is None:
-            params = llama.init(jax.random.PRNGKey(rng_seed), mc)
+            params = self.model.init(jax.random.PRNGKey(rng_seed), mc)
         self.params = params
-        self.caches = llama.init_paged_cache(mc, cfg.num_pages,
-                                             cfg.page_size)
+        self.caches = self.model.init_paged_cache(mc, cfg.num_pages,
+                                                  cfg.page_size)
         # page 0 is the write sink for slots that are idle during a decode
         # step (their dummy token writes land there, never attended); it is
         # never allocated to a sequence
@@ -252,8 +266,10 @@ class PagedInferenceEngine:
         # never learned fall to the overflow sink on eviction.
         self.chains = None
         self._chain_of: dict[int, int] = {}
-        page_nbytes = sum(int(l["k"].nbytes) + int(l["v"].nbytes)
-                          for l in self.caches) // max(cfg.num_pages, 1)
+        # bytes one page holds over every pool of every layer
+        self.page_nbytes = page_nbytes = sum(
+            int(pool.nbytes) for layer in self.caches
+            for pool in layer.values()) // max(cfg.num_pages, 1)
         if self._prefix_on and cfg.chain_stats_slots > 0:
             from .chainstats import ChainStatsTable
             self.chains = ChainStatsTable(cfg.chain_stats_slots,
@@ -280,6 +296,13 @@ class PagedInferenceEngine:
         # caller serializes against stepping (serving's step lock)
         self.lora = None
         if cfg.max_adapters > 0:
+            unknown = set(cfg.lora_targets) - set(
+                self.model.lora_targets(mc))
+            if unknown:
+                raise ValueError(
+                    f"PagedEngineConfig.max_adapters > 0 with lora_targets "
+                    f"{sorted(unknown)}: {self.model.__name__} adapts only "
+                    f"{self.model.lora_targets(mc)}")
             from .multilora.slots import AdapterSlotTable
             self.lora = AdapterSlotTable(mc, cfg.max_adapters,
                                          cfg.lora_rank, cfg.lora_targets)
@@ -310,6 +333,7 @@ class PagedInferenceEngine:
         # without the sort; the page bucket (_page_bucket) is the table
         # width the dispatch was sliced to. Cache pytrees are donated
         # through every one so XLA updates pages in place.
+        self._import_fns: dict = {}     # page scatters (_import_fn)
         self._decode_win_fns: dict[tuple, Any] = {}
         self._prefill_rows_fns: dict[tuple, Any] = {}
         self._verify_fns: dict[tuple, Any] = {}
@@ -378,7 +402,7 @@ class PagedInferenceEngine:
         # an MoE config's expert layer computes every row and token a
         # program runs, live or not (_moe_account); a dense config has no
         # such keys
-        if mc.moe_experts:
+        if self.model.routed_per_token(mc):
             self.stats.update(moe_assign_live=0, moe_assign_run=0,
                               moe_expert_load_sum=0, moe_expert_load_max=0)
         # speculation controller: EMA of tokens-per-slot-per-spec-dispatch
@@ -423,31 +447,22 @@ class PagedInferenceEngine:
     def _mesh_shardings(self) -> dict:
         """The explicit NamedShardings of everything committed to
         self.mesh: KV pages shard over kv-heads on tp, weights follow
-        llama.logical_axes, block tables / token ids stay replicated.
+        the model's logical_axes, block tables / token ids stay replicated.
         These are what every program family compiles with (in == out for
         the donated caches, so page updates keep aliasing in place — an
         unconstrained output sharding breaks donation, the way it once
         did for sharded opt_state)."""
         from ..parallel import sharding as shardlib
         from ..parallel.mesh import use_mesh
-        mc = self.cfg.model
-        sizes = dict(zip(self.mesh.axis_names, self.mesh.devices.shape))
-        tp = sizes.get("tp", 1)
-        if mc.n_kv_heads % tp or mc.n_heads % tp or mc.mlp_dim % tp:
-            raise ValueError(
-                f"mesh tp={tp} must divide n_heads={mc.n_heads}, "
-                f"n_kv_heads={mc.n_kv_heads} and mlp_dim={mc.mlp_dim}")
-        # vocab shards over (tp, fsdp) — embeddings/lm_head split both ways
-        vocab_ways = tp * sizes.get("fsdp", 1)
-        if mc.vocab_size % vocab_ways:
-            raise ValueError(
-                f"mesh tp*fsdp={vocab_ways} must divide "
-                f"vocab_size={mc.vocab_size}")
+        mc, model = self.cfg.model, self.model
+        model.check_mesh(mc, dict(zip(self.mesh.axis_names,
+                                      self.mesh.devices.shape)))
         with use_mesh(self.mesh):
             repl = shardlib.named_sharding(())
-            pshard = shardlib.logical_sharding(llama.logical_axes(mc))
-            kv = shardlib.named_sharding((None, None, "kv_heads"))
-            cshard = [{"k": kv, "v": kv} for _ in self.caches]
+            pshard = shardlib.logical_sharding(model.logical_axes(mc))
+            pools = {name: shardlib.named_sharding(axes) for name, axes
+                     in model.cache_logical_axes(mc).items()}
+            cshard = [dict(pools) for _ in self.caches]
             lshard = repl
             if self.lora is not None:
                 lshard = shardlib.logical_sharding(
@@ -577,14 +592,14 @@ class PagedInferenceEngine:
         fn = self._decode_win_fns.get((w, mode, pages))
         if fn is None:
             mc, page = self.cfg.model, self.cfg.page_size
-            interpret = self._interpret
+            model, interpret = self.model, self._interpret
             any_sampled, any_topk, want_logp = mode
 
             def run(p, c, tok0, bt, ln0, key, ctr, temps, top_ks,
                     lora=None, slots=None):
                 def body(carry, i):
                     toks, lens, caches = carry
-                    logits, caches, load = llama.decode_paged(
+                    logits, caches, load = model.decode_paged(
                         p, toks[:, None], caches, bt, lens, mc,
                         page_size=page, interpret=interpret,
                         lora=lora, slots=slots)
@@ -615,12 +630,12 @@ class PagedInferenceEngine:
         fn = self._prefill_rows_fns.get((r, mode, pages))
         if fn is None:
             mc, page = self.cfg.model, self.cfg.page_size
-            interpret = self._interpret
+            model, interpret = self.model, self._interpret
             any_sampled, any_topk, want_logp = mode
 
             def run(p, c, chunks, bts, sps, tls, key, ctr, temps, top_ks,
                     lora=None, slots=None):
-                last, c, load = llama.prefill_paged_rows(
+                last, c, load = model.prefill_paged_rows(
                     p, chunks, c, bts, sps, tls, mc, page_size=page,
                     interpret=interpret, lora=lora, slots=slots)
                 toks, lps = sample_logits_batch(
@@ -643,10 +658,10 @@ class PagedInferenceEngine:
         fn = self._verify_fns.get((r, s1, pages, want_logp))
         if fn is None:
             mc, page = self.cfg.model, self.cfg.page_size
-            interpret = self._interpret
+            model, interpret = self.model, self._interpret
 
             def run(p, c, toks, bts, starts, lora=None, slots=None):
-                logits, c, load = llama.verify_paged_rows(
+                logits, c, load = model.verify_paged_rows(
                     p, toks, c, bts, starts, mc, page_size=page,
                     interpret=interpret, lora=lora, slots=slots)
                 y = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -917,10 +932,8 @@ class PagedInferenceEngine:
         now = time.monotonic()
         if not self.spill.policy.admit(self.chains, slot, now):
             return      # heat-gated: not worth tier residence — free
-        ks = [np.asarray(layer["k"][pid]) for layer in self.caches]
-        vs = [np.asarray(layer["v"][pid]) for layer in self.caches]
         chain = slot if slot is not None else 0
-        expired = self.spill.add(h, chain, ks, vs, now)
+        expired = self.spill.add(h, chain, self._gather_pages(pid), now)
         captured = self.spill.has(h)
         if captured:
             self.stats["spill_demotions"] += 1
@@ -1443,8 +1456,8 @@ class PagedInferenceEngine:
         the routing was. ``load`` is None for a dense config."""
         if load is None:
             return
-        mc, st = self.cfg.model, self.stats
-        per_token = mc.moe_top_k * mc.n_layers
+        st = self.stats
+        per_token = self.model.routed_per_token(self.cfg.model)
         st["moe_assign_live"] += live_tokens * per_token
         st["moe_assign_run"] += run_tokens * per_token
         st["moe_expert_load_sum"] += int(load.sum())
@@ -1724,10 +1737,8 @@ class PagedInferenceEngine:
         """Gather this request's KV pages to host arrays for transfer to a
         decode replica (the role the KV-connector plays for the reference's
         PD deployments)."""
-        idx = jnp.asarray(np.asarray(req.pages, np.int32))
-        pages = [{"k": np.asarray(layer["k"][idx]),
-                  "v": np.asarray(layer["v"][idx])}
-                 for layer in self.caches]
+        pages = self._gather_pages(
+            jnp.asarray(np.asarray(req.pages, np.int32)))
         return {"prompt_ids": list(req.prompt_ids),
                 "first_token": int(first_token),
                 "page_size": self.cfg.page_size,
@@ -1775,7 +1786,7 @@ class PagedInferenceEngine:
                 raise RuntimeError("no free decode slot")
             req.slot = self._free_slots.popleft()
             n_pages = self._pages_needed(len(ids) + 1)
-            n_in = len(payload["pages"][0]["k"])
+            n_in = len(next(iter(payload["pages"][0].values())))
             if n_in != n_pages:
                 self._release(req)
                 raise ValueError(
@@ -1823,16 +1834,8 @@ class PagedInferenceEngine:
                         self.chains.miss(req.chain_slot,
                                          nf - len(matched))
             if fresh:
-                idx = jnp.asarray(np.asarray(
-                    [pages[i] for i in fresh], np.int32))
-                sel = np.asarray(fresh)
-                for li, layer in enumerate(self.caches):
-                    layer["k"] = self._import_fn(
-                        layer["k"], idx,
-                        jnp.asarray(payload["pages"][li]["k"][sel]))
-                    layer["v"] = self._import_fn(
-                        layer["v"], idx,
-                        jnp.asarray(payload["pages"][li]["v"][sel]))
+                self._scatter_pages([pages[i] for i in fresh],
+                                    payload["pages"], fresh)
                 if self._prefix_on and hashes:
                     for i in fresh:
                         if i < len(hashes):
@@ -1848,26 +1851,47 @@ class PagedInferenceEngine:
             self._maybe_finish(req, tok)
         return req
 
-    @property
-    def _import_fn(self):
-        fn = getattr(self, "_import_fn_cached", None)
-        if fn is None:
-            # donated in-place page scatter: cache pools are not copied
+    # A layer's cache is a dict of named pools [P, page, ...] whose names
+    # and shapes are the model's (llama: "k" and "v"; mla_moe: "ckv"). Spill,
+    # export and import move whole pages of every pool and look at nothing
+    # inside them: a payload's "pages" is one {pool name: [n, page, ...]}
+    # a layer.
+
+    def _gather_pages(self, idx) -> list[dict]:
+        """Host copies of the pages ``idx`` (one id or an array of them)
+        of every pool, a dict a layer."""
+        return [{name: np.asarray(pool[idx]) for name, pool in layer.items()}
+                for layer in self.caches]
+
+    def _scatter_pages(self, pids, payload_pages, sel) -> None:
+        """Write rows ``sel`` of a payload's pages into pages ``pids`` of
+        the pools, donated and in place."""
+        idx = jnp.asarray(np.asarray(pids, np.int32))
+        sel = np.asarray(sel)
+        for layer, data in zip(self.caches, payload_pages):
+            for name in layer:
+                layer[name] = self._import_fn(name)(
+                    layer[name], idx, jnp.asarray(data[name][sel]))
+
+    def _import_fn(self, pool: str):
+        """The donated in-place page scatter into one named pool (cache
+        pools are not copied); off a mesh one program serves them all."""
+        fns = self._import_fns
+        key = pool if self.mesh is not None else None
+        if key not in fns:
+            scatter = lambda c, idx, data: c.at[idx].set(data)  # noqa: E731
             if self.mesh is None:
-                fn = jax.jit(lambda c, idx, data: c.at[idx].set(data),
-                             donate_argnums=(0,))
+                fns[key] = jax.jit(scatter, donate_argnums=(0,))
             else:
                 # pinned shardings keep the donated pool usable in place
                 # (out == in) and land the host payload replicated-then-
                 # scattered without resharding the pool itself
-                kv = self._shardings["caches"][0]["k"]
+                kv = self._shardings["caches"][0][pool]
                 repl = self._shardings["repl"]
-                fn = jax.jit(lambda c, idx, data: c.at[idx].set(data),
-                             donate_argnums=(0,),
-                             in_shardings=(kv, repl, repl),
-                             out_shardings=kv)
-            self._import_fn_cached = fn
-        return fn
+                fns[key] = jax.jit(scatter, donate_argnums=(0,),
+                                   in_shardings=(kv, repl, repl),
+                                   out_shardings=kv)
+        return fns[key]
 
     # -- cluster prefix-cache directory hooks (serve/frontdoor/prefix.py;
     # cross-replica page import extends the import_prefill contract:
@@ -1919,10 +1943,8 @@ class PagedInferenceEngine:
                 pids.append(pid)
             if not pids:
                 return None
-            idx = jnp.asarray(np.asarray(pids, np.int32))
-            pages = [{"k": np.asarray(layer["k"][idx]),
-                      "v": np.asarray(layer["v"][idx])}
-                     for layer in self.caches]
+            pages = self._gather_pages(
+                jnp.asarray(np.asarray(pids, np.int32)))
             self.stats["prefix_exported_pages"] += len(pids)
             if self.chains is not None:
                 # peek, never assign: an export targets pages this
@@ -1984,15 +2006,7 @@ class PagedInferenceEngine:
             budget -= 1
         if not take_pids:
             return 0
-        idx = jnp.asarray(np.asarray(take_pids, np.int32))
-        sel = np.asarray(take_idx)
-        for li, layer in enumerate(self.caches):
-            layer["k"] = self._import_fn(
-                layer["k"], idx,
-                jnp.asarray(payload["pages"][li]["k"][sel]))
-            layer["v"] = self._import_fn(
-                layer["v"], idx,
-                jnp.asarray(payload["pages"][li]["v"][sel]))
+        self._scatter_pages(take_pids, payload["pages"], take_idx)
         slot = -1
         if chain is not None:
             slot = chain

@@ -116,15 +116,15 @@ class SpillPolicy:
 
 class _SpilledPage:
     """One demoted page: chain attribution + either staged host arrays
-    (ks/vs, one per layer) or a pointer into a stored segment."""
+    (``pools``: a {pool name: array} a layer, whatever pools the model's
+    cache has) or a pointer into a stored segment."""
 
-    __slots__ = ("chain", "seq", "ks", "vs", "seg", "row")
+    __slots__ = ("chain", "seq", "pools", "seg", "row")
 
-    def __init__(self, chain: int, seq: int, ks, vs):
+    def __init__(self, chain: int, seq: int, pools):
         self.chain = chain
         self.seq = seq
-        self.ks = ks            # staged: list[np.ndarray] per layer
-        self.vs = vs
+        self.pools = pools      # staged: list[dict[str, np.ndarray]]
         self.seg: Optional[str] = None   # stored: segment id
         self.row: int = -1               # row inside the segment payload
 
@@ -193,7 +193,7 @@ class SpillTier:
             self._seq += 1
             e.seq = self._seq
 
-    def add(self, h: bytes, chain: int, ks, vs,
+    def add(self, h: bytes, chain: int, pools,
             now: float = 0.0) -> list:
         """Stage a captured page. Returns the entries expired to fit
         the budget as ``[(hash, chain), ...]`` so the caller can keep
@@ -202,7 +202,7 @@ class SpillTier:
         if self.page_nbytes > self.max_bytes:
             return [(h, chain)]
         self._seq += 1
-        self._pages[h] = _SpilledPage(chain, self._seq, ks, vs)
+        self._pages[h] = _SpilledPage(chain, self._seq, pools)
         self.resident_bytes += self.page_nbytes
         self._pub_new.append(h)
         expired = []
@@ -239,7 +239,7 @@ class SpillTier:
                 if not seg.live:
                     del self._segs[e.seg]   # last member: drop the ref
         else:
-            e.ks = e.vs = None
+            e.pools = None
 
     def discard(self, hashes) -> list:
         """Drop entries outright (validate-on-promote failures, expiry
@@ -282,7 +282,7 @@ class SpillTier:
         resolves a stored segment's ref to its payload (ray_tpu.get
         under the serving layer; None = staged-only, the engine-local
         default — stored entries just end the run there)."""
-        rows: list = []           # (hash, list[k_layer], list[v_layer])
+        rows: list = []           # (hash, [{pool name: page} a layer])
         seg_cache: dict[str, Any] = {}
         bad: list[bytes] = []
         for h in hashes:
@@ -290,10 +290,10 @@ class SpillTier:
             if e is None:
                 break
             if e.seg is None:
-                if e.ks is None or e.vs is None:
+                if e.pools is None:
                     bad.append(h)
                     break
-                rows.append((h, e.ks, e.vs))
+                rows.append((h, e.pools))
                 continue
             seg = self._segs.get(e.seg)
             payload = seg_cache.get(e.seg)
@@ -310,10 +310,11 @@ class SpillTier:
                 seg_cache[e.seg] = payload
             try:
                 i = payload["page_hashes"].index(h)
-                rows.append((h,
-                             [lay["k"][i] for lay in payload["pages"]],
-                             [lay["v"][i] for lay in payload["pages"]]))
-            except (ValueError, KeyError, IndexError, TypeError):
+                rows.append((h, [{name: pool[i] for name, pool
+                                  in lay.items()}
+                                 for lay in payload["pages"]]))
+            except (ValueError, KeyError, IndexError, TypeError,
+                    AttributeError):
                 bad.append(h)       # segment no longer carries the hash
                 break
         if bad:
@@ -322,19 +323,15 @@ class SpillTier:
             return None, self.discard(bad)
         if not rows:
             return None, []
-        n_layers = len(rows[0][1])
-        shapes = [np.shape(k) for k in rows[0][1]]
-        for _h, ks, vs in rows:
-            if len(ks) != n_layers or \
-                    any(np.shape(k) != s for k, s in zip(ks, shapes)):
+        geometry = _geometry(rows[0][1])
+        for _h, pools in rows:
+            if _geometry(pools) != geometry:
                 return None, self.discard([_h])  # geometry drift:
                 # never scatter it into the live cache pools
         return {
             "page_size": page_size,
             "page_hashes": [r[0] for r in rows],
-            "pages": [{"k": np.stack([r[1][li] for r in rows]),
-                       "v": np.stack([r[2][li] for r in rows])}
-                      for li in range(n_layers)],
+            "pages": stack_pages([r[1] for r in rows]),
         }, []
 
     # -- cluster materialization (serving loop) ------------------------
@@ -378,13 +375,10 @@ class SpillTier:
             by_chain.setdefault(e.chain, []).append(h)
         for _chain, group in by_chain.items():
             entries = [self._pages[h] for h in group]
-            n_layers = len(entries[0].ks)
             payload = {
                 "page_size": page_size,
                 "page_hashes": list(group),
-                "pages": [{"k": np.stack([e.ks[li] for e in entries]),
-                           "v": np.stack([e.vs[li] for e in entries])}
-                          for li in range(n_layers)],
+                "pages": stack_pages([e.pools for e in entries]),
             }
             try:
                 ref = put(payload)
@@ -396,7 +390,7 @@ class SpillTier:
             for i, h in enumerate(group):
                 e = self._pages[h]
                 e.seg, e.row = seg_id, i
-                e.ks = e.vs = None
+                e.pools = None
                 out[h] = ref.binary()
         return out
 
@@ -410,6 +404,18 @@ class SpillTier:
                                 if e.seg is None),
             "stored_segments": len(self._segs),
         }
+
+
+def _geometry(pools: list) -> list:
+    """[{pool name: shape} a layer] of one page's staged arrays."""
+    return [{name: np.shape(a) for name, a in lay.items()} for lay in pools]
+
+
+def stack_pages(pages: list) -> list:
+    """n pages, each a {pool name: page} a layer -> the payload form: a
+    {pool name: [n, ...]} a layer."""
+    return [{name: np.stack([pg[li][name] for pg in pages])
+             for name in pages[0][li]} for li in range(len(pages[0]))]
 
 
 def _payload_ok(payload, page_size: int) -> bool:

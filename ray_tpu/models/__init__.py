@@ -13,7 +13,7 @@ same contract:
 """
 import importlib
 
-_MODULES = ("llama", "resnet")
+_MODULES = ("llama", "mla_moe", "resnet")
 __all__ = list(_MODULES)
 
 

@@ -214,6 +214,41 @@ def logical_axes(cfg: LlamaConfig) -> dict:
     }
 
 
+# -- what the serving engine asks a model module beside its forwards
+# (llm/paged_engine.py model_module; models/mla_moe.py gives the same) ----
+
+def cache_logical_axes(cfg: LlamaConfig) -> dict:
+    """Logical axes of one layer's page pools, by pool name."""
+    return {"k": _PAGES_AXES, "v": _PAGES_AXES}
+
+
+def check_mesh(cfg: LlamaConfig, sizes: dict) -> None:
+    """Refuse a mesh (axis name -> size) this model cannot be split
+    over."""
+    tp = sizes.get("tp", 1)
+    if cfg.n_kv_heads % tp or cfg.n_heads % tp or cfg.mlp_dim % tp:
+        raise ValueError(
+            f"mesh tp={tp} must divide n_heads={cfg.n_heads}, "
+            f"n_kv_heads={cfg.n_kv_heads} and mlp_dim={cfg.mlp_dim}")
+    # vocab shards over (tp, fsdp) — embeddings/lm_head split both ways
+    vocab_ways = tp * sizes.get("fsdp", 1)
+    if cfg.vocab_size % vocab_ways:
+        raise ValueError(
+            f"mesh tp*fsdp={vocab_ways} must divide "
+            f"vocab_size={cfg.vocab_size}")
+
+
+def lora_targets(cfg: LlamaConfig) -> tuple:
+    """Projections a LoRA slot table may adapt (_lora_add's targets)."""
+    return _LORA_LAYER_TARGETS + ("lm_head",)
+
+
+def routed_per_token(cfg: LlamaConfig) -> int:
+    """Token-expert assignments one token makes through the whole depth;
+    0 for a dense config, whose programs return no load."""
+    return cfg.moe_top_k * cfg.n_layers if cfg.moe_experts else 0
+
+
 # ---------------------------------------------------------------------------
 # Building blocks
 # ---------------------------------------------------------------------------
@@ -384,22 +419,36 @@ def _moe_ffn(h, p, cfg: LlamaConfig, interpret: bool = False):
     me = probs.mean(axis=(0, 1))                               # [E]
     ce = jax.nn.one_hot(idx_k[..., 0], E).mean(axis=(0, 1))    # [E]
     aux = E * jnp.sum(me * ce)
-    load = (idx_k.reshape(-1, 1) == jnp.arange(E)).sum(0, dtype=jnp.int32)
+    load = expert_load(idx_k, E)
+    out = routed_experts(h, idx_k, gate_k, p, E, cfg.mlp_dim, interpret)
+    return out, aux, load
 
+
+def expert_load(idx_k, n_experts: int):
+    """[E] int32: the assignments each expert got from idx_k [B, S, k]."""
+    return (idx_k.reshape(-1, 1) == jnp.arange(n_experts)).sum(
+        0, dtype=jnp.int32)
+
+
+def routed_experts(h, idx_k, gate_k, p, n_experts: int, mlp_dim: int,
+                   interpret: bool = False):
+    """sum_j gate_j * expert_{idx_j}(h) for h [B, S, D] and its [B, S, k]
+    experts and weights, whatever router chose them (_moe_ffn's softmax
+    top-k, models/mla_moe.py's sigmoid scores): the grouped expert FFN
+    over p's ``w_gate`` / ``w_up`` / ``w_down``, per shard under a mesh."""
     tok = ("batch", "sequence", None)
     # the serving paths hand over the layers' stacks and a layer index
     # (_layer_params): one more leading, unsharded axis
     layer = p.get("expert_layer")
     stack = () if layer is None else (None,)
     ffn = shard_kernel(
-        functools.partial(_expert_ffn, n_experts=E, mlp_dim=cfg.mlp_dim,
+        functools.partial(_expert_ffn, n_experts=n_experts, mlp_dim=mlp_dim,
                           layer=layer, kernel=interpret or _on_tpu(),
                           interpret=interpret),
         (tok, tok, tok, stack + ("expert", None, "mlp"),
          stack + ("expert", None, "mlp"), stack + ("expert", "mlp", None)),
         tok)
-    out = ffn(h, idx_k, gate_k, p["w_gate"], p["w_up"], p["w_down"])
-    return out, aux, load
+    return ffn(h, idx_k, gate_k, p["w_gate"], p["w_up"], p["w_down"])
 
 
 def _expert_ffn(h, idx, gate, w_gate, w_up, w_down, *, n_experts: int,
@@ -731,21 +780,14 @@ def _prefill_rows(params: dict, chunks: jax.Array, caches: list[dict],
     read sink-routed garbage the caller discards."""
     r, c = chunks.shape
     n_chunk_pages = c // page_size
-    max_pages = bt_rows.shape[1]
     if lora is not None and slots is None:
         slots = jnp.zeros((r,), jnp.int32)
     starts = start_pos.astype(jnp.int32)
     q_lens = true_lens.astype(jnp.int32)
     cos, sin = rope_freqs(cfg, starts[:, None] + jnp.arange(c)[None, :])
     attend = _window_attend(cfg.head_dim ** -0.5, interpret)
-    # gather (not dynamic_slice: it clamps at the row end and would silently
-    # shift the write window); invalid logical pages route to sink page 0
-    page_no = jnp.arange(n_chunk_pages)[None, :]
-    logical = starts[:, None] // page_size + page_no           # [R, C/page]
-    valid_pages = (q_lens[:, None] + page_size - 1) // page_size
-    valid = (page_no < valid_pages) & (logical < max_pages)
-    chunk_page_ids = jnp.where(valid, jnp.take_along_axis(
-        bt_rows, jnp.clip(logical, 0, max_pages - 1), axis=1), 0)
+    chunk_page_ids = chunk_pages(bt_rows, starts, q_lens, n_chunk_pages,
+                                 page_size)
     paged = (r, n_chunk_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
 
     x = params["embed"][chunks].astype(cfg.dtype)              # [R, C, D]
@@ -787,6 +829,23 @@ def _prefill_rows(params: dict, chunks: jax.Array, caches: list[dict],
     if lora is not None and "lm_head.A" in lora:
         logits = _lora_add(logits, x, lora, "lm_head", slots)
     return logits, new_caches, load
+
+
+def chunk_pages(bt_rows, starts, q_lens, n_chunk_pages: int,
+                page_size: int):
+    """[R, C / page] physical pages the chunk-rows' K/V are written to:
+    row r's chunk starts at the page-aligned position starts[r] and holds
+    q_lens[r] real tokens. Pages past a row's real tokens, and logical
+    pages beyond the block table, are sink page 0. A gather (not
+    dynamic_slice: it clamps at the row end and would silently shift the
+    write window)."""
+    max_pages = bt_rows.shape[1]
+    page_no = jnp.arange(n_chunk_pages)[None, :]
+    logical = starts[:, None] // page_size + page_no           # [R, C/page]
+    valid_pages = (q_lens[:, None] + page_size - 1) // page_size
+    valid = (page_no < valid_pages) & (logical < max_pages)
+    return jnp.where(valid, jnp.take_along_axis(
+        bt_rows, jnp.clip(logical, 0, max_pages - 1), axis=1), 0)
 
 
 def prefill_paged_chunk(params: dict, chunk: jax.Array, caches: list[dict],

@@ -124,14 +124,22 @@ def _softmax_step(s, keep, m_ref, l_ref, i):
 
 
 def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
-                   q_ref, k_hbm, v_hbm,               # q block; pools in HBM
-                   o_ref,                             # output block
-                   k_buf, v_buf, sems,                # block buffers, DMA
-                   acc_ref, m_ref, l_ref,             # softmax state
-                   *, scale: float, page_size: int, num_kv_heads: int,
-                   groups: int, q_tile: int, block_pages: int):
+                   q_ref, *refs,
+                   scale: float, page_size: int, num_kv_heads: int,
+                   groups: int, q_tile: int, block_pages: int,
+                   v_width: int | None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    if v_width is None:
+        # pools in HBM; output block; block buffers, DMA; softmax state
+        (k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+         acc_ref, m_ref, l_ref) = refs
+    else:
+        # the latent form: ONE pool and one block buffer, whose first
+        # ``v_width`` lanes are the values — each page is copied once
+        k_hbm, o_ref, k_buf, sems, acc_ref, m_ref, l_ref = refs
+        v_hbm = v_buf = None
 
     r = pl.program_id(0)
     t = pl.program_id(1)
@@ -156,10 +164,11 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
     block_keys = block_pages * page_size
     heads = num_kv_heads * groups
     d = q_ref.shape[-1]
-    all_heads = _all_heads(q_tile, groups)
+    d_v = d if v_width is None else v_width
+    all_heads = v_width is None and _all_heads(q_tile, groups)
 
     def _copies(block, slot):
-        """The 2 x block_pages page copies that fill buffer ``slot`` with
+        """The page copies (one a pool a page) that fill buffer ``slot`` with
         logical pages [block * block_pages, ...) of row r, clamped to
         the tile's last live page: a page the row does not own is never
         read (the table's tail is stale, or the poisoned sink), and the
@@ -171,8 +180,9 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
             rows = pl.ds(j * page_size, page_size)
             out.append(pltpu.make_async_copy(
                 k_hbm.at[phys], k_buf.at[slot, rows], sems.at[0, slot]))
-            out.append(pltpu.make_async_copy(
-                v_hbm.at[phys], v_buf.at[slot, rows], sems.at[1, slot]))
+            if v_hbm is not None:
+                out.append(pltpu.make_async_copy(
+                    v_hbm.at[phys], v_buf.at[slot, rows], sems.at[1, slot]))
         return out
 
     def _keep(rows, rows_per_query):
@@ -241,10 +251,11 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
                 q_sub, k_buf[slot, :, lanes], (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             pexp, alpha = _softmax_step(s, keep, m_ref, l_ref, h)
-            v_blk = v_buf[slot, :, lanes]                 # [block_keys, D]
+            v_blk = (k_buf[slot, :, :d_v] if v_buf is None
+                     else v_buf[slot, :, lanes])          # [block_keys, Dv]
             pv = jax.lax.dot_general(
                 pexp.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)       # [Q*G, D]
+                preferred_element_type=jnp.float32)       # [Q*G, Dv]
             acc_ref[h] = acc_ref[h] * alpha + pv
 
     @pl.when(b == pl.num_programs(2) - 1)
@@ -254,9 +265,9 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
             o_ref[...] = o.reshape(q_tile, heads, d).astype(o_ref.dtype)
             return
         for h in range(num_kv_heads):
-            o = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)  # [Q*G, D]
+            o = acc_ref[h] / jnp.maximum(l_ref[h], 1e-30)  # [Q*G, Dv]
             o_ref[:, h * groups:(h + 1) * groups, :] = o.reshape(
-                q_tile, groups, d).astype(o_ref.dtype)
+                q_tile, groups, d_v).astype(o_ref.dtype)
 
 
 # Most query elements (tile * heads * head_dim) one grid step may hold:
@@ -325,14 +336,21 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
 # shape, not once per layer: models/llama.py unrolls its layers in
 # Python, and 20 lowerings of this body are 3.7-5.6 s of every program's
 # set-up where one shared function is 0.1-0.2 s (sandbox, PR 25).
-@functools.partial(jax.jit, static_argnames=("scale", "q_tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "q_tile", "interpret",
+                                             "v_width"))
 def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
-                 scale: float, q_tile: int, interpret: bool):
+                 scale: float, q_tile: int, interpret: bool,
+                 v_width: int | None = None):
+    """``v_pages`` None is the latent form (ragged_latent_attention): one
+    pool, one shared kv head as wide as q, values its first ``v_width``
+    lanes."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     r, qw, h, d = q.shape
     _, page_size, kv_lanes = k_pages.shape
+    latent = v_pages is None
+    d_v = v_width if latent else d
     kvh = kv_lanes // d
     groups = h // kvh
     nb = _block_pages(page_size)
@@ -344,26 +362,27 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
 
     kernel = functools.partial(
         _ragged_kernel, scale=scale, page_size=page_size,
-        num_kv_heads=kvh, groups=groups, q_tile=q_tile, block_pages=nb)
+        num_kv_heads=kvh, groups=groups, q_tile=q_tile, block_pages=nb,
+        v_width=v_width if latent else None)
 
     def _q_index(ri, t, b, bt, start, qlen):
         return (ri, t, 0, 0)
 
     pool_in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     block_buf = pltpu.VMEM((2, nb * page_size, kv_lanes), k_pages.dtype)
+    pools = (k_pages,) if latent else (k_pages, v_pages)
     # softmax state: a slot a kv head, or one slot for all heads
-    slots, rows = (1, q_tile * h) if _all_heads(q_tile, groups) else (
-        kvh, q_tile * groups)
+    slots, rows = (1, q_tile * h) if not latent and _all_heads(
+        q_tile, groups) else (kvh, q_tile * groups)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(r, n_tiles, -(-block_tables.shape[1] // nb)),
-        in_specs=[pl.BlockSpec((None, q_tile, h, d), _q_index),
-                  pool_in_hbm, pool_in_hbm],
-        out_specs=pl.BlockSpec((None, q_tile, h, d), _q_index),
-        scratch_shapes=[
-            block_buf, block_buf,                   # K, V: two slots each
-            pltpu.SemaphoreType.DMA((2, 2)),        # [K | V, slot]
-            pltpu.VMEM((slots, rows, d), jnp.float32),
+        in_specs=[pl.BlockSpec((None, q_tile, h, d), _q_index)]
+        + [pool_in_hbm] * len(pools),
+        out_specs=pl.BlockSpec((None, q_tile, h, d_v), _q_index),
+        scratch_shapes=[block_buf] * len(pools) + [  # two slots a pool
+            pltpu.SemaphoreType.DMA((len(pools), 2)),   # [pool, slot]
+            pltpu.VMEM((slots, rows, d_v), jnp.float32),
             pltpu.VMEM((slots, rows, 1), jnp.float32),
             pltpu.VMEM((slots, rows, 1), jnp.float32),
         ],
@@ -371,11 +390,43 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((r, padded, h, d_v), q.dtype),
         interpret=interpret,
-        name="ragged_paged_attention",
-    )(block_tables, starts, q_lens, q, k_pages, v_pages)
+        # the trace reduction tells the family by this prefix and its two
+        # shapes by the output's window (benchmarks/reduce/)
+        name="ragged_paged_attention" + ("_latent" if latent else ""),
+    )(block_tables, starts, q_lens, q, *pools)
     return out[:, :qw] if padded != qw else out
+
+
+# The latent form's query tile (rows x heads x lanes one grid step may
+# hold): all heads share the one key block, so a tile's rows x heads are
+# the rows of ONE product, and a wider tile sweeps a row's pages fewer
+# times. Measured at 32 heads x 640 lanes, a 128-row chunk over 12,288
+# cached tokens, us a call (PERF.md §6, PR 31): tile 8 1,991, 16 1,757,
+# 32 1,631; 64 rows exceed the 16 MiB of scoped VMEM.
+_LATENT_TILE_ELEMS = 32 * 32 * 640
+
+
+def ragged_latent_attention(q, pages, block_tables, starts, q_lens, *,
+                            v_width: int, scale: float,
+                            interpret: bool = False):
+    """The family's latent (MLA, absorbed) form: ONE pool
+    ``pages [P, page, W]`` holds, per token, one shared kv head whose KEY
+    is all ``W`` lanes and whose VALUE is the key's first ``v_width``
+    lanes (the compressed latent; the rope key rides behind it).
+    q [R, Q, H, W] — every query head attends that one head — and the
+    result is [R, Q, H, v_width]. Rows, windows, the page sweep, the
+    clamp, the dead-step skip and the softmax step are
+    ragged_paged_attention's own (same contract for block_tables, starts,
+    q_lens); each page is copied into VMEM once and serves both products.
+    ``scale`` is the model's (the uncompressed head size's, not W's)."""
+    _, qw, h, d = q.shape
+    fit = max(8, _LATENT_TILE_ELEMS // (h * d) // 8 * 8)
+    return _ragged_call(
+        q, pages, None, block_tables, starts, q_lens, scale=float(scale),
+        q_tile=qw if qw <= fit else fit, interpret=interpret,
+        v_width=int(v_width))
 
 
 def ragged_decode_attention(q, k_pages, v_pages, block_table, lengths,
@@ -408,7 +459,7 @@ def ragged_paged_reference(q, k_pages, v_pages, block_tables, starts,
     if scale is None:
         scale = d ** -0.5
     k = k_pages[block_tables].reshape(r, klen, kvh, d)
-    v = v_pages[block_tables].reshape(r, klen, kvh, d)
+    v = v_pages[block_tables].reshape(r, klen, kvh, -1)   # [.., Dv]
     qg = q.reshape(r, qw, kvh, groups, d).astype(jnp.float32)
     s = jnp.einsum("rqhgd,rkhd->rhgqk", qg,
                    k.astype(jnp.float32)) * scale
@@ -420,7 +471,16 @@ def ragged_paged_reference(q, k_pages, v_pages, block_tables, starts,
     s = jnp.where(keep[:, None, None], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("rhgqk,rkhd->rqhgd", w, v.astype(jnp.float32))
-    return out.reshape(r, qw, h, d).astype(q.dtype)
+    return out.reshape(r, qw, h, -1).astype(q.dtype)
+
+
+def ragged_latent_reference(q, pages, block_tables, starts, q_lens, *,
+                            v_width: int, scale: float):
+    """ragged_latent_attention's oracle and CPU fallback: the window
+    oracle over one kv head whose values are the keys' first ``v_width``
+    lanes."""
+    return ragged_paged_reference(q, pages, pages[..., :v_width],
+                                  block_tables, starts, q_lens, scale)
 
 
 def paged_decode_reference(q, k_pages, v_pages, block_table, lengths,

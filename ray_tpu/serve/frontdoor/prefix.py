@@ -294,7 +294,7 @@ class PrefixDirectoryClient:
         from ...core.config import cfg
         from ...core.ids import ObjectID
         from ...core.ref import ObjectRef
-        from ...llm.tiering import _payload_ok
+        from ...llm.tiering import _payload_ok, stack_pages
         import numpy as np
         import ray_tpu
         run = hashes[local:spill_i + 1]
@@ -333,23 +333,19 @@ class PrefixDirectoryClient:
                 break
             try:
                 i = payload["page_hashes"].index(h)
-                rows.append((h,
-                             [lay["k"][i] for lay in payload["pages"]],
-                             [lay["v"][i] for lay in payload["pages"]]))
+                rows.append((h, [{name: pool[i] for name, pool
+                                  in lay.items()}
+                                 for lay in payload["pages"]]))
             except Exception:
                 stale.append(key)   # segment no longer carries the hash
                 break
         n = 0
         if rows:
             try:
-                n_layers = len(rows[0][1])
                 combined = {
                     "page_size": page_size,
                     "page_hashes": [r[0] for r in rows],
-                    "pages": [
-                        {"k": np.stack([r[1][li] for r in rows]),
-                         "v": np.stack([r[2][li] for r in rows])}
-                        for li in range(n_layers)],
+                    "pages": stack_pages([r[1] for r in rows]),
                 }
                 with steplock:
                     n = engine.import_prefix(combined)

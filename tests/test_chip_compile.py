@@ -115,6 +115,36 @@ def test_ragged_compiles_at_the_benchmark_cells_shapes(v5e, rows, q_window,
                      text)
 
 
+@pytest.mark.parametrize("rows,q_window,max_pages", [
+    (32, 1, 1024),         # decode: max_batch_size rows, the longest table
+    (4, 128, 1024),        # prefill: four chunk_size rows, 32-row tiles
+], ids=["decode", "prefill"])
+def test_latent_form_compiles_at_the_kanana_cells_shapes(v5e, rows,
+                                                         q_window,
+                                                         max_pages):
+    """`kanana-longdoc-sessions-1chip`: 32 heads on ONE latent kv head of
+    640 lanes (576 used), values its first 512. A 576-lane pool is refused
+    here (a page copy must be whole 128-lane tiles: PERF.md §6, PR 31)."""
+    from ray_tpu.ops.ragged_paged_attention import ragged_latent_attention
+    one = SingleDeviceSharding(v5e.devices[0])
+
+    def fn(lanes):
+        return _compile(
+            functools.partial(ragged_latent_attention, v_width=512,
+                              scale=192 ** -0.5),
+            ((rows, q_window, H, lanes), BF16), ((25600, 16, lanes), BF16),
+            ((rows, max_pages), jnp.int32), ((rows,), jnp.int32),
+            ((rows,), jnp.int32), sharding=[one] * 5).as_text()
+    # the name's prefix and the result's [rows, window, heads, Dv] are what
+    # reduce/families/*.json and reduce/kernels/*.json tell it by
+    assert re.search(rf"%ragged_paged_attention_latent[.\d]* = "
+                     rf"bf16\[{rows},{q_window},{H},512\].*tpu_custom_call",
+                     fn(640))
+    if q_window == 1:
+        with pytest.raises(Exception, match="aligned to tiling"):
+            fn(576)
+
+
 def test_flash_fwd_bwd_compile_at_8b_widths(v5e):
     one = SingleDeviceSharding(v5e.devices[0])
     q, kv = ((2, 1024, H, D), BF16), ((2, 1024, KVH, D), BF16)

@@ -12,7 +12,7 @@ import pytest
 from ray_tpu.llm import SamplingParams
 from ray_tpu.llm.paged_engine import (PHASES, PagedEngineConfig,
                                       PagedInferenceEngine)
-from ray_tpu.models import llama
+from ray_tpu.models import llama, mla_moe
 
 ENGINE_PHASES = [k for k in PHASES if "loop" not in k]
 
@@ -25,9 +25,11 @@ def _cfg(**over):
     return PagedEngineConfig(**kw)
 
 
-@pytest.fixture(scope="module")
-def engine():
-    eng = PagedInferenceEngine(_cfg(), rng_seed=0)
+@pytest.fixture(scope="module", params=["llama", "latent"])
+def engine(request):
+    over = {} if request.param == "llama" else dict(
+        model=mla_moe.mla_moe_tiny(vocab_size=258, max_seq_len=128))
+    eng = PagedInferenceEngine(_cfg(**over), rng_seed=0)
     # compile what the tests below dispatch, outside their clocks
     eng.generate([list(range(1, 40)), list(range(3, 20))],
                  SamplingParams(max_tokens=10))
@@ -154,6 +156,16 @@ def test_dispatch_and_request_counters(engine):
     assert d["prefill_attn_pairs"] >= d["prefill_tokens"]
     assert d["admitted"] == d["first_tokens"] == n
     assert d["queue_wait_ns"] > 0 and d["prefill_span_ns"] > 0
+    # a model that routes returns its [E] assignment counts with every
+    # dispatch (top-k x expert layers a token; the dense prefix and the
+    # shared expert route nothing); a dense model has no such keys
+    per_token = engine.model.routed_per_token(engine.cfg.model)
+    assert ("moe_assign_run" in d) == bool(per_token)
+    if per_token:
+        assert per_token == 2 * 2       # top-2, 2 of the 3 layers
+        assert d["moe_expert_load_sum"] == d["moe_assign_run"]
+        assert per_token * d["prefill_tokens"] < d["moe_assign_live"] \
+            <= d["moe_assign_run"]
 
 
 def test_launch_is_notified_once_a_dispatch(engine):

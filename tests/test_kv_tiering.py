@@ -14,9 +14,11 @@ import pytest
 from ray_tpu.llm import SamplingParams
 from ray_tpu.llm.paged_engine import PagedEngineConfig, PagedInferenceEngine
 from ray_tpu.llm.tiering import SpillPolicy, SpillTier
-from ray_tpu.models import llama
+from ray_tpu.models import llama, mla_moe
 
 TINY = llama.llama_tiny(vocab_size=258, max_seq_len=640)
+# ONE latent pool a layer where TINY has a k and a v pool
+LATENT = mla_moe.mla_moe_tiny(vocab_size=258, max_seq_len=640)
 
 
 def _cfg(**kw):
@@ -99,10 +101,10 @@ def test_spill_policy_gates_unit():
 
 
 def test_spill_tier_budget_unit():
-    ks = [np.zeros((2, 2), np.float32)]
+    pools = [{"ckv": np.zeros((2, 2), np.float32)}]
     tier = SpillTier(max_bytes=30, page_nbytes=10)
     hs = [bytes([i]) * 16 for i in range(4)]
-    expired = [tier.add(h, 0, ks, ks, now=float(i))
+    expired = [tier.add(h, 0, pools, now=float(i))
                for i, h in enumerate(hs)]
     # the 4th add pushed the tier over budget: FIFO victim (no chain
     # table bound) is the oldest entry
@@ -125,7 +127,7 @@ def test_spill_tier_budget_unit():
     assert tier.chain_of(hs[1]) == 0
     # a page larger than the whole budget is refused outright
     t2 = SpillTier(max_bytes=5, page_nbytes=10)
-    assert t2.add(b"h" * 16, 1, ks, ks) == [(b"h" * 16, 1)]
+    assert t2.add(b"h" * 16, 1, pools) == [(b"h" * 16, 1)]
     assert t2.resident_pages() == 0
     # teardown drops everything and reports it
     assert sorted(h for h, _c in tier.clear()) == sorted(hs[1:])
@@ -164,11 +166,12 @@ def test_tier_on_off_bit_identical_outputs():
     _assert_spill_parity(off)
 
 
-def test_demote_promote_bitwise_roundtrip():
+@pytest.mark.parametrize("model", [TINY, LATENT], ids=["llama", "latent"])
+def test_demote_promote_bitwise_roundtrip(model):
     """A promoted page is bit-identical to a never-evicted one: export
     the hot prefix, evict everything, promote it back via a resubmit,
-    export again — payloads match bitwise."""
-    eng = PagedInferenceEngine(_cfg(kv_spill=True), rng_seed=0)
+    export again — payloads match bitwise, every pool of every layer."""
+    eng = PagedInferenceEngine(_cfg(kv_spill=True, model=model), rng_seed=0)
     ids = _prompt(96, seed=11)
     _run_one(eng, ids, 2)
     hashes = eng.hash_prompt(ids)
@@ -181,9 +184,11 @@ def test_demote_promote_bitwise_roundtrip():
     assert eng.stats["spill_promotions"] >= len(hashes)
     after = eng.export_prefix(hashes)
     assert after["page_hashes"] == before["page_hashes"]
+    assert len(after["pages"]) == model.n_layers
     for la, lb in zip(after["pages"], before["pages"]):
-        assert np.array_equal(la["k"], lb["k"])
-        assert np.array_equal(la["v"], lb["v"])
+        assert set(la) == set(lb) == set(eng.caches[0])
+        for name in la:
+            assert np.array_equal(la[name], lb[name])
     _assert_spill_parity(eng)
 
 

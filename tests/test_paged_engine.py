@@ -9,13 +9,20 @@ import pytest
 
 from ray_tpu.llm import SamplingParams
 from ray_tpu.llm.paged_engine import PagedEngineConfig, PagedInferenceEngine
-from ray_tpu.models import llama
+from ray_tpu.models import llama, mla_moe
+
+# the engine's two model modules: k and v pools a layer, and ONE latent
+# pool a layer (models/mla_moe.py)
+TINY_MODELS = {
+    "llama": lambda **kw: llama.llama_tiny(vocab_size=258, **kw),
+    "latent": lambda **kw: mla_moe.mla_moe_tiny(vocab_size=258, **kw),
+}
 
 
-@pytest.fixture(scope="module")
-def engine():
+@pytest.fixture(scope="module", params=list(TINY_MODELS))
+def engine(request):
     cfg = PagedEngineConfig(
-        model=llama.llama_tiny(vocab_size=258, max_seq_len=128),
+        model=TINY_MODELS[request.param](max_seq_len=128),
         max_batch_size=4, page_size=8, num_pages=64,
         max_pages_per_seq=16, chunk_size=16)
     return PagedInferenceEngine(cfg, rng_seed=0)
@@ -29,8 +36,8 @@ def test_paged_greedy_matches_full_forward(engine):
     ids = list(prompt_ids)
     want = []
     for _ in range(8):
-        logits = llama.apply(engine.params, np.asarray([ids], np.int32),
-                             engine.cfg.model)
+        logits = engine.model.apply(
+            engine.params, np.asarray([ids], np.int32), engine.cfg.model)
         nxt = int(np.argmax(np.asarray(logits[0, -1])))
         want.append(nxt)
         ids.append(nxt)
@@ -48,8 +55,8 @@ def test_chunked_prefill_long_prompt(engine):
     ids = list(prompt_ids)
     want = []
     for _ in range(4):
-        logits = llama.apply(engine.params, np.asarray([ids], np.int32),
-                             engine.cfg.model)
+        logits = engine.model.apply(
+            engine.params, np.asarray([ids], np.int32), engine.cfg.model)
         nxt = int(np.argmax(np.asarray(logits[0, -1])))
         want.append(nxt)
         ids.append(nxt)
@@ -451,18 +458,27 @@ def test_prefill_rows_is_one_forward_not_a_loop_over_rows():
         assert lhs == (r, _CHUNK, cfg.dim)        # R x C rows, one matmul
 
 
+@pytest.mark.parametrize("kind", list(TINY_MODELS))
 @pytest.mark.parametrize("path", ["spill_tier", "pd_transfer"])
-def test_pages_that_leave_the_pool_come_back_in_its_layout(path):
+def test_pages_that_leave_the_pool_come_back_in_its_layout(path, kind):
     """The paths that carry pages out of the pools and back index them by
     the leading axis alone, so a change of the page's layout breaks them
     in silence: a prefix demoted to the spill tier and promoted back, and
     a prefill exported by `_export_kv_locked` and imported by `_import_fn`
     on another engine, continue with exactly the tokens of an engine whose
     pages never left. Two kv heads of 8 lanes each: a page is [page,
-    KVH * D] and a head order lost on the way would change the tokens."""
-    model = llama.llama_tiny(vocab_size=258, max_seq_len=256, dim=32,
-                             n_layers=2, n_heads=4, n_kv_heads=2,
-                             mlp_dim=64)
+    KVH * D] and a head order lost on the way would change the tokens.
+    The latent model's layer is ONE pool [page, latent_lanes]: the paths
+    carry whatever pools a layer has, by name."""
+    if kind == "latent":
+        model = TINY_MODELS[kind](max_seq_len=256)
+        pools = {"ckv": (8, model.latent_lanes)}
+    else:
+        model = llama.llama_tiny(vocab_size=258, max_seq_len=256, dim=32,
+                                 n_layers=2, n_heads=4, n_kv_heads=2,
+                                 mlp_dim=64)
+        pools = dict.fromkeys(
+            "kv", (8, model.n_kv_heads * model.head_dim))
     cfg = dict(model=model, max_batch_size=4, page_size=8, num_pages=32,
                max_pages_per_seq=16, chunk_size=16,
                enable_prefix_caching=True)
@@ -470,7 +486,6 @@ def test_pages_that_leave_the_pool_come_back_in_its_layout(path):
     rng = np.random.RandomState(21)
     shared = list(rng.randint(1, 250, (64,)))
     ask = shared + list(rng.randint(1, 250, (13,)))
-    page = (cfg["page_size"], model.n_kv_heads * model.head_dim)
 
     def run(eng, ids, params=sp):
         req = eng.submit(ids, params)
@@ -479,7 +494,9 @@ def test_pages_that_leave_the_pool_come_back_in_its_layout(path):
         return list(req.out_ids)
 
     stay = PagedInferenceEngine(PagedEngineConfig(**cfg), rng_seed=0)
-    assert stay.caches[0]["k"].shape == (cfg["num_pages"],) + page
+    assert {n: a.shape[1:] for n, a in stay.caches[0].items()} == pools
+    assert stay.page_nbytes == model.n_layers * sum(
+        int(np.prod(p)) * 4 for p in pools.values())
     want = run(stay, ask)
 
     if path == "spill_tier":
@@ -498,7 +515,8 @@ def test_pages_that_leave_the_pool_come_back_in_its_layout(path):
         pre = PagedInferenceEngine(PagedEngineConfig(**cfg), rng_seed=0)
         eng = PagedInferenceEngine(PagedEngineConfig(**cfg), rng_seed=0)
         payload = pre.prefill_export(ask, sp)
-        assert payload["pages"][0]["k"].shape[1:] == page
+        assert {n: a.shape[1:]
+                for n, a in payload["pages"][0].items()} == pools
         req = eng.import_prefill(payload, sp)
         eng.run_until_done([req])
         got = eng._result(req)["token_ids"]

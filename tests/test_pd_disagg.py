@@ -6,11 +6,13 @@ import pytest
 
 from ray_tpu.llm import SamplingParams
 from ray_tpu.llm.paged_engine import PagedEngineConfig, PagedInferenceEngine
-from ray_tpu.models import llama
+from ray_tpu.models import llama, mla_moe
+
+MODELS = {"llama": llama.llama_tiny, "latent": mla_moe.mla_moe_tiny}
 
 
-def _cfg():
-    model = llama.llama_tiny(vocab_size=258, max_seq_len=256)
+def _cfg(kind="llama"):
+    model = MODELS[kind](vocab_size=258, max_seq_len=256)
     return PagedEngineConfig(
         model=model, max_batch_size=4, page_size=8, num_pages=128,
         max_pages_per_seq=16, chunk_size=16)
@@ -24,11 +26,13 @@ def _prompt(n, seed=0):
 
 
 class TestEngineExportImport:
-    def test_pd_matches_single_engine_greedy(self):
+    @pytest.mark.parametrize("kind", list(MODELS))
+    def test_pd_matches_single_engine_greedy(self, kind):
         """Disaggregated prefill->transfer->decode must produce EXACTLY the
         tokens a single engine produces under greedy sampling — the KV
-        pages carry the full prefill state."""
-        cfg = _cfg()
+        pages carry the full prefill state, whatever pools a layer's
+        cache is made of (k and v, or one latent pool)."""
+        cfg = _cfg(kind)
         prompt = _prompt(37)  # crosses several chunks and pages
 
         single = PagedInferenceEngine(cfg, rng_seed=0)
@@ -38,6 +42,7 @@ class TestEngineExportImport:
         dec = PagedInferenceEngine(cfg, rng_seed=0)
         payload = pre.prefill_export(prompt, GREEDY)
         assert payload["first_token"] == expected["token_ids"][0]
+        assert set(payload["pages"][0]) == set(pre.caches[0])
         # prefill replica released everything: reusable immediately
         # (prefix caching parks retired pages in the cached LRU)
         st = pre.pool_stats()

@@ -8,9 +8,10 @@ import pytest
 
 from ray_tpu.llm import SamplingParams
 from ray_tpu.llm.paged_engine import PagedEngineConfig, PagedInferenceEngine
-from ray_tpu.models import llama
+from ray_tpu.models import llama, mla_moe
 
 TINY = llama.llama_tiny(vocab_size=258, max_seq_len=640)
+LATENT = mla_moe.mla_moe_tiny(vocab_size=258, max_seq_len=640)
 
 
 def _cfg(on=True, **kw):
@@ -25,7 +26,8 @@ def _prompt(n, seed=0):
     return list(np.random.RandomState(seed).randint(1, 250, (n,)))
 
 
-def test_shared_system_prompt_zero_recompute():
+@pytest.mark.parametrize("model", [TINY, LATENT], ids=["llama", "latent"])
+def test_shared_system_prompt_zero_recompute(model):
     """Acceptance: 16 requests sharing a 512-token system prompt — the
     second and later requests perform ZERO prefill for the whole cached
     region (everything up to the last chunk, which must recompute so the
@@ -33,7 +35,7 @@ def test_shared_system_prompt_zero_recompute():
     bit-identical with caching on vs off."""
     chunk, page, n_req = 64, 16, 16
     mk = lambda on: PagedInferenceEngine(PagedEngineConfig(
-        model=TINY, max_batch_size=n_req, page_size=page, num_pages=600,
+        model=model, max_batch_size=n_req, page_size=page, num_pages=600,
         max_pages_per_seq=40, chunk_size=chunk,
         enable_prefix_caching=on), rng_seed=0)
     system = _prompt(512, seed=1)

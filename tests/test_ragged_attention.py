@@ -484,3 +484,54 @@ def test_spec_verify_dispatches_bucketed():
     widths = {k[2] for k in on._verify_fns}
     assert widths and widths <= set(on._page_bucket_ladder()), widths
     assert max(widths) < 24, widths       # verify ran on SLICED tables
+
+
+# ---- the latent (MLA) form: one pool, values a lane slice of the keys ----
+
+@pytest.mark.parametrize("case", ["decode", "prefill", "verify",
+                                  "clamped_tail", "tiled_window"])
+def test_latent_form_matches_its_oracle(case):
+    """ragged_latent_attention under interpret=True against
+    ragged_latent_reference: ONE pool [P, page, W] whose rows are the keys
+    and whose first v_width lanes are the values, every query head on that
+    one kv head. Decode (window 1, ragged lengths, an idle row), a prefill
+    chunk with a padding row, verify windows, a table whose tail is stale
+    (the clamp must never read it: the sink page is poisoned), and a
+    window wider than the latent tile."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    rng = np.random.RandomState(7)
+    pool_pages, page, lanes, v_width, heads = 48, 8, 128, 96, 4
+    pages = rng.randn(pool_pages, page, lanes).astype(np.float32)
+    pages[0] = np.nan                              # the sink is never read
+    starts, q_lens, window, table = {
+        "decode": ([37, 0, 120, 9], [1, 1, 1, 0], 1, 16),
+        "prefill": ([16, 0, 48], [16, 11, 0], 16, 8),
+        "verify": ([21, 60], [3, 3], 3, 8),
+        "clamped_tail": ([8, 40], [8, 8], 8, 16),
+        "tiled_window": ([32, 0], [24, 24], 24, 8),
+    }[case]
+    rows = len(starts)
+    perm = rng.permutation(np.arange(1, pool_pages))
+    bt = np.zeros((rows, table), np.int32)         # tail: the poisoned sink
+    at = 0
+    for r in range(rows):
+        live = -(-(starts[r] + q_lens[r]) // page)
+        bt[r, :live] = perm[at:at + live]
+        at += live
+    q = jnp.asarray(rng.randn(rows, window, heads, lanes), jnp.float32)
+    args = (q, jnp.asarray(pages), jnp.asarray(bt),
+            jnp.asarray(starts, jnp.int32), jnp.asarray(q_lens, jnp.int32))
+    kw = dict(v_width=v_width, scale=0.11)
+    if case == "tiled_window":                     # three 8-row tiles
+        got = rpa._ragged_call(args[0], args[1], None, *args[2:], q_tile=8,
+                               interpret=True, **kw)
+    else:
+        got = rpa.ragged_latent_attention(*args, interpret=True, **kw)
+    assert got.shape == (rows, window, heads, v_width)
+    # the oracle gathers the whole table: give it a finite sink
+    want = rpa.ragged_latent_reference(
+        q, jnp.asarray(pages).at[0].set(0.0), *args[2:], **kw)
+    live = np.arange(window)[None, :] < np.asarray(q_lens)[:, None]
+    assert live.any() and np.isfinite(np.asarray(got)[live]).all()
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=1e-5)
