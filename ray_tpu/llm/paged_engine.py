@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import flight
+from ..ops.ragged_paged_attention import live_key_steps
 from ..util.compile_cache import enable_compile_cache
 from ..util.profiling import StepProfiler, phase
 from . import telemetry
@@ -60,9 +61,10 @@ def model_module(model_cfg):
     module: ``init``, ``init_paged_cache`` (a list, one dict of named page
     pools [P, page, ...] a layer, which the engine never interprets),
     ``decode_paged``, ``prefill_paged_rows``, ``verify_paged_rows``,
-    ``routed_per_token``, ``lora_targets`` and, under a mesh,
-    ``check_mesh`` (which may refuse) and then ``logical_axes`` and
-    ``cache_logical_axes`` (models/llama.py, models/mla_moe.py)."""
+    ``routed_per_token``, ``prefill_attn_step``, ``lora_targets`` and,
+    under a mesh, ``check_mesh`` (which may refuse) and then
+    ``logical_axes`` and ``cache_logical_axes`` (models/llama.py,
+    models/mla_moe.py)."""
     return sys.modules[type(model_cfg).__module__]
 
 
@@ -388,6 +390,11 @@ class PagedInferenceEngine:
                       "prefill_rows_live": 0,
                       "prefill_rows_padded": 0, "prefill_tokens": 0,
                       "prefill_ctx_pages": 0, "prefill_attn_pairs": 0,
+                      # live grid steps one layer's window kernel swept
+                      # for the prefill rows, and those of them whose
+                      # block needed the live-key predicate
+                      "prefill_key_steps": 0,
+                      "prefill_key_steps_masked": 0,
                       # request stamps, exact: submit -> admit and
                       # admit -> first token, summed over requests
                       "admitted": 0, "queue_wait_ns": 0,
@@ -1342,6 +1349,13 @@ class PagedInferenceEngine:
             # pos cached tokens and the row's first q + 1
             st["prefill_attn_pairs"] += sum(
                 n * pos + n * (n + 1) // 2 for _, pos, n in rows)
+            steps, masked = live_key_steps(
+                sps[:r], tls[:r], c, W, page_size=pg,
+                **self.model.prefill_attn_step(
+                    self.cfg.model, c, pg, W,
+                    1 if self.mesh is None else self.mesh.shape.get("tp", 1)))
+            st["prefill_key_steps"] += steps
+            st["prefill_key_steps_masked"] += masked
             self._moe_account(load, int(tls.sum()), rb * c)
             self._mesh_account(
                 chunks.nbytes + bts.nbytes + sps.nbytes + tls.nbytes

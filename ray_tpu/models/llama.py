@@ -249,6 +249,20 @@ def routed_per_token(cfg: LlamaConfig) -> int:
     return cfg.moe_top_k * cfg.n_layers if cfg.moe_experts else 0
 
 
+def prefill_attn_step(cfg: LlamaConfig, chunk_size: int, page_size: int,
+                      table_pages: int, head_shards: int = 1) -> dict:
+    """{'q_tile', 'block_keys'}: the query rows a tile and the keys a grid
+    step of the kernel `_window_attend` calls for a prefill chunk over a
+    table that wide, as ONE of ``head_shards`` (the mesh's tp) runs it —
+    the kernel module's own derivation, for the engine's count of the
+    steps its prefill rows sweep."""
+    from ..ops.ragged_paged_attention import window_step
+    return window_step(
+        chunk_size, cfg.n_heads // head_shards,
+        cfg.n_kv_heads // head_shards, cfg.head_dim, page_size=page_size,
+        table_pages=table_pages, itemsize=jnp.dtype(cfg.dtype).itemsize)
+
+
 # ---------------------------------------------------------------------------
 # Building blocks
 # ---------------------------------------------------------------------------

@@ -12,13 +12,20 @@ is proportional to each row's TRUE length (rounded up to a block), not
 the pool capacity:
 
 * grid ``(row, q_tile, kv_block)``. One grid step of the page sweep
-  covers a BLOCK of ``N = max(1, 128 // page_size)`` consecutive logical
-  pages — 8 pages = 128 keys at pages of 16 — so the key axis of a step
-  is as wide as a vreg's lanes and the MXU, and the streaming softmax
-  runs once per 128 keys, not once per page (at pages of 16 a step of one
-  page used 16 of 128 lanes and paid a grid step per 16 keys: PERF.md §6,
-  PR 25). ``N`` comes from the pool's page shape; at pages of 128 a block
-  is one page;
+  covers a BLOCK of consecutive logical pages, never narrower than 128
+  keys (8 pages of 16: a vreg's lanes, the MXU's width — at pages of 16
+  a step of one page used 16 of 128 lanes and paid a grid step per 16
+  keys: PERF.md §6, PR 25). The all-heads body below keeps 128 keys a
+  step. The per-kv-head body takes the widest block its VMEM budget
+  holds (`window_step`: 512 keys under the latent prefill tile, 1,024
+  under the latent decode tile and the GQA prefill tiles; never wider
+  than the table needs): ONE ``[rows, block_keys]`` score tile, one
+  streaming-softmax update and one rescale of the f32 accumulator for
+  all of a block's keys, where at 128 keys the rescale of a ``[1024,
+  512]`` accumulator — four times the score tile — and the softmax's
+  bookkeeping held the latent prefill step at a third of the MXU's peak
+  (4.3 us a 128 keys against 1.5; 2,985 us a call where 6,696: PERF.md
+  §6, PR 32). The width comes from static shapes alone;
 * a pool is ``[P, page, KVH * D]``: one token's kv heads side by side
   on the last axis, head ``h`` in lanes ``[h * D, (h + 1) * D)``. The
   pools stay in HBM (``memory_space=ANY``) and the kernel gathers a
@@ -51,11 +58,16 @@ the pool capacity:
   the LOGICAL page index) is attended by query position ``q_pos = start
   + i`` iff ``k_pos <= q_pos`` and ``k_pos < kv_len`` — which covers the
   prefix (always attended), the window (causal), a partly live block and
-  its clamped duplicates with one predicate;
+  its clamped duplicates with one predicate. In the per-kv-head body a
+  block every key of which every query of the tile attends
+  (`_prefix_blocks`: all but the last one or two of a long sweep) takes
+  a body WITHOUT the predicate and its two selects — the same bits,
+  3-4% of a call (PR 32);
 * GQA without any jnp.repeat, in one of two forms chosen by the static
   shape ``Q * G`` (Q the query TILE, G query heads a kv head) alone:
 
-  - ``Q * G > 8`` (prefill and verify tiles): a static per-kv-head loop.
+  - ``Q * G > 8`` (prefill and verify tiles; the latent form at every
+    window): a static per-kv-head loop.
     Each group of G query heads runs a [Q*G, N*page] MXU tile against
     its kv head's [N*page, D] block, and the softmax state is kept per
     kv head (``[KVH, Q*G, ...]``), so only one head's scores are live at
@@ -91,6 +103,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # one masking constant for the whole paged family: the kernel and both
 # jnp oracles below must mask identically
@@ -112,11 +125,16 @@ def _all_heads(q_tile: int, groups: int) -> bool:
 def _softmax_step(s, keep, m_ref, l_ref, i):
     """Fold one block's scores ``s`` [rows, keys] into the streaming
     softmax state of slot ``i``; returns the block's probabilities and
-    the factor the accumulator of slot ``i`` is rescaled by."""
-    s = jnp.where(keep, s, NEG_INF)
+    the factor the accumulator of slot ``i`` is rescaled by. ``keep``
+    None is a block every key of which every row attends: both selects
+    are then the identity and are left out (the same bits)."""
+    if keep is not None:
+        s = jnp.where(keep, s, NEG_INF)
     m_prev, l_prev = m_ref[i], l_ref[i]                   # [rows, 1]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1)[:, None])
-    pexp = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+    pexp = jnp.exp(s - m_new)
+    if keep is not None:
+        pexp = jnp.where(keep, pexp, 0.0)
     alpha = jnp.exp(m_prev - m_new)
     l_ref[i] = l_prev * alpha + jnp.sum(pexp, axis=-1)[:, None]
     m_ref[i] = m_new
@@ -167,23 +185,46 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
     d_v = d if v_width is None else v_width
     all_heads = v_width is None and _all_heads(q_tile, groups)
 
-    def _copies(block, slot):
-        """The page copies (one a pool a page) that fill buffer ``slot`` with
-        logical pages [block * block_pages, ...) of row r, clamped to
-        the tile's last live page: a page the row does not own is never
-        read (the table's tail is stale, or the poisoned sink), and the
-        duplicates in a partly live block sit at key positions the
+    # pages of the narrowest (128-key) block: what one iteration of the
+    # copy loops below unrolls
+    lane_pages = min(block_pages, _lane_block_keys(page_size) // page_size)
+
+    def _copies(block, slot, first_page=0):
+        """The page copies (one a pool a page) that fill ``lane_pages``
+        pages from ``first_page`` on of buffer ``slot`` with logical
+        pages [block * block_pages + first_page, ...) of row r, clamped
+        to the tile's last live page: a page the row does not own is
+        never read (the table's tail is stale, or the poisoned sink), and
+        the duplicates in a partly live block sit at key positions the
         predicate below masks."""
         out = []
-        for j in range(block_pages):
-            phys = bt_ref[r, jnp.minimum(block * block_pages + j, last_page)]
-            rows = pl.ds(j * page_size, page_size)
+        for j in range(lane_pages):
+            page = first_page + j
+            phys = bt_ref[r, jnp.minimum(block * block_pages + page,
+                                         last_page)]
+            rows = pl.ds(page * page_size, page_size)
             out.append(pltpu.make_async_copy(
                 k_hbm.at[phys], k_buf.at[slot, rows], sems.at[0, slot]))
             if v_hbm is not None:
                 out.append(pltpu.make_async_copy(
                     v_hbm.at[phys], v_buf.at[slot, rows], sems.at[1, slot]))
         return out
+
+    def _each_copy(block, slot, act):
+        """``act`` on every page copy of a block. A block wider than 128
+        keys loops over its 128-key groups: unrolled, 64 pages' copies —
+        started, prefetched and waited for — tripled the time to trace
+        and lower the kernel (1.06 s where 0.34 at doc-QA's prefill
+        shape), which every program of the warm-up ladder pays on every
+        start (PERF.md §6, PR 32)."""
+        def group(g, carry):
+            for c in _copies(block, slot, g * lane_pages):
+                act(c)
+            return carry
+        if block_pages == lane_pages:
+            group(0, None)
+        else:
+            jax.lax.fori_loop(0, block_pages // lane_pages, group, None)
 
     def _keep(rows, rows_per_query):
         """Live-key predicate [rows, block_keys], row = query-major.
@@ -203,18 +244,15 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
 
         @pl.when(b == 0)
         def _first():
-            for c in _copies(0, 0):
-                c.start()
+            _each_copy(0, 0, lambda c: c.start())
 
         @pl.when(b + 1 < n_blocks)
         def _prefetch():
-            for c in _copies(b + 1, 1 - slot):
-                c.start()
+            _each_copy(b + 1, 1 - slot, lambda c: c.start())
 
-        for c in _copies(b, slot):
-            c.wait()
-        q = q_ref[...]                                    # [Q, H, D]
+        _each_copy(b, slot, lambda c: c.wait())
         if all_heads:
+            q = q_ref[...]                                # [Q, H, D]
             # every head in one product: row q * H + head of the
             # block-diagonal operand keeps its own kv head's D lanes
             rows = q_tile * heads
@@ -243,20 +281,38 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
         # per kv head: [Q*G, block_keys] scores live at a time, not
         # [KVH*Q*G, block_keys] (2 x 1 MiB of f32 at the prefill tile)
         qg = q_tile * groups
-        keep = _keep(qg, groups)
-        for h in range(num_kv_heads):
-            lanes = slice(h * d, (h + 1) * d)
-            q_sub = q[:, h * groups:(h + 1) * groups, :].reshape(qg, d)
-            s = jax.lax.dot_general(
-                q_sub, k_buf[slot, :, lanes], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            pexp, alpha = _softmax_step(s, keep, m_ref, l_ref, h)
-            v_blk = (k_buf[slot, :, :d_v] if v_buf is None
-                     else v_buf[slot, :, lanes])          # [block_keys, Dv]
-            pv = jax.lax.dot_general(
-                pexp.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)       # [Q*G, Dv]
-            acc_ref[h] = acc_ref[h] * alpha + pv
+
+        def _heads(keep):
+            for h in range(num_kv_heads):
+                lanes = slice(h * d, (h + 1) * d)
+                q_sub = q_ref[:, h * groups:(h + 1) * groups, :].reshape(
+                    qg, d)
+                s = jax.lax.dot_general(
+                    q_sub, k_buf[slot, :, lanes], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                pexp, alpha = _softmax_step(s, keep, m_ref, l_ref, h)
+                v_blk = (k_buf[slot, :, :d_v] if v_buf is None
+                         else v_buf[slot, :, lanes])      # [block_keys, Dv]
+                pv = jax.lax.dot_general(
+                    pexp.astype(v_blk.dtype), v_blk,
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)   # [Q*G, Dv]
+                acc_ref[h] = acc_ref[h] * alpha + pv
+
+        # a block every key of which every query of the tile attends
+        # needs no predicate — all but the last block or two of a long
+        # sweep. Each body reads q from its ref itself: a value loaded
+        # above the branch is copied whole before it (1.3 MB at the
+        # latent tile: measured slower than never skipping)
+        in_prefix = b < _prefix_blocks(start, q_len, t, q_tile, block_keys)
+
+        @pl.when(in_prefix)
+        def _unmasked():
+            _heads(None)
+
+        @pl.when(jnp.logical_not(in_prefix))
+        def _masked():
+            _heads(_keep(qg, groups))
 
     @pl.when(b == pl.num_programs(2) - 1)
     def _finalize():
@@ -282,31 +338,138 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
 _Q_TILE_ELEMS = 64 * 32 * 128
 
 
-def _q_tile(q_window: int, heads: int, head_dim: int) -> int:
-    """Query rows per grid step: the whole window when it fits the VMEM
-    budget (decode, verify windows, small models), else the largest
-    multiple of 8 that does."""
-    fit = max(8, _Q_TILE_ELEMS // (heads * head_dim) // 8 * 8)
+# The latent form's query tile (rows x heads x lanes one grid step may
+# hold): all heads share the one key block, so a tile's rows x heads are
+# the rows of ONE product, and a wider tile sweeps a row's pages fewer
+# times. Measured at 32 heads x 640 lanes, four 128-row chunks over
+# 12,288 cached tokens, us a call: at 128 keys a step tiles of 8 / 16 /
+# 32 rows 8,037 / 7,003 / 6,431 (PERF.md §6, PR 31); at 512 keys a step
+# 32 rows 2,985, 64 rows 2,788 (PR 32). 64 rows were measured and left:
+# 6.6% of the kernel is ~1.5% of the cell's tokens per second, under what
+# a run resolves, for twice the compile time, executable and VMEM (30 MiB).
+_LATENT_TILE_ELEMS = 32 * 32 * 640
+
+
+def _q_tile(q_window: int, heads: int, head_dim: int,
+            latent: bool = False) -> int:
+    """Query rows per grid step: the whole window when it fits the form's
+    element budget (decode, verify windows, small models), else the
+    largest multiple of 8 that does."""
+    elems = _LATENT_TILE_ELEMS if latent else _Q_TILE_ELEMS
+    fit = max(8, elems // (heads * head_dim) // 8 * 8)
     return q_window if q_window <= fit else fit
 
 
-def _tile_pages(start, q_len, t, q_tile: int, page_size: int):
+def _tile_pages(start, q_len, t, q_tile: int, page_size: int, xp=jnp):
     """Live KV pages of query tile ``t`` of one row: those holding keys
     below min(kv_len, end of the tile) — later keys are in the causal
     future of every query of the tile — and none for a tile wholly past
     q_len (padding). The one source for the page copies' clamp and for
     the compute skip (in blocks: ceil(pages / block_pages)), so the two
-    can never disagree."""
-    live = jnp.minimum(start + q_len, start + (t + 1) * q_tile)
-    return jnp.where(t * q_tile < q_len,
-                     (live + page_size - 1) // page_size, 0)
+    can never disagree — and, with ``xp=numpy``, for the engine's count
+    of the steps a dispatch swept (`live_key_steps`)."""
+    live = xp.minimum(start + q_len, start + (t + 1) * q_tile)
+    return xp.where(t * q_tile < q_len,
+                    (live + page_size - 1) // page_size, 0)
 
 
-def _block_pages(page_size: int) -> int:
-    """Logical pages one grid step of the sweep covers: as many as make
-    its key axis 128 wide (a vreg's lanes, the MXU's width), one where a
-    page already is. Derived from the pool's page shape alone."""
-    return max(1, 128 // page_size)
+def _prefix_blocks(start, q_len, t, q_tile: int, block_keys: int, xp=jnp):
+    """Leading blocks of tile ``t``'s sweep that need no predicate: every
+    key of such a block lies at or below the tile's first query and below
+    kv_len, so every row of the tile attends all of it, it holds no
+    clamped duplicate, and `_keep` would be all true there."""
+    return xp.minimum(start + t * q_tile + 1, start + q_len) // block_keys
+
+
+def live_key_steps(starts, q_lens, q_window: int, table_pages: int, *,
+                   q_tile: int, block_keys: int, page_size: int):
+    """(live grid steps, those of them that carry the predicate) of ONE
+    call's sweeps over rows ``[starts[r], starts[r] + q_lens[r])`` — what
+    the per-kv-head body computes, counted on the host with the kernel's
+    own `_tile_pages` and `_prefix_blocks`."""
+    starts = np.asarray(starts, np.int64)[:, None]
+    q_lens = np.asarray(q_lens, np.int64)[:, None]
+    t = np.arange(-(-q_window // q_tile))[None, :]
+    block_pages = max(1, block_keys // page_size)
+    blocks = np.minimum(
+        -(-_tile_pages(starts, q_lens, t, q_tile, page_size, np)
+          // block_pages), -(-table_pages // block_pages))
+    plain = np.minimum(
+        _prefix_blocks(starts, q_lens, t, q_tile, block_keys, np), blocks)
+    return int(blocks.sum()), int((blocks - plain).sum())
+
+
+# The per-kv-head body's key block is the widest, doubling from 128 keys,
+# that (1) fits `_VMEM_BUDGET` by `_step_vmem_bytes` — what the call states
+# to the compiler as its ``vmem_limit_bytes``, whose default scope is 16
+# MiB of a v5e core's 128 MiB; (2) keeps one kv head's score tile within
+# `_SCORE_TILE_ELEMS` — at 512K scores a step is ~1.2 GFLOP at the latent
+# widths and its fixed costs are spent: wider only rounds every sweep up
+# further; (3) is at most `_MAX_BLOCK_KEYS`. All three by measurement,
+# kernel alone, us a call (PERF.md §6, PR 32): the latent prefill tile (32
+# rows x 32 heads, a 12,288-token cache: compute-bound) 6,696 at 128 keys,
+# 3,340 / 3,092 / 3,332 at 256 / 512 / 1,024; the latent decode tile 1,958
+# -> 845 / 724 / 722 at 512 / 1,024 / 2,048; the doc-QA prefill tile (256
+# rows a kv head, a third of its time the pages' bytes: more copies in
+# flight pay) 237 -> 123 / 101 / 131 at 512 / 1,024 / 2,048. Scopes of 20
+# to 100 MiB ran the same widths alike.
+_VMEM_BUDGET = 32 * 2 ** 20
+_SCORE_TILE_ELEMS = 512 * 1024
+_MAX_BLOCK_KEYS = 1024
+
+
+def _lane_block_keys(page_size: int) -> int:
+    """Keys of the narrowest block: as many pages as make the key axis 128
+    wide (a vreg's lanes, the MXU's width), one where a page already is."""
+    return max(1, 128 // page_size) * page_size
+
+
+def _step_vmem_bytes(q_tile: int, heads: int, kv_heads: int, d: int,
+                     d_v: int, pools: int, block_keys: int,
+                     itemsize: int) -> int:
+    """VMEM one grid step of the per-kv-head body holds at a block of
+    ``block_keys``: the q and out blocks (double-buffered by the
+    pipeline), the f32 accumulators and softmax state (m and l lie over
+    128 lanes each), two slots a pool of the block buffer — and one more
+    copy of a block where a kv head is a lane slice of it, not all of it
+    — one kv head's score tile as f32 scores, f32 probabilities and their
+    cast, and the PV product. Within 2 MiB under to 6 over what the v5e
+    compiler asks for at the cells' shapes (sandbox compiles, PR 32)."""
+    rows = q_tile * heads // kv_heads
+    blocks = 2 * q_tile * heads * (d + d_v) * itemsize
+    state = kv_heads * rows * (d_v + 2 * 128) * 4
+    buffers = (pools * (2 + (kv_heads > 1)) * block_keys * kv_heads * d
+               * itemsize)
+    scores = rows * block_keys * (4 + 4 + itemsize) + rows * d_v * 4
+    return blocks + state + buffers + scores
+
+
+def window_step(q_window: int, heads: int, kv_heads: int, d: int, *,
+                page_size: int, table_pages: int, itemsize: int,
+                v_width: int | None = None) -> dict:
+    """{'q_tile', 'block_keys'} of the call the family makes at these
+    static shapes (``v_width`` set: the latent form, one kv head as wide
+    as q) — for the wrappers below, and for whoever counts the steps such
+    a call sweeps (`live_key_steps`; a model module's
+    ``prefill_attn_step``). The all-heads body keeps the 128-key block:
+    its step is bound by HBM, not by the softmax's bookkeeping. The
+    per-kv-head body takes the widest block, doubling from 128 keys,
+    within the three limits above — and none of whose halves covers the
+    whole table: a 512-key block over a 16-page table would copy and
+    score what no row holds."""
+    latent = v_width is not None
+    q_tile = _q_tile(q_window, heads, d, latent)
+    keys = _lane_block_keys(page_size)
+    if not latent and _all_heads(q_tile, heads // kv_heads):
+        return dict(q_tile=q_tile, block_keys=keys)
+    while (2 * keys <= _MAX_BLOCK_KEYS
+           and keys < table_pages * page_size
+           and q_tile * heads // kv_heads * 2 * keys <= _SCORE_TILE_ELEMS
+           and _step_vmem_bytes(q_tile, heads, kv_heads, d,
+                                v_width if latent else d, 1 if latent else 2,
+                                2 * keys, itemsize) <= _VMEM_BUDGET):
+        keys *= 2
+    return dict(q_tile=q_tile, block_keys=keys)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
@@ -326,21 +489,25 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
     [R, Q, H, D].
     """
     _, qw, h, d = q.shape
+    _, page_size, kv_lanes = k_pages.shape
     return _ragged_call(
         q, k_pages, v_pages, block_tables, starts, q_lens,
         scale=float(d ** -0.5 if scale is None else scale),
-        q_tile=_q_tile(qw, h, d), interpret=interpret)
+        interpret=interpret, **window_step(
+            qw, h, kv_lanes // d, d, page_size=page_size,
+            table_pages=block_tables.shape[1],
+            itemsize=k_pages.dtype.itemsize))
 
 
 # Jitted so that a program traces and lowers the kernel body once per
 # shape, not once per layer: models/llama.py unrolls its layers in
 # Python, and 20 lowerings of this body are 3.7-5.6 s of every program's
 # set-up where one shared function is 0.1-0.2 s (sandbox, PR 25).
-@functools.partial(jax.jit, static_argnames=("scale", "q_tile", "interpret",
-                                             "v_width"))
+@functools.partial(jax.jit, static_argnames=("scale", "q_tile", "block_keys",
+                                             "interpret", "v_width"))
 def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
-                 scale: float, q_tile: int, interpret: bool,
-                 v_width: int | None = None):
+                 scale: float, q_tile: int, block_keys: int,
+                 interpret: bool, v_width: int | None = None):
     """``v_pages`` None is the latent form (ragged_latent_attention): one
     pool, one shared kv head as wide as q, values its first ``v_width``
     lanes."""
@@ -353,7 +520,7 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
     d_v = v_width if latent else d
     kvh = kv_lanes // d
     groups = h // kvh
-    nb = _block_pages(page_size)
+    nb = max(1, block_keys // page_size)
     n_tiles = -(-qw // q_tile)
     padded = n_tiles * q_tile
     if padded != qw:
@@ -372,8 +539,8 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
     block_buf = pltpu.VMEM((2, nb * page_size, kv_lanes), k_pages.dtype)
     pools = (k_pages,) if latent else (k_pages, v_pages)
     # softmax state: a slot a kv head, or one slot for all heads
-    slots, rows = (1, q_tile * h) if not latent and _all_heads(
-        q_tile, groups) else (kvh, q_tile * groups)
+    all_heads = not latent and _all_heads(q_tile, groups)
+    slots, rows = (1, q_tile * h) if all_heads else (kvh, q_tile * groups)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(r, n_tiles, -(-block_tables.shape[1] // nb)),
@@ -392,20 +559,15 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, padded, h, d_v), q.dtype),
         interpret=interpret,
+        # the per-kv-head body's blocks are sized against this scope
+        # (window_step); the all-heads body fits the compiler's own
+        compiler_params=None if all_heads else pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_BUDGET),
         # the trace reduction tells the family by this prefix and its two
         # shapes by the output's window (benchmarks/reduce/)
         name="ragged_paged_attention" + ("_latent" if latent else ""),
     )(block_tables, starts, q_lens, q, *pools)
     return out[:, :qw] if padded != qw else out
-
-
-# The latent form's query tile (rows x heads x lanes one grid step may
-# hold): all heads share the one key block, so a tile's rows x heads are
-# the rows of ONE product, and a wider tile sweeps a row's pages fewer
-# times. Measured at 32 heads x 640 lanes, a 128-row chunk over 12,288
-# cached tokens, us a call (PERF.md §6, PR 31): tile 8 1,991, 16 1,757,
-# 32 1,631; 64 rows exceed the 16 MiB of scoped VMEM.
-_LATENT_TILE_ELEMS = 32 * 32 * 640
 
 
 def ragged_latent_attention(q, pages, block_tables, starts, q_lens, *,
@@ -422,11 +584,12 @@ def ragged_latent_attention(q, pages, block_tables, starts, q_lens, *,
     q_lens); each page is copied into VMEM once and serves both products.
     ``scale`` is the model's (the uncompressed head size's, not W's)."""
     _, qw, h, d = q.shape
-    fit = max(8, _LATENT_TILE_ELEMS // (h * d) // 8 * 8)
     return _ragged_call(
         q, pages, None, block_tables, starts, q_lens, scale=float(scale),
-        q_tile=qw if qw <= fit else fit, interpret=interpret,
-        v_width=int(v_width))
+        interpret=interpret, v_width=int(v_width), **window_step(
+            qw, h, 1, d, page_size=pages.shape[1],
+            table_pages=block_tables.shape[1],
+            itemsize=pages.dtype.itemsize, v_width=int(v_width)))
 
 
 def ragged_decode_attention(q, k_pages, v_pages, block_table, lengths,

@@ -50,6 +50,11 @@ def v5e():
     cc.reset_cache()
 
 
+# how a compiled custom call carries its `vmem_limit_bytes`
+_SCOPE = '"scoped_memory_configs":[{"memory_space":"1","offset":"0",' \
+         '"size":"%d"}]' % (32 * 2 ** 20)
+
+
 def _compile(fn, *shapes, sharding):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=sh)
             for (s, dt), sh in zip(shapes, sharding)]
@@ -95,11 +100,16 @@ def test_ragged_decode_compiles_at_8b_widths(v5e):
     (32, 1, 256),          # decode: max_batch_size rows, the doc-QA bucket
     (1, 128, 256),         # prefill: one chunk_size row, two 64-query tiles
     (32, 1, 4),            # the bucket floor: a table under one 8-page block
-], ids=["decode", "prefill", "decode_floor"])
+    (1, 128, 8),           # a first chunk: the table holds one 128-key block
+    (1, 128, 32),          # 512 keys: the block is clamped to the table
+    (1, 128, 1024),        # the widest bucket any cell warms
+], ids=["decode", "prefill", "decode_floor", "prefill_floor", "prefill_32",
+        "prefill_1024"])
 def test_ragged_compiles_at_the_benchmark_cells_shapes(v5e, rows, q_window,
                                                        max_pages, h, kvh):
     """The shapes `docqa-sessions-1chip` and the pending chat cells run
-    (BENCHMARK.json; page 16, so a grid step gathers 8 pages = 128 keys),
+    (BENCHMARK.json; page 16: a decode step gathers 8 pages = 128 keys, a
+    prefill step up to 64 = 1,024 under the stated ``vmem_limit_bytes``),
     whole and as one tp=4 shard sees them (8 Q / 2 KV heads)."""
     one = SingleDeviceSharding(v5e.devices[0])
     text = _compile(
@@ -113,12 +123,20 @@ def test_ragged_compiles_at_the_benchmark_cells_shapes(v5e, rows, q_window,
     assert re.search(rf"%ragged_paged_attention[.\d]* = "
                      rf"bf16\[{rows},{q_window},{h},{D}\].*tpu_custom_call",
                      text)
+    # the per-kv-head body states its VMEM scope; the all-heads decode
+    # body is compiled as it was, under the compiler's own
+    assert (_SCOPE in text) == (q_window > 1)
 
 
 @pytest.mark.parametrize("rows,q_window,max_pages", [
     (32, 1, 1024),         # decode: max_batch_size rows, the longest table
     (4, 128, 1024),        # prefill: four chunk_size rows, 32-row tiles
-], ids=["decode", "prefill"])
+    (32, 1, 4),            # the ladder's floor and a middle bucket, both
+    (32, 1, 64),           # windows: the block is clamped to the table
+    (4, 128, 4),
+    (4, 128, 64),
+], ids=["decode", "prefill", "decode_4", "decode_64", "prefill_4",
+        "prefill_64"])
 def test_latent_form_compiles_at_the_kanana_cells_shapes(v5e, rows,
                                                          q_window,
                                                          max_pages):
@@ -137,12 +155,56 @@ def test_latent_form_compiles_at_the_kanana_cells_shapes(v5e, rows,
             ((rows,), jnp.int32), sharding=[one] * 5).as_text()
     # the name's prefix and the result's [rows, window, heads, Dv] are what
     # reduce/families/*.json and reduce/kernels/*.json tell it by
+    text = fn(640)
     assert re.search(rf"%ragged_paged_attention_latent[.\d]* = "
                      rf"bf16\[{rows},{q_window},{H},512\].*tpu_custom_call",
-                     fn(640))
-    if q_window == 1:
+                     text)
+    assert _SCOPE in text
+    if (q_window, max_pages) == (1, 1024):
         with pytest.raises(Exception, match="aligned to tiling"):
             fn(576)
+
+
+@pytest.mark.parametrize("shape", [
+    dict(q_window=128, heads=H, kv_heads=1, d=640, v_width=512),   # kanana
+    dict(q_window=1, heads=H, kv_heads=1, d=640, v_width=512),
+    dict(q_window=128, heads=H, kv_heads=KVH, d=D),                # doc-QA
+    dict(q_window=128, heads=16, kv_heads=16, d=D),                # OLMoE
+    dict(q_window=5, heads=H, kv_heads=KVH, d=D),                  # verify
+], ids=["latent_prefill", "latent_decode", "gqa_prefill", "mha_prefill",
+        "gqa_verify"])
+@pytest.mark.parametrize("table_pages", [4, 32, 256, 1024])
+def test_the_stated_vmem_budget_covers_the_declared_buffers(shape,
+                                                            table_pages):
+    """What `_ragged_call` declares at the widths `window_step` derives —
+    two slots a pool of the block buffer, the f32 accumulators and softmax
+    state (m and l lie over 128 lanes), the q and out blocks twice (the
+    pipeline double-buffers them) — is inside the `vmem_limit_bytes` it
+    states, with room for one kv head's score tile; the derivation's own
+    estimate counts at least as much; and the block is as wide as the
+    budget, the score tile's limit and the table allow. (The compiles above run under that
+    limit.)"""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    latent = "v_width" in shape
+    step = rpa.window_step(**shape, page_size=16, table_pages=table_pages,
+                           itemsize=2)
+    q_tile, keys = step["q_tile"], step["block_keys"]
+    h, kvh, d = shape["heads"], shape["kv_heads"], shape["d"]
+    d_v = shape.get("v_width", d)
+    pools = 1 if latent else 2
+    rows = q_tile * h // kvh
+    declared = (pools * 2 * keys * kvh * d * 2             # block buffers
+                + kvh * rows * (d_v + 2 * 128) * 4         # acc, m, l
+                + 2 * q_tile * h * (d + d_v) * 2)          # q, out blocks
+    scores = rows * keys * 4
+    estimate = rpa._step_vmem_bytes(q_tile, h, kvh, d, d_v, pools, keys, 2)
+    assert declared + scores <= estimate <= rpa._VMEM_BUDGET
+    assert 128 <= keys <= rpa._MAX_BLOCK_KEYS
+    assert keys == 128 or keys // 2 < table_pages * 16
+    if keys < min(rpa._MAX_BLOCK_KEYS, table_pages * 16):
+        assert (rows * 2 * keys > rpa._SCORE_TILE_ELEMS
+                or rpa._step_vmem_bytes(q_tile, h, kvh, d, d_v, pools,
+                                        2 * keys, 2) > rpa._VMEM_BUDGET)
 
 
 def test_flash_fwd_bwd_compile_at_8b_widths(v5e):

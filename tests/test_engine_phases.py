@@ -7,6 +7,7 @@ import re
 import time
 
 import jax
+import numpy as np
 import pytest
 
 from ray_tpu.llm import SamplingParams
@@ -257,3 +258,88 @@ def test_phases_are_spans_on_the_profilers_host_plane(engine, tmp_path,
     dev = [e for e in host["lines"][0]["events"]
            if e[0] == "rtpu.engine.decode.device"]
     assert dev and all(e[1] >= 0 and e[2] > 0 for e in dev)
+
+
+def _brute_key_steps(rows, window, table_pages, page, q_tile, block_keys):
+    """(live steps, those with the predicate) of a call over ``rows`` of
+    (pos, n), block by block: a block is live while it starts below the
+    tile's live pages (the kernel module's own `_tile_pages`), and takes
+    the body with the predicate unless every row of the tile attends
+    every key of it by the kernel's mask, ``k <= q_pos`` and ``k <
+    kv_len``, evaluated key by key."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    block_pages = max(1, block_keys // page)
+    steps = masked = 0
+    for pos, n in rows:
+        for t in range(-(-window // q_tile)):
+            pages = int(rpa._tile_pages(pos, n, t, q_tile, page, np))
+            q_pos = pos + t * q_tile + np.arange(q_tile)[:, None]
+            for b in range(-(-table_pages // block_pages)):
+                if b * block_pages >= pages:
+                    break
+                k = b * block_keys + np.arange(block_keys)[None, :]
+                steps += 1
+                masked += not ((k <= q_pos) & (k < pos + n)).all()
+    return steps, masked
+
+
+@pytest.mark.parametrize("module,cfg", [
+    ("llama", dict(dim=4096, n_heads=32, n_kv_heads=8)),       # doc-QA's
+    ("llama", dict(dim=2048, n_heads=16, n_kv_heads=16)),      # OLMoE's
+    ("mla_moe", {}),                                           # kanana's
+], ids=["gqa", "mha", "latent"])
+def test_key_step_counters_are_the_kernels_own_count(module, cfg):
+    """`live_key_steps` — what `_prefill_step` adds to
+    ``prefill_key_steps`` / ``prefill_key_steps_masked`` — over a seeded
+    mix of (pos, n) rows at the widths of the three serving cells and
+    every table bucket, against the brute-force count, with the tile and
+    block width each model module reports for its kernel."""
+    from ray_tpu.ops.ragged_paged_attention import live_key_steps
+    rng = np.random.RandomState(32)
+    if module == "llama":
+        mod, mc = llama, llama.LlamaConfig(
+            vocab_size=512, n_layers=1, mlp_dim=128, max_seq_len=16384,
+            **cfg)
+    else:
+        mod, mc = mla_moe, mla_moe.MlaMoeConfig(
+            vocab_size=512, n_layers=1, max_seq_len=16384)
+    chunk, page = 128, 16
+    widths = set()
+    for table in (4, 8, 16, 32, 64, 128, 256, 512, 1024):
+        step = mod.prefill_attn_step(mc, chunk, page, table)
+        widths.add(step["block_keys"])
+        top = table * page - chunk
+        rows = [(int(rng.randint(0, top + 1)) if top > 0 else 0,
+                 int(rng.randint(1, min(chunk, table * page) + 1)))
+                for _ in range(6)] + [(max(top, 0), min(chunk, table * page)),
+                                      (0, 0)]
+        got = live_key_steps([p for p, _ in rows], [n for _, n in rows],
+                             chunk, table, page_size=page, **step)
+        assert got == _brute_key_steps(rows, chunk, table, page, **step), \
+            (table, step)
+        assert 0 < got[1] <= got[0]
+    assert len(widths) > 1 and min(widths) == 128   # narrow tables clamp
+
+
+def test_prefill_step_counts_its_rows_key_steps(engine, monkeypatch):
+    """The engine adds, for every prefill dispatch, the count of its live
+    rows at the dispatch's table width."""
+    from ray_tpu.llm import paged_engine
+    want = [0, 0]
+
+    def counted(starts, q_lens, window, table_pages, *, page_size, **step):
+        assert page_size == engine.cfg.page_size
+        assert step == engine.model.prefill_attn_step(
+            engine.cfg.model, window, page_size, table_pages, 1)
+        got = _brute_key_steps(list(zip(map(int, starts), map(int, q_lens))),
+                               window, table_pages, page_size, **step)
+        want[0] += got[0]
+        want[1] += got[1]
+        return got
+    monkeypatch.setattr(paged_engine, "live_key_steps", counted)
+    before = dict(engine.stats)
+    engine.generate([list(range(2 + i, 50 + 9 * i)) for i in range(4)],
+                    SamplingParams(max_tokens=3))
+    d = _deltas(engine, before)
+    assert d["prefill_key_steps"] == want[0] >= d["prefill_rows_live"]
+    assert d["prefill_key_steps_masked"] == want[1] >= d["prefill_rows_live"]

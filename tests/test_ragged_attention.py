@@ -5,6 +5,8 @@ safety, and the paged-engine end-to-end contracts: kernel-on vs
 plain-JAX fallback within fp accumulation tolerance, block-table page
 bucketing changing nothing but the gather width, and the bucketed
 warmup ladder keeping the no-mid-burst-compiles contract."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -524,7 +526,7 @@ def test_latent_form_matches_its_oracle(case):
     kw = dict(v_width=v_width, scale=0.11)
     if case == "tiled_window":                     # three 8-row tiles
         got = rpa._ragged_call(args[0], args[1], None, *args[2:], q_tile=8,
-                               interpret=True, **kw)
+                               block_keys=128, interpret=True, **kw)
     else:
         got = rpa.ragged_latent_attention(*args, interpret=True, **kw)
     assert got.shape == (rows, window, heads, v_width)
@@ -535,3 +537,138 @@ def test_latent_form_matches_its_oracle(case):
     assert live.any() and np.isfinite(np.asarray(got)[live]).all()
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                atol=2e-5, rtol=1e-5)
+
+
+# ---- the per-kv-head body: wide key blocks, blocks without a predicate ----
+
+# block widths (keys a grid step) the derivation can return
+_WIDTHS = (128, 256, 512, 1024)
+
+
+def _per_head_case(form, rng, table, starts, q_lens, window, page=8):
+    """(args, kwargs of _ragged_call, oracle) of one call in the latent
+    form (4 heads on one 128-lane kv head, values its first 96 lanes) or
+    the GQA per-kv-head form (2 kv heads x 2 query heads of 32). Every
+    row's live pages are its own; the table's tail is the poisoned sink."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    rows = len(starts)
+    live = [-(-(s + n) // page) for s, n in zip(starts, q_lens)]
+    pool_pages = sum(live) + 1
+    perm = rng.permutation(np.arange(1, pool_pages))
+    bt = np.zeros((rows, table), np.int32)
+    at = 0
+    for r in range(rows):
+        bt[r, :live[r]] = perm[at:at + live[r]]
+        at += live[r]
+    tail = (jnp.asarray(bt), jnp.asarray(starts, jnp.int32),
+            jnp.asarray(q_lens, jnp.int32))
+    if form == "latent":
+        pages = rng.randn(pool_pages, page, 128).astype(np.float32)
+        pages[0] = np.nan                          # the sink is never read
+        q = jnp.asarray(rng.randn(rows, window, 4, 128), jnp.float32)
+        kw = dict(v_width=96, scale=0.11)
+        args = (q, jnp.asarray(pages), None) + tail
+        want = rpa.ragged_latent_reference(
+            q, jnp.asarray(pages).at[0].set(0.0), *tail, **kw)
+    else:
+        kp, vp = _pools(rng, pool_pages, page, 2, 32)
+        kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+        q = jnp.asarray(rng.randn(rows, window, 4, 32), jnp.float32)
+        kw = dict(scale=32 ** -0.5)
+        args = (q, kp, vp) + tail
+        want = ragged_paged_reference(q, kp.at[0].set(0.0),
+                                      vp.at[0].set(0.0), *tail)
+    return args, kw, want
+
+
+@pytest.mark.parametrize("block_keys", _WIDTHS)
+@pytest.mark.parametrize("form", ["latent", "gqa"])
+def test_per_head_body_at_every_block_width(form, block_keys):
+    """One call, a 24-query window in 16-row tiles (not a multiple: the
+    second tile is half padding) over pages of 8, rows chosen so that the
+    sweep meets: blocks wholly in the prefix (row 0: ~1,200 cached
+    tokens), the diagonal block from position 0 (row 1), a partly live
+    tail block with clamped duplicates behind an unaligned start and a
+    dead second tile (row 2), a kv_len that ends exactly with a block so
+    that the last live query's whole sweep is unmasked, and one that ends
+    inside an otherwise-prefix block (rows 3, 4), a pad row (row 5)."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    starts = [1200, 0, 1013, 1007, 1031, 0]
+    q_lens = [24, 24, 11, 17, 20, 0]
+    args, kw, want = _per_head_case(form, np.random.RandomState(11), 160,
+                                    starts, q_lens, 24)
+    assert not rpa._all_heads(16, 2)
+    steps, masked = rpa.live_key_steps(
+        starts, q_lens, 24, 160, q_tile=16, block_keys=block_keys,
+        page_size=8)
+    assert 0 < masked < steps               # both bodies ran
+    got = rpa._ragged_call(*args, q_tile=16, block_keys=block_keys,
+                           interpret=True, **kw)
+    live = np.arange(24)[None, :] < np.asarray(q_lens)[:, None]
+    assert np.isfinite(np.asarray(got)[live]).all()
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("block_keys", _WIDTHS)
+@pytest.mark.parametrize("form", ["latent", "gqa"])
+def test_per_head_body_under_a_table_narrower_than_a_block(form, block_keys):
+    """A 6-page table (48 keys) under blocks of 128 to 1,024: one grid step
+    whose copies are clamped to the row's last live page."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    starts, q_lens = [20, 0, 0], [16, 9, 0]
+    args, kw, want = _per_head_case(form, np.random.RandomState(12), 6,
+                                    starts, q_lens, 16)
+    got = rpa._ragged_call(*args, q_tile=16, block_keys=block_keys,
+                           interpret=True, **kw)
+    live = np.arange(16)[None, :] < np.asarray(q_lens)[:, None]
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=1e-5)
+
+
+def test_unmasked_and_masked_steps_agree_bit_for_bit():
+    """Where every key of a block is live for every row of the tile the
+    predicate is all true and both selects are the identity: the softmax
+    step without them (``keep`` None) returns the same bits as the one
+    with them, operation by operation (eagerly: nothing is fused)."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    rng = np.random.RandomState(13)
+    s = jnp.asarray(rng.randn(32, 256) * 3, jnp.float32)
+    m = jnp.asarray(rng.randn(32, 1), jnp.float32)
+    l = jnp.asarray(rng.rand(32, 1) + 1, jnp.float32)
+    (m0, l0), (m1, l1) = ([m], [l]), ([m], [l])    # the refs of two sweeps
+    plain = rpa._softmax_step(s, None, m0, l0, 0)
+    kept = rpa._softmax_step(s, jnp.ones(s.shape, bool), m1, l1, 0)
+    assert not np.array_equal(np.asarray(m0[0]), np.asarray(m))
+    for a, b in zip(plain + (m0[0], l0[0]), kept + (m1[0], l1[0])):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("form", ["latent", "gqa"])
+def test_a_sweep_forced_through_the_masked_body_gives_the_same(monkeypatch,
+                                                               form):
+    """The whole kernel with its prefix blocks forced through the body
+    with the predicate (`_prefix_blocks` -> 0) against the shipped one.
+    Under the interpreter the two bodies are two XLA:CPU programs whose
+    fused reductions sum in another order, so this holds to 1e-6 and the
+    bits are held by the step's test above."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    starts, q_lens = [640, 300], [16, 13]
+    args, kw, _ = _per_head_case(form, np.random.RandomState(13), 96,
+                                 starts, q_lens, 16)
+    steps, masked = rpa.live_key_steps(starts, q_lens, 16, 96, q_tile=16,
+                                       block_keys=128, page_size=8)
+    assert (steps, masked) == (6 + 3, 2)
+    call = functools.partial(rpa._ragged_call, *args, q_tile=16,
+                             block_keys=128, interpret=True, **kw)
+    fast = np.asarray(call())
+    monkeypatch.setattr(rpa, "_prefix_blocks", lambda *a, **k: 0)
+    jax.clear_caches()                  # _ragged_call is jitted
+    try:
+        slow = np.asarray(call())
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    live = np.arange(16)[None, :] < np.asarray(q_lens)[:, None]
+    assert np.isfinite(fast[live]).all()
+    np.testing.assert_allclose(fast[live], slow[live], atol=1e-6, rtol=0)
