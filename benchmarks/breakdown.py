@@ -1,8 +1,20 @@
 """The ``breakdown`` of a traced run: the ten device operations that took
-most time, and the longest idle gaps, each named by what the host was doing
-as far as the benchmark's own spans can tell (spans inside the engine do
-not exist yet)."""
+most time, and the longest idle gaps, each named by what the host was
+doing: what the client saw (requests in flight, how many still waited for a
+first token), then the annotation the traced process was inside — the
+engine's phase on the stepping thread (``rtpu.engine.*`` / ``rtpu.loop.*``),
+the training loop's ``train_step`` / ``report`` — then the programs on
+either side.
+
+A gap's name is cut to 64 characters where it is recorded, so it is built
+short: ``<annotation>.<client>.<program before>-to-<program after>``, as in
+``rtpu.engine.admit.wait17of24.decode_w1-to-prefill_r4`` — the stepping
+thread was admitting requests, 17 of the 24 requests in flight still waited
+for their first chunk (``stream24``: none did; ``idle_client``: none in
+flight), between a ``jit_rtpu_decode_w1`` and a ``jit_rtpu_prefill_r4``."""
 from __future__ import annotations
+
+import re
 
 
 def _host_state(ctx: dict, start_wall: float, end_wall: float) -> str:
@@ -16,10 +28,14 @@ def _host_state(ctx: dict, start_wall: float, end_wall: float) -> str:
     mid = (start_wall + end_wall) / 2 - off
     live = [r for r in records if r.sent and r.sent <= mid < (r.done or 1e18)]
     if not live:
-        return "no_request_in_flight"
+        return "idle_client"
     waiting = sum(1 for r in live if not r.first or r.first > mid)
-    return (f"first_chunk_wait.{waiting}of{len(live)}" if waiting
-            else f"streaming.{len(live)}_in_flight")
+    return f"wait{waiting}of{len(live)}" if waiting else f"stream{len(live)}"
+
+
+def _short(program: str) -> str:
+    """``jit_rtpu_decode_w8`` -> ``decode_w8``."""
+    return re.sub(r"^jit_(rtpu_)?", "", program)
 
 
 def breakdown(ctx: dict) -> dict:
@@ -28,13 +44,12 @@ def breakdown(ctx: dict) -> dict:
     gaps = []
     offset = trace.get("clock_offset_ns")
     for g in trace["idle_gaps"]:
-        # host annotations inside the traced process, else what the
-        # client saw at that instant, then the programs on either side
-        state = ".".join(g.get("host", []))
-        if not state and offset is not None:
+        state = ""
+        if offset is not None:
             t0 = (g["start_ns"] + offset) / 1e9
             state = _host_state(ctx, t0, t0 + g["seconds"])
-        name = (f"{state or 'host_unknown'}.after.{g['after'] or 'start'}"
-                f".before.{g['before'] or 'end'}")
-        gaps.append([name[:120], g["seconds"]])
+        state = ".".join(filter(None, [*g.get("host", []), state]))
+        name = (f"{state or 'host_unknown'}.{_short(g['after']) or 'start'}"
+                f"-to-{_short(g['before']) or 'end'}")
+        gaps.append([name[:64], g["seconds"]])
     return {"device_ops": ops, "idle_gaps": gaps}

@@ -13,12 +13,19 @@ import time
 import numpy as np
 
 from ..client import Client, Window
-from ..flops import kv_bytes_per_token
+from ..layer_metrics._engine import deltas
 from ..proc import Child, child_env
 from ..spec import ROOT
 
 MODEL_ID = "bench"          # benchmarks.serve_app.MODEL_ID (that module imports jax)
-TRACE_SLICE_S = (5.0, 1.0)  # traced slice: from 5 s to 1 s before the window's end
+# traced slice: the window's last 4 s. The replica then takes 20-54 s to stop
+# the profiler (26-47 MB of xplane) and answers nothing meanwhile, so the
+# counters that close a traced run's window are the ones it takes as the
+# slice ends (``trace["stats_after"]``), not those of the ``stats`` call
+# after it. Until PR 33 they were the latter, read 22 s and more after the
+# window's end with the queue's drain in them: ``gen_decode_slot_occupancy``
+# read 68 where the window's own counters give 86.
+TRACE_SLICE_S = 4.0
 STALL_S = 5.0               # a longer silence prints the workers' logs
 
 
@@ -80,6 +87,27 @@ def summarize(records, window: Window, slo: dict | None) -> dict:
     return out
 
 
+def _engine_readings(before: dict, after: dict) -> dict:
+    """What the engine's counters say of the window, for the ``window``
+    line of traced and untraced runs alike (per-layer metrics are printed
+    by traced runs only): every integer counter's delta, and the stepping
+    thread's longest single occurrence of each phase (``max_ns_<phase>``,
+    kept since the engine started) in ms, with the phases whose longest
+    fell inside the window — where a stall of seconds (PERF.md §7) was
+    spent."""
+    d = deltas({"stats_before": before, "stats_after": after})
+    longest = "max_ns_"
+    return {
+        "phase_longest_ms": {k[len(longest):]: v / 1e6
+                             for k, v in after.items()
+                             if k.startswith(longest)},
+        "phase_longest_in_window": sorted(
+            k[len(longest):] for k, v in d.items()
+            if k.startswith(longest) and v > 0),
+        "counters_in_window": {k: v for k, v in d.items()
+                               if not k.startswith(longest)}}
+
+
 async def _window(cell, traffic, vocab, seed, seconds, trace, child,
                   port) -> dict:
     """One warm-up plus one measured window against the running server."""
@@ -98,15 +126,15 @@ async def _window(cell, traffic, vocab, seed, seconds, trace, child,
             await asyncio.sleep(max(window.start - time.perf_counter(), 0))
             out["before"] = await ask("stats")
             if trace:
-                a, b = TRACE_SLICE_S
                 await asyncio.sleep(max(
-                    window.end - min(a, seconds * 0.6) - time.perf_counter(), 0))
+                    window.end - min(TRACE_SLICE_S, seconds * 0.6)
+                    - time.perf_counter(), 0))
                 await ask("trace_start", dir=os.path.join(
                     ROOT, "chiprun_out", "trace", cell.name))
-                await asyncio.sleep(max(
-                    window.end - min(b, seconds * 0.2) - time.perf_counter(), 0))
-                out["trace"] = (await ask("trace_stop", timeout=600.0))["trace"]
             await asyncio.sleep(max(window.end - time.perf_counter(), 0))
+            if trace:
+                out["trace"] = (await ask("trace_stop",
+                                          timeout=600.0))["trace"]
             out["after"] = await ask("stats")
 
         ctl = asyncio.ensure_future(control())
@@ -117,6 +145,10 @@ async def _window(cell, traffic, vocab, seed, seconds, trace, child,
             # cadence: read them once they cover the window
             await asyncio.sleep(2.5)
             out["after_flushed"] = await ask("stats")
+        # the window's closing counters: a traced run's are taken by the
+        # replica as the slice, and with it the window, ends
+        out["closing"] = (out.get("trace") or {}).get(
+            "stats_after") or out["after"]["stats"]
         out.update(window=window, records=client.records,
                    wall_offset=time.time() - time.perf_counter())
     return out
@@ -160,7 +192,10 @@ def run(cell, a, t_process_start: float, log) -> dict:
                 for k in ("hits", "misses")}
             log({"phase": "window", "rate_rps": t.get("rate_rps"),
                  **w["summary"], "compile_cache_in_window": w["cache_events"],
-                 "kv_bytes_per_token": kv_bytes_per_token(cfg)})
+                 # from the engine's pools (page_nbytes / page_size)
+                 "cache_bytes_per_token": ready["device"].get(
+                     "cache_bytes_per_token"),
+                 **_engine_readings(w["before"]["stats"], w["closing"])})
             if w["summary"]["longest_silence_s"] > STALL_S:
                 log(child.worker_log_tails())
             results.append(w)
@@ -192,7 +227,7 @@ def run(cell, a, t_process_start: float, log) -> dict:
         "ctx": {"cell": cell, "config": cfg, "traffic": traffic,
                 "records": in_window, "all_records": w["records"],
                 "window": window, "stats_before": before,
-                "stats_after": after,
+                "stats_after": w["closing"],
                 "engine_ttft": (baseline and baseline.get("ttft"),
                                 (w.get("after_flushed") or {}).get("ttft")),
                 "trace": w.get("trace"), "wall_offset": w["wall_offset"],

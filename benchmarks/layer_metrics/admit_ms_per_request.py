@@ -4,11 +4,11 @@ admitted: the wait for the pool lock, the prefix match over the prompt's
 page hashes, promotion from the spill tier, the page claim. Counters
 ``ns_admit`` / ``admitted``.
 
-Admission is pure Python, and the benchmark prints per-layer metrics only
-for a traced run, whose profiler session traces every Python call: on the
-chip it read 1.74 and 1.38 ms traced against 0.72 and 0.76 from the counters
-of untraced runs on the same seeds. Compare a traced value with traced
-values only; it is not the untraced program's cost."""
+Admission is pure Python. Until PR 33 the traced run's profiler session
+traced every Python call and the value was about twice the untraced
+program's (1.74 and 1.38 ms traced against 0.72 and 0.76 from the counters
+of untraced runs on the same seeds); since PR 33 the slice is traced without
+the Python tracer, and the level in the ledger breaks there."""
 from ._engine import per
 
 

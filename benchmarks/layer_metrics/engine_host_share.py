@@ -8,13 +8,13 @@ blocking readback). Set beside ``device_idle_share``: equal means the host
 phases explain the device's idle time; smaller means the rest is launch and
 readback latency inside the ``*_device`` phases.
 
-The benchmark prints per-layer metrics only for a traced run, and its
-profiler session traces every Python call; the host phases are Python, so
-what it reports is inflated by the tracer: on the chip 0.87 and 0.95 traced
-against 0.64 to 0.68 from the counters of untraced runs. Compare a traced
-value with traced values only, and do not read it as the untraced program's
-cost. The partition holds for a server that steps one engine (a per-LoRA
-engine books its ``step()`` to its own stats)."""
+The benchmark prints per-layer metrics only for a traced run. Until PR 33
+the profiler session traced every Python call, and the host phases are
+Python: the value was 1.3-2.4x what an untraced run's counters give. Since
+PR 33 the slice is traced without the Python tracer (``serve_app.py``
+``trace_start``): the level in the ledger breaks there. The partition holds
+for a server that steps one engine (a per-LoRA engine books its ``step()``
+to its own stats)."""
 from ._engine import deltas
 
 

@@ -4,22 +4,21 @@ over the time the kernel took per layer-step.
 
 Least: the larger of (a) bytes — the pages that hold the live sequences'
 tokens at a decode dispatch (counters ``decode_live_pages`` /
-``decode_dispatches``) x ``page_size`` x ``flops_mla.latent_bytes_per_token_
-layer`` (the 1,152 B attention has to read, not the 1,280 B row the pool
+``decode_dispatches`` over the traced slice, ``_engine.slice_deltas``) x
+``page_size`` x ``flops_mla.latent_bytes_per_token_layer`` (the 1,152 B attention has to read, not the 1,280 B row the pool
 stores) over the peak HBM rate — and (b) operations — those live tokens x
 ``flops_mla.attn_pair_flops`` (one query row a live sequence; 32 heads on
 one key make it 60 FLOP a byte, a quarter of the chip's ridge) over the
 peak bf16 rate. Measured: in the traced slice, the self time per call of
 the latent kernel's calls whose window is 1; one call is one layer of one
-step. None when the run was not traced or the program has no such kernel.
-
-The pages are the window's mean and the kernel time the traced slice's
-(``ragged_decode_roofline`` carries the same sampling error)."""
+step. None when the run was not traced, the program has no such kernel, or
+the trace carries no snapshots of the counters at the slice's ends (PR 33:
+both halves cover the slice; ``ragged_prefill_roofline`` says why)."""
 import re
 
 from .. import flops, flops_mla
 from ._common import trace
-from ._engine import per
+from ._engine import per, slice_deltas
 
 DECODE_SHAPE = re.compile(r"^ragged_paged_attention_latent[^:]*:\w+\[\d+,1,")
 
@@ -35,7 +34,8 @@ def kernel_calls(ctx: dict, shape: re.Pattern):
 
 
 def read(ctx: dict):
-    pages = per(ctx, "decode_live_pages", "decode_dispatches")
+    pages = per(ctx, "decode_live_pages", "decode_dispatches",
+                over=slice_deltas)
     calls = kernel_calls(ctx, DECODE_SHAPE)
     if pages is None or calls is None:
         return None

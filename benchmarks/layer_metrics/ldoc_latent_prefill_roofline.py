@@ -4,7 +4,8 @@ attention in one layer over the time the kernel took for it.
 
 A prefill program of models/mla_moe.py calls the kernel once a layer with
 all its R rows (``[R, chunk, heads, rank]``: R is in the operation's
-shape). Least, per live row and layer, from the counters over the window:
+shape). Least, per live row and layer, from the counters over the traced
+slice (``_engine.slice_deltas``; the window's until PR 33):
 FLOPs = ``flops_mla.attn_pair_flops`` x the causal (query, key) pairs a
 row scores (``prefill_attn_pairs`` / ``prefill_rows_live``); bytes = the
 latents of the pages the row attends, read once (``prefill_ctx_pages`` x
@@ -14,12 +15,13 @@ queries read (``latent_dim`` wide a head) and outputs written
 bounds (compute, by ~25x at a 128-row chunk). Measured: the latent
 kernel's self time at windows above 1, over the rows its calls ran (calls x
 R), x ``prefill_rows_padded`` / ``prefill_rows_live`` (a pad row's share of
-a call is time the live rows pay for). None when the run was not traced or
-the program has no such kernel."""
+a call is time the live rows pay for). None when the run was not traced,
+the program has no such kernel, or the trace carries no snapshots of the
+counters at the slice's ends."""
 import re
 
 from .. import flops, flops_mla
-from ._engine import deltas
+from ._engine import slice_deltas
 from .ldoc_latent_decode_roofline import kernel_calls
 
 PREFILL_SHAPE = re.compile(
@@ -27,7 +29,7 @@ PREFILL_SHAPE = re.compile(
 
 
 def read(ctx: dict):
-    d = deltas(ctx)
+    d = slice_deltas(ctx)
     rows = d.get("prefill_rows_live")
     calls = kernel_calls(ctx, PREFILL_SHAPE)
     if calls is None or not rows or not all(

@@ -6,9 +6,8 @@ block wholly in a tile's prefix takes. Counters ``prefill_key_steps_masked``
 / ``prefill_key_steps`` (llm/paged_engine.py ``_prefill_step``: integer
 arithmetic on each row's (pos, n) with the tile rows and keys a step of
 the model module's kernel, one layer's sweep a row). None on a program
-without the counters. Not declared in BENCHMARK.json yet (PERF.md §7): the
-entry is layer ``kernels``, ``program_counter``, better lower, moves
-``out_tok_s``, workloads ``kanana-longdoc-sessions-1chip``."""
+without the counters. Declared in BENCHMARK.json since PR 33; counters
+alone, so over the window."""
 from ._engine import per
 
 

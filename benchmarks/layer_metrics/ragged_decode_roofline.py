@@ -3,30 +3,33 @@
 cache over the time the kernel took per device step.
 
 Least: the pages that hold the live sequences' tokens at a decode dispatch
-(counters ``decode_live_pages`` / ``decode_dispatches``, over the window) x
+(counters ``decode_live_pages`` / ``decode_dispatches``, over the traced
+slice: ``_engine.slice_deltas``) x
 ``page_size`` x ``flops.kv_bytes_per_token`` (keys and values of every layer)
 over the peak HBM bytes/s: decode attention reads each live key and value
 once and is memory-bound. Measured: in the traced slice, the self time of
 the ragged kernel's calls whose shape has a query window of 1, per call, x
-``num_hidden_layers`` calls a step. None when the run was not traced.
+``num_hidden_layers`` calls a step. None when the run was not traced, or
+its trace carries no snapshots of the counters at the slice's ends.
 
-The pages are the mean over the whole window and the kernel time is the
-traced slice's, a tenth of it: sessions cycle through documents of 2-4k
-tokens, so the slice's live cache can differ from the window's mean and the
-share carries that sampling error (5.63 and 5.96 in two traced runs of one
-tree). Snapshots of the counters at the slice's ends would remove it."""
+Both halves cover the slice's seconds since PR 33. Before it the pages were
+the whole window's mean and the kernel time the slice's, a tenth of it:
+sessions cycle through documents, so the slice's live cache differs from
+the window's mean, and a kernel near its roofline then read over 100 with
+nothing miscounted (``ragged_prefill_roofline`` says how far)."""
 import re
 
 from .. import flops
 from ._common import trace
-from ._engine import per
+from ._engine import per, slice_deltas
 
 DECODE_SHAPE = re.compile(r"^ragged[^:]*:\w+\[\d+,1,")
 
 
 def read(ctx: dict):
     t = trace(ctx)
-    pages = per(ctx, "decode_live_pages", "decode_dispatches")
+    pages = per(ctx, "decode_live_pages", "decode_dispatches",
+                over=slice_deltas)
     if t is None or pages is None or ctx.get("rehearse"):
         return None
     calls = [(s, n) for name, s, n, *_ in t["ops"] if DECODE_SHAPE.match(name)]

@@ -28,7 +28,13 @@ COLLECTIVE = re.compile(
 
 _TARGET = re.compile(r'custom_call_target="([^"]+)"')
 _META = re.compile(r"kernel_metadata=\{([^}]*)\}")
-HOST_SPANS = re.compile(r"^(bench_|train_step$|report$)")
+# the benchmark's own annotations, and the program's: the stepping thread's
+# phases (``rtpu.engine.*`` / ``rtpu.loop.*``, util/profiling.phase) name
+# what the host was doing in an idle gap
+HOST_SPANS = re.compile(r"^(bench_|train_step$|report$|rtpu\.)")
+# an engine decode program is named for the device steps one execution
+# runs, its window: ``jit_rtpu_decode_w8``; any other program runs one
+WINDOW = re.compile(r"_w(\d+)$")
 
 
 def _describe(hlo: str) -> tuple:
@@ -48,7 +54,8 @@ def _describe(hlo: str) -> tuple:
 def load(path: str) -> list:
     """[{name, lines: [{name, events: [[name, start_ns, dur_ns, stats]]}]}]
     for the device planes of one ``.xplane.pb``, and the host plane's
-    benchmark annotations (``HOST_SPANS``) as the plane ``host``."""
+    benchmark's and the program's annotations (``HOST_SPANS``) as the plane
+    ``host``."""
     from jax.profiler import ProfileData
     planes, host = [], []
     for plane in ProfileData.from_file(path).planes:
@@ -182,13 +189,17 @@ def reduce(planes: list, chips: int = 1) -> dict:
                       if st.get("target") == "tpu_custom_call")
         starts = [k[0] for k in kern]
         by_family: dict = {}
+        family_steps: dict = {}
         for n, s, d, _ in mods:
             inside = {k[1] for k in kern[bisect.bisect_left(starts, s):
                                          bisect.bisect_right(starts, s + d)]}
             sig = " ".join([_program(n), *sorted(inside)])
+            window = WINDOW.search(_program(n))
             for fam, rx in families.items():
                 if rx.search(sig):
                     by_family.setdefault(fam, []).append(d)
+                    family_steps[fam] = family_steps.get(fam, 0) + (
+                        int(window.group(1)) if window else 1)
         gaps = [[busy[i][1], busy[i + 1][0]] for i in range(len(busy) - 1)]
         devices.append({
             "name": plane["name"], "first": busy[0][0], "last": busy[-1][1],
@@ -196,6 +207,7 @@ def reduce(planes: list, chips: int = 1) -> dict:
             "collective_ns": sum(e - s for s, e in coll),
             "collective_exposed_ns": _subtract(coll, compute),
             "ops": by_op, "modules": by_mod, "families": by_family,
+            "family_steps": family_steps,
             "gaps": gaps,
             "module_spans": [[_program(n), s, s + d] for n, s, d, _ in mods],
         })
@@ -217,11 +229,13 @@ def reduce(planes: list, chips: int = 1) -> dict:
             rec[1] += cnt
     mods_total: dict = {}
     fams_total: dict = {}
+    steps_total: dict = {}
     for d in devices:
         for n, durs in d["modules"].items():
             mods_total.setdefault(n, []).extend(durs)
         for n, durs in d["families"].items():
             fams_total.setdefault(n, []).extend(durs)
+            steps_total[n] = steps_total.get(n, 0) + d["family_steps"][n]
     # what the breakdown shows: operations of one kind and shape together
     # (a 20-layer program runs each under 20 names)
     shown: dict = {}
@@ -278,7 +292,11 @@ def reduce(planes: list, chips: int = 1) -> dict:
                        for n, (ns, cnt, st) in shown.items()),
                       key=lambda r: -r[1])[:20],
         "modules": stats_of(mods_total),
-        "families": stats_of(fams_total),
+        # ``steps``: the device steps the family's executions ran, a decode
+        # program's window each: total_s / steps is one number whatever
+        # the mixture of ``decode_w1`` and ``decode_w8`` in the slice
+        "families": {n: dict(st, steps=steps_total[n])
+                     for n, st in stats_of(fams_total).items()},
         "kernels": kernels,
         "idle_gaps": named_gaps,
     }

@@ -68,32 +68,63 @@ class BenchLLMServer(LLMServer):
                 "memory_peak_bytes": max((p for p in peaks if p), default=0),
                 "memory_limit_bytes": (devs[0].memory_stats() or {}).get(
                     "bytes_limit"),
-                "split": self._split}
+                "split": self._split,
+                # what a cached token costs, from the pools as the engine
+                # built them: whatever the layers hold (keys and values, a
+                # latent, nothing at all), padded as it is stored
+                "cache_bytes_per_token": (
+                    self.engine.page_nbytes // self.engine_cfg.page_size)}
 
     def trace_start(self, out_dir: str) -> dict:
-        """Starts the profiler in this process, the one that holds the chip."""
+        """Starts the profiler in this process, the one that holds the chip.
+
+        Without the profiler's Python tracer: with it every Python call of
+        the replica's threads is an event, the stepping thread runs
+        1.3-2.4x slower between dispatches, and the slice's idle share and
+        host shares measure the tracer (ledger, PR 32: the OLMoE cell's idle
+        share 86 traced where the untraced counters give a slot occupancy
+        of 95). The host tracer stays at its level, so the program's
+        ``TraceAnnotation``s (``rtpu.*``) and this file's still land."""
         import jax
         self._trace_dir = out_dir
         self._trace_t0 = time.perf_counter()
-        jax.profiler.start_trace(out_dir)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(out_dir, profiler_options=options)
         # the trace counts from the session's start: note the wall clock
         # inside an annotation, so that the reduction can place the
         # client's spans (another process) on the trace's clock
         with jax.profiler.TraceAnnotation(
                 f"bench_clock_sync.{time.time_ns()}"):
             time.sleep(0.001)
+        # the counters at the slice's two ends: a reader that divides a
+        # counter by a kernel's traced time takes both from these seconds
+        self._trace_stats = self.engine_stats()
         return {}
 
     def trace_stop(self) -> dict:
         """Stops the profiler and reduces the trace here (the parent never
-        imports jax); returns the reduction, not the trace."""
+        imports jax); returns the reduction with ``engine_stats()`` at the
+        slice's two ends (``stats_before`` / ``stats_after``), not the trace.
+        The closing snapshot is taken before anything else: the slice ends
+        where the window does, stopping the profiler takes this process
+        20-54 s, and the window's counters have to close on time."""
+        import os
+
         import jax
 
         from benchmarks.reduce import xplane
+        after = self.engine_stats()
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
-        window_s = time.perf_counter() - self._trace_t0
+        t1 = time.perf_counter()
+        xplane_bytes = os.path.getsize(xplane.find_xplane(self._trace_dir))
         out = xplane.reduce_dir(self._trace_dir, chips=self._bench["chips"])
-        out["window_s"] = out.get("window_s") or window_s
+        out["window_s"] = out.get("window_s") or t0 - self._trace_t0
+        out.update(stats_before=self._trace_stats, stats_after=after,
+                   # what the slice costs the replica once it has ended
+                   stop_s=t1 - t0, reduce_s=time.perf_counter() - t1,
+                   xplane_bytes=xplane_bytes)
         return out
 
     def reference_check(self, spec: dict, context_tokens: int) -> dict:

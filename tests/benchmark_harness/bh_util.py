@@ -42,6 +42,65 @@ def add_pending(root: str, name: str) -> None:
         json.dump(bench, f)
 
 
+FIFTH_CONFIG, FIFTH_CELL = "fifth-serve-1chip", "fifth-docqa-1chip"
+FIFTH_METRIC = "fifth_decode_step_ms"
+
+
+def add_fifth_cell(root: str, also=("kanana-longdoc-sessions-1chip",
+                                    "olmoe-gen-sessions-1chip")) -> None:
+    """What the next configuration's PR does, on a copy: a fifth
+    configuration (an existing file under a new name), a cell on it, its
+    listing under ``out_tok_s``, and one per-layer metric — a reader file
+    of its own — that lists the new cell and the existing cells ``also``.
+    Entries are appended and files added; none that is there is edited."""
+    here = os.path.join(root, "benchmarks")
+    shutil.copy(os.path.join(here, "configs", "mistral7b-serve-1chip.json"),
+                os.path.join(here, "configs", f"{FIFTH_CONFIG}.json"))
+    with open(os.path.join(here, "layer_metrics",
+                           f"{FIFTH_METRIC}.py"), "w") as f:
+        f.write('"""``decode_step_ms`` for the fifth cell."""\n'
+                "from .decode_step_ms import read  # noqa: F401\n")
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        bench = json.load(f)
+    donor = next(c for c in bench["configs"]
+                 if c["name"] == "mistral7b-serve-1chip")
+    bench["configs"].append(dict(
+        donor, name=FIFTH_CONFIG,
+        file=f"benchmarks/configs/{FIFTH_CONFIG}.json"))
+    bench["workloads"].append({
+        "name": FIFTH_CELL, "config": FIFTH_CONFIG,
+        "traffic": "docqa-sessions", "chips": 1,
+        "why": "a synthetic fifth cell: what the next configuration's PR "
+               "appends"})
+    next(m for m in bench["end_to_end"]
+         if m["name"] == "out_tok_s")["workloads"].append(FIFTH_CELL)
+    bench["per_layer"].append({
+        "name": FIFTH_METRIC, "unit": "ms", "better": "lower",
+        "source": "program_counter", "layer": "engine scheduler",
+        "moves": "out_tok_s", "workloads": [*also, FIFTH_CELL]})
+    with open(path, "w") as f:
+        json.dump(bench, f, indent=1)
+
+
+def load_json(root: str, *path):
+    with open(os.path.join(root, *path)) as f:
+        return json.load(f)
+
+
+def stands_before(names: list, name: str, before: list) -> bool:
+    """``before`` are the entries ahead of ``name`` in ``names``, in that
+    order: what a PR appended stays where it was appended, and whatever a
+    later PR appends after it is no concern of the earlier one's test."""
+    return name in names and names[:names.index(name)] == before
+
+
+def in_order(subset: list, names: list) -> bool:
+    """``subset`` is a subsequence of ``names``."""
+    rest = iter(names)
+    return all(n in rest for n in subset)
+
+
 def rehearse(workload: str, root: str = REPO, trace: int = 0,
              seconds: float = 2.0, timeout: float = 600.0) -> dict:
     """The driver's command with ``JAX_PLATFORMS=cpu --rehearse``; returns

@@ -72,8 +72,9 @@ def test_new_cell_config_mix_and_metric_are_only_new_files(tmp_path):
             assert fh.read() == data, f"{path} was edited"
 
 
-def test_benchmark_json_keeps_to_the_contract():
-    b = _bench()
+def test_benchmark_json_keeps_to_the_contract(bench_root):
+    """On the tree and on a copy with a fifth cell appended."""
+    b = _bench(bench_root)
     assert set(b) == {"command", "paths", "run_seconds", "configs",
                       "workloads", "end_to_end", "per_layer"}
     assert 1 <= b["run_seconds"] <= 51 and isinstance(b["run_seconds"], int)
@@ -122,14 +123,16 @@ def test_benchmark_json_keeps_to_the_contract():
         assert any(m in b["per_layer"] for m in cell_metrics)
     for c in b["configs"]:
         assert c["file"].startswith(tuple(p + "/" for p in b["paths"]))
-        with open(os.path.join(REPO, c["file"])) as f:
+        with open(os.path.join(bench_root, c["file"])) as f:
             cfg = json.load(f)
         assert set(c["reduced"]) == set(cfg["reduced"])
+    files = [c["file"] for c in b["configs"]]
+    assert len(set(files)) == len(files)    # no other configuration's file
 
 
-def test_every_named_file_exists():
-    b = _bench()
-    here = os.path.join(REPO, "benchmarks")
+def test_every_named_file_exists(bench_root):
+    b = _bench(bench_root)
+    here = os.path.join(bench_root, "benchmarks")
     for w in b["workloads"]:
         assert os.path.exists(os.path.join(here, "traffic",
                                            f"{w['traffic']}.json"))

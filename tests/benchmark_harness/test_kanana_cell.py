@@ -3,13 +3,19 @@ the CPU at toy sizes against its own plain reference; the cell, the
 configuration and the mix number for number; the latent cache's bytes,
 the attention FLOPs a pair and the parameter counts by hand; and the new
 readers on a synthetic ``ctx`` — each gives None on a program without the
-counters or the kernel, as the parent commit."""
+counters or the kernel, as the parent commit.
+
+What is asserted of BENCHMARK.json's lists is asserted of PR 31's entries
+and of what stood before them, never of what a later PR appends after
+them: the structural test takes ``bench_root`` (``conftest.py``) and runs on
+the tree and on a copy with a fifth cell appended."""
 import importlib
 import json
 import os
 
 import pytest
-from bh_util import LAST_LINE_KEYS, REPO, rehearse
+from bh_util import (LAST_LINE_KEYS, REPO, in_order, load_json, rehearse,
+                     stands_before)
 
 from benchmarks import flops_mla
 
@@ -28,9 +34,16 @@ FROM_COUNTERS = {"moe_load_max_over_mean", "decode_step_ms",
                  "queue_wait_ms", "ttft_cold_ms", "ttft_warm_ms"}
 
 
+# what stood in the lists when PR 31 appended the cell to them
+WORKLOADS_BEFORE = ["docqa-sessions-1chip", "pretrain-4k-1chip",
+                    "olmoe-gen-sessions-1chip"]
+OUT_TOK_S_BEFORE = ["docqa-sessions-1chip", "olmoe-gen-sessions-1chip"]
+# declared by PR 33, after the sixteen (its reader and counters are PR 32's)
+LATER = ["ldoc_prefill_masked_step_share"]
+
+
 def _json(*path):
-    with open(os.path.join(REPO, *path)) as f:
-        return json.load(f)
+    return load_json(REPO, *path)
 
 
 def _read(name: str, ctx: dict):
@@ -50,22 +63,33 @@ def test_cell_rehearses_with_its_ldoc_metrics_present_and_null():
     assert all(n.startswith("ldoc_") for n in line["metrics"])
 
 
-def test_cell_config_and_mix_are_what_the_issue_names():
+def test_cell_config_and_mix_are_what_the_issue_names(bench_root):
+    def _json(*path):
+        return load_json(bench_root, *path)
     bench = _json("BENCHMARK.json")
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "longdoc-sessions", 1) and len(cell["why"]) <= 200
-    assert bench["workloads"][-1] is cell        # appended, nothing moved
+    # appended, nothing moved: what stood before it still does, in order
+    assert stands_before([w["name"] for w in bench["workloads"]], CELL,
+                         WORKLOADS_BEFORE)
     entry = next(c for c in bench["configs"] if c["name"] == CONFIG)
     assert entry["reduced"] == ["num_hidden_layers"]
     e2e = {m["name"]: m for m in bench["end_to_end"]}
-    assert e2e["out_tok_s"]["workloads"][-1] == CELL
+    assert stands_before(e2e["out_tok_s"]["workloads"], CELL,
+                         OUT_TOK_S_BEFORE)
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    assert [m["name"] for m in mine] == [f"ldoc_{n}" for n in LDOC]
+    sixteen = [f"ldoc_{n}" for n in LDOC]
+    assert len(sixteen) == 16
+    assert in_order(sixteen + LATER, [m["name"] for m in mine])
+    own = [m for m in mine if m["name"] in sixteen + LATER]
     assert all(m["moves"] == "out_tok_s" and m["workloads"] == [CELL]
-               for m in mine)
-    layers = {m["layer"] for m in bench["per_layer"] if m not in mine}
-    assert {m["layer"] for m in mine} <= layers  # no layer under a new name
+               for m in own)
+    layers = {m["layer"] for m in bench["per_layer"] if m not in own}
+    assert {m["layer"] for m in own} <= layers   # no layer under a new name
+    masked = next(m for m in own if m["name"] == LATER[0])
+    assert (masked["layer"], masked["source"], masked["unit"],
+            masked["better"]) == ("kernels", "program_counter", "%", "lower")
 
     cfg = _json("benchmarks", "configs", f"{CONFIG}.json")
     assert entry["source"] == cfg["source"]
@@ -153,15 +177,21 @@ def test_flops_mla_counted_by_hand_for_one_layer():
         pytest.approx(30.67e9, rel=1e-3)
 
 
-def _ctx(ops, **stats):
+def _ctx(ops, in_slice=None, **stats):
+    """``stats``: the counters over the window; ``in_slice``: over the
+    traced slice (the snapshots ``trace_stop`` returns beside the
+    reduction), left out of the trace when None."""
     zero = dict.fromkeys(stats, 0)
+    trace = {"devices": 1, "busy_s": 2.0, "window_s": 2.5,
+             "busy_s_worst": 2.0, "ops": ops, "kernels": {
+                 "latent_attention": {"seconds": 0.8, "count": 900},
+                 "moe_ffn": {"seconds": 0.6, "count": 1400}}}
+    if in_slice is not None:
+        trace.update(stats_before=dict.fromkeys(in_slice, 0),
+                     stats_after=in_slice)
     return {"stats_before": zero, "stats_after": stats,
             "config": _json("benchmarks", "configs", f"{CONFIG}.json"),
-            "device": {"kind": "TPU v5 lite"},
-            "trace": {"devices": 1, "busy_s": 2.0, "window_s": 2.5,
-                      "busy_s_worst": 2.0, "ops": ops, "kernels": {
-                          "latent_attention": {"seconds": 0.8, "count": 900},
-                          "moe_ffn": {"seconds": 0.6, "count": 1400}}}}
+            "device": {"kind": "TPU v5 lite"}, "trace": trace}
 
 
 LATENT = "ragged_paged_attention_latent"
@@ -173,11 +203,20 @@ def test_latent_readers_on_a_synthetic_ctx():
            [f"{LATENT}:bf16[2,128,32,512]", 0.06, 20, "tpu_custom_call"],
            ["grouped_swiglu:bf16[1024,768]", 0.4, 700, "tpu_custom_call"],
            ["fusion:bf16[32,6144]", 0.2, 4000, ""]]
-    ctx = _ctx(ops, decode_live_pages=1_536_000, decode_dispatches=100,
-               prefill_rows_live=200, prefill_rows_padded=220,
-               prefill_tokens=200 * 128, prefill_ctx_pages=200 * 768,
-               prefill_attn_pairs=200 * 128 * 12224,
-               moe_expert_load_max=300, moe_expert_load_sum=19_200)
+    in_slice = dict(decode_live_pages=1_536_000, decode_dispatches=100,
+                    prefill_rows_live=200, prefill_rows_padded=220,
+                    prefill_tokens=200 * 128, prefill_ctx_pages=200 * 768,
+                    prefill_attn_pairs=200 * 128 * 12224)
+    # the window's counters tell of other documents than the slice's: a
+    # fifth more pages a decode dispatch, a fifth more pairs a prefill row.
+    # The rooflines take the slice's, the readers of counters alone these
+    window = dict(in_slice, decode_live_pages=12 * 1_843_200,
+                  decode_dispatches=1200, prefill_rows_live=2400,
+                  prefill_rows_padded=2640, prefill_tokens=2400 * 128,
+                  prefill_ctx_pages=2400 * 922,
+                  prefill_attn_pairs=2400 * 128 * 14669,
+                  moe_expert_load_max=300, moe_expert_load_sum=19_200)
+    ctx = _ctx(ops, in_slice, **window)
     assert _read("latent_attn_dev_share", ctx) == pytest.approx(40.0)
     assert _read("moe_ffn_dev_share", ctx) == pytest.approx(30.0)
     # 300 x 128 / 19,200: the busiest expert got twice the mean
@@ -196,6 +235,11 @@ def test_latent_readers_on_a_synthetic_ctx():
     assert _read("latent_prefill_roofline", ctx) == pytest.approx(
         100 * least / (0.36 / 240 * 1.1)) == pytest.approx(33.52, abs=0.01)
     assert _read("latent_prefill_roofline", ctx) < 100
+    # a trace without the slice's snapshots (reduced by the parent's
+    # ``trace_stop``): nothing to read, never the window's in their place
+    for name in ("latent_decode_roofline", "latent_prefill_roofline"):
+        assert _read(name, _ctx(ops, None, **window)) is None
+        assert _read(name, _ctx(ops, {}, **window)) is None
 
 
 @pytest.mark.parametrize("name", [

@@ -8,7 +8,7 @@ import json
 import os
 
 import pytest
-from bh_util import LAST_LINE_KEYS, REPO, rehearse
+from bh_util import LAST_LINE_KEYS, in_order, load_json, rehearse
 
 from benchmarks import flops_moe
 
@@ -39,30 +39,39 @@ def test_cell_rehearses_with_its_gen_metrics_present_and_null():
     assert all(n.startswith("gen_") for n in line["metrics"])
 
 
-def test_cell_is_what_the_issue_names():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+# PR 27's thirteen, in the order it appended them
+GEN = ["moe_ffn_dev_share", "moe_ffn_roofline", "moe_live_assign_share",
+       "moe_load_max_over_mean", "decode_step_ms", "decode_prog_dev_ms",
+       "decode_slot_occupancy", "decode_tok_per_dispatch",
+       "ragged_attn_dev_share", "ragged_decode_roofline",
+       "engine_host_share", "device_idle_share", "prefix_hit_tok_share"]
+
+
+def test_cell_is_what_the_issue_names(bench_root):
+    """On the tree and on a copy with a fifth cell appended: the thirteen
+    are a subset, in order, of the per-layer metrics that list the cell,
+    and what is asserted of a metric is asserted of them."""
+    bench = load_json(bench_root, "BENCHMARK.json")
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "olmoe7b-serve-1chip", "gen-sessions", 1)
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert CELL in e2e["out_tok_s"]["workloads"]
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    assert len(mine) == 13 and all(
-        m["name"].startswith("gen_") and m["moves"] == "out_tok_s"
-        and m["workloads"] == [CELL] for m in mine)
-    with open(os.path.join(REPO, "benchmarks", "configs",
-                           "olmoe7b-serve-1chip.json")) as f:
-        cfg = json.load(f)
+    thirteen = [f"gen_{n}" for n in GEN]
+    assert len(thirteen) == 13
+    assert in_order(thirteen, [m["name"] for m in mine])
+    assert all(m["moves"] == "out_tok_s" and m["workloads"] == [CELL]
+               for m in mine if m["name"] in thirteen)
+    cfg = load_json(bench_root, "benchmarks", "configs",
+                    "olmoe7b-serve-1chip.json")
     # every width as published; only the depth is cut
     for key, value in OLMOE.items():
         assert cfg[key] == value, key
     assert cfg["norm_topk_prob"] is False and list(cfg["reduced"]) == [
         "num_hidden_layers"]
     assert cfg["engine"]["max_batch_size"] == 64
-    with open(os.path.join(REPO, "benchmarks", "traffic",
-                           "gen-sessions.json")) as f:
-        mix = json.load(f)
+    mix = load_json(bench_root, "benchmarks", "traffic", "gen-sessions.json")
     assert mix["sessions"] == cfg["engine"]["max_batch_size"]
     assert mix["warmup_s"] == pytest.approx(
         (mix["sessions"] - 1) * mix["stagger_s"] + 1)

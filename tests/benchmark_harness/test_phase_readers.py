@@ -1,7 +1,9 @@
 """The nine per-layer metrics that read the engine's phase times and
 dispatch counters (PR 24), each on a hand-made ``ctx`` with a known answer,
 and None where the program has no such counter (every earlier commit) or
-the run was not traced."""
+the run was not traced. The two rooflines divide counters by a kernel's
+traced time: they take the counters over the traced slice (PR 33), the
+others over the window."""
 import importlib
 
 import pytest
@@ -39,16 +41,31 @@ CONFIG = {"num_hidden_layers": 20, "num_key_value_heads": 8, "head_dim": 128,
 # the decode shape (query window 1) ran 400 calls in 0.8 s: 2 ms a call, 40
 # ms a step of 20 layers; the prefill shape (a window above 1) ran 60 calls
 # in 0.3 s: 5 ms a call; each reader must leave the other's shape out
+# the slice's own counters (the snapshots ``trace_stop`` returns beside the
+# reduction): 10 decode dispatches with 24,000 live pages (2,400 a dispatch,
+# where the window's mean is 1,920); 6 prefill rows live of 8 run, with 720
+# tokens (120 a row), 600 context pages (100 a row) and 1,500,000 pairs
+# (250,000 a row, where the window's mean is 200,000)
+SLICE_BEFORE = {"decode_dispatches": 20, "decode_live_pages": 30_000,
+                "prefill_rows_live": 20, "prefill_rows_padded": 30,
+                "prefill_tokens": 3_000, "prefill_ctx_pages": 2_000,
+                "prefill_attn_pairs": 4_000_000, "clock_ns": 7}
+SLICE_AFTER = {"decode_dispatches": 30, "decode_live_pages": 54_000,
+               "prefill_rows_live": 26, "prefill_rows_padded": 38,
+               "prefill_tokens": 3_720, "prefill_ctx_pages": 2_600,
+               "prefill_attn_pairs": 5_500_000, "clock_ns": 400_000_007}
 TRACE = {"devices": 1, "busy_s": 1.0, "window_s": 1.1, "ops": [
     ["ragged_paged_attention:bf16[32,1,32,128]", 0.8, 400, "tpu_custom_call"],
     ["ragged_paged_attention:bf16[1,128,32,128]", 0.3, 60, "tpu_custom_call"],
-    ["fusion:bf16[32,14336]", 0.1, 400, ""]]}
+    ["fusion:bf16[32,14336]", 0.1, 400, ""]],
+    "stats_before": SLICE_BEFORE, "stats_after": SLICE_AFTER}
 
 
-def _ctx(**over):
+def _ctx(in_slice=(SLICE_BEFORE, SLICE_AFTER), **over):
     ctx = {"stats_before": BEFORE, "stats_after": AFTER, "config": CONFIG,
-           "trace": TRACE, "device": {"kind": "TPU v5 lite"},
-           "rehearse": False}
+           "trace": dict(TRACE, stats_before=in_slice[0],
+                         stats_after=in_slice[1]),
+           "device": {"kind": "TPU v5 lite"}, "rehearse": False}
     ctx.update(over)
     return ctx
 
@@ -58,15 +75,15 @@ def _read(name: str, ctx: dict):
         f"benchmarks.layer_metrics.docqa_{name}").read(ctx)
 
 
-# 1920 live pages a dispatch x 16 tokens x 81,920 B = 2.5166 GB, 3.0728 ms at
-# 819 GB/s, over 40 ms a step
-ROOFLINE = 100.0 * (1920 * 16 * 81_920 / 819e9) / 0.040
+# the slice's 2400 live pages a dispatch x 16 tokens x 81,920 B = 3.1457 GB,
+# 3.8410 ms at 819 GB/s, over 40 ms a step
+ROOFLINE = 100.0 * (2400 * 16 * 81_920 / 819e9) / 0.040
 
-# a live row and layer: 4 x 32 x 128 x 200,000 pairs = 3.2768 GFLOP = 16.63 us
-# at 197 TFLOP/s; 100 pages x 16 x 4,096 B of keys and values + 120 tokens x
-# 32 x 128 x 2 B read and written = 8.5197 MB = 10.40 us at 819 GB/s: the
-# compute bound, over 5 ms a call x 40 / 30 rows run per live row
-PREFILL_ROOFLINE = 100.0 * (4 * 32 * 128 * 200_000 / 197e12) / (0.005 * 40 / 30)
+# a live row and layer of the slice: 4 x 32 x 128 x 250,000 pairs = 4.096
+# GFLOP = 20.79 us at 197 TFLOP/s; 100 pages x 16 x 4,096 B of keys and values
+# + 120 tokens x 32 x 128 x 2 B read and written = 8.5197 MB = 10.40 us at
+# 819 GB/s: the compute bound, over 5 ms a call x 8 / 6 rows run per live row
+PREFILL_ROOFLINE = 100.0 * (4 * 32 * 128 * 250_000 / 197e12) / (0.005 * 8 / 6)
 
 EXPECTED = {
     # host: 4 + 6 + 2 + 8 = 20 ms of 100 ms worked; the idle 900 ms and
@@ -87,8 +104,8 @@ TRACED = ("ragged_decode_roofline", "ragged_prefill_roofline")
 @pytest.mark.parametrize("name", sorted(EXPECTED))
 def test_reader_gives_the_hand_computed_value(name):
     assert _read(name, _ctx()) == pytest.approx(EXPECTED[name], rel=1e-9)
-    assert 7.6 < ROOFLINE < 7.8
-    assert 0.24 < PREFILL_ROOFLINE < 0.26
+    assert 9.5 < ROOFLINE < 9.7
+    assert 0.31 < PREFILL_ROOFLINE < 0.32
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
@@ -96,20 +113,33 @@ def test_reader_gives_none_on_a_program_without_the_counters(name):
     """The parent commit's ``engine.stats`` has none of these keys: the
     reader returns None, it does not raise."""
     old = {"decode_dispatches": 10, "tokens_out": 7, "mesh": None}
-    assert _read(name, _ctx(stats_before=old, stats_after=dict(
-        old, decode_dispatches=30))) is None
-    assert _read(name, _ctx(stats_before=None, stats_after=None)) is None
+    new = dict(old, decode_dispatches=30)
+    assert _read(name, _ctx((old, new), stats_before=old,
+                            stats_after=new)) is None
+    assert _read(name, _ctx((None, None), stats_before=None,
+                            stats_after=None)) is None
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))
 def test_reader_gives_none_when_nothing_was_counted(name):
-    assert _read(name, _ctx(stats_after=BEFORE)) is None
+    assert _read(name, _ctx((SLICE_BEFORE, SLICE_BEFORE),
+                            stats_after=BEFORE)) is None
 
 
 @pytest.mark.parametrize("name", TRACED)
 def test_roofline_needs_the_trace_and_its_own_shape(name):
     assert _read(name, _ctx(trace=None)) is None
     assert _read(name, _ctx(trace={"devices": 0})) is None
+    # a rehearsal on the CPU: snapshots and no device plane
+    assert _read(name, _ctx(trace={
+        "devices": 0, "stats_before": SLICE_BEFORE,
+        "stats_after": SLICE_AFTER})) is None
+    # a trace without the slice's snapshots (the parent's ``trace_stop``):
+    # None, never the window's counters over the slice's kernel time
+    bare = {k: v for k, v in TRACE.items() if not k.startswith("stats_")}
+    assert _read(name, _ctx(trace=bare)) is None
+    assert _read(name, _ctx(trace=dict(bare, stats_before=SLICE_BEFORE))) \
+        is None
     other = [op for op in TRACE["ops"] if (",1,32," in op[0]) ==
              (name == "ragged_prefill_roofline")]
     assert _read(name, _ctx(trace=dict(TRACE, ops=other))) is None
@@ -117,19 +147,34 @@ def test_roofline_needs_the_trace_and_its_own_shape(name):
 
 
 def test_the_others_read_counters_alone():
+    """Over the window, whatever the slice's snapshots say."""
     for name in sorted(set(EXPECTED) - set(TRACED)):
         assert _read(name, _ctx(trace=None)) == pytest.approx(EXPECTED[name])
+        assert _read(name, _ctx((AFTER, AFTER))) == pytest.approx(
+            EXPECTED[name])
+
+
+def test_slice_deltas_are_the_traces_own_snapshots():
+    from benchmarks.layer_metrics import _engine
+    ctx = _ctx()
+    assert _engine.slice_deltas(ctx)["decode_live_pages"] == 24_000
+    assert _engine.deltas(ctx)["decode_live_pages"] == 38_400
+    assert _engine.per(ctx, "decode_live_pages", "decode_dispatches",
+                       over=_engine.slice_deltas) == 2400.0
+    assert _engine.per(ctx, "decode_live_pages", "decode_dispatches") == 1920.0
+    assert _engine.slice_deltas({"trace": None}) == {}
+    assert _engine.slice_deltas({}) == {}
 
 
 def test_prefill_roofline_takes_the_memory_bound_where_it_is_larger():
     """Rows of a few tokens over a long cache stream more than they
     compute: 20,000 pairs a row is 1.66 us of FLOPs against 10.40 us of
     bytes."""
-    after = dict(AFTER, prefill_attn_pairs=BEFORE["prefill_attn_pairs"]
-                 + 30 * 20_000)
+    after = dict(SLICE_AFTER, prefill_attn_pairs=SLICE_BEFORE[
+        "prefill_attn_pairs"] + 6 * 20_000)
     least = (100 * 16 * 4096 + 2 * 120 * 32 * 128 * 2) / 819e9
-    assert _read("ragged_prefill_roofline", _ctx(stats_after=after)) == \
-        pytest.approx(100.0 * least / (0.005 * 40 / 30), rel=1e-9)
+    assert _read("ragged_prefill_roofline", _ctx((SLICE_BEFORE, after))) == \
+        pytest.approx(100.0 * least / (0.005 * 8 / 6), rel=1e-9)
 
 
 def test_twins_share_one_reader():

@@ -4,7 +4,8 @@ serving cell (traced), of the training cell, of the pending open-loop cell,
 and of the pending four-chip cell's mesh on four forced host devices. A
 rehearsal's metrics are all null and its device says cpu."""
 import pytest
-from bh_util import LAST_LINE_KEYS, add_pending, copy_benchmark, rehearse
+from bh_util import (FIFTH_METRIC, LAST_LINE_KEYS, add_fifth_cell, add_pending,
+                     copy_benchmark, rehearse)
 
 
 def _check(line: dict, names: set) -> None:
@@ -25,6 +26,23 @@ def _check(line: dict, names: set) -> None:
 ])
 def test_cell_rehearses(workload, trace, names):
     _check(rehearse(workload, trace=trace), names)
+
+
+def test_existing_cell_rehearses_beside_an_appended_fifth(tmp_path):
+    """The next configuration's PR appends a configuration, a cell, an
+    ``out_tok_s`` listing and a per-layer metric (``bh_util.add_fifth_cell``,
+    here with doc-QA among the cells the new metric lists): the cell that
+    was there runs as it did, with the new metric in its line."""
+    root = copy_benchmark(tmp_path)
+    add_fifth_cell(root, also=("docqa-sessions-1chip",))
+    line = rehearse("docqa-sessions-1chip", root=root, trace=1)
+    _check(line, {"prefix_hit_tok_share", "docqa_decode_step_ms",
+                  FIFTH_METRIC})
+    # a rehearsal has no device trace: a reader that needs the traced
+    # slice finds nothing to read and is left out of a well-formed line
+    assert not {"docqa_ragged_decode_roofline",
+                "docqa_ragged_prefill_roofline",
+                "docqa_decode_prog_dev_ms"} & set(line["metrics"])
 
 
 def test_pending_chat_cell_rehearses(tmp_path):
