@@ -19,16 +19,26 @@ Two families of jitted programs with static shapes, keyed by unroll factor:
     per dispatch (lax.scan feeds each step's sampled tokens back in
     on-device; window 1 while prompts are pending keeps TTFT low).
 
-Sampling is fused into both programs (sample_logits_batch), so one engine
-step is ONE device dispatch and the only device->host traffic is the
-sampled token block. The Python loop does admission, page allocation and
-retirement; all math stays compiled. Cache buffers are donated through
-every program so XLA updates pages in place.
+Sampling is fused into both programs (sample_logits_batch), so the only
+device->host traffic of a dispatch is the sampled token block. The Python
+loop does admission, page allocation and retirement; all math stays
+compiled. Cache buffers are donated through every program so XLA updates
+pages in place, and that chain of donations is what orders the programs
+on the device.
+
+A dispatch is launched as soon as the values it needs are on the host,
+not once the one before it has been read back (step()): a prefill is
+built from host state alone, a decode needs the tokens of the decode
+before it. So up to two dispatches are outstanding, and the readback of
+one runs beside the program of the other.
 
 Where the stepping thread's time goes is counted in ``stats["ns_*"]``
 (PHASES below) and, under a profiler session, drawn as ``rtpu.engine.*``
-spans on the device trace's clock; the share of it in which no dispatch
-was outstanding is the benchmark's ``engine_host_share`` (PERF.md §3).
+spans on the device trace's clock. A ``*_device`` phase is a launch or
+the blocking part of a readback; the other phases mostly run while a
+dispatch is outstanding, so their share of the thread's time (the
+benchmark's ``engine_host_share``) is what the host costs a step, not
+what the device waits for (PERF.md §3).
 """
 from __future__ import annotations
 
@@ -78,11 +88,14 @@ class PagedEngineConfig:
     max_pages_per_seq: int = 64
     # prefill chunk (page multiple); up to prefill_rows chunks per step
     chunk_size: int = 128
-    # dispatch batching: chunk-rows prefetched per prefill dispatch and
-    # decode steps unrolled (lax.scan) per decode dispatch. Each dispatch
-    # costs a host->device round trip, which both paths amortize (on a
-    # locally attached v5e the host phases between two dispatches take
-    # ~1 ms against programs of 57-515 ms: PERF.md §5).
+    # dispatch batching: chunk-rows packed per prefill dispatch and
+    # decode steps unrolled (lax.scan) per decode dispatch. A dispatch's
+    # launch-to-readback round trip is 3-8 ms on a locally attached v5e
+    # against programs of 9-35 ms (PERF.md §5). While both families have
+    # work it is hidden: the next dispatch is queued on the device before
+    # this one's tokens come back (step()). Decode after decode still
+    # pays it, a launch needing the last one's tokens, and that is what
+    # decode_window is left to amortize.
     # decode_window only applies when no prefill is pending (window 1
     # keeps TTFT low while prompts are still entering the batch).
     prefill_rows: int = 4
@@ -208,9 +221,18 @@ PHASES = {
 }
 
 
+@dataclasses.dataclass
+class _Launched:
+    """A dispatch launched and not yet read back."""
+    family: str     # "prefill" | "decode": whose phases its booking is
+    outs: tuple     # device arrays: tokens, logprobs | None, load | None
+    host: dict      # the launch's own state, as its booking's keywords
+
+
 class PagedInferenceEngine:
-    """Synchronous paged engine; serving runs it on a background thread
-    (reference: the engine-loop surface of VLLMEngine)."""
+    """Paged engine stepped by one thread; serving runs it on a
+    background thread (reference: the engine-loop surface of
+    VLLMEngine)."""
 
     telemetry_kind = "paged"
 
@@ -239,6 +261,9 @@ class PagedInferenceEngine:
         self._active: dict[int, _Request] = {}
         self._prefilling: list[_Request] = []   # admitted, prompt not done
         self._pending: deque[_Request] = deque()
+        # launched, not yet read back, oldest first: at most two, and
+        # one between two step() calls (step())
+        self._inflight: deque[_Launched] = deque()
         # -- prefix cache state (enable_prefix_caching) -------------------
         # Full pages are content-addressed by a chained hash
         # h_i = H(h_{i-1} || page_token_ids) — the chain makes the flat
@@ -341,6 +366,9 @@ class PagedInferenceEngine:
         self._verify_fns: dict[tuple, Any] = {}
         # observability: dispatches per program family, spec accept stats
         self.stats = {"prefill_dispatches": 0, "decode_dispatches": 0,
+                      # launches made while another dispatch was
+                      # outstanding: how often step() runs ahead
+                      "dispatches_overlapped": 0,
                       "spec_dispatches": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "tokens_out": 0,
                       # prefix cache: full prompt pages served from cache
@@ -872,11 +900,13 @@ class PagedInferenceEngine:
         return req
 
     def has_work(self) -> bool:
-        return bool(self._pending or self._prefilling or self._active)
+        return bool(self._pending or self._prefilling or self._active
+                    or self._inflight)
 
     def run_until_done(self, reqs: list[_Request]):
         while not all(r.done for r in reqs):
             self.step()
+        self._drain()
 
     def _eos_id(self):
         return getattr(self.tokenizer, "eos_id",
@@ -1211,19 +1241,76 @@ class PagedInferenceEngine:
         return phase(self.stats, key, PHASES[key])
 
     def step(self):
-        """One iteration: admit, one prefill chunk (bounded), one decode.
-        All of it falls in one of the eight rtpu.engine.* phases
+        """One iteration: admit, launch one prefill dispatch (bounded),
+        launch one decode dispatch, and read back whatever an earlier
+        launch left on the device as soon as a launch needs it.
+
+        A prefill is built from host state alone, so it is queued behind
+        the dispatch the last step() left outstanding; a decode needs
+        the tokens of the decode before it (and the first tokens of
+        prompts that finished earlier), not those of the prefill just
+        launched: a prompt that ends in it joins the decode batch one
+        dispatch later. With both families at work that is launch P(k)
+        beside D(k-1), book D(k-1), launch D(k) beside P(k), book P(k);
+        with nothing decoding P(k+1) is launched beside P(k); with
+        nothing prefilling a decode follows the booking of the last one.
+        When P(k) holds the last chunk any prompt waits for, the decode
+        after it is a full window: launched beside P(k) it would run
+        decode_window steps (and a round of every stream's Python)
+        without the prompts that end in P(k), so it follows P(k)'s
+        booking and carries them.
+        The donated pools order the programs on the device, and a page
+        freed on the host can only be written by a program launched
+        later. Everything falls in one of the eight rtpu.engine.* phases
         (PHASES): admit, {prefill, decode} x {build, device, post},
-        telemetry."""
+        telemetry; ``device`` is a launch or a blocking readback."""
         with self._phase("ns_admit"):
             self._admit()
         # the mesh scope pins trace-time constrain() resolution for any
-        # program a dispatch compiles below (a no-op off-mesh)
+        # program a launch compiles below (a no-op off-mesh)
         with self._mesh_scope():
-            self._prefill_step()
-            self._decode_step()
+            ahead = self._launch_prefill() and self._prompts_wait()
+            self._book_until(1 if ahead else 0)
+            self._launch_decode()
+            self._book_until(1)
         with self._phase("ns_telemetry"):
             telemetry.on_step(self)
+
+    def _prompts_wait(self) -> bool:
+        """Is any prompt chunk still to be launched?"""
+        return bool(self._pending) or any(
+            r.prefill_pos < len(r.prompt_ids) for r in self._prefilling)
+
+    def _book_until(self, keep: int):
+        """Read back and book outstanding dispatches, oldest first (the
+        order the device runs them in), until ``keep`` are left."""
+        while len(self._inflight) > keep:
+            d = self._inflight[0]
+            book = (self._book_prefill if d.family == "prefill"
+                    else self._book_decode)
+            with self._phase(f"ns_{d.family}_device"):
+                # block until the outputs are on the host; a readback
+                # that raises leaves the dispatch outstanding
+                got = [None if x is None else np.asarray(x) for x in d.outs]
+            with self._phase(f"ns_{d.family}_post"):
+                book(*got, **d.host)
+                # the device's arrays and their host copies go inside
+                # the phase: the phases leave nothing of step() out
+                del self._inflight[0], d, got
+
+    def _drain(self):
+        """Leave nothing outstanding: what ends a blocking call."""
+        with self._mesh_scope():
+            self._book_until(0)
+
+    def _launched(self, family: str, outs: tuple, **host):
+        """A program was just launched: queue its booking, count it as
+        running ahead if an earlier one is still out, wake the streams."""
+        if self._inflight:
+            self.stats["dispatches_overlapped"] += 1
+        self._inflight.append(_Launched(family, outs, host))
+        self._rng_ctr += 1
+        self._notify_launch()
 
     def _admit(self):
         with self._lock:
@@ -1274,12 +1361,23 @@ class PagedInferenceEngine:
                 self.stats["queue_wait_ns"] += int(
                     (req.admit_t - req.submit_t) * 1e9)
 
-    def _prefill_step(self):
+    def _launch_prefill(self) -> bool:
+        """Pack and launch one prefill dispatch; False when no row can
+        be launched yet."""
         if not self._prefilling:
-            return
+            return False
         cfg = self.cfg
-        c = cfg.chunk_size
+        c, pg = cfg.chunk_size, cfg.page_size
         with self._phase("ns_prefill_build"):
+            # pages an outstanding prefill computes are published when
+            # it is booked: a request that could then map them in (the
+            # rest of an identical-prefix burst) waits for that, and
+            # does not compute them again
+            unpublished = {
+                h for d in self._inflight if d.family == "prefill"
+                for req, pos, n in d.host["rows"]
+                for h in self._prompt_hashes(req)[pos // pg:(pos + n) // pg]
+            } if self._prefix_on else ()
             # pack up to prefill_rows chunk-rows, queue order; a request
             # with several remaining chunks occupies consecutive rows (every
             # row's K/V is in the pages before any row attends, so later
@@ -1290,6 +1388,10 @@ class PagedInferenceEngine:
                 # identical-prefix burst: request 1 computes, the rest map)
                 self._try_reuse(req)
                 pos = req.prefill_pos
+                if unpublished and pos % c == 0 and \
+                        pos < self._reuse_limit(req) and \
+                        self._prompt_hashes(req)[pos // pg] in unpublished:
+                    continue
                 while pos < len(req.prompt_ids) and \
                         len(rows) < cfg.prefill_rows:
                     n = min(c, len(req.prompt_ids) - pos)
@@ -1297,6 +1399,8 @@ class PagedInferenceEngine:
                     pos += n
                 if len(rows) >= cfg.prefill_rows:
                     break
+            if not rows:
+                return False
             # bucket the row count to a power of two (same trick as
             # _spec_step): the jit cache holds O(log prefill_rows) prefill
             # programs instead of one per packed-row count. Pad rows carry
@@ -1308,7 +1412,6 @@ class PagedInferenceEngine:
             rb = min(1 << max(r - 1, 0).bit_length(), cfg.prefill_rows)
             # block-table width bucket: widest logical page any row reads
             # or writes this dispatch (prefix + chunk = pos + n tokens)
-            pg = cfg.page_size
             ctx_pages = [(pos + n + pg - 1) // pg for _, pos, n in rows]
             W = self._page_bucket(max(ctx_pages))
             chunks = np.zeros((rb, c), np.int32)
@@ -1325,53 +1428,60 @@ class PagedInferenceEngine:
                 temps[i] = req.params.temperature
                 topks[i] = req.params.top_k
                 lslots[i] = req.adapter_slot
+                # the next dispatch's rows start where these end: that
+                # needs no readback
+                req.prefill_pos = pos + n
             mode = self._sampling_mode([q for q, _, _ in rows])
             fn = self._prefill_rows_fn(rb, mode, W)
-        with self._phase("ns_prefill_device"), \
-                self.profiler.step("prefill", (rb, mode, W)):
-            toks, lps, load, self.caches = fn(
-                self.params, self.caches, chunks, bts, sps, tls,
-                self._rng_base, np.int32(self._rng_ctr), temps, topks,
-                *self._lora_args(lslots))
-            self._notify_launch()
-            toks = np.asarray(toks)     # block: the step must measure
-            lps = None if lps is None else np.asarray(lps)
-            load = None if load is None else np.asarray(load)
-        with self._phase("ns_prefill_post"):
-            self._rng_ctr += 1
-            st = self.stats
-            st["prefill_dispatches"] += 1
-            st["prefill_rows_live"] += r
-            st["prefill_rows_padded"] += rb
-            st["prefill_tokens"] += int(tls.sum())
-            st["prefill_ctx_pages"] += sum(ctx_pages)
-            # causal (query, key) pairs: token q of a row attends the
-            # pos cached tokens and the row's first q + 1
-            st["prefill_attn_pairs"] += sum(
-                n * pos + n * (n + 1) // 2 for _, pos, n in rows)
-            steps, masked = live_key_steps(
-                sps[:r], tls[:r], c, W, page_size=pg,
-                **self.model.prefill_attn_step(
-                    self.cfg.model, c, pg, W,
-                    1 if self.mesh is None else self.mesh.shape.get("tp", 1)))
-            st["prefill_key_steps"] += steps
-            st["prefill_key_steps_masked"] += masked
-            self._moe_account(load, int(tls.sum()), rb * c)
-            self._mesh_account(
-                chunks.nbytes + bts.nbytes + sps.nbytes + tls.nbytes
-                + temps.nbytes + topks.nbytes + lslots.nbytes,
-                toks.nbytes + sum(x.nbytes for x in (lps, load)
-                                  if x is not None))
-            if self._prefix_on:
-                self._publish_prefilled(rows)
-            for i, (req, pos, n) in enumerate(rows):
-                req.prefill_pos = pos + n
-                if req.prefill_pos >= len(req.prompt_ids):
-                    # prompt done: the row's in-jit sampled token is the
-                    # first generated token
-                    self._first_token(
-                        req, int(toks[i]),
-                        None if lps is None else float(lps[i]))
+        with self._phase("ns_prefill_device"):
+            with self.profiler.step("prefill", (rb, mode, W)):
+                toks, lps, load, self.caches = fn(
+                    self.params, self.caches, chunks, bts, sps, tls,
+                    self._rng_base, np.int32(self._rng_ctr), temps, topks,
+                    *self._lora_args(lslots))
+            self._launched(
+                "prefill", (toks, lps, load), rows=rows, rb=rb, W=W,
+                ctx_pages=ctx_pages, sps=sps, tls=tls,
+                in_bytes=chunks.nbytes + bts.nbytes + sps.nbytes
+                + tls.nbytes + temps.nbytes + topks.nbytes + lslots.nbytes)
+        return True
+
+    def _book_prefill(self, toks, lps, load, *, rows, rb, W, ctx_pages,
+                      sps, tls, in_bytes):
+        """Book a prefill dispatch read back; the keywords are what its
+        launch kept of the host's state (_launch_prefill)."""
+        r, c, pg = len(rows), self.cfg.chunk_size, self.cfg.page_size
+        st = self.stats
+        st["prefill_dispatches"] += 1
+        st["prefill_rows_live"] += r
+        st["prefill_rows_padded"] += rb
+        st["prefill_tokens"] += int(tls.sum())
+        st["prefill_ctx_pages"] += sum(ctx_pages)
+        # causal (query, key) pairs: token q of a row attends the
+        # pos cached tokens and the row's first q + 1
+        st["prefill_attn_pairs"] += sum(
+            n * pos + n * (n + 1) // 2 for _, pos, n in rows)
+        steps, masked = live_key_steps(
+            sps[:r], tls[:r], c, W, page_size=pg,
+            **self.model.prefill_attn_step(
+                self.cfg.model, c, pg, W,
+                1 if self.mesh is None else self.mesh.shape.get("tp", 1)))
+        st["prefill_key_steps"] += steps
+        st["prefill_key_steps_masked"] += masked
+        self._moe_account(load, int(tls.sum()), rb * c)
+        self._mesh_account(
+            in_bytes,
+            toks.nbytes + sum(x.nbytes for x in (lps, load)
+                              if x is not None))
+        if self._prefix_on:
+            self._publish_prefilled(rows)
+        for i, (req, pos, n) in enumerate(rows):
+            if pos + n >= len(req.prompt_ids):
+                # prompt done: the row's in-jit sampled token is the
+                # first generated token
+                self._first_token(
+                    req, int(toks[i]),
+                    None if lps is None else float(lps[i]))
         # NOTE: pad positions of the final chunk were written into the
         # sequence's own pages beyond its true length; decode masks
         # positions >= length so they are never attended.
@@ -1488,7 +1598,10 @@ class PagedInferenceEngine:
         runs when every slot is greedy (the accept rule reproduces exact
         greedy; sampled rows fall back to the windowed path) and at least
         one slot has a draft. Returns False to fall through. Its phases
-        are the decode ones: the verify dispatch is this step's decode."""
+        are the decode ones: the verify dispatch is this step's decode.
+        Launched and read back here (the drafts are proposed from every
+        token so far), with nothing else outstanding: quiet, no prefill
+        went out this step, and step() has booked what the last left."""
         cfg = self.cfg
         s, page = cfg.spec_tokens, cfg.page_size
         with self._phase("ns_decode_build"):
@@ -1600,7 +1713,10 @@ class PagedInferenceEngine:
                 self._spec_cooldown_len = 8
         return True
 
-    def _decode_step(self):
+    def _launch_decode(self):
+        """Launch one decode dispatch over the decode set as the last
+        booking left it. A caller has booked every earlier decode: this
+        one feeds their last tokens."""
         if not self._active:
             return
         cfg = self.cfg
@@ -1625,8 +1741,6 @@ class PagedInferenceEngine:
             W = self._page_bucket(max(
                 (self._lengths[sl] + w - 1) // page + 1
                 for sl in self._active))
-            live_slots = len(self._active)
-            live_pages = self._live_pages(self._active)
             tokens = np.zeros((bs,), np.int32)
             lengths = np.zeros((bs,), np.int32)
             temps = np.zeros((bs,), np.float32)
@@ -1636,8 +1750,12 @@ class PagedInferenceEngine:
             # their dummy writes go to sink page 0 instead of a live
             # (possibly reused) page
             bt = np.zeros((bs, W), np.int32)
+            # the rows of THIS dispatch: a request that joins the decode
+            # set before it is booked (import_prefill, a prompt's first
+            # token) is none of them
+            reqs = dict(self._active)
             allow: dict[int, int] = {}      # valid tokens per slot
-            for slot, req in self._active.items():
+            for slot, req in reqs.items():
                 allow[slot] = self._reserve(req, w)
                 tokens[slot] = req.out_ids[-1]
                 lengths[slot] = self._lengths[slot]
@@ -1645,51 +1763,55 @@ class PagedInferenceEngine:
                 topks[slot] = req.params.top_k
                 bt[slot] = self._block_tables[slot][:W]
                 lslots[slot] = req.adapter_slot
-            mode = self._sampling_mode(self._active.values())
+            mode = self._sampling_mode(reqs.values())
             fn = self._decode_window_fn(w, mode, W)
-        with self._phase("ns_decode_device"), \
-                self.profiler.step("decode", (w, mode, W)):
-            out, lps, load, self.caches = fn(
-                self.params, self.caches, tokens, bt, lengths,
-                self._rng_base, np.int32(self._rng_ctr), temps, topks,
-                *self._lora_args(lslots))
-            self._notify_launch()
-            out = np.asarray(out)           # [bs, w]; block to measure
-            lps = None if lps is None else np.asarray(lps)
-            load = None if load is None else np.asarray(load)
-        with self._phase("ns_decode_post"):
-            self._rng_ctr += 1
-            st = self.stats
-            st["decode_dispatches"] += 1
-            st["decode_live_slots"] += live_slots
-            st["decode_live_pages"] += live_pages
-            st["decode_table_pages"] += bt.size
-            st["decode_steps"] += w
-            self._moe_account(load, live_slots * w, bs * w)
-            self._mesh_account(
-                tokens.nbytes + bt.nbytes + lengths.nbytes + temps.nbytes
-                + topks.nbytes + lslots.nbytes,
-                out.nbytes + sum(x.nbytes for x in (lps, load)
-                                 if x is not None))
-            for slot in list(self._active):
-                req = self._active[slot]
-                for j in range(w):
-                    if j >= allow[slot]:
-                        # page pool exhausted mid-window: finish early
-                        # rather than wedge (tokens past the allocation
-                        # wrote to the sink page and are not trustworthy)
-                        telemetry.on_preempted(self)
-                        self._retire(req)
-                        break
-                    tok = int(out[slot, j])
-                    req.out_ids.append(tok)
-                    if lps is not None:
-                        req.out_logps.append(float(lps[slot, j]))
-                    self._lengths[slot] += 1
-                    st["tokens_out"] += 1
-                    if self._stop_after(req, tok):
-                        self._retire(req)
-                        break
+        with self._phase("ns_decode_device"):
+            with self.profiler.step("decode", (w, mode, W)):
+                out, lps, load, self.caches = fn(
+                    self.params, self.caches, tokens, bt, lengths,
+                    self._rng_base, np.int32(self._rng_ctr), temps, topks,
+                    *self._lora_args(lslots))
+            self._launched(
+                "decode", (out, lps, load), reqs=reqs, allow=allow, w=w,
+                live_pages=self._live_pages(reqs), table_pages=bt.size,
+                in_bytes=tokens.nbytes + bt.nbytes + lengths.nbytes
+                + temps.nbytes + topks.nbytes + lslots.nbytes)
+
+    def _book_decode(self, out, lps, load, *, reqs, allow, w, live_pages,
+                     table_pages, in_bytes):
+        """Book a decode dispatch read back ([bs, w] tokens); the
+        keywords are what its launch kept of the host's state
+        (_launch_decode)."""
+        st = self.stats
+        st["decode_dispatches"] += 1
+        st["decode_live_slots"] += len(reqs)
+        st["decode_live_pages"] += live_pages
+        st["decode_table_pages"] += table_pages
+        st["decode_steps"] += w
+        self._moe_account(load, len(reqs) * w,
+                          self.cfg.max_batch_size * w)
+        self._mesh_account(
+            in_bytes,
+            out.nbytes + sum(x.nbytes for x in (lps, load)
+                             if x is not None))
+        for slot, req in reqs.items():
+            for j in range(w):
+                if j >= allow[slot]:
+                    # page pool exhausted mid-window: finish early
+                    # rather than wedge (tokens past the allocation
+                    # wrote to the sink page and are not trustworthy)
+                    telemetry.on_preempted(self)
+                    self._retire(req)
+                    break
+                tok = int(out[slot, j])
+                req.out_ids.append(tok)
+                if lps is not None:
+                    req.out_logps.append(float(lps[slot, j]))
+                self._lengths[slot] += 1
+                st["tokens_out"] += 1
+                if self._stop_after(req, tok):
+                    self._retire(req)
+                    break
 
     def _reserve(self, req: _Request, width: int) -> int:
         """Pre-allocate pages for up to `width` new tokens and return how
@@ -1773,6 +1895,7 @@ class PagedInferenceEngine:
         req.export_payload = None
         while req.export_payload is None and not req.done:
             self.step()
+        self._drain()
         if req.export_payload is None:
             raise RuntimeError("prefill finished without an export "
                                "(prompt rejected?)")

@@ -37,15 +37,18 @@ Metric names (all prefixed ``rtpu_llm_``):
   spec_proposed_total    counter    speculative tokens proposed
   spec_accepted_total    counter    speculative tokens accepted
   dispatches_total       counter    device dispatches, by program family
+  dispatches_overlapped_total counter  launches made while another
+      dispatch was outstanding (over dispatches_total: how often the
+      engine runs ahead of its readbacks)
   decode_live_slots_total counter   slots live, summed over decode
       dispatches (over dispatches_total{family="decode"} x max_batch_size:
       the share of the decode program's rows doing useful work)
   loop_seconds_total     counter    the stepping thread's seconds, by
       ``phase``: admit, prefill_build / _device / _post, decode_build /
       _device / _post, telemetry, loop_other, loop_idle (the ``ns_*`` keys
-      of engine.stats, paged_engine.PHASES); they sum to wall time, and
-      all but ``*_device`` and ``loop_idle`` is host time with no dispatch
-      outstanding
+      of engine.stats, paged_engine.PHASES); they sum to wall time;
+      ``*_device`` is launches and blocking readbacks, the rest but
+      ``loop_idle`` is host time, most of it beside a running dispatch
   prefix_cache_hits_total      counter  full prompt pages served from cache
   prefix_cache_misses_total    counter  full prompt pages computed by prefill
   prefix_cache_evictions_total counter  cached pages reclaimed under pressure
@@ -283,6 +286,8 @@ _STAT_COUNTERS = (
      "device dispatches by program family", ("family", "decode")),
     ("spec_dispatches", "rtpu_llm_dispatches_total",
      "device dispatches by program family", ("family", "verify")),
+    ("dispatches_overlapped", "rtpu_llm_dispatches_overlapped_total",
+     "launches made while another dispatch was outstanding", None),
     ("decode_live_slots", "rtpu_llm_decode_live_slots_total",
      "slots live, summed over decode dispatches", None),
     ("prefix_hits", "rtpu_llm_prefix_cache_hits_total",

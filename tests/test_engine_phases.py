@@ -56,19 +56,41 @@ def test_phase_adds_time_keeps_the_longest_and_never_swallows():
     assert set(stats) == {"ns_x", "max_ns_x"}
 
 
-def test_engine_phases_partition_the_step(engine):
+@pytest.fixture(scope="module", params=["llama", "latent"])
+def deep_engine(request):
+    """An engine whose step takes tens of milliseconds on a CPU, as it
+    does on a chip: eight layers at four (two) times the tiny width. At
+    the tiny models' 2.5 ms a step the interpreter's own ~50 us between
+    one phase's exit and the next one's entry are 2% of it."""
+    if request.param == "llama":
+        model = llama.llama_tiny(vocab_size=258, max_seq_len=128, dim=256,
+                                 n_layers=8, mlp_dim=1024)
+    else:
+        model = mla_moe.mla_moe_tiny(vocab_size=258, max_seq_len=128, dim=128,
+                                     n_layers=8, dense_mlp_dim=512,
+                                     mlp_dim=128)
+    eng = PagedInferenceEngine(_cfg(model=model), rng_seed=0)
+    eng.generate([list(range(1, 40)), list(range(3, 20))],
+                 SamplingParams(max_tokens=10))
+    return eng
+
+
+def test_engine_phases_partition_the_step(deep_engine):
     """Every nanosecond of step() falls in one of the eight engine
-    phases: their deltas sum to the wall time around the step() calls."""
+    phases, whichever dispatch a launch or a readback belongs to: their
+    deltas sum to the wall time around the step() calls."""
+    engine = deep_engine
     assert set(ENGINE_PHASES) <= set(engine.stats)
     assert all("max_" + k in engine.stats for k in PHASES)
     before = dict(engine.stats)
     reqs = [engine.submit(list(range(5 + i, 45 + 3 * i)),
                           SamplingParams(max_tokens=12)) for i in range(3)]
     wall = 0
-    while not all(r.done for r in reqs):
+    while engine.has_work():        # to the last readback
         t0 = time.perf_counter_ns()
         engine.step()
         wall += time.perf_counter_ns() - t0
+    assert all(r.done for r in reqs)
     d = _deltas(engine, before)
     for k in ENGINE_PHASES:
         assert d[k] > 0, k
@@ -238,14 +260,8 @@ def test_programs_carry_their_family_name():
     assert "module @jit_rtpu_verify_r4" in hlo
 
 
-def test_phases_are_spans_on_the_profilers_host_plane(engine, tmp_path,
-                                                      monkeypatch):
+def test_phases_are_spans_on_the_profilers_host_plane(engine, tmp_path):
     from benchmarks.reduce import xplane
-    # ``load`` keeps only the benchmark's own annotations; widening its
-    # pattern by ``rtpu\.`` is a ``benchmark`` issue's edit (PERF.md §7).
-    # With that one alternation the phases come through unchanged code.
-    monkeypatch.setattr(xplane, "HOST_SPANS", re.compile(
-        xplane.HOST_SPANS.pattern[:-1] + r"|rtpu\.)"))
     with jax.profiler.trace(str(tmp_path)):
         engine.generate([list(range(9, 60))], SamplingParams(max_tokens=10))
     planes = xplane.load(xplane.find_xplane(str(tmp_path)))
@@ -289,7 +305,7 @@ def _brute_key_steps(rows, window, table_pages, page, q_tile, block_keys):
     ("mla_moe", {}),                                           # kanana's
 ], ids=["gqa", "mha", "latent"])
 def test_key_step_counters_are_the_kernels_own_count(module, cfg):
-    """`live_key_steps` — what `_prefill_step` adds to
+    """`live_key_steps` — what `_book_prefill` adds to
     ``prefill_key_steps`` / ``prefill_key_steps_masked`` — over a seeded
     mix of (pos, n) rows at the widths of the three serving cells and
     every table bucket, against the brute-force count, with the tile and

@@ -21,11 +21,14 @@ TINY_MODELS = {
 
 @pytest.fixture(scope="module", params=list(TINY_MODELS))
 def engine(request):
-    cfg = PagedEngineConfig(
-        model=TINY_MODELS[request.param](max_seq_len=128),
-        max_batch_size=4, page_size=8, num_pages=64,
-        max_pages_per_seq=16, chunk_size=16)
-    return PagedInferenceEngine(cfg, rng_seed=0)
+    return _tiny_engine(request.param)
+
+
+def _tiny_engine(kind, **over):
+    kw = dict(model=TINY_MODELS[kind](max_seq_len=128), max_batch_size=4,
+              page_size=8, num_pages=64, max_pages_per_seq=16, chunk_size=16)
+    kw.update(over)
+    return PagedInferenceEngine(PagedEngineConfig(**kw), rng_seed=0)
 
 
 def test_paged_greedy_matches_full_forward(engine):
@@ -521,3 +524,157 @@ def test_pages_that_leave_the_pool_come_back_in_its_layout(path, kind):
         eng.run_until_done([req])
         got = eng._result(req)["token_ids"]
     assert got == want
+
+
+# -- launches run ahead of readbacks (step()) -------------------------------
+
+def _ids(n, seed):
+    return [int(t) for t in np.random.RandomState(seed).randint(1, 250, (n,))]
+
+
+@pytest.fixture(scope="module", params=list(TINY_MODELS))
+def solo(request):
+    """(model kind, an engine that is given one prompt at a time, with no
+    prefix cache: what every mixed run below has to reproduce)."""
+    return request.param, _tiny_engine(request.param,
+                                       enable_prefix_caching=False)
+
+
+@pytest.mark.parametrize("case", ["late_long_prompts", "prefix_burst",
+                                  "small_pool"])
+def test_run_ahead_tokens_equal_one_at_a_time(solo, case):
+    """A prefill launched beside the last decode, a decode launched beside
+    that prefill, a prefill beside a prefill: whatever was outstanding
+    when a dispatch went out, every request's greedy tokens are those of
+    the same prompt run alone."""
+    kind, alone = solo
+    sp = sp_first = SamplingParams(max_tokens=9)
+    if case == "late_long_prompts":
+        eng = _tiny_engine(kind)
+        sp_first = SamplingParams(max_tokens=24)    # still decoding then
+        first = [_ids(11, 1), _ids(20, 2)]
+        later = [_ids(100, 3), _ids(70, 4), _ids(90, 5)]
+    elif case == "prefix_burst":
+        eng = _tiny_engine(kind)
+        head = _ids(80, 6)
+        first, later = [head + _ids(3 + i, 7 + i) for i in range(4)], []
+    else:
+        # 16 allocatable pages: a 50-token prompt and its answer take 8,
+        # so two requests wait in _pending for pages a retirement frees
+        eng = _tiny_engine(kind, num_pages=17, max_pages_per_seq=8,
+                           enable_prefix_caching=False)
+        first, later = [_ids(50, 20 + i) for i in range(4)], []
+    eng.params = alone.params
+    reqs = [eng.submit(p, sp_first) for p in first]
+    if later:
+        while not eng._active:              # the first prompts decode
+            eng.step()
+        reqs += [eng.submit(p, sp) for p in later]
+    waited = False
+    while not all(r.done for r in reqs):
+        eng.step()
+        waited |= bool(eng._pending and eng._free_slots)
+        assert len(eng._inflight) <= 1      # one between two step() calls
+    eng._drain()
+    assert waited or case != "small_pool"   # for pages, with slots free
+    want = [alone.generate([p], r.params)[0]["token_ids"]
+            for p, r in zip(first + later, reqs)]
+    assert [r.out_ids for r in reqs] == want
+    st = eng.stats
+    assert 0 < st["dispatches_overlapped"] <= \
+        st["prefill_dispatches"] + st["decode_dispatches"]
+    if case == "prefix_burst":
+        # the followers mapped the leader's pages in and did not compute
+        # them again beside it: 80 tokens = 5 chunks each
+        assert st["prefix_tokens_saved"] == 3 * 80
+    assert not eng._inflight and not eng.has_work()
+    assert st["tokens_out"] == sum(r.params.max_tokens for r in reqs)
+
+
+def test_decode_after_decode_launches_nothing_ahead(engine):
+    """With nothing prefilling a decode launch needs the tokens of the
+    last one: no launch is made beside an outstanding dispatch."""
+    reqs = [engine.submit(_ids(9 + i, 30 + i), SamplingParams(max_tokens=20))
+            for i in range(3)]
+    while engine._prefilling or engine._pending or not engine._active:
+        engine.step()
+    before = dict(engine.stats)
+    engine.run_until_done(reqs)
+    assert engine.stats["decode_dispatches"] > before["decode_dispatches"]
+    assert engine.stats["prefill_dispatches"] == before["prefill_dispatches"]
+    assert engine.stats["dispatches_overlapped"] == \
+        before["dispatches_overlapped"]
+
+
+def test_blocking_calls_leave_nothing_outstanding(engine):
+    """run_until_done returns with every dispatch read back, also when the
+    requests it waited for are done and others are not; has_work() is
+    true while a dispatch is outstanding."""
+    sp = SamplingParams
+    short = engine.submit(_ids(12, 40), sp(max_tokens=2))
+    long = engine.submit(_ids(90, 41), sp(max_tokens=30))
+    engine.step()
+    assert engine._inflight and engine.has_work()
+    engine.run_until_done([short])
+    assert short.done and not long.done
+    assert not engine._inflight and engine.has_work()
+    n = len(long.out_ids)
+    engine.step()                       # launches; books nothing new
+    assert len(engine._inflight) == 1 and len(long.out_ids) == n
+    assert engine.generate([_ids(7, 42)], sp(max_tokens=3))[0]["token_ids"]
+    engine.run_until_done([long])
+    assert not engine._inflight and not engine.has_work()
+    assert len(long.out_ids) == 30
+
+
+@pytest.mark.parametrize("another_waits", [True, False])
+def test_a_finished_prompt_joins_decode_one_dispatch_later(engine,
+                                                           another_waits):
+    """The decode dispatch launched beside the prefill that ends a prompt
+    cannot carry that prompt: its second token comes from the decode
+    dispatch after the next one to be read back. When no other prompt
+    waits, the decode (a full window) follows that prefill's booking and
+    carries it."""
+    sp = SamplingParams
+    old = engine.submit(_ids(10, 50), sp(max_tokens=60))
+    while not old.out_ids:
+        engine.step()
+    new = engine.submit(_ids(30, 51), sp(max_tokens=4))     # two rows
+    other = engine.submit(_ids(100, 52), sp(max_tokens=2)) \
+        if another_waits else None      # two more rows, and five to go
+    while not new.out_ids:
+        engine.step()
+    # booked so far: every decode but the one left outstanding
+    first_at = engine.stats["decode_dispatches"]
+    (out,) = engine._inflight
+    assert out.family == "decode"
+    assert (new in out.host["reqs"].values()) == (not another_waits)
+    assert out.host["w"] == (1 if another_waits else engine.cfg.decode_window)
+    while len(new.out_ids) < 2:
+        engine.step()
+    assert engine.stats["decode_dispatches"] == first_at + 1 + another_waits
+    engine.run_until_done([r for r in (old, new, other) if r])
+
+
+def test_import_between_steps_waits_for_the_outstanding_decode(solo):
+    """What a decode replica does under its step lock (pd_disagg.py
+    `start`): a prefill imported while a decode dispatch is outstanding
+    is no row of that dispatch, and decodes from the next launch on."""
+    kind, pre = solo
+    sp = SamplingParams(max_tokens=12)
+    eng = _tiny_engine(kind)
+    eng.params = pre.params
+    a, b = _ids(30, 60), _ids(45, 61)
+    first = eng.submit(a, sp)
+    while len(first.out_ids) < 2:
+        eng.step()
+    (out,) = eng._inflight
+    assert out.family == "decode"
+    second = eng.import_prefill(pre.prefill_export(b, sp), sp)
+    assert second.slot not in out.host["reqs"]
+    eng.step()                  # books it: only the first request's tokens
+    assert len(second.out_ids) == 1
+    eng.run_until_done([first, second])
+    want = [pre.generate([p], sp)[0]["token_ids"] for p in (a, b)]
+    assert [first.out_ids, second.out_ids] == want
+    assert not pre._inflight and not pre.has_work()     # prefill_export's
