@@ -1,0 +1,16 @@
+"""Engine scheduler (llm/paged_engine.py ``step()`` / ``_launched``): the
+share of dispatches launched while another was still outstanding — how
+often the engine runs ahead of its readbacks (PR 36). A decode that follows
+a decode cannot (its input is the last one's tokens), a prefill beside a
+decode can. Counters ``dispatches_overlapped`` / (``prefill_dispatches`` +
+``decode_dispatches`` + ``spec_dispatches``) over the window."""
+from ._engine import deltas
+
+
+def read(ctx: dict):
+    d = deltas(ctx)
+    launches = sum(d.get(f"{f}_dispatches", 0)
+                   for f in ("prefill", "decode", "spec"))
+    if "dispatches_overlapped" not in d or not launches:
+        return None
+    return 100.0 * d["dispatches_overlapped"] / launches

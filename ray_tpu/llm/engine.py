@@ -64,6 +64,13 @@ class _Request:
     done: bool = False
     submit_t: float = 0.0
     first_token_t: float = 0.0    # TTFT = first_token_t - submit_t
+    # perf_counter_ns stamps of a token's way out (paged engine +
+    # llm/serving.py completions_stream): the booking that put the
+    # newest token and the first on the host, and the instant the
+    # transport had taken the chunk that carried the first
+    token_ns: int = 0
+    first_token_ns: int = 0
+    first_chunk_ns: int = 0
     # telemetry (llm/telemetry.py): admission time, wall-clock submit
     # (spans use wall time), serve request id, and the submitter's trace
     # context so the engine thread can emit an llm.request span

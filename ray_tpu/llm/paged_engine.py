@@ -34,11 +34,14 @@ one runs beside the program of the other.
 
 Where the stepping thread's time goes is counted in ``stats["ns_*"]``
 (PHASES below) and, under a profiler session, drawn as ``rtpu.engine.*``
-spans on the device trace's clock. A ``*_device`` phase is a launch or
-the blocking part of a readback; the other phases mostly run while a
-dispatch is outstanding, so their share of the thread's time (the
-benchmark's ``engine_host_share``) is what the host costs a step, not
-what the device waits for (PERF.md §3).
+spans on the device trace's clock. ``ns_<family>_device`` holds two
+kinds of span: a launch (``rtpu.engine.<family>.launch``: the jitted
+call, which waits for the interpreter alone and is also counted in
+``launch_ns_<family>``) and the blocking part of a readback
+(``rtpu.engine.<family>.wait``: the device's answer). The other phases
+mostly run while a dispatch is outstanding, so their share of the
+thread's time (the benchmark's ``engine_host_share``) is what the host
+costs a step, not what the device waits for (PERF.md §3).
 """
 from __future__ import annotations
 
@@ -206,19 +209,30 @@ class PagedEngineConfig:
 # (util/profiling.phase). The rtpu.engine.* phases partition step();
 # the rtpu.loop.* phases are LLMServer._loop's time outside step():
 # idle is the wait for work alone, other is everything else. The ten
-# sum to the thread's wall time.
+# sum to the thread's wall time. A *_device key is worn by two spans:
+# the blocking readback named here and the launch of LAUNCHES.
 PHASES = {
     "ns_admit": "rtpu.engine.admit",
     "ns_prefill_build": "rtpu.engine.prefill.build",
-    "ns_prefill_device": "rtpu.engine.prefill.device",
+    "ns_prefill_device": "rtpu.engine.prefill.wait",
     "ns_prefill_post": "rtpu.engine.prefill.post",
     "ns_decode_build": "rtpu.engine.decode.build",
-    "ns_decode_device": "rtpu.engine.decode.device",
+    "ns_decode_device": "rtpu.engine.decode.wait",
     "ns_decode_post": "rtpu.engine.decode.post",
     "ns_telemetry": "rtpu.engine.telemetry",
     "ns_loop_other": "rtpu.loop.other",
     "ns_loop_idle": "rtpu.loop.idle",
 }
+# A launch (the jitted call up to the streams' wake-up), by family: its
+# span, and the counter it grows beside ns_<family>_device.
+LAUNCHES = {
+    family: (f"rtpu.engine.{family}.launch", f"launch_ns_{family}")
+    for family in ("prefill", "decode")}
+# What the stream threads count of a token's way out of the replica
+# (llm/serving.py _StreamMeter); the stepping thread only stamps the
+# bookings (_Request.token_ns).
+STREAM_COUNTERS = ("stream_chunks", "stream_lag_ns", "stream_first_chunks",
+                   "stream_first_lag_ns", "stream_cpu_ns")
 
 
 @dataclasses.dataclass
@@ -434,6 +448,12 @@ class PagedInferenceEngine:
         # sum is the thread's wall time.
         for key in PHASES:
             self.stats[key] = self.stats["max_" + key] = 0
+        for _, also in LAUNCHES.values():
+            self.stats[also] = 0
+        self.stats.update(dict.fromkeys(STREAM_COUNTERS, 0))
+        # perf_counter_ns of the booking in progress: the stamp of the
+        # tokens it puts on the host (_Request.token_ns)
+        self._book_ns = 0
         # an MoE config's expert layer computes every row and token a
         # program runs, live or not (_moe_account); a dense config has no
         # such keys
@@ -1240,6 +1260,11 @@ class PagedInferenceEngine:
     def _phase(self, key: str):
         return phase(self.stats, key, PHASES[key])
 
+    def _launch(self, family: str):
+        """The ``ns_<family>_device`` phase of a launch."""
+        name, also = LAUNCHES[family]
+        return phase(self.stats, f"ns_{family}_device", name, also)
+
     def step(self):
         """One iteration: admit, launch one prefill dispatch (bounded),
         launch one decode dispatch, and read back whatever an earlier
@@ -1263,7 +1288,8 @@ class PagedInferenceEngine:
         freed on the host can only be written by a program launched
         later. Everything falls in one of the eight rtpu.engine.* phases
         (PHASES): admit, {prefill, decode} x {build, device, post},
-        telemetry; ``device`` is a launch or a blocking readback."""
+        telemetry; ``device`` is two spans, a ``.launch`` (LAUNCHES) or
+        the ``.wait`` of a blocking readback."""
         with self._phase("ns_admit"):
             self._admit()
         # the mesh scope pins trace-time constrain() resolution for any
@@ -1293,6 +1319,7 @@ class PagedInferenceEngine:
                 # that raises leaves the dispatch outstanding
                 got = [None if x is None else np.asarray(x) for x in d.outs]
             with self._phase(f"ns_{d.family}_post"):
+                self._book_ns = time.perf_counter_ns()
                 book(*got, **d.host)
                 # the device's arrays and their host copies go inside
                 # the phase: the phases leave nothing of step() out
@@ -1433,7 +1460,7 @@ class PagedInferenceEngine:
                 req.prefill_pos = pos + n
             mode = self._sampling_mode([q for q, _, _ in rows])
             fn = self._prefill_rows_fn(rb, mode, W)
-        with self._phase("ns_prefill_device"):
+        with self._launch("prefill"):
             with self.profiler.step("prefill", (rb, mode, W)):
                 toks, lps, load, self.caches = fn(
                     self.params, self.caches, chunks, bts, sps, tls,
@@ -1506,6 +1533,7 @@ class PagedInferenceEngine:
         """A prompt's last chunk returned: book its first generated
         token and move the request into the decode set (or export it,
         on a disaggregated prefill replica)."""
+        req.first_token_ns = req.token_ns = self._book_ns
         req.out_ids.append(tok)
         if lp is not None:
             req.out_logps.append(lp)
@@ -1649,16 +1677,18 @@ class PagedInferenceEngine:
                 lslots[i] = req.adapter_slot
             want_lp = any(self._active[sl].params.logprobs for sl in slots)
             fn = self._verify_fn(rb, s1, W, want_lp)
-        with self._phase("ns_decode_device"), \
-                self.profiler.step("verify", (rb, s1, W, want_lp)):
-            y, ylp, load, self.caches = fn(
-                self.params, self.caches, toks, bts, starts,
-                *self._lora_args(lslots))
+        with self._launch("decode"):
+            with self.profiler.step("verify", (rb, s1, W, want_lp)):
+                y, ylp, load, self.caches = fn(
+                    self.params, self.caches, toks, bts, starts,
+                    *self._lora_args(lslots))
             self._notify_launch()
+        with self._phase("ns_decode_device"):
             y = np.asarray(y)               # [r, s1]; block: measure
             ylp = None if ylp is None else np.asarray(ylp)
             load = None if load is None else np.asarray(load)
         with self._phase("ns_decode_post"):
+            self._book_ns = time.perf_counter_ns()
             self.stats["spec_dispatches"] += 1
             self._moe_account(load, r * s1, rb * s1)
             self._mesh_account(
@@ -1682,6 +1712,7 @@ class PagedInferenceEngine:
                     out.append((int(y[i, j + 1]), _lp(i, j + 1)))
                     self.stats["spec_accepted"] += 1
                 consumed = 0
+                req.token_ns = self._book_ns
                 for tok, lp in out:
                     if consumed >= allow[slot]:
                         telemetry.on_preempted(self)
@@ -1765,7 +1796,7 @@ class PagedInferenceEngine:
                 lslots[slot] = req.adapter_slot
             mode = self._sampling_mode(reqs.values())
             fn = self._decode_window_fn(w, mode, W)
-        with self._phase("ns_decode_device"):
+        with self._launch("decode"):
             with self.profiler.step("decode", (w, mode, W)):
                 out, lps, load, self.caches = fn(
                     self.params, self.caches, tokens, bt, lengths,
@@ -1795,6 +1826,9 @@ class PagedInferenceEngine:
             out.nbytes + sum(x.nbytes for x in (lps, load)
                              if x is not None))
         for slot, req in reqs.items():
+            # stamped before the tokens are appended: a stream that
+            # sees a token finds a stamp no older than its booking
+            req.token_ns = self._book_ns
             for j in range(w):
                 if j >= allow[slot]:
                     # page pool exhausted mid-window: finish early
@@ -1979,10 +2013,11 @@ class PagedInferenceEngine:
                             self._register_page(pages[i], hashes[i],
                                                 chain=req.chain_slot)
             tok = int(payload["first_token"])
+            req.first_token_t = time.perf_counter()
+            req.first_token_ns = req.token_ns = int(req.first_token_t * 1e9)
             req.out_ids.append(tok)
             self.stats["tokens_out"] += 1
             req.prefill_pos = len(ids)
-            req.first_token_t = time.perf_counter()
             self._lengths[req.slot] = len(ids)
             self._active[req.slot] = req
             self._maybe_finish(req, tok)

@@ -56,6 +56,61 @@ class LLMConfig:
     warmup_sampled: bool = False
 
 
+class _StreamMeter:
+    """One stream's way out of the replica, counted chunk by chunk into
+    ``engine.stats`` on the stream's own thread (``completions_stream``):
+
+    - ``stream_chunks``: chunks that carried text and were taken by the
+      transport — the generator was resumed after their ``yield`` (the
+      replica's drain thread has written its ring, a ``stream_next``
+      reply has gone);
+    - ``stream_lag_ns``: from the booking of a chunk's newest token
+      (``_Request.token_ns``, the stepping thread's stamp) to that
+      resumption: the wait for the next launch's wake-up, the thread's
+      turn at the interpreter, the detokenisation and the transport's
+      write together — what a chunk's delivery takes inside the replica;
+    - ``stream_first_chunks`` / ``stream_first_lag_ns``: the same for
+      the chunk that carries a request's first token, from that token's
+      booking: the part of a client's TTFT between the engine's
+      (``rtpu_llm_ttft_seconds``) and the proxy;
+    - ``stream_cpu_ns``: the thread's CPU from one chunk's resumption to
+      the next, the empty wake-ups between, the detokenisation and the
+      transport's write included — what the stream costs the one
+      interpreter."""
+
+    __slots__ = ("_stats", "_lock", "_req", "_first", "_cpu", "_thread")
+
+    def __init__(self, stats: dict, lock, req):
+        self._stats, self._lock, self._req = stats, lock, req
+        self._first = True
+        # a thread's CPU clock says nothing of another's, and a
+        # stream_next reply may resume the generator on another thread
+        # of the actor's pool: the clock is kept with its thread
+        self._cpu, self._thread = (_time.thread_time_ns(),
+                                   threading.get_ident())
+
+    def taken(self, booked: int) -> None:
+        """The transport has taken a chunk whose newest token was booked
+        at ``booked`` (``token_ns`` as read when its tokens were seen: a
+        token is stamped before it is appended, paged_engine._book_decode,
+        so the stamp is no older than the booking of any of them)."""
+        now = _time.perf_counter_ns()
+        cpu, thread = _time.thread_time_ns(), threading.get_ident()
+        spent = cpu - self._cpu if thread == self._thread else 0
+        self._cpu, self._thread = cpu, thread
+        req, st = self._req, self._stats
+        with self._lock:
+            st["stream_chunks"] += 1
+            st["stream_lag_ns"] += now - booked
+            st["stream_cpu_ns"] += spent
+            if self._first:
+                st["stream_first_chunks"] += 1
+                st["stream_first_lag_ns"] += now - req.first_token_ns
+        if self._first:
+            self._first = False
+            req.first_chunk_ns = now
+
+
 class LLMServer:
     """Deployment callable: background engine thread + request futures
     (reference: llm_server.py:409)."""
@@ -83,6 +138,11 @@ class LLMServer:
         # concurrent scatter/gather would read deleted buffers — same
         # contract as pd_disagg's _steplock around import_prefill)
         self._steplock = threading.Lock()
+        # the stream threads' adds to engine.stats (_StreamMeter): a
+        # read-modify-write a chunk, which two of them must not
+        # interleave. The stepping thread never writes those keys and
+        # never takes this lock.
+        self._stream_lock = threading.Lock()
         # cluster prefix directory (serve/frontdoor/prefix.py). The
         # controller injects this replica's own handle via
         # set_replica_handle; publishing starts then.
@@ -103,6 +163,10 @@ class LLMServer:
                 AdapterRegistry(cfg.lora_namespace or cfg.model_id))
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
+        # the stepping thread's CPU clock, which engine_stats() reads
+        # from outside: the thread itself never pays for a reading
+        self._step_cpu_clock = _time.pthread_getcpuclockid(
+            self._thread.ident)
 
     def _build_engine(self, params):
         eng = PagedInferenceEngine(self.engine_cfg, params)
@@ -284,8 +348,15 @@ class LLMServer:
     def completions_stream(self, request: dict):
         """Generator of token-delta dicts while the engine decodes
         (reference: the streaming response path of llm_server.py; pairs
-        with handle.options(stream=True) / the SSE proxy path)."""
+        with handle.options(stream=True) / the SSE proxy path).
+
+        What a token's way out of the replica costs is counted here,
+        chunk by chunk, into ``engine.stats`` (``_StreamMeter``): a
+        chunk is counted once the transport (the replica's drain thread
+        writing its ring, or a ``stream_next`` reply) has taken it and
+        resumes the generator."""
         eng, req = self.engine, self._submit(request)
+        meter = _StreamMeter(eng.stats, self._stream_lock, req)
         sent = 0
         last_text = ""
         # the engine says when it has launched a dispatch: sleep on
@@ -298,6 +369,7 @@ class LLMServer:
             gen = eng.launch_gen
             n = len(req.out_ids)
             if n > sent:
+                booked = req.token_ns
                 text = eng.tokenizer.decode(list(req.out_ids))
                 delta, last_text = text[len(last_text):], text
                 sent = n
@@ -306,6 +378,7 @@ class LLMServer:
                            "model": self.model_id,
                            "choices": [{"text": delta, "index": 0,
                                         "finish_reason": None}]}
+                    meter.taken(booked)
             if req.done:
                 break
             with launched:
@@ -313,11 +386,16 @@ class LLMServer:
                 # (the engine went idle, or its loop died)
                 if eng.launch_gen == gen and not req.done:
                     launched.wait(timeout=0.05)
+        booked = req.token_ns
         out = eng._result(req)
         tail = out["text"][len(last_text):]
         yield {"object": "text_completion.chunk", "model": self.model_id,
                "choices": [{"text": tail, "index": 0,
                             "finish_reason": out["finish_reason"]}]}
+        # the closing chunk is empty unless the last tokens were booked
+        # between this thread's look and the retirement
+        if tail:
+            meter.taken(booked)
 
     def set_replica_handle(self, handle) -> None:
         """Controller-injected handle to THIS replica's actor: the value
@@ -340,6 +418,12 @@ class LLMServer:
         """Counter snapshot for ops introspection: the engine's
         stats dict (the stepping thread's ``ns_*`` phase times among
         them) plus the resolved mesh axis sizes (None single-chip).
+        ``step_thread_cpu_ns`` is the stepping thread's CPU time so
+        far: between two snapshots, its host phases' and launches' wall
+        time (``ns_*`` but ``*_device`` and ``ns_loop_idle``, plus
+        ``launch_ns_*``) less this is the time the thread stood runnable
+        and did not run — the GIL, a lock, the kernel — give or take
+        the little CPU a readback's wait takes.
         On a mesh, ``mesh_reshard_bytes`` staying 0 IS the steady-state
         zero-involuntary-reshard invariant — a nonzero value means some
         dispatch committed a buffer off its pinned sharding."""
@@ -350,6 +434,11 @@ class LLMServer:
         # the ns_* counters' clock at this snapshot: between two
         # snapshots their deltas sum to this one's
         st["clock_ns"] = _time.perf_counter_ns()
+        try:
+            st["step_thread_cpu_ns"] = _time.clock_gettime_ns(
+                self._step_cpu_clock)
+        except OSError:
+            pass    # the loop's thread has ended: no clock to read
         mesh = self.engine.mesh
         st["mesh"] = None if mesh is None else {
             k: int(v) for k, v in mesh.shape.items()}

@@ -19,7 +19,12 @@ replica's task span when the request came through Serve), so a proxy
 ``request_id``, three children end to end: ``llm.queue`` (submit ->
 admit), ``llm.prefill`` (admit -> first token) and ``llm.decode`` (first
 token -> retire), with ``prompt_tokens``, ``prefix_tokens_saved`` and
-``out_tokens`` as arguments.
+``out_tokens`` as arguments. A streamed request has a fourth,
+``llm.deliver``, beside ``llm.decode``: first token booked -> the
+transport has taken the chunk that carries it (serving's
+``_StreamMeter``). The spans are emitted when the request retires, so
+a request that retires before its first chunk was taken (a short
+answer, a slow transport) has no ``llm.deliver``.
 
 Metric names (all prefixed ``rtpu_llm_``):
   ttft_seconds           histogram  submit -> first generated token
@@ -47,8 +52,23 @@ Metric names (all prefixed ``rtpu_llm_``):
       ``phase``: admit, prefill_build / _device / _post, decode_build /
       _device / _post, telemetry, loop_other, loop_idle (the ``ns_*`` keys
       of engine.stats, paged_engine.PHASES); they sum to wall time;
-      ``*_device`` is launches and blocking readbacks, the rest but
-      ``loop_idle`` is host time, most of it beside a running dispatch
+      ``*_device`` is a launch OR a blocking readback wait (the launches'
+      part is launch_seconds_total of the family, the rest is the wait
+      for the device's answer), the others but ``loop_idle`` are host
+      time, most of it beside a running dispatch
+  launch_seconds_total   counter    seconds inside the jitted calls that
+      launch a program, by ``family`` (prefill, decode; a verify dispatch
+      is decode's): over dispatches_total, what a launch takes the
+      thread — milliseconds when it has the interpreter, tens of them
+      among awake stream threads
+  stream_chunks_total    counter    text chunks the streams handed to
+      their transport (serving's completions_stream)
+  stream_lag_seconds_total counter  booking of a chunk's newest token ->
+      the transport has taken the chunk, summed; over
+      stream_chunks_total: a chunk's delivery time inside the replica
+  stream_cpu_seconds_total counter  CPU seconds of the stream threads,
+      detokenisation and the transport's write included; its rate is the
+      share of one core, so of the one interpreter, the streams take
   prefix_cache_hits_total      counter  full prompt pages served from cache
   prefix_cache_misses_total    counter  full prompt pages computed by prefill
   prefix_cache_evictions_total counter  cached pages reclaimed under pressure
@@ -290,6 +310,8 @@ _STAT_COUNTERS = (
      "launches made while another dispatch was outstanding", None),
     ("decode_live_slots", "rtpu_llm_decode_live_slots_total",
      "slots live, summed over decode dispatches", None),
+    ("stream_chunks", "rtpu_llm_stream_chunks_total",
+     "text chunks the streams handed to their transport", None),
     ("prefix_hits", "rtpu_llm_prefix_cache_hits_total",
      "full prompt pages served from the prefix cache", None),
     ("prefix_misses", "rtpu_llm_prefix_cache_misses_total",
@@ -336,12 +358,30 @@ _STAT_COUNTERS = (
 )
 
 
+# engine.stats keys in nanoseconds, shipped as seconds
+_STAT_SECONDS = (
+    ("launch_ns_prefill", "rtpu_llm_launch_seconds_total",
+     "the engine loop thread's seconds inside launches, by family",
+     ("family", "prefill")),
+    ("launch_ns_decode", "rtpu_llm_launch_seconds_total",
+     "the engine loop thread's seconds inside launches, by family",
+     ("family", "decode")),
+    ("stream_lag_ns", "rtpu_llm_stream_lag_seconds_total",
+     "a chunk's newest token booked -> taken by the transport, summed",
+     None),
+    ("stream_cpu_ns", "rtpu_llm_stream_cpu_seconds_total",
+     "CPU seconds of the stream threads", None),
+)
+
+
 def _ship_stat_deltas(engine, stats: dict, tags: dict) -> None:
     last = getattr(engine, "_telem_shipped", None)
     if last is None:
         last = engine._telem_shipped = {}
     for key, name, desc, label in _STAT_COUNTERS:
         _ship_delta(stats, last, key, name, desc, label, tags)
+    for key, name, desc, label in _STAT_SECONDS:
+        _ship_delta(stats, last, key, name, desc, label, tags, scale=1e-9)
     # the stepping thread's time by phase (paged_engine.PHASES): every
     # ns_<phase> key, in seconds, under one family
     for key in stats:
@@ -489,9 +529,15 @@ def _emit_request_span(req) -> None:
         args = {"prompt_tokens": len(req.prompt_ids),
                 "prefix_tokens_saved": req.prefix_tokens_saved,
                 "out_tokens": len(req.out_ids)}
-        for name, t0, t1 in (("llm.queue", 0.0, admit),
-                             ("llm.prefill", admit, first),
-                             ("llm.decode", first, total)):
+        kids = [("llm.queue", 0.0, admit), ("llm.prefill", admit, first),
+                ("llm.decode", first, total)]
+        if req.first_chunk_ns:
+            # a streamed request: first token booked -> its chunk taken
+            # by the transport (the stamps are perf_counter_ns, the
+            # engine's clock like submit_t)
+            kids.append(("llm.deliver", first, min(max(
+                req.first_chunk_ns * 1e-9 - req.submit_t, first), total)))
+        for name, t0, t1 in kids:
             tracing.record_span({
                 **rec, "span_id": tracing.new_span_id(),
                 "parent_id": rec["span_id"], "name": name,

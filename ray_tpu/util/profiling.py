@@ -23,12 +23,20 @@ whose methodology ships with the system). Three pieces:
   timed with (llm/paged_engine.py ``step()``, llm/serving.py ``_loop``):
   a ``jax.profiler.TraceAnnotation`` (a span on the host plane of the
   profiler's trace, on the device operations' clock, when a profiler
-  session is active; a branch when none is) plus the elapsed
-  nanoseconds added to a counter. Always on: no flag, no config field.
+  session is active; an object built and dropped when none is) plus
+  the elapsed nanoseconds added to a counter. Always on: no flag, no
+  config field.
 
-Profilers are cheap enough to leave attached (two perf_counter reads and
-two flight events per step); FLOPs estimation triggers an extra XLA
-compile, so it runs only when explicitly requested.
+Both are cheap enough to leave attached. A ``StepProfiler.step`` is two
+perf_counter reads and two flight events around a jitted call; a
+``phase`` is two reads of the wall clock (a vDSO read, 0.1 us), one
+annotation and two or three dict updates, some twelve times a
+``step()`` of the engine. Neither reads the thread's CPU clock: that is
+a system call (0.3 us on a plain kernel, 6 us under gVisor, where the
+chip's machines run), and who wants a thread's CPU time reads it from
+outside the thread (llm/serving.py ``engine_stats``). FLOPs estimation
+triggers an extra XLA compile, so it runs only when explicitly
+requested.
 """
 from __future__ import annotations
 
@@ -150,13 +158,6 @@ class StepProfiler:
         self.compiles += 1
         self._seen.add((kind, key))
 
-    def executed_tags(self) -> list:
-        """(kind, key) tags with at least one EXECUTED step (compiles
-        excluded) — what a length-aware FLOPs estimator should cost:
-        estimating only dispatched shapes keeps the out-of-band compile
-        count at the number of programs actually used."""
-        return sorted(self._steps_by_tag, key=repr)
-
     def attach_flops(self, kind: str, flops: Optional[float],
                      key: Any = None) -> None:
         """Record a FLOPs-per-step estimate for steps of ``(kind, key)``.
@@ -215,21 +216,26 @@ class phase:
     - a ``jax.profiler.TraceAnnotation(name)``: with a profiler session
       active the span lands on the host plane of the same xplane as the
       device operations, on their clock, so an idle gap on the device
-      can be named by the phase the host was in; with none active it
-      costs a branch;
+      can be named by the phase the host was in; with none active the
+      object is built and dropped;
     - ``stats[key]`` grows by the elapsed ``perf_counter_ns``, and
       ``stats["max_" + key]`` keeps the longest single occurrence (a
       stall names its phase; the ``max_`` prefix keeps a sum over every
-      ``ns_*`` key from adding a maximum).
+      ``ns_*`` key from adding a maximum);
+    - ``stats[also]``, where given, grows by the same amount: one
+      occurrence counted under a second heading (the engine's launches,
+      which share ``ns_<family>_device`` with the readback waits).
 
     The clock is read first on entry and last on exit, so consecutive
     phases leave only the interpreter's own call overhead between them.
     jax is imported on first use (``import ray_tpu`` stays light)."""
 
-    __slots__ = ("_stats", "_key", "_name", "_ann", "_t0")
+    __slots__ = ("_stats", "_key", "_name", "_also", "_ann", "_t0")
 
-    def __init__(self, stats: dict, key: str, name: str):
+    def __init__(self, stats: dict, key: str, name: str,
+                 also: Optional[str] = None):
         self._stats, self._key, self._name = stats, key, name
+        self._also = also
 
     def __enter__(self):
         global _annotation
@@ -248,4 +254,6 @@ class phase:
         stats[key] = stats.get(key, 0) + dt
         if dt > stats.get("max_" + key, 0):
             stats["max_" + key] = dt
+        if self._also is not None:
+            stats[self._also] = stats.get(self._also, 0) + dt
         return False

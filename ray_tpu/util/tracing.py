@@ -9,9 +9,7 @@ captures the submitter's current span as parent, the executing worker opens
 a child span around the function body, and completed spans flow back on the
 done message into the head's chrome-trace timeline (ray_tpu.timeline()),
 where trace_id/span_id/parent_id args let tools stitch cross-process
-flows. W3C-sized ids (128-bit trace, 64-bit span). If the opentelemetry
-SDK is importable, spans are additionally forwarded to its tracer; the
-image does not ship it, so that path is soft-gated.
+flows. W3C-sized ids (128-bit trace, 64-bit span).
 
 Enable with cfg.override(tracing_enabled=True) (or RTPU_TRACING_ENABLED=1)
 before ray_tpu.init() — driver overrides propagate to workers.
@@ -75,7 +73,6 @@ def activate(trace_ctx: tuple, name: str):
     finally:
         _current.reset(token)
         rec["dur_s"] = time.time() - rec["start_s"]
-        _export_otel(rec)
 
 
 @contextlib.contextmanager
@@ -105,7 +102,6 @@ def span(name: str, root: bool = False):
         _current.reset(token)
         rec["dur_s"] = time.time() - rec["start_s"]
         record_span(rec)
-        _export_otel(rec)
 
 
 def record_span(rec: dict) -> None:
@@ -122,23 +118,3 @@ def record_span(rec: dict) -> None:
             rt.send({"t": "trace_span", "span": rec})
         except Exception:
             pass  # conn gone; span loss is acceptable
-
-
-def _export_otel(rec: dict) -> None:
-    """Forward to the OpenTelemetry SDK when it's installed (the
-    reference's default exporter path); silently absent otherwise."""
-    try:
-        from opentelemetry import trace as _ot  # noqa: F401
-    except Exception:
-        return  # SDK absent: soft-gated exporter
-    try:
-        tracer = _ot.get_tracer("ray_tpu")
-        sp = tracer.start_span(rec["name"],
-                               start_time=int(rec["start_s"] * 1e9))
-        sp.set_attribute("rtpu.trace_id", rec["trace_id"])
-        sp.set_attribute("rtpu.span_id", rec["span_id"])
-        if rec.get("parent_id"):
-            sp.set_attribute("rtpu.parent_id", rec["parent_id"])
-        sp.end(end_time=int((rec["start_s"] + rec["dur_s"]) * 1e9))
-    except Exception:
-        pass  # exporter must never break traced code

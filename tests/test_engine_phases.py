@@ -1,9 +1,14 @@
 """The serving loop's phase times and dispatch counters
 (llm/paged_engine.py PHASES / stats, util/profiling.phase): the phases
-partition the stepping thread's time, the counters count what the
-dispatch decided, the programs carry their family's name, and under a
-profiler session the phases are spans on the trace's host plane."""
+partition the stepping thread's wall time (its CPU time is read from
+outside it), a launch is told from a readback's wait, the counters count what
+the dispatch decided, the programs carry their family's name, under a
+profiler session the phases are spans on the trace's host plane, and the
+stream threads count a token's way from its booking to the transport
+(llm/serving.py _StreamMeter)."""
 import re
+import sys
+import threading
 import time
 
 import jax
@@ -11,7 +16,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm import SamplingParams
-from ray_tpu.llm.paged_engine import (PHASES, PagedEngineConfig,
+from ray_tpu.llm.paged_engine import (LAUNCHES, PHASES, STREAM_COUNTERS,
+                                      PagedEngineConfig,
                                       PagedInferenceEngine)
 from ray_tpu.models import llama, mla_moe
 
@@ -54,6 +60,20 @@ def test_phase_adds_time_keeps_the_longest_and_never_swallows():
         with phase(stats, "ns_x", "rtpu.test.x"):
             raise KeyError("through")
     assert set(stats) == {"ns_x", "max_ns_x"}
+
+
+def test_phase_counts_an_occurrence_under_a_second_heading():
+    """``also`` grows by what the phase's own key grows by, occurrence
+    for occurrence, and has no maximum of its own."""
+    from ray_tpu.util.profiling import phase
+    stats = {"launch_ns_t": 5}
+    for _ in range(3):
+        with phase(stats, "ns_t_device", "rtpu.test.launch", "launch_ns_t"):
+            time.sleep(0.001)
+    with phase(stats, "ns_t_device", "rtpu.test.wait"):
+        time.sleep(0.001)
+    assert 3e6 <= stats["launch_ns_t"] - 5 < stats["ns_t_device"]
+    assert set(stats) == {"ns_t_device", "max_ns_t_device", "launch_ns_t"}
 
 
 @pytest.fixture(scope="module", params=["llama", "latent"])
@@ -100,6 +120,14 @@ def test_engine_phases_partition_the_step(deep_engine):
     total = sum(d[k] for k in ENGINE_PHASES)
     assert total <= wall
     assert total >= 0.98 * wall, (total, wall)
+    # the new keys leave the partition alone: no key that starts with
+    # ns_ but the ten, and none of them among the maxima
+    assert {k for k in engine.stats if k.startswith("ns_")} == set(PHASES)
+    assert {k for k in engine.stats if k.startswith("max_ns_")} == \
+        {"max_" + k for k in PHASES}
+    # a launch is part of its family's device phase
+    for family, (_, launch_key) in LAUNCHES.items():
+        assert 0 < d[launch_key] <= d[f"ns_{family}_device"], family
 
 
 @pytest.mark.parametrize("max_adapters", [0, 2])
@@ -145,6 +173,14 @@ def test_loop_phases_through_a_local_server(max_adapters):
         after = srv.engine_stats()
         assert after["ns_loop_other"] > idle["ns_loop_other"]
         assert after["ns_decode_device"] > 0
+        # the thread's CPU clock, read from this thread: asleep in
+        # rtpu.loop.idle it burns next to none, at work it burns some,
+        # and never more than the wall time it worked for
+        cpu = [s["step_thread_cpu_ns"] for s in (first, idle, after)]
+        assert 0 <= cpu[1] - cpu[0] < 0.1 * grown
+        worked = sum(after[k] - idle[k] for k in PHASES
+                     if k != "ns_loop_idle")
+        assert 0 < cpu[2] - cpu[1] <= worked + 20e6
         # over a window, all ten sum to the thread's wall time, give or
         # take the idle wait (50 ms at most) in progress at either end
         span = after["clock_ns"] - first["clock_ns"]
@@ -270,10 +306,152 @@ def test_phases_are_spans_on_the_profilers_host_plane(engine, tmp_path):
     if not names:
         pytest.skip("the CPU profiler wrote no host plane here")
     assert {PHASES[k] for k in ENGINE_PHASES} <= names
-    # on the trace's clock: each device phase lies inside the session
-    dev = [e for e in host["lines"][0]["events"]
-           if e[0] == "rtpu.engine.decode.device"]
-    assert dev and all(e[1] >= 0 and e[2] > 0 for e in dev)
+    # a device phase is two spans, a launch and a readback's wait, and
+    # no span wears the name they shared
+    assert {name for name, _ in LAUNCHES.values()} <= names
+    assert {"rtpu.engine.prefill.wait", "rtpu.engine.decode.wait"} <= names
+    assert not [n for n in names if n.endswith(".device")]
+    # on the trace's clock: each lies inside the session
+    events = host["lines"][0]["events"]
+    for name in ("rtpu.engine.decode.launch", "rtpu.engine.decode.wait"):
+        dev = [e for e in events if e[0] == name]
+        assert dev and all(e[1] >= 0 and e[2] > 0 for e in dev)
+    # siblings, not children: no launch or wait lies inside another
+    # span of the stepping thread, so a gap's name holds one of them
+    spans = sorted((e[1], e[1] + e[2]) for e in events
+                   if e[0].startswith("rtpu.engine."))
+    assert all(a[1] <= b[0] for a, b in zip(spans, spans[1:]))
+
+
+def _stream_server(**over):
+    from ray_tpu.llm.serving import LLMConfig, LLMServer
+    return LLMServer(LLMConfig(model_id="tiny-streams", warmup=False,
+                               engine=_cfg(**over)))
+
+
+def test_sixteen_streams_count_their_chunks_exactly():
+    """Sixteen concurrent streams through a local server, four slots:
+    ``stream_chunks`` is the text chunks the clients got, every request
+    has one first chunk, and a transport that sleeps on every chunk
+    shows in the lag (booking -> taken by the transport), not in the
+    stream threads' CPU time."""
+    srv = _stream_server()
+    nap, got, firsts = 0.004, [], []
+
+    def client(i):
+        chunks, tokens = 0, 0
+        for ch in srv.completions_stream(
+                {"prompt": list(range(1 + i, 25 + i)), "max_tokens": 9}):
+            text = ch["choices"][0]["text"]
+            chunks += bool(text)
+            tokens += len(text)
+            time.sleep(nap)             # the transport's write
+        got.append(chunks)
+        firsts.append(tokens)
+
+    before = srv.engine_stats()
+    assert all(before[k] == 0 for k in STREAM_COUNTERS)
+    threads = [threading.Thread(target=client, args=(i,)) for i in range(16)]
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads)
+        st = srv.engine_stats()
+    finally:
+        srv._stop = True
+        srv._wake.set()
+    assert len(got) == 16 and all(n >= 1 for n in got)
+    assert st["stream_chunks"] == sum(got)
+    assert st["stream_first_chunks"] == 16
+    # every chunk's lag holds its own transport's nap; the first chunks'
+    # are among them
+    assert st["stream_lag_ns"] >= sum(got) * nap * 1e9
+    assert st["stream_first_lag_ns"] >= 16 * nap * 1e9
+    assert st["stream_first_lag_ns"] < st["stream_lag_ns"]
+    # asleep is not CPU: the stream threads' CPU time is far under the
+    # naps alone
+    assert 0 < st["stream_cpu_ns"] < 0.5 * sum(got) * nap * 1e9
+    # the stepping thread's partition is none the worse for them
+    assert {k for k in st if k.startswith("ns_")} == set(PHASES)
+
+
+def test_stream_meter_loses_no_update_between_threads():
+    """The stream threads' adds are read-modify-writes of one dict:
+    sixteen threads at a short switch interval, each taking 500 chunks
+    of a known lag, lose none."""
+    from ray_tpu.llm.engine import _Request
+    from ray_tpu.llm.serving import _StreamMeter
+    stats, lock = dict.fromkeys(STREAM_COUNTERS, 0), threading.Lock()
+    n_threads, n_chunks = 16, 500
+    # every chunk booked this long before the clock's zero: the sum of
+    # the lags counts the chunks in its high digits (8,000 readings of
+    # perf_counter_ns stay far under it)
+    EPOCH = 10 ** 24
+
+    def stream():
+        req = _Request(rid=0, prompt_ids=[1], params=SamplingParams())
+        req.first_token_ns = time.perf_counter_ns()
+        meter = _StreamMeter(stats, lock, req)
+        for _ in range(n_chunks):
+            meter.taken(-EPOCH)
+        assert req.first_chunk_ns >= req.first_token_ns
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=stream) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(was)
+    assert stats["stream_chunks"] == n_threads * n_chunks
+    assert stats["stream_lag_ns"] // EPOCH == n_threads * n_chunks
+    assert stats["stream_first_chunks"] == n_threads
+    assert stats["stream_cpu_ns"] > 0
+
+
+def test_a_streamed_request_has_an_llm_deliver_span():
+    """With tracing on, a streamed request's ``llm.request`` gets a
+    fourth child: first token booked -> its chunk taken by the
+    transport, inside ``llm.decode``'s extent."""
+    from ray_tpu.core import runtime as rt_mod
+    from ray_tpu.core.config import cfg
+
+    class _StubRT:
+        def __init__(self):
+            self.spans = []
+
+        def record_trace_span(self, rec):
+            self.spans.append(rec)
+
+    stub = _StubRT()
+    prev_rt = rt_mod.get_runtime_if_exists()
+    cfg.override(tracing_enabled=True)
+    rt_mod.set_runtime(stub)
+    srv = _stream_server()
+    try:
+        chunks = list(srv.completions_stream(
+            {"prompt": list(range(1, 30)), "max_tokens": 12}))
+        assert chunks[-1]["choices"][0]["finish_reason"] == "length"
+    finally:
+        srv._stop = True
+        srv._wake.set()
+        rt_mod.set_runtime(prev_rt)
+        cfg.reset("tracing_enabled")
+    (root,) = [s for s in stub.spans if s["name"] == "llm.request"]
+    kids = {s["name"]: s for s in stub.spans
+            if s.get("parent_id") == root["span_id"]}
+    assert set(kids) == {"llm.queue", "llm.prefill", "llm.decode",
+                         "llm.deliver"}
+    deliver, decode = kids["llm.deliver"], kids["llm.decode"]
+    assert deliver["trace_id"] == root["trace_id"]
+    assert deliver["start_s"] == pytest.approx(decode["start_s"], abs=1e-6)
+    assert 0.0 < deliver["dur_s"] <= decode["dur_s"] + 1e-6
 
 
 def _brute_key_steps(rows, window, table_pages, page, q_tile, block_keys):

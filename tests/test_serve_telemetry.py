@@ -85,6 +85,41 @@ def test_loop_phase_seconds_and_live_slots_reach_metrics(fresh_registry,
     assert 'rtpu_llm_decode_live_slots_total{engine="paged"}' in text
 
 
+def _shipped(text: str, series: str) -> float:
+    return float(next(ln for ln in text.splitlines()
+                      if ln.startswith(series)).split()[-1])
+
+
+def test_launch_and_stream_counters_reach_metrics(fresh_registry, engine):
+    """The families an operator reads S12 by: the launches' seconds by
+    family beside the phases' seconds, and the streams' chunks, lag and
+    CPU (counted by serving's stream threads; set by hand here, a bare
+    engine has no stream)."""
+    engine._telem_shipped = None
+    engine.stats.update(stream_chunks=engine.stats["stream_chunks"] + 5,
+                        stream_lag_ns=engine.stats["stream_lag_ns"] + 10**9,
+                        stream_cpu_ns=engine.stats["stream_cpu_ns"] + 10**7)
+    _drive(engine)
+    text = "\n".join(um.prometheus_lines(um.local_store()))
+    for family in ("prefill", "decode"):
+        series = (f'rtpu_llm_launch_seconds_total{{engine="paged",'
+                  f'family="{family}"}}')
+        assert _shipped(text, series) == pytest.approx(
+            engine.stats[f"launch_ns_{family}"] * 1e-9, rel=1e-6)
+        # a launch is part of the family's device phase
+        assert _shipped(text, series) <= _shipped(
+            text, f'rtpu_llm_loop_seconds_total{{engine="paged",'
+                  f'phase="{family}_device"}}')
+    for name, key, scale in (
+            ("rtpu_llm_stream_chunks_total", "stream_chunks", 1.0),
+            ("rtpu_llm_stream_lag_seconds_total", "stream_lag_ns", 1e-9),
+            ("rtpu_llm_stream_cpu_seconds_total", "stream_cpu_ns", 1e-9)):
+        assert _shipped(text, name + '{engine="paged"}') == pytest.approx(
+            engine.stats[key] * scale, rel=1e-6)
+    # no launch is shipped as a phase by a prefix's accident
+    assert 'phase="ns_' not in text and 'phase="launch' not in text
+
+
 def test_engine_request_span_parents_to_submitter(fresh_registry, engine):
     from ray_tpu.core import runtime as rt_mod
     from ray_tpu.core.config import cfg
