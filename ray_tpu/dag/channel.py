@@ -456,6 +456,29 @@ class RingWriter:
                    push_addr=self.push_addr)
         self.seq = n + 1
 
+    def try_write(self, value: Any) -> bool:
+        """write() that never parks: False, and nothing written, while
+        the ring has no credit. One probe of {retiring ack, stop} where
+        credit_ready() + closed() + write() make three — for a producer
+        that serves many rings from one thread (the serve replica's
+        pushed streams). Raises ChannelClosed once the stop flag is
+        sealed."""
+        n = self.seq
+        if n >= self.ring:
+            ack = slot_oid(self.ack_base, n - self.ring)
+            acked, stopped = self.store.wait_sealed([ack, self.stop], 1, 0)
+            if stopped:
+                raise ChannelClosed("channel stop flag sealed")
+            if not acked:
+                return False
+            self.store.delete(ack)
+        elif self.closed():
+            raise ChannelClosed("channel stop flag sealed")
+        write_slot(self.store, self.base, n, value,
+                   push_addr=self.push_addr)
+        self.seq = n + 1
+        return True
+
     def finish(self, timeout_s: Optional[float] = None) -> None:
         """End the stream cleanly: seal EOS (carrying the final count —
         needs no ring credit), retire every still-outstanding ring

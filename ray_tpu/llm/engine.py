@@ -65,9 +65,9 @@ class _Request:
     submit_t: float = 0.0
     first_token_t: float = 0.0    # TTFT = first_token_t - submit_t
     # perf_counter_ns stamps of a token's way out (paged engine +
-    # llm/serving.py completions_stream): the booking that put the
-    # newest token and the first on the host, and the instant the
-    # transport had taken the chunk that carried the first
+    # llm/serving.py's stream pump): the booking that put the newest
+    # token and the first on the host, and the instant the stream's sink
+    # had taken the chunk that carried the first
     token_ns: int = 0
     first_token_ns: int = 0
     first_chunk_ns: int = 0

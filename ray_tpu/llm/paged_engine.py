@@ -223,16 +223,18 @@ PHASES = {
     "ns_loop_other": "rtpu.loop.other",
     "ns_loop_idle": "rtpu.loop.idle",
 }
-# A launch (the jitted call up to the streams' wake-up), by family: its
+# A launch (the jitted call up to the stream pump's wake-up), by family: its
 # span, and the counter it grows beside ns_<family>_device.
 LAUNCHES = {
     family: (f"rtpu.engine.{family}.launch", f"launch_ns_{family}")
     for family in ("prefill", "decode")}
-# What the stream threads count of a token's way out of the replica
-# (llm/serving.py _StreamMeter); the stepping thread only stamps the
-# bookings (_Request.token_ns).
+# What the stream pump counts of a token's way out of the replica
+# (llm/serving.py LLMServer._pump, the one thread that serves every open
+# stream); the stepping thread only stamps the bookings
+# (_Request.token_ns).
 STREAM_COUNTERS = ("stream_chunks", "stream_lag_ns", "stream_first_chunks",
-                   "stream_first_lag_ns", "stream_cpu_ns")
+                   "stream_first_lag_ns", "stream_cpu_ns", "stream_passes",
+                   "stream_deferred")
 
 
 @dataclasses.dataclass
@@ -358,7 +360,7 @@ class PagedInferenceEngine:
         self._rng_ctr = 0
         self._lock = threading.Lock()
         # notified when a dispatch has been launched (_notify_launch):
-        # what a stream of tokens sleeps on (llm/serving.py)
+        # what serving's stream pump sleeps on (llm/serving.py _pump)
         self.launched = threading.Condition()
         self.launch_gen = 0
         self._interpret = interpret
@@ -1332,7 +1334,8 @@ class PagedInferenceEngine:
 
     def _launched(self, family: str, outs: tuple, **host):
         """A program was just launched: queue its booking, count it as
-        running ahead if an earlier one is still out, wake the streams."""
+        running ahead if an earlier one is still out, wake the stream
+        pump."""
         if self._inflight:
             self.stats["dispatches_overlapped"] += 1
         self._inflight.append(_Launched(family, outs, host))
@@ -1587,13 +1590,15 @@ class PagedInferenceEngine:
         return [int(t) for t in ctx[start:start + s]]
 
     def _notify_launch(self):
-        """Wake whoever waits for new tokens (serving's streams), called
-        with a program just launched and not yet awaited: their Python —
-        decoding, one reply a stream — then runs beside the program. Woken
-        when the tokens are booked instead, 64 streams hold the GIL for
-        tens of ms exactly when this thread needs it to launch the next
-        program (PERF.md §6, PR 27). The tokens they find are the
-        previous dispatch's."""
+        """Wake whoever waits for new tokens (serving's stream pump, one
+        thread whatever the number of streams), called with a program
+        just launched and not yet awaited: its Python — detokenising,
+        one ring write a stream — then runs beside the program. Woken
+        when the tokens are booked instead, that Python holds the GIL
+        exactly when this thread needs it to launch the next program
+        (PERF.md §6, PR 27; with a thread a stream, 64 of them took
+        turns at it and a launch that needs 3 ms took 50-80: PR 39). The
+        tokens the pump finds are the previous dispatch's."""
         with self.launched:
             self.launch_gen += 1
             self.launched.notify_all()

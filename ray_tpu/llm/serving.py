@@ -13,7 +13,9 @@ TPU resources.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
+import queue
 import threading
 import time as _time
 from typing import Optional
@@ -56,59 +58,104 @@ class LLMConfig:
     warmup_sampled: bool = False
 
 
-class _StreamMeter:
-    """One stream's way out of the replica, counted chunk by chunk into
-    ``engine.stats`` on the stream's own thread (``completions_stream``):
+class _QueueSink:
+    """The sink of a stream that is iterated in this process (a
+    ``stream_next`` reply, local mode, a test): the pump's items wait in
+    a queue for ``TokenStream.__next__``. It never refuses credit."""
 
-    - ``stream_chunks``: chunks that carried text and were taken by the
-      transport — the generator was resumed after their ``yield`` (the
-      replica's drain thread has written its ring, a ``stream_next``
-      reply has gone);
-    - ``stream_lag_ns``: from the booking of a chunk's newest token
-      (``_Request.token_ns``, the stepping thread's stamp) to that
-      resumption: the wait for the next launch's wake-up, the thread's
-      turn at the interpreter, the detokenisation and the transport's
-      write together — what a chunk's delivery takes inside the replica;
-    - ``stream_first_chunks`` / ``stream_first_lag_ns``: the same for
-      the chunk that carries a request's first token, from that token's
-      booking: the part of a client's TTFT between the engine's
-      (``rtpu_llm_ttft_seconds``) and the proxy;
-    - ``stream_cpu_ns``: the thread's CPU from one chunk's resumption to
-      the next, the empty wake-ups between, the detokenisation and the
-      transport's write included — what the stream costs the one
-      interpreter."""
+    __slots__ = ("items", "shut")
 
-    __slots__ = ("_stats", "_lock", "_req", "_first", "_cpu", "_thread")
+    def __init__(self):
+        self.items: queue.SimpleQueue = queue.SimpleQueue()
+        self.shut = False
 
-    def __init__(self, stats: dict, lock, req):
-        self._stats, self._lock, self._req = stats, lock, req
-        self._first = True
-        # a thread's CPU clock says nothing of another's, and a
-        # stream_next reply may resume the generator on another thread
-        # of the actor's pool: the clock is kept with its thread
-        self._cpu, self._thread = (_time.thread_time_ns(),
-                                   threading.get_ident())
+    def put(self, item) -> bool:
+        self.items.put(("i", item))
+        return True
 
-    def taken(self, booked: int) -> None:
-        """The transport has taken a chunk whose newest token was booked
-        at ``booked`` (``token_ns`` as read when its tokens were seen: a
-        token is stamped before it is appended, paged_engine._book_decode,
-        so the stamp is no older than the booking of any of them)."""
-        now = _time.perf_counter_ns()
-        cpu, thread = _time.thread_time_ns(), threading.get_ident()
-        spent = cpu - self._cpu if thread == self._thread else 0
-        self._cpu, self._thread = cpu, thread
-        req, st = self._req, self._stats
-        with self._lock:
-            st["stream_chunks"] += 1
-            st["stream_lag_ns"] += now - booked
-            st["stream_cpu_ns"] += spent
-            if self._first:
-                st["stream_first_chunks"] += 1
-                st["stream_first_lag_ns"] += now - req.first_token_ns
-        if self._first:
-            self._first = False
-            req.first_chunk_ns = now
+    def end(self) -> bool:
+        self.items.put(("e", None))
+        return True
+
+    def fail(self, exc: BaseException) -> bool:
+        self.items.put(("x", exc))
+        return True
+
+    def closed(self) -> bool:
+        return self.shut
+
+
+class _Open:
+    """The pump's record of one open stream: the request, the sink its
+    chunks go to, and how far the sink has got."""
+
+    __slots__ = ("req", "sink", "seen", "text", "sent", "booked", "finish",
+                 "first", "closing")
+
+    def __init__(self, req, sink):
+        self.req, self.sink = req, sink
+        self.seen = 0           # tokens of req.out_ids detokenised
+        self.text = ""          # the answer so far, detokenised whole
+        self.sent = 0           # characters of it the sink has taken
+        self.booked = 0         # token_ns of the newest token in text
+        self.finish = None      # finish_reason, once the request is done
+        self.first = True       # no chunk taken yet
+        self.closing = False    # the closing chunk has gone: end() is owed
+
+
+class TokenStream:
+    """What ``LLMServer.completions_stream`` returns: a request already
+    submitted, whose chunks the server's one pump thread hands to a
+    sink. Two ways to take them:
+
+    - **push**: ``attach(sink)`` — the pump calls ``sink.put(chunk)``,
+      ``sink.end()`` after the chunk that carries ``finish_reason``, or
+      ``sink.fail(exc)``; each returns False when the sink cannot take
+      it now (the pump keeps the text and tries again on a later pass,
+      with whatever has come since in the same chunk) and none may
+      block; a sink whose ``closed()`` is true is dropped. The serve
+      replica attaches a sink over the stream's ring
+      (serve/controller.py ``_start_stream_channel``) and starts no
+      thread;
+    - **pull**: iterate it. The first ``next()`` attaches a queue as the
+      sink; ``close()`` (or dropping the object) ends the pump's work
+      for it."""
+
+    def __init__(self, server: "LLMServer", req):
+        self._server, self._req = server, req
+        self._own: Optional[_QueueSink] = None
+        self._attached = self._over = False
+
+    def attach(self, sink) -> None:
+        if self._attached:
+            raise RuntimeError("this stream already has a sink")
+        self._attached = True
+        self._server._open_stream(_Open(self._req, sink))
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        if self._over:
+            raise StopIteration
+        if self._own is None:
+            own = _QueueSink()
+            self.attach(own)
+            self._own = own
+        kind, payload = self._own.items.get()
+        if kind == "i":
+            return payload
+        self._over = True
+        if kind == "x":
+            raise payload
+        raise StopIteration
+
+    def close(self) -> None:
+        self._over = True
+        if self._own is not None:
+            self._own.shut = True
+
+    __del__ = close
 
 
 class LLMServer:
@@ -138,11 +185,11 @@ class LLMServer:
         # concurrent scatter/gather would read deleted buffers — same
         # contract as pd_disagg's _steplock around import_prefill)
         self._steplock = threading.Lock()
-        # the stream threads' adds to engine.stats (_StreamMeter): a
-        # read-modify-write a chunk, which two of them must not
-        # interleave. The stepping thread never writes those keys and
-        # never takes this lock.
-        self._stream_lock = threading.Lock()
+        # streams attached and not yet seen by the pump (TokenStream
+        # .attach, any thread -> _pump), and what wakes a pump that has
+        # none open
+        self._opening: collections.deque = collections.deque()
+        self._pump_wake = threading.Event()
         # cluster prefix directory (serve/frontdoor/prefix.py). The
         # controller injects this replica's own handle via
         # set_replica_handle; publishing starts then.
@@ -167,6 +214,10 @@ class LLMServer:
         # from outside: the thread itself never pays for a reading
         self._step_cpu_clock = _time.pthread_getcpuclockid(
             self._thread.ident)
+        # the one thread that carries every open stream's tokens out
+        self._pump_thread = threading.Thread(
+            target=self._pump, daemon=True, name="llm-stream-pump")
+        self._pump_thread.start()
 
     def _build_engine(self, params):
         eng = PagedInferenceEngine(self.engine_cfg, params)
@@ -239,6 +290,8 @@ class LLMServer:
             for req in (list(eng._active.values()) + list(eng._pending)
                         + list(eng._prefilling)):
                 req.event.set()
+            # and the pump, which fails every open stream
+            eng._notify_launch()
 
     # -- OpenAI-ish surface ------------------------------------------------
 
@@ -345,57 +398,141 @@ class LLMServer:
             },
         }
 
-    def completions_stream(self, request: dict):
-        """Generator of token-delta dicts while the engine decodes
-        (reference: the streaming response path of llm_server.py; pairs
-        with handle.options(stream=True) / the SSE proxy path).
+    def completions_stream(self, request: dict) -> TokenStream:
+        """Submit now, on the caller's thread — a refused request (an
+        unknown adapter's ValueError, RuntimeError("overloaded: ...")) is
+        raised here and no stream exists — and return the request's
+        ``TokenStream`` of token-delta dicts (reference: the streaming
+        response path of llm_server.py; pairs with
+        handle.options(stream=True) / the SSE proxy path). No thread
+        belongs to it: the server's one pump (``_pump``) detokenises and
+        delivers for every open stream, and counts what a token's way
+        out of the replica costs into ``engine.stats``."""
+        return TokenStream(self, self._submit(request))
 
-        What a token's way out of the replica costs is counted here,
-        chunk by chunk, into ``engine.stats`` (``_StreamMeter``): a
-        chunk is counted once the transport (the replica's drain thread
-        writing its ring, or a ``stream_next`` reply) has taken it and
-        resumes the generator."""
-        eng, req = self.engine, self._submit(request)
-        meter = _StreamMeter(eng.stats, self._stream_lock, req)
-        sent = 0
-        last_text = ""
-        # the engine says when it has launched a dispatch: sleep on
-        # that, not on a 50 Hz poll, so that this thread's work runs
-        # beside the device's and not in the stepping thread's way
-        launched = eng.launched
-        while True:
-            if self._error is not None and not req.done:
-                raise RuntimeError("llm engine loop died") from self._error
+    # -- the stream pump ---------------------------------------------------
+
+    def _open_stream(self, opened: _Open) -> None:
+        self._opening.append(opened)
+        self._pump_wake.set()
+
+    def _pump(self):
+        """The one thread that serves every open stream. It sleeps on
+        ``engine.launched``, so a pass runs with a program just launched
+        and not yet awaited — beside the device's work, and as ONE
+        runnable thread at the interpreter the stepping thread needs for
+        its next launch, where a thread a stream made 64 (PERF.md §6,
+        PR 39). The tokens a pass finds are the previous dispatch's.
+
+        Counted into ``engine.stats``, by this thread alone:
+
+        - ``stream_chunks``: chunks that carried text and that their
+          sink took (the ring write returned, the queue has it);
+        - ``stream_lag_ns``: from the booking of a chunk's newest token
+          (``_Request.token_ns``, the stepping thread's stamp) to that
+          instant: the wait for the next launch's wake-up, the stream's
+          turn in the pass, the detokenisation and the sink's write,
+          and every pass a sink without credit held the text back;
+        - ``stream_first_chunks`` / ``stream_first_lag_ns``: the same
+          for the chunk that carries a request's first token, from that
+          token's booking: the part of a client's TTFT between the
+          engine's (``rtpu_llm_ttft_seconds``) and the proxy; the
+          instant is the request's ``first_chunk_ns`` (``llm.deliver``);
+        - ``stream_cpu_ns``: this thread's CPU, read once a pass — what
+          all the streams cost the one interpreter;
+        - ``stream_passes``: passes in which at least one chunk was
+          taken; ``stream_deferred``: puts a sink refused for want of
+          credit, the text kept for a later pass."""
+        eng = self.engine
+        st, launched = eng.stats, eng.launched
+        streams: list[_Open] = []
+        cpu = _time.thread_time_ns()
+        while not self._stop:
+            while self._opening:
+                streams.append(self._opening.popleft())
+            if not streams:
+                # no launch is worth waking for
+                self._pump_wake.wait(timeout=1.0)
+                self._pump_wake.clear()
+                continue
             gen = eng.launch_gen
-            n = len(req.out_ids)
-            if n > sent:
-                booked = req.token_ns
-                text = eng.tokenizer.decode(list(req.out_ids))
-                delta, last_text = text[len(last_text):], text
-                sent = n
-                if delta:
-                    yield {"object": "text_completion.chunk",
-                           "model": self.model_id,
-                           "choices": [{"text": delta, "index": 0,
-                                        "finish_reason": None}]}
-                    meter.taken(booked)
-            if req.done:
-                break
+            chunks = st["stream_chunks"]
+            streams = [s for s in streams if not self._pump_stream(s)]
+            if st["stream_chunks"] > chunks:
+                st["stream_passes"] += 1
+            now = _time.thread_time_ns()
+            st["stream_cpu_ns"] += now - cpu
+            cpu = now
             with launched:
                 # the timeout bounds the wait when no dispatch follows
                 # (the engine went idle, or its loop died)
-                if eng.launch_gen == gen and not req.done:
+                if eng.launch_gen == gen and not self._opening:
                     launched.wait(timeout=0.05)
-        booked = req.token_ns
-        out = eng._result(req)
-        tail = out["text"][len(last_text):]
-        yield {"object": "text_completion.chunk", "model": self.model_id,
-               "choices": [{"text": tail, "index": 0,
-                            "finish_reason": out["finish_reason"]}]}
-        # the closing chunk is empty unless the last tokens were booked
-        # between this thread's look and the retirement
-        if tail:
-            meter.taken(booked)
+
+    def _pump_stream(self, s: _Open) -> bool:
+        """One stream's turn in a pass; True once the pump is done with
+        it. An error of its own (a sink's, the tokenizer's) ends that
+        stream alone."""
+        try:
+            return self._deliver(s)
+        except Exception as e:  # noqa: BLE001 — shipped to the consumer
+            try:
+                s.sink.fail(e)
+            except Exception:  # noqa: BLE001 — nothing left to tell
+                pass
+            return True
+
+    def _deliver(self, s: _Open) -> bool:
+        eng, req, sink = self.engine, s.req, s.sink
+        if sink.closed():
+            return True         # the consumer cancelled
+        if s.finish is None:
+            # done is read before the tokens: a done request's are all in
+            if req.done:
+                out = eng._result(req)
+                s.booked, s.seen = req.token_ns, len(req.out_ids)
+                s.text, s.finish = out["text"], out["finish_reason"]
+            elif self._error is not None:
+                err = RuntimeError("llm engine loop died")
+                err.__cause__ = self._error
+                return sink.fail(err) or sink.closed()
+            elif len(req.out_ids) > s.seen:
+                ids = list(req.out_ids)
+                # a token is stamped before it is appended
+                # (paged_engine._book_decode): read after the ids, the
+                # stamp is no older than the booking of any of them
+                s.booked, s.seen = req.token_ns, len(ids)
+                s.text = eng.tokenizer.decode(ids)
+        delta = s.text[s.sent:]
+        if not s.closing and (delta or s.finish is not None):
+            took = sink.put({
+                "object": "text_completion.chunk", "model": self.model_id,
+                "choices": [{"text": delta, "index": 0,
+                             "finish_reason": s.finish}]})
+            if not took:
+                if sink.closed():
+                    return True
+                # no credit: the text is kept, and goes with what the
+                # next passes add in one longer chunk
+                eng.stats["stream_deferred"] += 1
+                return False
+            if delta:
+                self._taken(s)
+            s.sent = len(s.text)
+            s.closing = s.finish is not None
+        return s.closing and (sink.end() or sink.closed())
+
+    def _taken(self, s: _Open) -> None:
+        """A sink has taken a chunk that carried text."""
+        now = _time.perf_counter_ns()
+        st = self.engine.stats
+        st["stream_chunks"] += 1
+        st["stream_lag_ns"] += now - s.booked
+        if s.first:
+            s.first = False
+            st["stream_first_chunks"] += 1
+            st["stream_first_lag_ns"] += now - s.req.first_token_ns
+            s.req.first_chunk_ns = now
 
     def set_replica_handle(self, handle) -> None:
         """Controller-injected handle to THIS replica's actor: the value
@@ -466,6 +603,8 @@ class LLMServer:
     def check_health(self):
         if self._error is not None or not self._thread.is_alive():
             raise RuntimeError("engine loop died") from self._error
+        if not self._pump_thread.is_alive():
+            raise RuntimeError("stream pump died")
 
 
 def build_llm_deployment(cfg: LLMConfig, params_ref=None):

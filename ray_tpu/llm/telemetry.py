@@ -21,8 +21,8 @@ admit), ``llm.prefill`` (admit -> first token) and ``llm.decode`` (first
 token -> retire), with ``prompt_tokens``, ``prefix_tokens_saved`` and
 ``out_tokens`` as arguments. A streamed request has a fourth,
 ``llm.deliver``, beside ``llm.decode``: first token booked -> the
-transport has taken the chunk that carries it (serving's
-``_StreamMeter``). The spans are emitted when the request retires, so
+stream's sink has taken the chunk that carries it (serving's stream
+pump, ``LLMServer._pump``). The spans are emitted when the request retires, so
 a request that retires before its first chunk was taken (a short
 answer, a slow transport) has no ``llm.deliver``.
 
@@ -60,15 +60,21 @@ Metric names (all prefixed ``rtpu_llm_``):
       launch a program, by ``family`` (prefill, decode; a verify dispatch
       is decode's): over dispatches_total, what a launch takes the
       thread — milliseconds when it has the interpreter, tens of them
-      among awake stream threads
-  stream_chunks_total    counter    text chunks the streams handed to
-      their transport (serving's completions_stream)
+      when it has to take turns at it
+  stream_chunks_total    counter    text chunks the streams' sinks took
+      (serving's stream pump: a ring write returned, a queue has it)
   stream_lag_seconds_total counter  booking of a chunk's newest token ->
-      the transport has taken the chunk, summed; over
+      its sink has taken the chunk, summed; over
       stream_chunks_total: a chunk's delivery time inside the replica
-  stream_cpu_seconds_total counter  CPU seconds of the stream threads,
-      detokenisation and the transport's write included; its rate is the
-      share of one core, so of the one interpreter, the streams take
+  stream_cpu_seconds_total counter  CPU seconds of the stream pump, the
+      one thread that serves every open stream, detokenisation and the
+      sinks' writes included; its rate is the share of one core, so of
+      the one interpreter, the streams take
+  stream_passes_total    counter    passes of the pump in which a sink
+      took at least one chunk; stream_chunks_total over it is the
+      chunks a wake-up carries
+  stream_deferred_total  counter    puts a sink refused for want of ring
+      credit (a slow consumer): the text was kept and went later
   prefix_cache_hits_total      counter  full prompt pages served from cache
   prefix_cache_misses_total    counter  full prompt pages computed by prefill
   prefix_cache_evictions_total counter  cached pages reclaimed under pressure
@@ -311,7 +317,11 @@ _STAT_COUNTERS = (
     ("decode_live_slots", "rtpu_llm_decode_live_slots_total",
      "slots live, summed over decode dispatches", None),
     ("stream_chunks", "rtpu_llm_stream_chunks_total",
-     "text chunks the streams handed to their transport", None),
+     "text chunks the streams' sinks took", None),
+    ("stream_passes", "rtpu_llm_stream_passes_total",
+     "stream pump passes in which a sink took a chunk", None),
+    ("stream_deferred", "rtpu_llm_stream_deferred_total",
+     "puts a stream's sink refused for want of credit", None),
     ("prefix_hits", "rtpu_llm_prefix_cache_hits_total",
      "full prompt pages served from the prefix cache", None),
     ("prefix_misses", "rtpu_llm_prefix_cache_misses_total",
@@ -367,10 +377,10 @@ _STAT_SECONDS = (
      "the engine loop thread's seconds inside launches, by family",
      ("family", "decode")),
     ("stream_lag_ns", "rtpu_llm_stream_lag_seconds_total",
-     "a chunk's newest token booked -> taken by the transport, summed",
+     "a chunk's newest token booked -> taken by its sink, summed",
      None),
     ("stream_cpu_ns", "rtpu_llm_stream_cpu_seconds_total",
-     "CPU seconds of the stream threads", None),
+     "CPU seconds of the stream pump thread", None),
 )
 
 

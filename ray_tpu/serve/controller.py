@@ -21,6 +21,49 @@ from ..core import flight as _fl
 from .api import AutoscalingConfig, DeploymentSpec
 
 
+class _RingSink:
+    """The sink a pushing stream is given (``ReplicaActor.
+    _start_stream_channel``): the drain thread's writes to one stream's
+    ring, made by whatever thread the stream's owner pushes from. None
+    of them blocks: each returns False, with nothing written, while the
+    ring has no credit. The stream is retired — stale slots swept, the
+    replica's slot freed — by the write that ends it or the one that
+    finds the consumer's stop flag."""
+
+    def __init__(self, writer, retire):
+        self._writer, self._retire = writer, retire
+
+    def put(self, item) -> bool:
+        return self._write(("i", item), False)
+
+    def end(self) -> bool:
+        return self._write(("e", None), True)
+
+    def fail(self, exc: BaseException) -> bool:
+        return self._write(("x", exc), True)
+
+    def closed(self) -> bool:
+        return self._retire is None
+
+    def _write(self, msg, last: bool) -> bool:
+        from ..dag.channel import ChannelClosed
+        if self._retire is None:
+            return False
+        try:
+            took = self._writer.try_write(msg)
+            over = took and last
+        except ChannelClosed:
+            took, over = False, True    # the consumer cancelled
+        except Exception:
+            import traceback
+            traceback.print_exc()
+            took, over = False, True
+        if over:
+            retire, self._retire = self._retire, None
+            retire()
+        return took
+
+
 class ReplicaActor:
     """Hosts one replica of a deployment's callable (reference:
     replica.py:945 — async execution with max_ongoing_requests enforced by
@@ -156,13 +199,28 @@ class ReplicaActor:
 
     def _start_stream_channel(self, sid: int, gen, chan: dict,
                               context: Optional[dict]) -> bool:
-        """Serve this stream over a sealed ring channel: a drain thread
-        pulls the generator and seals each item into shm; the handle
-        reads them directly — zero control-plane dispatches per item
-        (reference analog: compiling the decode step into a static plan
-        instead of one stream_next RPC per chunk). Returns False when
-        this replica can't share a store with the caller (own-store
-        node) so the handle falls back to the poll transport."""
+        """Serve this stream over a sealed ring channel: each item is
+        sealed into shm and the handle reads them directly — zero
+        control-plane dispatches per item (reference analog: compiling
+        the decode step into a static plan instead of one stream_next
+        RPC per chunk). Who seals them depends on what the deployment
+        returned:
+
+        - an object that offers ``attach(sink)`` PUSHES: it is given a
+          ``_RingSink`` over this stream's ring and calls
+          ``sink.put(item)`` / ``sink.end()`` / ``sink.fail(exc)`` from
+          a thread of its own — none may block, each returns False
+          while the ring has no credit (the object keeps the item and
+          tries again), and a sink whose ``closed()`` is true (the
+          consumer cancelled) is to be dropped. No thread is started
+          here: a deployment with many open streams serves them all
+          from one (llm/serving.py's stream pump);
+        - any other generator, sync or async, is PULLED by a drain
+          thread of this stream's own.
+
+        Returns False when this replica can't share a store with the
+        caller (own-store node) so the handle falls back to the poll
+        transport."""
         import os
         if os.environ.get("RTPU_OWN_STORE") == "1":
             return False
@@ -182,11 +240,32 @@ class ReplicaActor:
                             int(chan["ring"]))
         is_async = hasattr(gen, "__anext__")
 
+        def retire():
+            """The stream is over, whichever way: called once, by the
+            thread that wrote its last item."""
+            _fl.evt(_fl.SRV_DRAIN_END, sid, writer.seq)
+            try:
+                # cancelled streams leave the stop flag and a ring
+                # window of unread slots behind: sweep them
+                if store.contains(stop_oid):
+                    drain_stale_slots(
+                        store,
+                        [chan["base"], writer.ack_base],
+                        writer.seq - int(chan["ring"]), writer.seq)
+                    store.delete(stop_oid)
+            except Exception:
+                pass  # store closing: slots die with it
+            loop.call_soon_threadsafe(self._drop_stream, sid)
+
+        # items are counted by the CONSUMING handle (symmetric with the
+        # poll transport) — no replica-side inc, or the series would
+        # double
+        _fl.evt(_fl.SRV_DRAIN_BEGIN, sid)
+        if not is_async and hasattr(gen, "attach"):
+            gen.attach(_RingSink(writer, retire))
+            return True
+
         def drain():
-            # items are counted by the CONSUMING handle (symmetric with
-            # the poll transport) — no replica-side inc, or the series
-            # would double
-            _fl.evt(_fl.SRV_DRAIN_BEGIN, sid)
             try:
                 while True:
                     if writer.closed():
@@ -210,19 +289,7 @@ class ReplicaActor:
                 import traceback
                 traceback.print_exc()
             finally:
-                _fl.evt(_fl.SRV_DRAIN_END, sid, writer.seq)
-                try:
-                    # cancelled streams leave the stop flag and a ring
-                    # window of unread slots behind: sweep them
-                    if store.contains(stop_oid):
-                        drain_stale_slots(
-                            store,
-                            [chan["base"], writer.ack_base],
-                            writer.seq - int(chan["ring"]), writer.seq)
-                        store.delete(stop_oid)
-                except Exception:
-                    pass  # store closing: slots die with it
-                loop.call_soon_threadsafe(self._drop_stream, sid)
+                retire()
 
         threading.Thread(target=drain, daemon=True,
                          name=f"serve-stream-chan-{sid}").start()

@@ -88,8 +88,11 @@ class DeploymentResponse:
 
 class ChannelResponseGenerator:
     """Iterator over a streaming response served by the STATIC DECODE
-    PLAN: the replica drains its generator into a sealed ring channel
-    (dag/channel.py) and this end reads items straight out of shm —
+    PLAN: the replica seals the stream's items into a ring channel
+    (dag/channel.py) — a drain thread that pulls the deployment's
+    generator, or the deployment's own thread where its return value
+    pushes (controller.py ``_start_stream_channel``) — and this end
+    reads items straight out of shm —
     zero control-plane dispatches per item in steady state (the only
     actor calls are the setup and, when the stream goes quiet for a long
     time, a liveness probe so a dead replica raises instead of hanging).
@@ -164,9 +167,10 @@ class ChannelResponseGenerator:
     def cancel(self):
         if self._done:
             return
-        # sealing the stop flag is the whole cancellation: the replica's
-        # drain thread observes it (its next write/closed() check) and
-        # sweeps the channel — no actor call, zero dispatches
+        # sealing the stop flag is the whole cancellation: whoever
+        # writes this ring in the replica (the stream's drain thread, or
+        # the deployment's pushing thread) observes it at its next write
+        # and sweeps the channel — no actor call, zero dispatches
         self._reader.close()
         self._settle()
 

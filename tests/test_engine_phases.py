@@ -4,8 +4,9 @@ partition the stepping thread's wall time (its CPU time is read from
 outside it), a launch is told from a readback's wait, the counters count what
 the dispatch decided, the programs carry their family's name, under a
 profiler session the phases are spans on the trace's host plane, and the
-stream threads count a token's way from its booking to the transport
-(llm/serving.py _StreamMeter)."""
+stream pump counts a token's way from its booking to its stream's sink
+(llm/serving.py LLMServer._pump; the pump's own tests are
+tests/test_stream_pump.py)."""
 import re
 import sys
 import threading
@@ -329,30 +330,93 @@ def _stream_server(**over):
                                engine=_cfg(**over)))
 
 
+class _NappingSink:
+    """A pushed stream's sink whose write takes ``nap`` seconds."""
+
+    def __init__(self, nap):
+        self.nap, self.texts = nap, []
+        self.over = threading.Event()
+
+    def put(self, item):
+        time.sleep(self.nap)            # the transport's write
+        self.texts.append(item["choices"][0]["text"])
+        return True
+
+    def end(self):
+        self.over.set()
+        return True
+
+    fail = end
+
+    def closed(self):
+        return False
+
+
 def test_sixteen_streams_count_their_chunks_exactly():
-    """Sixteen concurrent streams through a local server, four slots:
-    ``stream_chunks`` is the text chunks the clients got, every request
-    has one first chunk, and a transport that sleeps on every chunk
-    shows in the lag (booking -> taken by the transport), not in the
-    stream threads' CPU time."""
+    """Sixteen concurrent streams through a local server, four slots,
+    each pushed to a sink: ``stream_chunks`` is the text chunks the
+    sinks took, every request has one first chunk, and a sink that
+    sleeps in every write shows in the lag (booking -> taken by the
+    sink), not in the pump thread's CPU time."""
     srv = _stream_server()
-    nap, got, firsts = 0.004, [], []
-
-    def client(i):
-        chunks, tokens = 0, 0
-        for ch in srv.completions_stream(
-                {"prompt": list(range(1 + i, 25 + i)), "max_tokens": 9}):
-            text = ch["choices"][0]["text"]
-            chunks += bool(text)
-            tokens += len(text)
-            time.sleep(nap)             # the transport's write
-        got.append(chunks)
-        firsts.append(tokens)
-
+    nap = 0.004
+    sinks = [_NappingSink(nap) for _ in range(16)]
     before = srv.engine_stats()
     assert all(before[k] == 0 for k in STREAM_COUNTERS)
-    threads = [threading.Thread(target=client, args=(i,)) for i in range(16)]
     try:
+        for i, sink in enumerate(sinks):
+            srv.completions_stream(
+                {"prompt": list(range(1 + i, 25 + i)),
+                 "max_tokens": 9}).attach(sink)
+        for sink in sinks:
+            assert sink.over.wait(120)
+        st = srv.engine_stats()
+    finally:
+        srv._stop = True
+        srv._wake.set()
+    got = [sum(map(bool, sink.texts)) for sink in sinks]
+    assert all(n >= 1 for n in got)
+    assert st["stream_chunks"] == sum(got)
+    assert st["stream_first_chunks"] == 16
+    # every chunk's lag holds its own sink's nap (and those of the
+    # streams served before it in the pass); the first chunks' are
+    # among them
+    assert st["stream_lag_ns"] >= sum(got) * nap * 1e9
+    assert st["stream_first_lag_ns"] >= 16 * nap * 1e9
+    assert st["stream_first_lag_ns"] < st["stream_lag_ns"]
+    # asleep is not CPU: the pump's CPU time is far under the naps alone
+    assert 0 < st["stream_cpu_ns"] < 0.5 * sum(got) * nap * 1e9
+    assert 0 < st["stream_passes"] <= st["stream_chunks"]
+    assert st["stream_deferred"] == 0
+    # the stepping thread's partition is none the worse for them
+    assert {k for k in st if k.startswith("ns_")} == set(PHASES)
+
+
+def test_the_pump_and_the_loop_lose_no_update_between_them():
+    """The pump's adds and the stepping thread's are read-modify-writes
+    of one dict, each of its own keys, while a third thread takes
+    snapshots: at a short switch interval, sixteen pulled streams of 40
+    tokens lose no chunk and no token."""
+    srv = _stream_server()
+    n_streams, n_tokens = 16, 40
+    got, snaps = [], []
+
+    def client(i):
+        chunks = [ch["choices"][0]["text"] for ch in srv.completions_stream(
+            {"prompt": list(range(1 + i, 25 + i)), "max_tokens": n_tokens})]
+        got.append(sum(map(bool, chunks)))
+
+    def snapshots():
+        while len(got) < n_streams:
+            snaps.append(srv.engine_stats()["stream_chunks"])
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        before = srv.engine_stats()
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(n_streams)]
+        threads.append(threading.Thread(target=snapshots))
         for t in threads:
             t.start()
         for t in threads:
@@ -360,59 +424,15 @@ def test_sixteen_streams_count_their_chunks_exactly():
         assert not any(t.is_alive() for t in threads)
         st = srv.engine_stats()
     finally:
+        sys.setswitchinterval(was)
         srv._stop = True
         srv._wake.set()
-    assert len(got) == 16 and all(n >= 1 for n in got)
-    assert st["stream_chunks"] == sum(got)
-    assert st["stream_first_chunks"] == 16
-    # every chunk's lag holds its own transport's nap; the first chunks'
-    # are among them
-    assert st["stream_lag_ns"] >= sum(got) * nap * 1e9
-    assert st["stream_first_lag_ns"] >= 16 * nap * 1e9
-    assert st["stream_first_lag_ns"] < st["stream_lag_ns"]
-    # asleep is not CPU: the stream threads' CPU time is far under the
-    # naps alone
-    assert 0 < st["stream_cpu_ns"] < 0.5 * sum(got) * nap * 1e9
-    # the stepping thread's partition is none the worse for them
-    assert {k for k in st if k.startswith("ns_")} == set(PHASES)
-
-
-def test_stream_meter_loses_no_update_between_threads():
-    """The stream threads' adds are read-modify-writes of one dict:
-    sixteen threads at a short switch interval, each taking 500 chunks
-    of a known lag, lose none."""
-    from ray_tpu.llm.engine import _Request
-    from ray_tpu.llm.serving import _StreamMeter
-    stats, lock = dict.fromkeys(STREAM_COUNTERS, 0), threading.Lock()
-    n_threads, n_chunks = 16, 500
-    # every chunk booked this long before the clock's zero: the sum of
-    # the lags counts the chunks in its high digits (8,000 readings of
-    # perf_counter_ns stay far under it)
-    EPOCH = 10 ** 24
-
-    def stream():
-        req = _Request(rid=0, prompt_ids=[1], params=SamplingParams())
-        req.first_token_ns = time.perf_counter_ns()
-        meter = _StreamMeter(stats, lock, req)
-        for _ in range(n_chunks):
-            meter.taken(-EPOCH)
-        assert req.first_chunk_ns >= req.first_token_ns
-
-    was = sys.getswitchinterval()
-    sys.setswitchinterval(1e-5)
-    try:
-        threads = [threading.Thread(target=stream) for _ in range(n_threads)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(120)
-        assert not any(t.is_alive() for t in threads)
-    finally:
-        sys.setswitchinterval(was)
-    assert stats["stream_chunks"] == n_threads * n_chunks
-    assert stats["stream_lag_ns"] // EPOCH == n_threads * n_chunks
-    assert stats["stream_first_chunks"] == n_threads
-    assert stats["stream_cpu_ns"] > 0
+    assert len(got) == n_streams
+    assert st["stream_chunks"] - before["stream_chunks"] == sum(got)
+    assert st["stream_first_chunks"] == n_streams
+    assert st["tokens_out"] - before["tokens_out"] == n_streams * n_tokens
+    assert snaps == sorted(snaps) and snaps[-1] <= st["stream_chunks"]
+    assert st["stream_cpu_ns"] > 0 and st["stream_lag_ns"] > 0
 
 
 def test_a_streamed_request_has_an_llm_deliver_span():

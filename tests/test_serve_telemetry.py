@@ -93,12 +93,14 @@ def _shipped(text: str, series: str) -> float:
 def test_launch_and_stream_counters_reach_metrics(fresh_registry, engine):
     """The families an operator reads S12 by: the launches' seconds by
     family beside the phases' seconds, and the streams' chunks, lag and
-    CPU (counted by serving's stream threads; set by hand here, a bare
+    CPU (counted by serving's stream pump; set by hand here, a bare
     engine has no stream)."""
     engine._telem_shipped = None
     engine.stats.update(stream_chunks=engine.stats["stream_chunks"] + 5,
                         stream_lag_ns=engine.stats["stream_lag_ns"] + 10**9,
-                        stream_cpu_ns=engine.stats["stream_cpu_ns"] + 10**7)
+                        stream_cpu_ns=engine.stats["stream_cpu_ns"] + 10**7,
+                        stream_passes=engine.stats["stream_passes"] + 3,
+                        stream_deferred=engine.stats["stream_deferred"] + 2)
     _drive(engine)
     text = "\n".join(um.prometheus_lines(um.local_store()))
     for family in ("prefill", "decode"):
@@ -112,6 +114,8 @@ def test_launch_and_stream_counters_reach_metrics(fresh_registry, engine):
                   f'phase="{family}_device"}}')
     for name, key, scale in (
             ("rtpu_llm_stream_chunks_total", "stream_chunks", 1.0),
+            ("rtpu_llm_stream_passes_total", "stream_passes", 1.0),
+            ("rtpu_llm_stream_deferred_total", "stream_deferred", 1.0),
             ("rtpu_llm_stream_lag_seconds_total", "stream_lag_ns", 1e-9),
             ("rtpu_llm_stream_cpu_seconds_total", "stream_cpu_ns", 1e-9)):
         assert _shipped(text, name + '{engine="paged"}') == pytest.approx(
