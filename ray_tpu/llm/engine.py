@@ -41,6 +41,11 @@ class _Request:
     out_logps: list[float] = dataclasses.field(default_factory=list)
     slot: int = -1
     pages: list[int] = dataclasses.field(default_factory=list)
+    # paged engine over a model with sliding-window layers: the window
+    # pool's pages of logical pages wlo, wlo + 1, ... (those behind the
+    # window have been handed back)
+    wpages: list[int] = dataclasses.field(default_factory=list)
+    wlo: int = 0
     prefill_pos: int = 0          # prompt tokens already prefilled
     # prompt tokens the prefix cache served (paged; the request's share of
     # stats["prefix_tokens_saved"], an argument of its llm.prefill span)

@@ -71,12 +71,18 @@ from .tokenizer import get_tokenizer
 def model_module(model_cfg):
     """The module that defines a model config's class: the engine's one
     seam to a model. It calls, by these names and nothing else of the
-    module: ``init``, ``init_paged_cache`` (a list, one dict of named page
-    pools [P, page, ...] a layer, which the engine never interprets),
-    ``decode_paged``, ``prefill_paged_rows``, ``verify_paged_rows``,
-    ``routed_per_token``, ``prefill_attn_step``, ``lora_targets`` and,
-    under a mesh, ``check_mesh`` (which may refuse) and then
-    ``logical_axes`` and ``cache_logical_axes`` (models/llama.py,
+    module: ``init``, ``cache_window`` (keys a sliding layer keeps
+    behind a query; 0 where every layer keeps every key),
+    ``init_paged_cache`` (a list, one dict of named page pools
+    [P, page, ...] a layer, which the engine never interprets: P is
+    ``num_pages``, or ``window_pages`` — the call's last argument, given
+    only where ``cache_window`` is not 0 — in a sliding layer),
+    ``window_ring_pages`` (that case only: the width of the sliding
+    layers' table), ``decode_paged``, ``prefill_paged_rows``,
+    ``verify_paged_rows`` (each takes one block table, or with a window
+    the pair (full, ring)), ``routed_per_token``, ``prefill_attn_step``,
+    ``lora_targets`` and, under a mesh, ``check_mesh`` (which may refuse)
+    and then ``logical_axes`` and ``cache_logical_axes`` (models/llama.py,
     models/mla_moe.py)."""
     return sys.modules[type(model_cfg).__module__]
 
@@ -88,6 +94,14 @@ class PagedEngineConfig:
     max_batch_size: int = 8
     page_size: int = 16
     num_pages: int = 512
+    # a model with sliding-window layers (model_module's cache_window):
+    # pages of the second pool, the one those layers' keys and values
+    # live in. A sequence holds there the pages of one window and of the
+    # dispatch in flight and hands back the ones behind them; what the
+    # prefix cache has published of those stays until the pool's LRU
+    # reclaims it. At least max_batch_size rings (the engine says how
+    # many when it refuses fewer). 0 for every other model.
+    num_window_pages: int = 0
     max_pages_per_seq: int = 64
     # prefill chunk (page multiple); up to prefill_rows chunks per step
     chunk_size: int = 128
@@ -237,6 +251,42 @@ STREAM_COUNTERS = ("stream_chunks", "stream_lag_ns", "stream_first_chunks",
                    "stream_deferred")
 
 
+class _PagePool:
+    """One page-id space: its free list, the references requests hold,
+    the content index of published pages (hash <-> page) and the LRU of
+    published pages nobody holds (``tiers``: reclaimed tier by tier, the
+    least recently parked first in each; ``lru`` is the last tier). Page
+    0 is the space's write sink and is never handed out. The engine has
+    one space (every layer keeps every key), or two: the full layers',
+    and the window layers' with a cold tier before its LRU (a model with
+    sliding layers, PagedInferenceEngine._hand_back)."""
+
+    def __init__(self, num_pages: int, tiers: int = 1):
+        self.num_pages = num_pages
+        self.free = list(range(1, num_pages))
+        self.refs = np.zeros((num_pages,), np.int32)
+        self.hash_to_page: dict[bytes, int] = {}
+        self.page_to_hash: dict[int, bytes] = {}
+        # insertion order = eviction order
+        self.tiers = [OrderedDict() for _ in range(tiers)]
+        self.lru: "OrderedDict[int, None]" = self.tiers[-1]
+
+    def avail(self) -> int:
+        """Pages allocatable right now: truly free + reclaimable."""
+        return len(self.free) + sum(map(len, self.tiers))
+
+    def live(self) -> int:
+        """Pages some request holds."""
+        return self.num_pages - 1 - self.avail()
+
+    def unpark(self, pid: int) -> None:
+        """Take a published page nobody holds out of the reclaimable
+        tiers: it is pinned, or reclaimed."""
+        for tier in self.tiers:
+            if tier.pop(pid, False) is not False:
+                return
+
+
 @dataclasses.dataclass
 class _Launched:
     """A dispatch launched and not yet read back."""
@@ -264,12 +314,28 @@ class PagedInferenceEngine:
         if params is None:
             params = self.model.init(jax.random.PRNGKey(rng_seed), mc)
         self.params = params
-        self.caches = self.model.init_paged_cache(mc, cfg.num_pages,
-                                                  cfg.page_size)
+        # a model with sliding-window layers keeps two kinds of page: the
+        # full layers' (every key of a sequence) and the window layers'
+        # (the last `window` keys; _hand_back). None: one kind
+        self._wpool: Optional[_PagePool] = None
+        self.window = int(self.model.cache_window(mc))
+        if self.window:
+            self._init_window_pool()
+            self.caches = self.model.init_paged_cache(
+                mc, cfg.num_pages, cfg.page_size, cfg.num_window_pages)
+        else:
+            if cfg.num_window_pages:
+                raise ValueError(
+                    "num_window_pages is for a model with sliding-window "
+                    f"layers; {type(mc).__name__} has none")
+            self.caches = self.model.init_paged_cache(mc, cfg.num_pages,
+                                                      cfg.page_size)
+            self._window_layers = [False] * len(self.caches)
         # page 0 is the write sink for slots that are idle during a decode
         # step (their dummy token writes land there, never attended); it is
         # never allocated to a sequence
-        self._free_pages = list(range(1, cfg.num_pages))
+        self._pool = _PagePool(cfg.num_pages)
+        self._free_pages = self._pool.free
         self._free_slots = deque(range(cfg.max_batch_size))
         self._block_tables = np.zeros(
             (cfg.max_batch_size, cfg.max_pages_per_seq), np.int32)
@@ -290,10 +356,11 @@ class PagedInferenceEngine:
         # of returning to _free_pages, and are reclaimed LRU-first when
         # allocation outruns the free list.
         self._prefix_on = bool(cfg.enable_prefix_caching)
-        self._page_refs = np.zeros((cfg.num_pages,), np.int32)
-        self._hash_to_page: dict[bytes, int] = {}
-        self._page_to_hash: dict[int, bytes] = {}
-        self._cached_lru: "OrderedDict[int, None]" = OrderedDict()
+        # the full pool's parts under the names this file has always used
+        self._page_refs = self._pool.refs
+        self._hash_to_page = self._pool.hash_to_page
+        self._page_to_hash = self._pool.page_to_hash
+        self._cached_lru = self._pool.lru
         # cluster prefix-directory delta tracking (serve/frontdoor):
         # hashes registered/unregistered since the last drain. Appended
         # only when track_page_publish is on (the serving layer enables
@@ -309,10 +376,16 @@ class PagedInferenceEngine:
         # never learned fall to the overflow sink on eviction.
         self.chains = None
         self._chain_of: dict[int, int] = {}
-        # bytes one page holds over every pool of every layer
-        self.page_nbytes = page_nbytes = sum(
-            int(pool.nbytes) for layer in self.caches
-            for pool in layer.values()) // max(cfg.num_pages, 1)
+        # bytes one page holds over every pool of every layer (with a
+        # window: of every full layer, what a token of a long sequence
+        # costs for good; window_page_nbytes is what it costs while a
+        # window holds it)
+        per_layer = [sum(int(pool.nbytes) // pool.shape[0]
+                         for pool in layer.values()) for layer in self.caches]
+        self.window_page_nbytes = sum(
+            n for n, w in zip(per_layer, self._window_layers) if w)
+        self.page_nbytes = page_nbytes = \
+            sum(per_layer) - self.window_page_nbytes
         if self._prefix_on and cfg.chain_stats_slots > 0:
             from .chainstats import ChainStatsTable
             self.chains = ChainStatsTable(cfg.chain_stats_slots,
@@ -462,6 +535,24 @@ class PagedInferenceEngine:
         if self.model.routed_per_token(mc):
             self.stats.update(moe_assign_live=0, moe_assign_run=0,
                               moe_expert_load_sum=0, moe_expert_load_max=0)
+        # a model with sliding-window layers: pages claimed from and
+        # handed back to each pool, each pool's live pages summed over
+        # decode dispatches (their mean is the pool's fill), admissions
+        # whose cached prefix was cut short, or lost, for want of the
+        # window layers' pages behind it (and the tokens that cost), and
+        # the window layers' share of the work decided at a dispatch: the
+        # pages a decode step streams of the ring it runs, the pages and
+        # (query, key) pairs of the prefill rows. Other models have no
+        # such keys
+        if self.window:
+            self.stats.update(dict.fromkeys((
+                "window_pages_claimed", "window_pages_returned",
+                "full_pages_claimed", "full_pages_returned",
+                "window_pool_live_pages", "full_pool_live_pages",
+                "window_evictions", "prefix_tail_cut", "prefix_tail_lost",
+                "prefix_tail_tokens_lost", "decode_live_wpages",
+                "decode_table_wpages", "prefill_ctx_wpages",
+                "prefill_attn_wpairs"), 0))
         # speculation controller: EMA of tokens-per-slot-per-spec-dispatch
         # (starts optimistic), plus a cooldown of windowed dispatches
         # before re-probing once the EMA drops below the window
@@ -474,6 +565,137 @@ class PagedInferenceEngine:
         # programs compiled by warmup(): profiler.compiles beyond this
         # count compiled under traffic (profile_summary)
         self.warm_programs = 0
+
+    # -- the second kind of page (a model with sliding-window layers) ------
+
+    def _init_window_pool(self):
+        """The window layers' pool and block tables. A table is a ring
+        (logical page p in column p % width) as wide as a window plus the
+        most one dispatch writes past its oldest query, so its width is
+        one number whatever the context: no page bucket, no program."""
+        cfg, mc = self.cfg, self.cfg.model
+        if cfg.kv_spill:
+            raise ValueError(
+                "kv_spill over a two-kind (window + full) cache: the "
+                "spill tier moves one kind of page (ROADMAP R2)")
+        page = cfg.page_size
+        write = max(cfg.prefill_rows * cfg.chunk_size, cfg.decode_window,
+                    cfg.spec_tokens + 1)
+        self._ring = self.model.window_ring_pages(mc, page, write)
+        # every sequence a ring, and what two prefill dispatches in
+        # flight hold before the first is booked and hands back
+        need = cfg.max_batch_size * self._ring + 2 * -(-write // page) + 1
+        if cfg.num_window_pages < need:
+            raise ValueError(
+                f"num_window_pages={cfg.num_window_pages}: {cfg.max_batch_size}"
+                f" sequences of a {self.window}-key window need {need} "
+                f"pages of {page} (a ring of {self._ring} each)")
+        self._wpool = _PagePool(cfg.num_window_pages, tiers=2)
+        self._wtables = np.zeros((cfg.max_batch_size, self._ring), np.int32)
+        # which layers' pools are the window pool's, told by a probe of
+        # shapes alone: the engine reads nothing else of a cache
+        probe = jax.eval_shape(
+            lambda: self.model.init_paged_cache(mc, 2, page, 1))
+        self._window_layers = [
+            next(iter(layer.values())).shape[0] == 1 for layer in probe]
+
+    def _tables(self, full, rows=None):
+        """What a program takes as its block tables: ``full`` [n, W], and
+        with a window the pair (full, ring [n, ring]) — the rings of
+        engine slots ``rows``, or zeros (warm-up, padding)."""
+        if self._wpool is None:
+            return full
+        ring = np.zeros((len(full), self._ring), np.int32)
+        for i, slot in enumerate(() if rows is None else rows):
+            if slot >= 0:
+                ring[i] = self._wtables[slot]
+        return full, ring
+
+    def _refuse_two_kinds(self, what: str):
+        if self._wpool is not None:
+            raise NotImplementedError(
+                f"{what} over a two-kind (window + full) cache: a payload "
+                "carries one kind of page (ROADMAP R2)")
+
+    def _ensure_window(self, req: _Request, upto_tokens: int) -> bool:
+        """Grow req's window pages to cover upto_tokens; False if that
+        pool is dry (it cannot be while num_window_pages holds its
+        floor). True at once where the model has no window."""
+        pool = self._wpool
+        if pool is None:
+            return True
+        have = req.wlo + len(req.wpages)
+        need = self._pages_needed(upto_tokens) - have
+        if need <= 0:
+            return True
+        if pool.avail() < need:
+            return False
+        ring = self._wtables[req.slot]
+        for p in range(have, have + need):
+            pid = self._pop_free_page(pool)
+            pool.refs[pid] = 1
+            ring[p % self._ring] = pid
+            req.wpages.append(pid)
+        self.stats["window_pages_claimed"] += need
+        return True
+
+    def _hand_back(self, req: _Request, next_pos: int):
+        """Give back req's window pages every key of which is a window or
+        more behind ``next_pos``, the oldest query still to be launched
+        for it. Called from a booking: a dispatch in flight has its own
+        copy of the ring and reads nothing behind its own oldest query,
+        and whoever gets the page next writes it in a later program. A
+        published page parks where the prefix cache can still find it
+        (_cold says in which tier)."""
+        pool, page = self._wpool, self.cfg.page_size
+        n = min(max(next_pos - self.window + 1, 0) // page - req.wlo,
+                len(req.wpages))
+        if n <= 0:
+            return
+        for i, pid in enumerate(req.wpages[:n]):
+            self._decref(pid, pool, self._cold(req, req.wlo + i))
+        del req.wpages[:n]
+        req.wlo += n
+        self.stats["window_pages_returned"] += n
+
+    def _cold(self, req: _Request, logical_page: int) -> bool:
+        """Is a window page worth less than the others once nobody holds
+        it? The pool is a few windows a sequence, so what it keeps
+        unheld has to be chosen. A window page serves the prefix cache
+        only as part of the TAIL of a prefix: the window - 1 keys before
+        the point where a later prompt leaves this one. Cold, reclaimed
+        before any other: a page too far back to be in the tail of a
+        prefix that ends within a window of this prompt's end — where a
+        follow-up's shared prefix ends (the same document and another
+        question, the next turn) — and a page in a sequence's first two
+        windows, which guards a prefix that is cheap to compute again."""
+        first_key = logical_page * self.cfg.page_size
+        return (first_key + self.window < len(req.prompt_ids) - self.window
+                or first_key + self.cfg.page_size <= 2 * self.window)
+
+    def _match_window(self, req: _Request, matched: list[int]) -> tuple:
+        """Cut a cached run of full pages (``_match_prefix``) back to the
+        longest whole-chunk prefix whose window tail is cached too: a
+        prefix of N tokens is a hit only if the window layers' pages over
+        the window - 1 keys before N are held. Returns (the run, the
+        tail's first logical page, the tail's pages, the pages of the run
+        that were cut for want of a tail)."""
+        pool, page = self._wpool, self.cfg.page_size
+        per_chunk = self.cfg.chunk_size // page
+        tail = -(-(self.window - 1) // page)
+        hashes = self._prompt_hashes(req)
+        found = n = len(matched)
+        lo, got = 0, []
+        while n > 0:
+            lo = max(n - tail, 0)
+            got = [pool.hash_to_page.get(hashes[i]) for i in range(lo, n)]
+            gone = [i for i, pid in enumerate(got) if pid is None]
+            if not gone:
+                break
+            # no prefix whose tail holds the newest missing page is a hit
+            n = (lo + gone[-1]) // per_chunk * per_chunk
+        return (matched[:n], lo, got, found - n) if n else (
+            [], 0, [], found)
 
     # -- mesh-parallel placement (cfg.mesh) --------------------------------
 
@@ -819,7 +1041,7 @@ class PagedInferenceEngine:
                         rb, mode, maxp)(
                         self.params, self.caches,
                         np.zeros((rb, c), np.int32),
-                        np.zeros((rb, maxp), np.int32),
+                        self._tables(np.zeros((rb, maxp), np.int32)),
                         np.zeros((rb,), np.int32), np.zeros((rb,), np.int32),
                         key, ctr, np.zeros((rb,), np.float32),
                         np.zeros((rb,), np.int32),
@@ -839,7 +1061,7 @@ class PagedInferenceEngine:
                     out, _lps, _load, self.caches = self._decode_window_fn(
                         w, mode, maxp)(
                         self.params, self.caches, np.zeros((bs,), np.int32),
-                        np.zeros((bs, maxp), np.int32),
+                        self._tables(np.zeros((bs, maxp), np.int32)),
                         np.zeros((bs,), np.int32), key, ctr,
                         np.zeros((bs,), np.float32),
                         np.zeros((bs,), np.int32),
@@ -858,7 +1080,7 @@ class PagedInferenceEngine:
                         rb, s1, maxp)(
                         self.params, self.caches,
                         np.zeros((rb, s1), np.int32),
-                        np.zeros((rb, maxp), np.int32),
+                        self._tables(np.zeros((rb, maxp), np.int32)),
                         np.zeros((rb,), np.int32),
                         *self._lora_args(np.zeros((rb,), np.int32)))
                     np.asarray(y)
@@ -958,11 +1180,19 @@ class PagedInferenceEngine:
         """Pages allocatable right now: truly free + LRU-reclaimable."""
         return len(self._free_pages) + len(self._cached_lru)
 
-    def _pop_free_page(self) -> int:
-        """One allocatable page; evicts the least-recently-used
-        unreferenced cached page when the free list is dry. Never touches
-        a page with live references — only refcount-0 pages sit in the
-        LRU. Callers must check _pages_avail() first."""
+    def _pop_free_page(self, pool: Optional[_PagePool] = None) -> int:
+        """One allocatable page of ``pool`` (the full pool when left out);
+        evicts the least-recently-used unreferenced cached page when the
+        free list is dry. Never touches a page with live references —
+        only refcount-0 pages sit in the LRU. Callers must check the
+        pool's avail() first."""
+        if pool is self._wpool is not None:
+            if pool.free:
+                return pool.free.pop()
+            pid, _ = next(t for t in pool.tiers if t).popitem(last=False)
+            self._unregister(pid, pool)
+            self.stats["window_evictions"] += 1
+            return pid
         if self._free_pages:
             return self._free_pages.pop()
         pid, _ = self._cached_lru.popitem(last=False)
@@ -1022,7 +1252,14 @@ class PagedInferenceEngine:
             if self.chains is not None:
                 self.chains.spilled_sub(chain)
 
-    def _unregister(self, pid: int):
+    def _unregister(self, pid: int, pool: Optional[_PagePool] = None):
+        if pool is self._wpool is not None:
+            # the window pool's index is this engine's alone: no
+            # directory, no chain, no tier hears of it
+            h = pool.page_to_hash.pop(pid, None)
+            if h is not None and pool.hash_to_page.get(h) == pid:
+                del pool.hash_to_page[h]
+            return
         h = self._page_to_hash.pop(pid, None)
         if h is not None and self._hash_to_page.get(h) == pid:
             del self._hash_to_page[h]
@@ -1045,23 +1282,28 @@ class PagedInferenceEngine:
             self.chains.evict(slot)
             flight.evt(flight.PREFIX_EVICT, pid, slot)
 
-    def _incref(self, pid: int):
+    def _incref(self, pid: int, pool: Optional[_PagePool] = None):
         """Pin a page for a request; a cached (refcount-0) page leaves
         the eviction pool."""
-        if self._page_refs[pid] == 0:
-            self._cached_lru.pop(pid, None)
-        self._page_refs[pid] += 1
+        pool = pool or self._pool
+        if pool.refs[pid] == 0:
+            pool.unpark(pid)
+        pool.refs[pid] += 1
 
-    def _decref(self, pid: int):
+    def _decref(self, pid: int, pool: Optional[_PagePool] = None,
+                cold: bool = False):
         """Drop one reference; at zero the page parks in the cached LRU
-        (published content, reusable) or returns to the free list."""
-        self._page_refs[pid] -= 1
-        if self._page_refs[pid] > 0:
+        (published content, reusable; ``cold``: in the tier that is
+        reclaimed first) or returns to the free list."""
+        pool = pool or self._pool
+        pool.refs[pid] -= 1
+        if pool.refs[pid] > 0:
             return
-        if pid in self._page_to_hash:
-            self._cached_lru[pid] = None    # most-recently-released last
+        if pid in pool.page_to_hash:
+            # most-recently-released last
+            (pool.tiers[0] if cold else pool.lru)[pid] = None
         else:
-            self._free_pages.append(pid)
+            pool.free.append(pid)
 
     def _claim_pages(self, matched: list[int],
                      n_pages: int) -> Optional[list[int]]:
@@ -1084,6 +1326,8 @@ class PagedInferenceEngine:
             pid = self._pop_free_page()
             self._page_refs[pid] = 1
             pages.append(pid)
+        if self.window:
+            self.stats["full_pages_claimed"] += n_pages
         return pages
 
     def _ensure_pages(self, req: _Request, upto_tokens: int) -> bool:
@@ -1099,6 +1343,8 @@ class PagedInferenceEngine:
             req.pages.append(pid)
         bt = self._block_tables[req.slot]
         bt[:len(req.pages)] = req.pages
+        if self.window:
+            self.stats["full_pages_claimed"] += need
         return True
 
     def _release(self, req: _Request):
@@ -1106,6 +1352,14 @@ class PagedInferenceEngine:
             self._register_request_pages(req)
         for pid in req.pages:
             self._decref(pid)
+        if self.window:
+            self.stats["full_pages_returned"] += len(req.pages)
+            self.stats["window_pages_returned"] += len(req.wpages)
+            for i, pid in enumerate(req.wpages):
+                self._decref(pid, self._wpool, self._cold(req, req.wlo + i))
+            req.wpages, req.wlo = [], 0
+            if req.slot >= 0:
+                self._wtables[req.slot, :] = 0
         req.pages = []
         if req.slot >= 0:
             # zero the row so nothing stale survives into the next tenant
@@ -1191,6 +1445,21 @@ class PagedInferenceEngine:
             pids = [self._hash_to_page.get(hashes[i]) for i in idxs]
             if any(p is None for p in pids):
                 break
+            if self._wpool is not None:
+                # the chunk's keys enter the window of what follows: its
+                # window pages are mapped in too (the ones before it are
+                # this request's own already), or the chunk is computed
+                wpids = [self._wpool.hash_to_page.get(hashes[i])
+                         for i in idxs]
+                if any(p is None for p in wpids) or \
+                        req.wlo + len(req.wpages) != pos // page:
+                    break
+                for i, pid in zip(idxs, wpids):
+                    self._incref(pid, self._wpool)
+                    self._wtables[req.slot, i % self._ring] = pid
+                    req.wpages.append(pid)
+                self.stats["window_pages_claimed"] += len(wpids)
+                self._hand_back(req, pos + c)
             for i, pid in zip(idxs, pids):
                 old = req.pages[i]
                 if old == pid:
@@ -1208,11 +1477,15 @@ class PagedInferenceEngine:
             req.prefill_pos = pos
             self._block_tables[req.slot, :len(req.pages)] = req.pages
 
-    def _register_page(self, pid: int, h: bytes, chain: int = -1):
-        if pid in self._page_to_hash or h in self._hash_to_page:
+    def _register_page(self, pid: int, h: bytes, chain: int = -1,
+                       pool: Optional[_PagePool] = None):
+        pool = pool or self._pool
+        if pid in pool.page_to_hash or h in pool.hash_to_page:
             return      # already published, or duplicate content elsewhere
-        self._page_to_hash[pid] = h
-        self._hash_to_page[h] = pid
+        pool.page_to_hash[pid] = h
+        pool.hash_to_page[h] = pid
+        if pool is not self._pool:
+            return
         if self.chains is not None and chain >= 0:
             self._chain_of[pid] = chain
             self.chains.resident_add(chain)
@@ -1256,6 +1529,11 @@ class PagedInferenceEngine:
         for i in range(n_full):
             self._register_page(req.pages[i], hashes[i],
                                 chain=req.chain_slot)
+        # the window pages still held: the tail of a prefix that ends
+        # within a window of this sequence's end
+        for i in range(req.wlo, min(n_full, req.wlo + len(req.wpages))):
+            self._register_page(req.wpages[i - req.wlo], hashes[i],
+                                pool=self._wpool)
 
     # -- engine loop -------------------------------------------------------
 
@@ -1355,6 +1633,10 @@ class PagedInferenceEngine:
                     # so the match (and the hit accounting below) sees
                     # them exactly like never-evicted pages
                     matched = self._match_prefix(req)
+                wlo, wmatched, cut = 0, [], 0
+                if self._wpool is not None and matched:
+                    matched, wlo, wmatched, cut = self._match_window(
+                        req, matched)
                 pages = self._claim_pages(
                     matched, self._pages_needed(len(req.prompt_ids) + 1))
                 if pages is None:
@@ -1363,6 +1645,20 @@ class PagedInferenceEngine:
                 req.slot = self._free_slots.popleft()
                 req.pages = pages
                 self._block_tables[req.slot, :len(pages)] = pages
+                if wmatched:
+                    # the tail is pinned; the window pages of what is
+                    # still to be computed are claimed dispatch by
+                    # dispatch (_ensure_window)
+                    for i, pid in enumerate(wmatched):
+                        self._incref(pid, self._wpool)
+                        self._wtables[req.slot, (wlo + i) % self._ring] = pid
+                    req.wlo, req.wpages = wlo, list(wmatched)
+                    self.stats["window_pages_claimed"] += len(wmatched)
+                if cut:
+                    self.stats["prefix_tail_cut" if matched
+                               else "prefix_tail_lost"] += 1
+                    self.stats["prefix_tail_tokens_lost"] += \
+                        cut * self.cfg.page_size
                 if self.chains is not None:
                     hs = self._prompt_hashes(req)
                     if hs:
@@ -1425,6 +1721,8 @@ class PagedInferenceEngine:
                 while pos < len(req.prompt_ids) and \
                         len(rows) < cfg.prefill_rows:
                     n = min(c, len(req.prompt_ids) - pos)
+                    if not self._ensure_window(req, pos + n):
+                        break
                     rows.append((req, pos, n))
                     pos += n
                 if len(rows) >= cfg.prefill_rows:
@@ -1463,10 +1761,11 @@ class PagedInferenceEngine:
                 req.prefill_pos = pos + n
             mode = self._sampling_mode([q for q, _, _ in rows])
             fn = self._prefill_rows_fn(rb, mode, W)
+            tables = self._tables(bts, [q.slot for q, _, _ in rows])
         with self._launch("prefill"):
             with self.profiler.step("prefill", (rb, mode, W)):
                 toks, lps, load, self.caches = fn(
-                    self.params, self.caches, chunks, bts, sps, tls,
+                    self.params, self.caches, chunks, tables, sps, tls,
                     self._rng_base, np.int32(self._rng_ctr), temps, topks,
                     *self._lora_args(lslots))
             self._launched(
@@ -1505,6 +1804,8 @@ class PagedInferenceEngine:
                               if x is not None))
         if self._prefix_on:
             self._publish_prefilled(rows)
+        if self.window:
+            self._book_window_rows(rows)
         for i, (req, pos, n) in enumerate(rows):
             if pos + n >= len(req.prompt_ids):
                 # prompt done: the row's in-jit sampled token is the
@@ -1515,6 +1816,21 @@ class PagedInferenceEngine:
         # NOTE: pad positions of the final chunk were written into the
         # sequence's own pages beyond its true length; decode masks
         # positions >= length so they are never attended.
+
+    def _book_window_rows(self, rows):
+        """The window layers' part of a prefill booking: the pages and
+        (query, key) pairs the rows' window kernel swept, and the pages
+        the rows have moved past, handed back."""
+        st, pg, win = self.stats, self.cfg.page_size, self.window
+        for req, pos, n in rows:
+            st["prefill_ctx_wpages"] += (
+                (pos + n - 1) // pg - max(pos - win + 1, 0) // pg + 1)
+            # query q attends min(q + 1, window) keys
+            ramp = min(max(win - 1 - pos, 0), n)
+            st["prefill_attn_wpairs"] += (
+                ramp * pos + ramp * (ramp + 1) // 2 + (n - ramp) * win)
+            if req.slot >= 0:       # not retired since its launch
+                self._hand_back(req, pos + n)
 
     def _publish_prefilled(self, rows):
         """Full prompt pages the dispatch's rows computed are misses;
@@ -1531,6 +1847,9 @@ class PagedInferenceEngine:
             for j in range(lo, hi):
                 self._register_page(req.pages[j], hashes[j],
                                     chain=req.chain_slot)
+                if self._wpool is not None and j >= req.wlo:
+                    self._register_page(req.wpages[j - req.wlo], hashes[j],
+                                        pool=self._wpool)
 
     def _first_token(self, req: _Request, tok: int, lp: Optional[float]):
         """A prompt's last chunk returned: book its first generated
@@ -1626,6 +1945,14 @@ class PagedInferenceEngine:
         page = self.cfg.page_size
         return int(sum(-(-int(self._lengths[sl]) // page) for sl in slots))
 
+    def _live_window_pages(self, slots) -> int:
+        """Window pages one decode step's window kernel streams: those
+        that hold the ``window`` keys up to each slot's current token."""
+        page, win = self.cfg.page_size, self.window
+        return int(sum(
+            int(n) // page - max(int(n) + 1 - win, 0) // page + 1
+            for n in (self._lengths[sl] for sl in slots)))
+
     def _spec_step(self) -> bool:
         """One speculative verify dispatch over every active slot. Only
         runs when every slot is greedy (the accept rule reproduces exact
@@ -1682,10 +2009,11 @@ class PagedInferenceEngine:
                 lslots[i] = req.adapter_slot
             want_lp = any(self._active[sl].params.logprobs for sl in slots)
             fn = self._verify_fn(rb, s1, W, want_lp)
+            tables = self._tables(bts, slots)
         with self._launch("decode"):
             with self.profiler.step("verify", (rb, s1, W, want_lp)):
                 y, ylp, load, self.caches = fn(
-                    self.params, self.caches, toks, bts, starts,
+                    self.params, self.caches, toks, tables, starts,
                     *self._lora_args(lslots))
             self._notify_launch()
         with self._phase("ns_decode_device"):
@@ -1732,6 +2060,8 @@ class PagedInferenceEngine:
                     if self._stop_after(req, tok):
                         self._retire(req)
                         break
+                if self.window and req.slot >= 0:
+                    self._hand_back(req, int(self._lengths[slot]))
                 emitted += consumed
             # controller: keep speculating only while it beats the window;
             # on fallback, re-probe optimistically after a cooldown that
@@ -1801,12 +2131,20 @@ class PagedInferenceEngine:
                 lslots[slot] = req.adapter_slot
             mode = self._sampling_mode(reqs.values())
             fn = self._decode_window_fn(w, mode, W)
+            tables = self._tables(
+                bt, [sl if sl in reqs else -1 for sl in range(bs)])
         with self._launch("decode"):
             with self.profiler.step("decode", (w, mode, W)):
                 out, lps, load, self.caches = fn(
-                    self.params, self.caches, tokens, bt, lengths,
+                    self.params, self.caches, tokens, tables, lengths,
                     self._rng_base, np.int32(self._rng_ctr), temps, topks,
                     *self._lora_args(lslots))
+            if self.window:
+                # counted at the launch, as live_pages is: what the
+                # program streams of the ring it runs
+                self.stats["decode_live_wpages"] += \
+                    self._live_window_pages(reqs)
+                self.stats["decode_table_wpages"] += bs * self._ring
             self._launched(
                 "decode", (out, lps, load), reqs=reqs, allow=allow, w=w,
                 live_pages=self._live_pages(reqs), table_pages=bt.size,
@@ -1851,6 +2189,11 @@ class PagedInferenceEngine:
                 if self._stop_after(req, tok):
                     self._retire(req)
                     break
+            if self.window and req.slot >= 0:
+                self._hand_back(req, int(self._lengths[slot]))
+        if self.window:
+            st["window_pool_live_pages"] += self._wpool.live()
+            st["full_pool_live_pages"] += self._pool.live()
 
     def _reserve(self, req: _Request, width: int) -> int:
         """Pre-allocate pages for up to `width` new tokens and return how
@@ -1867,9 +2210,13 @@ class PagedInferenceEngine:
         total = len(req.prompt_ids) + len(req.out_ids)
         remaining = max(req.params.max_tokens - len(req.out_ids), 1)
         target = min(total + min(width, remaining), self.cfg.max_seq_len)
-        if self._ensure_pages(req, target):
+        if self._ensure_pages(req, target) and \
+                self._ensure_window(req, target):
             return target - total
-        return max(len(req.pages) * self.cfg.page_size - total, 0)
+        held = len(req.pages)
+        if self.window:
+            held = min(held, req.wlo + len(req.wpages))
+        return max(held * self.cfg.page_size - total, 0)
 
     def _stop_after(self, req: _Request, tok: int) -> bool:
         """Stop condition evaluated after appending tok to req.out_ids."""
@@ -1899,7 +2246,8 @@ class PagedInferenceEngine:
         if not stop:
             # growing by one token may need one more page
             total = len(req.prompt_ids) + len(req.out_ids)
-            if not self._ensure_pages(req, total + 1):
+            if not (self._ensure_pages(req, total + 1)
+                    and self._ensure_window(req, total + 1)):
                 stop = True  # pool exhausted: finish early rather than wedge
                 telemetry.on_preempted(self)
         if stop:
@@ -1929,6 +2277,7 @@ class PagedInferenceEngine:
     def prefill_export(self, prompt, params: SamplingParams) -> dict:
         """Chunked-prefill `prompt` and return its exported KV payload
         (drives the engine loop until the export is ready)."""
+        self._refuse_two_kinds("prefill_export")
         req = self.submit(prompt, params)
         req.prefill_only = True
         req.export_payload = None
@@ -1946,6 +2295,7 @@ class PagedInferenceEngine:
         allocate slot+pages, scatter the page data into this engine's
         pools, and place the request directly in the decode set."""
         import time
+        self._refuse_two_kinds("import_prefill")
         if payload["page_size"] != self.cfg.page_size:
             raise ValueError(
                 f"page_size mismatch: payload {payload['page_size']} vs "
@@ -2111,6 +2461,7 @@ class PagedInferenceEngine:
         since publishing). CALLER must serialize against the stepping
         thread (serving.py's step lock): dispatches donate self.caches,
         so a concurrent step would invalidate the buffers mid-gather."""
+        self._refuse_two_kinds("export_prefix")
         with self._lock:
             pids: list[int] = []
             for h in hashes:
@@ -2147,6 +2498,7 @@ class PagedInferenceEngine:
         _import_fn donates the cache pools)."""
         if payload is None or not self._prefix_on:
             return 0
+        self._refuse_two_kinds("import_prefix")
         if payload["page_size"] != self.cfg.page_size:
             raise ValueError(
                 f"page_size mismatch: payload {payload['page_size']} vs "
@@ -2393,6 +2745,12 @@ class PagedInferenceEngine:
             "active": len(self._active),
             "prefilling": len(self._prefilling),
             "pending": len(self._pending),
+            # the second pool of a model with sliding-window layers
+            **({} if self._wpool is None else {
+                "window_free_pages": len(self._wpool.free),
+                "window_cached_pages": sum(map(len, self._wpool.tiers)),
+                "window_total_pages": self._wpool.num_pages,
+                "window_ring_pages": self._ring}),
             **self.stats,
         }
 

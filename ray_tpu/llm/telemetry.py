@@ -33,6 +33,11 @@ Metric names (all prefixed ``rtpu_llm_``):
   e2e_seconds            histogram  submit -> request retired
   batch_occupancy        gauge      active slots / max_batch_size
   kv_utilization         gauge      KV pages in use / pool size
+  kv_window_utilization  gauge      the same of the second pool of a
+      model with sliding-window layers (its window-layer pages)
+  kv_pages_claimed_total / kv_pages_returned_total  counter  pages
+      requests claimed / gave back, by ``pool`` (full, window); such a
+      model only, as prefix_window_*, prefix_tail_*
   pending_requests       gauge      submitted, not yet admitted
   prefilling_requests    gauge      admitted, prompt not fully prefilled
   decoding_requests      gauge      in the decode set
@@ -272,6 +277,16 @@ def on_step(engine) -> None:
            "KV pages in use / pool size").set(
         (pool - len(engine._free_pages) - len(engine._cached_lru))
         / max(pool, 1), tags=gtags)
+    wpool = getattr(engine, "_wpool", None)
+    if wpool is not None:
+        # a model with sliding-window layers: its second pool, the same
+        # way (held pages / pool size; parked prefix pages are capacity)
+        _gauge("rtpu_llm_kv_window_utilization",
+               "window-layer KV pages in use / window pool size").set(
+            wpool.live() / max(wpool.num_pages - 1, 1), tags=gtags)
+        _gauge("rtpu_llm_prefix_window_cached_pages",
+               "unreferenced window-layer pages retained as prefix "
+               "tails").set(sum(map(len, wpool.tiers)), tags=gtags)
     if engine._prefix_on:
         # single accounting source (paged_engine.prefix_accounting):
         # the gauges here, pool_stats() and metrics_summary() must
@@ -334,6 +349,30 @@ _STAT_COUNTERS = (
      "pages seeded from another replica's export", None),
     ("prefix_exported_pages", "rtpu_llm_prefix_cache_exported_pages_total",
      "cached pages gathered to host for another replica", None),
+    # a model with sliding-window layers (two page pools): pages claimed
+    # and handed back a pool kind, and prefix hits the window layers cut
+    ("window_pages_claimed", "rtpu_llm_kv_pages_claimed_total",
+     "KV pages claimed by requests, by pool kind", ("pool", "window")),
+    ("full_pages_claimed", "rtpu_llm_kv_pages_claimed_total",
+     "KV pages claimed by requests, by pool kind", ("pool", "full")),
+    ("window_pages_returned", "rtpu_llm_kv_pages_returned_total",
+     "KV pages requests gave back (window pages: as the window moved "
+     "past them), by pool kind", ("pool", "window")),
+    ("full_pages_returned", "rtpu_llm_kv_pages_returned_total",
+     "KV pages requests gave back (window pages: as the window moved "
+     "past them), by pool kind", ("pool", "full")),
+    ("window_evictions", "rtpu_llm_prefix_window_evictions_total",
+     "cached window-layer pages reclaimed under allocation pressure",
+     None),
+    ("prefix_tail_cut", "rtpu_llm_prefix_tail_misses_total",
+     "admissions whose cached prefix was cut short (cut) or lost for "
+     "want of the window layers' pages behind it", ("outcome", "cut")),
+    ("prefix_tail_lost", "rtpu_llm_prefix_tail_misses_total",
+     "admissions whose cached prefix was cut short (cut) or lost for "
+     "want of the window layers' pages behind it", ("outcome", "lost")),
+    ("prefix_tail_tokens_lost", "rtpu_llm_prefix_tail_tokens_lost_total",
+     "prompt tokens the full layers' cache covered and a missing "
+     "window tail had prefilled again", None),
     # spill tier (cfg.kv_spill, llm/tiering.py) — the
     # rtpu_llm_prefix_spill_* family; engine.stats is the single source
     ("spill_pages", "rtpu_llm_prefix_spill_pages_total",

@@ -184,6 +184,11 @@ _NO_MESH = ("PagedEngineConfig.mesh: models/mla_moe.py has no sharding "
             "mesh=None")
 
 
+def cache_window(cfg: MlaMoeConfig) -> int:
+    """Every layer keeps every key: one kind of page pool."""
+    return 0
+
+
 def check_mesh(cfg: MlaMoeConfig, sizes: dict) -> None:
     """The engine asks this before ``logical_axes`` and
     ``cache_logical_axes``, which this module therefore does not have."""

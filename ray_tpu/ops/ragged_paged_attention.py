@@ -145,7 +145,7 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
                    q_ref, *refs,
                    scale: float, page_size: int, num_kv_heads: int,
                    groups: int, q_tile: int, block_pages: int,
-                   v_width: int | None):
+                   v_width: int | None, window: int | None = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -161,9 +161,9 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
 
     r = pl.program_id(0)
     t = pl.program_id(1)
-    b = pl.program_id(2)
+    step = pl.program_id(2)
 
-    @pl.when(b == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
@@ -174,12 +174,28 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
     kv_len = start + q_len                 # positions < kv_len are live
     tile_start = start + t * q_tile        # position of the tile's query 0
     n_pages = _tile_pages(start, q_len, t, q_tile, page_size)
-    # live blocks of this sweep; never more than the grid has steps, so
-    # no copy is started that no step waits for
-    n_blocks = jnp.minimum((n_pages + block_pages - 1) // block_pages,
-                           pl.num_programs(2))
-    last_page = jnp.maximum(jnp.minimum(n_pages, bt_ref.shape[1]) - 1, 0)
     block_keys = block_pages * page_size
+    if window is None:
+        # the sweep starts at the table's first page: grid step = block
+        sweep_first, b = 0, step
+        # live blocks of this sweep; never more than the grid has steps,
+        # so no copy is started that no step waits for
+        n_blocks = jnp.minimum((n_pages + block_pages - 1) // block_pages,
+                               pl.num_programs(2))
+        last_page = jnp.maximum(
+            jnp.minimum(n_pages, bt_ref.shape[1]) - 1, 0)
+    else:
+        # the window form: the sweep starts at the block that holds the
+        # first key the tile's first query attends, grid step 0 is that
+        # block, and the table is a ring (logical page p in column
+        # p % width), so no column bounds a logical index
+        sweep_first = _window_first_page(tile_start, window, page_size)
+        b0 = sweep_first // block_pages
+        b = step + b0
+        n_blocks = b0 + jnp.clip(
+            (n_pages + block_pages - 1) // block_pages - b0, 0,
+            pl.num_programs(2))
+        last_page = jnp.maximum(n_pages - 1, sweep_first)
     heads = num_kv_heads * groups
     d = q_ref.shape[-1]
     d_v = d if v_width is None else v_width
@@ -193,15 +209,19 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
         """The page copies (one a pool a page) that fill ``lane_pages``
         pages from ``first_page`` on of buffer ``slot`` with logical
         pages [block * block_pages + first_page, ...) of row r, clamped
-        to the tile's last live page: a page the row does not own is
-        never read (the table's tail is stale, or the poisoned sink), and
-        the duplicates in a partly live block sit at key positions the
-        predicate below masks."""
+        to the tile's last live page (and, in the window form, to its
+        first): a page the row does not own is never read (the table's
+        tail is stale, or the poisoned sink, or a page behind the window
+        that was handed back), and the duplicates in a partly live block
+        sit at key positions the predicate below masks."""
         out = []
         for j in range(lane_pages):
             page = first_page + j
-            phys = bt_ref[r, jnp.minimum(block * block_pages + page,
-                                         last_page)]
+            logical = jnp.minimum(block * block_pages + page, last_page)
+            if window is not None:
+                logical = jnp.maximum(logical, sweep_first) \
+                    % bt_ref.shape[1]
+            phys = bt_ref[r, logical]
             rows = pl.ds(page * page_size, page_size)
             out.append(pltpu.make_async_copy(
                 k_hbm.at[phys], k_buf.at[slot, rows], sems.at[0, slot]))
@@ -236,15 +256,18 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
             jnp.int32, (rows, block_keys), 0) // rows_per_query
         k_pos = b * block_keys + jax.lax.broadcasted_iota(
             jnp.int32, (rows, block_keys), 1)
-        return (k_pos <= q_pos) & (k_pos < kv_len)
+        keep = (k_pos <= q_pos) & (k_pos < kv_len)
+        if window is not None:
+            keep &= q_pos - k_pos < window
+        return keep
 
     @pl.when(b < n_blocks)
     def _compute():
-        slot = b % 2
+        slot = step % 2
 
-        @pl.when(b == 0)
+        @pl.when(step == 0)
         def _first():
-            _each_copy(0, 0, lambda c: c.start())
+            _each_copy(b, 0, lambda c: c.start())
 
         @pl.when(b + 1 < n_blocks)
         def _prefetch():
@@ -304,6 +327,12 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
         # sweep. Each body reads q from its ref itself: a value loaded
         # above the branch is copied whole before it (1.3 MB at the
         # latent tile: measured slower than never skipping)
+        if window is not None:
+            # a window's sweep is a handful of blocks whose first and last
+            # are masked: one body, half the kernel to compile in each of
+            # the ladder's prefill programs (1.7 -> 1.1 s, sandbox, PR 40)
+            _heads(_keep(qg, groups))
+            return
         in_prefix = b < _prefix_blocks(start, q_len, t, q_tile, block_keys)
 
         @pl.when(in_prefix)
@@ -314,7 +343,7 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
         def _masked():
             _heads(_keep(qg, groups))
 
-    @pl.when(b == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         if all_heads:
             o = acc_ref[0] / jnp.maximum(l_ref[0], 1e-30)  # [Q*H, D]
@@ -371,6 +400,29 @@ def _tile_pages(start, q_len, t, q_tile: int, page_size: int, xp=jnp):
     live = xp.minimum(start + q_len, start + (t + 1) * q_tile)
     return xp.where(t * q_tile < q_len,
                     (live + page_size - 1) // page_size, 0)
+
+
+def _window_first_page(tile_start, window: int, page_size: int):
+    """First logical page a sweep of the window form reads: the one that
+    holds key ``tile_start - window + 1``, the oldest the tile's first
+    query attends. Whoever owns the row's pages keeps every page from
+    this one on (llm/paged_engine.py hands back the ones before it)."""
+    return jnp.maximum(tile_start - window + 1, 0) // page_size
+
+
+def window_sweep_steps(q_tile: int, window: int, block_keys: int) -> int:
+    """Grid steps a window-form sweep needs at most: the blocks that keys
+    ``[first query - window + 1, last query]`` of one tile can touch."""
+    return (window + q_tile - 2) // block_keys + 2
+
+
+def window_table_pages(window: int, page_size: int, write_tokens: int) -> int:
+    """Width of a window layer's block table, a RING (logical page ``p``
+    in column ``p % width``): the pages of one window behind the oldest
+    query of a dispatch, of the ``write_tokens`` the dispatch may write
+    past it, and one more for the two ends falling inside pages. A column
+    is then reused only by a page a whole window behind every query."""
+    return -(-(window + write_tokens) // page_size) + 1
 
 
 def _prefix_blocks(start, q_len, t, q_tile: int, block_keys: int, xp=jnp):
@@ -446,7 +498,8 @@ def _step_vmem_bytes(q_tile: int, heads: int, kv_heads: int, d: int,
 
 def window_step(q_window: int, heads: int, kv_heads: int, d: int, *,
                 page_size: int, table_pages: int, itemsize: int,
-                v_width: int | None = None) -> dict:
+                v_width: int | None = None,
+                window: int | None = None) -> dict:
     """{'q_tile', 'block_keys'} of the call the family makes at these
     static shapes (``v_width`` set: the latent form, one kv head as wide
     as q) — for the wrappers below, and for whoever counts the steps such
@@ -456,13 +509,16 @@ def window_step(q_window: int, heads: int, kv_heads: int, d: int, *,
     per-kv-head body takes the widest block, doubling from 128 keys,
     within the three limits above — and none of whose halves covers the
     whole table: a 512-key block over a 16-page table would copy and
-    score what no row holds."""
+    score what no row holds. The window form's sweep starts and ends
+    inside blocks, so a block is at most a quarter of the window: wider
+    ones score more masked keys than they save steps."""
     latent = v_width is not None
     q_tile = _q_tile(q_window, heads, d, latent)
     keys = _lane_block_keys(page_size)
     if not latent and _all_heads(q_tile, heads // kv_heads):
         return dict(q_tile=q_tile, block_keys=keys)
     while (2 * keys <= _MAX_BLOCK_KEYS
+           and (window is None or 2 * keys <= max(window // 4, keys))
            and keys < table_pages * page_size
            and q_tile * heads // kv_heads * 2 * keys <= _SCORE_TILE_ELEMS
            and _step_vmem_bytes(q_tile, heads, kv_heads, d,
@@ -474,7 +530,8 @@ def window_step(q_window: int, heads: int, kv_heads: int, d: int, *,
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
                            q_lens, *, scale: float | None = None,
-                           interpret: bool = False):
+                           interpret: bool = False,
+                           window: int | None = None):
     """q [R, Q, H, D]; k_pages/v_pages [P, page, KVH * D] (head ``h`` in
     lanes ``[h * D, (h + 1) * D)`` of the last axis);
     block_tables [R, max_pages] int32 (physical page per logical page);
@@ -487,16 +544,30 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
     positions ``i >= q_lens[r]`` produce garbage outputs the caller
     discards (their compute is bounded by the row's live pages). Returns
     [R, Q, H, D].
+
+    ``window`` (the window form, a sliding-window layer): a query also
+    attends only keys less than ``window`` positions behind it, the sweep
+    starts at the page of the oldest such key (`_window_first_page`), and
+    ``block_tables`` is a ring at least `window_table_pages` wide: logical
+    page ``p`` sits in column ``p % width``, and pages before the sweep's
+    first are never read, so their columns may hold anything.
     """
     _, qw, h, d = q.shape
     _, page_size, kv_lanes = k_pages.shape
+    step = window_step(
+        qw, h, kv_lanes // d, d, page_size=page_size,
+        table_pages=block_tables.shape[1],
+        itemsize=k_pages.dtype.itemsize, window=window)
+    if window is not None and block_tables.shape[1] < window_table_pages(
+            window, page_size, qw):
+        raise ValueError(
+            f"a window of {window} keys under {qw} queries needs a ring of "
+            f"{window_table_pages(window, page_size, qw)} pages of "
+            f"{page_size} or more; the table has {block_tables.shape[1]}")
     return _ragged_call(
         q, k_pages, v_pages, block_tables, starts, q_lens,
         scale=float(d ** -0.5 if scale is None else scale),
-        interpret=interpret, **window_step(
-            qw, h, kv_lanes // d, d, page_size=page_size,
-            table_pages=block_tables.shape[1],
-            itemsize=k_pages.dtype.itemsize))
+        interpret=interpret, window=window, **step)
 
 
 # Jitted so that a program traces and lowers the kernel body once per
@@ -504,10 +575,12 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, starts,
 # Python, and 20 lowerings of this body are 3.7-5.6 s of every program's
 # set-up where one shared function is 0.1-0.2 s (sandbox, PR 25).
 @functools.partial(jax.jit, static_argnames=("scale", "q_tile", "block_keys",
-                                             "interpret", "v_width"))
+                                             "interpret", "v_width",
+                                             "window"))
 def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
                  scale: float, q_tile: int, block_keys: int,
-                 interpret: bool, v_width: int | None = None):
+                 interpret: bool, v_width: int | None = None,
+                 window: int | None = None):
     """``v_pages`` None is the latent form (ragged_latent_attention): one
     pool, one shared kv head as wide as q, values its first ``v_width``
     lanes."""
@@ -530,7 +603,7 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
     kernel = functools.partial(
         _ragged_kernel, scale=scale, page_size=page_size,
         num_kv_heads=kvh, groups=groups, q_tile=q_tile, block_pages=nb,
-        v_width=v_width if latent else None)
+        v_width=v_width if latent else None, window=window)
 
     def _q_index(ri, t, b, bt, start, qlen):
         return (ri, t, 0, 0)
@@ -543,7 +616,10 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
     slots, rows = (1, q_tile * h) if all_heads else (kvh, q_tile * groups)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(r, n_tiles, -(-block_tables.shape[1] // nb)),
+        # the window form sweeps one window's blocks, however wide its
+        # ring is
+        grid=(r, n_tiles, -(-block_tables.shape[1] // nb) if window is None
+              else window_sweep_steps(q_tile, window, nb * page_size)),
         in_specs=[pl.BlockSpec((None, q_tile, h, d), _q_index)]
         + [pool_in_hbm] * len(pools),
         out_specs=pl.BlockSpec((None, q_tile, h, d_v), _q_index),
@@ -565,7 +641,8 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
             vmem_limit_bytes=_VMEM_BUDGET),
         # the trace reduction tells the family by this prefix and its two
         # shapes by the output's window (benchmarks/reduce/)
-        name="ragged_paged_attention" + ("_latent" if latent else ""),
+        name="ragged_paged_attention" + (
+            "_latent" if latent else "" if window is None else "_window"),
     )(block_tables, starts, q_lens, q, *pools)
     return out[:, :qw] if padded != qw else out
 
@@ -594,7 +671,8 @@ def ragged_latent_attention(q, pages, block_tables, starts, q_lens, *,
 
 def ragged_decode_attention(q, k_pages, v_pages, block_table, lengths,
                             *, scale: float | None = None,
-                            interpret: bool = False):
+                            interpret: bool = False,
+                            window: int | None = None):
     """Decode as the q_len=1 degenerate case: q [B, H, D], k_pages /
     v_pages [num_pages, page_size, KVH * D], block_table [B, max_pages],
     lengths [B] = tokens in cache INCLUDING the current step's (attend
@@ -604,12 +682,26 @@ def ragged_decode_attention(q, k_pages, v_pages, block_table, lengths,
         q[:, None], k_pages, v_pages, block_table,
         starts=jnp.maximum(lengths - 1, 0),
         q_lens=jnp.minimum(lengths, 1),     # length 0 rows = padding
-        scale=scale, interpret=interpret)
+        scale=scale, interpret=interpret, window=window)
     return out[:, 0]
 
 
+def _ring_key_positions(kv_len, table_pages: int, page_size: int):
+    """[R, table_pages * page] key position each lane of a gathered RING
+    table holds (logical page ``p`` in column ``p % table_pages``): the
+    newest page congruent to the column at or below the row's last live
+    one; negative where no page has reached the column yet."""
+    last = (jnp.maximum(kv_len, 1) - 1) // page_size          # [R]
+    col = jnp.arange(table_pages)[None, :]
+    logical = last[:, None] - (last[:, None] - col) % table_pages
+    return (logical[:, :, None] * page_size
+            + jnp.arange(page_size)[None, None, :]).reshape(
+                kv_len.shape[0], -1)
+
+
 def ragged_paged_reference(q, k_pages, v_pages, block_tables, starts,
-                           q_lens, scale: float | None = None):
+                           q_lens, scale: float | None = None,
+                           window: int | None = None):
     """Numerical oracle (jnp gather, grouped-GQA einsum — no repeat).
     Same contract as the kernel; masks exactly the kernel's live-key
     predicate, so outputs match at every query position i < q_lens[r]."""
@@ -626,11 +718,14 @@ def ragged_paged_reference(q, k_pages, v_pages, block_tables, starts,
     qg = q.reshape(r, qw, kvh, groups, d).astype(jnp.float32)
     s = jnp.einsum("rqhgd,rkhd->rhgqk", qg,
                    k.astype(jnp.float32)) * scale
-    k_pos = jnp.arange(klen)
     q_pos = starts[:, None] + jnp.arange(qw)[None, :]
     kv_len = starts + q_lens
-    keep = (k_pos[None, None, :] <= q_pos[:, :, None]) & \
-        (k_pos[None, None, :] < kv_len[:, None, None])    # [R, Q, K]
+    k_pos = (jnp.arange(klen)[None] if window is None else
+             _ring_key_positions(kv_len, max_pages, page_size))[:, None, :]
+    keep = (k_pos <= q_pos[:, :, None]) & \
+        (k_pos < kv_len[:, None, None])                   # [R, Q, K]
+    if window is not None:
+        keep &= (k_pos >= 0) & (q_pos[:, :, None] - k_pos < window)
     s = jnp.where(keep[:, None, None], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("rhgqk,rkhd->rqhgd", w, v.astype(jnp.float32))
@@ -647,7 +742,8 @@ def ragged_latent_reference(q, pages, block_tables, starts, q_lens, *,
 
 
 def paged_decode_reference(q, k_pages, v_pages, block_table, lengths,
-                           scale: float | None = None):
+                           scale: float | None = None,
+                           window: int | None = None):
     """Numerical oracle of the decode shape (jnp gather) and
     llama.decode_paged's fallback off the TPU. Same contract as
     ragged_decode_attention.
@@ -669,8 +765,14 @@ def paged_decode_reference(q, k_pages, v_pages, block_table, lengths,
     qg = q.reshape(b, kvh, groups, d).astype(jnp.float32)
     s = jnp.einsum("bhgd,bkhd->bhgk", qg,
                    k.astype(jnp.float32)) * scale
-    pos = jnp.arange(max_pages * page_size)[None, :]
-    s = jnp.where((pos < lengths[:, None])[:, None, None], s, NEG_INF)
+    if window is None:
+        pos = jnp.arange(max_pages * page_size)[None, :]
+        keep = pos < lengths[:, None]
+    else:
+        pos = _ring_key_positions(lengths, max_pages, page_size)
+        keep = (pos >= 0) & (pos < lengths[:, None]) & \
+            (lengths[:, None] - 1 - pos < window)
+    s = jnp.where(keep[:, None, None], s, NEG_INF)
     w = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhgk,bkhd->bhgd", w,
                       v.astype(jnp.float32)).reshape(b, h, d).astype(q.dtype)

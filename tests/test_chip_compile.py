@@ -403,3 +403,118 @@ def test_prefill_r4_program_compiles_at_the_cells_widths(
     assert jax.tree.leaves(compiled.out_info)[0].shape == (
         rows, mc.vocab_size)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# ---------------------------------------------------------------------------
+# Mellum2 (mellum-mixed-queue-1chip): the window form and the programs
+# ---------------------------------------------------------------------------
+
+MELLUM = dict(vocab_size=98304, dim=2304, n_heads=32, n_kv_heads=4,
+              head_dim=128, mlp_dim=896, moe_experts=64, moe_top_k=8,
+              moe_renormalize=True, sliding_window=1024, norm_eps=1e-6,
+              max_seq_len=131072, rope_theta=5e5)
+# window 1,024 + prefill_rows 4 x chunk 128 + a page, in pages of 16
+MELLUM_RING = 97
+
+
+@pytest.mark.parametrize("rows,q_window", [(32, 1), (4, 128)],
+                         ids=["decode", "prefill"])
+def test_window_form_compiles_at_the_mellum_cells_shapes(v5e, rows,
+                                                         q_window):
+    """The sliding layers' kernel of `mellum-mixed-queue-1chip`: 32 / 4
+    heads of 128 over the fixed 97-page ring (the same at every context),
+    32 decode rows and four 128-token chunk-rows, under a name of its own
+    (the trace reduction tells the two kinds of layer by it). The sweep's
+    grid depends on the window alone: 9 steps of 128 keys a decode row, 6
+    of 256 a 64-query prefill tile."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    one = SingleDeviceSharding(v5e.devices[0])
+    text = _compile(
+        functools.partial(ragged_paged_attention, window=1024),
+        *_paged_shapes(rows, q_window, 16, MELLUM_RING, pool=4096, h=32,
+                       kvh=4), sharding=[one] * 6).as_text()
+    assert re.search(rf"%ragged_paged_attention_window[.\d]* = "
+                     rf"bf16\[{rows},{q_window},32,{D}\].*tpu_custom_call",
+                     text)
+    step = rpa.window_step(q_window, 32, 4, D, page_size=16,
+                           table_pages=MELLUM_RING, itemsize=2, window=1024)
+    assert step == ({"q_tile": 1, "block_keys": 128} if q_window == 1
+                    else {"q_tile": 64, "block_keys": 256})
+    assert rpa.window_sweep_steps(step["q_tile"], 1024,
+                                  step["block_keys"]) == (
+        9 if q_window == 1 else 6)
+    assert rpa.window_table_pages(1024, 16, 4 * 128) == MELLUM_RING
+
+
+def test_full_layers_table_compiles_at_the_mellum_cells_heads(v5e):
+    """The full layers' kernel at 32 / 4 heads over the 1,024-page table
+    (the widest bucket): the plain form, eight query heads a KV head."""
+    one = SingleDeviceSharding(v5e.devices[0])
+    for rows, q_window in ((32, 1), (1, 128)):
+        text = _compile(
+            ragged_paged_attention,
+            *_paged_shapes(rows, q_window, 16, 1024, pool=25600, h=32,
+                           kvh=4), sharding=[one] * 6).as_text()
+        assert re.search(rf"%ragged_paged_attention[.\d]* = "
+                         rf"bf16\[{rows},{q_window},32,{D}\]", text)
+
+
+@pytest.mark.parametrize("family", ["prefill_r4", "decode_w1"])
+def test_mellum_programs_compile_at_the_cells_widths(v5e, monkeypatch,
+                                                     family):
+    """`rtpu_prefill_r4` and `rtpu_decode_w1` bodies of
+    `mellum-mixed-queue-1chip` at one period of layers (three sliding, one
+    full): hidden 2304 = 18 lanes of 128, experts of 896 = 7, q of 4096
+    under a hidden size of 2304, two pools of two sizes, a pair of tables.
+    Each kind of layer calls its own kernel, once a row in prefill."""
+    from ray_tpu.models import llama
+
+    one = SingleDeviceSharding(v5e.devices[0])
+    mc = llama.LlamaConfig(
+        n_layers=4, layer_types=("sliding",) * 3 + ("full",),
+        rope_yarn=llama.Yarn(16.0, 8192, 32.0, 1.0, 1.2772588722239782),
+        **MELLUM)
+    page, pool, wpool, max_pages = 16, 25600, 4096, 1024
+    monkeypatch.setattr(llama, "_on_tpu", lambda: True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+    params = jax.tree.map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), mc)))
+    assert params["layers"]["wq"].shape == (4, 2304, 4096)
+    caches = jax.tree.map(
+        lambda s: sds(s.shape, s.dtype),
+        jax.eval_shape(lambda: llama.init_paged_cache(mc, pool, page,
+                                                      wpool)))
+    assert [c["k"].shape[0] for c in caches] == [wpool] * 3 + [pool]
+    if family == "prefill_r4":
+        rows, chunk = 4, 128
+        tables = (sds((rows, max_pages), jnp.int32),
+                  sds((rows, MELLUM_RING), jnp.int32))
+        compiled = jax.jit(functools.partial(
+            llama.prefill_paged_rows, cfg=mc, page_size=page),
+            donate_argnums=(2,)).lower(
+            params, sds((rows, chunk), jnp.int32), caches, tables,
+            sds((rows,), jnp.int32), sds((rows,), jnp.int32)).compile()
+        shape, per_layer = f"bf16[1,{chunk},32,{D}]", rows
+    else:
+        rows = 32
+        tables = (sds((rows, max_pages), jnp.int32),
+                  sds((rows, MELLUM_RING), jnp.int32))
+        compiled = jax.jit(functools.partial(
+            llama.decode_paged, cfg=mc, page_size=page),
+            donate_argnums=(2,)).lower(
+            params, sds((rows, 1), jnp.int32), caches, tables,
+            sds((rows,), jnp.int32)).compile()
+        shape, per_layer = f"bf16[{rows},1,32,{D}]", 1
+    text = compiled.as_text()
+    window = re.findall(
+        r"%ragged_paged_attention_window[.\d]* = (bf16\[[\d,]*\])", text)
+    full = re.findall(r"%ragged_paged_attention[.\d]* = (bf16\[[\d,]*\])",
+                      text)
+    assert window == [shape] * (3 * per_layer)
+    assert full == [shape] * per_layer
+    assert jax.tree.leaves(compiled.out_info)[0].shape == (
+        rows, mc.vocab_size)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
