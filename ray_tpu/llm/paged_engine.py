@@ -26,11 +26,13 @@ compiled. Cache buffers are donated through every program so XLA updates
 pages in place, and that chain of donations is what orders the programs
 on the device.
 
-A dispatch is launched as soon as the values it needs are on the host,
-not once the one before it has been read back (step()): a prefill is
-built from host state alone, a decode needs the tokens of the decode
-before it. So up to two dispatches are outstanding, and the readback of
-one runs beside the program of the other.
+A dispatch is launched as soon as the host knows what it needs, not once
+the one before it has been read back (step()): a prefill is built from
+host state alone, and a decode takes the last tokens of the decode before
+it from the device, where that one left them, and reckons lengths and
+pages from what it allowed each row. So up to three dispatches are
+outstanding (a decode, the prefill behind it, the next decode), and a
+readback runs one dispatch behind, beside the programs queued after it.
 
 Where the stepping thread's time goes is counted in ``stats["ns_*"]``
 (PHASES below) and, under a profiler session, drawn as ``rtpu.engine.*``
@@ -108,11 +110,11 @@ class PagedEngineConfig:
     # dispatch batching: chunk-rows packed per prefill dispatch and
     # decode steps unrolled (lax.scan) per decode dispatch. A dispatch's
     # launch-to-readback round trip is 3-8 ms on a locally attached v5e
-    # against programs of 9-35 ms (PERF.md §5). While both families have
-    # work it is hidden: the next dispatch is queued on the device before
-    # this one's tokens come back (step()). Decode after decode still
-    # pays it, a launch needing the last one's tokens, and that is what
-    # decode_window is left to amortize.
+    # against programs of 9-35 ms (PERF.md §5). It is hidden: the next
+    # dispatch is queued on the device before this one's tokens come
+    # back, a decode behind a decode too (step()). What decode_window
+    # still amortizes is the host's work a dispatch, and the stream
+    # pump's a chunk.
     # decode_window only applies when no prefill is pending (window 1
     # keeps TTFT low while prompts are still entering the batch).
     prefill_rows: int = 4
@@ -294,6 +296,14 @@ class _Launched:
     outs: tuple     # device arrays: tokens, logprobs | None, load | None
     host: dict      # the launch's own state, as its booking's keywords
 
+    def ahead_of(self, req: _Request) -> Optional[int]:
+        """Tokens this unbooked decode holds for ``req`` (what its launch
+        allowed the row); None where ``req`` is no row of it."""
+        if self.family != "decode" or \
+                self.host["reqs"].get(req.slot) is not req:
+            return None
+        return self.host["allow"][req.slot]
+
 
 class PagedInferenceEngine:
     """Paged engine stepped by one thread; serving runs it on a
@@ -339,11 +349,14 @@ class PagedInferenceEngine:
         self._free_slots = deque(range(cfg.max_batch_size))
         self._block_tables = np.zeros(
             (cfg.max_batch_size, cfg.max_pages_per_seq), np.int32)
+        # a slot's tokens in the cache or written there by a program
+        # launched: a decode's launch moves it by what it allows the row
+        # (no booking does), a verify dispatch's booking by what it kept
         self._lengths = np.zeros((cfg.max_batch_size,), np.int32)
         self._active: dict[int, _Request] = {}
         self._prefilling: list[_Request] = []   # admitted, prompt not done
         self._pending: deque[_Request] = deque()
-        # launched, not yet read back, oldest first: at most two, and
+        # launched, not yet read back, oldest first: at most three, and
         # one between two step() calls (step())
         self._inflight: deque[_Launched] = deque()
         # -- prefix cache state (enable_prefix_caching) -------------------
@@ -431,6 +444,13 @@ class PagedInferenceEngine:
             self._init_mesh()
         self._rng_base = jax.random.PRNGKey(rng_seed ^ 0x5EED)
         self._rng_ctr = 0
+        # [B] last tokens of the newest decode program, on the device and
+        # never read back: every decode takes it (_decode_window_fn's
+        # ``prev``), and the rows that continue an unbooked decode start
+        # from it
+        self._last = jnp.zeros((cfg.max_batch_size,), jnp.int32)
+        if self.mesh is not None:
+            self._last = jax.device_put(self._last, self._shardings["repl"])
         self._lock = threading.Lock()
         # notified when a dispatch has been launched (_notify_launch):
         # what serving's stream pump sleeps on (llm/serving.py _pump)
@@ -458,6 +478,13 @@ class PagedInferenceEngine:
                       # launches made while another dispatch was
                       # outstanding: how often step() runs ahead
                       "dispatches_overlapped": 0,
+                      # of a decode launched behind an unbooked decode:
+                      # rows whose first token came from the device (the
+                      # last tokens that decode left there), and rows x
+                      # steps run for a request the booking before found
+                      # done (a stop the host could not foresee)
+                      "decode_rows_fed_on_device": 0,
+                      "decode_dead_rows": 0,
                       "spec_dispatches": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "tokens_out": 0,
                       # prefix cache: full prompt pages served from cache
@@ -579,7 +606,8 @@ class PagedInferenceEngine:
                 "kv_spill over a two-kind (window + full) cache: the "
                 "spill tier moves one kind of page (ROADMAP R2)")
         page = cfg.page_size
-        write = max(cfg.prefill_rows * cfg.chunk_size, cfg.decode_window,
+        # a sequence has at most two decode windows in flight (step())
+        write = max(cfg.prefill_rows * cfg.chunk_size, 2 * cfg.decode_window,
                     cfg.spec_tokens + 1)
         self._ring = self.model.window_ring_pages(mc, page, write)
         # every sequence a ring, and what two prefill dispatches in
@@ -759,7 +787,7 @@ class PagedInferenceEngine:
         from ..parallel.mesh import use_mesh
         return use_mesh(self.mesh)
 
-    def _family_jit(self, run, n_plain: int, name: str):
+    def _family_jit(self, run, n_plain: int, name: str, n_out: int = 3):
         """jit a dispatch family with the donated caches at arg 1, under
         ``name`` (family and static window / rows: ``rtpu_decode_w8``,
         ``rtpu_prefill_r4``, ``rtpu_verify_r2``), which the profiler's
@@ -768,19 +796,21 @@ class PagedInferenceEngine:
         mesh: every in/out sharding pinned — params/caches/lora at their
         committed placements, the n_plain host-array args (token ids,
         block tables, lengths, rng, temps) replicated, outputs (sampled
-        tokens, logprobs, an MoE config's per-expert load) replicated and
-        the cache outputs bit-matching
-        their inputs so donation aliases. Pinning is what guarantees the
-        compiled program never inserts an involuntary reshard of a
-        committed buffer: any transfer beyond the declared host arrays
-        would need an in/out sharding this signature forbids."""
+        tokens, logprobs, an MoE config's per-expert load; ``n_out`` of
+        them: a decode program's fourth is its rows' last tokens, which
+        the next decode takes as an input) replicated and the cache
+        outputs bit-matching their inputs so donation aliases. Pinning
+        is what guarantees the compiled program never inserts an
+        involuntary reshard of a committed buffer: any transfer beyond
+        the declared host arrays would need an in/out sharding this
+        signature forbids."""
         run.__name__ = run.__qualname__ = name
         if self.mesh is None:
             return jax.jit(run, donate_argnums=(1,))
         sh = self._shardings
         ins = (sh["params"], sh["caches"]) + (sh["repl"],) * n_plain + (
             sh["lora"], sh["repl"])
-        outs = (sh["repl"], sh["repl"], sh["repl"], sh["caches"])
+        outs = (sh["repl"],) * n_out + (sh["caches"],)
         return jax.jit(run, donate_argnums=(1,), in_shardings=ins,
                        out_shardings=outs)
 
@@ -865,7 +895,12 @@ class PagedInferenceEngine:
         decode+sample, feeding each step's sampled tokens straight back in
         on-device. Only the [B, w] token block crosses back to the host
         (with an MoE config, also the [E] per-expert assignment counts of
-        the dispatch, summed over layers and steps: _moe_account).
+        the dispatch, summed over layers and steps: _moe_account). The
+        rows' last tokens stay on the device as one more output, [B]:
+        the next decode dispatch takes it as ``prev`` and starts the rows
+        that ``fed`` marks from it, so it can be launched before this
+        one's tokens are on the host (step()); the other rows start from
+        ``tok0``, the host's.
         ``pages`` is the block-table width this program was built for
         (_page_bucket): part of the static key, like w and the mode."""
         fn = self._decode_win_fns.get((w, mode, pages))
@@ -874,7 +909,7 @@ class PagedInferenceEngine:
             model, interpret = self.model, self._interpret
             any_sampled, any_topk, want_logp = mode
 
-            def run(p, c, tok0, bt, ln0, key, ctr, temps, top_ks,
+            def run(p, c, tok0, bt, ln0, key, ctr, temps, top_ks, prev, fed,
                     lora=None, slots=None):
                 def body(carry, i):
                     toks, lens, caches = carry
@@ -891,13 +926,14 @@ class PagedInferenceEngine:
                     return (nxt, lens + 1, caches), (
                         nxt, lp if want_logp else None, load)
 
-                (_, _, c), (out, lps, load) = jax.lax.scan(
-                    body, (tok0, ln0, c), jnp.arange(w))
+                (last, _, c), (out, lps, load) = jax.lax.scan(
+                    body, (jnp.where(fed, prev, tok0), ln0, c),
+                    jnp.arange(w))
                 # [B, w] tokens (and logprobs); the steps' loads summed
                 return (out.T, None if lps is None else lps.T,
-                        None if load is None else load.sum(0), c)
+                        None if load is None else load.sum(0), last, c)
 
-            fn = self._family_jit(run, 7, f"rtpu_decode_w{w}")
+            fn = self._family_jit(run, 9, f"rtpu_decode_w{w}", n_out=4)
             self._decode_win_fns[(w, mode, pages)] = fn
         return fn
 
@@ -1058,13 +1094,14 @@ class PagedInferenceEngine:
             for maxp in (buckets if "decode" in families else ()):
                 for w in sorted({1, cfg.decode_window}):
                     tw = _time.perf_counter()
-                    out, _lps, _load, self.caches = self._decode_window_fn(
-                        w, mode, maxp)(
+                    out, _lps, _load, self._last, self.caches = \
+                        self._decode_window_fn(w, mode, maxp)(
                         self.params, self.caches, np.zeros((bs,), np.int32),
                         self._tables(np.zeros((bs, maxp), np.int32)),
                         np.zeros((bs,), np.int32), key, ctr,
                         np.zeros((bs,), np.float32),
                         np.zeros((bs,), np.int32),
+                        self._last, np.zeros((bs,), np.bool_),
                         *self._lora_args(np.zeros((bs,), np.int32)))
                     np.asarray(out)
                     self.profiler.record_compile(
@@ -1547,38 +1584,66 @@ class PagedInferenceEngine:
 
     def step(self):
         """One iteration: admit, launch one prefill dispatch (bounded),
-        launch one decode dispatch, and read back whatever an earlier
-        launch left on the device as soon as a launch needs it.
+        launch one decode dispatch, then read back everything older than
+        the newest launch. No launch waits for a readback; the readbacks
+        run one dispatch behind.
 
-        A prefill is built from host state alone, so it is queued behind
-        the dispatch the last step() left outstanding; a decode needs
-        the tokens of the decode before it (and the first tokens of
-        prompts that finished earlier), not those of the prefill just
-        launched: a prompt that ends in it joins the decode batch one
-        dispatch later. With both families at work that is launch P(k)
-        beside D(k-1), book D(k-1), launch D(k) beside P(k), book P(k);
-        with nothing decoding P(k+1) is launched beside P(k); with
-        nothing prefilling a decode follows the booking of the last one.
+        A prefill P(k) is built from host state alone and goes out
+        beside the decode D(k-1) that the last step() left on the device.
+        The decode D(k) goes out behind both. It continues D(k-1) without
+        that one's tokens: the rows' last tokens are on the device, in
+        the [B] array D(k-1) left there, and D(k) takes its first tokens
+        from it (_decode_window_fn); what the booking of D(k-1) would
+        tell the host it reckons at the launch from what D(k-1) allowed
+        each row (``allow``): lengths, the block table's width and the
+        pages to reserve count booked + in-flight tokens, and a request
+        that D(k-1) ends by max_tokens, by the sequence ceiling or by a
+        dry pool is no row of D(k) (_launch_decode). A stop the host
+        cannot foresee (EOS, stop_token_ids) is seen one dispatch late:
+        that row runs one dead dispatch, whose tokens its booking throws
+        away as it does the tokens past a stop inside one window. Rows
+        that joined since D(k-1) went out (a prompt's first token, which a
+        prefill's booking produced; import_prefill) start from the
+        host's token: a prompt that ends in P(k) joins the decode batch
+        one dispatch later. Then D(k-1) and P(k) are read back and
+        booked, in the device's order, beside the programs queued behind
+        them, and D(k) stays out. So when a decode is launched at most
+        one earlier decode and one prefill are unbooked, at most three
+        dispatches are outstanding, and one between two step() calls.
+        Behind an unbooked FULL window the decode is one step, and behind
+        that step goes the next full window: the step covers the host's
+        turn from the window's readback to the next launch, and a prompt
+        that arrives while the host waits for the window finds one step
+        queued ahead of its prefill, not a second window.
+
         When P(k) holds the last chunk any prompt waits for, the decode
         after it is a full window: launched beside P(k) it would run
-        decode_window steps (and a round of every stream's Python)
+        decode_window steps (and a round of the stream pump's Python)
         without the prompts that end in P(k), so it follows P(k)'s
-        booking and carries them.
+        booking and carries them. Speculation proposes its drafts from
+        the tokens on the host: an engine with spec_tokens > 0 books
+        every decode before it launches the next (_launch_decode). A
+        step that launched nothing books everything.
+
         The donated pools order the programs on the device, and a page
         freed on the host can only be written by a program launched
-        later. Everything falls in one of the eight rtpu.engine.* phases
-        (PHASES): admit, {prefill, decode} x {build, device, post},
-        telemetry; ``device`` is two spans, a ``.launch`` (LAUNCHES) or
-        the ``.wait`` of a blocking readback."""
+        later; a dead row writes past its request's last valid token,
+        never into a page the prefix cache has published. Everything
+        falls in one of the eight rtpu.engine.* phases (PHASES): admit,
+        {prefill, decode} x {build, device, post}, telemetry; ``device``
+        is two spans, a ``.launch`` (LAUNCHES) or the ``.wait`` of a
+        blocking readback."""
         with self._phase("ns_admit"):
             self._admit()
         # the mesh scope pins trace-time constrain() resolution for any
         # program a launch compiles below (a no-op off-mesh)
         with self._mesh_scope():
-            ahead = self._launch_prefill() and self._prompts_wait()
-            self._book_until(1 if ahead else 0)
-            self._launch_decode()
-            self._book_until(1)
+            launched = self._launch_prefill()
+            if launched:
+                # one prefill at most stays out beside a decode
+                self._book_until(1 if self._prompts_wait() else 0, "prefill")
+            launched |= self._launch_decode()
+            self._book_until(1 if launched else 0)
         with self._phase("ns_telemetry"):
             telemetry.on_step(self)
 
@@ -1587,10 +1652,16 @@ class PagedInferenceEngine:
         return bool(self._pending) or any(
             r.prefill_pos < len(r.prompt_ids) for r in self._prefilling)
 
-    def _book_until(self, keep: int):
+    def _book_until(self, keep: int, family: Optional[str] = None):
         """Read back and book outstanding dispatches, oldest first (the
-        order the device runs them in), until ``keep`` are left."""
-        while len(self._inflight) > keep:
+        order the device runs them in), until ``keep`` are left (of
+        ``family``, where one is given). A booking that another
+        readback's wait follows wakes the stream pump: the tokens it put
+        on the host would otherwise lie there for the length of that
+        wait (_notify_launch)."""
+        def left():
+            return sum(family in (None, d.family) for d in self._inflight)
+        while left() > keep:
             d = self._inflight[0]
             book = (self._book_prefill if d.family == "prefill"
                     else self._book_decode)
@@ -1604,6 +1675,8 @@ class PagedInferenceEngine:
                 # the device's arrays and their host copies go inside
                 # the phase: the phases leave nothing of step() out
                 del self._inflight[0], d, got
+                if left() > keep:
+                    self._notify_launch()
 
     def _drain(self):
         """Leave nothing outstanding: what ends a blocking call."""
@@ -1910,14 +1983,19 @@ class PagedInferenceEngine:
 
     def _notify_launch(self):
         """Wake whoever waits for new tokens (serving's stream pump, one
-        thread whatever the number of streams), called with a program
-        just launched and not yet awaited: its Python — detokenising,
-        one ring write a stream — then runs beside the program. Woken
-        when the tokens are booked instead, that Python holds the GIL
+        thread whatever the number of streams), called where this
+        thread's next act is to wait for the device: with a program just
+        launched (_launched), or after a booking that another readback
+        follows (_book_until). The pump's Python — detokenising, one
+        ring write a stream — then runs beside a program and beside that
+        wait. Woken by every booking instead, that Python holds the GIL
         exactly when this thread needs it to launch the next program
         (PERF.md §6, PR 27; with a thread a stream, 64 of them took
         turns at it and a launch that needs 3 ms took 50-80: PR 39). The
-        tokens the pump finds are the previous dispatch's."""
+        tokens the pump finds at a launch are those of the booking
+        before it: of the dispatch two before the one just launched
+        when a decode follows a decode, since the readback runs one
+        dispatch behind (step())."""
         with self.launched:
             self.launch_gen += 1
             self.launched.notify_all()
@@ -1961,7 +2039,8 @@ class PagedInferenceEngine:
         are the decode ones: the verify dispatch is this step's decode.
         Launched and read back here (the drafts are proposed from every
         token so far), with nothing else outstanding: quiet, no prefill
-        went out this step, and step() has booked what the last left."""
+        went out this step, and _launch_decode has booked what the last
+        left."""
         cfg = self.cfg
         s, page = cfg.spec_tokens, cfg.page_size
         with self._phase("ns_decode_build"):
@@ -2079,13 +2158,19 @@ class PagedInferenceEngine:
                 self._spec_cooldown_len = 8
         return True
 
-    def _launch_decode(self):
-        """Launch one decode dispatch over the decode set as the last
-        booking left it. A caller has booked every earlier decode: this
-        one feeds their last tokens."""
-        if not self._active:
-            return
+    def _launch_decode(self) -> bool:
+        """Launch one decode dispatch; False when there is no row for
+        one. At most one earlier decode is unbooked (step()). The rows
+        are the decode set less the requests that decode is known to
+        end; a row that continues it starts from the token it left on
+        the device and counts the tokens it allowed as there already, the
+        others (booked to their last token) start from the host's."""
         cfg = self.cfg
+        if cfg.spec_tokens > 0:
+            # a draft is proposed from every token so far
+            self._book_until(0, "decode")
+        if not self._active:
+            return False
         bs, page = cfg.max_batch_size, cfg.page_size
         quiet = not (self._prefilling or self._pending)
         if cfg.spec_tokens > 0 and quiet and \
@@ -2094,51 +2179,72 @@ class PagedInferenceEngine:
             if self._spec_cooldown > 0:
                 self._spec_cooldown -= 1
             elif self._spec_step():
-                return
+                return True
         with self._phase("ns_decode_build"):
             # full window only when no prompt is waiting: a pending
             # prefill gets interleaved every step, keeping TTFT low under
             # bursts
             w = 1 if not quiet else cfg.decode_window
+            ahead = next(
+                (d for d in self._inflight if d.family == "decode"), None)
+            if ahead is not None and ahead.host["w"] > 1:
+                # behind an unbooked full window, one step: it covers
+                # the host's turn from that window's readback to the
+                # next launch, and a prompt that arrives during the wait
+                # is queued behind a step, not behind a second window
+                w = 1
+            # the rows of THIS dispatch, each with the tokens it has in
+            # flight (None: none, its last token is the host's): a
+            # request that joins the decode set before it is booked
+            # (import_prefill, a prompt's first token) is none of them
+            rows: dict[int, tuple] = {}
+            for slot, req in self._active.items():
+                fly = ahead and ahead.ahead_of(req)
+                if fly is None or not self._ends_after(
+                        req, fly, ahead.host["w"]):
+                    rows[slot] = (req, fly)
+            if not rows:
+                return False
+            tokens = np.zeros((bs,), np.int32)
+            fed = np.zeros((bs,), np.bool_)
+            lengths = np.zeros((bs,), np.int32)
+            temps = np.zeros((bs,), np.float32)
+            topks = np.zeros((bs,), np.int32)
+            lslots = np.zeros((bs,), np.int32)
+            allow: dict[int, int] = {}      # valid tokens per slot
+            for slot, (req, fly) in rows.items():
+                allow[slot] = self._reserve(req, w, fly or 0)
+                if fly is None:
+                    tokens[slot] = req.out_ids[-1]
+                else:
+                    fed[slot] = True
+                lengths[slot] = self._lengths[slot]
+                temps[slot] = req.params.temperature
+                topks[slot] = req.params.top_k
+                lslots[slot] = req.adapter_slot
             # table-width bucket: the window writes positions
             # len..len+w-1 per slot, so the width covers every such page
             # (beyond-allocation writes then hit zero entries = sink page,
             # never a clamp)
             W = self._page_bucket(max(
-                (self._lengths[sl] + w - 1) // page + 1
-                for sl in self._active))
-            tokens = np.zeros((bs,), np.int32)
-            lengths = np.zeros((bs,), np.int32)
-            temps = np.zeros((bs,), np.float32)
-            topks = np.zeros((bs,), np.int32)
-            lslots = np.zeros((bs,), np.int32)
+                (lengths[sl] + w - 1) // page + 1 for sl in rows))
             # slots not decoding this step get a zeroed block-table row:
             # their dummy writes go to sink page 0 instead of a live
             # (possibly reused) page
             bt = np.zeros((bs, W), np.int32)
-            # the rows of THIS dispatch: a request that joins the decode
-            # set before it is booked (import_prefill, a prompt's first
-            # token) is none of them
-            reqs = dict(self._active)
-            allow: dict[int, int] = {}      # valid tokens per slot
-            for slot, req in reqs.items():
-                allow[slot] = self._reserve(req, w)
-                tokens[slot] = req.out_ids[-1]
-                lengths[slot] = self._lengths[slot]
-                temps[slot] = req.params.temperature
-                topks[slot] = req.params.top_k
+            for slot in rows:
                 bt[slot] = self._block_tables[slot][:W]
-                lslots[slot] = req.adapter_slot
+            reqs = {slot: req for slot, (req, _) in rows.items()}
             mode = self._sampling_mode(reqs.values())
             fn = self._decode_window_fn(w, mode, W)
             tables = self._tables(
                 bt, [sl if sl in reqs else -1 for sl in range(bs)])
         with self._launch("decode"):
             with self.profiler.step("decode", (w, mode, W)):
-                out, lps, load, self.caches = fn(
+                out, lps, load, self._last, self.caches = fn(
                     self.params, self.caches, tokens, tables, lengths,
                     self._rng_base, np.int32(self._rng_ctr), temps, topks,
-                    *self._lora_args(lslots))
+                    self._last, fed, *self._lora_args(lslots))
             if self.window:
                 # counted at the launch, as live_pages is: what the
                 # program streams of the ring it runs
@@ -2147,28 +2253,45 @@ class PagedInferenceEngine:
                 self.stats["decode_table_wpages"] += bs * self._ring
             self._launched(
                 "decode", (out, lps, load), reqs=reqs, allow=allow, w=w,
+                fed_rows=int(fed.sum()),
                 live_pages=self._live_pages(reqs), table_pages=bt.size,
-                in_bytes=tokens.nbytes + bt.nbytes + lengths.nbytes
-                + temps.nbytes + topks.nbytes + lslots.nbytes)
+                in_bytes=tokens.nbytes + fed.nbytes + bt.nbytes
+                + lengths.nbytes + temps.nbytes + topks.nbytes
+                + lslots.nbytes)
+            # the next launch starts where this one ends: no booking
+            # moves a length
+            for slot, n in allow.items():
+                self._lengths[slot] += n
+        return True
 
-    def _book_decode(self, out, lps, load, *, reqs, allow, w, live_pages,
-                     table_pages, in_bytes):
+    def _ends_after(self, req: _Request, fly: int, w: int) -> bool:
+        """Does the unbooked decode of ``w`` steps that allowed ``req``
+        ``fly`` tokens end it, for all the host knows at its launch: cut
+        short by a dry pool, or at max_tokens or the sequence ceiling?"""
+        return fly < w or self._at_limit(req, len(req.out_ids) + fly)
+
+    def _book_decode(self, out, lps, load, *, reqs, allow, w, fed_rows,
+                     live_pages, table_pages, in_bytes):
         """Book a decode dispatch read back ([bs, w] tokens); the
         keywords are what its launch kept of the host's state
-        (_launch_decode)."""
+        (_launch_decode). A row whose request the booking before this
+        one found done is dead: nothing of it is kept."""
         st = self.stats
+        live = {slot: req for slot, req in reqs.items() if not req.done}
         st["decode_dispatches"] += 1
-        st["decode_live_slots"] += len(reqs)
+        st["decode_live_slots"] += len(live)
+        st["decode_dead_rows"] += (len(reqs) - len(live)) * w
+        st["decode_rows_fed_on_device"] += fed_rows
         st["decode_live_pages"] += live_pages
         st["decode_table_pages"] += table_pages
         st["decode_steps"] += w
-        self._moe_account(load, len(reqs) * w,
+        self._moe_account(load, len(live) * w,
                           self.cfg.max_batch_size * w)
         self._mesh_account(
             in_bytes,
             out.nbytes + sum(x.nbytes for x in (lps, load)
                              if x is not None))
-        for slot, req in reqs.items():
+        for slot, req in live.items():
             # stamped before the tokens are appended: a stream that
             # sees a token finds a stamp no older than its booking
             req.token_ns = self._book_ns
@@ -2184,7 +2307,6 @@ class PagedInferenceEngine:
                 req.out_ids.append(tok)
                 if lps is not None:
                     req.out_logps.append(float(lps[slot, j]))
-                self._lengths[slot] += 1
                 st["tokens_out"] += 1
                 if self._stop_after(req, tok):
                     self._retire(req)
@@ -2195,9 +2317,10 @@ class PagedInferenceEngine:
             st["window_pool_live_pages"] += self._wpool.live()
             st["full_pool_live_pages"] += self._pool.live()
 
-    def _reserve(self, req: _Request, width: int) -> int:
+    def _reserve(self, req: _Request, width: int, fly: int = 0) -> int:
         """Pre-allocate pages for up to `width` new tokens and return how
-        many of the dispatch's tokens are VALID for this request.
+        many of the dispatch's tokens are VALID for this request, which
+        has ``fly`` more in an unbooked decode than the host has booked.
 
         Pages are grabbed only for tokens the request can still emit
         (width, max_tokens remainder, sequence ceiling — whichever is
@@ -2207,8 +2330,9 @@ class PagedInferenceEngine:
         keeps only the tokens its allocated pages cover and finishes
         early. Shared by the windowed-decode and speculative paths so
         their page budgeting can never diverge."""
-        total = len(req.prompt_ids) + len(req.out_ids)
-        remaining = max(req.params.max_tokens - len(req.out_ids), 1)
+        out = len(req.out_ids) + fly
+        total = len(req.prompt_ids) + out
+        remaining = max(req.params.max_tokens - out, 1)
         target = min(total + min(width, remaining), self.cfg.max_seq_len)
         if self._ensure_pages(req, target) and \
                 self._ensure_window(req, target):
@@ -2218,12 +2342,16 @@ class PagedInferenceEngine:
             held = min(held, req.wlo + len(req.wpages))
         return max(held * self.cfg.page_size - total, 0)
 
+    def _at_limit(self, req: _Request, out: int) -> bool:
+        """Is a request of ``out`` generated tokens at max_tokens or at
+        the sequence ceiling: the stops the host can foresee?"""
+        return (out >= req.params.max_tokens
+                or len(req.prompt_ids) + out >= self.cfg.max_seq_len - 1)
+
     def _stop_after(self, req: _Request, tok: int) -> bool:
         """Stop condition evaluated after appending tok to req.out_ids."""
-        total = len(req.prompt_ids) + len(req.out_ids)
-        return (len(req.out_ids) >= req.params.max_tokens
-                or tok == self._eos_id() or tok in req.params.stop_token_ids
-                or total >= self.cfg.max_seq_len - 1)
+        return (self._at_limit(req, len(req.out_ids))
+                or tok == self._eos_id() or tok in req.params.stop_token_ids)
 
     def _finish_request(self, req: _Request, finish=None):
         """Retire a request: mark done, wake waiters, emit telemetry

@@ -418,11 +418,13 @@ class LLMServer:
 
     def _pump(self):
         """The one thread that serves every open stream. It sleeps on
-        ``engine.launched``, so a pass runs with a program just launched
-        and not yet awaited — beside the device's work, and as ONE
+        ``engine.launched``, so a pass runs where the stepping thread's
+        next act is a wait for the device (a program just launched, or a
+        booking that another readback follows: paged_engine
+        ``_notify_launch``) — beside the device's work, and as ONE
         runnable thread at the interpreter the stepping thread needs for
         its next launch, where a thread a stream made 64 (PERF.md §6,
-        PR 39). The tokens a pass finds are the previous dispatch's.
+        PR 39). The tokens a pass finds are those of the last booking.
 
         Counted into ``engine.stats``, by this thread alone:
 
@@ -430,7 +432,7 @@ class LLMServer:
           sink took (the ring write returned, the queue has it);
         - ``stream_lag_ns``: from the booking of a chunk's newest token
           (``_Request.token_ns``, the stepping thread's stamp) to that
-          instant: the wait for the next launch's wake-up, the stream's
+          instant: the wait for the next wake-up, the stream's
           turn in the pass, the detokenisation and the sink's write,
           and every pass a sink without credit held the text back;
         - ``stream_first_chunks`` / ``stream_first_lag_ns``: the same

@@ -50,6 +50,11 @@ Metric names (all prefixed ``rtpu_llm_``):
   dispatches_overlapped_total counter  launches made while another
       dispatch was outstanding (over dispatches_total: how often the
       engine runs ahead of its readbacks)
+  decode_rows_fed_on_device_total counter  rows of a decode launched
+      behind an unbooked decode whose first token came from the device
+  decode_dead_rows_total counter    rows x steps a decode ran for a
+      request the booking before it found done (a stop seen one
+      dispatch late; over decode steps x max_batch_size: their share)
   decode_live_slots_total counter   slots live, summed over decode
       dispatches (over dispatches_total{family="decode"} x max_batch_size:
       the share of the decode program's rows doing useful work)
@@ -329,6 +334,10 @@ _STAT_COUNTERS = (
      "device dispatches by program family", ("family", "verify")),
     ("dispatches_overlapped", "rtpu_llm_dispatches_overlapped_total",
      "launches made while another dispatch was outstanding", None),
+    ("decode_rows_fed_on_device", "rtpu_llm_decode_rows_fed_on_device_total",
+     "decode rows whose first token came from the device", None),
+    ("decode_dead_rows", "rtpu_llm_decode_dead_rows_total",
+     "rows x steps a decode ran for a request already done", None),
     ("decode_live_slots", "rtpu_llm_decode_live_slots_total",
      "slots live, summed over decode dispatches", None),
     ("stream_chunks", "rtpu_llm_stream_chunks_total",

@@ -68,6 +68,28 @@ def test_tp2_greedy_bit_identical_and_zero_reshards():
     assert 0 < eng.stats["mesh_output_bytes"] < 1 << 20
 
 
+def test_tp2_decode_behind_a_decode_is_fed_on_the_mesh():
+    """A decode launched behind an unbooked decode under the mesh: the
+    rows' last tokens and the mask that says which rows start from them
+    are two more replicated arguments of the pinned signature, the tokens
+    are the unsharded engine's, and nothing drifts off its sharding."""
+    prompts = _prompts(np.random.RandomState(4))
+    sp = SamplingParams(max_tokens=30, temperature=0.0)
+    ref = PagedInferenceEngine(_cfg(), rng_seed=0)
+    eng = PagedInferenceEngine(_cfg(mesh={"tp": 2}), rng_seed=0)
+    want = [o["token_ids"] for o in ref.generate(prompts, sp)]
+    assert [o["token_ids"] for o in eng.generate(prompts, sp)] == want
+    for e in (ref, eng):
+        # 29 tokens to come, 8 + 1 + 8 + 1 + 8 + 1 + 2: every dispatch
+        # after the first goes out behind the one before it
+        assert e.stats["decode_dispatches"] == 7
+        assert e.stats["decode_rows_fed_on_device"] == 6 * len(prompts)
+        assert e.stats["decode_dead_rows"] == 0
+    assert eng.stats["mesh_reshard_bytes"] == 0, eng.stats
+    repl = eng._shardings["repl"]
+    assert repl.is_equivalent_to(eng._last.sharding, eng._last.ndim)
+
+
 @pytest.mark.skipif(len(jax.devices()) < 4,
                     reason="needs >=4 (virtual) devices")
 def test_tp4_greedy_bit_identical():

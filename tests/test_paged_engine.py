@@ -24,9 +24,20 @@ def engine(request):
     return _tiny_engine(request.param)
 
 
+# and a model with sliding-window layers beside full ones (two page
+# pools, the window layers' table a ring), for the tests of step()'s order
+RUN_AHEAD_MODELS = dict(
+    TINY_MODELS,
+    window=lambda **kw: llama.llama_tiny(
+        vocab_size=258, layer_types=("sliding", "full"), sliding_window=16,
+        **kw))
+
+
 def _tiny_engine(kind, **over):
-    kw = dict(model=TINY_MODELS[kind](max_seq_len=128), max_batch_size=4,
+    kw = dict(model=RUN_AHEAD_MODELS[kind](max_seq_len=128), max_batch_size=4,
               page_size=8, num_pages=64, max_pages_per_seq=16, chunk_size=16)
+    if kind == "window":
+        kw["num_window_pages"] = 64
     kw.update(over)
     return PagedInferenceEngine(PagedEngineConfig(**kw), rng_seed=0)
 
@@ -591,19 +602,187 @@ def test_run_ahead_tokens_equal_one_at_a_time(solo, case):
     assert st["tokens_out"] == sum(r.params.max_tokens for r in reqs)
 
 
-def test_decode_after_decode_launches_nothing_ahead(engine):
-    """With nothing prefilling a decode launch needs the tokens of the
-    last one: no launch is made beside an outstanding dispatch."""
-    reqs = [engine.submit(_ids(9 + i, 30 + i), SamplingParams(max_tokens=20))
+def test_decode_after_decode_launches_behind_the_one_before(engine):
+    """With nothing prefilling every decode after the first is launched
+    beside the one before it, unbooked: its rows start from the tokens
+    that one left on the device, and the readback runs one dispatch
+    behind. Behind a full window goes one step, behind that step the
+    next full window."""
+    reqs = [engine.submit(_ids(9 + i, 30 + i), SamplingParams(max_tokens=30))
             for i in range(3)]
     while engine._prefilling or engine._pending or not engine._active:
         engine.step()
+    engine._drain()                 # the first window went out in that step
+    assert [len(r.out_ids) for r in reqs] == [9] * 3
     before = dict(engine.stats)
-    engine.run_until_done(reqs)
-    assert engine.stats["decode_dispatches"] > before["decode_dispatches"]
-    assert engine.stats["prefill_dispatches"] == before["prefill_dispatches"]
-    assert engine.stats["dispatches_overlapped"] == \
-        before["dispatches_overlapped"]
+    windows = _windows(engine)
+    while not all(r.done for r in reqs):
+        engine.step()
+        assert len(engine._inflight) <= 1   # one between two step() calls
+        assert all(d.family == "decode" for d in engine._inflight)
+    del engine._launched                    # the spy
+    assert not engine._inflight             # the last step launched nothing
+    d = {k: engine.stats[k] - before[k] for k in before}
+    assert d["prefill_dispatches"] == 0
+    # 21 tokens to come: 8 + 1 + 8 + 1 + 3 of a last window, every
+    # dispatch but the first behind an outstanding one, all rows fed there
+    assert windows == [8, 1, 8, 1, 8] and d["decode_steps"] == sum(windows)
+    assert d["decode_dispatches"] == 5
+    assert d["dispatches_overlapped"] == d["decode_dispatches"] - 1
+    assert d["decode_rows_fed_on_device"] == 4 * 3
+    # max_tokens ends a request where the host can foresee it: no row ran
+    # for a request that was done
+    assert d["decode_dead_rows"] == 0
+    assert d["tokens_out"] == 3 * 21 and [len(r.out_ids) for r in reqs] == \
+        [30] * 3
+
+
+def _windows(eng) -> list:
+    """Spy on ``eng``'s launches: the list that each decode launch's
+    window is appended to (``del eng._launched`` takes the spy off)."""
+    ws, launched = [], eng._launched
+
+    def spy(family, outs, **host):
+        if family == "decode":
+            ws.append(host["w"])
+        return launched(family, outs, **host)
+    eng._launched = spy
+    return ws
+
+
+def _one_at_a_time(eng, prompts, params, windows=()):
+    """Every dispatch read back and booked before the next is launched:
+    each decode starts from the host's tokens. What a decode launched
+    behind an unbooked one has to reproduce. ``windows``: the window of
+    each decode dispatch in turn, where the run has to make the same
+    dispatches as another (a sampled draw is keyed by the launch)."""
+    reqs = [eng.submit(p, sp) for p, sp in zip(prompts, params)]
+    full, done = eng.cfg.decode_window, _windows(eng)
+    while not all(r.done for r in reqs):
+        if len(done) < len(windows):
+            eng.cfg.decode_window = windows[len(done)]
+        eng.step()
+        eng._drain()
+    eng.cfg.decode_window = full
+    del eng._launched
+    assert eng.stats["decode_rows_fed_on_device"] == 0
+    assert not windows or done == list(windows)
+    return [list(r.out_ids) for r in reqs]
+
+
+@pytest.fixture(scope="module", params=list(RUN_AHEAD_MODELS))
+def lone(request):
+    """(model kind, an engine with no prefix cache that runs one prompt
+    at a time and books every dispatch before the next)."""
+    kind = request.param
+    return kind, _tiny_engine(kind, enable_prefix_caching=False)
+
+
+@pytest.mark.parametrize("case", [
+    "max_tokens_on_a_window_edge", "max_tokens_inside_a_window",
+    "stop_token_inside_a_window", "pool_dry_mid_window", "sampled",
+    "import_between_two_launches"])
+def test_decode_behind_a_decode_serves_the_tokens_of_one_at_a_time(lone,
+                                                                   case):
+    """A decode launched before the last one is booked: whatever ends a
+    row of the unbooked dispatch, each request's tokens are those of the
+    same prompt run alone with every dispatch booked before the next."""
+    kind, alone = lone
+    sp = SamplingParams
+    prompts = [_ids(21, 70), _ids(13, 71)]
+    over, fed_rows = {}, True
+    if case == "max_tokens_on_a_window_edge":
+        # the first token is a prefill's; two and three whole windows
+        params = [sp(max_tokens=17), sp(max_tokens=25)]
+    elif case == "max_tokens_inside_a_window":
+        params = [sp(max_tokens=12), sp(max_tokens=22)]
+    elif case == "stop_token_inside_a_window":
+        full = _one_at_a_time(alone, prompts[:1], [sp(max_tokens=40)])[0]
+        # a token first seen inside the second decode window (tokens
+        # 9..16), not at its edge: the third window is launched before
+        # the stop is on the host
+        k = next(k for k in range(9, 16) if full[k] not in full[:k])
+        params = [sp(max_tokens=40, stop_token_ids=(full[k],)),
+                  sp(max_tokens=40)]
+        # two slots: the third request waits for the stopped one's
+        prompts = prompts + [_ids(30, 72)]
+        params = params + [sp(max_tokens=9)]
+        over = dict(max_batch_size=2)
+    elif case == "pool_dry_mid_window":
+        # 16 allocatable pages of 8 tokens; the requests hold 6 and 3
+        # when they start to decode and would need 10 and 8: the second
+        # is cut short mid-window, and the first ends on its pages
+        prompts = [_ids(40, 79), _ids(20, 74)]
+        params = [sp(max_tokens=40), sp(max_tokens=40)]
+        over = dict(num_pages=17, max_pages_per_seq=12,
+                    enable_prefix_caching=False)
+    elif case == "sampled":
+        params = [sp(max_tokens=20, temperature=0.8, top_k=20, seed=7),
+                  sp(max_tokens=27, temperature=1.1, seed=8)]
+    else:
+        params = [sp(max_tokens=30), sp(max_tokens=14)]
+    eng = _tiny_engine(kind, **over)
+    eng.params = alone.params
+    if case == "sampled":
+        # a draw is keyed by the launch counter and the row: the same
+        # dispatches in the same order, in an engine of the same seed
+        windows = _windows(eng)
+        reqs = [eng.submit(p, q) for p, q in zip(prompts, params)]
+        eng.run_until_done(reqs)
+        ref = _tiny_engine(kind, **over)
+        ref.params = alone.params
+        want = _one_at_a_time(ref, prompts, params, windows)
+    elif case == "import_between_two_launches":
+        want = [alone.generate([p], q)[0]["token_ids"]
+                for p, q in zip(prompts, params)]
+        first = eng.submit(prompts[0], params[0])
+        while len(first.out_ids) < 2:
+            eng.step()
+        (out,) = eng._inflight              # a decode, unbooked
+        if kind == "window":
+            # no payload carries two kinds of page: the prompt comes in
+            # by the queue, and its prefill's booking gives its first token
+            second = eng.submit(prompts[1], params[1])
+        else:
+            second = eng.import_prefill(
+                alone.prefill_export(prompts[1], params[1]), params[1])
+            eng.step()  # launches behind it: one row fed there, one here
+            assert eng._inflight[-1].host["reqs"] == {
+                first.slot: first, second.slot: second}
+            assert eng._inflight[-1].host["fed_rows"] == 1
+        reqs = [first, second]
+        eng.run_until_done(reqs)
+    else:
+        want = [_one_at_a_time(alone, [p], [q])[0]
+                for p, q in zip(prompts, params)]
+        reqs = [eng.submit(p, q) for p, q in zip(prompts, params)]
+        eng.run_until_done(reqs)
+    got = [list(r.out_ids) for r in reqs]
+    st = eng.stats
+    assert st["decode_rows_fed_on_device"] > 0
+    assert not eng._inflight and not eng.has_work()
+    if case == "pool_dry_mid_window":
+        # cut short where its pages ended, never wrong before that
+        assert all(g == w[:len(g)] for g, w in zip(got, want))
+        assert [len(g) for g in got] == [40, 28]
+        assert st["decode_dead_rows"] == 0      # foreseen at the launch
+    else:
+        assert got == want
+    if case == "stop_token_inside_a_window":
+        assert got[0][-1] == params[0].stop_token_ids[0]
+        assert len(got[0]) == k + 1 < 16
+        # the window launched behind the one that held the stop ran a
+        # row for a request its booking found done: none of it is kept
+        assert st["decode_dead_rows"] > 0
+        assert st["tokens_out"] == sum(map(len, got))
+    elif case != "sampled":
+        # what ends a request there the host foresees (a sampled token
+        # may be the end of sequence)
+        assert st["decode_dead_rows"] == 0
+    # every page and slot is back
+    pool = eng.pool_stats()
+    assert pool["free_pages"] + pool["cached_pages"] == eng.cfg.num_pages - 1
+    assert len(eng._free_slots) == eng.cfg.max_batch_size
 
 
 def test_blocking_calls_leave_nothing_outstanding(engine):
