@@ -395,14 +395,25 @@ def _attention(q, k, v, cfg: LlamaConfig, causal: bool, attn_impl):
     return mha_reference(q, k, v, causal=causal)
 
 
-def _qkv(h, p, cfg: LlamaConfig, cos, sin, lora=None, slots=None):
+def _qkv(h, p, cfg: LlamaConfig, cos, sin, lora=None, slots=None,
+         fence=False):
     """Projections (+ QK-norm over the whole projected vector when
     cfg.qk_norm) + RoPE, shared by every forward mode. h [B, S, D].
 
     ``lora``/``slots``: optional per-layer adapter slot table
     (_lora_at_layer) and per-row slot ids — the batched multi-LoRA
     serving path adds scale·(h@A[slot])@B[slot] to each projection.
-    None (every training/base path) leaves the math untouched."""
+    None (every training/base path) leaves the math untouched.
+
+    ``fence`` (the paged forwards, which unroll their layers over the
+    stacked weights): the flat projections are finished before they are
+    cut into heads. Left free, the TPU compiler folds the reshape into
+    the q and k matmuls (heads as a batch axis), wants ``wq`` / ``wk`` as
+    [H, D, d] for it and — they are parameters — transposes all of both,
+    once a layer in every dispatch (32 MB + 8 MB at Mistral-7B's widths;
+    tests/test_chip_compile.py holds the programs to "no copy of a
+    projection weight"). Behind the fence each weight is read once, where
+    it lies in its stack, and the same values come out."""
     b, s, _ = h.shape
     q = h @ p["wq"]
     k = h @ p["wk"]
@@ -414,6 +425,8 @@ def _qkv(h, p, cfg: LlamaConfig, cos, sin, lora=None, slots=None):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
+    if fence:
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
     q = q.reshape(b, s, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
     v = v.reshape(b, s, cfg.n_kv_heads, cfg.head_dim)
@@ -902,7 +915,8 @@ def decode_paged(params: dict, tokens: jax.Array, caches: list[dict],
         cache = caches[layer]
         (cos, sin), page_ids, attend, table = kinds[cfg.sliding(layer)]
         h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(h, p, cfg, cos, sin, ll, slots)     # q [B,1,H,D]
+        q, k, v = _qkv(h, p, cfg, cos, sin, ll, slots,
+                       fence=True)                         # q [B,1,H,D]
         k_pages = cache["k"].at[page_ids, offsets].set(
             k.reshape(b, -1).astype(cache["k"].dtype))
         v_pages = cache["v"].at[page_ids, offsets].set(
@@ -984,7 +998,8 @@ def _prefill_rows(params: dict, chunks: jax.Array, caches: list[dict],
         cache = caches[layer]
         (cos, sin), attend, chunk_page_ids, table = kinds[cfg.sliding(layer)]
         h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q, k, v = _qkv(h, p, cfg, cos, sin, ll, slots)     # [R,C,H/KVH,D]
+        q, k, v = _qkv(h, p, cfg, cos, sin, ll, slots,
+                       fence=True)                     # [R,C,H/KVH,D]
 
         # write every row's K/V into its (page-aligned) pages
         k_pages = cache["k"].at[chunk_page_ids].set(
@@ -1158,7 +1173,8 @@ def verify_paged_rows(params: dict, tokens: jax.Array, caches: list[dict],
             cache = carry[layer]
             (cos, sin), attend, page_ids, table = kinds[cfg.sliding(layer)]
             h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-            q, k, v = _qkv(h, p, cfg, cos, sin, ll, sl)    # [1,S1,H/KVH,D]
+            q, k, v = _qkv(h, p, cfg, cos, sin, ll, sl,
+                           fence=True)                # [1,S1,H/KVH,D]
             k_pages = cache["k"].at[page_ids, offsets].set(
                 k.reshape(s1, -1).astype(cache["k"].dtype))
             v_pages = cache["v"].at[page_ids, offsets].set(

@@ -266,7 +266,12 @@ def _projections(h, p, cfg: MlaMoeConfig, cos, sin):
     (rotated), c [B, S, rank] (after its norm), k_rope [B, S, rope]
     (rotated): what both attention forms start from."""
     b, s, _ = h.shape
-    q = (h @ p["wq"]).reshape(b, s, cfg.n_heads, -1)
+    # the flat projection is finished before it is cut into heads: left
+    # free, the TPU compiler folds the reshape into the matmul and
+    # transposes all of wq for it in every layer of every dispatch
+    # (llama._qkv's ``fence``)
+    q = jax.lax.optimization_barrier(h @ p["wq"])
+    q = q.reshape(b, s, cfg.n_heads, -1)
     a = h @ p["wkv_a"]
     c = rms_norm(a[..., :cfg.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
     k_rope = apply_rope(a[:, :, None, cfg.kv_lora_rank:], cos, sin)[:, :, 0]

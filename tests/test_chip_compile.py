@@ -8,6 +8,7 @@ chunk_size x page_size the engine's defaults and bench_serve.py use.
 A compile that passes is not a chip run: nothing here measures anything.
 """
 import functools
+import math
 import os
 import re
 
@@ -518,3 +519,130 @@ def test_mellum_programs_compile_at_the_cells_widths(v5e, monkeypatch,
     assert jax.tree.leaves(compiled.out_info)[0].shape == (
         rows, mc.vocab_size)
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# ---------------------------------------------------------------------------
+# No paged program copies a projection weight (PERF.md §3, program families)
+# ---------------------------------------------------------------------------
+
+_SERVING = ("mistral7b", "olmoe7b", "mellum2-12b", "kanana2-30b")
+
+
+def _serving_model(name, n_layers):
+    """(model module, config, engine settings) of
+    ``benchmarks/configs/<name>-serve-1chip.json`` at its attention widths
+    and ``n_layers`` layers. The experts are left out where a dense MLP
+    runs the same attention block (models/llama.py: their compile time
+    buys nothing here); mla_moe keeps one dense and one expert layer, as
+    its parameter tree has both."""
+    import dataclasses
+    import json
+
+    from benchmarks import spec
+    from ray_tpu.llm.paged_engine import model_module
+    with open(os.path.join(spec.ROOT, "benchmarks", "configs",
+                           f"{name}-serve-1chip.json")) as f:
+        model = json.load(f)
+    cfg = spec.resolve(model["builder"])(model).cfg
+    cut = {"n_layers": n_layers}
+    if hasattr(cfg, "layer_types"):
+        cut["moe_experts"] = 0
+        if cfg.layer_types:        # one layer of each kind at least
+            cut["layer_types"] = cfg.layer_types[2:2 + n_layers]
+    return model_module(cfg), dataclasses.replace(cfg, **cut), model["engine"]
+
+
+# what only moves data: through these a copy's operand is followed back to
+# the program's parameter (a fusion counts when the compiler named it for
+# nothing but such steps, as in `slice_bitcast_fusion`)
+_MOVES = {"get-tuple-element", "bitcast", "reshape", "slice", "transpose",
+          "copy", "copy-start", "copy-done", "slice-start", "slice-done"}
+_MOVING_FUSION = re.compile(r"(?:slice|bitcast|copy|transpose|_)+fusion")
+
+
+def _weight_copies(text, weights):
+    """Lines of the entry computation that copy or transpose — a ``copy``,
+    or a fusion the compiler named ``copy…`` / ``transpose…`` — one of
+    ``weights`` ({name: shape}): a result with as many elements as the
+    weight, made from that weight's parameter by nothing but moves (an
+    activation of the same size comes out of a matmul or a kernel)."""
+    ops = {}
+    for ln in text[text.index("ENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \(*\w+\[([\d,]*)\]\S*.*? "
+                     r"([\w-]+)\((.*)", ln)
+        if m:
+            ops[m[1]] = (m[3], m[2], re.findall(r"%([\w.-]+)", m[4]), ln)
+
+    def source(name):
+        """The weight whose parameter ``name`` is a moved copy of."""
+        op, _, operands, ln = ops[name]
+        if op == "parameter":
+            key = re.match(r"params__(?:dense_)?layers____(\w+?)__", name)
+            return key and key[1] in weights and key[1]
+        if not (op in _MOVES
+                or op == "fusion" and _MOVING_FUSION.match(name)
+                or op == "custom-call" and "ConcatBitcast" in ln):
+            return None
+        return next(filter(None, (source(o) for o in operands if o in ops)),
+                    None)
+    sizes = {math.prod(shape) for shape in weights.values()}
+    found = []
+    for name, (op, dims, _, ln) in ops.items():
+        if (op in ("copy", "transpose") or op == "fusion"
+                and name.startswith(("copy", "transpose"))) \
+                and math.prod(map(int, filter(None, dims.split(",")))) \
+                in sizes and (weight := source(name)):
+            found.append(f"{weight}: {ln.strip()[:140]}")
+    return found
+
+
+@pytest.mark.parametrize("family", ["decode", "prefill_r4"])
+@pytest.mark.parametrize("name,n_layers", [
+    (n, 2) for n in _SERVING] + [("mistral7b", 20)],
+    ids=[*_SERVING, "mistral7b_full_depth"])
+def test_no_paged_program_copies_a_projection_weight(v5e, monkeypatch, name,
+                                                     n_layers, family):
+    """`decode_paged` at ``max_batch_size`` rows and `prefill_paged_rows`
+    at 4 x 128 of every serving configuration: each layer's projection
+    weights are read out of their stacks where they lie. Until PR 44 the
+    compiler folded the reshape into heads into the q and k projections
+    and transposed all of ``wq`` and ``wk`` for it, once a layer in every
+    dispatch (`copy bf16[4096,4096]`, 32 MB, at Mistral's widths)."""
+    from ray_tpu.models import llama, mla_moe
+
+    module, cfg, engine = _serving_model(name, n_layers)
+    one = SingleDeviceSharding(v5e.devices[0])
+    page, max_pages = engine["page_size"], engine["max_pages_per_seq"]
+    for owner in (llama, mla_moe):      # mla_moe's experts are llama's
+        monkeypatch.setattr(owner, "_on_tpu", lambda: True)
+
+    def sds(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def shaped(make):
+        return jax.tree.map(lambda s: sds(s.shape, s.dtype),
+                            jax.eval_shape(make))
+    params = shaped(lambda: module.init(jax.random.PRNGKey(0), cfg))
+    # a config with sliding layers has a second pool and a ring table
+    window = [engine["num_window_pages"]] if getattr(cfg, "sliding_window", 0) else []
+    caches = shaped(lambda: module.init_paged_cache(
+        cfg, engine["num_pages"], page, *window))
+    rows = engine["max_batch_size"] if family == "decode" else 4
+    tables = sds((rows, max_pages))
+    if window:
+        tables = (tables, sds((rows, MELLUM_RING)))
+    if family == "decode":
+        fn, args = module.decode_paged, (sds((rows, 1)), caches, tables,
+                                         sds((rows,)))
+    else:
+        fn, args = module.prefill_paged_rows, (
+            sds((rows, 128)), caches, tables, sds((rows,)), sds((rows,)))
+    text = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
+                   donate_argnums=(2,)).lower(params, *args).compile(
+                   ).as_text()
+    weights = {k: a.shape[1:] for stack in ("layers", "dense_layers")
+               for k, a in params.get(stack, {}).items()
+               if k in ("wq", "wk", "wv", "wo", "wkv_a", "w_uk", "w_uv")}
+    assert {"wq", "wo"} <= set(weights)
+    copied = _weight_copies(text, weights)
+    assert not copied, copied
