@@ -60,6 +60,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import flight
+from ..ops.grouped_matmul import MAX_TILE_ROWS
 from ..ops.ragged_paged_attention import live_key_steps
 from ..util.compile_cache import enable_compile_cache
 from ..util.profiling import StepProfiler, phase
@@ -82,7 +83,8 @@ def model_module(model_cfg):
     ``window_ring_pages`` (that case only: the width of the sliding
     layers' table), ``decode_paged``, ``prefill_paged_rows``,
     ``verify_paged_rows`` (each takes one block table, or with a window
-    the pair (full, ring)), ``routed_per_token``, ``prefill_attn_step``,
+    the pair (full, ring)), ``routed_per_token``, ``expert_routing``,
+    ``prefill_attn_step``,
     ``lora_targets`` and, under a mesh, ``check_mesh`` (which may refuse)
     and then ``logical_axes`` and ``cache_logical_axes`` (models/llama.py,
     models/mla_moe.py)."""
@@ -117,7 +119,10 @@ class PagedEngineConfig:
     # pump's a chunk.
     # decode_window only applies when no prefill is pending (window 1
     # keeps TTFT low while prompts are still entering the batch).
-    prefill_rows: int = 4
+    # prefill_rows None: the engine derives it from the model's routing
+    # (derived_prefill_rows) and its pools (_init_window_pool): 4 for a
+    # dense model, more for one whose experts' weights a dispatch streams.
+    prefill_rows: Optional[int] = None
     decode_window: int = 8
     # speculative decoding (prompt-lookup n-gram drafts, greedy only):
     # propose up to spec_tokens continuation tokens by matching the last
@@ -203,7 +208,8 @@ class PagedEngineConfig:
     def __post_init__(self):
         if self.chunk_size % self.page_size:
             raise ValueError("chunk_size must be a multiple of page_size")
-        if self.prefill_rows < 1 or self.decode_window < 1:
+        if (self.prefill_rows is not None and self.prefill_rows < 1) \
+                or self.decode_window < 1:
             raise ValueError("prefill_rows and decode_window must be >= 1")
         if self.page_buckets not in ("auto", "on", "off"):
             raise ValueError("page_buckets must be 'auto', 'on' or 'off'")
@@ -219,6 +225,31 @@ class PagedEngineConfig:
     @property
     def max_seq_len(self) -> int:
         return self.max_pages_per_seq * self.page_size
+
+
+# Chunk-rows of a prefill dispatch where the user set none
+# (derived_prefill_rows): a dense model's, and the most a routed model's
+# dispatch is grown to (2,048 tokens at the default chunk: what bounds a
+# decoding row's wait for its next token behind the program).
+_DENSE_PREFILL_ROWS = 4
+_MAX_PREFILL_ROWS = 16
+
+
+def derived_prefill_rows(routing: tuple, chunk_size: int) -> int:
+    """Chunk-rows of a prefill dispatch where the user set none, from the
+    model's ``expert_routing`` (experts, top-k). A dense model: 4 (512
+    tokens at the default chunk run its matmuls near the chip's peak). A
+    model with routed experts streams every expert's weights once a layer
+    a dispatch however few rows each gets, so its dispatch is grown until
+    it pays for the stream: the smallest power of two of rows at which an
+    expert's mean group (rows x chunk x top-k / experts) fills the grouped
+    kernel's largest row tile, from the dense 4 up to _MAX_PREFILL_ROWS."""
+    experts, top_k = routing
+    rows = _DENSE_PREFILL_ROWS
+    while experts and rows < _MAX_PREFILL_ROWS and \
+            rows * chunk_size * top_k < MAX_TILE_ROWS * experts:
+        rows *= 2
+    return rows
 
 
 # Host phases of the stepping thread: engine.stats key -> span name
@@ -329,6 +360,12 @@ class PagedInferenceEngine:
         # (the last `window` keys; _hand_back). None: one kind
         self._wpool: Optional[_PagePool] = None
         self.window = int(self.model.cache_window(mc))
+        # chunk-rows a prefill dispatch may carry: what the user set, or
+        # derived from the model's routing (and, with a window, halved
+        # until the window pool holds its ring: _init_window_pool)
+        self._routing = tuple(self.model.expert_routing(mc))
+        self.prefill_rows = cfg.prefill_rows or derived_prefill_rows(
+            self._routing, cfg.chunk_size)
         if self.window:
             self._init_window_pool()
             self.caches = self.model.init_paged_cache(
@@ -593,6 +630,21 @@ class PagedInferenceEngine:
         # count compiled under traffic (profile_summary)
         self.warm_programs = 0
 
+    # -- what a prefill dispatch carries ------------------------------------
+
+    def _prefill_row_ladder(self) -> list[int]:
+        """Every row count a prefill program is built for (ascending,
+        the budget last): a launch runs the smallest that holds its rows
+        and warm-up compiles them all. Powers of two up to the budget;
+        for a routed model's budget above 4 one rung more, not a longer
+        ladder — {1, 4, budget}: a pad row there costs its own FLOPs and
+        no weight stream (the rows share each layer's), and every rung is
+        one more program a page bucket to warm."""
+        top = self.prefill_rows
+        if self._routing[0] and top > _DENSE_PREFILL_ROWS:
+            return [1, _DENSE_PREFILL_ROWS, top]
+        return [1 << i for i in range((top - 1).bit_length())] + [top]
+
     # -- the second kind of page (a model with sliding-window layers) ------
 
     def _init_window_pool(self):
@@ -606,13 +658,20 @@ class PagedInferenceEngine:
                 "kv_spill over a two-kind (window + full) cache: the "
                 "spill tier moves one kind of page (ROADMAP R2)")
         page = cfg.page_size
-        # a sequence has at most two decode windows in flight (step())
-        write = max(cfg.prefill_rows * cfg.chunk_size, 2 * cfg.decode_window,
-                    cfg.spec_tokens + 1)
-        self._ring = self.model.window_ring_pages(mc, page, write)
-        # every sequence a ring, and what two prefill dispatches in
-        # flight hold before the first is booked and hands back
-        need = cfg.max_batch_size * self._ring + 2 * -(-write // page) + 1
+        while True:
+            # a sequence has at most two decode windows in flight (step())
+            write = max(self.prefill_rows * cfg.chunk_size,
+                        2 * cfg.decode_window, cfg.spec_tokens + 1)
+            self._ring = self.model.window_ring_pages(mc, page, write)
+            # every sequence a ring, and what two prefill dispatches in
+            # flight hold before the first is booked and hands back
+            need = cfg.max_batch_size * self._ring + 2 * -(-write // page) + 1
+            # a derived budget gives way to the pool; one the user set
+            # is refused below
+            if cfg.num_window_pages >= need or cfg.prefill_rows \
+                    or self.prefill_rows == 1:
+                break
+            self.prefill_rows //= 2
         if cfg.num_window_pages < need:
             raise ValueError(
                 f"num_window_pages={cfg.num_window_pages}: {cfg.max_batch_size}"
@@ -1058,6 +1117,13 @@ class PagedInferenceEngine:
             took = self._warmup_traced(sample_modes, families,
                                        _time.perf_counter())
         self.warm_programs = self.profiler.compiles
+        # the one line an engine writes as it starts (a replica's lands in
+        # its worker's log)
+        print(f"paged_engine: {self.warm_programs} programs warm in "
+              f"{took:.1f} s; prefill rows {self._prefill_row_ladder()} "
+              f"({'set' if self.cfg.prefill_rows else 'derived'}), page "
+              f"buckets {self._page_bucket_ladder()}",
+              file=sys.stderr, flush=True)
         return took
 
     def _warmup_traced(self, sample_modes, families, t0) -> float:
@@ -1069,9 +1135,7 @@ class PagedInferenceEngine:
         buckets = self._page_bucket_ladder()
         for mode in modes:
             for maxp in (buckets if "prefill" in families else ()):
-                rb = 1
-                while True:
-                    rb = min(rb, cfg.prefill_rows)
+                for rb in self._prefill_row_ladder():
                     tw = _time.perf_counter()
                     toks, _lps, _load, self.caches = self._prefill_rows_fn(
                         rb, mode, maxp)(
@@ -1088,9 +1152,6 @@ class PagedInferenceEngine:
                     self.profiler.record_compile(
                         _time.perf_counter() - tw, "prefill",
                         (rb, mode, maxp))
-                    if rb >= cfg.prefill_rows:
-                        break
-                    rb <<= 1
             for maxp in (buckets if "decode" in families else ()):
                 for w in sorted({1, cfg.decode_window}):
                     tw = _time.perf_counter()
@@ -1777,7 +1838,7 @@ class PagedInferenceEngine:
                 for req, pos, n in d.host["rows"]
                 for h in self._prompt_hashes(req)[pos // pg:(pos + n) // pg]
             } if self._prefix_on else ()
-            # pack up to prefill_rows chunk-rows, queue order; a request
+            # pack up to self.prefill_rows chunk-rows, queue order; a request
             # with several remaining chunks occupies consecutive rows (every
             # row's K/V is in the pages before any row attends, so later
             # rows see earlier rows' page writes)
@@ -1792,25 +1853,24 @@ class PagedInferenceEngine:
                         self._prompt_hashes(req)[pos // pg] in unpublished:
                     continue
                 while pos < len(req.prompt_ids) and \
-                        len(rows) < cfg.prefill_rows:
+                        len(rows) < self.prefill_rows:
                     n = min(c, len(req.prompt_ids) - pos)
                     if not self._ensure_window(req, pos + n):
                         break
                     rows.append((req, pos, n))
                     pos += n
-                if len(rows) >= cfg.prefill_rows:
+                if len(rows) >= self.prefill_rows:
                     break
             if not rows:
                 return False
-            # bucket the row count to a power of two (same trick as
-            # _spec_step): the jit cache holds O(log prefill_rows) prefill
-            # programs instead of one per packed-row count. Pad rows carry
-            # true_len 0, so the kernel routes all their writes to sink
-            # page 0 (prefill_paged_rows docstring) — they cost compute
-            # but no fresh XLA compile, and a mid-burst compile lands in
-            # some request's latency.
+            # bucket the row count to a rung of the ladder: the jit cache
+            # holds its few prefill programs instead of one per packed-row
+            # count. Pad rows carry true_len 0, so the kernel routes all
+            # their writes to sink page 0 (prefill_paged_rows docstring) —
+            # they cost compute but no fresh XLA compile, and a mid-burst
+            # compile lands in some request's latency.
             r = len(rows)
-            rb = min(1 << max(r - 1, 0).bit_length(), cfg.prefill_rows)
+            rb = next(b for b in self._prefill_row_ladder() if b >= r)
             # block-table width bucket: widest logical page any row reads
             # or writes this dispatch (prefix + chunk = pos + n tokens)
             ctx_pages = [(pos + n + pg - 1) // pg for _, pos, n in rows]
@@ -2819,6 +2879,10 @@ class PagedInferenceEngine:
         (in warm-up and after it) and dispatches per family. Where the
         stepping thread's time goes is in ``stats["ns_*"]`` (PHASES)."""
         return {**self.profiler.summary(),
+                # the prefill row budget (set, or derived from the model's
+                # routing and the pools) and the programs built under it
+                "prefill_rows": self.prefill_rows,
+                "prefill_row_ladder": self._prefill_row_ladder(),
                 # zero when warm-up covered every shape traffic reached
                 "in_window_compiles":
                     self.profiler.compiles - self.warm_programs,

@@ -312,6 +312,12 @@ def routed_per_token(cfg: LlamaConfig) -> int:
     return cfg.moe_top_k * cfg.n_layers if cfg.moe_experts else 0
 
 
+def expert_routing(cfg: LlamaConfig) -> tuple[int, int]:
+    """(routed experts a layer, experts a token goes to): what sizes the
+    groups of the grouped expert matmul; (0, 0) for a dense config."""
+    return (cfg.moe_experts, cfg.moe_top_k) if cfg.moe_experts else (0, 0)
+
+
 def prefill_attn_step(cfg: LlamaConfig, chunk_size: int, page_size: int,
                       table_pages: int, head_shards: int = 1) -> dict:
     """{'q_tile', 'block_keys'}: the query rows a tile and the keys a grid

@@ -207,6 +207,14 @@ def routed_per_token(cfg: MlaMoeConfig) -> int:
     return cfg.moe_top_k * (cfg.n_layers - cfg.n_dense_layers)
 
 
+def expert_routing(cfg: MlaMoeConfig) -> tuple[int, int]:
+    """(routed experts a layer, experts a token goes to): what sizes the
+    groups of the grouped expert matmul; (0, 0) where every layer is
+    dense (llama.expert_routing's twin)."""
+    return (cfg.moe_experts, cfg.moe_top_k) if routed_per_token(cfg) \
+        else (0, 0)
+
+
 def prefill_attn_step(cfg: MlaMoeConfig, chunk_size: int, page_size: int,
                       table_pages: int, head_shards: int = 1) -> dict:
     """{'q_tile', 'block_keys'}: the query rows a tile and the keys a grid

@@ -43,12 +43,18 @@ import jax.numpy as jnp
 _W_BLOCK_ELEMS = 1 << 20
 
 
+# the largest row tile: a group of this many rows reads its expert's weight
+# block for a full tile of work (what the serving engine sizes a routed
+# model's prefill dispatch by)
+MAX_TILE_ROWS = 128
+
+
 def tile_rows(assignments: int, experts: int) -> int:
     """Rows per tile: the power of two at or above twice the mean group
     (most groups then fit one tile, and the padding stays under the
-    weights' cost), from 16 (a bf16 vreg's sublanes) to 128."""
+    weights' cost), from 16 (a bf16 vreg's sublanes) to MAX_TILE_ROWS."""
     mean = max(1, assignments // max(experts, 1))
-    return min(128, max(16, 1 << (2 * mean - 1).bit_length()))
+    return min(MAX_TILE_ROWS, max(16, 1 << (2 * mean - 1).bit_length()))
 
 
 def num_tiles(assignments: int, experts: int, tm: int) -> int:

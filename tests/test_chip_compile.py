@@ -528,13 +528,13 @@ def test_mellum_programs_compile_at_the_cells_widths(v5e, monkeypatch,
 _SERVING = ("mistral7b", "olmoe7b", "mellum2-12b", "kanana2-30b")
 
 
-def _serving_model(name, n_layers):
+def _serving_model(name, n_layers, experts=False):
     """(model module, config, engine settings) of
     ``benchmarks/configs/<name>-serve-1chip.json`` at its attention widths
-    and ``n_layers`` layers. The experts are left out where a dense MLP
-    runs the same attention block (models/llama.py: their compile time
-    buys nothing here); mla_moe keeps one dense and one expert layer, as
-    its parameter tree has both."""
+    and ``n_layers`` layers. Unless ``experts``, they are left out where a
+    dense MLP runs the same attention block (models/llama.py: their
+    compile time buys nothing for a check of the projections); mla_moe
+    keeps one dense and one expert layer, as its parameter tree has both."""
     import dataclasses
     import json
 
@@ -546,7 +546,8 @@ def _serving_model(name, n_layers):
     cfg = spec.resolve(model["builder"])(model).cfg
     cut = {"n_layers": n_layers}
     if hasattr(cfg, "layer_types"):
-        cut["moe_experts"] = 0
+        if not experts:
+            cut["moe_experts"] = 0
         if cfg.layer_types:        # one layer of each kind at least
             cut["layer_types"] = cfg.layer_types[2:2 + n_layers]
     return model_module(cfg), dataclasses.replace(cfg, **cut), model["engine"]
@@ -596,21 +597,14 @@ def _weight_copies(text, weights):
     return found
 
 
-@pytest.mark.parametrize("family", ["decode", "prefill_r4"])
-@pytest.mark.parametrize("name,n_layers", [
-    (n, 2) for n in _SERVING] + [("mistral7b", 20)],
-    ids=[*_SERVING, "mistral7b_full_depth"])
-def test_no_paged_program_copies_a_projection_weight(v5e, monkeypatch, name,
-                                                     n_layers, family):
-    """`decode_paged` at ``max_batch_size`` rows and `prefill_paged_rows`
-    at 4 x 128 of every serving configuration: each layer's projection
-    weights are read out of their stacks where they lie. Until PR 44 the
-    compiler folded the reshape into heads into the q and k projections
-    and transposed all of ``wq`` and ``wk`` for it, once a layer in every
-    dispatch (`copy bf16[4096,4096]`, 32 MB, at Mistral's widths)."""
+def _paged_program(v5e, monkeypatch, module, cfg, engine, family, rows,
+                   ring):
+    """(`decode_paged` or `prefill_paged_rows` of ``rows`` x 128 tokens
+    compiled for one described chip over the cell's pools and tables — a
+    config with sliding layers has a second pool and a table of ``ring``
+    pages —, its projection weights {name: shape})."""
     from ray_tpu.models import llama, mla_moe
 
-    module, cfg, engine = _serving_model(name, n_layers)
     one = SingleDeviceSharding(v5e.devices[0])
     page, max_pages = engine["page_size"], engine["max_pages_per_seq"]
     for owner in (llama, mla_moe):      # mla_moe's experts are llama's
@@ -623,26 +617,83 @@ def test_no_paged_program_copies_a_projection_weight(v5e, monkeypatch, name,
         return jax.tree.map(lambda s: sds(s.shape, s.dtype),
                             jax.eval_shape(make))
     params = shaped(lambda: module.init(jax.random.PRNGKey(0), cfg))
-    # a config with sliding layers has a second pool and a ring table
-    window = [engine["num_window_pages"]] if getattr(cfg, "sliding_window", 0) else []
+    window = [engine["num_window_pages"]] \
+        if getattr(cfg, "sliding_window", 0) else []
     caches = shaped(lambda: module.init_paged_cache(
         cfg, engine["num_pages"], page, *window))
-    rows = engine["max_batch_size"] if family == "decode" else 4
     tables = sds((rows, max_pages))
     if window:
-        tables = (tables, sds((rows, MELLUM_RING)))
+        tables = (tables, sds((rows, ring)))
     if family == "decode":
         fn, args = module.decode_paged, (sds((rows, 1)), caches, tables,
                                          sds((rows,)))
     else:
         fn, args = module.prefill_paged_rows, (
             sds((rows, 128)), caches, tables, sds((rows,)), sds((rows,)))
-    text = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
-                   donate_argnums=(2,)).lower(params, *args).compile(
-                   ).as_text()
+    compiled = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
+                       donate_argnums=(2,)).lower(params, *args).compile()
     weights = {k: a.shape[1:] for stack in ("layers", "dense_layers")
                for k, a in params.get(stack, {}).items()
                if k in ("wq", "wk", "wv", "wo", "wkv_a", "w_uk", "w_uv")}
     assert {"wq", "wo"} <= set(weights)
+    return compiled, weights
+
+
+@pytest.mark.parametrize("family", ["decode", "prefill_r4"])
+@pytest.mark.parametrize("name,n_layers", [
+    (n, 2) for n in _SERVING] + [("mistral7b", 20)],
+    ids=[*_SERVING, "mistral7b_full_depth"])
+def test_no_paged_program_copies_a_projection_weight(v5e, monkeypatch, name,
+                                                     n_layers, family):
+    """`decode_paged` at ``max_batch_size`` rows and `prefill_paged_rows`
+    at 4 x 128 of every serving configuration: each layer's projection
+    weights are read out of their stacks where they lie. Until PR 44 the
+    compiler folded the reshape into heads into the q and k projections
+    and transposed all of ``wq`` and ``wk`` for it, once a layer in every
+    dispatch (`copy bf16[4096,4096]`, 32 MB, at Mistral's widths)."""
+    module, cfg, engine = _serving_model(name, n_layers)
+    rows = engine["max_batch_size"] if family == "decode" else 4
+    compiled, weights = _paged_program(v5e, monkeypatch, module, cfg, engine,
+                                       family, rows, MELLUM_RING)
+    copied = _weight_copies(compiled.as_text(), weights)
+    assert not copied, copied
+
+
+# ---------------------------------------------------------------------------
+# The top rung of a routed model's prefill ladder (PR 45): the row budget
+# the engine derives from the model's routing, with the experts in
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name,rows", [
+    ("olmoe7b", 8), ("mellum2-12b", 8), ("kanana2-30b", 16)])
+def test_the_derived_top_rung_compiles_with_its_experts(v5e, monkeypatch,
+                                                        name, rows):
+    """`prefill_paged_rows` at the rows `derived_prefill_rows` gives the
+    configuration's routing (1,024 tokens for 64 experts top-8, the cap of
+    2,048 for 128 experts top-6), experts in, two layers: the grouped
+    kernels at 128-row tiles and the attention kernels at that many rows
+    compile for the chip, no projection weight is copied, and the program's
+    temporaries stay under 1 GiB beside pools and weights that fill the
+    chip. Mellum's ring at 8 rows fits the cell's window pool (at 16 it
+    would not: the engine halves a derived budget until it does)."""
+    from ray_tpu.llm.paged_engine import derived_prefill_rows
+    from ray_tpu.ops import ragged_paged_attention as rpa
+
+    module, cfg, engine = _serving_model(name, 2, experts=True)
+    assert derived_prefill_rows(module.expert_routing(cfg), 128) == rows
+    ring = 0
+    if getattr(cfg, "sliding_window", 0):
+        def need(r):
+            return engine["max_batch_size"] * rpa.window_table_pages(
+                cfg.sliding_window, engine["page_size"], r * 128) \
+                + 2 * r * 128 // engine["page_size"] + 1
+        assert need(rows) <= engine["num_window_pages"] < need(2 * rows)
+        ring = rpa.window_table_pages(cfg.sliding_window,
+                                      engine["page_size"], rows * 128)
+    compiled, weights = _paged_program(v5e, monkeypatch, module, cfg, engine,
+                                       "prefill", rows, ring)
+    text = compiled.as_text()
+    assert "grouped_swiglu" in text and "grouped_matmul" in text
     copied = _weight_copies(text, weights)
     assert not copied, copied
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -213,7 +213,7 @@ def test_dispatch_and_request_counters(engine):
     assert d["decode_steps"] >= d["decode_dispatches"] > 0
     assert 0 < d["prefill_rows_live"] <= d["prefill_rows_padded"]
     assert d["prefill_rows_padded"] <= \
-        d["prefill_dispatches"] * engine.cfg.prefill_rows
+        d["prefill_dispatches"] * engine.prefill_rows
     assert d["prefill_tokens"] + d["prefix_tokens_saved"] == \
         sum(o["prompt_tokens"] for o in outs)
     assert d["prefill_ctx_pages"] >= d["prefill_rows_live"]

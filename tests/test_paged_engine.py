@@ -34,8 +34,11 @@ RUN_AHEAD_MODELS = dict(
 
 
 def _tiny_engine(kind, **over):
+    # prefill_rows is set: these tests count the rows of a 4-row dispatch
+    # (the tiny routed model's derived budget is another, tested below)
     kw = dict(model=RUN_AHEAD_MODELS[kind](max_seq_len=128), max_batch_size=4,
-              page_size=8, num_pages=64, max_pages_per_seq=16, chunk_size=16)
+              page_size=8, num_pages=64, max_pages_per_seq=16, chunk_size=16,
+              prefill_rows=4)
     if kind == "window":
         kw["num_window_pages"] = 64
     kw.update(over)
@@ -857,3 +860,114 @@ def test_import_between_steps_waits_for_the_outstanding_decode(solo):
     want = [pre.generate([p], sp)[0]["token_ids"] for p in (a, b)]
     assert [first.out_ids, second.out_ids] == want
     assert not pre._inflight and not pre.has_work()     # prefill_export's
+
+
+# -- the prefill row budget: derived from the model's routing and the pools --
+
+def _window_need(rows, *, batch=4, window=16, page=8, chunk=16):
+    """Window pages `batch` sequences need at a budget of ``rows``
+    (_init_window_pool's sum)."""
+    from ray_tpu.ops.ragged_paged_attention import window_table_pages
+    write = rows * chunk
+    return batch * window_table_pages(window, page, write) \
+        + 2 * -(-write // page) + 1
+
+
+assert _window_need(8) < _window_need(16)
+
+
+def _routed_window(**kw):
+    # 32 experts top-2 at chunk 16: the mean group reaches no tile before
+    # the cap, so the routing alone derives 16 rows
+    return llama.llama_tiny(
+        vocab_size=258, moe_experts=32, moe_top_k=2, mlp_dim=16,
+        layer_types=("sliding", "full"), sliding_window=16, **kw)
+
+
+ROW_BUDGETS = {
+    # case: (model, engine overrides, budget, ladder) — None: refused
+    "dense": (lambda: llama.llama_tiny(), dict(chunk_size=128), 4, [1, 2, 4]),
+    "64_experts_top8": (
+        lambda: llama.llama_tiny(moe_experts=64, moe_top_k=8, mlp_dim=16),
+        dict(chunk_size=128), 8, [1, 4, 8]),
+    "128_experts_top6": (
+        lambda: mla_moe.mla_moe_tiny(moe_experts=128, moe_top_k=6, mlp_dim=8),
+        dict(chunk_size=128), 16, [1, 4, 16]),
+    "every_layer_dense": (
+        lambda: mla_moe.mla_moe_tiny(n_layers=1, n_dense_layers=1),
+        dict(chunk_size=128), 4, [1, 2, 4]),
+    "window_pool_holds_8_not_16": (
+        _routed_window, dict(num_window_pages=_window_need(8)), 8, [1, 4, 8]),
+    "window_pool_holds_16": (
+        _routed_window, dict(num_window_pages=_window_need(16)), 16,
+        [1, 4, 16]),
+    "set_by_the_user": (
+        lambda: mla_moe.mla_moe_tiny(), dict(prefill_rows=2), 2, [1, 2]),
+    "set_by_the_user_routed_8": (
+        lambda: mla_moe.mla_moe_tiny(), dict(prefill_rows=8), 8, [1, 4, 8]),
+    "set_by_the_user_dense_8": (
+        lambda: llama.llama_tiny(), dict(prefill_rows=8), 8, [1, 2, 4, 8]),
+    "set_by_the_user_over_the_pool": (
+        _routed_window,
+        dict(prefill_rows=16, num_window_pages=_window_need(8)), None, None),
+}
+
+
+@pytest.mark.parametrize("case", list(ROW_BUDGETS))
+def test_prefill_row_budget(case):
+    """Unset, the budget follows the model's routing (4 dense; rows until
+    an expert's mean group fills the grouped kernel's largest tile, at
+    most 16) and gives way to the window pool; set, it is kept, and
+    refused where the window pool cannot hold its ring."""
+    model, over, budget, ladder = ROW_BUDGETS[case]
+    kw = dict(model=model(), max_batch_size=4, page_size=8, num_pages=64,
+              max_pages_per_seq=16, chunk_size=16)
+    kw.update(over)
+    if budget is None:
+        with pytest.raises(ValueError, match="num_window_pages"):
+            PagedInferenceEngine(PagedEngineConfig(**kw))
+        return
+    eng = PagedInferenceEngine(PagedEngineConfig(**kw))
+    assert eng.prefill_rows == budget
+    assert eng._prefill_row_ladder() == ladder
+    summary = eng.profile_summary()
+    assert summary["prefill_rows"] == budget
+    assert summary["prefill_row_ladder"] == ladder
+    assert eng.cfg.prefill_rows == over.get("prefill_rows")    # untouched
+
+
+def test_derived_budget_gives_the_tokens_of_four_rows_and_compiles_nothing(
+        capsys):
+    """A routed model at its derived budget (16 rows here): greedy tokens
+    of prompts of 1-20 chunks equal those at prefill_rows=4; warm-up holds
+    at most three prefill programs a page bucket, says so in its one log
+    line, and a run that packs every row count from 1 to the budget then
+    compiles nothing."""
+    kw = dict(model=mla_moe.mla_moe_tiny(vocab_size=258, max_seq_len=256),
+              max_batch_size=4, page_size=8, num_pages=160,
+              max_pages_per_seq=24, chunk_size=8, page_buckets="on",
+              enable_prefix_caching=False)
+    four = PagedInferenceEngine(PagedEngineConfig(prefill_rows=4, **kw))
+    eng = PagedInferenceEngine(PagedEngineConfig(**kw), four.params)
+    assert eng.prefill_rows == 16 and four.prefill_rows == 4
+    eng.warmup()
+    assert "prefill rows [1, 4, 16] (derived)" in capsys.readouterr().err
+    assert eng.warm_programs == eng.profiler.compiles > 0
+    for bucket in eng._page_bucket_ladder():
+        rows = sorted(k[0] for k in eng._prefill_rows_fns if k[2] == bucket)
+        assert rows == [1, 4, 16]
+    # lengths of 1 to 20 chunks, whole and ragged, queued together
+    sp = SamplingParams(max_tokens=5)
+    prompts = [_ids(n, 70 + n) for n in (8, 13, 40, 160, 3, 96, 121, 64, 27)]
+    got = eng.generate(prompts, sp)
+    want = four.generate(prompts, sp)
+    assert [g["token_ids"] for g in got] == [w["token_ids"] for w in want]
+    assert eng.stats["prefill_tokens"] == four.stats["prefill_tokens"]
+    assert eng.stats["prefill_dispatches"] < four.stats["prefill_dispatches"]
+    # every row count once: a prompt of r whole chunks alone is one dispatch
+    before = eng.stats["prefill_dispatches"]
+    for r in range(1, eng.prefill_rows + 1):
+        eng.generate([_ids(8 * r, 100 + r)], SamplingParams(max_tokens=2))
+    assert eng.stats["prefill_dispatches"] == before + eng.prefill_rows
+    assert eng.profiler.compiles == eng.warm_programs
+    assert eng.profile_summary()["in_window_compiles"] == 0
