@@ -3,7 +3,7 @@ the cache heat plane).
 
 A *chain* is a family of prompts sharing the same first full KV page —
 the chain-head hash ``h_0 = H(salt || page_0_tokens)`` of the engine's
-chained content hashes (paged_engine._hash_chain). Every request whose
+chained content hashes (kv_cache.hash_chain). Every request whose
 prompt opens with the same system prompt (under the same tenant salt)
 lands in one chain, so chain granularity is exactly the granularity
 cache policy cares about: "this assistant's system prompt is hot",

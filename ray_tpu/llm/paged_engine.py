@@ -48,11 +48,10 @@ costs a step, not what the device waits for (PERF.md §3).
 from __future__ import annotations
 
 import dataclasses
-import hashlib
 import sys
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import deque
 from typing import Any, Optional
 
 import jax
@@ -68,6 +67,7 @@ from . import telemetry
 from .engine import (  # noqa: F401 — SamplingParams re-exported
     SamplingParams, _Request, sample_logits_batch,
 )
+from .kv_cache import WINDOW_COUNTERS, KVCache, WindowPages, window_need
 from .tokenizer import get_tokenizer
 
 
@@ -120,7 +120,7 @@ class PagedEngineConfig:
     # decode_window only applies when no prefill is pending (window 1
     # keeps TTFT low while prompts are still entering the batch).
     # prefill_rows None: the engine derives it from the model's routing
-    # (derived_prefill_rows) and its pools (_init_window_pool): 4 for a
+    # (derived_prefill_rows) and its pools (kv_cache.window_need): 4 for a
     # dense model, more for one whose experts' weights a dispatch streams.
     prefill_rows: Optional[int] = None
     decode_window: int = 8
@@ -163,14 +163,13 @@ class PagedEngineConfig:
     # (exact — padded lanes contribute 0·0 terms)
     lora_rank: int = 8
     lora_targets: tuple = ("wq", "wk", "wv", "wo", "lm_head")
-    # automatic prefix caching (vLLM-style block-hash reuse): retired
-    # requests park their full KV pages in a content-addressed LRU pool
+    # automatic prefix caching (vLLM-style block-hash reuse, llm/kv_cache.py):
+    # retired requests park their full KV pages in a content-addressed LRU
     # instead of freeing them; a later request whose prompt shares a
-    # page-aligned prefix maps those pages into its block table and starts
-    # chunked prefill at the first uncached, chunk-aligned token. Shared
-    # pages are refcounted and read-only (every write lands past the
-    # cached region, so divergence copies instead of corrupting); the LRU
-    # pool is reclaimed page-by-page under allocation pressure.
+    # page-aligned prefix maps them into its block table and starts chunked
+    # prefill at the first uncached chunk. Shared pages are refcounted and
+    # read-only (every write lands past the cached region); the LRU is
+    # reclaimed page by page under allocation pressure.
     enable_prefix_caching: bool = True
     # cache heat plane (llm/chainstats.py): fixed-memory per-chain stats
     # keyed by chain-head hash — hits/misses/evictions/imports per
@@ -284,42 +283,6 @@ STREAM_COUNTERS = ("stream_chunks", "stream_lag_ns", "stream_first_chunks",
                    "stream_deferred")
 
 
-class _PagePool:
-    """One page-id space: its free list, the references requests hold,
-    the content index of published pages (hash <-> page) and the LRU of
-    published pages nobody holds (``tiers``: reclaimed tier by tier, the
-    least recently parked first in each; ``lru`` is the last tier). Page
-    0 is the space's write sink and is never handed out. The engine has
-    one space (every layer keeps every key), or two: the full layers',
-    and the window layers' with a cold tier before its LRU (a model with
-    sliding layers, PagedInferenceEngine._hand_back)."""
-
-    def __init__(self, num_pages: int, tiers: int = 1):
-        self.num_pages = num_pages
-        self.free = list(range(1, num_pages))
-        self.refs = np.zeros((num_pages,), np.int32)
-        self.hash_to_page: dict[bytes, int] = {}
-        self.page_to_hash: dict[int, bytes] = {}
-        # insertion order = eviction order
-        self.tiers = [OrderedDict() for _ in range(tiers)]
-        self.lru: "OrderedDict[int, None]" = self.tiers[-1]
-
-    def avail(self) -> int:
-        """Pages allocatable right now: truly free + reclaimable."""
-        return len(self.free) + sum(map(len, self.tiers))
-
-    def live(self) -> int:
-        """Pages some request holds."""
-        return self.num_pages - 1 - self.avail()
-
-    def unpark(self, pid: int) -> None:
-        """Take a published page nobody holds out of the reclaimable
-        tiers: it is pinned, or reclaimed."""
-        for tier in self.tiers:
-            if tier.pop(pid, False) is not False:
-                return
-
-
 @dataclasses.dataclass
 class _Launched:
     """A dispatch launched and not yet read back."""
@@ -355,37 +318,31 @@ class PagedInferenceEngine:
         if params is None:
             params = self.model.init(jax.random.PRNGKey(rng_seed), mc)
         self.params = params
-        # a model with sliding-window layers keeps two kinds of page: the
-        # full layers' (every key of a sequence) and the window layers'
-        # (the last `window` keys; _hand_back). None: one kind
-        self._wpool: Optional[_PagePool] = None
-        self.window = int(self.model.cache_window(mc))
         # chunk-rows a prefill dispatch may carry: what the user set, or
-        # derived from the model's routing (and, with a window, halved
-        # until the window pool holds its ring: _init_window_pool)
+        # derived from the model's routing; a derived budget gives way to
+        # the window pool of a model with sliding layers, halved until the
+        # pool holds its rings (the cache refuses one the user set)
         self._routing = tuple(self.model.expert_routing(mc))
         self.prefill_rows = cfg.prefill_rows or derived_prefill_rows(
             self._routing, cfg.chunk_size)
-        if self.window:
-            self._init_window_pool()
-            self.caches = self.model.init_paged_cache(
-                mc, cfg.num_pages, cfg.page_size, cfg.num_window_pages)
-        else:
-            if cfg.num_window_pages:
-                raise ValueError(
-                    "num_window_pages is for a model with sliding-window "
-                    f"layers; {type(mc).__name__} has none")
-            self.caches = self.model.init_paged_cache(mc, cfg.num_pages,
-                                                      cfg.page_size)
-            self._window_layers = [False] * len(self.caches)
-        # page 0 is the write sink for slots that are idle during a decode
-        # step (their dummy token writes land there, never attended); it is
-        # never allocated to a sequence
-        self._pool = _PagePool(cfg.num_pages)
-        self._free_pages = self._pool.free
+        while not cfg.prefill_rows and self.prefill_rows > 1 and \
+                cfg.num_window_pages < window_need(
+                    cfg, self.model, self.prefill_rows)[1]:
+            self.prefill_rows //= 2
+        # counters, filled in below: the cache books into the same dict
+        self.stats: dict = {}
+        # who holds which page, whatever kinds of layer the model has
+        # (llm/kv_cache.py); the pools, on the device, are self.caches
+        self.cache = KVCache(cfg, self.model, self.stats, self.prefill_rows)
+        self.window = int(self.model.cache_window(mc))  # keys kept; 0: all
+        if cfg.kv_spill and self.cache.two_kinds:
+            raise ValueError(
+                "kv_spill over a two-kind (window + full) cache: the "
+                "spill tier moves one kind of page (ROADMAP R2)")
+        self.caches = self.model.init_paged_cache(
+            mc, cfg.num_pages, cfg.page_size,
+            *([cfg.num_window_pages] if self.cache.two_kinds else []))
         self._free_slots = deque(range(cfg.max_batch_size))
-        self._block_tables = np.zeros(
-            (cfg.max_batch_size, cfg.max_pages_per_seq), np.int32)
         # a slot's tokens in the cache or written there by a program
         # launched: a decode's launch moves it by what it allows the row
         # (no booking does), a verify dispatch's booking by what it kept
@@ -396,36 +353,10 @@ class PagedInferenceEngine:
         # launched, not yet read back, oldest first: at most three, and
         # one between two step() calls (step())
         self._inflight: deque[_Launched] = deque()
-        # -- prefix cache state (enable_prefix_caching) -------------------
-        # Full pages are content-addressed by a chained hash
-        # h_i = H(h_{i-1} || page_token_ids) — the chain makes the flat
-        # dict an implicit trie: a page's key encodes its whole prefix.
-        # _page_refs counts live request references per page; pages whose
-        # refcount drops to zero but that hold published (hashed) content
-        # park in _cached_lru (insertion order = eviction order) instead
-        # of returning to _free_pages, and are reclaimed LRU-first when
-        # allocation outruns the free list.
-        self._prefix_on = bool(cfg.enable_prefix_caching)
-        # the full pool's parts under the names this file has always used
-        self._page_refs = self._pool.refs
-        self._hash_to_page = self._pool.hash_to_page
-        self._page_to_hash = self._pool.page_to_hash
-        self._cached_lru = self._pool.lru
-        # cluster prefix-directory delta tracking (serve/frontdoor):
-        # hashes registered/unregistered since the last drain. Appended
-        # only when track_page_publish is on (the serving layer enables
-        # it), and only ever touched from the stepping thread — the
-        # drain contract (drain_directory_delta) keeps it lock-free.
-        self.track_page_publish = False
-        self._dir_new: list[bytes] = []
-        self._dir_dropped: list[bytes] = []
-        # per-chain heat table (llm/chainstats.py): observation only —
-        # no policy path reads it. _chain_of maps a registered page to
-        # the chain slot it was published under, so evictions can be
-        # attributed without re-deriving hashes; pages whose chain was
-        # never learned fall to the overflow sink on eviction.
+        self._prefix_on = self.cache.prefix_on
+        # per-chain heat table (llm/chainstats.py): observation only, no
+        # policy path reads it (the cache tells it of hits and evictions)
         self.chains = None
-        self._chain_of: dict[int, int] = {}
         # bytes one page holds over every pool of every layer (with a
         # window: of every full layer, what a token of a long sequence
         # costs for good; window_page_nbytes is what it costs while a
@@ -433,7 +364,7 @@ class PagedInferenceEngine:
         per_layer = [sum(int(pool.nbytes) // pool.shape[0]
                          for pool in layer.values()) for layer in self.caches]
         self.window_page_nbytes = sum(
-            n for n, w in zip(per_layer, self._window_layers) if w)
+            n for n, w in zip(per_layer, self.cache.window_layers) if w)
         self.page_nbytes = page_nbytes = \
             sum(per_layer) - self.window_page_nbytes
         if self._prefix_on and cfg.chain_stats_slots > 0:
@@ -456,6 +387,10 @@ class PagedInferenceEngine:
                 SpillPolicy(min_hits=cfg.kv_spill_min_hits,
                             max_idle_s=cfg.kv_spill_max_idle_s))
             self.spill.bind_chains(self.chains)
+        self.cache.log.chains = self.chains
+        if self.spill is not None:
+            self.cache.log.demote = self._maybe_demote
+            self.cache.promote = self._promote_for_locked
         self._next_rid = 0
         # resident-adapter slot table (cfg.max_adapters): device arrays
         # every dispatch gathers per-row; loads are donated scatters the
@@ -511,75 +446,62 @@ class PagedInferenceEngine:
         self._prefill_rows_fns: dict[tuple, Any] = {}
         self._verify_fns: dict[tuple, Any] = {}
         # observability: dispatches per program family, spec accept stats
-        self.stats = {"prefill_dispatches": 0, "decode_dispatches": 0,
-                      # launches made while another dispatch was
-                      # outstanding: how often step() runs ahead
-                      "dispatches_overlapped": 0,
-                      # of a decode launched behind an unbooked decode:
-                      # rows whose first token came from the device (the
-                      # last tokens that decode left there), and rows x
-                      # steps run for a request the booking before found
-                      # done (a stop the host could not foresee)
-                      "decode_rows_fed_on_device": 0,
-                      "decode_dead_rows": 0,
-                      "spec_dispatches": 0, "spec_proposed": 0,
-                      "spec_accepted": 0, "tokens_out": 0,
-                      # prefix cache: full prompt pages served from cache
-                      # vs computed by prefill, LRU pages reclaimed under
-                      # pressure, and prompt tokens whose prefill was
-                      # skipped entirely
-                      "prefix_hits": 0, "prefix_misses": 0,
-                      "prefix_evictions": 0, "prefix_tokens_saved": 0,
-                      # pages seeded from ANOTHER replica's cache via the
-                      # cluster prefix directory (import_prefix), and
-                      # cached pages gathered FOR a peer (export_prefix)
-                      "prefix_imported_pages": 0,
-                      "prefix_exported_pages": 0,
-                      # spill tier (cfg.kv_spill): pages/bytes captured
-                      # into the host tier, demote decisions that kept
-                      # a tier copy (captures + clean re-evictions),
-                      # pages promoted back into HBM (admission-time,
-                      # re-warm, or cross-replica via the directory),
-                      # pages expired from the tier (budget/teardown),
-                      # and validate-on-promote drops (stale/corrupt
-                      # tier content — cost a cold prefill, nothing
-                      # else). All permanently 0 while kv_spill is off.
-                      "spill_pages": 0, "spill_bytes": 0,
-                      "spill_demotions": 0, "spill_promotions": 0,
-                      "spill_expired": 0, "spill_drops": 0,
-                      # mesh-parallel dispatch accounting (cfg.mesh):
-                      # host<->device bytes a dispatch legitimately moves
-                      # (token-id/table inputs, sampled-token outputs) vs
-                      # bytes that would move because a committed buffer
-                      # drifted off its pinned sharding. The reshard
-                      # counter staying 0 IS the zero-involuntary-reshard
-                      # contract; all permanently 0 while mesh is off.
-                      "mesh_dispatches": 0, "mesh_input_bytes": 0,
-                      "mesh_output_bytes": 0, "mesh_reshard_bytes": 0,
-                      # work decided at the dispatch, summed over
-                      # dispatches: slots and KV pages the decode program
-                      # had live (of max_batch_size rows x the table's
-                      # width it runs: decode_table_pages), device
-                      # steps (the window w), prefill rows live / run
-                      # (the power-of-two bucket), prompt tokens
-                      # prefilled, the pages their rows attend and the
-                      # causal (query, key) pairs they score. Each is
-                      # read by a per-layer metric of the benchmark
-                      # (PERF.md §3)
-                      "decode_live_slots": 0, "decode_live_pages": 0,
-                      "decode_table_pages": 0, "decode_steps": 0,
-                      "prefill_rows_live": 0,
-                      "prefill_rows_padded": 0, "prefill_tokens": 0,
-                      "prefill_ctx_pages": 0, "prefill_attn_pairs": 0,
-                      # live grid steps one layer's window kernel swept
-                      # for the prefill rows, and those of them whose
-                      # block needed the live-key predicate
-                      "prefill_key_steps": 0,
-                      "prefill_key_steps_masked": 0,
-                      # request stamps, exact: submit -> admit and
-                      # admit -> first token, summed over requests
-                      "admitted": 0, "queue_wait_ns": 0,
-                      "first_tokens": 0, "prefill_span_ns": 0}
+        self.stats.update({
+            "prefill_dispatches": 0, "decode_dispatches": 0,
+            # launches made while another dispatch was outstanding: how often
+            # step() runs ahead
+            "dispatches_overlapped": 0,
+            # of a decode launched behind an unbooked decode: rows whose first
+            # token came from the device (the last tokens that decode left
+            # there), and rows x steps run for a request the booking before
+            # found done (a stop the host could not foresee)
+            "decode_rows_fed_on_device": 0, "decode_dead_rows": 0,
+            "spec_dispatches": 0, "spec_proposed": 0, "spec_accepted": 0,
+            "tokens_out": 0,
+            # prefix cache: full prompt pages served from cache vs computed by
+            # prefill, LRU pages reclaimed under pressure, and prompt tokens
+            # whose prefill was skipped entirely
+            "prefix_hits": 0, "prefix_misses": 0, "prefix_evictions": 0,
+            "prefix_tokens_saved": 0,
+            # pages seeded from ANOTHER replica's cache via the cluster prefix
+            # directory (import_prefix), and cached pages gathered FOR a peer
+            # (export_prefix)
+            "prefix_imported_pages": 0, "prefix_exported_pages": 0,
+            # spill tier (cfg.kv_spill): pages/bytes captured into the host
+            # tier, demote decisions that kept a tier copy (captures + clean
+            # re-evictions), pages promoted back into HBM (admission-time,
+            # re-warm, or cross-replica via the directory), pages expired from
+            # the tier (budget/teardown), and validate-on-promote drops
+            # (stale/corrupt tier content: cost a cold prefill, nothing else).
+            # All permanently 0 while kv_spill is off.
+            "spill_pages": 0, "spill_bytes": 0, "spill_demotions": 0,
+            "spill_promotions": 0, "spill_expired": 0, "spill_drops": 0,
+            # mesh-parallel dispatch accounting (cfg.mesh): host<->device bytes
+            # a dispatch legitimately moves (token-id/table inputs,
+            # sampled-token outputs) vs bytes that would move because a
+            # committed buffer drifted off its pinned sharding. The reshard
+            # counter staying 0 IS the zero-involuntary-reshard contract; all
+            # permanently 0 while mesh is off.
+            "mesh_dispatches": 0, "mesh_input_bytes": 0,
+            "mesh_output_bytes": 0, "mesh_reshard_bytes": 0,
+            # work decided at the dispatch, summed over dispatches: slots and
+            # KV pages the decode program had live (of max_batch_size rows x
+            # the table's width it runs: decode_table_pages), device steps (the
+            # window w), prefill rows live / run (the power-of-two bucket),
+            # prompt tokens prefilled, the pages their rows attend and the
+            # causal (query, key) pairs they score. Each is read by a per-layer
+            # metric of the benchmark (PERF.md §3)
+            "decode_live_slots": 0, "decode_live_pages": 0,
+            "decode_table_pages": 0, "decode_steps": 0, "prefill_rows_live": 0,
+            "prefill_rows_padded": 0, "prefill_tokens": 0,
+            "prefill_ctx_pages": 0, "prefill_attn_pairs": 0,
+            # live grid steps one layer's window kernel swept for the prefill
+            # rows, and those of them whose block needed the live-key predicate
+            "prefill_key_steps": 0, "prefill_key_steps_masked": 0,
+            # request stamps, exact: submit -> admit and admit -> first token,
+            # summed over requests
+            "admitted": 0, "queue_wait_ns": 0, "first_tokens": 0,
+            "prefill_span_ns": 0})
         # the stepping thread's time by phase (util/profiling.phase):
         # ns_<phase> sums, max_ns_<phase> keeps the longest occurrence.
         # The eight engine phases partition step(); the two loop phases
@@ -599,24 +521,9 @@ class PagedInferenceEngine:
         if self.model.routed_per_token(mc):
             self.stats.update(moe_assign_live=0, moe_assign_run=0,
                               moe_expert_load_sum=0, moe_expert_load_max=0)
-        # a model with sliding-window layers: pages claimed from and
-        # handed back to each pool, each pool's live pages summed over
-        # decode dispatches (their mean is the pool's fill), admissions
-        # whose cached prefix was cut short, or lost, for want of the
-        # window layers' pages behind it (and the tokens that cost), and
-        # the window layers' share of the work decided at a dispatch: the
-        # pages a decode step streams of the ring it runs, the pages and
-        # (query, key) pairs of the prefill rows. Other models have no
-        # such keys
-        if self.window:
-            self.stats.update(dict.fromkeys((
-                "window_pages_claimed", "window_pages_returned",
-                "full_pages_claimed", "full_pages_returned",
-                "window_pool_live_pages", "full_pool_live_pages",
-                "window_evictions", "prefix_tail_cut", "prefix_tail_lost",
-                "prefix_tail_tokens_lost", "decode_live_wpages",
-                "decode_table_wpages", "prefill_ctx_wpages",
-                "prefill_attn_wpairs"), 0))
+        # a model with layers of two kinds: what the cache counts of each
+        if self.cache.two_kinds:
+            self.stats.update(dict.fromkeys(WINDOW_COUNTERS, 0))
         # speculation controller: EMA of tokens-per-slot-per-spec-dispatch
         # (starts optimistic), plus a cooldown of windowed dispatches
         # before re-probing once the EMA drops below the window
@@ -645,144 +552,11 @@ class PagedInferenceEngine:
             return [1, _DENSE_PREFILL_ROWS, top]
         return [1 << i for i in range((top - 1).bit_length())] + [top]
 
-    # -- the second kind of page (a model with sliding-window layers) ------
-
-    def _init_window_pool(self):
-        """The window layers' pool and block tables. A table is a ring
-        (logical page p in column p % width) as wide as a window plus the
-        most one dispatch writes past its oldest query, so its width is
-        one number whatever the context: no page bucket, no program."""
-        cfg, mc = self.cfg, self.cfg.model
-        if cfg.kv_spill:
-            raise ValueError(
-                "kv_spill over a two-kind (window + full) cache: the "
-                "spill tier moves one kind of page (ROADMAP R2)")
-        page = cfg.page_size
-        while True:
-            # a sequence has at most two decode windows in flight (step())
-            write = max(self.prefill_rows * cfg.chunk_size,
-                        2 * cfg.decode_window, cfg.spec_tokens + 1)
-            self._ring = self.model.window_ring_pages(mc, page, write)
-            # every sequence a ring, and what two prefill dispatches in
-            # flight hold before the first is booked and hands back
-            need = cfg.max_batch_size * self._ring + 2 * -(-write // page) + 1
-            # a derived budget gives way to the pool; one the user set
-            # is refused below
-            if cfg.num_window_pages >= need or cfg.prefill_rows \
-                    or self.prefill_rows == 1:
-                break
-            self.prefill_rows //= 2
-        if cfg.num_window_pages < need:
-            raise ValueError(
-                f"num_window_pages={cfg.num_window_pages}: {cfg.max_batch_size}"
-                f" sequences of a {self.window}-key window need {need} "
-                f"pages of {page} (a ring of {self._ring} each)")
-        self._wpool = _PagePool(cfg.num_window_pages, tiers=2)
-        self._wtables = np.zeros((cfg.max_batch_size, self._ring), np.int32)
-        # which layers' pools are the window pool's, told by a probe of
-        # shapes alone: the engine reads nothing else of a cache
-        probe = jax.eval_shape(
-            lambda: self.model.init_paged_cache(mc, 2, page, 1))
-        self._window_layers = [
-            next(iter(layer.values())).shape[0] == 1 for layer in probe]
-
-    def _tables(self, full, rows=None):
-        """What a program takes as its block tables: ``full`` [n, W], and
-        with a window the pair (full, ring [n, ring]) — the rings of
-        engine slots ``rows``, or zeros (warm-up, padding)."""
-        if self._wpool is None:
-            return full
-        ring = np.zeros((len(full), self._ring), np.int32)
-        for i, slot in enumerate(() if rows is None else rows):
-            if slot >= 0:
-                ring[i] = self._wtables[slot]
-        return full, ring
-
     def _refuse_two_kinds(self, what: str):
-        if self._wpool is not None:
+        if self.cache.two_kinds:
             raise NotImplementedError(
                 f"{what} over a two-kind (window + full) cache: a payload "
                 "carries one kind of page (ROADMAP R2)")
-
-    def _ensure_window(self, req: _Request, upto_tokens: int) -> bool:
-        """Grow req's window pages to cover upto_tokens; False if that
-        pool is dry (it cannot be while num_window_pages holds its
-        floor). True at once where the model has no window."""
-        pool = self._wpool
-        if pool is None:
-            return True
-        have = req.wlo + len(req.wpages)
-        need = self._pages_needed(upto_tokens) - have
-        if need <= 0:
-            return True
-        if pool.avail() < need:
-            return False
-        ring = self._wtables[req.slot]
-        for p in range(have, have + need):
-            pid = self._pop_free_page(pool)
-            pool.refs[pid] = 1
-            ring[p % self._ring] = pid
-            req.wpages.append(pid)
-        self.stats["window_pages_claimed"] += need
-        return True
-
-    def _hand_back(self, req: _Request, next_pos: int):
-        """Give back req's window pages every key of which is a window or
-        more behind ``next_pos``, the oldest query still to be launched
-        for it. Called from a booking: a dispatch in flight has its own
-        copy of the ring and reads nothing behind its own oldest query,
-        and whoever gets the page next writes it in a later program. A
-        published page parks where the prefix cache can still find it
-        (_cold says in which tier)."""
-        pool, page = self._wpool, self.cfg.page_size
-        n = min(max(next_pos - self.window + 1, 0) // page - req.wlo,
-                len(req.wpages))
-        if n <= 0:
-            return
-        for i, pid in enumerate(req.wpages[:n]):
-            self._decref(pid, pool, self._cold(req, req.wlo + i))
-        del req.wpages[:n]
-        req.wlo += n
-        self.stats["window_pages_returned"] += n
-
-    def _cold(self, req: _Request, logical_page: int) -> bool:
-        """Is a window page worth less than the others once nobody holds
-        it? The pool is a few windows a sequence, so what it keeps
-        unheld has to be chosen. A window page serves the prefix cache
-        only as part of the TAIL of a prefix: the window - 1 keys before
-        the point where a later prompt leaves this one. Cold, reclaimed
-        before any other: a page too far back to be in the tail of a
-        prefix that ends within a window of this prompt's end — where a
-        follow-up's shared prefix ends (the same document and another
-        question, the next turn) — and a page in a sequence's first two
-        windows, which guards a prefix that is cheap to compute again."""
-        first_key = logical_page * self.cfg.page_size
-        return (first_key + self.window < len(req.prompt_ids) - self.window
-                or first_key + self.cfg.page_size <= 2 * self.window)
-
-    def _match_window(self, req: _Request, matched: list[int]) -> tuple:
-        """Cut a cached run of full pages (``_match_prefix``) back to the
-        longest whole-chunk prefix whose window tail is cached too: a
-        prefix of N tokens is a hit only if the window layers' pages over
-        the window - 1 keys before N are held. Returns (the run, the
-        tail's first logical page, the tail's pages, the pages of the run
-        that were cut for want of a tail)."""
-        pool, page = self._wpool, self.cfg.page_size
-        per_chunk = self.cfg.chunk_size // page
-        tail = -(-(self.window - 1) // page)
-        hashes = self._prompt_hashes(req)
-        found = n = len(matched)
-        lo, got = 0, []
-        while n > 0:
-            lo = max(n - tail, 0)
-            got = [pool.hash_to_page.get(hashes[i]) for i in range(lo, n)]
-            gone = [i for i, pid in enumerate(got) if pid is None]
-            if not gone:
-                break
-            # no prefix whose tail holds the newest missing page is a hit
-            n = (lo + gone[-1]) // per_chunk * per_chunk
-        return (matched[:n], lo, got, found - n) if n else (
-            [], 0, [], found)
 
     # -- mesh-parallel placement (cfg.mesh) --------------------------------
 
@@ -1141,7 +915,7 @@ class PagedInferenceEngine:
                         rb, mode, maxp)(
                         self.params, self.caches,
                         np.zeros((rb, c), np.int32),
-                        self._tables(np.zeros((rb, maxp), np.int32)),
+                        self.cache.tables([-1] * rb, maxp),
                         np.zeros((rb,), np.int32), np.zeros((rb,), np.int32),
                         key, ctr, np.zeros((rb,), np.float32),
                         np.zeros((rb,), np.int32),
@@ -1158,7 +932,7 @@ class PagedInferenceEngine:
                     out, _lps, _load, self._last, self.caches = \
                         self._decode_window_fn(w, mode, maxp)(
                         self.params, self.caches, np.zeros((bs,), np.int32),
-                        self._tables(np.zeros((bs, maxp), np.int32)),
+                        self.cache.tables([-1] * bs, maxp),
                         np.zeros((bs,), np.int32), key, ctr,
                         np.zeros((bs,), np.float32),
                         np.zeros((bs,), np.int32),
@@ -1178,7 +952,7 @@ class PagedInferenceEngine:
                         rb, s1, maxp)(
                         self.params, self.caches,
                         np.zeros((rb, s1), np.int32),
-                        self._tables(np.zeros((rb, maxp), np.int32)),
+                        self.cache.tables([-1] * rb, maxp),
                         np.zeros((rb,), np.int32),
                         *self._lora_args(np.zeros((rb,), np.int32)))
                     np.asarray(y)
@@ -1269,45 +1043,12 @@ class PagedInferenceEngine:
                          and req.out_logps else None),
         }
 
-    # -- page allocation ---------------------------------------------------
+    # -- the spill tier's hooks into the cache (cfg.kv_spill) ---------------
 
-    def _pages_needed(self, tokens: int) -> int:
-        return (tokens + self.cfg.page_size - 1) // self.cfg.page_size
-
-    def _pages_avail(self) -> int:
-        """Pages allocatable right now: truly free + LRU-reclaimable."""
-        return len(self._free_pages) + len(self._cached_lru)
-
-    def _pop_free_page(self, pool: Optional[_PagePool] = None) -> int:
-        """One allocatable page of ``pool`` (the full pool when left out);
-        evicts the least-recently-used unreferenced cached page when the
-        free list is dry. Never touches a page with live references —
-        only refcount-0 pages sit in the LRU. Callers must check the
-        pool's avail() first."""
-        if pool is self._wpool is not None:
-            if pool.free:
-                return pool.free.pop()
-            pid, _ = next(t for t in pool.tiers if t).popitem(last=False)
-            self._unregister(pid, pool)
-            self.stats["window_evictions"] += 1
-            return pid
-        if self._free_pages:
-            return self._free_pages.pop()
-        pid, _ = self._cached_lru.popitem(last=False)
-        if self.spill is not None:
-            # demote hook: capture the page's KV for the host tier
-            # BEFORE _unregister drops the hash mapping and the page id
-            # is handed back (the device page gets overwritten by its
-            # next owner)
-            self._maybe_demote(pid)
-        self._unregister(pid)
-        self.stats["prefix_evictions"] += 1
-        return pid
-
-    def _maybe_demote(self, pid: int):
-        h = self._page_to_hash.get(pid)
-        if h is None or self._hash_to_page.get(h) != pid:
-            return      # unpublished page: nothing content-addressed
+    def _maybe_demote(self, pid: int, h: bytes, slot: Optional[int]):
+        """The cache evicts published page ``pid`` (hash ``h``, chain
+        ``slot``), whose next owner overwrites the device page: capture
+        its KV for the host tier first (kv_cache.IndexLog.demote)."""
         if self.spill.has(h):
             # content already in the tier (promoted or re-computed,
             # then evicted again): a clean eviction — refresh recency,
@@ -1315,7 +1056,6 @@ class PagedInferenceEngine:
             self.spill.touch(h)
             self.stats["spill_demotions"] += 1
             return
-        slot = self._chain_of.get(pid)
         now = time.monotonic()
         if not self.spill.policy.admit(self.chains, slot, now):
             return      # heat-gated: not worth tier residence — free
@@ -1350,288 +1090,13 @@ class PagedInferenceEngine:
             if self.chains is not None:
                 self.chains.spilled_sub(chain)
 
-    def _unregister(self, pid: int, pool: Optional[_PagePool] = None):
-        if pool is self._wpool is not None:
-            # the window pool's index is this engine's alone: no
-            # directory, no chain, no tier hears of it
-            h = pool.page_to_hash.pop(pid, None)
-            if h is not None and pool.hash_to_page.get(h) == pid:
-                del pool.hash_to_page[h]
-            return
-        h = self._page_to_hash.pop(pid, None)
-        if h is not None and self._hash_to_page.get(h) == pid:
-            del self._hash_to_page[h]
-            if self.track_page_publish:
-                self._dir_dropped.append(h)
-                if len(self._dir_dropped) > 4 * self.cfg.num_pages:
-                    # publisher not draining (no directory attached):
-                    # drop the log — un-dropped stale entries are hints
-                    # the importer validates anyway
-                    del self._dir_dropped[:]
-        if self.chains is not None:
-            # heat attribution: pages whose chain was never learned fold
-            # to the overflow sink, so per-chain eviction totals always
-            # sum to the aggregate prefix_evictions counter
-            slot = self._chain_of.pop(pid, None)
-            if slot is None:
-                slot = 0
-            else:
-                self.chains.resident_sub(slot)
-            self.chains.evict(slot)
-            flight.evt(flight.PREFIX_EVICT, pid, slot)
-
-    def _incref(self, pid: int, pool: Optional[_PagePool] = None):
-        """Pin a page for a request; a cached (refcount-0) page leaves
-        the eviction pool."""
-        pool = pool or self._pool
-        if pool.refs[pid] == 0:
-            pool.unpark(pid)
-        pool.refs[pid] += 1
-
-    def _decref(self, pid: int, pool: Optional[_PagePool] = None,
-                cold: bool = False):
-        """Drop one reference; at zero the page parks in the cached LRU
-        (published content, reusable; ``cold``: in the tier that is
-        reclaimed first) or returns to the free list."""
-        pool = pool or self._pool
-        pool.refs[pid] -= 1
-        if pool.refs[pid] > 0:
-            return
-        if pid in pool.page_to_hash:
-            # most-recently-released last
-            (pool.tiers[0] if cold else pool.lru)[pid] = None
-        else:
-            pool.free.append(pid)
-
-    def _claim_pages(self, matched: list[int],
-                     n_pages: int) -> Optional[list[int]]:
-        """Assemble a page list: pin `matched` (a cached prefix run), then
-        allocate fresh pages up to n_pages. Returns None — with NO side
-        effects — when the pool cannot cover the remainder. Matches are
-        pinned BEFORE any fresh allocation (an allocation could otherwise
-        evict a still-unpinned match), and claiming an unreferenced LRU
-        page removes an eviction candidate, so those count against
-        availability. Shared by admission and PD import so their pool
-        accounting can never diverge."""
-        need = n_pages - len(matched)
-        if need > self._pages_avail() - sum(
-                1 for p in matched if self._page_refs[p] == 0):
-            return None
-        for pid in matched:
-            self._incref(pid)
-        pages = list(matched)
-        for _ in range(need):
-            pid = self._pop_free_page()
-            self._page_refs[pid] = 1
-            pages.append(pid)
-        if self.window:
-            self.stats["full_pages_claimed"] += n_pages
-        return pages
-
-    def _ensure_pages(self, req: _Request, upto_tokens: int) -> bool:
-        """Grow req's page list to cover upto_tokens; False if pool dry."""
-        need = self._pages_needed(upto_tokens) - len(req.pages)
-        if need <= 0:
-            return True
-        if self._pages_avail() < need:
-            return False
-        for _ in range(need):
-            pid = self._pop_free_page()
-            self._page_refs[pid] = 1
-            req.pages.append(pid)
-        bt = self._block_tables[req.slot]
-        bt[:len(req.pages)] = req.pages
-        if self.window:
-            self.stats["full_pages_claimed"] += need
-        return True
-
     def _release(self, req: _Request):
-        if self._prefix_on:
-            self._register_request_pages(req)
-        for pid in req.pages:
-            self._decref(pid)
-        if self.window:
-            self.stats["full_pages_returned"] += len(req.pages)
-            self.stats["window_pages_returned"] += len(req.wpages)
-            for i, pid in enumerate(req.wpages):
-                self._decref(pid, self._wpool, self._cold(req, req.wlo + i))
-            req.wpages, req.wlo = [], 0
-            if req.slot >= 0:
-                self._wtables[req.slot, :] = 0
-        req.pages = []
+        """A retired request's pages and its slot go back."""
+        self.cache.release(req)
         if req.slot >= 0:
-            # zero the row so nothing stale survives into the next tenant
-            # (writes through leftover entries would hit recycled pages)
-            self._block_tables[req.slot, :] = 0
             self._free_slots.append(req.slot)
             self._lengths[req.slot] = 0
             req.slot = -1
-
-    # -- prefix cache (enable_prefix_caching) ------------------------------
-
-    def _hash_chain(self, tokens, prev: bytes = b"") -> list[bytes]:
-        """Chained content hashes of `tokens`' FULL pages: each full page
-        is keyed by H(parent_digest || page_token_ids), so equal keys
-        imply equal whole prefixes (the flat index is an implicit trie).
-        blake2b over the raw int32 bytes: stable across processes, so
-        PD-disagg payloads can carry the hashes verbatim."""
-        page = self.cfg.page_size
-        arr = np.asarray(tokens, np.int32)
-        out = []
-        for i in range(len(arr) // page):
-            prev = hashlib.blake2b(
-                prev + arr[i * page:(i + 1) * page].tobytes(),
-                digest_size=16).digest()
-            out.append(prev)
-        return out
-
-    def _prompt_hashes(self, req: _Request) -> list[bytes]:
-        if req.page_hashes is None:
-            # the chain SEED is the request's prefix salt (empty for the
-            # base model): adapter requests hash into a disjoint key
-            # space per (adapter_id, version), so cached/directory pages
-            # can never match across tenants — required for correctness
-            # (different adapters write different K/V for equal tokens),
-            # and what keeps warmed prefixes tenant-private
-            req.page_hashes = self._hash_chain(req.prompt_ids,
-                                               prev=req.prefix_salt)
-        return req.page_hashes
-
-    def _reuse_limit(self, req: _Request) -> int:
-        """Most prompt tokens admissible from cache: chunk-aligned (so
-        prefill resumes on a chunk boundary) and strictly short of the
-        prompt, so at least one token always prefills — the request's
-        first generated token is sampled from real last-position logits."""
-        c = self.cfg.chunk_size
-        return ((len(req.prompt_ids) - 1) // c) * c
-
-    def _match_prefix(self, req: _Request) -> list[int]:
-        """Longest cached page run covering the prompt's head, truncated
-        to whole chunks and to _reuse_limit. Pure lookup — no pinning."""
-        if not self._prefix_on:
-            return []
-        limit = self._reuse_limit(req)
-        if limit <= 0:
-            return []
-        page = self.cfg.page_size
-        hashes = self._prompt_hashes(req)
-        pages: list[int] = []
-        for i in range(limit // page):
-            pid = self._hash_to_page.get(hashes[i])
-            if pid is None:
-                break
-            pages.append(pid)
-        per_chunk = self.cfg.chunk_size // page
-        return pages[:(len(pages) // per_chunk) * per_chunk]
-
-    def _try_reuse(self, req: _Request):
-        """Mid-prefill reuse: jump req.prefill_pos over chunks whose pages
-        another request has published since this one was admitted (an
-        identical-prompt burst: the first request prefills, the rest map
-        its pages in as they land). Swapped-out private pages go straight
-        back to the free list."""
-        if not self._prefix_on:
-            return
-        c, page = self.cfg.chunk_size, self.cfg.page_size
-        pos = req.prefill_pos
-        if pos % c:
-            return
-        limit = self._reuse_limit(req)
-        hashes = self._prompt_hashes(req)
-        while pos < limit:
-            idxs = range(pos // page, (pos + c) // page)
-            pids = [self._hash_to_page.get(hashes[i]) for i in idxs]
-            if any(p is None for p in pids):
-                break
-            if self._wpool is not None:
-                # the chunk's keys enter the window of what follows: its
-                # window pages are mapped in too (the ones before it are
-                # this request's own already), or the chunk is computed
-                wpids = [self._wpool.hash_to_page.get(hashes[i])
-                         for i in idxs]
-                if any(p is None for p in wpids) or \
-                        req.wlo + len(req.wpages) != pos // page:
-                    break
-                for i, pid in zip(idxs, wpids):
-                    self._incref(pid, self._wpool)
-                    self._wtables[req.slot, i % self._ring] = pid
-                    req.wpages.append(pid)
-                self.stats["window_pages_claimed"] += len(wpids)
-                self._hand_back(req, pos + c)
-            for i, pid in zip(idxs, pids):
-                old = req.pages[i]
-                if old == pid:
-                    continue
-                self._incref(pid)
-                req.pages[i] = pid
-                self._decref(old)
-            pos += c
-            self.stats["prefix_hits"] += len(pids)
-            self.stats["prefix_tokens_saved"] += c
-            req.prefix_tokens_saved += c
-            if self.chains is not None and req.chain_slot >= 0:
-                self.chains.hit(req.chain_slot, len(pids), c)
-        if pos != req.prefill_pos:
-            req.prefill_pos = pos
-            self._block_tables[req.slot, :len(req.pages)] = req.pages
-
-    def _register_page(self, pid: int, h: bytes, chain: int = -1,
-                       pool: Optional[_PagePool] = None):
-        pool = pool or self._pool
-        if pid in pool.page_to_hash or h in pool.hash_to_page:
-            return      # already published, or duplicate content elsewhere
-        pool.page_to_hash[pid] = h
-        pool.hash_to_page[h] = pid
-        if pool is not self._pool:
-            return
-        if self.chains is not None and chain >= 0:
-            self._chain_of[pid] = chain
-            self.chains.resident_add(chain)
-        if self.track_page_publish:
-            self._dir_new.append(h)
-            if len(self._dir_new) > 4 * self.cfg.num_pages:
-                # publisher not draining: the delta log is redundant
-                # with the index itself — compress to a full resync so
-                # an undrained engine's memory stays bounded
-                self._dir_new = list(self._hash_to_page)
-
-    def _register_request_pages(self, req: _Request):
-        """Publish req's full, KV-materialized pages into the content
-        index (retirement path). KV is materialized for the prompt plus
-        every generated token except the last — a sampled token's K/V is
-        only written when it is fed back on the next dispatch — so pages
-        holding generated text become reusable for multi-turn follow-ups
-        whose prompt embeds this request's output."""
-        page = self.cfg.page_size
-        n_tok = len(req.prompt_ids) + max(len(req.out_ids) - 1, 0)
-        if req.prefill_pos < len(req.prompt_ids):
-            # released mid-prefill (e.g. a future cancel path): only
-            # positions < prefill_pos hold computed KV — publishing
-            # further pages would serve garbage to matching prompts
-            n_tok = req.prefill_pos
-        n_full = min(n_tok // page, len(req.pages))
-        if n_full <= 0:
-            return
-        hashes = self._prompt_hashes(req)
-        if n_full > len(hashes):
-            tokens = (req.prompt_ids + req.out_ids)[
-                len(hashes) * page:n_full * page]
-            hashes = hashes + self._hash_chain(
-                tokens, prev=hashes[-1] if hashes else req.prefix_salt)
-        if self.chains is not None and req.chain_slot < 0 and hashes:
-            # short prompts never visited the admission-time chain
-            # assignment; learn the chain here so the published pages'
-            # evictions attribute to it instead of the overflow sink
-            req.chain_slot = self.chains.slot_for(hashes[0],
-                                                  req.prefix_salt)
-        for i in range(n_full):
-            self._register_page(req.pages[i], hashes[i],
-                                chain=req.chain_slot)
-        # the window pages still held: the tail of a prefix that ends
-        # within a window of this sequence's end
-        for i in range(req.wlo, min(n_full, req.wlo + len(req.wpages))):
-            self._register_page(req.wpages[i - req.wlo], hashes[i],
-                                pool=self._wpool)
 
     # -- engine loop -------------------------------------------------------
 
@@ -1757,64 +1222,21 @@ class PagedInferenceEngine:
     def _admit(self):
         with self._lock:
             while self._pending and self._free_slots:
-                # admission control: hold requests until the pool can cover
-                # the whole prompt (avoids deadlocking a half-prefilled seq)
+                # held back until the pools cover the whole prompt; then
+                # its pages, the cached prefix mapped in (KVCache.admit)
                 req = self._pending[0]
-                matched = self._match_prefix(req)
-                if self.spill is not None and \
-                        self._promote_for_locked(req, len(matched)) > 0:
-                    # promoted pages registered + LRU-parked: re-walk
-                    # so the match (and the hit accounting below) sees
-                    # them exactly like never-evicted pages
-                    matched = self._match_prefix(req)
-                wlo, wmatched, cut = 0, [], 0
-                if self._wpool is not None and matched:
-                    matched, wlo, wmatched, cut = self._match_window(
-                        req, matched)
-                pages = self._claim_pages(
-                    matched, self._pages_needed(len(req.prompt_ids) + 1))
-                if pages is None:
+                if not self.cache.admit(req, self._free_slots[0]):
                     break
                 self._pending.popleft()
-                req.slot = self._free_slots.popleft()
-                req.pages = pages
-                self._block_tables[req.slot, :len(pages)] = pages
-                if wmatched:
-                    # the tail is pinned; the window pages of what is
-                    # still to be computed are claimed dispatch by
-                    # dispatch (_ensure_window)
-                    for i, pid in enumerate(wmatched):
-                        self._incref(pid, self._wpool)
-                        self._wtables[req.slot, (wlo + i) % self._ring] = pid
-                    req.wlo, req.wpages = wlo, list(wmatched)
-                    self.stats["window_pages_claimed"] += len(wmatched)
-                if cut:
-                    self.stats["prefix_tail_cut" if matched
-                               else "prefix_tail_lost"] += 1
-                    self.stats["prefix_tail_tokens_lost"] += \
-                        cut * self.cfg.page_size
-                if self.chains is not None:
-                    hs = self._prompt_hashes(req)
-                    if hs:
-                        req.chain_slot = self.chains.slot_for(
-                            hs[0], req.prefix_salt)
-                        if self.spill is not None and req.chain_slot > 0:
-                            # remember the chain's longest head-rooted
-                            # hash run — what proactive re-warm promotes
-                            prev = self._chain_runs.get(req.chain_slot)
-                            if prev is None or len(hs) > len(prev):
-                                self._chain_runs[req.chain_slot] = \
-                                    list(hs[:self.cfg.max_pages_per_seq])
-                if matched:
-                    # chunked prefill starts at the first uncached chunk
-                    # boundary
-                    req.prefill_pos = len(matched) * self.cfg.page_size
-                    req.prefix_tokens_saved = req.prefill_pos
-                    self.stats["prefix_hits"] += len(matched)
-                    self.stats["prefix_tokens_saved"] += req.prefill_pos
-                    if self.chains is not None:
-                        self.chains.hit(req.chain_slot, len(matched),
-                                        req.prefill_pos)
+                self._free_slots.popleft()
+                if self.spill is not None and req.chain_slot > 0:
+                    # remember the chain's longest head-rooted hash run —
+                    # what proactive re-warm promotes
+                    hs = self.cache.prompt_hashes(req)
+                    prev = self._chain_runs.get(req.chain_slot)
+                    if prev is None or len(hs) > len(prev):
+                        self._chain_runs[req.chain_slot] = \
+                            list(hs[:self.cfg.max_pages_per_seq])
                 self._prefilling.append(req)
                 telemetry.on_admit(self, req)
                 self.stats["admitted"] += 1
@@ -1836,7 +1258,8 @@ class PagedInferenceEngine:
             unpublished = {
                 h for d in self._inflight if d.family == "prefill"
                 for req, pos, n in d.host["rows"]
-                for h in self._prompt_hashes(req)[pos // pg:(pos + n) // pg]
+                for h in self.cache.prompt_hashes(req)[
+                    pos // pg:(pos + n) // pg]
             } if self._prefix_on else ()
             # pack up to self.prefill_rows chunk-rows, queue order; a request
             # with several remaining chunks occupies consecutive rows (every
@@ -1846,16 +1269,17 @@ class PagedInferenceEngine:
             for req in self._prefilling:
                 # skip ahead over chunks published since the last step (an
                 # identical-prefix burst: request 1 computes, the rest map)
-                self._try_reuse(req)
+                self.cache.reuse(req)
                 pos = req.prefill_pos
                 if unpublished and pos % c == 0 and \
-                        pos < self._reuse_limit(req) and \
-                        self._prompt_hashes(req)[pos // pg] in unpublished:
+                        pos < self.cache.reuse_limit(len(req.prompt_ids)) \
+                        and self.cache.prompt_hashes(req)[
+                            pos // pg] in unpublished:
                     continue
                 while pos < len(req.prompt_ids) and \
                         len(rows) < self.prefill_rows:
                     n = min(c, len(req.prompt_ids) - pos)
-                    if not self._ensure_window(req, pos + n):
+                    if not self.cache.ensure(req, pos + n):
                         break
                     rows.append((req, pos, n))
                     pos += n
@@ -1876,7 +1300,6 @@ class PagedInferenceEngine:
             ctx_pages = [(pos + n + pg - 1) // pg for _, pos, n in rows]
             W = self._page_bucket(max(ctx_pages))
             chunks = np.zeros((rb, c), np.int32)
-            bts = np.zeros((rb, W), np.int32)
             sps = np.zeros((rb,), np.int32)
             tls = np.zeros((rb,), np.int32)
             temps = np.zeros((rb,), np.float32)
@@ -1884,7 +1307,6 @@ class PagedInferenceEngine:
             lslots = np.zeros((rb,), np.int32)
             for i, (req, pos, n) in enumerate(rows):
                 chunks[i, :n] = req.prompt_ids[pos:pos + n]
-                bts[i] = self._block_tables[req.slot][:W]
                 sps[i], tls[i] = pos, n
                 temps[i] = req.params.temperature
                 topks[i] = req.params.top_k
@@ -1894,7 +1316,8 @@ class PagedInferenceEngine:
                 req.prefill_pos = pos + n
             mode = self._sampling_mode([q for q, _, _ in rows])
             fn = self._prefill_rows_fn(rb, mode, W)
-            tables = self._tables(bts, [q.slot for q, _, _ in rows])
+            tables = self.cache.tables(
+                [q.slot for q, _, _ in rows] + [-1] * (rb - r), W)
         with self._launch("prefill"):
             with self.profiler.step("prefill", (rb, mode, W)):
                 toks, lps, load, self.caches = fn(
@@ -1904,7 +1327,7 @@ class PagedInferenceEngine:
             self._launched(
                 "prefill", (toks, lps, load), rows=rows, rb=rb, W=W,
                 ctx_pages=ctx_pages, sps=sps, tls=tls,
-                in_bytes=chunks.nbytes + bts.nbytes + sps.nbytes
+                in_bytes=chunks.nbytes + 4 * rb * W + sps.nbytes
                 + tls.nbytes + temps.nbytes + topks.nbytes + lslots.nbytes)
         return True
 
@@ -1935,10 +1358,7 @@ class PagedInferenceEngine:
             in_bytes,
             toks.nbytes + sum(x.nbytes for x in (lps, load)
                               if x is not None))
-        if self._prefix_on:
-            self._publish_prefilled(rows)
-        if self.window:
-            self._book_window_rows(rows)
+        self.cache.booked_prefill(rows)
         for i, (req, pos, n) in enumerate(rows):
             if pos + n >= len(req.prompt_ids):
                 # prompt done: the row's in-jit sampled token is the
@@ -1949,40 +1369,6 @@ class PagedInferenceEngine:
         # NOTE: pad positions of the final chunk were written into the
         # sequence's own pages beyond its true length; decode masks
         # positions >= length so they are never attended.
-
-    def _book_window_rows(self, rows):
-        """The window layers' part of a prefill booking: the pages and
-        (query, key) pairs the rows' window kernel swept, and the pages
-        the rows have moved past, handed back."""
-        st, pg, win = self.stats, self.cfg.page_size, self.window
-        for req, pos, n in rows:
-            st["prefill_ctx_wpages"] += (
-                (pos + n - 1) // pg - max(pos - win + 1, 0) // pg + 1)
-            # query q attends min(q + 1, window) keys
-            ramp = min(max(win - 1 - pos, 0), n)
-            st["prefill_attn_wpairs"] += (
-                ramp * pos + ramp * (ramp + 1) // 2 + (n - ramp) * win)
-            if req.slot >= 0:       # not retired since its launch
-                self._hand_back(req, pos + n)
-
-    def _publish_prefilled(self, rows):
-        """Full prompt pages the dispatch's rows computed are misses;
-        publish them immediately so the rest of a burst can reuse them
-        (their K/V is fully written once the dispatch returns)."""
-        page = self.cfg.page_size
-        for req, pos, n in rows:
-            lo, hi = pos // page, (pos + n) // page
-            self.stats["prefix_misses"] += hi - lo
-            if self.chains is not None and hi > lo \
-                    and req.chain_slot >= 0:
-                self.chains.miss(req.chain_slot, hi - lo)
-            hashes = self._prompt_hashes(req)
-            for j in range(lo, hi):
-                self._register_page(req.pages[j], hashes[j],
-                                    chain=req.chain_slot)
-                if self._wpool is not None and j >= req.wlo:
-                    self._register_page(req.wpages[j - req.wlo], hashes[j],
-                                        pool=self._wpool)
 
     def _first_token(self, req: _Request, tok: int, lp: Optional[float]):
         """A prompt's last chunk returned: book its first generated
@@ -2004,7 +1390,7 @@ class PagedInferenceEngine:
         if getattr(req, "prefill_only", False):
             # disaggregated prefill: export the KV pages + first token
             # instead of decoding here (llm/pd_disagg.py). Under the
-            # pool lock: _release mutates _free_slots/_page_refs,
+            # pool lock: _release mutates _free_slots and the cache,
             # which a concurrent submit/import_prefill (replica
             # threads) also touches — and the export must not observe
             # a cache swap mid-gather. _finish_request stays OUTSIDE
@@ -2084,12 +1470,10 @@ class PagedInferenceEngine:
         return int(sum(-(-int(self._lengths[sl]) // page) for sl in slots))
 
     def _live_window_pages(self, slots) -> int:
-        """Window pages one decode step's window kernel streams: those
-        that hold the ``window`` keys up to each slot's current token."""
-        page, win = self.cfg.page_size, self.window
-        return int(sum(
-            int(n) // page - max(int(n) + 1 - win, 0) // page + 1
-            for n in (self._lengths[sl] for sl in slots)))
+        """Window pages one decode step streams for ``slots``: by this
+        name tests/benchmark_harness holds benchmarks/flops_window to it."""
+        return WindowPages.live_pages(
+            self._lengths, slots, self.cfg.page_size, self.window)
 
     def _spec_step(self) -> bool:
         """One speculative verify dispatch over every active slot. Only
@@ -2134,7 +1518,6 @@ class PagedInferenceEngine:
             W = self._page_bucket(max(
                 (self._lengths[sl] + s1 - 1) // page + 1 for sl in slots))
             toks = np.zeros((rb, s1), np.int32)
-            bts = np.zeros((rb, W), np.int32)
             starts = np.zeros((rb,), np.int32)
             lslots = np.zeros((rb,), np.int32)
             allow: dict[int, int] = {}
@@ -2143,12 +1526,11 @@ class PagedInferenceEngine:
                 allow[slot] = self._reserve(req, s1)
                 toks[i, 0] = req.out_ids[-1]
                 toks[i, 1:1 + len(drafts[slot])] = drafts[slot]
-                bts[i] = self._block_tables[slot][:W]
                 starts[i] = self._lengths[slot]
                 lslots[i] = req.adapter_slot
             want_lp = any(self._active[sl].params.logprobs for sl in slots)
             fn = self._verify_fn(rb, s1, W, want_lp)
-            tables = self._tables(bts, slots)
+            tables = self.cache.tables(slots + [-1] * (rb - r), W)
         with self._launch("decode"):
             with self.profiler.step("verify", (rb, s1, W, want_lp)):
                 y, ylp, load, self.caches = fn(
@@ -2164,7 +1546,7 @@ class PagedInferenceEngine:
             self.stats["spec_dispatches"] += 1
             self._moe_account(load, r * s1, rb * s1)
             self._mesh_account(
-                toks.nbytes + bts.nbytes + starts.nbytes + lslots.nbytes,
+                toks.nbytes + 4 * rb * W + starts.nbytes + lslots.nbytes,
                 y.nbytes + sum(x.nbytes for x in (ylp, load)
                                if x is not None))
             emitted = 0
@@ -2199,8 +1581,8 @@ class PagedInferenceEngine:
                     if self._stop_after(req, tok):
                         self._retire(req)
                         break
-                if self.window and req.slot >= 0:
-                    self._hand_back(req, int(self._lengths[slot]))
+                if req.slot >= 0:
+                    self.cache.advanced(req, int(self._lengths[slot]))
                 emitted += consumed
             # controller: keep speculating only while it beats the window;
             # on fallback, re-probe optimistically after a cooldown that
@@ -2288,34 +1670,26 @@ class PagedInferenceEngine:
             # never a clamp)
             W = self._page_bucket(max(
                 (lengths[sl] + w - 1) // page + 1 for sl in rows))
-            # slots not decoding this step get a zeroed block-table row:
-            # their dummy writes go to sink page 0 instead of a live
-            # (possibly reused) page
-            bt = np.zeros((bs, W), np.int32)
-            for slot in rows:
-                bt[slot] = self._block_tables[slot][:W]
             reqs = {slot: req for slot, (req, _) in rows.items()}
             mode = self._sampling_mode(reqs.values())
             fn = self._decode_window_fn(w, mode, W)
-            tables = self._tables(
-                bt, [sl if sl in reqs else -1 for sl in range(bs)])
+            # slots not decoding this step get a zeroed block-table row:
+            # their dummy writes go to sink page 0 instead of a live
+            # (possibly reused) page
+            tables = self.cache.tables(
+                [sl if sl in reqs else -1 for sl in range(bs)], W)
         with self._launch("decode"):
             with self.profiler.step("decode", (w, mode, W)):
                 out, lps, load, self._last, self.caches = fn(
                     self.params, self.caches, tokens, tables, lengths,
                     self._rng_base, np.int32(self._rng_ctr), temps, topks,
                     self._last, fed, *self._lora_args(lslots))
-            if self.window:
-                # counted at the launch, as live_pages is: what the
-                # program streams of the ring it runs
-                self.stats["decode_live_wpages"] += \
-                    self._live_window_pages(reqs)
-                self.stats["decode_table_wpages"] += bs * self._ring
+            self.cache.launched_decode(lengths, reqs)
             self._launched(
                 "decode", (out, lps, load), reqs=reqs, allow=allow, w=w,
                 fed_rows=int(fed.sum()),
-                live_pages=self._live_pages(reqs), table_pages=bt.size,
-                in_bytes=tokens.nbytes + fed.nbytes + bt.nbytes
+                live_pages=self._live_pages(reqs), table_pages=bs * W,
+                in_bytes=tokens.nbytes + fed.nbytes + 4 * bs * W
                 + lengths.nbytes + temps.nbytes + topks.nbytes
                 + lslots.nbytes)
             # the next launch starts where this one ends: no booking
@@ -2371,11 +1745,9 @@ class PagedInferenceEngine:
                 if self._stop_after(req, tok):
                     self._retire(req)
                     break
-            if self.window and req.slot >= 0:
-                self._hand_back(req, int(self._lengths[slot]))
-        if self.window:
-            st["window_pool_live_pages"] += self._wpool.live()
-            st["full_pool_live_pages"] += self._pool.live()
+            if req.slot >= 0:
+                self.cache.advanced(req, int(self._lengths[slot]))
+        self.cache.booked_decode()
 
     def _reserve(self, req: _Request, width: int, fly: int = 0) -> int:
         """Pre-allocate pages for up to `width` new tokens and return how
@@ -2394,13 +1766,9 @@ class PagedInferenceEngine:
         total = len(req.prompt_ids) + out
         remaining = max(req.params.max_tokens - out, 1)
         target = min(total + min(width, remaining), self.cfg.max_seq_len)
-        if self._ensure_pages(req, target) and \
-                self._ensure_window(req, target):
+        if self.cache.ensure(req, target):
             return target - total
-        held = len(req.pages)
-        if self.window:
-            held = min(held, req.wlo + len(req.wpages))
-        return max(held * self.cfg.page_size - total, 0)
+        return max(self.cache.held(req) * self.cfg.page_size - total, 0)
 
     def _at_limit(self, req: _Request, out: int) -> bool:
         """Is a request of ``out`` generated tokens at max_tokens or at
@@ -2434,8 +1802,7 @@ class PagedInferenceEngine:
         if not stop:
             # growing by one token may need one more page
             total = len(req.prompt_ids) + len(req.out_ids)
-            if not (self._ensure_pages(req, total + 1)
-                    and self._ensure_window(req, total + 1)):
+            if not self.cache.ensure(req, total + 1):
                 stop = True  # pool exhausted: finish early rather than wedge
                 telemetry.on_preempted(self)
         if stop:
@@ -2456,7 +1823,7 @@ class PagedInferenceEngine:
                 # chained content hashes of the FULL prompt pages, in page
                 # order: the decode side dedupes payload pages it already
                 # holds instead of re-allocating and re-scattering them
-                "page_hashes": list(self._prompt_hashes(req)),
+                "page_hashes": list(self.cache.prompt_hashes(req)),
                 # the chain's seed, so the decode side's request hashes
                 # land in the same (tenant-scoped) key space
                 "prefix_salt": req.prefix_salt,
@@ -2499,7 +1866,7 @@ class PagedInferenceEngine:
             if not self._free_slots:
                 raise RuntimeError("no free decode slot")
             req.slot = self._free_slots.popleft()
-            n_pages = self._pages_needed(len(ids) + 1)
+            n_pages = self.cache.pages_for(len(ids) + 1)
             n_in = len(next(iter(payload["pages"][0].values())))
             if n_in != n_pages:
                 self._release(req)
@@ -2515,21 +1882,16 @@ class PagedInferenceEngine:
             # it at position len(ids)).
             hashes = payload.get("page_hashes")
             if hashes is None and self._prefix_on:
-                hashes = self._hash_chain(ids, prev=req.prefix_salt)
-            matched: list[int] = []
-            if self._prefix_on and hashes:
-                for h in hashes:      # chain property: a prefix run
-                    pid = self._hash_to_page.get(h)
-                    if pid is None:
-                        break
-                    matched.append(pid)
-            pages = self._claim_pages(matched, n_pages)
-            if pages is None:
+                hashes = self.cache.hash_chain(ids, prev=req.prefix_salt)
+            # chain property: what is held is a prefix run
+            matched = self.cache.index.run(hashes) \
+                if self._prefix_on and hashes else []
+            if not self.cache.full.claim(req, req.slot, matched,
+                                         n_pages):
                 self._release(req)
                 raise RuntimeError("page pool exhausted importing prefill")
             fresh = list(range(len(matched), n_pages))
-            req.pages = pages
-            self._block_tables[req.slot, :n_pages] = pages
+            pages = req.pages
             if self._prefix_on:
                 # hits/misses track page-level cache efficacy; deduped
                 # imports save scatter/transfer, NOT prefill compute (the
@@ -2553,8 +1915,8 @@ class PagedInferenceEngine:
                 if self._prefix_on and hashes:
                     for i in fresh:
                         if i < len(hashes):
-                            self._register_page(pages[i], hashes[i],
-                                                chain=req.chain_slot)
+                            self.cache.index.publish(pages[i], hashes[i],
+                                                     req.chain_slot)
             tok = int(payload["first_token"])
             req.first_token_t = time.perf_counter()
             req.first_token_ns = req.token_ns = int(req.first_token_t * 1e9)
@@ -2616,29 +1978,20 @@ class PagedInferenceEngine:
 
     def hash_prompt(self, prompt, salt: bytes = b"") -> list[bytes]:
         """Chained hashes of the prompt's admission-reusable pages: the
-        whole full pages inside the chunk-aligned _reuse_limit, exactly
-        the run _match_prefix can admit from cache. ``salt`` must match
+        whole full pages inside the chunk-aligned reuse limit, exactly
+        the run admission can serve from cache. ``salt`` must match
         the prefix_salt the request will submit with (tenant-scoped
-        chains — _prompt_hashes). Pure computation — no lock, no
+        chains — KVCache.prompt_hashes). Pure computation — no lock, no
         state."""
         ids = (self.tokenizer.encode(prompt) if isinstance(prompt, str)
                else list(prompt))
-        c = self.cfg.chunk_size
-        limit = ((len(ids) - 1) // c) * c
-        if limit <= 0:
-            return []
-        return self._hash_chain(ids[:limit], prev=salt)
+        return self.cache.hash_prompt(ids, salt)
 
     def cached_prefix_len(self, hashes) -> int:
         """How many of `hashes` (a chain run) this engine's cache already
         covers, walking from the head until the first miss."""
         with self._lock:
-            n = 0
-            for h in hashes:
-                if h not in self._hash_to_page:
-                    break
-                n += 1
-            return n
+            return len(self.cache.index.run(hashes))
 
     def export_prefix(self, hashes) -> Optional[dict]:
         """Gather the cached pages for a chain run of hashes to host
@@ -2651,12 +2004,7 @@ class PagedInferenceEngine:
         so a concurrent step would invalidate the buffers mid-gather."""
         self._refuse_two_kinds("export_prefix")
         with self._lock:
-            pids: list[int] = []
-            for h in hashes:
-                pid = self._hash_to_page.get(h)
-                if pid is None:
-                    break
-                pids.append(pid)
+            pids = self.cache.index.run(hashes)
             if not pids:
                 return None
             pages = self._gather_pages(
@@ -2675,15 +2023,14 @@ class PagedInferenceEngine:
     def import_prefix(self, payload: Optional[dict],
                       reserve_pages: Optional[int] = None) -> int:
         """Seed this engine's prefix cache with another replica's
-        exported pages: allocate, scatter (donated, in place), register
-        under the payload's chain hashes, and park refcount-0 in the
-        cached LRU — the next _match_prefix/_try_reuse admits them like
-        locally computed pages. Imports stop once the pool would drop
-        below `reserve_pages` allocatable pages (default one page per
-        slot) so a warm import can never starve active requests.
-        Returns pages imported. CALLER must serialize against the
-        stepping thread (same contract as export_prefix/import_prefill:
-        _import_fn donates the cache pools)."""
+        exported pages: allocate, scatter (donated, in place), publish
+        under the payload's chain hashes and park unheld — the next
+        admission or mid-prefill reuse admits them like computed pages.
+        Imports stop once the pool would drop below `reserve_pages`
+        allocatable pages (default one a slot), so a warm import never
+        starves active requests. Returns pages imported. CALLER must
+        serialize against the stepping thread (as for export_prefix /
+        import_prefill: _import_fn donates the cache pools)."""
         if payload is None or not self._prefix_on:
             return 0
         self._refuse_two_kinds("import_prefix")
@@ -2699,30 +2046,18 @@ class PagedInferenceEngine:
 
     def _import_payload_locked(self, payload: dict, reserve_pages: int,
                                chain: Optional[int] = None) -> int:
-        """The shared allocate/scatter/register/LRU-park core behind
-        import_prefix (cross-replica) and the spill-tier promote paths
-        (same payload format — a promoted page is bit-identical to a
-        never-evicted one by construction). ``chain`` pins the heat
-        attribution (promotes know their chain from the tier entry);
-        None means cross-replica import accounting: slot from the
-        payload's head hash, imported_pages counters, flight event.
-        Caller holds self._lock and serializes against stepping."""
+        """The take / scatter / park core behind import_prefix
+        (cross-replica) and the spill tier's promotes (same payload
+        format: a promoted page is bit-identical to a never-evicted one).
+        ``chain`` pins the heat attribution (a promote knows its chain
+        from the tier entry); None means a cross-replica import's
+        accounting: slot from the payload's head hash, imported_pages,
+        flight event. Caller holds self._lock, serialized with stepping."""
         hashes = payload["page_hashes"]
-        take_idx: list[int] = []
-        take_pids: list[int] = []
-        budget = self._pages_avail() - reserve_pages
-        for i, h in enumerate(hashes):
-            if h in self._hash_to_page:
-                continue    # already cached locally (either source)
-            if budget <= 0:
-                break
-            pid = self._pop_free_page()
-            self._page_refs[pid] = 0
-            take_idx.append(i)
-            take_pids.append(pid)
-            budget -= 1
-        if not take_pids:
+        took = self.cache.take_unheld(hashes, reserve_pages)
+        if not took:
             return 0
+        take_idx, take_pids = map(list, zip(*took))
         self._scatter_pages(take_pids, payload["pages"], take_idx)
         slot = -1
         if chain is not None:
@@ -2736,9 +2071,7 @@ class PagedInferenceEngine:
         if chain is None and self.chains is not None:
             self.chains.imported(slot, len(take_pids))
             flight.evt(flight.PREFIX_IMPORT, len(take_pids), slot)
-        for i, pid in zip(take_idx, take_pids):
-            self._register_page(pid, hashes[i], chain=slot)
-            self._cached_lru[pid] = None
+        self.cache.park(took, hashes, slot)
         if chain is None:
             self.stats["prefix_imported_pages"] += len(take_pids)
         return len(take_pids)
@@ -2752,13 +2085,13 @@ class PagedInferenceEngine:
         prefill. Runs under self._lock on the stepping thread (called
         from _admit). Returns pages promoted; the caller re-matches."""
         page = self.cfg.page_size
-        limit = self._reuse_limit(req) // page
+        limit = self.cache.reuse_limit(len(req.prompt_ids)) // page
         if have >= limit:
             return 0
-        need = self._pages_needed(len(req.prompt_ids) + 1)
-        if self._pages_avail() < need:
+        need = self.cache.pages_for(len(req.prompt_ids) + 1)
+        if self.cache.index.avail() < need:
             return 0    # admission would stall regardless: no churn
-        hashes = self._prompt_hashes(req)
+        hashes = self.cache.prompt_hashes(req)
         run = self.spill.covered_run(hashes[have:limit])
         if run <= 0:
             return 0
@@ -2787,8 +2120,8 @@ class PagedInferenceEngine:
         if self.spill is None or self.chains is None:
             return 0
         with self._lock:
-            pool = self.cfg.num_pages - 1
-            free_frac = len(self._free_pages) / max(pool, 1)
+            free_frac = len(self.cache.index.free) / max(
+                self.cfg.num_pages - 1, 1)
             slot = self.spill.policy.rewarm_slot(
                 self.chains, self.spill.spilled_slots(), free_frac)
             if slot is None:
@@ -2796,18 +2129,16 @@ class PagedInferenceEngine:
             run = self._chain_runs.get(slot)
             if not run:
                 return 0
+            index = self.cache.index.hash_to_page
             # the head-rooted usable run: pages already hot pass
             # through (the scatter skips them), tier-resident pages
             # promote, the first page in neither tier ends the run
             want: list[bytes] = []
             for h in run:
-                if h in self._hash_to_page:
-                    want.append(h)
-                elif self.spill.has(h):
-                    want.append(h)
-                else:
+                if not (h in index or self.spill.has(h)):
                     break
-            want = [h for h in want if h not in self._hash_to_page]
+                want.append(h)
+            want = [h for h in want if h not in index]
             if max_pages is not None:
                 want = want[:max(int(max_pages), 0)]
             if not want:
@@ -2855,22 +2186,16 @@ class PagedInferenceEngine:
             return len(removed)
 
     def drain_directory_delta(self) -> tuple:
-        """-> (new_hashes, dropped_hashes) accumulated since the last
-        drain, filtered against current cache state so a
-        publish-then-evict (or evict-then-republish) nets out to the
-        truth. Only meaningful with track_page_publish on; must be
-        called serialized with stepping (the serving layer's engine
-        loop), which is also what bounds the lists."""
-        if not self._dir_new and not self._dir_dropped:
+        """-> (new_hashes, dropped_hashes) since the last drain, filtered
+        against the cache so a publish-then-evict (or the reverse) nets
+        out. Only meaningful with ``cache.log.track`` on; called serialized
+        with stepping (the serving layer's engine loop), which is also
+        what bounds the lists."""
+        log = self.cache.log
+        if not log.new and not log.dropped:
             return (), ()
-        new, self._dir_new = self._dir_new, []
-        dropped, self._dir_dropped = self._dir_dropped, []
         with self._lock:
-            new = [h for h in dict.fromkeys(new)
-                   if h in self._hash_to_page]
-            dropped = [h for h in dict.fromkeys(dropped)
-                       if h not in self._hash_to_page]
-        return new, dropped
+            return log.drain()
 
     # -- stats -------------------------------------------------------------
 
@@ -2906,7 +2231,7 @@ class PagedInferenceEngine:
             "tokens_saved": self.stats["prefix_tokens_saved"],
             "imported_pages": self.stats["prefix_imported_pages"],
             "exported_pages": self.stats["prefix_exported_pages"],
-            "cached_pages": len(self._cached_lru),
+            "cached_pages": self.cache.index.parked(),
             "hit_rate": round(hits / (hits + misses), 4)
             if hits + misses else 0.0,
             # spill tier (cfg.kv_spill): cumulative counters + current
@@ -2927,22 +2252,12 @@ class PagedInferenceEngine:
     def pool_stats(self) -> dict:
         acct = self.prefix_accounting()
         return {
-            # free + cached together are the allocatable pool: cached
-            # pages hold reusable prefix KV but evict on demand, so a
-            # "full" pool with a deep cache is warm, not saturated
-            "free_pages": len(self._free_pages),
-            "cached_pages": acct["cached_pages"],
-            "total_pages": self.cfg.num_pages,
+            # the pools: free, cached and total pages of each kind
+            **self.cache.pool_stats(),
             "prefix_hit_rate": acct["hit_rate"],
             "active": len(self._active),
             "prefilling": len(self._prefilling),
             "pending": len(self._pending),
-            # the second pool of a model with sliding-window layers
-            **({} if self._wpool is None else {
-                "window_free_pages": len(self._wpool.free),
-                "window_cached_pages": sum(map(len, self._wpool.tiers)),
-                "window_total_pages": self._wpool.num_pages,
-                "window_ring_pages": self._ring}),
             **self.stats,
         }
 

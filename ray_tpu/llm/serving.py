@@ -198,7 +198,7 @@ class LLMServer:
         if rcfg.serve_prefix_directory and self.engine._prefix_on:
             from ..serve.frontdoor.prefix import PrefixDirectoryClient
             self._prefix_dir = PrefixDirectoryClient(cfg.model_id)
-            self.engine.track_page_publish = True
+            self.engine.cache.log.track = True
         # batched multi-LoRA (llm/multilora): one engine, many tenants.
         # The manager resolves adapter ids to resident slot-table rows
         # at admission; version pinning, LRU and hot-swap live there.

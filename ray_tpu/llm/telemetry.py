@@ -274,24 +274,23 @@ def on_step(engine) -> None:
     _gauge("rtpu_llm_prefilling_requests",
            "admitted, prompt not fully prefilled").set(
         len(engine._prefilling), tags=gtags)
-    pool = cfg.num_pages - 1  # page 0 is the write sink
     # cached (unreferenced, prefix-reusable) pages are reclaimable on
     # demand: they count as capacity, not utilization — a warm cache
-    # must not read as a saturated pool
+    # must not read as a saturated pool (page 0 is the write sink)
+    full, window = engine.cache.index, engine.cache.window
     _gauge("rtpu_llm_kv_utilization",
            "KV pages in use / pool size").set(
-        (pool - len(engine._free_pages) - len(engine._cached_lru))
-        / max(pool, 1), tags=gtags)
-    wpool = getattr(engine, "_wpool", None)
-    if wpool is not None:
+        full.live() / max(full.num_pages - 1, 1), tags=gtags)
+    if window is not None:
         # a model with sliding-window layers: its second pool, the same
         # way (held pages / pool size; parked prefix pages are capacity)
         _gauge("rtpu_llm_kv_window_utilization",
                "window-layer KV pages in use / window pool size").set(
-            wpool.live() / max(wpool.num_pages - 1, 1), tags=gtags)
+            window.space.live() / max(window.space.num_pages - 1, 1),
+            tags=gtags)
         _gauge("rtpu_llm_prefix_window_cached_pages",
                "unreferenced window-layer pages retained as prefix "
-               "tails").set(sum(map(len, wpool.tiers)), tags=gtags)
+               "tails").set(window.space.parked(), tags=gtags)
     if engine._prefix_on:
         # single accounting source (paged_engine.prefix_accounting):
         # the gauges here, pool_stats() and metrics_summary() must

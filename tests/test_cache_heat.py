@@ -90,7 +90,7 @@ def _assert_table_matches_stats(eng):
     assert t["exported_pages"] == st["prefix_exported_pages"]
     # resident attribution: every registered (hash-published) page is
     # charged to exactly one chain
-    assert t["resident_pages"] == len(eng._hash_to_page)
+    assert t["resident_pages"] == len(eng.cache.full.space.hash_to_page)
 
 
 def test_engine_chain_attribution_counter_verified():
@@ -299,7 +299,7 @@ def test_heat_publish_and_cache_report_cluster(ray_start_regular):
     from ray_tpu import state as state_mod
 
     eng = PagedInferenceEngine(_cfg(num_pages=48))
-    eng.track_page_publish = True
+    eng.cache.log.track = True
     sp = SamplingParams(max_tokens=4, temperature=0.0)
     shared = _prompt(64, seed=31)
     for i in range(4):

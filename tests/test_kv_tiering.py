@@ -313,7 +313,7 @@ def test_cross_replica_promote_from_store(ray_start_regular):
     from ray_tpu.serve.frontdoor.prefix import PrefixDirectoryClient
 
     src = PagedInferenceEngine(_cfg(kv_spill=True), rng_seed=0)
-    src.track_page_publish = True
+    src.cache.log.track = True
     dst = PagedInferenceEngine(_cfg(num_pages=64), rng_seed=0)
     dst.params = src.params
     shared = _prompt(96, seed=13)
@@ -396,7 +396,7 @@ def test_spill_teardown_drains_store(ray_start_regular):
 
     rt = rt_mod.get_runtime_if_exists()
     eng = PagedInferenceEngine(_cfg(kv_spill=True), rng_seed=0)
-    eng.track_page_publish = True
+    eng.cache.log.track = True
     base = rt.store.bytes_in_use()
     _run_one(eng, _prompt(96, seed=41), 2)
     _flush(eng, count=4, seed0=9800)

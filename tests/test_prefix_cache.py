@@ -114,6 +114,7 @@ def test_eviction_under_pressure_never_touches_live_pages():
                                     max_pages_per_seq=8), rng_seed=0)
     ref.params = eng.params
     sp = SamplingParams(max_tokens=6)
+    space = eng.cache.full.space
     for seed in range(6):               # distinct prompts fill + churn LRU
         p = _prompt(40, seed=10 + seed)
         reqs = [eng.submit(p, sp), eng.submit(_prompt(40, seed=50 + seed),
@@ -122,8 +123,8 @@ def test_eviction_under_pressure_never_touches_live_pages():
             eng.step()
             for req in (*eng._prefilling, *eng._active.values()):
                 for pid in req.pages:
-                    assert eng._page_refs[pid] >= 1
-                    assert pid not in eng._cached_lru
+                    assert space.refs[pid] >= 1
+                    assert pid not in space.lru
         got = eng._result(reqs[0])
         want = ref.generate([p], sp)[0]
         assert got["token_ids"] == want["token_ids"]
@@ -131,11 +132,11 @@ def test_eviction_under_pressure_never_touches_live_pages():
     assert st["prefix_evictions"] > 0, st
     # pool accounting intact after churn
     assert st["free_pages"] + st["cached_pages"] == cfg.num_pages - 1
-    assert not np.any(eng._page_refs < 0)
-    for h, pid in eng._hash_to_page.items():
-        assert eng._page_to_hash[pid] == h
-    for pid in eng._cached_lru:
-        assert eng._page_refs[pid] == 0 and pid in eng._page_to_hash
+    assert not np.any(space.refs < 0)
+    for h, pid in space.hash_to_page.items():
+        assert space.page_to_hash[pid] == h
+    for pid in space.lru:
+        assert space.refs[pid] == 0 and pid in space.page_to_hash
 
 
 def test_pd_import_dedupes_cached_pages():

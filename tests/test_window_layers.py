@@ -209,10 +209,10 @@ def test_engine_hands_window_pages_back_and_serves_the_reference(
     assert st["window_pages_claimed"] > eng.cfg.num_window_pages
     assert st["window_pages_returned"] == st["window_pages_claimed"]
     assert st["full_pages_returned"] == st["full_pages_claimed"]
-    assert eng._wpool.live() == 0 and eng._pool.live() == 0
+    assert all(kind.space.live() == 0 for kind in eng.cache.kinds)
     # a live sequence never held more than a ring and a dispatch
     assert st["window_pool_live_pages"] <= st["decode_dispatches"] * 3 * (
-        eng._ring + 2 * CHUNK // PAGE)
+        eng.cache.window.ring + 2 * CHUNK // PAGE)
     assert st["decode_live_wpages"] <= st["decode_table_wpages"]
 
 
@@ -262,14 +262,14 @@ def test_a_lost_window_tail_shortens_the_hit_never_the_answer(
     # a short answer: the first ask ends inside the window of the
     # document's end, so every page it published is still held at release
     eng.generate([doc[:6 * CHUNK] + [1, 2]], SamplingParams(max_tokens=2))
-    pool = eng._wpool
-    hashes = eng._hash_chain(doc[:6 * CHUNK])
+    pool = eng.cache.window.space
+    hashes = eng.cache.hash_chain(doc[:6 * CHUNK])
     gone = hashes[5 * CHUNK // PAGE:] if evict == "last_chunk" else hashes
     for h in gone:
         pid = pool.hash_to_page.get(h)
         if pid is not None:
             pool.unpark(pid)
-            eng._unregister(pid, pool)
+            pool.forget(pid)
             pool.free.append(pid)
     ask = doc[:6 * CHUNK] + [9, 8, 7]
     before = eng.stats["prefix_tokens_saved"]
