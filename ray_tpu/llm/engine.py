@@ -46,6 +46,14 @@ class _Request:
     # window have been handed back)
     wpages: list[int] = dataclasses.field(default_factory=list)
     wlo: int = 0
+    # paged engine over a model with recurrent-state layers
+    # (kv_cache.StateSlots): the snapshot pinned to resume from, the one a
+    # launched prefill rows filed and their booking publishes ({pages of
+    # the prompt behind it: id}; ids of the snapshot pool, 0: none), and
+    # whether a row has loaded the slot's state
+    state_snap: int = 0
+    state_taken: dict = dataclasses.field(default_factory=dict)
+    state_started: bool = False
     prefill_pos: int = 0          # prompt tokens already prefilled
     # prompt tokens the prefix cache served (paged; the request's share of
     # stats["prefix_tokens_saved"], an argument of its llm.prefill span)

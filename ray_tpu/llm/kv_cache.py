@@ -10,14 +10,15 @@ model has. From the bottom:
                holds, and whoever hears of a publish or an eviction.
   IndexLog     what hears of the FULL pages' index: chain heat, the
                cluster directory's delta, the spill tier's demotion.
-  FullPages,   a cache KIND, how one kind of layer holds a sequence's keys:
-  WindowPages  every page while it lives (a flat table, THE prefix index),
-               or a ring of what one window and the dispatches in flight
-               need (the pages behind handed back).
-  KVCache      the kinds a model has, behind the engine's vocabulary. A
-               third kind (a fixed-size state a sequence, ROADMAP R5) is
-               one more class answering a kind's calls, in ``kinds``, that
-               says in admit / reuse what of a cached prefix it can back.
+  FullPages,   a cache KIND, how one kind of layer holds a sequence:
+  WindowPages, every page while it lives (a flat table, THE prefix index),
+  StateSlots   a ring of what one window and the dispatches in flight need
+               (the pages behind handed back), or a fixed-size recurrent
+               state in the sequence's decode slot, with a pool of hashed
+               snapshots of it that a cached prefix is resumed from.
+  KVCache      the kinds a model has (``model.cache_layers``: one kind a
+               layer), behind the engine's vocabulary; a kind says in
+               admit / reuse what of a cached prefix it can back.
 
 Full pages are content-addressed by a chained hash h_i = H(h_{i-1} ||
 page_token_ids): the flat dict is an implicit trie. ``refs`` counts the
@@ -32,7 +33,6 @@ import hashlib
 from collections import OrderedDict
 from typing import Optional
 
-import jax
 import numpy as np
 
 from ..core import flight
@@ -47,6 +47,17 @@ WINDOW_COUNTERS = (
     "window_evictions", "prefix_tail_cut", "prefix_tail_lost",
     "prefix_tail_tokens_lost", "decode_live_wpages", "decode_table_wpages",
     "prefill_ctx_wpages", "prefill_attn_wpairs")
+
+
+# What a model with recurrent-state layers adds to engine.stats: snapshots
+# filed, resumed, reclaimed and refused (a pool whose every snapshot is
+# pinned), prompt tokens a resumed snapshot covered / a request still ran,
+# full pages a hit found beyond the newest snapshot (re-run, not trusted),
+# and the snapshots the pool held at each decode booking (PERF.md §3)
+STATE_COUNTERS = (
+    "state_snapshots_taken", "state_snapshots_refused",
+    "state_snapshot_hits", "state_evictions", "state_hit_tokens",
+    "state_rerun_tokens", "state_pages_untrusted", "state_pool_live")
 
 
 def window_need(cfg, model, prefill_rows: int) -> tuple:
@@ -251,6 +262,19 @@ class _Kind:
 
     advanced = booked_prefill = launched_decode = lambda self, *_: None
 
+    def booked_decode(self) -> None:
+        self._count("pool_live_pages", self.space.live())
+
+    def rows(self, slots, cols: int, prefill) -> np.ndarray:
+        """What a program takes as this kind's table: the rows of engine
+        slots ``slots``, ``cols`` wide; -1 is a row of zeros (padding, an
+        idle row, a warm-up's: its writes route to the sink)."""
+        out = np.zeros((len(slots), cols), np.int32)
+        for i, slot in enumerate(slots):
+            if slot >= 0:
+                out[i] = self.table[slot, :cols]
+        return out
+
 
 class FullPages(_Kind):
     """Layers that keep every key: a sequence holds every page while it
@@ -433,12 +457,145 @@ class WindowPages(_Kind):
         self.stats["decode_table_wpages"] += self.table.size
 
 
+class StateSlots(_Kind):
+    """Recurrent-state layers (a gated delta rule's): what a sequence
+    holds is a fixed-size state in its decode slot — row slot + 1 of the
+    layers' device arrays, row 0 their sink — whatever its length: no page
+    is claimed, none runs dry. ``space`` is the id space of the SNAPSHOT
+    pool: copies of every state layer's state at one page boundary of a
+    prompt, filed under that page's hash, parked and reclaimed as pages
+    are. A cached prefix is a hit only as far as its newest snapshot
+    (``newest``); the full pages beyond it are re-run. A prefill row loads
+    and stores the slot's state or a snapshot inside its program, told by
+    this kind's table (``rows``, a row [load, mode, snapshot from, store,
+    snapshot to] — models/qwen3_next.py names the columns); a decode
+    dispatch's table is the slots' rows alone. A snapshot is taken where
+    a prompt's last whole page ends (``cut``: a later turn re-runs the
+    previous answer, its new message and under a page), where a prefill
+    dispatch leaves a prompt it has not finished (a long document asked
+    again under another question resumes at most a dispatch short of what
+    is shared), and nowhere in decode. A pool whose every snapshot is
+    pinned refuses the snapshot, never the request."""
+
+    name = "state"
+    bucketed = False
+    CONTINUE, FRESH, RESUME, CHAIN = range(4)
+
+    def __init__(self, cfg, stats: dict):
+        # two tiers: a snapshot from the middle of a prompt is reclaimed
+        # before any from a prompt's end (a session's next turn needs that
+        # one; with one tier the ~2k-token snapshots of cold prompts push
+        # the ends out, every miss re-runs a history in more dispatches,
+        # and those file more: PERF.md §6, PR 47)
+        super().__init__(cfg, stats, PageSpace(
+            cfg.num_state_snapshots + 1, stats, "state_evictions",
+            tiers=2), 1)
+        self.on = bool(cfg.enable_prefix_caching)
+
+    def held(self, req: _Request) -> int:
+        return 1 << 30          # a state covers any length
+
+    def ensure(self, req: _Request, upto_tokens: int) -> bool:
+        return True
+
+    def newest(self, hashes, n: int) -> int:
+        """Pages of a cached run of ``n`` full pages that the newest
+        snapshot on the chain covers (0: none)."""
+        while n > 0 and hashes[n - 1] not in self.space.hash_to_page:
+            n -= 1
+        return n
+
+    def claim(self, req: _Request, n_pages: int, hashes) -> None:
+        """req was admitted, resuming behind ``n_pages`` cached pages: its
+        slot's row, and the snapshot pinned until the row that loads it
+        is launched."""
+        self.table[req.slot, 0] = req.slot + 1
+        req.state_started = False
+        if n_pages:
+            req.state_snap = self.space.hash_to_page[hashes[n_pages - 1]]
+            self.space.pin(req.state_snap)
+            self.stats["state_snapshot_hits"] += 1
+        resumed = n_pages * self.page
+        self.stats["state_hit_tokens"] += resumed
+        self.stats["state_rerun_tokens"] += len(req.prompt_ids) - resumed
+
+    def cut(self, req: _Request) -> int:
+        """Where req's prefill takes its snapshot: the end of its prompt's
+        last whole page, 0 for none (nothing whole, or where it resumed)."""
+        at = len(req.prompt_ids) // self.page * self.page
+        return at if at > req.prefix_tokens_saved else 0
+
+    def rows(self, slots, cols: int, prefill) -> np.ndarray:
+        """A decode's table: the slots' rows [n, 1]. A prefill's
+        (``prefill``: its rows (req, start, tokens), fewer than ``slots``
+        where the tail is padding): [n, 5] as the class says. The last row
+        a request has in the dispatch stores its slot's state; that row
+        (unless it ends the prompt) and the row that ends at ``cut`` also
+        file a snapshot, if the pool has one to give."""
+        if prefill is None:
+            return super().rows(slots, 1, None)
+        out = np.zeros((len(slots), 5), np.int32)
+        for i, (req, pos, n) in enumerate(prefill):
+            row = req.slot + 1
+            out[i, 0] = row
+            if i and prefill[i - 1][0] is req:
+                out[i, 1] = self.CHAIN
+            elif req.state_started:
+                out[i, 1] = self.CONTINUE
+            elif req.state_snap:
+                out[i, 1], out[i, 2] = self.RESUME, req.state_snap
+                # the program that reads it is launched: whoever takes
+                # the snapshot's id next writes it in a later one
+                self.space.unpin(req.state_snap)
+                req.state_snap = 0
+            else:
+                out[i, 1] = self.FRESH
+            req.state_started = True
+            last = i + 1 == len(prefill) or prefill[i + 1][0] is not req
+            end = pos + n
+            if last:
+                out[i, 3] = row
+            if self.on and end // self.page not in req.state_taken and (
+                    end == self.cut(req)
+                    or last and end < len(req.prompt_ids)):
+                if self.space.avail():
+                    req.state_taken[end // self.page] = out[i, 4] = \
+                        self.space.take()
+                    self.stats["state_snapshots_taken"] += 1
+                else:
+                    self.stats["state_snapshots_refused"] += 1
+        return out
+
+    def publish(self, req: _Request, lo: int, hi: int, hashes) -> None:
+        """The snapshots launched rows of req filed behind pages lo .. hi,
+        now that those are booked: each under its last page's hash, then
+        parked."""
+        end = self.cut(req) // self.page
+        for at in [a for a in req.state_taken if lo < a <= hi]:
+            sid = req.state_taken.pop(at)
+            self.space.publish(sid, hashes[at - 1])
+            self.space.unpin(sid, cold=at != end)
+
+    def release(self, req: _Request) -> None:
+        # pinned and never loaded / filed and never booked
+        for sid in (req.state_snap, *req.state_taken.values()):
+            if sid:
+                self.space.unpin(sid)
+        req.state_snap = 0
+        req.state_taken.clear()
+
+    def booked_decode(self) -> None:
+        self.stats["state_pool_live"] += (
+            self.space.num_pages - 1 - len(self.space.free))
+
+
 class KVCache:
     """The cache the engine holds: ``full`` pages always, ``window`` pages
-    where ``model.cache_window`` is not 0 (``prefill_rows`` sizes its
-    ring: window_need). The spill tier's hooks, where there is one:
-    ``log.demote`` and ``promote`` (req, pages matched) -> pages brought
-    back, which admission calls where the index's match ends."""
+    and ``state`` slots where ``model.cache_layers`` names such layers
+    (``prefill_rows`` sizes a window's ring: window_need). The spill
+    tier's hooks, where there is one: ``log.demote`` and ``promote`` (req,
+    pages matched) -> pages brought back, which admission calls where the
+    index's match ends."""
 
     def __init__(self, cfg, model, stats: dict, prefill_rows: int):
         self.cfg, self.stats = cfg, stats
@@ -448,8 +605,10 @@ class KVCache:
         # THE prefix index, which export, import and the spill tier ask
         # (one kind of page: refused over two, ROADMAP R2), and its log
         self.index, self.log = self.full.space, self.full.log
+        # the kind each layer holds a sequence in, as the model says it
+        self.layer_kinds = list(model.cache_layers(cfg.model))
         self.window: Optional[WindowPages] = None
-        self.window_layers: list = []   # which layers' pools are window's
+        self.state: Optional[StateSlots] = None
         window = int(model.cache_window(cfg.model))
         ring, need = window_need(cfg, model, prefill_rows)
         if not window and cfg.num_window_pages:
@@ -464,13 +623,35 @@ class KVCache:
                 "each)")
         if window:
             self.window = WindowPages(cfg, stats, window, ring)
-            # a probe of shapes alone (a leading dimension of 1)
-            probe = jax.eval_shape(lambda: model.init_paged_cache(
-                cfg.model, 2, cfg.page_size, 1))
-            self.window_layers = [
-                next(iter(layer.values())).shape[0] == 1 for layer in probe]
-        self.kinds = [k for k in (self.full, self.window) if k is not None]
+        if "state" in self.layer_kinds:
+            if window or cfg.spec_tokens:
+                raise ValueError(
+                    "recurrent-state layers beside sliding-window layers, "
+                    "or under spec_tokens > 0 (a rejected draft has "
+                    "already moved the state): neither is built")
+            self.state = StateSlots(cfg, stats)
+        elif cfg.num_state_snapshots:
+            raise ValueError(
+                "num_state_snapshots is for a model with recurrent-state "
+                f"layers; {type(cfg.model).__name__} has none")
+        self.kinds = [k for k in (self.full, self.window, self.state)
+                      if k is not None]
         self.two_kinds = len(self.kinds) > 1
+        # where a cached prefix may end: on a chunk (prefill resumes
+        # there), or with state layers on any page — a snapshot lies where
+        # a prompt's last whole page ends, and rows start where it does
+        self.align = cfg.page_size if self.state else cfg.chunk_size
+
+    def pool_args(self) -> dict:
+        """What ``model.init_paged_cache`` is told beside the full pool:
+        the sizes of the other kinds' pools."""
+        out = {}
+        if self.window is not None:
+            out["window_pages"] = self.cfg.num_window_pages
+        if self.state is not None:
+            out.update(state_slots=self.cfg.max_batch_size,
+                       state_snapshots=self.cfg.num_state_snapshots)
+        return out
 
     # -- the index's key scheme ---------------------------------------------
 
@@ -500,11 +681,11 @@ class KVCache:
         return req.page_hashes
 
     def reuse_limit(self, n_prompt: int) -> int:
-        """Most prompt tokens admissible from cache: chunk-aligned (prefill
-        resumes on a chunk boundary) and short of the prompt, so that the
-        first generated token is sampled from real last-position logits."""
-        c = self.cfg.chunk_size
-        return ((n_prompt - 1) // c) * c
+        """Most prompt tokens admissible from cache: aligned (``align``:
+        prefill resumes on a chunk boundary, with state layers on a page's)
+        and short of the prompt, so that the first generated token is
+        sampled from real last-position logits."""
+        return ((n_prompt - 1) // self.align) * self.align
 
     def hash_prompt(self, ids, salt: bytes = b"") -> list[bytes]:
         """Chained hashes of a prompt's admission-reusable pages: the
@@ -520,7 +701,7 @@ class KVCache:
             return []
         page = self.cfg.page_size
         pages = self.index.run(self.prompt_hashes(req)[:limit // page])
-        per_chunk = self.cfg.chunk_size // page
+        per_chunk = self.align // page
         return pages[:(len(pages) // per_chunk) * per_chunk]
 
     # -- a request's pages ----------------------------------------------------
@@ -541,15 +722,21 @@ class KVCache:
         if self.window is not None and matched:
             n, lo, tail = self.window.cut(self.prompt_hashes(req), found)
             del matched[n:]
+        if self.state is not None:
+            # a hit reaches as far as the newest snapshot on the chain:
+            # the full pages beyond it are re-run, not trusted
+            del matched[self.state.newest(self.prompt_hashes(req), found):]
         if not self.full.claim(req, slot, matched,
                                self.pages_for(len(req.prompt_ids) + 1)):
             return False
         if tail:
             self.window.map_in(req, lo, tail)
-        if found > len(matched):
+        if self.window is not None and found > len(matched):
             st["prefix_tail_cut" if matched else "prefix_tail_lost"] += 1
             st["prefix_tail_tokens_lost"] += \
                 (found - len(matched)) * self.cfg.page_size
+        if self.state is not None:
+            st["state_pages_untrusted"] += found - len(matched)
         if chains is not None:
             hs = self.prompt_hashes(req)
             if hs:
@@ -562,6 +749,8 @@ class KVCache:
             st["prefix_tokens_saved"] += req.prefill_pos
             if chains is not None:
                 chains.hit(req.chain_slot, len(matched), req.prefill_pos)
+        if self.state is not None:
+            self.state.claim(req, len(matched), self.prompt_hashes(req))
         return True
 
     def ensure(self, req: _Request, upto_tokens: int) -> bool:
@@ -572,6 +761,21 @@ class KVCache:
         """Logical pages req holds in every kind."""
         return min(k.held(req) for k in self.kinds)
 
+    @property
+    def reuses_mid_prefill(self) -> bool:
+        """Can a request half-way through its prompt map in pages another
+        has published since? Not over state layers: their state at that
+        point is the request's own to compute."""
+        return self.prefix_on and self.state is None
+
+    def row_tokens(self, req: _Request, pos: int) -> int:
+        """Tokens the prefill row of req that starts at ``pos`` carries: a
+        chunk, the prompt's rest, or up to where a kind wants a row to end
+        (the state layers' snapshot)."""
+        n = min(self.cfg.chunk_size, len(req.prompt_ids) - pos)
+        cut = self.state.cut(req) if self.state and self.prefix_on else 0
+        return cut - pos if pos < cut < pos + n else n
+
     def reuse(self, req: _Request) -> None:
         """Mid-prefill reuse: jump req.prefill_pos over chunks whose pages
         another request has published since this one was admitted (an
@@ -579,7 +783,7 @@ class KVCache:
         in as they land). Swapped-out private pages go to the free list."""
         c, page = self.cfg.chunk_size, self.cfg.page_size
         pos = req.prefill_pos
-        if not self.prefix_on or pos % c:
+        if not self.reuses_mid_prefill or pos % c:
             return
         limit = self.reuse_limit(len(req.prompt_ids))
         hashes, sp = self.prompt_hashes(req), self.index
@@ -633,8 +837,8 @@ class KVCache:
             k.launched_decode(lengths, slots)
 
     def booked_decode(self) -> None:
-        for k in self.kinds if self.two_kinds else ():
-            self.stats[f"{k.name}_pool_live_pages"] += k.space.live()
+        for k in self.kinds:
+            k.booked_decode()
 
     def release(self, req: _Request) -> None:
         """A retired request's pages go back, published first, and its
@@ -675,18 +879,16 @@ class KVCache:
         for k in self.kinds:
             k.publish(req, 0, n_full, hashes)
 
-    def tables(self, slots, width: int):
+    def tables(self, slots, width: int, prefill=None):
         """What a program takes as its block tables: the full table
-        [n, width] of engine slots ``slots``, and with a window the pair
-        (full, ring [n, ring]). A slot of -1 is a row of zeros (padding,
-        an idle row, a warm-up's): its writes route to the sink page."""
-        out = []
-        for k in self.kinds:
-            cols = width if k.bucketed else k.table.shape[1]
-            out.append(np.zeros((len(slots), cols), np.int32))
-            for i, slot in enumerate(slots):
-                if slot >= 0:
-                    out[-1][i] = k.table[slot, :cols]
+        [n, width] of engine slots ``slots``, and with more kinds the
+        tuple (full, the other kind's: a ring [n, ring], the state rows).
+        A slot of -1 is a row of zeros (padding, an idle row, a
+        warm-up's): its writes route to the sink. ``prefill``: the
+        dispatch's rows (req, start, tokens), for a prefill program (a
+        list, empty for a warm-up's); None for a decode's."""
+        out = [k.rows(slots, width if k.bucketed else k.table.shape[1],
+                      prefill) for k in self.kinds]
         return out[0] if len(out) == 1 else tuple(out)
 
     def take_unheld(self, hashes, reserve: int) -> list[tuple]:
@@ -723,4 +925,9 @@ class KVCache:
                        window_cached_pages=wsp.parked(),
                        window_total_pages=wsp.num_pages,
                        window_ring_pages=self.window.ring)
+        if self.state is not None:
+            ssp = self.state.space
+            out.update(state_free_snapshots=len(ssp.free),
+                       state_cached_snapshots=ssp.parked(),
+                       state_total_snapshots=ssp.num_pages - 1)
         return out

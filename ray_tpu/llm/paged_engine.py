@@ -67,27 +67,35 @@ from . import telemetry
 from .engine import (  # noqa: F401 — SamplingParams re-exported
     SamplingParams, _Request, sample_logits_batch,
 )
-from .kv_cache import WINDOW_COUNTERS, KVCache, WindowPages, window_need
+from .kv_cache import (STATE_COUNTERS, WINDOW_COUNTERS, KVCache, WindowPages,
+                       window_need)
 from .tokenizer import get_tokenizer
 
 
 def model_module(model_cfg):
     """The module that defines a model config's class: the engine's one
     seam to a model. It calls, by these names and nothing else of the
-    module: ``init``, ``cache_window`` (keys a sliding layer keeps
-    behind a query; 0 where every layer keeps every key),
-    ``init_paged_cache`` (a list, one dict of named page pools
-    [P, page, ...] a layer, which the engine never interprets: P is
-    ``num_pages``, or ``window_pages`` — the call's last argument, given
-    only where ``cache_window`` is not 0 — in a sliding layer),
-    ``window_ring_pages`` (that case only: the width of the sliding
-    layers' table), ``decode_paged``, ``prefill_paged_rows``,
-    ``verify_paged_rows`` (each takes one block table, or with a window
-    the pair (full, ring)), ``routed_per_token``, ``expert_routing``,
+    module: ``init``, ``cache_layers`` (the cache kind of each layer:
+    ``full`` or ``window`` pages, or a recurrent ``state``),
+    ``cache_window`` (keys a sliding layer keeps behind a query; 0 where
+    no layer slides), ``init_paged_cache`` (a list, one dict of named
+    pools a layer, which the engine never interprets: page pools
+    [P, page, ...] with P ``num_pages``, or the keyword ``window_pages``
+    in a sliding layer; a state layer's arrays by slot and by snapshot,
+    sized by the keywords ``state_slots`` and ``state_snapshots`` —
+    KVCache.pool_args), ``window_ring_pages`` (a model with sliding
+    layers only: the width of their table), ``decode_paged``,
+    ``prefill_paged_rows``, ``verify_paged_rows`` (each takes one block
+    table, or with a second kind the pair (full, that kind's):
+    KVCache.tables), ``routed_per_token``, ``expert_routing``,
+    ``experts_held`` ([lo, hi) of the routed experts whose weights this
+    replica has; where that is a share of them the programs hand back a
+    load of E + 1 entries, the last the distinct held experts reached, a
+    layer a step),
     ``prefill_attn_step``,
     ``lora_targets`` and, under a mesh, ``check_mesh`` (which may refuse)
     and then ``logical_axes`` and ``cache_logical_axes`` (models/llama.py,
-    models/mla_moe.py)."""
+    models/mla_moe.py, models/qwen3_next.py)."""
     return sys.modules[type(model_cfg).__module__]
 
 
@@ -106,6 +114,12 @@ class PagedEngineConfig:
     # reclaims it. At least max_batch_size rings (the engine says how
     # many when it refuses fewer). 0 for every other model.
     num_window_pages: int = 0
+    # a model with recurrent-state layers (model_module's cache_layers):
+    # snapshots the pool holds of a sequence's states at a page boundary
+    # of its prompt, which a later request with that prefix resumes from
+    # (kv_cache.StateSlots). A sequence's live state is in its decode
+    # slot and needs none. 0 for every other model.
+    num_state_snapshots: int = 0
     max_pages_per_seq: int = 64
     # prefill chunk (page multiple); up to prefill_rows chunks per step
     chunk_size: int = 128
@@ -236,16 +250,18 @@ _MAX_PREFILL_ROWS = 16
 
 def derived_prefill_rows(routing: tuple, chunk_size: int) -> int:
     """Chunk-rows of a prefill dispatch where the user set none, from the
-    model's ``expert_routing`` (experts, top-k). A dense model: 4 (512
-    tokens at the default chunk run its matmuls near the chip's peak). A
-    model with routed experts streams every expert's weights once a layer
-    a dispatch however few rows each gets, so its dispatch is grown until
-    it pays for the stream: the smallest power of two of rows at which an
-    expert's mean group (rows x chunk x top-k / experts) fills the grouped
-    kernel's largest row tile, from the dense 4 up to _MAX_PREFILL_ROWS."""
-    experts, top_k = routing
+    model's ``expert_routing`` (experts, top-k, experts held). A dense
+    model: 4 (512 tokens at the default chunk run its matmuls near the
+    chip's peak). A model with routed experts streams every held expert's
+    weights once a layer a dispatch however few rows each gets, so its
+    dispatch is grown until it pays for the stream: the smallest power of
+    two of rows at which an expert's mean group (rows x chunk x top-k /
+    experts: what a held expert gets, whoever holds the rest) fills the
+    grouped kernel's largest row tile, from the dense 4 up to
+    _MAX_PREFILL_ROWS."""
+    experts, top_k, held = routing
     rows = _DENSE_PREFILL_ROWS
-    while experts and rows < _MAX_PREFILL_ROWS and \
+    while held and rows < _MAX_PREFILL_ROWS and \
             rows * chunk_size * top_k < MAX_TILE_ROWS * experts:
         rows *= 2
     return rows
@@ -337,11 +353,11 @@ class PagedInferenceEngine:
         self.window = int(self.model.cache_window(mc))  # keys kept; 0: all
         if cfg.kv_spill and self.cache.two_kinds:
             raise ValueError(
-                "kv_spill over a two-kind (window + full) cache: the "
-                "spill tier moves one kind of page (ROADMAP R2)")
+                "kv_spill over a two-kind cache (window pages or states "
+                "beside full pages): the spill tier moves one kind of "
+                "page (ROADMAP R2)")
         self.caches = self.model.init_paged_cache(
-            mc, cfg.num_pages, cfg.page_size,
-            *([cfg.num_window_pages] if self.cache.two_kinds else []))
+            mc, cfg.num_pages, cfg.page_size, **self.cache.pool_args())
         self._free_slots = deque(range(cfg.max_batch_size))
         # a slot's tokens in the cache or written there by a program
         # launched: a decode's launch moves it by what it allows the row
@@ -361,12 +377,17 @@ class PagedInferenceEngine:
         # window: of every full layer, what a token of a long sequence
         # costs for good; window_page_nbytes is what it costs while a
         # window holds it)
+        # (a state layer's arrays are no pages: what one slot, and one
+        # snapshot, holds over them is state_nbytes)
         per_layer = [sum(int(pool.nbytes) // pool.shape[0]
                          for pool in layer.values()) for layer in self.caches]
-        self.window_page_nbytes = sum(
-            n for n, w in zip(per_layer, self.cache.window_layers) if w)
-        self.page_nbytes = page_nbytes = \
-            sum(per_layer) - self.window_page_nbytes
+        def of_kind(kind):
+            return sum(n for n, k in zip(per_layer, self.cache.layer_kinds)
+                       if k == kind)
+        self.window_page_nbytes = of_kind("window")
+        self.page_nbytes = page_nbytes = of_kind("full")
+        # a state layer has two arrays of each: by slot and by snapshot
+        self.state_nbytes = of_kind("state") // 2
         if self._prefix_on and cfg.chain_stats_slots > 0:
             from .chainstats import ChainStatsTable
             self.chains = ChainStatsTable(cfg.chain_stats_slots,
@@ -521,9 +542,19 @@ class PagedInferenceEngine:
         if self.model.routed_per_token(mc):
             self.stats.update(moe_assign_live=0, moe_assign_run=0,
                               moe_expert_load_sum=0, moe_expert_load_max=0)
+        # a replica that holds a share of the routed experts: the
+        # assignments that fell on them, the busiest of them, and the
+        # distinct ones a decode step reached
+        self._held = tuple(self.model.experts_held(mc))
+        self._share = self._routing[2] < self._routing[0]
+        if self._share:
+            self.stats.update(moe_assign_held=0, moe_held_load_max=0,
+                              moe_held_hit_decode=0)
         # a model with layers of two kinds: what the cache counts of each
-        if self.cache.two_kinds:
+        if self.cache.window is not None:
             self.stats.update(dict.fromkeys(WINDOW_COUNTERS, 0))
+        if self.cache.state is not None:
+            self.stats.update(dict.fromkeys(STATE_COUNTERS, 0))
         # speculation controller: EMA of tokens-per-slot-per-spec-dispatch
         # (starts optimistic), plus a cooldown of windowed dispatches
         # before re-probing once the EMA drops below the window
@@ -548,15 +579,16 @@ class PagedInferenceEngine:
         no weight stream (the rows share each layer's), and every rung is
         one more program a page bucket to warm."""
         top = self.prefill_rows
-        if self._routing[0] and top > _DENSE_PREFILL_ROWS:
+        if self._routing[2] and top > _DENSE_PREFILL_ROWS:
             return [1, _DENSE_PREFILL_ROWS, top]
         return [1 << i for i in range((top - 1).bit_length())] + [top]
 
     def _refuse_two_kinds(self, what: str):
         if self.cache.two_kinds:
             raise NotImplementedError(
-                f"{what} over a two-kind (window + full) cache: a payload "
-                "carries one kind of page (ROADMAP R2)")
+                f"{what} over a two-kind cache (window pages or states "
+                "beside full pages): a payload carries one kind of page "
+                "(ROADMAP R2)")
 
     # -- mesh-parallel placement (cfg.mesh) --------------------------------
 
@@ -915,7 +947,7 @@ class PagedInferenceEngine:
                         rb, mode, maxp)(
                         self.params, self.caches,
                         np.zeros((rb, c), np.int32),
-                        self.cache.tables([-1] * rb, maxp),
+                        self.cache.tables([-1] * rb, maxp, prefill=[]),
                         np.zeros((rb,), np.int32), np.zeros((rb,), np.int32),
                         key, ctr, np.zeros((rb,), np.float32),
                         np.zeros((rb,), np.int32),
@@ -1260,7 +1292,7 @@ class PagedInferenceEngine:
                 for req, pos, n in d.host["rows"]
                 for h in self.cache.prompt_hashes(req)[
                     pos // pg:(pos + n) // pg]
-            } if self._prefix_on else ()
+            } if self.cache.reuses_mid_prefill else ()
             # pack up to self.prefill_rows chunk-rows, queue order; a request
             # with several remaining chunks occupies consecutive rows (every
             # row's K/V is in the pages before any row attends, so later
@@ -1278,7 +1310,7 @@ class PagedInferenceEngine:
                     continue
                 while pos < len(req.prompt_ids) and \
                         len(rows) < self.prefill_rows:
-                    n = min(c, len(req.prompt_ids) - pos)
+                    n = self.cache.row_tokens(req, pos)
                     if not self.cache.ensure(req, pos + n):
                         break
                     rows.append((req, pos, n))
@@ -1317,7 +1349,8 @@ class PagedInferenceEngine:
             mode = self._sampling_mode([q for q, _, _ in rows])
             fn = self._prefill_rows_fn(rb, mode, W)
             tables = self.cache.tables(
-                [q.slot for q, _, _ in rows] + [-1] * (rb - r), W)
+                [q.slot for q, _, _ in rows] + [-1] * (rb - r), W,
+                prefill=rows)
         with self._launch("prefill"):
             with self.profiler.step("prefill", (rb, mode, W)):
                 toks, lps, load, self.caches = fn(
@@ -1446,7 +1479,8 @@ class PagedInferenceEngine:
             self.launch_gen += 1
             self.launched.notify_all()
 
-    def _moe_account(self, load, live_tokens: int, run_tokens: int):
+    def _moe_account(self, load, live_tokens: int, run_tokens: int,
+                     decode: bool = False):
         """Book one dispatch of an MoE config: token-expert assignments
         that belonged to live rows / real prompt tokens (known here) and
         that the program routed (idle rows and padding included: each
@@ -1457,6 +1491,14 @@ class PagedInferenceEngine:
         if load is None:
             return
         st = self.stats
+        if self._share:
+            # a share's load ends in the distinct held experts its layers
+            # reached, summed over layers and steps (model_module)
+            load, hit = load[:-1], int(load[-1])
+            here = load[self._held[0]:self._held[1]]
+            st["moe_held_hit_decode"] += hit * decode
+            st["moe_assign_held"] += int(here.sum())
+            st["moe_held_load_max"] += int(here.max())
         per_token = self.model.routed_per_token(self.cfg.model)
         st["moe_assign_live"] += live_tokens * per_token
         st["moe_assign_run"] += run_tokens * per_token
@@ -1720,7 +1762,7 @@ class PagedInferenceEngine:
         st["decode_table_pages"] += table_pages
         st["decode_steps"] += w
         self._moe_account(load, len(live) * w,
-                          self.cfg.max_batch_size * w)
+                          self.cfg.max_batch_size * w, decode=True)
         self._mesh_account(
             in_bytes,
             out.nbytes + sum(x.nbytes for x in (lps, load)

@@ -281,6 +281,13 @@ def cache_window(cfg: LlamaConfig) -> int:
     return cfg.sliding_window
 
 
+def cache_layers(cfg: LlamaConfig) -> list:
+    """The cache kind each layer holds a sequence in (llm/kv_cache.py):
+    ``window`` pages for a sliding layer, ``full`` pages otherwise."""
+    return ["window" if cfg.sliding(i) else "full"
+            for i in range(cfg.n_layers)]
+
+
 def check_mesh(cfg: LlamaConfig, sizes: dict) -> None:
     """Refuse a mesh (axis name -> size) this model cannot be split
     over."""
@@ -312,10 +319,17 @@ def routed_per_token(cfg: LlamaConfig) -> int:
     return cfg.moe_top_k * cfg.n_layers if cfg.moe_experts else 0
 
 
-def expert_routing(cfg: LlamaConfig) -> tuple[int, int]:
-    """(routed experts a layer, experts a token goes to): what sizes the
-    groups of the grouped expert matmul; (0, 0) for a dense config."""
-    return (cfg.moe_experts, cfg.moe_top_k) if cfg.moe_experts else (0, 0)
+def expert_routing(cfg: LlamaConfig) -> tuple[int, int, int]:
+    """(routed experts a layer, experts a token goes to, experts held
+    here: all of them): what sizes the groups of the grouped expert
+    matmul; zeros for a dense config."""
+    return (cfg.moe_experts, cfg.moe_top_k, cfg.moe_experts) \
+        if cfg.moe_experts else (0, 0, 0)
+
+
+def experts_held(cfg: LlamaConfig) -> tuple[int, int]:
+    """[lo, hi) of the routed experts whose weights this replica has."""
+    return 0, cfg.moe_experts
 
 
 def prefill_attn_step(cfg: LlamaConfig, chunk_size: int, page_size: int,
@@ -556,19 +570,26 @@ def expert_load(idx_k, n_experts: int):
 
 
 def routed_experts(h, idx_k, gate_k, p, n_experts: int, mlp_dim: int,
-                   interpret: bool = False):
+                   interpret: bool = False, held: Optional[tuple] = None):
     """sum_j gate_j * expert_{idx_j}(h) for h [B, S, D] and its [B, S, k]
     experts and weights, whatever router chose them (_moe_ffn's softmax
     top-k, models/mla_moe.py's sigmoid scores): the grouped expert FFN
-    over p's ``w_gate`` / ``w_up`` / ``w_down``, per shard under a mesh."""
+    over p's ``w_gate`` / ``w_up`` / ``w_down``, per shard under a mesh.
+    ``held`` = (lo, hi): p's weights are those of experts lo .. hi - 1 of
+    the ``n_experts`` the router chose among (one chip's share of a
+    layer, models/qwen3_next.py); the sum then runs over the assignments
+    that fall on them and leaves out what the absent experts would add.
+    None: every expert is held."""
     tok = ("batch", "sequence", None)
     # the serving paths hand over the layers' stacks and a layer index
     # (_layer_params): one more leading, unsharded axis
     layer = p.get("expert_layer")
     stack = () if layer is None else (None,)
     ffn = shard_kernel(
-        functools.partial(_expert_ffn, n_experts=n_experts, mlp_dim=mlp_dim,
-                          layer=layer, kernel=interpret or _on_tpu(),
+        functools.partial(_expert_ffn, n_experts=n_experts,
+                          held=held or (0, n_experts), mlp_dim=mlp_dim,
+                          layer=layer,
+                          kernel=interpret or _on_tpu(),
                           interpret=interpret),
         (tok, tok, tok, stack + ("expert", None, "mlp"),
          stack + ("expert", None, "mlp"), stack + ("expert", "mlp", None)),
@@ -577,21 +598,30 @@ def routed_experts(h, idx_k, gate_k, p, n_experts: int, mlp_dim: int,
 
 
 def _expert_ffn(h, idx, gate, w_gate, w_up, w_down, *, n_experts: int,
-                mlp_dim: int, layer: Optional[int], kernel: bool,
-                interpret: bool):
+                held: tuple, mlp_dim: int, layer: Optional[int],
+                kernel: bool, interpret: bool):
     """The experts' part of _moe_ffn on one shard: h [B, S, D], idx and
     gate [B, S, k], weights [E_here, D, F_here] / [E_here, F_here, D], or
-    the layers' stacks of them with ``layer`` naming the one to use."""
+    the layers' stacks of them with ``layer`` naming the one to use.
+    The layer is told which experts it holds: ``held`` = (lo, hi) of the
+    ``n_experts`` routed over are what the whole mesh (or the one device)
+    has, and this shard's E_here begin ``axis_index("ep") * E_here`` into
+    them. One path for a mesh and for none: an assignment to an expert
+    that is not here is not ``owned`` and adds nothing."""
     from ..ops import grouped_matmul as gmm
 
     b, s, d = h.shape
     k = idx.shape[-1]
     t, e_here = b * s, w_gate.shape[-3]
-    over_ep, over_tp = e_here != n_experts, w_gate.shape[-1] != mlp_dim
+    lo, hi = held
+    over_ep, over_tp = e_here != hi - lo, w_gate.shape[-1] != mlp_dim
     flat = idx.reshape(t * k)
     owned = None
-    if over_ep:
-        flat = flat - jax.lax.axis_index("ep") * e_here
+    if e_here != n_experts:
+        if over_ep:
+            here = jax.lax.axis_index("ep") * e_here
+            lo = lo + here if lo else here
+        flat = flat - lo
         owned = (flat >= 0) & (flat < e_here)
     tm = gmm.tile_rows(t * k, e_here)
     row_of, padded, tile_expert, n_live = gmm.group_layout(

@@ -189,6 +189,11 @@ def cache_window(cfg: MlaMoeConfig) -> int:
     return 0
 
 
+def cache_layers(cfg: MlaMoeConfig) -> list:
+    """The cache kind each layer holds a sequence in: latent pages."""
+    return ["full"] * cfg.n_layers
+
+
 def check_mesh(cfg: MlaMoeConfig, sizes: dict) -> None:
     """The engine asks this before ``logical_axes`` and
     ``cache_logical_axes``, which this module therefore does not have."""
@@ -207,12 +212,18 @@ def routed_per_token(cfg: MlaMoeConfig) -> int:
     return cfg.moe_top_k * (cfg.n_layers - cfg.n_dense_layers)
 
 
-def expert_routing(cfg: MlaMoeConfig) -> tuple[int, int]:
-    """(routed experts a layer, experts a token goes to): what sizes the
-    groups of the grouped expert matmul; (0, 0) where every layer is
-    dense (llama.expert_routing's twin)."""
-    return (cfg.moe_experts, cfg.moe_top_k) if routed_per_token(cfg) \
-        else (0, 0)
+def expert_routing(cfg: MlaMoeConfig) -> tuple[int, int, int]:
+    """(routed experts a layer, experts a token goes to, experts held
+    here: all of them): what sizes the groups of the grouped expert
+    matmul; zeros where every layer is dense (llama.expert_routing's
+    twin)."""
+    return (cfg.moe_experts, cfg.moe_top_k, cfg.moe_experts) \
+        if routed_per_token(cfg) else (0, 0, 0)
+
+
+def experts_held(cfg: MlaMoeConfig) -> tuple[int, int]:
+    """[lo, hi) of the routed experts whose weights this replica has."""
+    return (0, cfg.moe_experts) if routed_per_token(cfg) else (0, 0)
 
 
 def prefill_attn_step(cfg: MlaMoeConfig, chunk_size: int, page_size: int,

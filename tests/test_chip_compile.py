@@ -525,7 +525,8 @@ def test_mellum_programs_compile_at_the_cells_widths(v5e, monkeypatch,
 # No paged program copies a projection weight (PERF.md §3, program families)
 # ---------------------------------------------------------------------------
 
-_SERVING = ("mistral7b", "olmoe7b", "mellum2-12b", "kanana2-30b")
+_SERVING = ("mistral7b", "olmoe7b", "mellum2-12b", "kanana2-30b",
+            "qwen3next-80b")
 
 
 def _serving_model(name, n_layers, experts=False):
@@ -545,6 +546,8 @@ def _serving_model(name, n_layers, experts=False):
         model = json.load(f)
     cfg = spec.resolve(model["builder"])(model).cfg
     cut = {"n_layers": n_layers}
+    if hasattr(cfg, "full_interval"):   # GDN layers, then one full layer
+        cut["full_interval"] = n_layers
     if hasattr(cfg, "layer_types"):
         if not experts:
             cut["moe_experts"] = 0
@@ -603,11 +606,13 @@ def _paged_program(v5e, monkeypatch, module, cfg, engine, family, rows,
     compiled for one described chip over the cell's pools and tables — a
     config with sliding layers has a second pool and a table of ``ring``
     pages —, its projection weights {name: shape})."""
-    from ray_tpu.models import llama, mla_moe
+    from ray_tpu.models import llama, mla_moe, qwen3_next
+    from ray_tpu.ops import gated_delta
 
     one = SingleDeviceSharding(v5e.devices[0])
     page, max_pages = engine["page_size"], engine["max_pages_per_seq"]
-    for owner in (llama, mla_moe):      # mla_moe's experts are llama's
+    # mla_moe's and qwen3_next's experts are llama's
+    for owner in (llama, mla_moe, qwen3_next, gated_delta):
         monkeypatch.setattr(owner, "_on_tpu", lambda: True)
 
     def sds(shape, dtype=jnp.int32):
@@ -619,10 +624,15 @@ def _paged_program(v5e, monkeypatch, module, cfg, engine, family, rows,
     params = shaped(lambda: module.init(jax.random.PRNGKey(0), cfg))
     window = [engine["num_window_pages"]] \
         if getattr(cfg, "sliding_window", 0) else []
+    state = "state" in module.cache_layers(cfg)
+    if state:       # a slot a decode row, and the cell's snapshot pool
+        window = [engine["max_batch_size"], engine["num_state_snapshots"]]
     caches = shaped(lambda: module.init_paged_cache(
         cfg, engine["num_pages"], page, *window))
     tables = sds((rows, max_pages))
-    if window:
+    if state:       # the state rows of a decode, a prefill's [rows, 5]
+        tables = (tables, sds((rows, 1) if family == "decode" else (rows, 5)))
+    elif window:
         tables = (tables, sds((rows, ring)))
     if family == "decode":
         fn, args = module.decode_paged, (sds((rows, 1)), caches, tables,
@@ -632,9 +642,11 @@ def _paged_program(v5e, monkeypatch, module, cfg, engine, family, rows,
             sds((rows, 128)), caches, tables, sds((rows,)), sds((rows,)))
     compiled = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
                        donate_argnums=(2,)).lower(params, *args).compile()
-    weights = {k: a.shape[1:] for stack in ("layers", "dense_layers")
+    weights = {k: a.shape[1:] for stack in (
+        "layers", "dense_layers", "full_layers", "gdn_layers")
                for k, a in params.get(stack, {}).items()
-               if k in ("wq", "wk", "wv", "wo", "wkv_a", "w_uk", "w_uv")}
+               if k in ("wq", "wk", "wv", "wo", "wkv_a", "w_uk", "w_uv",
+                        "w_qkvz", "w_out")}
     assert {"wq", "wo"} <= set(weights)
     return compiled, weights
 
@@ -665,7 +677,8 @@ def test_no_paged_program_copies_a_projection_weight(v5e, monkeypatch, name,
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("name,rows", [
-    ("olmoe7b", 8), ("mellum2-12b", 8), ("kanana2-30b", 16)])
+    ("olmoe7b", 8), ("mellum2-12b", 8), ("kanana2-30b", 16),
+    ("qwen3next-80b", 16)])
 def test_the_derived_top_rung_compiles_with_its_experts(v5e, monkeypatch,
                                                         name, rows):
     """`prefill_paged_rows` at the rows `derived_prefill_rows` gives the

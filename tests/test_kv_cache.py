@@ -1,6 +1,6 @@
 """The paged cache's host side (llm/kv_cache.py) driven alone: no model,
 no weights, no jit. A stub stands for the model module's cache seam
-(cache_window / window_ring_pages / init_paged_cache); the walk below plays
+(cache_layers / cache_window / window_ring_pages / init_paged_cache); the walk below plays
 the engine's part — admit, mid-prefill reuse, ensure, the bookings,
 release — and checks after EVERY operation what the engine tests can only
 check after whole generations."""
@@ -28,6 +28,10 @@ class _Seam:
 
     def cache_window(self, mc):
         return self.window
+
+    def cache_layers(self, mc):
+        # a sliding layer, then a full one
+        return (["window"] if self.window else []) + ["full"]
 
     def window_ring_pages(self, mc, page, write):
         return -(-(self.window + write) // page) + 1
@@ -353,8 +357,8 @@ def test_the_window_pool_says_what_it_needs():
     with pytest.raises(ValueError, match="sliding-window"):
         _cache(0, num_window_pages=8)
     two = _cache(WINDOW)
-    assert two.window_layers == [True, False] and two.two_kinds
-    assert _cache(0).window_layers == [] and not _cache(0).two_kinds
+    assert two.layer_kinds == ["window", "full"] and two.two_kinds
+    assert _cache(0).layer_kinds == ["full"] and not _cache(0).two_kinds
 
 
 def test_adopted_pages_keep_a_reserve_and_park_published():
