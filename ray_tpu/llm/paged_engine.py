@@ -92,7 +92,7 @@ def model_module(model_cfg):
     replica has; where that is a share of them the programs hand back a
     load of E + 1 entries, the last the distinct held experts reached, a
     layer a step),
-    ``prefill_attn_step``,
+    ``attn_step``,
     ``lora_targets`` and, under a mesh, ``check_mesh`` (which may refuse)
     and then ``logical_axes`` and ``cache_logical_axes`` (models/llama.py,
     models/mla_moe.py, models/qwen3_next.py)."""
@@ -507,13 +507,16 @@ class PagedInferenceEngine:
             "mesh_output_bytes": 0, "mesh_reshard_bytes": 0,
             # work decided at the dispatch, summed over dispatches: slots and
             # KV pages the decode program had live (of max_batch_size rows x
-            # the table's width it runs: decode_table_pages), device steps (the
+            # the table's width it runs: decode_table_pages; each live row's
+            # rounded up to the kernel's key block, the pages a call's sweeps
+            # step through: decode_swept_pages), device steps (the
             # window w), prefill rows live / run (the power-of-two bucket),
             # prompt tokens prefilled, the pages their rows attend and the
             # causal (query, key) pairs they score. Each is read by a per-layer
             # metric of the benchmark (PERF.md §3)
             "decode_live_slots": 0, "decode_live_pages": 0,
-            "decode_table_pages": 0, "decode_steps": 0, "prefill_rows_live": 0,
+            "decode_table_pages": 0, "decode_swept_pages": 0,
+            "decode_steps": 0, "prefill_rows_live": 0,
             "prefill_rows_padded": 0, "prefill_tokens": 0,
             "prefill_ctx_pages": 0, "prefill_attn_pairs": 0,
             # live grid steps one layer's window kernel swept for the prefill
@@ -1380,10 +1383,7 @@ class PagedInferenceEngine:
         st["prefill_attn_pairs"] += sum(
             n * pos + n * (n + 1) // 2 for _, pos, n in rows)
         steps, masked = live_key_steps(
-            sps[:r], tls[:r], c, W, page_size=pg,
-            **self.model.prefill_attn_step(
-                self.cfg.model, c, pg, W,
-                1 if self.mesh is None else self.mesh.shape.get("tp", 1)))
+            sps[:r], tls[:r], c, W, page_size=pg, **self._attn_step(c, W))
         st["prefill_key_steps"] += steps
         st["prefill_key_steps_masked"] += masked
         self._moe_account(load, int(tls.sum()), rb * c)
@@ -1510,6 +1510,27 @@ class PagedInferenceEngine:
         step's attention has to stream."""
         page = self.cfg.page_size
         return int(sum(-(-int(self._lengths[sl]) // page) for sl in slots))
+
+    def _attn_step(self, q_window: int, table_pages: int) -> dict:
+        """The model's kernel step ({'q_tile', 'block_keys'}) under a
+        window of ``q_window`` queries a row over a table that wide, as
+        one of the mesh's head shards runs it."""
+        return self.model.attn_step(
+            self.cfg.model, q_window, self.cfg.page_size, table_pages,
+            1 if self.mesh is None else self.mesh.shape.get("tp", 1))
+
+    def _swept_pages(self, slots, table_pages: int) -> int:
+        """KV pages of the key blocks one decode step's kernel call
+        sweeps for the given slots over a table that wide: a row's live
+        pages rounded up to the call's key block — a partly live block is
+        scored whole, its tail under a probability of 0. What a wider
+        block costs."""
+        step = self._attn_step(1, table_pages)
+        ends = np.asarray([self._lengths[sl] for sl in slots], np.int64)
+        blocks, _ = live_key_steps(
+            np.maximum(ends - 1, 0), np.minimum(ends, 1), 1, table_pages,
+            page_size=self.cfg.page_size, **step)
+        return blocks * max(1, step["block_keys"] // self.cfg.page_size)
 
     def _live_window_pages(self, slots) -> int:
         """Window pages one decode step streams for ``slots``: by this
@@ -1731,6 +1752,7 @@ class PagedInferenceEngine:
                 "decode", (out, lps, load), reqs=reqs, allow=allow, w=w,
                 fed_rows=int(fed.sum()),
                 live_pages=self._live_pages(reqs), table_pages=bs * W,
+                swept_pages=self._swept_pages(reqs, W),
                 in_bytes=tokens.nbytes + fed.nbytes + 4 * bs * W
                 + lengths.nbytes + temps.nbytes + topks.nbytes
                 + lslots.nbytes)
@@ -1747,7 +1769,7 @@ class PagedInferenceEngine:
         return fly < w or self._at_limit(req, len(req.out_ids) + fly)
 
     def _book_decode(self, out, lps, load, *, reqs, allow, w, fed_rows,
-                     live_pages, table_pages, in_bytes):
+                     live_pages, table_pages, swept_pages, in_bytes):
         """Book a decode dispatch read back ([bs, w] tokens); the
         keywords are what its launch kept of the host's state
         (_launch_decode). A row whose request the booking before this
@@ -1760,6 +1782,7 @@ class PagedInferenceEngine:
         st["decode_rows_fed_on_device"] += fed_rows
         st["decode_live_pages"] += live_pages
         st["decode_table_pages"] += table_pages
+        st["decode_swept_pages"] += swept_pages
         st["decode_steps"] += w
         self._moe_account(load, len(live) * w,
                           self.cfg.max_batch_size * w, decode=True)

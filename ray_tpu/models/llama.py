@@ -332,16 +332,17 @@ def experts_held(cfg: LlamaConfig) -> tuple[int, int]:
     return 0, cfg.moe_experts
 
 
-def prefill_attn_step(cfg: LlamaConfig, chunk_size: int, page_size: int,
-                      table_pages: int, head_shards: int = 1) -> dict:
+def attn_step(cfg: LlamaConfig, q_window: int, page_size: int,
+              table_pages: int, head_shards: int = 1) -> dict:
     """{'q_tile', 'block_keys'}: the query rows a tile and the keys a grid
-    step of the kernel `_window_attend` calls for a prefill chunk over a
-    table that wide, as ONE of ``head_shards`` (the mesh's tp) runs it —
-    the kernel module's own derivation, for the engine's count of the
-    steps its prefill rows sweep."""
+    step of the kernel the full layers call under a window of ``q_window``
+    queries a row (a prefill chunk; 1 = decode) over a table that wide, as
+    ONE of ``head_shards`` (the mesh's tp) runs it — the kernel module's
+    own derivation, for the engine's counts of the steps its prefill rows
+    sweep and of the pages its decode rows sweep."""
     from ..ops.ragged_paged_attention import window_step
     return window_step(
-        chunk_size, cfg.n_heads // head_shards,
+        q_window, cfg.n_heads // head_shards,
         cfg.n_kv_heads // head_shards, cfg.head_dim, page_size=page_size,
         table_pages=table_pages, itemsize=jnp.dtype(cfg.dtype).itemsize)
 

@@ -226,17 +226,18 @@ def experts_held(cfg: MlaMoeConfig) -> tuple[int, int]:
     return (0, cfg.moe_experts) if routed_per_token(cfg) else (0, 0)
 
 
-def prefill_attn_step(cfg: MlaMoeConfig, chunk_size: int, page_size: int,
-                      table_pages: int, head_shards: int = 1) -> dict:
+def attn_step(cfg: MlaMoeConfig, q_window: int, page_size: int,
+              table_pages: int, head_shards: int = 1) -> dict:
     """{'q_tile', 'block_keys'}: the query rows a tile and the keys a grid
-    step of the kernel `_attend` calls for a prefill chunk over a table
-    that wide — the kernel module's own derivation, for the engine's
-    count of the steps its prefill rows sweep."""
+    step of the kernel `_attend` calls under a window of ``q_window``
+    queries a row (a prefill chunk; 1 = decode) over a table that wide —
+    the kernel module's own derivation, for the engine's counts of the
+    steps its prefill rows sweep and of the pages its decode rows sweep."""
     from ..ops.ragged_paged_attention import window_step
     if head_shards != 1:
         raise NotImplementedError(_NO_MESH)
     return window_step(
-        chunk_size, cfg.n_heads, 1, cfg.latent_lanes, page_size=page_size,
+        q_window, cfg.n_heads, 1, cfg.latent_lanes, page_size=page_size,
         table_pages=table_pages, itemsize=jnp.dtype(cfg.dtype).itemsize,
         v_width=cfg.kv_lora_rank)
 

@@ -220,14 +220,14 @@ def experts_held(cfg: Qwen3NextConfig) -> tuple:
     return cfg.held
 
 
-def prefill_attn_step(cfg: Qwen3NextConfig, chunk_size: int, page_size: int,
-                      table_pages: int, head_shards: int = 1) -> dict:
-    """llama.prefill_attn_step for the full layers' kernel call."""
+def attn_step(cfg: Qwen3NextConfig, q_window: int, page_size: int,
+              table_pages: int, head_shards: int = 1) -> dict:
+    """llama.attn_step for the full layers' kernel call."""
     from ..ops.ragged_paged_attention import window_step
     if head_shards != 1:
         raise NotImplementedError(_NO_MESH)
     return window_step(
-        chunk_size, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
+        q_window, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
         page_size=page_size, table_pages=table_pages,
         itemsize=jnp.dtype(cfg.dtype).itemsize)
 

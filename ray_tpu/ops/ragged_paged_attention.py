@@ -15,17 +15,24 @@ the pool capacity:
   covers a BLOCK of consecutive logical pages, never narrower than 128
   keys (8 pages of 16: a vreg's lanes, the MXU's width — at pages of 16
   a step of one page used 16 of 128 lanes and paid a grid step per 16
-  keys: PERF.md §6, PR 25). The all-heads body below keeps 128 keys a
-  step. The per-kv-head body takes the widest block its VMEM budget
-  holds (`window_step`: 512 keys under the latent prefill tile, 1,024
-  under the latent decode tile and the GQA prefill tiles; never wider
-  than the table needs): ONE ``[rows, block_keys]`` score tile, one
-  streaming-softmax update and one rescale of the f32 accumulator for
-  all of a block's keys, where at 128 keys the rescale of a ``[1024,
-  512]`` accumulator — four times the score tile — and the softmax's
-  bookkeeping held the latent prefill step at a third of the MXU's peak
-  (4.3 us a 128 keys against 1.5; 2,985 us a call where 6,696: PERF.md
-  §6, PR 32). The width comes from static shapes alone;
+  keys: PERF.md §6, PR 25). The per-kv-head body takes the widest block
+  its VMEM budget holds (`window_step`: 512 keys under the latent
+  prefill tile, 1,024 under the latent decode tile and the GQA prefill
+  tiles; never wider than the table needs): ONE ``[rows, block_keys]``
+  score tile, one streaming-softmax update and one rescale of the f32
+  accumulator for all of a block's keys, where at 128 keys the rescale
+  of a ``[1024, 512]`` accumulator — four times the score tile — and the
+  softmax's bookkeeping held the latent prefill step at a third of the
+  MXU's peak (4.3 us a 128 keys against 1.5; 2,985 us a call where
+  6,696: PERF.md §6, PR 32). The all-heads body below is bound by the
+  bytes it fetches, and a step costs 0.3-0.5 us beside them whatever it
+  carries: it takes a block as wide as a step's bytes pay for — up to 2
+  MiB of K and V a step, within a sixteenth of the table (128 keys at
+  2,048 lanes of kv heads, 256 at 1,024 under a 256-page table, 1,024 at
+  512 under one of 1,024 pages; us a call by width and shape at
+  `_ALL_HEADS_STEP_BYTES`: 2,645 -> 1,500 at Qwen3-Next's decode shape,
+  280 -> 220 at doc-QA's, PERF.md §6, PR 48). The width comes from
+  static shapes alone;
 * a pool is ``[P, page, KVH * D]``: one token's kv heads side by side
   on the last axis, head ``h`` in lanes ``[h * D, (h + 1) * D)``. The
   pools stay in HBM (``memory_space=ANY``) and the kernel gathers a
@@ -50,7 +57,11 @@ the pool capacity:
   page — and pages wholly in a tile's causal future are neither read
   nor computed (`pl.when` skips a dead block's body: nothing is copied
   and a dead step costs its launch alone). In a partly live block the
-  clamp repeats the last live page, at key positions the mask hides;
+  clamp repeats the last live page, at key positions the mask hides —
+  and the all-heads body, whose blocks are wide where a key is narrow,
+  copies the 128-key groups that hold a live page alone and zeroes the
+  values of the rest (`_live_groups`: an idle decode row attends one key
+  of the sink, thirty-odd such rows a call);
 * a streaming-softmax accumulator in VMEM scratch carries across the
   sweep (TPU grids iterate the last dimension fastest, so scratch
   persists across one tile's sweep);
@@ -204,6 +215,8 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
     # pages of the narrowest (128-key) block: what one iteration of the
     # copy loops below unrolls
     lane_pages = min(block_pages, _lane_block_keys(page_size) // page_size)
+    # the all-heads body copies a partly live block's live groups alone
+    trim = all_heads and window is None and block_pages > lane_pages
 
     def _copies(block, slot, first_page=0):
         """The page copies (one a pool a page) that fill ``lane_pages``
@@ -244,7 +257,35 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
         if block_pages == lane_pages:
             group(0, None)
         else:
-            jax.lax.fori_loop(0, block_pages // lane_pages, group, None)
+            jax.lax.fori_loop(0, _live_groups(block), group, None)
+
+    def _live_groups(block):
+        """128-key groups of a live block that `_each_copy` copies: all of
+        them, but in the all-heads body only those that hold a live page —
+        there a row of one key (an idle decode row attends the sink's
+        first) would fetch a whole block of duplicates, 2 MiB where 256
+        KiB, once a row a call. What the copies leave out of V is zeroed
+        (`_zero_dead_values`): probabilities of 0 never meet VMEM that
+        nothing wrote; K's dead groups yield scores the predicate
+        replaces."""
+        if not trim:
+            return block_pages // lane_pages
+        return jnp.clip(
+            (n_pages - block * block_pages + lane_pages - 1) // lane_pages,
+            1, block_pages // lane_pages)
+
+    def _zero_dead_values(block, slot):
+        """Zero the V rows of the groups `_each_copy` left out of
+        ``block``: none in a wholly live block."""
+        lane_keys = lane_pages * page_size
+
+        def group(g, carry):
+            at = pl.ds(pl.multiple_of(g * lane_keys, lane_keys), lane_keys)
+            v_buf[slot, at, :] = jnp.zeros(
+                (lane_keys, v_buf.shape[-1]), v_buf.dtype)
+            return carry
+        jax.lax.fori_loop(_live_groups(block), block_pages // lane_pages,
+                          group, None)
 
     def _keep(rows, rows_per_query):
         """Live-key predicate [rows, block_keys], row = query-major.
@@ -274,6 +315,8 @@ def _ragged_kernel(bt_ref, start_ref, qlen_ref,       # scalar prefetch
             _each_copy(b + 1, 1 - slot, lambda c: c.start())
 
         _each_copy(b, slot, lambda c: c.wait())
+        if trim:
+            _zero_dead_values(b, slot)
         if all_heads:
             q = q_ref[...]                                # [Q, H, D]
             # every head in one product: row q * H + head of the
@@ -437,8 +480,11 @@ def live_key_steps(starts, q_lens, q_window: int, table_pages: int, *,
                    q_tile: int, block_keys: int, page_size: int):
     """(live grid steps, those of them that carry the predicate) of ONE
     call's sweeps over rows ``[starts[r], starts[r] + q_lens[r])`` — what
-    the per-kv-head body computes, counted on the host with the kernel's
-    own `_tile_pages` and `_prefix_blocks`."""
+    either body computes (the all-heads body carries the predicate on
+    every step, whatever the second count says), counted on the host with
+    the kernel's own `_tile_pages` and `_prefix_blocks`. A live step
+    scores a whole block: steps x the block's pages are the pages a call
+    sweeps (the engine's ``decode_swept_pages``)."""
     starts = np.asarray(starts, np.int64)[:, None]
     q_lens = np.asarray(q_lens, np.int64)[:, None]
     t = np.arange(-(-q_window // q_tile))[None, :]
@@ -469,6 +515,44 @@ _VMEM_BUDGET = 32 * 2 ** 20
 _SCORE_TILE_ELEMS = 512 * 1024
 _MAX_BLOCK_KEYS = 1024
 
+# The all-heads body's step is bound by the K and V bytes it fetches, and a
+# step costs 0.3-0.5 us beside them whatever it fetches (the grid step, the
+# copies' start and wait, the block-diagonal operand, one rescale, the
+# predicate), a dead one 0.07: its key block doubles from 128 keys to the
+# widest that fetches at most `_ALL_HEADS_STEP_BYTES` a step and is at most
+# a sixteenth of the table — a row's sweep waits for its first block with
+# nothing to overlap it and rounds its last one up, a block and a half
+# whatever the row holds, so the blocks of a short table stay narrow (a
+# step's bytes alone cannot tell OLMoE's rows under 128 pages, best at 1
+# MiB a step, from the two 512-lane shapes under 1,024, best at 2 MiB).
+# Inside the compiler's own VMEM scope (no ``vmem_limit_bytes`` is stated
+# for this body: two slots a pool of a 2 MiB step are 4 MiB). Measured,
+# kernel alone, one v5e, us a decode call at 128 / 256 / 512 / 1,024 keys a
+# step (PERF.md §6, PR 48), by heads (lanes of K), live rows x context and
+# the table's pages:
+#   32/8 x 128 (1,024)   8 of 32 x 2-4k, 256     280 / 220 / 210 / 231
+#                        12 of 32 x 2-4k, 256    359 / 289 / 289 / 315
+#                        8 of 32 x 4-8k, 512     471 / 349 / 325 / 334
+#   16/2 x 256 (512)     32 of 64 x 8-16k, 1,024 2,645 / 2,074 / 1,679 / 1,500
+#                        32 of 64 x 2-6k, 1,024  1,282 / 913 / 705 / 612
+#   32/4 x 128 (512)     24 of 32 x 8-15k, 1,024 1,920 / 1,493 / 1,220 / 1,110
+#                        24 of 32 x 4-8k, 512    1,010 / 787 / 658 / 608
+#   16/16 x 128 (2,048)  48 of 64 x 0.2-1.7k, 128  661 / 681 / 764 / 961
+#                        64 of 64 x 1-2k, 128    1,214 / 1,270 / 1,402 / 1,681
+#   8/2 x 128 (256: the tp=4 shard) 8 of 32 x 2-4k, 256  217 / 171 / 146 / 141
+# Building the block-diagonal operand once a row into scratch was measured
+# beside each of these and is within 3% either way: it is built a step.
+# As the engine runs a call, its idle rows attend one key of the sink: with
+# those rows in it and a partly live block's copies trimmed to its live
+# 128-key groups (`_live_groups`), doc-QA's first line reads 322 / 266 / 263
+# / 289 (untrimmed 324 / 285 / 325 / 436), Qwen3-Next's 2,690 / 2,181 /
+# 1,755 / 1,564 (2,701 / 2,146 / 1,779 / 1,685), Mellum's 1,938 / 1,548 /
+# 1,255 / 1,128, OLMoE's 696 / 694 / 727: a row's first fetch, ~1.9 us
+# that nothing overlaps, live row or idle, is what is left.
+_ALL_HEADS_STEP_BYTES = 2 * 2 ** 20
+_ALL_HEADS_TABLE_BLOCKS = 16
+_DEFAULT_VMEM_SCOPE = 16 * 2 ** 20
+
 
 def _lane_block_keys(page_size: int) -> int:
     """Keys of the narrowest block: as many pages as make the key axis 128
@@ -478,22 +562,27 @@ def _lane_block_keys(page_size: int) -> int:
 
 def _step_vmem_bytes(q_tile: int, heads: int, kv_heads: int, d: int,
                      d_v: int, pools: int, block_keys: int,
-                     itemsize: int) -> int:
-    """VMEM one grid step of the per-kv-head body holds at a block of
-    ``block_keys``: the q and out blocks (double-buffered by the
-    pipeline), the f32 accumulators and softmax state (m and l lie over
-    128 lanes each), two slots a pool of the block buffer — and one more
-    copy of a block where a kv head is a lane slice of it, not all of it
-    — one kv head's score tile as f32 scores, f32 probabilities and their
-    cast, and the PV product. Within 2 MiB under to 6 over what the v5e
-    compiler asks for at the cells' shapes (sandbox compiles, PR 32)."""
-    rows = q_tile * heads // kv_heads
+                     itemsize: int, all_heads: bool = False) -> int:
+    """VMEM one grid step holds at a block of ``block_keys``: the q and
+    out blocks (double-buffered by the pipeline), the f32 accumulators and
+    softmax state (m and l lie over 128 lanes each), two slots a pool of
+    the block buffer, the score tile as f32 scores, f32 probabilities and
+    their cast, and the PV product. The per-kv-head body keeps a slot of
+    state a kv head, scores one kv head at a time and holds one more copy
+    of a block where a kv head is a lane slice of it, not all of it; the
+    all-heads body keeps one slot for all ``q_tile * heads`` rows, and its
+    PV product and its block-diagonal query operand are as wide as a
+    block's lanes. Within 2 MiB under to 6 over what the v5e compiler asks
+    for at the cells' shapes (sandbox compiles, PR 32)."""
+    slots, lanes = (1, kv_heads * d) if all_heads else (kv_heads, d_v)
+    rows = q_tile * heads // slots
     blocks = 2 * q_tile * heads * (d + d_v) * itemsize
-    state = kv_heads * rows * (d_v + 2 * 128) * 4
-    buffers = (pools * (2 + (kv_heads > 1)) * block_keys * kv_heads * d
+    state = slots * rows * (d_v + 2 * 128) * 4
+    buffers = (pools * (2 + (slots > 1)) * block_keys * kv_heads * d
                * itemsize)
-    scores = rows * block_keys * (4 + 4 + itemsize) + rows * d_v * 4
-    return blocks + state + buffers + scores
+    scores = rows * block_keys * (4 + 4 + itemsize) + rows * lanes * 4
+    wide = all_heads * rows * lanes * itemsize
+    return blocks + state + buffers + scores + wide
 
 
 def window_step(q_window: int, heads: int, kv_heads: int, d: int, *,
@@ -503,27 +592,47 @@ def window_step(q_window: int, heads: int, kv_heads: int, d: int, *,
     """{'q_tile', 'block_keys'} of the call the family makes at these
     static shapes (``v_width`` set: the latent form, one kv head as wide
     as q) — for the wrappers below, and for whoever counts the steps such
-    a call sweeps (`live_key_steps`; a model module's
-    ``prefill_attn_step``). The all-heads body keeps the 128-key block:
-    its step is bound by HBM, not by the softmax's bookkeeping. The
-    per-kv-head body takes the widest block, doubling from 128 keys,
-    within the three limits above — and none of whose halves covers the
-    whole table: a 512-key block over a 16-page table would copy and
-    score what no row holds. The window form's sweep starts and ends
-    inside blocks, so a block is at most a quarter of the window: wider
-    ones score more masked keys than they save steps."""
+    a call sweeps or the pages it copies (`live_key_steps`; a model
+    module's ``attn_step``). Both bodies double the block from 128 keys,
+    never past `_MAX_BLOCK_KEYS`, the VMEM the body holds
+    (`_step_vmem_bytes`) or a block none of whose halves covers the whole
+    table: a 512-key block over a 16-page table would copy and score what
+    no row holds. The per-kv-head body takes the widest such block that
+    keeps its score tile within `_SCORE_TILE_ELEMS`; the all-heads body
+    stops where a step's bytes pay for it (`_ALL_HEADS_STEP_BYTES`, within
+    a sixteenth of the table): 128 keys at OLMoE's 2,048 lanes, 256 at
+    doc-QA's 1,024 under its 256-page table, 1,024 at Mellum's and
+    Qwen3-Next's 512 under theirs of 1,024 pages. The window form's sweep
+    starts and ends inside blocks, so a block is at most a quarter of the
+    window: wider ones score more masked keys than they save steps — and
+    under the all-heads body (a sliding layer's decode: nine steps, the
+    first and last partly masked) it keeps the 128-key step, not measured
+    at another."""
     latent = v_width is not None
     q_tile = _q_tile(q_window, heads, d, latent)
     keys = _lane_block_keys(page_size)
-    if not latent and _all_heads(q_tile, heads // kv_heads):
+    all_heads = not latent and _all_heads(q_tile, heads // kv_heads)
+    if all_heads and window is not None:
         return dict(q_tile=q_tile, block_keys=keys)
+    d_v, pools = (v_width, 1) if latent else (d, 2)
+
+    def pays(keys: int) -> bool:
+        """Is a block of ``keys`` worth its step, for all the body
+        knows?"""
+        if all_heads:
+            return (pools * kv_heads * d * itemsize * keys
+                    <= _ALL_HEADS_STEP_BYTES
+                    and _ALL_HEADS_TABLE_BLOCKS * keys
+                    <= table_pages * page_size)
+        return q_tile * heads // kv_heads * keys <= _SCORE_TILE_ELEMS
+
     while (2 * keys <= _MAX_BLOCK_KEYS
            and (window is None or 2 * keys <= max(window // 4, keys))
            and keys < table_pages * page_size
-           and q_tile * heads // kv_heads * 2 * keys <= _SCORE_TILE_ELEMS
-           and _step_vmem_bytes(q_tile, heads, kv_heads, d,
-                                v_width if latent else d, 1 if latent else 2,
-                                2 * keys, itemsize) <= _VMEM_BUDGET):
+           and pays(2 * keys)
+           and _step_vmem_bytes(q_tile, heads, kv_heads, d, d_v, pools,
+                                2 * keys, itemsize, all_heads)
+           <= (_DEFAULT_VMEM_SCOPE if all_heads else _VMEM_BUDGET)):
         keys *= 2
     return dict(q_tile=q_tile, block_keys=keys)
 
@@ -635,8 +744,8 @@ def _ragged_call(q, k_pages, v_pages, block_tables, starts, q_lens, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, padded, h, d_v), q.dtype),
         interpret=interpret,
-        # the per-kv-head body's blocks are sized against this scope
-        # (window_step); the all-heads body fits the compiler's own
+        # the per-kv-head body's blocks are sized against this scope, the
+        # all-heads body's against the compiler's own (window_step)
         compiler_params=None if all_heads else pltpu.CompilerParams(
             vmem_limit_bytes=_VMEM_BUDGET),
         # the trace reduction tells the family by this prefix and its two

@@ -257,8 +257,10 @@ def delete(name: str = "default") -> None:
 def shutdown() -> None:
     import ray_tpu
 
+    from .handle import end_listeners
     from .local_mode import _REGISTRY
     _REGISTRY.clear()
+    end_listeners()
     if not ray_tpu.is_initialized():
         return  # nothing cluster-side to stop; never BOOT one to shut down
     ray = _ray()

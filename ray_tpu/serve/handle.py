@@ -229,15 +229,33 @@ class DeploymentResponseGenerator:
                 self._on_done = None
 
 
+# Bumped by serve.shutdown() (api.py): a listener thread started under an
+# earlier value ends at its next turn instead of backing off and retrying
+# against whatever controller the process starts next — where each stale
+# thread parked one long-poll object in the NEW cluster's store (the
+# objects tests/test_serve_frontdoor.py's drain check allows one of).
+_serve_epoch = 0
+
+
+def end_listeners() -> None:
+    global _serve_epoch
+    _serve_epoch += 1
+
+
 def _listen_loop_weak(handle_ref):
     """Body of a handle's long-poll listener thread. Takes a weakref so an
     abandoned handle (and this thread) can die; between polls only ids are
-    kept live."""
+    kept live. Ends when serve is shut down in this process: the handle
+    starts a new one at its next request (`_ensure_listener`)."""
     import ray_tpu
     failures = 0
+    epoch = _serve_epoch
     while True:
         h = handle_ref()
         if h is None:
+            return
+        if epoch != _serve_epoch:
+            h._listener_started = False
             return
         ctrl, app, dep, known = (h._ctrl, h.app_name, h.deployment_name,
                                  h._version)

@@ -62,9 +62,10 @@ def _compile(fn, *shapes, sharding):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _paged_shapes(rows, q_window, page, max_pages, pool=256, h=H, kvh=KVH):
-    pages = ((pool, page, kvh * D), BF16)      # as the engine stores them
-    return [((rows, q_window, h, D), BF16), pages, pages,
+def _paged_shapes(rows, q_window, page, max_pages, pool=256, h=H, kvh=KVH,
+                  d=D):
+    pages = ((pool, page, kvh * d), BF16)      # as the engine stores them
+    return [((rows, q_window, h, d), BF16), pages, pages,
             ((rows, max_pages), jnp.int32), ((rows,), jnp.int32),
             ((rows,), jnp.int32)]
 
@@ -125,8 +126,38 @@ def test_ragged_compiles_at_the_benchmark_cells_shapes(v5e, rows, q_window,
                      rf"bf16\[{rows},{q_window},{h},{D}\].*tpu_custom_call",
                      text)
     # the per-kv-head body states its VMEM scope; the all-heads decode
-    # body is compiled as it was, under the compiler's own
+    # body is compiled under the compiler's own at every derived width
     assert (_SCOPE in text) == (q_window > 1)
+
+
+@pytest.mark.parametrize("rows,h,kvh,d,max_pages,keys", [
+    (32, 32, 8, 128, 256, 256),      # doc-QA's bucket at 2-4k contexts
+    (32, 32, 8, 128, 512, 512),      # ... and its widest (max_pages_per_seq)
+    (32, 32, 4, 128, 1024, 1024),    # Mellum's full layers
+    (64, 16, 2, 256, 1024, 1024),    # Qwen3-Next: page_buckets off
+    (64, 16, 16, 128, 128, 128),     # OLMoE: the program it had
+    (64, 16, 16, 128, 256, 256),     # ... and its widest table
+    (32, 8, 2, 128, 1024, 1024),     # a tp=4 shard's heads, the widest table
+], ids=["docqa", "docqa_512", "mellum_full", "qwen3next", "olmoe",
+        "olmoe_256", "tp4_shard_1024"])
+def test_all_heads_decode_compiles_at_the_derived_width(v5e, rows, h, kvh, d,
+                                                        max_pages, keys):
+    """The decode shape (query window 1: the all-heads body) of the four
+    serving configurations that run it, at the key block `window_step`
+    derives from their heads and tables (PR 48: up to 2 MiB of K and V a
+    step, within a sixteenth of the table), compiled for the described
+    v5e inside the compiler's own VMEM scope — no ``vmem_limit_bytes``."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    assert rpa.window_step(1, h, kvh, d, page_size=16, table_pages=max_pages,
+                           itemsize=2) == {"q_tile": 1, "block_keys": keys}
+    one = SingleDeviceSharding(v5e.devices[0])
+    text = _compile(
+        ragged_paged_attention,
+        *_paged_shapes(rows, 1, 16, max_pages, pool=4096, h=h, kvh=kvh, d=d),
+        sharding=[one] * 6).as_text()
+    assert re.search(rf"%ragged_paged_attention[.\d]* = "
+                     rf"bf16\[{rows},1,{h},{d}\].*tpu_custom_call", text)
+    assert _SCOPE not in text and '"scoped_memory_configs":[]' in text
 
 
 @pytest.mark.parametrize("rows,q_window,max_pages", [
@@ -172,8 +203,14 @@ def test_latent_form_compiles_at_the_kanana_cells_shapes(v5e, rows,
     dict(q_window=128, heads=H, kv_heads=KVH, d=D),                # doc-QA
     dict(q_window=128, heads=16, kv_heads=16, d=D),                # OLMoE
     dict(q_window=5, heads=H, kv_heads=KVH, d=D),                  # verify
+    dict(q_window=1, heads=H, kv_heads=KVH, d=D),         # the all-heads body
+    dict(q_window=1, heads=H, kv_heads=4, d=D),           # Mellum's full
+    dict(q_window=1, heads=16, kv_heads=2, d=256),        # Qwen3-Next
+    dict(q_window=1, heads=16, kv_heads=16, d=D),         # OLMoE
+    dict(q_window=8, heads=16, kv_heads=16, d=D),         # its widest verify
 ], ids=["latent_prefill", "latent_decode", "gqa_prefill", "mha_prefill",
-        "gqa_verify"])
+        "gqa_verify", "gqa_decode", "gqa8_decode", "gqa_of_256_decode",
+        "mha_decode", "mha_verify"])
 @pytest.mark.parametrize("table_pages", [4, 32, 256, 1024])
 def test_the_stated_vmem_budget_covers_the_declared_buffers(shape,
                                                             table_pages):
@@ -181,10 +218,12 @@ def test_the_stated_vmem_budget_covers_the_declared_buffers(shape,
     two slots a pool of the block buffer, the f32 accumulators and softmax
     state (m and l lie over 128 lanes), the q and out blocks twice (the
     pipeline double-buffers them) — is inside the `vmem_limit_bytes` it
-    states, with room for one kv head's score tile; the derivation's own
-    estimate counts at least as much; and the block is as wide as the
-    budget, the score tile's limit and the table allow. (The compiles above run under that
-    limit.)"""
+    states (the per-kv-head body) or the compiler's own scope (the
+    all-heads body, which states none), with room for the score tile; the
+    derivation's own estimate counts at least as much; and the block is as
+    wide as the budget, the body's own limit (the score tile's elements;
+    a step's bytes and a sixteenth of the table) and the table allow. (The
+    compiles above run under those scopes.)"""
     from ray_tpu.ops import ragged_paged_attention as rpa
     latent = "v_width" in shape
     step = rpa.window_step(**shape, page_size=16, table_pages=table_pages,
@@ -193,19 +232,26 @@ def test_the_stated_vmem_budget_covers_the_declared_buffers(shape,
     h, kvh, d = shape["heads"], shape["kv_heads"], shape["d"]
     d_v = shape.get("v_width", d)
     pools = 1 if latent else 2
-    rows = q_tile * h // kvh
+    all_heads = not latent and rpa._all_heads(q_tile, h // kvh)
+    slots = 1 if all_heads else kvh
+    rows = q_tile * h // slots
     declared = (pools * 2 * keys * kvh * d * 2             # block buffers
-                + kvh * rows * (d_v + 2 * 128) * 4         # acc, m, l
+                + slots * rows * (d_v + 2 * 128) * 4       # acc, m, l
                 + 2 * q_tile * h * (d + d_v) * 2)          # q, out blocks
     scores = rows * keys * 4
-    estimate = rpa._step_vmem_bytes(q_tile, h, kvh, d, d_v, pools, keys, 2)
-    assert declared + scores <= estimate <= rpa._VMEM_BUDGET
+    budget = rpa._DEFAULT_VMEM_SCOPE if all_heads else rpa._VMEM_BUDGET
+
+    def estimate(keys):
+        return rpa._step_vmem_bytes(q_tile, h, kvh, d, d_v, pools, keys, 2,
+                                    all_heads)
+    assert declared + scores <= estimate(keys) <= budget
     assert 128 <= keys <= rpa._MAX_BLOCK_KEYS
     assert keys == 128 or keys // 2 < table_pages * 16
     if keys < min(rpa._MAX_BLOCK_KEYS, table_pages * 16):
-        assert (rows * 2 * keys > rpa._SCORE_TILE_ELEMS
-                or rpa._step_vmem_bytes(q_tile, h, kvh, d, d_v, pools,
-                                        2 * keys, 2) > rpa._VMEM_BUDGET)
+        assert estimate(2 * keys) > budget or (
+            pools * kvh * d * 2 * 2 * keys > rpa._ALL_HEADS_STEP_BYTES
+            or rpa._ALL_HEADS_TABLE_BLOCKS * 2 * keys > table_pages * 16
+            if all_heads else rows * 2 * keys > rpa._SCORE_TILE_ELEMS)
 
 
 def test_flash_fwd_bwd_compile_at_8b_widths(v5e):

@@ -274,6 +274,9 @@ def test_decode_live_pages_by_hand():
     # 4 rows x the whole 16-wide table (no bucketing under 48 pages),
     # three times
     assert st["decode_table_pages"] == 3 * 4 * 16
+    # the 16-page table is one 128-key block: each live row's sweep copies
+    # all of it, clamped, three times
+    assert st["decode_swept_pages"] == 3 * 2 * 16
     assert st["prefill_tokens"] == 15 + 24
     # rows of 16 tokens: [0,15) | [0,16) [16,24): 2, 2 and 3 pages
     assert st["prefill_rows_live"] == 3
@@ -507,6 +510,16 @@ def _brute_key_steps(rows, window, table_pages, page, q_tile, block_keys):
     return steps, masked
 
 
+def _cell_model(module, cfg):
+    """(model module, one-layer config) at a serving cell's heads."""
+    if module == "llama":
+        return llama, llama.LlamaConfig(
+            vocab_size=512, n_layers=1, mlp_dim=128, max_seq_len=16384,
+            **cfg)
+    return mla_moe, mla_moe.MlaMoeConfig(
+        vocab_size=512, n_layers=1, max_seq_len=16384)
+
+
 @pytest.mark.parametrize("module,cfg", [
     ("llama", dict(dim=4096, n_heads=32, n_kv_heads=8)),       # doc-QA's
     ("llama", dict(dim=2048, n_heads=16, n_kv_heads=16)),      # OLMoE's
@@ -520,17 +533,11 @@ def test_key_step_counters_are_the_kernels_own_count(module, cfg):
     block width each model module reports for its kernel."""
     from ray_tpu.ops.ragged_paged_attention import live_key_steps
     rng = np.random.RandomState(32)
-    if module == "llama":
-        mod, mc = llama, llama.LlamaConfig(
-            vocab_size=512, n_layers=1, mlp_dim=128, max_seq_len=16384,
-            **cfg)
-    else:
-        mod, mc = mla_moe, mla_moe.MlaMoeConfig(
-            vocab_size=512, n_layers=1, max_seq_len=16384)
+    mod, mc = _cell_model(module, cfg)
     chunk, page = 128, 16
     widths = set()
     for table in (4, 8, 16, 32, 64, 128, 256, 512, 1024):
-        step = mod.prefill_attn_step(mc, chunk, page, table)
+        step = mod.attn_step(mc, chunk, page, table)
         widths.add(step["block_keys"])
         top = table * page - chunk
         rows = [(int(rng.randint(0, top + 1)) if top > 0 else 0,
@@ -545,6 +552,57 @@ def test_key_step_counters_are_the_kernels_own_count(module, cfg):
     assert len(widths) > 1 and min(widths) == 128   # narrow tables clamp
 
 
+@pytest.mark.parametrize("cells", [(
+    ("llama", dict(dim=4096, n_heads=32, n_kv_heads=8)),       # doc-QA's
+    ("llama", dict(dim=2048, n_heads=16, n_kv_heads=16)),      # OLMoE's
+    ("llama", dict(dim=4096, n_heads=32, n_kv_heads=4)),       # Mellum's
+    ("mla_moe", {}),                                           # kanana's
+)], ids=["gqa-mha-gqa8-latent"])
+def test_decode_swept_pages_against_a_brute_count(cells):
+    for module, cfg in cells:
+        _swept_pages_against_a_brute_count(module, cfg)
+
+
+def _swept_pages_against_a_brute_count(module, cfg):
+    """`_swept_pages` — what a decode launch adds to
+    ``decode_swept_pages`` — at the heads of the serving cells and every
+    table bucket, against the pages the kernel's sweeps step through
+    counted block by block: a live row's blocks (those that start below
+    its live pages, never more than the grid has steps) x the pages a
+    block holds, its masked tail and all; a row of length 0 sweeps
+    nothing. (The method on a stand-in for the engine: its model seam,
+    its lengths, its page size.)"""
+    import types
+    mod, mc = _cell_model(module, cfg)
+    page, bs = 16, 4
+    eng = types.SimpleNamespace(
+        cfg=types.SimpleNamespace(page_size=page),
+        _lengths=np.zeros(bs, np.int64),
+        _attn_step=lambda window, table: mod.attn_step(mc, window, page,
+                                                       table))
+
+    def swept(slots, table):
+        return PagedInferenceEngine._swept_pages(eng, slots, table)
+    rng = np.random.RandomState(48)
+    widths = set()
+    for table in (4, 16, 64, 128, 256, 512, 1024):
+        block_pages = mod.attn_step(mc, 1, page, table)["block_keys"] // page
+        widths.add(block_pages * page)
+        cap = table * page
+        eng._lengths[:] = [cap, 0, int(rng.randint(1, cap + 1)),
+                           min(cap, block_pages * page)]
+        want = live = 0
+        for sl in range(bs):
+            pages = -(-int(eng._lengths[sl]) // page)
+            live += pages
+            for b in range(-(-table // block_pages)):
+                if b * block_pages < pages:
+                    want += block_pages         # a live step: one block
+        assert swept(range(bs), table) == want >= live, table
+        assert swept([1], table) == 0
+    assert len(widths) > 1 and min(widths) == 128
+
+
 def test_prefill_step_counts_its_rows_key_steps(engine, monkeypatch):
     """The engine adds, for every prefill dispatch, the count of its live
     rows at the dispatch's table width."""
@@ -553,12 +611,13 @@ def test_prefill_step_counts_its_rows_key_steps(engine, monkeypatch):
 
     def counted(starts, q_lens, window, table_pages, *, page_size, **step):
         assert page_size == engine.cfg.page_size
-        assert step == engine.model.prefill_attn_step(
+        assert step == engine.model.attn_step(
             engine.cfg.model, window, page_size, table_pages, 1)
         got = _brute_key_steps(list(zip(map(int, starts), map(int, q_lens))),
                                window, table_pages, page_size, **step)
-        want[0] += got[0]
-        want[1] += got[1]
+        if window > 1:          # a decode launch counts its sweeps too
+            want[0] += got[0]
+            want[1] += got[1]
         return got
     monkeypatch.setattr(paged_engine, "live_key_steps", counted)
     before = dict(engine.stats)

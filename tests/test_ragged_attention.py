@@ -545,11 +545,13 @@ def test_latent_form_matches_its_oracle(case):
 _WIDTHS = (128, 256, 512, 1024)
 
 
-def _per_head_case(form, rng, table, starts, q_lens, window, page=8):
+def _per_head_case(form, rng, table, starts, q_lens, window, page=8,
+                   heads=(4, 2, 32)):
     """(args, kwargs of _ragged_call, oracle) of one call in the latent
     form (4 heads on one 128-lane kv head, values its first 96 lanes) or
-    the GQA per-kv-head form (2 kv heads x 2 query heads of 32). Every
-    row's live pages are its own; the table's tail is the poisoned sink."""
+    the GQA form at ``heads`` = (query heads, kv heads, head size): 2 kv
+    heads x 2 query heads of 32 unless given. Every row's live pages are
+    its own; the table's tail is the poisoned sink."""
     from ray_tpu.ops import ragged_paged_attention as rpa
     rows = len(starts)
     live = [-(-(s + n) // page) for s, n in zip(starts, q_lens)]
@@ -571,10 +573,11 @@ def _per_head_case(form, rng, table, starts, q_lens, window, page=8):
         want = rpa.ragged_latent_reference(
             q, jnp.asarray(pages).at[0].set(0.0), *tail, **kw)
     else:
-        kp, vp = _pools(rng, pool_pages, page, 2, 32)
+        h, kvh, d = heads
+        kp, vp = _pools(rng, pool_pages, page, kvh, d)
         kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
-        q = jnp.asarray(rng.randn(rows, window, 4, 32), jnp.float32)
-        kw = dict(scale=32 ** -0.5)
+        q = jnp.asarray(rng.randn(rows, window, h, d), jnp.float32)
+        kw = dict(scale=d ** -0.5)
         args = (q, kp, vp) + tail
         want = ragged_paged_reference(q, kp.at[0].set(0.0),
                                       vp.at[0].set(0.0), *tail)
@@ -624,6 +627,52 @@ def test_per_head_body_under_a_table_narrower_than_a_block(form, block_keys):
     live = np.arange(16)[None, :] < np.asarray(q_lens)[:, None]
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                atol=2e-5, rtol=1e-5)
+
+
+# ---- the all-heads body's derived key block (PR 48; the body itself at every
+# width: tests/test_ragged_all_heads.py) ----
+
+# the decode shape (query window 1) of the five serving configurations:
+# window_step's arguments and the key block it derives
+_DECODE_STEPS = {
+    # 8 x 128 lanes under doc-QA's 256-page bucket: 1 MiB of K and V a step
+    "docqa": (dict(heads=32, kv_heads=8, d=128, table_pages=256), 256),
+    # 16 x 128: a 128-key step already carries 1 MiB, the table is short
+    "olmoe": (dict(heads=16, kv_heads=16, d=128, table_pages=128), 128),
+    # 4 x 128 and 2 x 256 lanes under 1,024 pages: 1 MiB at the widest
+    "mellum_full": (dict(heads=32, kv_heads=4, d=128, table_pages=1024),
+                    1024),
+    "qwen3next": (dict(heads=16, kv_heads=2, d=256, table_pages=1024), 1024),
+    # the window form keeps its 128-key step under the all-heads body
+    "mellum_window": (dict(heads=32, kv_heads=4, d=128, table_pages=97,
+                           window=1024), 128),
+    # the latent form is the per-kv-head body's: PR 32's width
+    "kanana": (dict(heads=32, kv_heads=1, d=640, v_width=512,
+                    table_pages=1024), 1024),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_DECODE_STEPS))
+def test_window_step_at_the_serving_decode_shapes(name):
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    shape, keys = _DECODE_STEPS[name]
+    assert rpa.window_step(1, **shape, page_size=16, itemsize=2) == {
+        "q_tile": 1, "block_keys": keys}
+
+
+@pytest.mark.parametrize("table_pages,keys", [
+    (4, 128), (64, 128), (128, 128), (256, 256), (512, 512), (1024, 512)])
+def test_all_heads_block_follows_the_table_and_the_steps_bytes(table_pages,
+                                                              keys):
+    """Doc-QA's heads over its ladder of table buckets: a block is at most
+    a sixteenth of the table (a row's sweep pays a block and a half
+    whatever it holds) and stops doubling at 2 MiB of K and V a step —
+    static shapes alone, the same for any model with these heads."""
+    from ray_tpu.ops import ragged_paged_attention as rpa
+    step = rpa.window_step(1, 32, 8, 128, page_size=16,
+                           table_pages=table_pages, itemsize=2)
+    assert step == {"q_tile": 1, "block_keys": keys}
+    assert 2 * 8 * 128 * 2 * keys <= rpa._ALL_HEADS_STEP_BYTES
 
 
 def test_unmasked_and_masked_steps_agree_bit_for_bit():
