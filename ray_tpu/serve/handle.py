@@ -4,10 +4,15 @@ Reference parity: serve/handle.py:639 (DeploymentHandle.remote :715 ->
 DeploymentResponse), _private/router.py:365 (AsyncioRouter.assign_request
 :676) and request_router/pow_2_router.py:27 (power-of-two-choices).
 
-Routing here tracks in-flight counts per handle (each handle routes its own
-traffic) and picks the lighter of two random replicas; the replica set is
-cached and refreshed from the controller when its version changes or a
-replica dies mid-call (retried once on a fresh set).
+The routing state of a deployment is ONE object a process (``_Router``):
+the cached replica set, the in-flight counts of what this process sent
+over it, and the one long-poll listener that keeps it fresh. Every handle
+on the deployment — ``options()``, ``handle.method``, an unpickled copy —
+is a view of that object and keeps only what selects a call (method,
+stream, model id, pinned replica), so a handle made per request costs no
+controller round trip and no thread. Routing picks the lighter of two
+random replicas by those shared counts; the set is refreshed when its
+version changes or a replica dies mid-call (retried once on a fresh set).
 
 Prefix affinity: LLM-style requests (a dict carrying ``prompt``, or an
 explicit ``session_id``) rendezvous-hash onto a stable replica so repeated
@@ -20,7 +25,9 @@ prefix cannot hotspot a replica into queueing.
 from __future__ import annotations
 
 import random
+import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Optional
 
@@ -28,7 +35,7 @@ from ..core.config import cfg as _cfg
 from ..core import flight as _fl
 
 # affinity yields to load: the preferred replica is skipped when it has
-# this many more in-flight requests (on this handle) than the lightest
+# this many more in-flight requests (from this process) than the lightest
 # replica — a cache hit saves prefill, not a queueing delay
 _AFFINITY_SLACK = 4
 
@@ -73,6 +80,10 @@ class DeploymentResponse:
             self._done = True
             self._on_done()
 
+    # a response dropped without result() must not stay counted in the
+    # router its deployment's other handles route by
+    __del__ = _settle
+
     def _to_object_ref(self):
         return self._ref
 
@@ -109,13 +120,13 @@ class ChannelResponseGenerator:
         from ..core.ids import ObjectID
         from ..dag.channel import RingReader
         rt = rt_mod.get_runtime_if_exists()
+        self._on_done = on_done
+        self._done = False
         self._replica = replica
         self._reader = RingReader(rt.store, chan["base"],
                                   ObjectID(chan["stop"]),
                                   int(chan["ring"]))
-        self._on_done = on_done
         self._tags = {**tags, "transport": "chan"}
-        self._done = False
         self._idle = 0
 
     def __iter__(self):
@@ -164,6 +175,8 @@ class ChannelResponseGenerator:
                 self._on_done()
                 self._on_done = None
 
+    __del__ = _settle
+
     def cancel(self):
         if self._done:
             return
@@ -210,30 +223,34 @@ class DeploymentResponseGenerator:
                 pass  # telemetry must never fail a stream
             self._buf.extend(items)
             if done:
-                self._done = True
-                if self._on_done:
-                    self._on_done()
-                    self._on_done = None
+                self._settle()
         return self._buf.popleft()
+
+    def _settle(self):
+        self._done = True
+        if self._on_done:
+            self._on_done()
+            self._on_done = None
+
+    __del__ = _settle
 
     def cancel(self):
         import ray_tpu
         if not self._done:
-            self._done = True
             try:
                 ray_tpu.get(self._replica.stream_cancel.remote(self._sid))
             except Exception:
                 pass  # replica died; stream is gone either way
-            if self._on_done:
-                self._on_done()
-                self._on_done = None
+            self._settle()
 
 
 # Bumped by serve.shutdown() (api.py): a listener thread started under an
 # earlier value ends at its next turn instead of backing off and retrying
 # against whatever controller the process starts next — where each stale
 # thread parked one long-poll object in the NEW cluster's store (the
-# objects tests/test_serve_frontdoor.py's drain check allows one of).
+# objects tests/test_serve_frontdoor.py's drain check allows one of). It
+# is part of the registry's key too, so a handle made after a shutdown
+# never finds the router (and the replicas) of the serve instance before.
 _serve_epoch = 0
 
 
@@ -242,24 +259,166 @@ def end_listeners() -> None:
     _serve_epoch += 1
 
 
-def _listen_loop_weak(handle_ref):
-    """Body of a handle's long-poll listener thread. Takes a weakref so an
-    abandoned handle (and this thread) can die; between polls only ids are
-    kept live. Ends when serve is shut down in this process: the handle
-    starts a new one at its next request (`_ensure_listener`)."""
+class _ReplicaSet:
+    """One version of a deployment's replica set and the in-flight count
+    of what this process sent to each member. A change of the set is a NEW
+    object installed by one assignment (``_Router.rs``): a routing thread
+    that read ``rs`` once holds replicas, version and counts that belong
+    together, and a response that settles after a change counts down on
+    the set it was counted up on."""
+
+    __slots__ = ("version", "replicas", "inflight")
+
+    def __init__(self, version: int, replicas: list):
+        self.version = version
+        self.replicas = replicas
+        self.inflight = [0] * len(replicas)  # guarded by: _Router.lock
+
+
+class _Router:
+    """What ``remote()`` routes by, once a (controller, app, deployment)
+    in this process; handles are views of it (``_router_for``)."""
+
+    def __init__(self, ctrl, app: str, deployment: str):
+        self.ctrl = ctrl
+        self.app = app
+        self.deployment = deployment
+        self.rs = _ReplicaSet(-1, [])
+        # monotonic time the newest answer was asked for (a long-poll's:
+        # returned); 0.0 until the first
+        self.last_refresh = 0.0
+        # re-entrant: a response dropped unsettled counts down from its
+        # finalizer, which may run inside this thread's own hold
+        self.lock = threading.RLock()
+        self._fetch_lock = threading.Lock()
+        self._listener_started = False  # guarded by: self.lock
+
+    def install(self, version: int, replicas: list, asked: float) -> None:
+        with self.lock:
+            if version != self.rs.version:
+                self.rs = _ReplicaSet(version, replicas)
+            self.last_refresh = max(self.last_refresh, asked)
+
+    def _why_fetch(self, force: bool, called: float) -> Optional[str]:
+        """Why a caller that arrived at `called` has to fetch; None when
+        what is installed will do."""
+        last = self.last_refresh
+        if not last:
+            return "cold"
+        if force or not self.rs.replicas:
+            # satisfied by an answer that was asked for after this call
+            return None if last > called else "forced"
+        if called - last >= _cfg.serve_replica_poll_s:
+            return "ttl"
+        return None
+
+    def refresh(self, force: bool = False) -> None:
+        """Fetch the replica set unless it is fresh. One fetch at a time:
+        the threads that arrive at a cold router together wait for the
+        first one's answer and find it fresh."""
+        import ray_tpu
+        called = time.monotonic()
+        if self._why_fetch(force, called) is None:
+            return
+        with self._fetch_lock:
+            why = self._why_fetch(force, called)
+            if why is None:
+                return
+            asked = time.monotonic()
+            version, replicas = ray_tpu.get(self.ctrl.get_replicas.remote(
+                self.app, self.deployment))
+            self.install(version, replicas, asked)
+        try:
+            from . import metrics as sm
+            sm.handle_refreshes().inc(1.0, tags={
+                "app": self.app, "deployment": self.deployment, "why": why})
+        except Exception:
+            pass  # telemetry must never fail a request
+        if why == "cold":
+            _publish_routers(self.app, self.deployment)
+
+    def ensure_listener(self) -> None:
+        """Long-poll push of replica-set changes (reference:
+        _private/long_poll.py LongPollClient): one daemon thread parks in
+        the controller's listen_for_change, so scale-ups/downs reach every
+        handle on this deployment promptly instead of on the next TTL
+        poll, and steady-state traffic costs the controller one parked
+        waiter a process, not one a handle. The thread holds only a
+        WEAKREF to the router and exits when the last handle is gone."""
+        with self.lock:
+            if self._listener_started:
+                return
+            self._listener_started = True
+        threading.Thread(target=_listen_loop_weak,
+                         args=(weakref.ref(self), self.app, self.deployment),
+                         daemon=True,
+                         name=f"serve-lp-{self.deployment}").start()
+
+
+_routers: "weakref.WeakValueDictionary" = weakref.WeakValueDictionary()
+_routers_lock = threading.Lock()
+
+
+def _router_for(ctrl, app: str, deployment: str) -> _Router:
+    """The process's router for a deployment, made at the first handle on
+    it. Weak: it goes with its last handle (and in-flight response)."""
+    key = (_serve_epoch, getattr(ctrl, "_actor_id", None), app, deployment)
+    with _routers_lock:
+        router = _routers.get(key)
+        if router is None:
+            router = _routers[key] = _Router(ctrl, app, deployment)
+    return router
+
+
+def _publish_routers(app: str, deployment: str) -> None:
+    """Set the live-routers gauge: after a router's first fetch (one that
+    never routed is not counted — the controller makes and pickles away a
+    handle for every bound child) and where its listener ends. Never from
+    a finalizer: those run inside any lock."""
+    try:
+        from . import metrics as sm
+        with _routers_lock:
+            n = sum(1 for r in _routers.values()
+                    if r.app == app and r.deployment == deployment)
+        sm.handle_routers().set(float(n), tags={
+            "app": app, "deployment": deployment, "proc": _proc()})
+    except Exception:
+        pass  # telemetry must never fail a request
+
+
+def _proc() -> str:
+    """host:pid, the label the head's worker-death sweep zeroes a dead
+    process's gauges by (llm/telemetry.py has the same; importing it here
+    would bring jax into the proxy)."""
+    import os
+    import socket
+    return f"{socket.gethostname()}:{os.getpid()}"
+
+
+def _listen_loop_weak(router_ref, app: str, deployment: str):
+    """Body of a router's long-poll listener thread. Takes a weakref so an
+    abandoned router (and this thread) can die; between polls only ids are
+    kept live. Ends when serve is shut down in this process: the router
+    starts a new one at its next request (`ensure_listener`)."""
+    try:
+        _listen_loop(router_ref)
+    finally:
+        _publish_routers(app, deployment)
+
+
+def _listen_loop(router_ref):
     import ray_tpu
     failures = 0
     epoch = _serve_epoch
     while True:
-        h = handle_ref()
-        if h is None:
+        r = router_ref()
+        if r is None:
             return
         if epoch != _serve_epoch:
-            h._listener_started = False
+            r._listener_started = False
             return
-        ctrl, app, dep, known = (h._ctrl, h.app_name, h.deployment_name,
-                                 h._version)
-        del h  # don't pin the handle across the (long) poll
+        ctrl, app, dep, known = r.ctrl, r.app, r.deployment, r.rs.version
+        del r  # don't pin the router across the (long) poll
         try:
             version, replicas = ray_tpu.get(
                 ctrl.listen_for_change.remote(app, dep, known),
@@ -268,33 +427,30 @@ def _listen_loop_weak(handle_ref):
         except Exception:
             # controller busy/restarting or deployment deleted; back off
             # and give up after repeated failures (the TTL path in
-            # _refresh still keeps the handle usable)
+            # refresh still keeps the router usable)
             failures += 1
-            h = handle_ref()
-            if failures >= 5 or h is None:
-                if h is not None:
-                    h._listener_started = False
+            r = router_ref()
+            if failures >= 5 or r is None:
+                if r is not None:
+                    r._listener_started = False
                 return
-            del h
+            del r
             time.sleep(min(2.0 ** failures, 10.0))
             continue
-        h = handle_ref()
-        if h is None:
+        r = router_ref()
+        if r is None:
             return
-        if version != h._version:
-            # atomic installs: readers snapshot these attributes
-            h._inflight = {i: 0 for i in range(len(replicas))}
-            h._replicas = replicas
-            h._version = version
-        h._last_refresh = time.monotonic()
-        del h
+        # the poll's answer is the state as it returns, not as asked
+        r.install(version, replicas, time.monotonic())
+        del r
 
 
 class DeploymentHandle:
     def __init__(self, deployment: str, app: str, controller,
                  method: str = "__call__", stream: bool = False,
                  multiplexed_model_id: str = "",
-                 replica_index: Optional[int] = None):
+                 replica_index: Optional[int] = None,
+                 _router: Optional[_Router] = None):
         self.deployment_name = deployment
         self.app_name = app
         self._ctrl = controller
@@ -302,13 +458,10 @@ class DeploymentHandle:
         self._stream = stream
         self._model_id = multiplexed_model_id
         self._replica_index = replica_index
-        self._replicas: list = []
-        self._version = -1
-        self._inflight: dict[int, int] = {}
-        self._last_refresh = 0.0
-        self._listener_started = False
+        self._router = _router or _router_for(controller, app, deployment)
 
-    # handles pickle into replicas/tasks; router state is rebuilt lazily
+    # handles pickle into replicas/tasks and find (or make) the receiving
+    # process's router there
     def __reduce__(self):
         return (DeploymentHandle,
                 (self.deployment_name, self.app_name, self._ctrl,
@@ -327,14 +480,12 @@ class DeploymentHandle:
             self._model_id if multiplexed_model_id is None
             else multiplexed_model_id,
             self._replica_index if replica_index is None
-            else replica_index)
+            else replica_index, _router=self._router)
 
     def __getattr__(self, name: str) -> "DeploymentHandle":
         if name.startswith("_"):
             raise AttributeError(name)
-        return DeploymentHandle(self.deployment_name, self.app_name,
-                                self._ctrl, name, self._stream,
-                                self._model_id, self._replica_index)
+        return self.options(method_name=name)
 
     # -- routing ----------------------------------------------------------
 
@@ -342,40 +493,8 @@ class DeploymentHandle:
         """Live replica count (fresh poll) — lets index-pinned callers
         (see ``options(replica_index=...)``) size their routing modulus
         to the deployment's actual width."""
-        self._refresh(force=True)
-        return len(self._replicas)
-
-    def _ensure_listener(self):
-        """Long-poll push of replica-set changes (reference:
-        _private/long_poll.py LongPollClient): one daemon thread parks in
-        the controller's listen_for_change, so scale-ups/downs reach this
-        handle promptly instead of on the next TTL poll, and steady-state
-        traffic costs the controller one parked waiter, not one
-        get_replicas per poll interval. The thread holds only a WEAKREF to
-        this handle and exits when the handle is collected — short-lived
-        handles (e.g. per-request ones) must not each pin a thread."""
-        if self._listener_started:
-            return
-        self._listener_started = True
-        import threading
-        import weakref
-        threading.Thread(target=_listen_loop_weak,
-                         args=(weakref.ref(self),), daemon=True,
-                         name=f"serve-lp-{self.deployment_name}").start()
-
-    def _refresh(self, force: bool = False):
-        import ray_tpu
-        now = time.monotonic()
-        if not force and self._replicas and (
-                now - self._last_refresh < _cfg.serve_replica_poll_s):
-            return
-        version, replicas = ray_tpu.get(self._ctrl.get_replicas.remote(
-            self.app_name, self.deployment_name))
-        if version != self._version:
-            self._version = version
-            self._replicas = replicas
-            self._inflight = {i: 0 for i in range(len(replicas))}
-        self._last_refresh = now
+        self._router.refresh(force=True)
+        return len(self._router.rs.replicas)
 
     @staticmethod
     def _affinity_key(args: tuple, kwargs: dict) -> Optional[str]:
@@ -399,8 +518,8 @@ class DeploymentHandle:
             return "tok:" + ",".join(map(str, prompt[:64]))
         return None
 
-    def _pick(self, replicas: list, affinity: Optional[str] = None) -> int:
-        """Power-of-two-choices over local in-flight counts
+    def _pick(self, rs: _ReplicaSet, affinity: Optional[str] = None) -> int:
+        """Power-of-two-choices over the process's in-flight counts
         (reference: pow_2_router.py:27). With a multiplexed model id,
         rendezvous hashing over stable replica (actor) ids instead: same
         model → same replica while it lives, so its weights stay
@@ -408,8 +527,9 @@ class DeploymentHandle:
         prompt prefix / session) rendezvous-hashes the same way — same
         prefix → same replica → warm prefix cache — but yields to the
         least-loaded replica when the preferred one is clearly busier.
-        Operates on the caller's SNAPSHOT of the replica list — the
-        listener thread may swap self._replicas concurrently."""
+        Operates on the caller's SNAPSHOT of the replica set — the
+        listener thread may install another concurrently."""
+        replicas, loads = rs.replicas, rs.inflight
         n = len(replicas)
         if n == 1:
             return 0
@@ -425,13 +545,11 @@ class DeploymentHandle:
             return rendezvous(self._model_id)
         if affinity is not None:
             pref = rendezvous(affinity)
-            loads = [self._inflight.get(i, 0) for i in range(n)]
             if loads[pref] <= min(loads) + _AFFINITY_SLACK:
                 return pref
             return loads.index(min(loads))
         i, j = random.sample(range(n), 2)
-        return i if self._inflight.get(i, 0) <= self._inflight.get(j, 0) \
-            else j
+        return i if loads[i] <= loads[j] else j
 
     @staticmethod
     def _make_chan_spec():
@@ -453,34 +571,37 @@ class DeploymentHandle:
     def remote(self, *args, **kwargs) -> DeploymentResponse:
         import ray_tpu
         t0 = time.perf_counter()
-        self._refresh()
-        self._ensure_listener()
+        router = self._router
+        router.refresh()
+        router.ensure_listener()
         deadline = time.monotonic() + 30.0
-        while not self._replicas:
+        while not router.rs.replicas:
             if time.monotonic() > deadline:
                 raise RuntimeError(
                     f"no replicas for {self.deployment_name!r}")
             time.sleep(0.05)
-            self._refresh(force=True)
+            router.refresh(force=True)
         args = tuple(a._to_object_ref() if isinstance(a, DeploymentResponse)
                      else a for a in args)
         kwargs = {k: (v._to_object_ref()
                       if isinstance(v, DeploymentResponse) else v)
                   for k, v in kwargs.items()}
-        replicas = self._replicas  # snapshot: listener may swap the list
+        rs = router.rs  # snapshot: the listener may install another set
         if self._replica_index is not None:
             # pinned routing (PD channel pairing): the caller addresses a
             # specific replica by stable index, modulo the live count so a
             # scale-down degrades to wraparound instead of erroring
-            idx = self._replica_index % len(replicas)
+            idx = self._replica_index % len(rs.replicas)
         else:
-            idx = self._pick(replicas, self._affinity_key(args, kwargs))
-        replica = replicas[idx]
-        self._inflight[idx] = self._inflight.get(idx, 0) + 1
+            idx = self._pick(rs, self._affinity_key(args, kwargs))
+        replica = rs.replicas[idx]
+        with router.lock:
+            rs.inflight[idx] += 1
         _fl.evt(_fl.SRV_DISPATCH, idx, int(self._stream))
 
-        def done(i=idx):
-            self._inflight[i] = max(0, self._inflight.get(i, 1) - 1)
+        def done():
+            with router.lock:
+                rs.inflight[idx] -= 1
 
         request_id = ""
         try:
@@ -503,8 +624,12 @@ class DeploymentHandle:
             import ray_tpu
             tags = {"app": self.app_name, "deployment": self.deployment_name}
             chan = self._make_chan_spec()
-            resp = ray_tpu.get(replica.handle_request_streaming.remote(
-                self._method, args, kwargs, context, chan))
+            try:
+                resp = ray_tpu.get(replica.handle_request_streaming.remote(
+                    self._method, args, kwargs, context, chan))
+            except BaseException:
+                done()  # no stream was opened: nothing else settles it
+                raise
             try:
                 from . import metrics as sm
                 sm.stream_dispatches().inc(1.0, tags={
@@ -521,12 +646,12 @@ class DeploymentHandle:
             return DeploymentResponseGenerator(replica, resp, done, tags)
 
         def retry():
-            self._refresh(force=True)
-            rs = self._replicas
-            if not rs:
+            router.refresh(force=True)
+            fresh = router.rs
+            if not fresh.replicas:
                 raise RuntimeError(
                     f"no replicas for {self.deployment_name!r}")
-            r = rs[self._pick(rs)]
+            r = fresh.replicas[self._pick(fresh)]
             return r.handle_request.remote(self._method, args, kwargs,
                                            context)
 

@@ -14,6 +14,14 @@ Metric names and label sets:
   rtpu_serve_handle_requests_total{app,deployment}       counter
   rtpu_serve_router_wait_seconds{app,deployment}         histogram (handle
       call -> request handed to a replica: replica-set refresh + cold start)
+  rtpu_serve_handle_routers{app,deployment,proc}         gauge (live
+      routers — a deployment's shared routing state, handle._Router — in
+      the process proc = host:pid: one a deployment the process calls,
+      however many handles it made)
+  rtpu_serve_handle_refreshes_total{app,deployment,why}  counter (replica
+      sets fetched from the controller: cold = a router's first, ttl = the
+      listener's push is older than cfg.serve_replica_poll_s, forced = an
+      empty or dead set; grows with routers, never with requests)
   rtpu_serve_replica_latency_seconds{app,deployment}     histogram
   rtpu_serve_replica_requests_total{app,deployment,outcome} counter
   rtpu_serve_queue_depth{app,deployment}                 gauge (ongoing
@@ -97,6 +105,20 @@ def router_wait() -> Histogram:
                    "handle call to replica hand-off (replica-set refresh "
                    "and cold-start wait)", boundaries=_LAT,
                    tag_keys=("app", "deployment"))
+
+
+def handle_routers() -> Gauge:
+    return _metric(Gauge, "rtpu_serve_handle_routers",
+                   "live routers (a deployment's shared routing state) "
+                   "in one process",
+                   tag_keys=("app", "deployment", "proc"))
+
+
+def handle_refreshes() -> Counter:
+    return _metric(Counter, "rtpu_serve_handle_refreshes_total",
+                   "replica sets fetched from the controller, by why: "
+                   "cold | ttl | forced",
+                   tag_keys=("app", "deployment", "why"))
 
 
 def replica_latency() -> Histogram:
@@ -302,6 +324,8 @@ def metrics_summary() -> dict:
           outcomes (front-door fairness/quota counter-verification)
       lora — {requests, hits, loads, evictions, swaps, publishes,
           resident_adapters} multi-LoRA lifecycle counters
+      handles — {routers, refreshes: {cold, ttl, forced}}: live routers
+          summed over processes and the replica sets they fetched
       requests — {proxy, handle, replica, errors} cumulative counts
     Worker-side series ship on a ~2s cadence; a summary taken immediately
     after traffic may trail by one flush tick.
@@ -478,6 +502,19 @@ def metrics_summary() -> dict:
                 "rtpu_serve_prefix_directory_publishes_total")),
             "stale_dropped": _counter_total(store.get(
                 "rtpu_serve_prefix_directory_stale_total")),
+        }
+    refreshes = store.get("rtpu_serve_handle_refreshes_total")
+    if refreshes:
+        by_why: dict = {}
+        for kk, vv in refreshes["series"].items():
+            why = next((v for k, v in kk if k == "why"), "")
+            by_why[why] = by_why.get(why, 0.0) + vv
+        # one router a (process, deployment called): the sum over the
+        # proc-labelled series, beside what they fetched and why
+        out["handles"] = {
+            "routers": _counter_total(
+                store.get("rtpu_serve_handle_routers")),
+            "refreshes": by_why,
         }
     out["requests"] = {
         "proxy": _counter_total(

@@ -39,11 +39,12 @@ class ProxyActor:
         self._port = port
         self._index = index
         self._runner = None
-        # handle cache: a DeploymentHandle per routing variant, NOT per
-        # request — each handle runs one long-poll listener thread, so
-        # per-request construction would leak threads/waiters. Bounded
-        # LRU; evicted handles are GC'd and their listener threads exit
-        # (weakref-based, see handle._ensure_listener)
+        # handle cache: a DeploymentHandle per routing variant, so a
+        # request resolves no controller by name. The variants of one
+        # deployment are views of this process's one router for it
+        # (handle._Router: one replica set, one long-poll listener
+        # thread, whatever this holds). Bounded LRU; a router goes, and
+        # its listener ends, with the last handle on its deployment
         from collections import OrderedDict
         self._handles: "OrderedDict" = OrderedDict()
         self._handles_max = 256

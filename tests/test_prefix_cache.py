@@ -228,13 +228,12 @@ class TestPrefixAffinityRouting:
     def _handle(n):
         from types import SimpleNamespace
 
-        from ray_tpu.serve.handle import DeploymentHandle
+        from ray_tpu.serve.handle import DeploymentHandle, _ReplicaSet
         h = DeploymentHandle("d", "a", controller=None)
         replicas = [SimpleNamespace(
             _actor_id=SimpleNamespace(hex=lambda i=i: f"replica-{i:02d}"))
             for i in range(n)]
-        h._inflight = {i: 0 for i in range(n)}
-        return h, replicas
+        return h, _ReplicaSet(0, replicas)
 
     def test_affinity_key_extraction(self):
         from ray_tpu.serve.handle import DeploymentHandle
@@ -262,7 +261,7 @@ class TestPrefixAffinityRouting:
         from ray_tpu.serve.handle import _AFFINITY_SLACK
         h, replicas = self._handle(4)
         pref = h._pick(replicas, "tok:hot-prefix")
-        h._inflight[pref] = _AFFINITY_SLACK + 1
+        replicas.inflight[pref] = _AFFINITY_SLACK + 1
         idle = h._pick(replicas, "tok:hot-prefix")
         assert idle != pref
-        assert h._inflight[idle] == 0
+        assert replicas.inflight[idle] == 0

@@ -98,7 +98,7 @@ def test_serve_longpoll_pushes_scale_change(ray_start_regular):
 
     h = serve.run(hello.bind(), name="lp-app")
     assert h.remote().result(timeout_s=60) == "hi"
-    v0 = h._version
+    v0 = h._router.rs.version
 
     # long-poll on the controller directly: scale up must wake the waiter
     ctrl = h._ctrl

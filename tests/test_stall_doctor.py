@@ -185,7 +185,12 @@ def test_stack_pull_returns_while_executor_blocked(stall_ray):
         for p in rep["procs"]:
             for th in p.get("threads", ()):
                 w = th.get("wait")
-                if w and th.get("task", "").startswith("blocked_get"):
+                # a parked get asks the head where its object is once a
+                # second (worker._try_fetch's locate rpc) and waits a few
+                # ms for that reply, an object no task produces: take the
+                # sample that shows it parked on the ref itself
+                if w and w.get("target") and \
+                        th.get("task", "").startswith("blocked_get"):
                     parked = (p, th)
         if parked is None:
             time.sleep(0.2)
