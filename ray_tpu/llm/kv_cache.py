@@ -53,11 +53,13 @@ WINDOW_COUNTERS = (
 # filed, resumed, reclaimed and refused (a pool whose every snapshot is
 # pinned), prompt tokens a resumed snapshot covered / a request still ran,
 # full pages a hit found beyond the newest snapshot (re-run, not trusted),
-# and the snapshots the pool held at each decode booking (PERF.md §3)
+# the snapshots the pool held at each decode booking, and the pages of the
+# page pool beside it that some sequence held there (PERF.md §3)
 STATE_COUNTERS = (
     "state_snapshots_taken", "state_snapshots_refused",
     "state_snapshot_hits", "state_evictions", "state_hit_tokens",
-    "state_rerun_tokens", "state_pages_untrusted", "state_pool_live")
+    "state_rerun_tokens", "state_pages_untrusted", "state_pool_live",
+    "full_pool_live_pages")
 
 
 def window_need(cfg, model, prefill_rows: int) -> tuple:

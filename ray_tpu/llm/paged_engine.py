@@ -91,11 +91,12 @@ def model_module(model_cfg):
     ``experts_held`` ([lo, hi) of the routed experts whose weights this
     replica has; where that is a share of them the programs hand back a
     load of E + 1 entries, the last the distinct held experts reached, a
-    layer a step),
+    layer a step, and E + 2 where the router selects groups first:
+    models/llama.py ``held_load``),
     ``attn_step``,
     ``lora_targets`` and, under a mesh, ``check_mesh`` (which may refuse)
     and then ``logical_axes`` and ``cache_logical_axes`` (models/llama.py,
-    models/mla_moe.py, models/qwen3_next.py)."""
+    models/mla_moe.py, models/qwen3_next.py, models/ling_hybrid.py)."""
     return sys.modules[type(model_cfg).__module__]
 
 
@@ -546,13 +547,16 @@ class PagedInferenceEngine:
             self.stats.update(moe_assign_live=0, moe_assign_run=0,
                               moe_expert_load_sum=0, moe_expert_load_max=0)
         # a replica that holds a share of the routed experts: the
-        # assignments that fell on them, the busiest of them, and the
-        # distinct ones a decode step reached
+        # assignments that fell on them, the busiest of them, the
+        # distinct ones a decode step reached, and (a router that selects
+        # groups first) the tokens x layers whose groups include a held
+        # one — beside moe_assign_held, what tells "half the tokens bring
+        # two assignments" from "every token brings one"
         self._held = tuple(self.model.experts_held(mc))
         self._share = self._routing[2] < self._routing[0]
         if self._share:
             self.stats.update(moe_assign_held=0, moe_held_load_max=0,
-                              moe_held_hit_decode=0)
+                              moe_held_hit_decode=0, moe_group_hits=0)
         # a model with layers of two kinds: what the cache counts of each
         if self.cache.window is not None:
             self.stats.update(dict.fromkeys(WINDOW_COUNTERS, 0))
@@ -1492,11 +1496,14 @@ class PagedInferenceEngine:
             return
         st = self.stats
         if self._share:
-            # a share's load ends in the distinct held experts its layers
-            # reached, summed over layers and steps (model_module)
-            load, hit = load[:-1], int(load[-1])
+            # behind a share's load: the distinct held experts its layers
+            # reached and, under group-limited selection, the tokens whose
+            # groups include a held one — each summed over layers and
+            # steps (model_module)
+            load, tail = load[:self._routing[0]], load[self._routing[0]:]
             here = load[self._held[0]:self._held[1]]
-            st["moe_held_hit_decode"] += hit * decode
+            st["moe_held_hit_decode"] += int(tail[0]) * decode
+            st["moe_group_hits"] += int(tail[1]) if len(tail) > 1 else 0
             st["moe_assign_held"] += int(here.sum())
             st["moe_held_load_max"] += int(here.max())
         per_token = self.model.routed_per_token(self.cfg.model)

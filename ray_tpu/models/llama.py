@@ -570,6 +570,29 @@ def expert_load(idx_k, n_experts: int):
         0, dtype=jnp.int32)
 
 
+def held_load(load, held: tuple, n_experts: int, kept_groups=None):
+    """What a program hands back of one expert layer where this replica
+    holds a share ``held`` = (lo, hi) of the ``n_experts`` routed over:
+    ``load`` [E] and behind it the distinct HELD experts it reached — what
+    a step streams of this layer's expert weights, which skewed routing
+    makes fewer than the assignments would spread over (the engine's
+    ``moe_held_hit_decode``) — and, under group-limited selection
+    (``kept_groups`` [B, S, G] bool: the groups a token chose among), the
+    tokens whose groups include one with a held expert
+    (``moe_group_hits``). ``load`` as it is where every expert is held."""
+    lo, hi = held
+    if hi - lo == n_experts:
+        return load
+    tail = [(load[lo:hi] > 0).sum(dtype=jnp.int32)[None]]
+    if kept_groups is not None:
+        per = n_experts // kept_groups.shape[-1]
+        ours = np.arange(kept_groups.shape[-1])
+        ours = (ours * per < hi) & ((ours + 1) * per > lo)
+        tail.append((kept_groups & ours).any(axis=-1).sum(
+            dtype=jnp.int32)[None])
+    return jnp.concatenate([load, *tail])
+
+
 def routed_experts(h, idx_k, gate_k, p, n_experts: int, mlp_dim: int,
                    interpret: bool = False, held: Optional[tuple] = None):
     """sum_j gate_j * expert_{idx_j}(h) for h [B, S, D] and its [B, S, k]

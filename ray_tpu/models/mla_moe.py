@@ -14,9 +14,14 @@ second model module: it gives ``llm/paged_engine.py`` the same functions
     dense layer x = x + SwiGLU(n2(x); dense_mlp_dim)   (the first
                 n_dense_layers)
     MoE layer   z = n2(x);  s = sigmoid(z Wr) in float32
-                E = the top_k experts by (s + b);  w_e = routed_scale * s_e
+                E = the top_k experts by (s + b) — with n_group > 1 among
+                the experts of the topk_group best groups alone, a group
+                (E / n_group consecutive experts) scored by the sum of its
+                two largest (s + b);  w_e = routed_scale * s_e
                 / (sum_E s + 1e-20)              (weights from s WITHOUT b)
-                x = x + sum_E w_e SwiGLU_e(z) + SwiGLU_shared(z)
+                x = x + sum_E w_e SwiGLU_e(z) + SwiGLU_shared(z), the sum
+                over the chosen experts HELD here (``experts_held``: one
+                chip's share of a layer; None: all of them)
 
 What a token leaves in the cache is ``c ‖ k_r`` (rank + rope values, one
 "kv head" for all query heads), and the paged forwards read it in the
@@ -35,25 +40,29 @@ back in order. Reused, not copied, from models/llama.py: ``rms_norm``,
 the grouped expert FFN (``routed_experts`` over ``_expert_ffn`` and
 ops/grouped_matmul.py) and the per-expert load count.
 
+Its pieces also serve ``models/ling_hybrid.py``, whose latent layers are
+these behind a gate a head (``_attn_out``'s ``gate``) and whose expert
+layers are `_ffn_block` with grouped routing over a held share.
+
 Not built here, and refused by name where asked for: LoRA targets
 (``lora_targets`` is empty, so ``PagedEngineConfig.max_adapters`` > 0
-raises), a mesh (``check_mesh`` raises on ``PagedEngineConfig.mesh``),
-grouped routing (``n_group`` > 1) and a compressed query (``q_lora_rank``):
-the builder refuses those keys. Training wants a flash kernel with unequal
-key and value widths (ROADMAP R3); ``apply`` is plain jnp.
+raises), a mesh (``check_mesh`` raises on ``PagedEngineConfig.mesh``) and
+a compressed query (``q_lora_rank``): the builder refuses that key.
+Training wants a flash kernel with unequal key and value widths (ROADMAP
+R3); ``apply`` is plain jnp.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
 import math
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ..ops.flash_attention import _on_tpu
-from .llama import (_add_load, chunk_pages, expert_load, rms_norm,
+from .llama import (_add_load, chunk_pages, expert_load, held_load, rms_norm,
                     routed_experts)
 
 
@@ -74,6 +83,12 @@ class MlaMoeConfig:
     mlp_dim: int = 768                # ONE routed expert's width
     n_shared_experts: int = 2         # one SwiGLU of n_shared x mlp_dim
     routed_scale: float = 2.448
+    # group-limited selection: the experts in n_group groups of consecutive
+    # experts, a token's top_k taken inside its topk_group best groups
+    n_group: int = 1
+    topk_group: int = 1
+    # the experts this replica holds, [lo, hi) of moe_experts; None: all
+    experts_held: Optional[tuple] = None
     max_seq_len: int = 32768
     rope_theta: float = 1e6
     norm_eps: float = 1e-6
@@ -96,6 +111,10 @@ class MlaMoeConfig:
     @property
     def softmax_scale(self) -> float:
         return (self.qk_nope_dim + self.qk_rope_dim) ** -0.5
+
+    @property
+    def held(self) -> tuple:
+        return tuple(self.experts_held or (0, self.moe_experts))
 
 
 def mla_moe_tiny(**kw) -> MlaMoeConfig:
@@ -157,6 +176,7 @@ def init(rng: jax.Array, cfg: MlaMoeConfig) -> dict:
     ffn = ("w_gate", "w_up", "w_down")
     kd, km = jax.random.split(k_dense), jax.random.split(k_moe, 5)
     e = cfg.moe_experts
+    held = cfg.held[1] - cfg.held[0]
     return {
         "embed": dense(k_emb, (cfg.vocab_size, d), d),
         "dense_layers": {
@@ -168,7 +188,7 @@ def init(rng: jax.Array, cfg: MlaMoeConfig) -> dict:
             / math.sqrt(d),
             "router_bias": 0.1 * jax.random.normal(km[2], (n_moe, e),
                                                    jnp.float32),
-            **swiglu(km[3], (n_moe, e), cfg.mlp_dim, ffn),
+            **swiglu(km[3], (n_moe, held), cfg.mlp_dim, ffn),
             **swiglu(km[4], (n_moe,), f_sh,
                      ("ws_gate", "ws_up", "ws_down"))},
         "final_norm": ones(d),
@@ -214,16 +234,15 @@ def routed_per_token(cfg: MlaMoeConfig) -> int:
 
 def expert_routing(cfg: MlaMoeConfig) -> tuple[int, int, int]:
     """(routed experts a layer, experts a token goes to, experts held
-    here: all of them): what sizes the groups of the grouped expert
-    matmul; zeros where every layer is dense (llama.expert_routing's
-    twin)."""
-    return (cfg.moe_experts, cfg.moe_top_k, cfg.moe_experts) \
+    here): what sizes the groups of the grouped expert matmul; zeros where
+    every layer is dense (llama.expert_routing's twin)."""
+    return (cfg.moe_experts, cfg.moe_top_k, cfg.held[1] - cfg.held[0]) \
         if routed_per_token(cfg) else (0, 0, 0)
 
 
 def experts_held(cfg: MlaMoeConfig) -> tuple[int, int]:
     """[lo, hi) of the routed experts whose weights this replica has."""
-    return (0, cfg.moe_experts) if routed_per_token(cfg) else (0, 0)
+    return cfg.held if routed_per_token(cfg) else (0, 0)
 
 
 def attn_step(cfg: MlaMoeConfig, q_window: int, page_size: int,
@@ -312,10 +331,14 @@ def _absorbed(h, p, cfg: MlaMoeConfig, cos, sin):
     return lanes(q_lat, q_rope), lanes(c, k_rope)
 
 
-def _attn_out(o_lat, p, cfg: MlaMoeConfig):
-    """Attended latents [B, S, H, rank] -> the block's residual term."""
+def _attn_out(o_lat, p, cfg: MlaMoeConfig, gate=None):
+    """Attended latents [B, S, H, rank] -> the block's residual term.
+    ``gate`` [B, S, H] float32: a factor a head on the attention's output —
+    behind W_UV, which the absorbed form applies here, and ahead of Wo."""
     b, s = o_lat.shape[:2]
     o = jnp.einsum("bshc,hcv->bshv", o_lat.astype(cfg.dtype), p["w_uv"])
+    if gate is not None:
+        o = (o.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
     return o.reshape(b, s, -1) @ p["wo"]
 
 
@@ -323,32 +346,57 @@ def _swiglu(z, gate, up, down):
     return (jax.nn.silu(z @ gate) * (z @ up)) @ down
 
 
-def route(z, p, cfg: MlaMoeConfig):
-    """z [B, S, D] -> (weights [B, S, k] float32, experts [B, S, k]):
-    sigmoid scores in float32, the top_k by score + bias, weighted by the
-    scores alone, normalised over the selected and scaled."""
+def _select(z, p, cfg: MlaMoeConfig):
+    """`route`, and with it the groups a token's selection was limited to
+    ([B, S, n_group] bool; None without groups)."""
     logits = jnp.einsum("bsd,de->bse", z.astype(jnp.float32), p["w_router"],
                         precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(scores + p["router_bias"], cfg.moe_top_k)
+    biased, kept = scores + p["router_bias"], None
+    if cfg.n_group > 1:
+        groups = biased.reshape(biased.shape[:-1] + (cfg.n_group, -1))
+        # a group's two largest as two reductions: its maximum, and the
+        # maximum of the rest (a top_k of 2 is a whole sort on the TPU:
+        # 7% of the device's time at 8 groups of 64, PERF.md §6, PR 50)
+        first = groups.argmax(axis=-1, keepdims=True)
+        rest = jnp.where(jnp.arange(groups.shape[-1]) == first, -jnp.inf,
+                         groups)
+        best = groups.max(axis=-1) + rest.max(axis=-1)          # [B, S, G]
+        _, chosen = jax.lax.top_k(best, cfg.topk_group)
+        kept = (chosen[..., None] == jnp.arange(cfg.n_group)).any(axis=-2)
+        biased = jnp.where(kept[..., None], groups, -jnp.inf).reshape(
+            biased.shape)
+    _, idx = jax.lax.top_k(biased, cfg.moe_top_k)
     picked = jnp.take_along_axis(scores, idx, axis=-1)
     weights = cfg.routed_scale * picked / (
         picked.sum(axis=-1, keepdims=True) + 1e-20)
-    return weights, idx
+    return weights, idx, kept
+
+
+def route(z, p, cfg: MlaMoeConfig):
+    """z [B, S, D] -> (weights [B, S, k] float32, experts [B, S, k]):
+    sigmoid scores in float32, the top_k by score + bias — among the
+    experts of the ``topk_group`` best of ``n_group`` groups where the
+    config has groups, a group scored by the sum of its two largest
+    score + bias —, weighted by the scores alone, normalised over the
+    selected and scaled."""
+    return _select(z, p, cfg)[:2]
 
 
 def _ffn_block(x, p, cfg: MlaMoeConfig, interpret: bool):
     """The block's second half with its residual. Returns (x, load): the
-    [E] int32 assignment counts of an expert layer, None for a layer of
-    the dense prefix."""
+    [E] int32 assignment counts of an expert layer — behind them, where a
+    share of the experts is held, llama.held_load's counts —, None for a
+    layer of the dense prefix."""
     z = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
     if "w_router" not in p:
         return x + _swiglu(z, p["w_gate"], p["w_up"], p["w_down"]), None
-    weights, idx = route(z, p, cfg)
+    weights, idx, kept = _select(z, p, cfg)
     y = routed_experts(z, idx, weights, p, cfg.moe_experts, cfg.mlp_dim,
-                       interpret)
+                       interpret, held=cfg.experts_held)
     y = y + _swiglu(z, p["ws_gate"], p["ws_up"], p["ws_down"])
-    return x + y, expert_load(idx, cfg.moe_experts)
+    return x + y, held_load(expert_load(idx, cfg.moe_experts), cfg.held,
+                            cfg.moe_experts, kept)
 
 
 def _head(params, x, cfg: MlaMoeConfig):
@@ -361,6 +409,22 @@ def _head(params, x, cfg: MlaMoeConfig):
 # Full-sequence forward (expanded attention, plain jnp)
 # ---------------------------------------------------------------------------
 
+def _expanded_attention(h, p, cfg: MlaMoeConfig, cos, sin, causal):
+    """h [B, S, D] (normed) -> [B, S, H, v] float32: per-head keys
+    ``k_nope_h ‖ k_rope`` and values ``v_h`` expanded from the latents,
+    causal softmax in float32 (``causal`` [S, S] bool)."""
+    q_nope, q_rope, c, k_rope = _projections(h, p, cfg, cos, sin)
+    k_nope = jnp.einsum("bsc,hnc->bshn", c, p["w_uk"])
+    v = jnp.einsum("bsc,hcv->bshv", c, p["w_uv"])
+    scores = (jnp.einsum("bqhn,bkhn->bhqk", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqhr,bkr->bhqk", q_rope, k_rope,
+                           preferred_element_type=jnp.float32)
+              ) * cfg.softmax_scale
+    w = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhv->bqhv", w, v.astype(jnp.float32))
+
+
 def apply(params: dict, tokens: jax.Array, cfg: MlaMoeConfig) -> jax.Array:
     """tokens [B, S] -> logits [B, S, V] float32, no cache: per-head keys
     ``k_nope_h ‖ k_rope`` and values ``v_h`` expanded from the latents,
@@ -372,16 +436,7 @@ def apply(params: dict, tokens: jax.Array, cfg: MlaMoeConfig) -> jax.Array:
     for layer in range(cfg.n_layers):
         p = _layer_params(params, layer, cfg)
         h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
-        q_nope, q_rope, c, k_rope = _projections(h, p, cfg, cos, sin)
-        k_nope = jnp.einsum("bsc,hnc->bshn", c, p["w_uk"])
-        v = jnp.einsum("bsc,hcv->bshv", c, p["w_uv"])
-        scores = (jnp.einsum("bqhn,bkhn->bhqk", q_nope, k_nope,
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("bqhr,bkr->bhqk", q_rope, k_rope,
-                               preferred_element_type=jnp.float32)
-                  ) * cfg.softmax_scale
-        w = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
-        o = jnp.einsum("bhqk,bkhv->bqhv", w, v.astype(jnp.float32))
+        o = _expanded_attention(h, p, cfg, cos, sin, causal)
         x = x + o.astype(cfg.dtype).reshape(x.shape[:2] + (-1,)) @ p["wo"]
         x, _ = _ffn_block(x, p, cfg, False)
     return _head(params, x, cfg)

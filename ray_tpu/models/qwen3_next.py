@@ -1,10 +1,13 @@
 """Hybrid decoder of gated-delta-rule (GDN, linear attention) layers and
 gated softmax-attention layers, three to one, with a routed mixture of
 experts and a gated shared expert in every layer — the block
-``model_type: qwen3_next`` publishes (Qwen3-Next-80B-A3B). The serving
-engine's third model module: it gives ``llm/paged_engine.py`` the functions
-``models/llama.py`` and ``models/mla_moe.py`` do, over layers of TWO cache
-kinds: a full layer's keys and values live in pages, a GDN layer's
+``model_type: qwen3_next`` publishes (Qwen3-Next-80B-A3B). One of the
+serving engine's model modules: it gives ``llm/paged_engine.py`` the
+functions ``models/llama.py`` and ``models/mla_moe.py`` do, over layers of
+TWO cache kinds (``models/ling_hybrid.py``, whose recurrence has a decay a
+key channel and whose pages are latent, takes from here the state table's
+columns, the convolution and `state_rows`, the way a state travels through
+a prefill dispatch): a full layer's keys and values live in pages, a GDN layer's
 recurrent state — a float32 ``[nv, dk, dv]`` matrix and the last
 ``conv_width - 1`` inputs of its convolution — in the sequence's decode
 slot (``llm/kv_cache.py`` ``StateSlots``).
@@ -51,7 +54,7 @@ from ..ops.flash_attention import _on_tpu
 from ..ops.gated_delta import (gated_delta_decode, gated_delta_prefill,
                                gated_delta_scan)
 from .llama import (_add_load, _window_attend, chunk_pages, expert_load,
-                    routed_experts)
+                    held_load, routed_experts)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -332,19 +335,24 @@ def _conv(ext, w):
     return jax.nn.silu(acc).astype(ext.dtype)
 
 
-def _gdn_qkv(x, cfg: Qwen3NextConfig):
-    """The convolution's output [B, S, conv_dim] -> q, k [B, S, nk, dk]
-    (L2-normalised, q scaled) and v [B, S, nv, dv]."""
+def delta_qkv(x, nk: int, dk: int, nv: int, dv: int):
+    """The convolution's output [B, S, 2 nk dk + nv dv] -> q, k [B, S, nk,
+    dk] (L2-normalised, q scaled) and v [B, S, nv, dv]: what a delta-rule
+    layer hands its recurrence (models/ling_hybrid.py's too)."""
     b, s, _ = x.shape
-    nk, dk = cfg.gdn_k_heads, cfg.gdn_k_dim
 
     def l2(t):
         tf = t.astype(jnp.float32)
         return tf * jax.lax.rsqrt(jnp.sum(tf * tf, -1, keepdims=True) + 1e-6)
     q = l2(x[..., :nk * dk].reshape(b, s, nk, dk)) * dk ** -0.5
     k = l2(x[..., nk * dk:2 * nk * dk].reshape(b, s, nk, dk))
-    v = x[..., 2 * nk * dk:].reshape(b, s, cfg.gdn_v_heads, cfg.gdn_v_dim)
+    v = x[..., 2 * nk * dk:].reshape(b, s, nv, dv)
     return q.astype(x.dtype), k.astype(x.dtype), v
+
+
+def _gdn_qkv(x, cfg: Qwen3NextConfig):
+    return delta_qkv(x, cfg.gdn_k_heads, cfg.gdn_k_dim, cfg.gdn_v_heads,
+                     cfg.gdn_v_dim)
 
 
 def _gdn_out(o, z, p, cfg: Qwen3NextConfig):
@@ -374,10 +382,7 @@ def route(z, p, cfg: Qwen3NextConfig):
 def _moe_block(x, p, cfg: Qwen3NextConfig, interpret: bool):
     """The block's second half with its residual -> (x, load int32): the
     assignments each of the E experts routed over got, [E]; where a share
-    of them is held, [E + 1], the last the distinct HELD experts they
-    reached — what a step streams of this layer's expert weights, which
-    skewed routing makes fewer than the assignments would spread over
-    (the engine's ``moe_held_hit_decode``)."""
+    of them is held, [E + 1] (llama.held_load)."""
     z = norm(x, p["mlp_norm"], cfg.norm_eps)
     weights, idx = route(z, p, cfg)
     y = routed_experts(z, idx, weights, p, cfg.moe_experts, cfg.mlp_dim,
@@ -385,11 +390,8 @@ def _moe_block(x, p, cfg: Qwen3NextConfig, interpret: bool):
     shared = _swiglu(z, p["ws_gate"], p["ws_up"], p["ws_down"])
     sg = jax.nn.sigmoid((z @ p["w_sg"]).astype(jnp.float32))
     y = y + (sg * shared.astype(jnp.float32)).astype(cfg.dtype)
-    load = expert_load(idx, cfg.moe_experts)
-    lo, hi = cfg.held
-    if hi - lo < cfg.moe_experts:
-        hit = (load[lo:hi] > 0).sum(dtype=jnp.int32)
-        load = jnp.concatenate([load, hit[None]])
+    load = held_load(expert_load(idx, cfg.moe_experts), cfg.held,
+                     cfg.moe_experts)
     return x + y, load
 
 
@@ -544,20 +546,23 @@ def decode_paged(params: dict, tokens: jax.Array, caches: list[dict],
     return _head(params, x, cfg)[:, 0], new_caches, load
 
 
-def _gdn_prefill(h, p, cfg: Qwen3NextConfig, cache: dict, st, q_lens,
-                 interpret: bool):
-    """A GDN layer over R chunk-rows [R, C, D] that lie flat one after
-    another. ``st`` [R, 5] says for each row where its state comes from
-    (MODE: CONTINUE what row LOAD of ``S`` holds, FRESH zeros, RESUME
-    snapshot SNAP_FROM, CHAIN the state the row before it leaves: the next
-    chunk of the same prompt) and where its end state goes (row STORE of
-    ``S``, snapshot SNAP_TO; 0, the sink, for neither). The convolution's
-    tail crosses row bounds the same way. A row's q_lens real tokens alone
-    move its state. Returns (the residual term, the layer's cache)."""
-    r, c, _ = h.shape
-    width = cfg.conv_width
+def state_rows(qkv, conv_w, cache: dict, st, q_lens, recur):
+    """How a recurrent state travels through a prefill dispatch of R
+    chunk-rows that lie flat one after another, whatever the recurrence:
+    ``qkv`` [R, C, channels] is the convolution's input, ``st`` [R, 5]
+    says for each row where its state comes from (MODE: CONTINUE what row
+    LOAD of ``S`` holds, FRESH zeros, RESUME snapshot SNAP_FROM, CHAIN the
+    state the row before it leaves: the next chunk of the same prompt) and
+    where its end state goes (row STORE of ``S``, snapshot SNAP_TO; 0, the
+    sink, for neither). The convolution's tail crosses row bounds the same
+    way. ``recur(x, s0, chain, live)`` — the convolution's output [R, C,
+    channels], the rows' start states, which rows chain, which tokens are
+    real [R, C, 1] — returns (o, the states at the rows' ends): a row's
+    q_lens real tokens alone may move its state. Returns (o, the layer's
+    cache)."""
+    r, c, _ = qkv.shape
+    width = conv_w.shape[0]
     mode = st[:, MODE]
-    qkv, z, beta, g = _gdn_inputs(h, p, cfg)
 
     def start(pool, snaps):
         def rows(flag):
@@ -578,17 +583,29 @@ def _gdn_prefill(h, p, cfg: Qwen3NextConfig, cache: dict, st, q_lens,
         tails.append(tail)
         ends.append(prev)
     ext = jnp.concatenate([jnp.stack(tails), qkv], axis=1)
-    q, k, v = _gdn_qkv(_conv(ext, p["conv_w"]), cfg)
     live = (jnp.arange(c)[None, :] < q_lens[:, None])[..., None]
-    with jax.named_scope("gdn_prefill"):
-        o, s_end = gated_delta_prefill(
-            q, k, v, jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0),
-            s0, chain, interpret=interpret)
+    o, s_end = recur(_conv(ext, conv_w), s0, chain, live)
     ends = jnp.stack(ends)
     new = {"S": cache["S"].at[st[:, STORE]].set(s_end),
            "conv": cache["conv"].at[st[:, STORE]].set(ends),
            "snap_S": cache["snap_S"].at[st[:, SNAP_TO]].set(s_end),
            "snap_conv": cache["snap_conv"].at[st[:, SNAP_TO]].set(ends)}
+    return o, new
+
+
+def _gdn_prefill(h, p, cfg: Qwen3NextConfig, cache: dict, st, q_lens,
+                 interpret: bool):
+    """A GDN layer over R chunk-rows [R, C, D] (`state_rows`). Returns
+    (the residual term, the layer's cache)."""
+    qkv, z, beta, g = _gdn_inputs(h, p, cfg)
+
+    def recur(x, s0, chain, live):
+        q, k, v = _gdn_qkv(x, cfg)
+        with jax.named_scope("gdn_prefill"):
+            return gated_delta_prefill(
+                q, k, v, jnp.where(live, g, 0.0), jnp.where(live, beta, 0.0),
+                s0, chain, interpret=interpret)
+    o, new = state_rows(qkv, p["conv_w"], cache, st, q_lens, recur)
     return _gdn_out(o, z, p, cfg), new
 
 
@@ -601,7 +618,7 @@ def prefill_paged_rows(params: dict, chunks: jax.Array, caches: list[dict],
     may be consecutive chunks of one sequence; true_lens == 0 rows are
     padding; returns last_logits [R, V], caches, load) with ``bt_rows``
     the pair (the full layers' table [R, max_pages], the state table [R,
-    5] of `_gdn_prefill`)."""
+    5] of `state_rows`)."""
     _no_lora(lora)
     table, st = bt_rows
     r, c = chunks.shape

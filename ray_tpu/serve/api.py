@@ -9,6 +9,7 @@ non-ingress nodes are injected into their parents as DeploymentHandles
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Callable, Optional
 
 from .handle import DeploymentHandle
@@ -285,3 +286,14 @@ def shutdown() -> None:
         ray.kill(ctrl)
     except Exception:
         pass  # already dead
+    # the kill is registered a moment later: until then the name still
+    # resolves, and a `run` right behind this call would deploy on the
+    # dying controller (its call parks for good, or its replicas die with
+    # it: tests/test_serve_handle_router.py under a loaded machine)
+    deadline = time.monotonic() + 10.0
+    while time.monotonic() < deadline:
+        try:
+            ray.get_actor(CONTROLLER_NAME)
+        except ValueError:
+            break
+        time.sleep(0.01)

@@ -572,7 +572,7 @@ def test_mellum_programs_compile_at_the_cells_widths(v5e, monkeypatch,
 # ---------------------------------------------------------------------------
 
 _SERVING = ("mistral7b", "olmoe7b", "mellum2-12b", "kanana2-30b",
-            "qwen3next-80b")
+            "qwen3next-80b", "ling3flash-125b")
 
 
 def _serving_model(name, n_layers, experts=False):
@@ -594,6 +594,8 @@ def _serving_model(name, n_layers, experts=False):
     cut = {"n_layers": n_layers}
     if hasattr(cfg, "full_interval"):   # GDN layers, then one full layer
         cut["full_interval"] = n_layers
+    if hasattr(cfg, "mla_interval"):    # KDA layers, then one latent layer
+        cut["mla_interval"] = n_layers
     if hasattr(cfg, "layer_types"):
         if not experts:
             cut["moe_experts"] = 0
@@ -627,7 +629,7 @@ def _weight_copies(text, weights):
         """The weight whose parameter ``name`` is a moved copy of."""
         op, _, operands, ln = ops[name]
         if op == "parameter":
-            key = re.match(r"params__(?:dense_)?layers____(\w+?)__", name)
+            key = re.match(r"params__(?:[a-z]+_)?layers____(\w+?)__", name)
             return key and key[1] in weights and key[1]
         if not (op in _MOVES
                 or op == "fusion" and _MOVING_FUSION.match(name)
@@ -655,6 +657,7 @@ def _paged_program(v5e, monkeypatch, module, cfg, engine, family, rows,
     from ray_tpu.models import llama, mla_moe, qwen3_next
     from ray_tpu.ops import gated_delta
 
+    # (models/ling_hybrid.py runs mla_moe's and qwen3_next's pieces)
     one = SingleDeviceSharding(v5e.devices[0])
     page, max_pages = engine["page_size"], engine["max_pages_per_seq"]
     # mla_moe's and qwen3_next's experts are llama's
@@ -689,10 +692,11 @@ def _paged_program(v5e, monkeypatch, module, cfg, engine, family, rows,
     compiled = jax.jit(functools.partial(fn, cfg=cfg, page_size=page),
                        donate_argnums=(2,)).lower(params, *args).compile()
     weights = {k: a.shape[1:] for stack in (
-        "layers", "dense_layers", "full_layers", "gdn_layers")
+        "layers", "dense_layers", "full_layers", "gdn_layers", "mla_layers",
+        "kda_layers")
                for k, a in params.get(stack, {}).items()
                if k in ("wq", "wk", "wv", "wo", "wkv_a", "w_uk", "w_uv",
-                        "w_qkvz", "w_out")}
+                        "w_qkvz", "w_out", "w_f", "w_g")}
     assert {"wq", "wo"} <= set(weights)
     return compiled, weights
 
@@ -724,7 +728,7 @@ def test_no_paged_program_copies_a_projection_weight(v5e, monkeypatch, name,
 
 @pytest.mark.parametrize("name,rows", [
     ("olmoe7b", 8), ("mellum2-12b", 8), ("kanana2-30b", 16),
-    ("qwen3next-80b", 16)])
+    ("qwen3next-80b", 16), ("ling3flash-125b", 16)])
 def test_the_derived_top_rung_compiles_with_its_experts(v5e, monkeypatch,
                                                         name, rows):
     """`prefill_paged_rows` at the rows `derived_prefill_rows` gives the
