@@ -50,16 +50,21 @@ WINDOW_COUNTERS = (
 
 
 # What a model with recurrent-state layers adds to engine.stats: snapshots
-# filed, resumed, reclaimed and refused (a pool whose every snapshot is
-# pinned), prompt tokens a resumed snapshot covered / a request still ran,
-# full pages a hit found beyond the newest snapshot (re-run, not trusted),
-# the snapshots the pool held at each decode booking, and the pages of the
-# page pool beside it that some sequence held there (PERF.md §3)
+# filed, resumed, reclaimed (and of those the ones from the middle of a
+# prompt / from a prompt's end: state_evictions is their sum) and refused
+# (a pool whose every snapshot is pinned), prompt tokens a resumed snapshot
+# covered / a request still ran, full pages a hit found beyond the newest
+# snapshot (re-run, not trusted), admissions that would have resumed a
+# snapshot the pool had reclaimed (its ghost) and the prompt tokens that
+# one would have saved them, the snapshots the pool held at each decode
+# booking, and the pages of the page pool beside it that some sequence
+# held there (PERF.md §3)
 STATE_COUNTERS = (
     "state_snapshots_taken", "state_snapshots_refused",
     "state_snapshot_hits", "state_evictions", "state_hit_tokens",
     "state_rerun_tokens", "state_pages_untrusted", "state_pool_live",
-    "full_pool_live_pages")
+    "full_pool_live_pages", "state_ghost_hits", "state_ghost_hit_tokens",
+    "state_evictions_mid", "state_evictions_end")
 
 
 def window_need(cfg, model, prefill_rows: int) -> tuple:
@@ -83,9 +88,10 @@ class PageSpace:
     """One page-id space. Page 0 is its write sink (idle slots' dummy
     writes land there, never attended) and is never handed out. ``tiers``
     hold published pages nobody holds, insertion order = eviction order,
-    the first non-empty tier reclaimed first; ``lru`` is the last.
-    ``heard``: the observers of its index (published / forgot);
-    ``evictions``: the counter a reclaim grows."""
+    the first non-empty tier reclaimed first (or, where a kind hands the
+    space an ``order``, the first non-empty of the tiers that returns);
+    ``lru`` is the last. ``heard``: the observers of its index (published
+    / forgot); ``evictions``: the counter a reclaim grows."""
 
     def __init__(self, num_pages: int, stats: dict, evictions: str,
                  tiers: int = 1):
@@ -97,6 +103,7 @@ class PageSpace:
         self.tiers = [OrderedDict() for _ in range(tiers)]
         self.lru: "OrderedDict[int, None]" = self.tiers[-1]
         self.stats, self.evictions, self.heard = stats, evictions, ()
+        self.order = None
 
     def parked(self) -> int:
         return sum(map(len, self.tiers))
@@ -115,7 +122,8 @@ class PageSpace:
         if self.free:
             pid = self.free.pop()
         else:
-            pid, _ = next(t for t in self.tiers if t).popitem(last=False)
+            tiers = self.tiers if self.order is None else self.order()
+            pid, _ = next(t for t in tiers if t).popitem(last=False)
             self.forget(pid)
             self.stats[self.evictions] += 1
         self.refs[pid] = refs
@@ -477,22 +485,86 @@ class StateSlots(_Kind):
     dispatch leaves a prompt it has not finished (a long document asked
     again under another question resumes at most a dispatch short of what
     is shared), and nowhere in decode. A pool whose every snapshot is
-    pinned refuses the snapshot, never the request."""
+    pinned refuses the snapshot, never the request.
+
+    Which parked snapshot is reclaimed. A snapshot is of one of two KINDS
+    by where it was taken, ``END`` (at ``cut``) or ``MID`` (a dispatch left
+    the prompt unfinished there), parks in its kind's tier, LRU inside it,
+    and goes back to that tier's tail when a hit lets go of it. Which kind
+    requests come back for is the traffic's: sessions that resend their
+    history resume the previous turn's END and never a MID; a document
+    asked again under another question shares the document alone, so only
+    a MID can serve it, beside streams whose ENDs nobody extends. So the
+    order is learned from what admissions hit (``claim``): a resumed
+    snapshot scores its kind, and so does the deepest GHOST (``ghosts``:
+    the hash and kind of the last ``num_state_snapshots`` snapshots
+    reclaimed; no device memory) on the request's chain between the
+    newest snapshot and the end of its cached pages: the reclaimed
+    snapshot the request would have resumed. Nothing shallower scores.
+    The scores are held to a sum of the pool's size (both scaled down when
+    a new one passes it), so the old ones fade and a change of traffic is
+    followed. ``order``: the tier reclaimed from is the one with more
+    residents per unit of score, len(tier) / (score + 1). A tie goes to
+    MID, and with it the first reclaims of a pool nothing has scored in:
+    a lost MID has another of its prompt a dispatch before it, a lost END
+    re-runs a whole turn. So where only ENDs are ever hit, END's score
+    settles at the pool's size and every MID goes before any END (PR 47's
+    order); where only MIDs are, the dead ENDs go first; and where both
+    are, each kind holds the pool by what was hit."""
 
     name = "state"
     bucketed = False
     CONTINUE, FRESH, RESUME, CHAIN = range(4)
 
+    MID, END = 0, 1             # a snapshot's kind: its tier of ``space``
+
     def __init__(self, cfg, stats: dict):
-        # two tiers: a snapshot from the middle of a prompt is reclaimed
-        # before any from a prompt's end (a session's next turn needs that
-        # one; with one tier the ~2k-token snapshots of cold prompts push
-        # the ends out, every miss re-runs a history in more dispatches,
-        # and those file more: PERF.md §6, PR 47)
+        # a tier a kind. No fixed order serves both kinds of traffic: MID
+        # always first is right for sessions (with one LRU tier the
+        # ~2k-token snapshots of cold prompts push the ends out, every
+        # miss re-runs a history in more dispatches, and those file more:
+        # PERF.md §6, PR 47) and leaves a pool of dead ENDs under
+        # documents asked twice beside short streams (no second ask ever
+        # resumed: PERF.md §6, PR 51)
         super().__init__(cfg, stats, PageSpace(
             cfg.num_state_snapshots + 1, stats, "state_evictions",
             tiers=2), 1)
-        self.on = bool(cfg.enable_prefix_caching)
+        self.on, self.size = bool(cfg.enable_prefix_caching), \
+            cfg.num_state_snapshots
+        self.kind = np.zeros((self.space.num_pages,), np.int8)
+        self.score = [0.0, 0.0]
+        self.ghosts: "OrderedDict[bytes, int]" = OrderedDict()
+        self.space.order, self.space.heard = self.order, (self,)
+
+    def order(self) -> list:
+        """The snapshot pool's tiers, the one to reclaim from first."""
+        mid, end = self.space.tiers
+        if len(mid) * (self.score[self.END] + 1) >= \
+                len(end) * (self.score[self.MID] + 1):
+            return [mid, end]
+        return [end, mid]
+
+    def _scored(self, kind: int) -> None:
+        """An admission came back for a snapshot of ``kind``."""
+        self.score[kind] += 1
+        total = sum(self.score)
+        if total > self.size:
+            self.score = [s * self.size / total for s in self.score]
+
+    def published(self, sid: int, h: bytes, chain: int) -> None:
+        self.ghosts.pop(h, None)            # filed again: no ghost
+
+    def forgot(self, sid: int, h: bytes) -> None:
+        """The pool reclaimed a parked snapshot: its ghost stays."""
+        kind = int(self.kind[sid])
+        self.stats[("state_evictions_mid", "state_evictions_end")[kind]] += 1
+        self.ghosts[h] = kind
+        if len(self.ghosts) > self.size:
+            self.ghosts.popitem(last=False)
+
+    def _park(self, sid: int) -> None:
+        """Let go of a snapshot: into its own kind's tier, or free."""
+        self.space.unpin(sid, cold=self.kind[sid] == self.MID)
 
     def held(self, req: _Request) -> int:
         return 1 << 30          # a state covers any length
@@ -507,19 +579,30 @@ class StateSlots(_Kind):
             n -= 1
         return n
 
-    def claim(self, req: _Request, n_pages: int, hashes) -> None:
-        """req was admitted, resuming behind ``n_pages`` cached pages: its
-        slot's row, and the snapshot pinned until the row that loads it
-        is launched."""
+    def claim(self, req: _Request, n_pages: int, found: int, hashes) -> None:
+        """req was admitted, resuming behind ``n_pages`` of the ``found``
+        full pages cached for it: its slot's row, the snapshot pinned
+        until the row that loads it is launched, and the score of what it
+        came back for (the class says)."""
+        st = self.stats
         self.table[req.slot, 0] = req.slot + 1
         req.state_started = False
         if n_pages:
             req.state_snap = self.space.hash_to_page[hashes[n_pages - 1]]
             self.space.pin(req.state_snap)
-            self.stats["state_snapshot_hits"] += 1
+            st["state_snapshot_hits"] += 1
+            self._scored(int(self.kind[req.state_snap]))
+        st["state_pages_untrusted"] += found - n_pages
+        for at in range(found, n_pages, -1):
+            kind = self.ghosts.get(hashes[at - 1])
+            if kind is not None:
+                st["state_ghost_hits"] += 1
+                st["state_ghost_hit_tokens"] += (at - n_pages) * self.page
+                self._scored(kind)
+                break
         resumed = n_pages * self.page
-        self.stats["state_hit_tokens"] += resumed
-        self.stats["state_rerun_tokens"] += len(req.prompt_ids) - resumed
+        st["state_hit_tokens"] += resumed
+        st["state_rerun_tokens"] += len(req.prompt_ids) - resumed
 
     def cut(self, req: _Request) -> int:
         """Where req's prefill takes its snapshot: the end of its prompt's
@@ -548,7 +631,7 @@ class StateSlots(_Kind):
                 out[i, 1], out[i, 2] = self.RESUME, req.state_snap
                 # the program that reads it is launched: whoever takes
                 # the snapshot's id next writes it in a later one
-                self.space.unpin(req.state_snap)
+                self._park(req.state_snap)
                 req.state_snap = 0
             else:
                 out[i, 1] = self.FRESH
@@ -571,18 +654,19 @@ class StateSlots(_Kind):
     def publish(self, req: _Request, lo: int, hi: int, hashes) -> None:
         """The snapshots launched rows of req filed behind pages lo .. hi,
         now that those are booked: each under its last page's hash, then
-        parked."""
+        parked with its kind."""
         end = self.cut(req) // self.page
         for at in [a for a in req.state_taken if lo < a <= hi]:
             sid = req.state_taken.pop(at)
+            self.kind[sid] = self.END if at == end else self.MID
             self.space.publish(sid, hashes[at - 1])
-            self.space.unpin(sid, cold=at != end)
+            self._park(sid)
 
     def release(self, req: _Request) -> None:
         # pinned and never loaded / filed and never booked
         for sid in (req.state_snap, *req.state_taken.values()):
             if sid:
-                self.space.unpin(sid)
+                self._park(sid)
         req.state_snap = 0
         req.state_taken.clear()
 
@@ -737,8 +821,6 @@ class KVCache:
             st["prefix_tail_cut" if matched else "prefix_tail_lost"] += 1
             st["prefix_tail_tokens_lost"] += \
                 (found - len(matched)) * self.cfg.page_size
-        if self.state is not None:
-            st["state_pages_untrusted"] += found - len(matched)
         if chains is not None:
             hs = self.prompt_hashes(req)
             if hs:
@@ -752,7 +834,8 @@ class KVCache:
             if chains is not None:
                 chains.hit(req.chain_slot, len(matched), req.prefill_pos)
         if self.state is not None:
-            self.state.claim(req, len(matched), self.prompt_hashes(req))
+            self.state.claim(req, len(matched), found,
+                             self.prompt_hashes(req))
         return True
 
     def ensure(self, req: _Request, upto_tokens: int) -> bool:

@@ -63,6 +63,33 @@ def test_a_later_ask_resumes_a_snapshot_and_serves_what_a_cold_run_does(
     np.testing.assert_allclose(got[1], want, atol=LOGIT_TOL, rtol=0)
 
 
+def test_a_second_ask_resumes_behind_more_short_prompts_than_the_pool_holds(
+        model):
+    """The pool of 6 files 2 snapshots inside the document (a dispatch of
+    64 tokens each) and 9 prompt ends before the document comes again:
+    the ends nobody extends are what it gives up (the parent's order gave
+    up the two inside the document first, and re-ran it)."""
+    cfg, params, ref = model
+    eng = engine(cfg, params, prefill_rows=2)
+    doc = tokens(150, seed=17)
+    served(eng, doc + tokens(10, seed=18), 2)
+    for i in range(8):
+        served(eng, tokens(12 + i, seed=20 + i), 2)
+    ask = doc + tokens(12, seed=19)
+    got = served(eng, ask, 5)
+    st = eng.stats
+    # 2 + 1 of the first ask, 8 ends, the second ask's end: 6 too many
+    assert st["state_snapshots_taken"] == 12
+    assert st["state_evictions_end"] == 6 and st["state_evictions_mid"] == 0
+    assert st["state_snapshot_hits"] == 1 and st["state_hit_tokens"] == 128
+    cold = served(engine(cfg, params), ask, 5)
+    assert got[0] == cold[0]
+    np.testing.assert_allclose(got[1], cold[1], atol=LOGIT_TOL, rtol=0)
+    want, greedy = reference_greedy(ref, params, ask, got[0])
+    assert greedy
+    np.testing.assert_allclose(got[1], want, atol=LOGIT_TOL, rtol=0)
+
+
 def test_tables_of_128_rows(model):
     """``max_batch_size`` 128, twice any other cell's: the decode tables,
     129 slot rows a KDA layer, and more sequences at once than a dispatch
