@@ -230,6 +230,9 @@ def run(cell, a, t_process_start: float, log) -> dict:
                 "stats_after": w["closing"],
                 "engine_ttft": (baseline and baseline.get("ttft"),
                                 (w.get("after_flushed") or {}).get("ttft")),
+                "serve_summary": (
+                    baseline and baseline.get("serve_summary"),
+                    (w.get("after_flushed") or {}).get("serve_summary")),
                 "trace": w.get("trace"), "wall_offset": w["wall_offset"],
                 "device": device, "seconds": a.seconds,
                 "setup_s": window.start + w["wall_offset"] - t_process_start},

@@ -89,7 +89,12 @@ def main() -> int:
                 summary = serve.metrics_summary()
                 emit(event="stats", stats=call("engine_stats"),
                      device=call("device_info"),
-                     ttft=summary.get("ttft"), t=time.time())
+                     ttft=summary.get("ttft"),
+                     # the front path's own series, beside the engine's
+                     serve_summary={k: summary[k] for k in (
+                         "router_wait", "replica_latency", "handles",
+                         "requests") if k in summary},
+                     t=time.time())
             elif cmd == "trace_start":
                 emit(event="trace_start", **call("trace_start", msg["dir"]))
             elif cmd == "trace_stop":

@@ -1,5 +1,5 @@
 """Arithmetic shared by the readers of a decoder with two kinds of layer
-(``mixq_*``): the two ragged kernels told apart by name in the traced
+(``window_*`` / ``full_*``): the two ragged kernels told apart by name in the traced
 slice, and the engine's counters a pool kind (``ray_tpu/llm/paged_engine.py``
 ``stats``, the keys a model with sliding-window layers adds). A program
 without the kernel or the counters gives None."""

@@ -6,7 +6,9 @@ from .. import flops
 
 
 def read(ctx: dict):
-    m, cfg = ctx["train"], ctx["config"]
+    m, cfg = ctx.get("train"), ctx.get("config")
+    if not m or not cfg:
+        return None
     if ctx.get("rehearse"):
         return 0.0
     peak = flops.peaks(ctx["device"]["kind"])["bf16_flops"]

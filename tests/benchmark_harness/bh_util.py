@@ -1,6 +1,7 @@
 """Shared by the benchmark harness's tests: run ``benchmarks.run`` as the
 driver does, in a subprocess, from the repository or from a copy of the
 benchmark's own files."""
+import importlib
 import json
 import os
 import shutil
@@ -42,24 +43,52 @@ def add_pending(root: str, name: str) -> None:
         json.dump(bench, f)
 
 
+def declared_pairs(root: str = REPO, names=None) -> list:
+    """[(metric, cell)] of BENCHMARK.json's ``per_layer``, in its order: a
+    reader counts once a cell it serves. ``names`` narrows it to those
+    metrics."""
+    bench = load_json(root, "BENCHMARK.json")
+    return [(m["name"], cell) for m in bench["per_layer"]
+            if names is None or m["name"] in names
+            for cell in m["workloads"]]
+
+
+def cell_config(cell: str, root: str = REPO) -> dict:
+    """The configuration file of a cell of BENCHMARK.json, as it is run."""
+    bench = load_json(root, "BENCHMARK.json")
+    config = next(w["config"] for w in bench["workloads"]
+                  if w["name"] == cell)
+    return load_json(root, next(c["file"] for c in bench["configs"]
+                                if c["name"] == config))
+
+
+def read_metric(name: str, ctx: dict):
+    return importlib.import_module(
+        f"benchmarks.layer_metrics.{name}").read(ctx)
+
+
 FIFTH_CONFIG, FIFTH_CELL = "fifth-serve-1chip", "fifth-docqa-1chip"
-FIFTH_METRIC = "fifth_decode_step_ms"
+FIFTH_METRIC = "fifth_decode_launch_ms"
+# a reader that is there and serves the new cell too: its entry's list grows
+FIFTH_SHARED = "ragged_decode_roofline"
 
 
 def add_fifth_cell(root: str, also=("kanana-longdoc-sessions-1chip",
                                     "olmoe-gen-sessions-1chip")) -> None:
     """What the next configuration's PR does, on a copy: a fifth
     configuration (an existing file under a new name), a cell on it, its
-    listing under ``out_tok_s``, and one per-layer metric — a reader file
-    of its own — that lists the new cell and the existing cells ``also``.
-    Entries are appended and files added; none that is there is edited."""
+    listing under ``out_tok_s`` and under a reader that is there
+    (``FIFTH_SHARED``), and one per-layer metric — a reader file of its
+    own — that lists the new cell and the existing cells ``also``.
+    Entries and cells are appended and files added; no file that is there
+    is edited."""
     here = os.path.join(root, "benchmarks")
     shutil.copy(os.path.join(here, "configs", "mistral7b-serve-1chip.json"),
                 os.path.join(here, "configs", f"{FIFTH_CONFIG}.json"))
     with open(os.path.join(here, "layer_metrics",
                            f"{FIFTH_METRIC}.py"), "w") as f:
-        f.write('"""``decode_step_ms`` for the fifth cell."""\n'
-                "from .decode_step_ms import read  # noqa: F401\n")
+        f.write('"""``decode_launch_ms`` for the fifth cell."""\n'
+                "from .decode_launch_ms import read  # noqa: F401\n")
     path = os.path.join(root, "BENCHMARK.json")
     with open(path) as f:
         bench = json.load(f)
@@ -75,6 +104,8 @@ def add_fifth_cell(root: str, also=("kanana-longdoc-sessions-1chip",
                "appends"})
     next(m for m in bench["end_to_end"]
          if m["name"] == "out_tok_s")["workloads"].append(FIFTH_CELL)
+    next(m for m in bench["per_layer"]
+         if m["name"] == FIFTH_SHARED)["workloads"].append(FIFTH_CELL)
     bench["per_layer"].append({
         "name": FIFTH_METRIC, "unit": "ms", "better": "lower",
         "source": "program_counter", "layer": "engine scheduler",

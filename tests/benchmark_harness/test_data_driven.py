@@ -79,6 +79,10 @@ def test_benchmark_json_keeps_to_the_contract(bench_root):
                       "workloads", "end_to_end", "per_layer"}
     assert 1 <= b["run_seconds"] <= 51 and isinstance(b["run_seconds"], int)
     assert 2 <= len(b["workloads"]) <= 24
+    # the contract's caps: the benchmark reached 128 per-layer entries
+    # unnoticed once (PR 50), because nothing asserted this
+    assert 1 <= len(b["per_layer"]) <= 128
+    assert 1 <= len(b["end_to_end"]) <= 16 and len(b["configs"]) <= 24
     names = [x["name"] for k in ("configs", "workloads", "end_to_end",
                                  "per_layer") for x in b[k]]
     for n in names + [w["traffic"] for w in b["workloads"]] + \

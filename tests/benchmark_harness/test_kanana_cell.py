@@ -9,26 +9,27 @@ What is asserted of BENCHMARK.json's lists is asserted of PR 31's entries
 and of what stood before them, never of what a later PR appends after
 them: the structural test takes ``bench_root`` (``conftest.py``) and runs on
 the tree and on a copy with a fifth cell appended."""
-import importlib
 import json
 import os
 
 import pytest
-from bh_util import (LAST_LINE_KEYS, REPO, in_order, load_json, rehearse,
-                     stands_before)
+from bh_util import (LAST_LINE_KEYS, REPO, declared_pairs, load_json,
+                     read_metric, rehearse, stands_before)
 
 from benchmarks import flops_mla
 
 CELL = "kanana-longdoc-sessions-1chip"
 CONFIG = "kanana2-30b-serve-1chip"
+# PR 31's sixteen less ``decode_step_ms`` (retired by PR 52); since PR 52
+# under the readers' own names, each entry listing this cell among others
 LDOC = ["latent_attn_dev_share", "latent_decode_roofline",
         "latent_prefill_roofline", "moe_ffn_dev_share",
         "moe_load_max_over_mean", "decode_prog_dev_ms", "prefill_prog_dev_ms",
-        "decode_step_ms", "decode_slot_occupancy", "prefill_row_fill",
+        "decode_slot_occupancy", "prefill_row_fill",
         "prefix_hit_tok_share", "engine_host_share", "device_idle_share",
         "queue_wait_ms", "ttft_cold_ms", "ttft_warm_ms"]
 # what no trace is needed for: present (and null) in a rehearsal's line
-FROM_COUNTERS = {"moe_load_max_over_mean", "decode_step_ms",
+FROM_COUNTERS = {"moe_load_max_over_mean",
                  "decode_slot_occupancy", "prefill_row_fill",
                  "prefix_hit_tok_share", "engine_host_share",
                  "queue_wait_ms", "ttft_cold_ms", "ttft_warm_ms"}
@@ -39,28 +40,28 @@ WORKLOADS_BEFORE = ["docqa-sessions-1chip", "pretrain-4k-1chip",
                     "olmoe-gen-sessions-1chip"]
 OUT_TOK_S_BEFORE = ["docqa-sessions-1chip", "olmoe-gen-sessions-1chip"]
 # declared by PR 33, after the sixteen (its reader and counters are PR 32's)
-LATER = ["ldoc_prefill_masked_step_share"]
+LATER = ["prefill_masked_step_share"]
 
 
 def _json(*path):
     return load_json(REPO, *path)
 
 
-def _read(name: str, ctx: dict):
-    return importlib.import_module(
-        f"benchmarks.layer_metrics.ldoc_{name}").read(ctx)
+_read = read_metric
 
 
-def test_cell_rehearses_with_its_ldoc_metrics_present_and_null():
+def test_cell_rehearses_with_its_metrics_present_and_null():
     line = rehearse(CELL, trace=1)
     assert LAST_LINE_KEYS <= set(line)
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["device"]["platform"] == "cpu"
     # what the counters and the client give is there; what needs a device
     # trace finds nothing to read on the CPU and is left out
-    assert {f"ldoc_{n}" for n in FROM_COUNTERS} <= set(line["metrics"])
+    assert FROM_COUNTERS <= set(line["metrics"])
     assert all(m["value"] is None for m in line["metrics"].values())
-    assert all(n.startswith("ldoc_") for n in line["metrics"])
+    # and nothing that another cell's entry alone declares
+    assert set(line["metrics"]) <= {n for n, c in declared_pairs()
+                                    if c == CELL}
 
 
 def test_cell_config_and_mix_are_what_the_issue_names(bench_root):
@@ -79,13 +80,13 @@ def test_cell_config_and_mix_are_what_the_issue_names(bench_root):
     assert stands_before(e2e["out_tok_s"]["workloads"], CELL,
                          OUT_TOK_S_BEFORE)
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    sixteen = [f"ldoc_{n}" for n in LDOC]
-    assert len(sixteen) == 16
-    assert in_order(sixteen + LATER, [m["name"] for m in mine])
-    own = [m for m in mine if m["name"] in sixteen + LATER]
-    assert all(m["moves"] == "out_tok_s" and m["workloads"] == [CELL]
-               for m in own)
-    layers = {m["layer"] for m in bench["per_layer"] if m not in own}
+    assert len(LDOC) == 15
+    assert set(LDOC + LATER) <= {m["name"] for m in mine}
+    own = [m for m in mine if m["name"] in LDOC + LATER]
+    assert all(m["moves"] == "out_tok_s" for m in own)
+    assert "decode_step_ms" not in {m["name"] for m in bench["per_layer"]}
+    layers = {m["layer"] for m in bench["per_layer"]
+              if CELL not in m["workloads"]}
     assert {m["layer"] for m in own} <= layers   # no layer under a new name
     masked = next(m for m in own if m["name"] == LATER[0])
     assert (masked["layer"], masked["source"], masked["unit"],
@@ -259,18 +260,18 @@ def test_latent_readers_give_none_without_counters_or_kernel(name):
     assert _read(name, {"config": bare["config"]}) is None
 
 
-def test_twin_readers_are_the_readers_they_name():
-    named = {n: n for n in LDOC if n not in (
-        "latent_attn_dev_share", "latent_decode_roofline",
-        "latent_prefill_roofline", "moe_load_max_over_mean")}
-    named.update(ttft_cold_ms="docqa_ttft_cold_ms",
-                 ttft_warm_ms="docqa_ttft_warm_ms",
-                 moe_ffn_dev_share="gen_moe_ffn_dev_share")
-    assert len(named) == 12
-    for twin, base in named.items():
-        mod = importlib.import_module(f"benchmarks.layer_metrics.ldoc_{twin}")
-        assert mod.read is importlib.import_module(
-            f"benchmarks.layer_metrics.{base}").read
+def test_the_folded_readers_tell_this_configuration_by_its_file():
+    """``moe_load_max_over_mean`` serves four configurations: this one's
+    routed experts are ``n_routed_experts`` (the two shared experts are not
+    routed and not counted), and none of them is held apart."""
+    cfg = _json("benchmarks", "configs", f"{CONFIG}.json")
+    assert "num_experts" not in cfg and "experts_routed" not in cfg
+    stats = dict(moe_expert_load_max=300, moe_expert_load_sum=19_200,
+                 moe_held_load_max=1, moe_assign_held=1)
+    ctx = {"stats_before": dict.fromkeys(stats, 0), "stats_after": stats,
+           "config": cfg}
+    assert _read("moe_load_max_over_mean", ctx) == pytest.approx(
+        300 * cfg["n_routed_experts"] / 19_200) == pytest.approx(2.0)
 
 
 def test_routed_check_holds_the_share_within_the_margin_not_every_token():

@@ -1,35 +1,34 @@
-"""PR 38's fifteen per-layer metrics — a launch apart from a readback, the
+"""PR 38's seven per-layer readers — a launch apart from a readback, the
 stepping thread's waits for the interpreter, a token from its booking to the
 transport, the engine's run-ahead — each on a hand-made ``ctx`` with a known
 answer and None where the program has no such counter (every earlier
-commit); their entries in BENCHMARK.json, on the tree and on a copy with a
-fifth cell appended."""
-import importlib
-
+commit), once for every cell BENCHMARK.json declares the reader in (one
+entry a reader since PR 52: a folded reader still counts once a cell, under
+that cell's own configuration); their entries, on the tree and on a copy
+with a fifth cell appended."""
 import pytest
-from bh_util import in_order, load_json
+from bh_util import cell_config, declared_pairs, load_json, read_metric
 
-CELLS = {"docqa": "docqa-sessions-1chip", "gen": "olmoe-gen-sessions-1chip",
-         "ldoc": "kanana-longdoc-sessions-1chip"}
-# metric -> (layer, unit, better, the cells' prefixes), in the order of
-# the entries
+DOCQA, OLMOE = "docqa-sessions-1chip", "olmoe-gen-sessions-1chip"
+KANANA = "kanana-longdoc-sessions-1chip"
+# metric -> (layer, unit, better, the cells PR 38 declared it in: a later
+# PR appends cells to an entry's list, none leaves it)
 METRICS = {
     "prefill_launch_ms": ("engine scheduler", "ms", "lower",
-                          ("gen", "ldoc")),
-    "decode_launch_ms": ("engine scheduler", "ms", "lower", ("gen",)),
+                          (OLMOE, KANANA)),
+    "decode_launch_ms": ("engine scheduler", "ms", "lower", (OLMOE,)),
     "step_thread_offcpu_share": ("engine scheduler", "%", "lower",
-                                 ("docqa", "gen", "ldoc")),
+                                 (DOCQA, OLMOE, KANANA)),
     "stream_cpu_share": ("HTTP front and router", "%", "lower",
-                         ("gen", "ldoc")),
+                         (OLMOE, KANANA)),
     "stream_lag_ms": ("HTTP front and router", "ms", "lower",
-                      ("docqa", "gen")),
+                      (DOCQA, OLMOE)),
     "first_chunk_lag_ms": ("HTTP front and router", "ms", "lower",
-                           ("docqa", "gen")),
+                           (DOCQA, OLMOE)),
     "dispatch_overlap_share": ("engine scheduler", "%", "higher",
-                               ("docqa", "gen", "ldoc")),
+                               (DOCQA, OLMOE, KANANA)),
 }
-TWINS = [(name, prefix) for name, (*_, prefixes) in METRICS.items()
-         for prefix in prefixes]
+PAIRS = declared_pairs(names=METRICS)
 
 # A window of one second, wall ms: admit 4, prefill build 6 and post 2,
 # decode build 5 and post 3, telemetry 2, loop other 8: host phases 30;
@@ -38,7 +37,7 @@ TWINS = [(name, prefix) for name, (*_, prefixes) in METRICS.items()
 # phases, 6 in the launches, 2 at the end of the readbacks' waits. 4 prefill
 # dispatches, 10 decode and 2 verify dispatches, 8 of the 16 launched beside
 # another. 50 chunks lagged 400 ms together, 5 of them first chunks lagging
-# 60 ms; the stream threads took 250 ms of CPU.
+# 60 ms; the stream pump took 250 ms of CPU.
 MS = 1_000_000
 DELTA = {
     "ns_admit": 4 * MS, "ns_prefill_build": 6 * MS, "ns_prefill_post": 2 * MS,
@@ -73,40 +72,34 @@ EXPECTED = {
 }
 
 
-def _ctx(before=BEFORE, after=AFTER):
-    return {"stats_before": before, "stats_after": after, "trace": None,
-            "rehearse": False}
+def _ctx(before=BEFORE, after=AFTER, cell=None):
+    ctx = {"stats_before": before, "stats_after": after, "trace": None,
+           "rehearse": False}
+    if cell is not None:
+        ctx["config"] = cell_config(cell)
+    return ctx
 
 
-def _read(name: str, ctx: dict):
-    return importlib.import_module(
-        f"benchmarks.layer_metrics.{name}").read(ctx)
+_read = read_metric
 
 
-@pytest.mark.parametrize("name,prefix", TWINS)
-def test_reader_gives_the_hand_computed_value(name, prefix):
-    assert _read(f"{prefix}_{name}", _ctx()) == pytest.approx(
+@pytest.mark.parametrize("name,cell", PAIRS)
+def test_reader_gives_the_hand_computed_value(name, cell):
+    assert _read(name, _ctx(cell=cell)) == pytest.approx(
         EXPECTED[name], rel=1e-12)
 
 
-@pytest.mark.parametrize("name,prefix", TWINS)
-def test_reader_gives_none_without_its_counters(name, prefix):
+@pytest.mark.parametrize("name,cell", PAIRS)
+def test_reader_gives_none_without_its_counters(name, cell):
     """On the parent commit's ``engine.stats`` (the phases' wall time and
     the dispatch counts, PR 36's ``dispatches_overlapped`` left out), with
     a snapshot missing, and where nothing was counted."""
     theirs = ("step_thread_", "launch_", "stream_", "dispatches_overlapped")
     old = [{k: v for k, v in s.items() if not k.startswith(theirs)}
            for s in (BEFORE, AFTER)]
-    assert _read(f"{prefix}_{name}", _ctx(*old)) is None
-    assert _read(f"{prefix}_{name}", _ctx(None, None)) is None
-    assert _read(f"{prefix}_{name}", _ctx(BEFORE, BEFORE)) is None
-
-
-@pytest.mark.parametrize("name,prefix", TWINS)
-def test_twin_shares_the_reader_of_its_metric(name, prefix):
-    twin = importlib.import_module(f"benchmarks.layer_metrics.{prefix}_{name}")
-    base = importlib.import_module(f"benchmarks.layer_metrics.{name}")
-    assert twin.read is base.read
+    assert _read(name, _ctx(*old, cell=cell)) is None
+    assert _read(name, _ctx(None, None, cell=cell)) is None
+    assert _read(name, _ctx(BEFORE, BEFORE, cell=cell)) is None
 
 
 def test_overlap_share_reads_on_pr_36s_program():
@@ -126,30 +119,21 @@ def test_offcpu_share_leaves_the_readback_waits_out():
         pytest.approx(100.0 * 18 / 200)
 
 
-def test_the_fifteen_entries(bench_root):
-    """Appended, each with its cell, its reader file and the layer's name
-    as BENCHMARK.json already has it; nothing closed: later entries may
-    follow."""
+def test_the_seven_entries(bench_root):
+    """One entry a reader, with the layer's name as BENCHMARK.json already
+    has it, listing at least the cells PR 38 declared; nothing closed:
+    later cells and entries may follow."""
     bench = load_json(bench_root, "BENCHMARK.json")
     by_name = {m["name"]: m for m in bench["per_layer"]}
     layers = {m["layer"] for m in bench["per_layer"]
-              if m["name"] not in {f"{p}_{n}" for n, p in TWINS}}
+              if m["name"] not in METRICS}
     cells = {w["name"] for w in bench["workloads"]}
-    for name, (layer, unit, better, prefixes) in METRICS.items():
-        for prefix in prefixes:
-            m = by_name[f"{prefix}_{name}"]
-            # a later cell may be appended to its list
-            assert dict(m, workloads=m["workloads"][:1]) == {
-                "name": f"{prefix}_{name}", "unit": unit, "better": better,
-                "source": "program_counter", "layer": layer,
-                "moves": "out_tok_s", "workloads": [CELLS[prefix]]}
-            assert layer in layers and CELLS[prefix] in cells
-            importlib.import_module(
-                f"benchmarks.layer_metrics.{prefix}_{name}")
-    assert len(TWINS) == 15
-    assert in_order([f"{p}_{n}" for n, p in TWINS],
-                    [m["name"] for m in bench["per_layer"]])
-    # after everything PR 33 left: appended, not slipped in
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names.index("ldoc_prefill_masked_step_share") < \
-        names.index("gen_prefill_launch_ms")
+    for name, (layer, unit, better, declared) in METRICS.items():
+        m = by_name[name]
+        assert {k: m[k] for k in m if k != "workloads"} == {
+            "name": name, "unit": unit, "better": better,
+            "source": "program_counter", "layer": layer,
+            "moves": "out_tok_s"}
+        assert set(declared) <= set(m["workloads"]) <= cells
+        assert layer in layers
+    assert len(PAIRS) >= 15 and {n for n, _ in PAIRS} == set(METRICS)

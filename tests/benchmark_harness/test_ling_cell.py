@@ -9,7 +9,6 @@ commit; and each of the reference's four controls fails the check.
 
 What is asserted of BENCHMARK.json's lists is asserted of PR 50's entries
 and of what stood before them, never of what a later PR appends."""
-import importlib
 import json
 import os
 import subprocess
@@ -17,15 +16,41 @@ import sys
 import uuid
 
 import pytest
-from bh_util import LAST_LINE_KEYS, REPO, in_order, load_json
+from bh_util import (LAST_LINE_KEYS, REPO, declared_pairs, in_order, load_json,
+                     read_metric)
 
 from benchmarks import flops_kda
 
 CELL = "ling-longgen-mixed-1chip"
 CONFIG = "ling3flash-125b-serve-1chip"
+# PR 50's seven, under the readers' own names since PR 52
 LGEN = ["kda_dev_share", "kda_decode_roofline", "latent_decode_roofline",
         "moe_ffn_roofline", "moe_group_hit_share", "decode_prog_dev_ms",
         "device_idle_share"]
+# what waited for a slot until PR 52 made room: twenty of ISSUE 50's names
+# from readers that were there, the chunked kernel's roofline (new), and
+# the handle's share of the front overhead (new, every serving cell)
+WAITED = ["decode_slot_occupancy", "dispatch_overlap_share",
+          "engine_host_share", "front_overhead_ms", "latent_attn_dev_share",
+          "full_pool_live_share", "moe_ffn_dev_share",
+          "moe_held_assign_share", "moe_load_max_over_mean",
+          "prefill_tok_per_dispatch", "prefill_wait_us_per_tok",
+          "prefix_hit_tok_share", "queue_wait_ms",
+          "ragged_decode_sweep_fill", "state_hit_tok_share",
+          "state_pool_live_share", "state_snapshot_refused_share",
+          "stream_lag_ms", "ttft_short_ms", "ttft_long_ms",
+          "kda_prefill_roofline", "router_wait_ms"]
+# what no trace is needed for: present (and null) in a rehearsal's line
+FROM_COUNTERS = {"moe_group_hit_share", "decode_slot_occupancy",
+                 "dispatch_overlap_share", "engine_host_share",
+                 "front_overhead_ms", "full_pool_live_share",
+                 "moe_held_assign_share", "moe_load_max_over_mean",
+                 "prefill_tok_per_dispatch", "prefill_wait_us_per_tok",
+                 "prefix_hit_tok_share", "queue_wait_ms",
+                 "ragged_decode_sweep_fill", "state_hit_tok_share",
+                 "state_pool_live_share", "state_snapshot_refused_share",
+                 "stream_lag_ms", "ttft_short_ms", "ttft_long_ms",
+                 "router_wait_ms"}
 WORKLOADS_BEFORE = ["docqa-sessions-1chip", "pretrain-4k-1chip",
                     "olmoe-gen-sessions-1chip",
                     "kanana-longdoc-sessions-1chip",
@@ -35,9 +60,7 @@ MARK = "LING_CELL_TEST_RUN"
 CATALOG = "/opt/skills/guides/model-configs/architectures.jsonl"
 
 
-def _read(name: str, ctx: dict):
-    return importlib.import_module(
-        f"benchmarks.layer_metrics.lgen_{name}").read(ctx)
+_read = read_metric
 
 
 def _alive_with(mark: str) -> list:
@@ -57,8 +80,7 @@ def _alive_with(mark: str) -> list:
 
 def test_cell_rehearses_and_nothing_of_the_run_outlives_it():
     """The driver's command with ``--rehearse --trace 1`` and a seed over
-    2**31; the one ``lgen_*`` metric that needs no device is in its line,
-    null; the reference check's repeated document resumed a snapshot; the
+    2**31; the cell's metrics that need no device are in its line, null; the reference check's repeated document resumed a snapshot; the
     state check held the three KDA layers' snapshot to the reference's
     scan; and once it has returned nothing it started is alive."""
     mark = uuid.uuid4().hex
@@ -76,8 +98,11 @@ def test_cell_rehearses_and_nothing_of_the_run_outlives_it():
     assert LAST_LINE_KEYS <= set(line)
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["device"]["platform"] == "cpu"
-    assert set(line["metrics"]) == {"lgen_moe_group_hit_share"}
-    assert line["metrics"]["lgen_moe_group_hit_share"]["value"] is None
+    assert FROM_COUNTERS <= set(line["metrics"])
+    assert all(m["value"] is None for m in line["metrics"].values())
+    # and nothing that another cell's entry alone declares
+    assert set(line["metrics"]) <= {n for n, c in declared_pairs()
+                                    if c == CELL}
     window = next(json.loads(ln) for ln in proc.stdout.splitlines()
                   if ln.startswith('{"phase": "window"'))
     counters = window["counters_in_window"]
@@ -173,9 +198,10 @@ def test_cell_config_and_mix_are_what_the_issue_names(bench_root):
     assert mix["long"]["sessions"] * mix["stagger_s"] + 1 \
         == mix["warmup_s"] <= 45
     per_layer = {m["name"]: m for m in bench["per_layer"]}
-    for name in LGEN:
-        m = per_layer[f"lgen_{name}"]
-        assert m["workloads"] == [CELL] and m["moves"] == "out_tok_s"
+    assert len(WAITED) == 22 and not set(WAITED) & set(LGEN)
+    for name in LGEN + WAITED:
+        m = per_layer[name]
+        assert CELL in m["workloads"] and m["moves"] == "out_tok_s"
 
 
 @pytest.mark.skipif(not os.path.exists(CATALOG), reason="no catalog here")
@@ -254,13 +280,13 @@ def _ctx(**over):
 
 def test_a_reader_finds_nothing_on_a_program_without_its_source():
     """The parent commit has no such kernel or counter: no raise, no
-    number, from any of the seven (one case: the file's cases are kept
-    few so that it sorts to the end of the suite's queue)."""
+    number, from any of the cell's readers (one case: the file's cases are
+    kept few so that it sorts to the end of the suite's queue)."""
     empty = {"ns_admit": 0}
     trace = {"devices": 1, "window_s": 0.0, "busy_s": 0.0,
              "busy_s_worst": 0.0, "kernels": {}, "families": {}, "ops": [],
              "stats_before": empty, "stats_after": empty}
-    for name in LGEN:
+    for name in LGEN + WAITED:
         assert _read(name, _ctx()) is None, name
         assert _read(name, _ctx(trace=trace, stats_before=empty,
                                 stats_after=empty)) is None, name
@@ -307,6 +333,66 @@ def test_the_readers_by_hand():
     assert _read("moe_group_hit_share", ctx) == 50.0
     assert _read("decode_prog_dev_ms", ctx) == pytest.approx(15.0)
     assert _read("device_idle_share", ctx) == pytest.approx(20.0)
+
+
+def test_what_waited_for_a_slot_by_hand():
+    """The readers PR 52 declared for this cell, on this configuration's
+    file: the folded ones take the held experts' counters (64 of 512) and
+    the expert layers alone; the chunked kernel's roofline is new."""
+    cfg = load_json(REPO, "benchmarks", "configs", f"{CONFIG}.json")
+    zero = dict.fromkeys((
+        "prefill_tokens", "prefill_dispatches", "prefill_rows_live",
+        "decode_dispatches", "decode_live_slots", "moe_held_load_max",
+        "moe_assign_held", "moe_expert_load_max", "moe_expert_load_sum",
+        "full_pool_live_pages", "state_pool_live", "state_hit_tokens",
+        "state_rerun_tokens", "ns_prefill_device"), 0)
+    # a slice of 10 prefill dispatches of 16 live rows and 2,000 live
+    # tokens each; the window's counters beside it
+    after = dict(zero, prefill_tokens=20_000, prefill_dispatches=10,
+                 prefill_rows_live=160, decode_dispatches=100,
+                 decode_live_slots=100 * 120, moe_held_load_max=300,
+                 moe_assign_held=6_400, moe_expert_load_max=9_999,
+                 moe_expert_load_sum=51_200,
+                 full_pool_live_pages=100 * 49_151, state_pool_live=100 * 96,
+                 state_hit_tokens=3_000, state_rerun_tokens=9_000,
+                 ns_prefill_device=1_000_000_000)
+    trace = {"devices": 1, "window_s": 1.0, "busy_s": 0.8,
+             "busy_s_worst": 0.8, "ops": [], "families": {},
+             "kernels": {"kda_prefill": {"seconds": 0.03, "count": 60},
+                         "kda_decode": {"seconds": 0.24, "count": 240},
+                         "latent_attention": {"seconds": 0.08, "count": 50}},
+             "stats_before": zero, "stats_after": after}
+    ctx = _ctx(trace=trace, stats_before=zero, stats_after=after)
+    # a call: 2,000 tokens x (12,288 channels of q, k, v in bf16 + the
+    # decay read and the output written, 4,096 float32 each) + 16 rows'
+    # states in and out = 181.8 MB, 0.222 ms at the peak rate; its 18.9
+    # GFLOP need 0.096 ms: memory-bound. Against 0.5 ms a call
+    by = 2000 * (12288 * 2 + 2 * 4096 * 4) + 16 * 2 * 2097152
+    assert flops_kda.kda_prefill_bytes(cfg, 2000, 16) == by
+    assert flops_kda.kda_prefill_flops(cfg, 2000) / 197e12 < by / 819e9
+    assert _read("kda_prefill_roofline", ctx) == pytest.approx(
+        100 * (by / 819e9) / 0.0005)
+    assert 0 < _read("kda_prefill_roofline", ctx) < 100
+    # a slice without a prefill dispatch, or without the kernel: nothing
+    none = dict(after, prefill_tokens=0, prefill_dispatches=0,
+                prefill_rows_live=0)
+    assert _read("kda_prefill_roofline",
+                 _ctx(trace=dict(trace, stats_after=none))) is None
+    assert _read("kda_prefill_roofline", _ctx(trace=dict(trace, kernels={
+        "kda_prefill": {"seconds": 0.0, "count": 0}}))) is None
+    assert _read("kda_prefill_roofline", dict(ctx, rehearse=True)) is None
+    # the busiest HELD expert's 300 over the mean held expert's 6,400 / 64
+    assert _read("moe_load_max_over_mean", ctx) == pytest.approx(3.0)
+    assert _read("moe_held_assign_share", ctx) == pytest.approx(12.5)
+    assert _read("latent_attn_dev_share", ctx) == pytest.approx(10.0)
+    assert _read("full_pool_live_share", ctx) == pytest.approx(
+        100 * 49_151 / 98_303)
+    assert _read("state_pool_live_share", ctx) == pytest.approx(75.0)
+    assert _read("state_hit_tok_share", ctx) == pytest.approx(25.0)
+    assert _read("prefill_tok_per_dispatch", ctx) == pytest.approx(2000.0)
+    assert _read("prefill_wait_us_per_tok", ctx) == pytest.approx(50.0)
+    assert _read("decode_slot_occupancy", ctx) == pytest.approx(
+        100 * 120 / 128)
 
 
 def test_each_control_fails_the_reference_check():

@@ -1,13 +1,11 @@
-"""``ldoc_prefill_masked_step_share`` (PR 32) on hand-made counters, and
-None where the program has no ``prefill_key_steps`` (every earlier commit)
-or nothing was counted. The reader has no entry in BENCHMARK.json yet:
-``test_kanana_cell.py`` holds the cell's list of per-layer metrics
-closed, and only a ``benchmark`` PR may edit that file (PERF.md §7)."""
+"""``prefill_masked_step_share`` (PR 32; declared for the kanana cell since
+PR 33) on hand-made counters, and None where the program has no
+``prefill_key_steps`` (every earlier commit) or nothing was counted."""
 import importlib
 
 import pytest
 
-NAME = "ldoc_prefill_masked_step_share"
+NAME = "prefill_masked_step_share"
 # 61 prefill dispatches of four rows, each row four 32-row tiles of ~25
 # live 512-key steps, of which the last one or two carry the predicate
 BEFORE = {"prefill_dispatches": 10, "prefill_key_steps": 4_000,

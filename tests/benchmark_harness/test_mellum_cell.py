@@ -9,7 +9,6 @@ What is asserted of BENCHMARK.json's lists is asserted of PR 40's entries
 and of what stood before them, never of what a later PR appends after
 them: the structural test takes ``bench_root`` (``conftest.py``) and runs on
 the tree and on a copy with a fifth cell appended."""
-import importlib
 import json
 import os
 import subprocess
@@ -18,13 +17,15 @@ import time
 import uuid
 
 import pytest
-from bh_util import (LAST_LINE_KEYS, REPO, in_order, load_json,
-                     stands_before)
+from bh_util import (LAST_LINE_KEYS, REPO, declared_pairs, load_json,
+                     read_metric, stands_before)
 
 from benchmarks import flops_window
 
 CELL = "mellum-mixed-queue-1chip"
 CONFIG = "mellum2-12b-serve-1chip"
+# PR 40's twenty; since PR 52 under the readers' own names, each entry
+# listing this cell among others
 MIXQ = ["window_attn_dev_share", "full_attn_dev_share",
         "window_decode_roofline", "full_decode_roofline",
         "window_prefill_roofline", "window_pages_returned_share",
@@ -49,9 +50,7 @@ OUT_TOK_S_BEFORE = ["docqa-sessions-1chip", "olmoe-gen-sessions-1chip",
 TAG = "MELLUM_CELL_TEST_RUN"
 
 
-def _read(name: str, ctx: dict):
-    return importlib.import_module(
-        f"benchmarks.layer_metrics.mixq_{name}").read(ctx)
+_read = read_metric
 
 
 def _alive_with(tag: str) -> list:
@@ -73,7 +72,7 @@ def _alive_with(tag: str) -> list:
 
 
 def test_cell_rehearses_and_nothing_of_the_run_outlives_it():
-    """The driver's command with ``--rehearse --trace 1``; the ``mixq_*``
+    """The driver's command with ``--rehearse --trace 1``; the cell's
     metrics that need no device are in its line, null; and once it has
     returned no ``ray_tpu.core.worker`` and no ``*_child`` it started is
     alive (what refused PR 35: a later run could be served by one)."""
@@ -91,9 +90,11 @@ def test_cell_rehearses_and_nothing_of_the_run_outlives_it():
     assert LAST_LINE_KEYS <= set(line)
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["device"]["platform"] == "cpu"
-    assert {f"mixq_{n}" for n in FROM_COUNTERS} <= set(line["metrics"])
+    assert FROM_COUNTERS <= set(line["metrics"])
     assert all(m["value"] is None for m in line["metrics"].values())
-    assert all(n.startswith("mixq_") for n in line["metrics"])
+    # and nothing that another cell's entry alone declares
+    assert set(line["metrics"]) <= {n for n, c in declared_pairs()
+                                    if c == CELL}
     # the window line's counters: both classes were served, pages went
     # back, and the second ask of a document hit through the window tail
     window = next(json.loads(ln) for ln in proc.stdout.splitlines()
@@ -128,13 +129,11 @@ def test_cell_config_and_mix_are_what_the_issue_names(bench_root):
                          OUT_TOK_S_BEFORE)
     assert "workloads" not in e2e["setup_s"]
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    twenty = [f"mixq_{n}" for n in MIXQ]
-    assert len(twenty) == 20
-    assert in_order(twenty, [m["name"] for m in mine])
-    own = [m for m in mine if m["name"] in twenty]
-    assert all(m["moves"] == "out_tok_s" and m["workloads"] == [CELL]
-               for m in own)
-    layers = {m["layer"] for m in bench["per_layer"] if m not in own}
+    assert len(MIXQ) == 20 and set(MIXQ) <= {m["name"] for m in mine}
+    own = [m for m in mine if m["name"] in MIXQ]
+    assert all(m["moves"] == "out_tok_s" for m in own)
+    layers = {m["layer"] for m in bench["per_layer"]
+              if CELL not in m["workloads"]}
     assert {m["layer"] for m in own} <= layers   # no layer under a new name
     assert all(m["unit"] == "%" and m["source"] == "device_trace"
                for m in own if m["name"].endswith("_roofline"))
@@ -305,14 +304,12 @@ def test_window_counts_by_hand():
     assert flops_window.live_pages(5000, 16, 1024) == 65
     assert flops_window.live_pages(5000, 16) == 313
     assert flops_window.live_pages(10, 16, 1024) == 1
-    # what the engine counts is what these functions count
-    from ray_tpu.llm.paged_engine import PagedInferenceEngine as Engine
-
-    class Fake:
-        window, cfg, _lengths = 1024, type("C", (), {"page_size": 16}), {
-            0: 5000, 1: 10, 2: 1023, 3: 1024}
-    assert Engine._live_window_pages(Fake, range(4)) == sum(
-        flops_window.live_pages(n, 16, 1024) for n in (5000, 10, 1023, 1024))
+    # what the engine counts (``decode_live_wpages``, at every decode's
+    # launch) is what these functions count
+    from ray_tpu.llm.kv_cache import WindowPages
+    lengths = {0: 5000, 1: 10, 2: 1023, 3: 1024}
+    assert WindowPages.live_pages(lengths, range(4), 16, 1024) == sum(
+        flops_window.live_pages(n, 16, 1024) for n in lengths.values())
 
 
 def _ctx(**over):

@@ -3,12 +3,12 @@ sizes (8 experts, top-2) against its own plain reference; the operations
 and bytes of an expert layer counted by hand; and the new readers on a
 synthetic ``ctx`` — each gives None on a program without the counters or
 the kernel, as every commit before this PR and every dense configuration."""
-import importlib
 import json
 import os
 
 import pytest
-from bh_util import LAST_LINE_KEYS, in_order, load_json, rehearse
+from bh_util import (LAST_LINE_KEYS, declared_pairs, load_json, read_metric,
+                     rehearse)
 
 from benchmarks import flops_moe
 
@@ -19,38 +19,39 @@ OLMOE = {"hidden_size": 2048, "intermediate_size": 1024, "num_experts": 64,
          "vocab_size": 50304}
 
 
-def _read(name: str, ctx: dict):
-    return importlib.import_module(
-        f"benchmarks.layer_metrics.{name}").read(ctx)
+_read = read_metric
 
 
-def test_cell_rehearses_with_its_gen_metrics_present_and_null():
+def test_cell_rehearses_with_its_metrics_present_and_null():
     line = rehearse(CELL, trace=1)
     assert LAST_LINE_KEYS <= set(line)
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["device"]["platform"] == "cpu"
     # what the counters alone give is there; what needs a device trace
     # finds nothing to read on the CPU and is left out
-    assert {"gen_moe_live_assign_share", "gen_moe_load_max_over_mean",
-            "gen_decode_step_ms", "gen_decode_slot_occupancy",
-            "gen_decode_tok_per_dispatch", "gen_engine_host_share",
-            "gen_prefix_hit_tok_share"} <= set(line["metrics"])
+    assert {"moe_live_assign_share", "moe_load_max_over_mean",
+            "decode_slot_occupancy", "decode_tok_per_dispatch",
+            "engine_host_share", "prefix_hit_tok_share",
+            "decode_dead_row_share"} <= set(line["metrics"])
     assert all(m["value"] is None for m in line["metrics"].values())
-    assert all(n.startswith("gen_") for n in line["metrics"])
+    # and nothing that another cell's entry alone declares
+    assert set(line["metrics"]) <= {n for n, c in declared_pairs()
+                                    if c == CELL}
 
 
-# PR 27's thirteen, in the order it appended them
+# PR 27's thirteen less ``decode_step_ms`` (retired by PR 52); since PR 52
+# under the readers' own names, each entry listing this cell among others
 GEN = ["moe_ffn_dev_share", "moe_ffn_roofline", "moe_live_assign_share",
-       "moe_load_max_over_mean", "decode_step_ms", "decode_prog_dev_ms",
+       "moe_load_max_over_mean", "decode_prog_dev_ms",
        "decode_slot_occupancy", "decode_tok_per_dispatch",
        "ragged_attn_dev_share", "ragged_decode_roofline",
        "engine_host_share", "device_idle_share", "prefix_hit_tok_share"]
 
 
 def test_cell_is_what_the_issue_names(bench_root):
-    """On the tree and on a copy with a fifth cell appended: the thirteen
-    are a subset, in order, of the per-layer metrics that list the cell,
-    and what is asserted of a metric is asserted of them."""
+    """On the tree and on a copy with a fifth cell appended: the twelve
+    are among the per-layer metrics that list the cell, and what is
+    asserted of a metric is asserted of them."""
     bench = load_json(bench_root, "BENCHMARK.json")
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
@@ -58,11 +59,8 @@ def test_cell_is_what_the_issue_names(bench_root):
     e2e = {m["name"]: m for m in bench["end_to_end"]}
     assert CELL in e2e["out_tok_s"]["workloads"]
     mine = [m for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    thirteen = [f"gen_{n}" for n in GEN]
-    assert len(thirteen) == 13
-    assert in_order(thirteen, [m["name"] for m in mine])
-    assert all(m["moves"] == "out_tok_s" and m["workloads"] == [CELL]
-               for m in mine if m["name"] in thirteen)
+    assert len(GEN) == 12 and set(GEN) <= {m["name"] for m in mine}
+    assert all(m["moves"] == "out_tok_s" for m in mine if m["name"] in GEN)
     cfg = load_json(bench_root, "benchmarks", "configs",
                     "olmoe7b-serve-1chip.json")
     # every width as published; only the depth is cut
@@ -125,21 +123,27 @@ def test_moe_readers_on_a_synthetic_ctx():
                ["grouped_swiglu:bf16[3008,1024]", 0.20, 100, "tpu_custom_call"],
                ["grouped_matmul:bf16[3008,2048]", 0.10, 100, "tpu_custom_call"],
                ["fusion:bf16[64,2048]", 0.30, 4000, ""]])}
-    assert _read("gen_moe_live_assign_share", ctx) == pytest.approx(90.0)
+    assert _read("moe_live_assign_share", ctx) == pytest.approx(90.0)
     # (250 - 50) x 64 / 10,000: the busiest expert got 1.28 x the mean
-    assert _read("gen_moe_load_max_over_mean", ctx) == pytest.approx(1.28)
-    assert _read("gen_moe_ffn_dev_share", ctx) == pytest.approx(45.0)
+    assert _read("moe_load_max_over_mean", ctx) == pytest.approx(1.28)
+    assert _read("moe_ffn_dev_share", ctx) == pytest.approx(45.0)
     # decode shape = the calls with the fewest rows: 0.6 s in 400 layer
     # steps = 1.5 ms, where 805 MB + rows need 0.985 ms
     least = flops_moe.expert_ffn_bytes(OLMOE, 64) / 819e9
-    assert _read("gen_moe_ffn_roofline", ctx) == pytest.approx(
+    assert _read("moe_ffn_roofline", ctx) == pytest.approx(
         100 * least / 1.5e-3)
-    assert 60 < _read("gen_moe_ffn_roofline", ctx) < 70
+    assert 60 < _read("moe_ffn_roofline", ctx) < 70
+    # the same reader under a configuration whose key for ONE expert's
+    # width is ``moe_intermediate_size`` (Mellum's: ``intermediate_size``
+    # is a dense MLP its layers do not use): that key decides
+    other = dict(ctx, config=dict(ctx["config"], intermediate_size=7168,
+                                  moe_intermediate_size=1024))
+    assert _read("moe_ffn_roofline", other) == _read("moe_ffn_roofline", ctx)
 
 
 @pytest.mark.parametrize("name", [
-    "gen_moe_live_assign_share", "gen_moe_load_max_over_mean",
-    "gen_moe_ffn_dev_share", "gen_moe_ffn_roofline"])
+    "moe_live_assign_share", "moe_load_max_over_mean",
+    "moe_ffn_dev_share", "moe_ffn_roofline"])
 def test_moe_readers_give_none_without_counters_or_kernel(name):
     """The parent commit and every dense configuration: no ``moe_*`` keys
     in ``engine.stats``, no grouped kernel in the trace, no ``moe_ffn``
@@ -153,17 +157,6 @@ def test_moe_readers_give_none_without_counters_or_kernel(name):
     assert _read(name, dense) is None
     assert _read(name, dict(dense, trace=None)) is None
     assert _read(name, {"config": dense["config"]}) is None
-
-
-def test_twin_readers_are_the_readers_they_name():
-    for twin in ("decode_step_ms", "decode_prog_dev_ms",
-                 "decode_slot_occupancy", "decode_tok_per_dispatch",
-                 "ragged_attn_dev_share", "ragged_decode_roofline",
-                 "engine_host_share", "device_idle_share",
-                 "prefix_hit_tok_share"):
-        mod = importlib.import_module(f"benchmarks.layer_metrics.gen_{twin}")
-        base = importlib.import_module(f"benchmarks.layer_metrics.{twin}")
-        assert mod.read is base.read
 
 
 def test_wide_tokenizer_keeps_one_character_a_token():

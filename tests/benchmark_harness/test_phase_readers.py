@@ -1,12 +1,13 @@
-"""The nine per-layer metrics that read the engine's phase times and
-dispatch counters (PR 24), each on a hand-made ``ctx`` with a known answer,
-and None where the program has no such counter (every earlier commit) or
-the run was not traced. The two rooflines divide counters by a kernel's
-traced time: they take the counters over the traced slice (PR 33), the
-others over the window."""
-import importlib
-
+"""The eight per-layer metrics that read the engine's phase times and
+dispatch counters (PR 24; the ninth, ``decode_step_ms``, was retired by
+PR 52), each on a hand-made ``ctx`` with a known answer, and None where the
+program has no such counter (every earlier commit) or the run was not
+traced — once for every cell BENCHMARK.json declares the reader in, under
+that cell's own configuration. The two rooflines divide counters by a
+kernel's traced time: they take the counters over the traced slice (PR 33),
+the others over the window."""
 import pytest
+from bh_util import cell_config, declared_pairs, read_metric
 
 BEFORE = {"ns_admit": 1_000, "ns_prefill_build": 0, "ns_prefill_device": 0,
           "ns_decode_device": 5_000, "ns_telemetry": 0, "ns_loop_other": 0,
@@ -35,11 +36,9 @@ AFTER = {"ns_admit": 4_001_000, "ns_prefill_build": 6_000_000,
          "prefill_rows_padded": 44, "prefill_tokens": 3_900,
          "prefill_ctx_pages": 3_100, "prefill_attn_pairs": 6_010_000,
          "clock_ns": 1_000_000_005, "mesh": None}
-CONFIG = {"num_hidden_layers": 20, "num_key_value_heads": 8, "head_dim": 128,
-          "hidden_size": 4096, "num_attention_heads": 32,
-          "engine": {"max_batch_size": 32, "page_size": 16}}
+DOCQA = "docqa-sessions-1chip"
 # the decode shape (query window 1) ran 400 calls in 0.8 s: 2 ms a call, 40
-# ms a step of 20 layers; the prefill shape (a window above 1) ran 60 calls
+# ms a step of doc-QA's 20 layers; the prefill shape (a window above 1) ran 60 calls
 # in 0.3 s: 5 ms a call; each reader must leave the other's shape out
 # the slice's own counters (the snapshots ``trace_stop`` returns beside the
 # reduction): 10 decode dispatches with 24,000 live pages (2,400 a dispatch,
@@ -61,8 +60,9 @@ TRACE = {"devices": 1, "busy_s": 1.0, "window_s": 1.1, "ops": [
     "stats_before": SLICE_BEFORE, "stats_after": SLICE_AFTER}
 
 
-def _ctx(in_slice=(SLICE_BEFORE, SLICE_AFTER), **over):
-    ctx = {"stats_before": BEFORE, "stats_after": AFTER, "config": CONFIG,
+def _ctx(cell=DOCQA, in_slice=(SLICE_BEFORE, SLICE_AFTER), **over):
+    ctx = {"stats_before": BEFORE, "stats_after": AFTER,
+           "config": cell_config(cell),
            "trace": dict(TRACE, stats_before=in_slice[0],
                          stats_after=in_slice[1]),
            "device": {"kind": "TPU v5 lite"}, "rehearse": False}
@@ -70,21 +70,34 @@ def _ctx(in_slice=(SLICE_BEFORE, SLICE_AFTER), **over):
     return ctx
 
 
-def _read(name: str, ctx: dict):
-    return importlib.import_module(
-        f"benchmarks.layer_metrics.docqa_{name}").read(ctx)
+_read = read_metric
 
 
-# the slice's 2400 live pages a dispatch x 16 tokens x 81,920 B = 3.1457 GB,
-# 3.8410 ms at 819 GB/s, over 40 ms a step
-ROOFLINE = 100.0 * (2400 * 16 * 81_920 / 819e9) / 0.040
+def _head_dim(cfg: dict) -> int:
+    return cfg.get("head_dim") or cfg["hidden_size"] // cfg[
+        "num_attention_heads"]
 
-# a live row and layer of the slice: 4 x 32 x 128 x 250,000 pairs = 4.096
-# GFLOP = 20.79 us at 197 TFLOP/s; 100 pages x 16 x 4,096 B of keys and values
-# + 120 tokens x 32 x 128 x 2 B read and written = 8.5197 MB = 10.40 us at
-# 819 GB/s: the compute bound, over 5 ms a call x 8 / 6 rows run per live row
-PREFILL_ROOFLINE = 100.0 * (4 * 32 * 128 * 250_000 / 197e12) / (0.005 * 8 / 6)
 
+def roofline(cfg: dict) -> float:
+    """The slice's 2400 live pages a dispatch x 16 tokens x the keys and
+    values of every layer (doc-QA: 81,920 B a token = 3.1457 GB, 3.8410 ms
+    at 819 GB/s), over 2 ms a call x the layers a step (doc-QA: 40 ms)."""
+    layers = cfg["num_hidden_layers"]
+    kv = 2 * layers * cfg["num_key_value_heads"] * _head_dim(cfg) * 2
+    return 100.0 * (2400 * 16 * kv / 819e9) / (0.002 * layers)
+
+
+def prefill_roofline(cfg: dict) -> float:
+    """A live row and layer of the slice: 4 x heads x head_dim x 250,000
+    pairs (doc-QA: 4.096 GFLOP = 20.79 us at 197 TFLOP/s; its 100 pages x 16
+    x 4,096 B of keys and values + 120 tokens x 32 x 128 x 2 B read and
+    written = 8.5197 MB = 10.40 us at 819 GB/s: the compute bound), over
+    5 ms a call x 8 / 6 rows run per live row."""
+    fl = 4 * cfg["num_attention_heads"] * _head_dim(cfg) * 250_000
+    return 100.0 * (fl / 197e12) / (0.005 * 8 / 6)
+
+
+# a number, or what it is under a cell's configuration
 EXPECTED = {
     # host: 4 + 6 + 2 + 8 = 20 ms of 100 ms worked; the idle 900 ms and
     # the max_ns_* keys stay out
@@ -92,37 +105,51 @@ EXPECTED = {
     "admit_ms_per_request": 1.0,
     "queue_wait_ms": 3.0,
     "prefill_span_ms": 50.0,
-    "decode_slot_occupancy": 25.0,      # 8 of 32 slots a dispatch
-    "ragged_decode_roofline": ROOFLINE,
+    # 8 slots a dispatch of the cell's ``max_batch_size``
+    "decode_slot_occupancy":
+        lambda cfg: 100.0 * 8 / cfg["engine"]["max_batch_size"],
+    "ragged_decode_roofline": roofline,
     "prefill_row_fill": 75.0,           # 30 of 40 rows
-    "decode_step_ms": 0.5,              # 50 ms in 100 device steps
-    "ragged_prefill_roofline": PREFILL_ROOFLINE,
+    "ragged_prefill_roofline": prefill_roofline,
 }
 TRACED = ("ragged_decode_roofline", "ragged_prefill_roofline")
+PAIRS = declared_pairs(names=EXPECTED)
 
 
-@pytest.mark.parametrize("name", sorted(EXPECTED))
-def test_reader_gives_the_hand_computed_value(name):
-    assert _read(name, _ctx()) == pytest.approx(EXPECTED[name], rel=1e-9)
-    assert 9.5 < ROOFLINE < 9.7
-    assert 0.31 < PREFILL_ROOFLINE < 0.32
+def _expected(name: str, cell: str) -> float:
+    want = EXPECTED[name]
+    return want(cell_config(cell)) if callable(want) else want
 
 
-@pytest.mark.parametrize("name", sorted(EXPECTED))
-def test_reader_gives_none_on_a_program_without_the_counters(name):
+def test_the_rooflines_of_the_docqa_cell_by_hand():
+    cfg = cell_config(DOCQA)
+    assert 2400 * 16 * 81_920 / 819e9 / 0.040 * 100 == pytest.approx(
+        roofline(cfg)) and 9.5 < roofline(cfg) < 9.7
+    assert 0.31 < prefill_roofline(cfg) < 0.32
+    assert _expected("decode_slot_occupancy", DOCQA) == 25.0
+
+
+@pytest.mark.parametrize("name,cell", PAIRS)
+def test_reader_gives_the_hand_computed_value(name, cell):
+    assert _read(name, _ctx(cell)) == pytest.approx(
+        _expected(name, cell), rel=1e-9)
+
+
+@pytest.mark.parametrize("name,cell", PAIRS)
+def test_reader_gives_none_on_a_program_without_the_counters(name, cell):
     """The parent commit's ``engine.stats`` has none of these keys: the
     reader returns None, it does not raise."""
     old = {"decode_dispatches": 10, "tokens_out": 7, "mesh": None}
     new = dict(old, decode_dispatches=30)
-    assert _read(name, _ctx((old, new), stats_before=old,
+    assert _read(name, _ctx(cell, (old, new), stats_before=old,
                             stats_after=new)) is None
-    assert _read(name, _ctx((None, None), stats_before=None,
+    assert _read(name, _ctx(cell, (None, None), stats_before=None,
                             stats_after=None)) is None
 
 
-@pytest.mark.parametrize("name", sorted(EXPECTED))
-def test_reader_gives_none_when_nothing_was_counted(name):
-    assert _read(name, _ctx((SLICE_BEFORE, SLICE_BEFORE),
+@pytest.mark.parametrize("name,cell", PAIRS)
+def test_reader_gives_none_when_nothing_was_counted(name, cell):
+    assert _read(name, _ctx(cell, (SLICE_BEFORE, SLICE_BEFORE),
                             stats_after=BEFORE)) is None
 
 
@@ -149,9 +176,10 @@ def test_roofline_needs_the_trace_and_its_own_shape(name):
 def test_the_others_read_counters_alone():
     """Over the window, whatever the slice's snapshots say."""
     for name in sorted(set(EXPECTED) - set(TRACED)):
-        assert _read(name, _ctx(trace=None)) == pytest.approx(EXPECTED[name])
-        assert _read(name, _ctx((AFTER, AFTER))) == pytest.approx(
-            EXPECTED[name])
+        want = _expected(name, DOCQA)
+        assert _read(name, _ctx(trace=None)) == pytest.approx(want)
+        assert _read(name, _ctx(in_slice=(AFTER, AFTER))) == pytest.approx(
+            want)
 
 
 def test_slice_deltas_are_the_traces_own_snapshots():
@@ -173,13 +201,6 @@ def test_prefill_roofline_takes_the_memory_bound_where_it_is_larger():
     after = dict(SLICE_AFTER, prefill_attn_pairs=SLICE_BEFORE[
         "prefill_attn_pairs"] + 6 * 20_000)
     least = (100 * 16 * 4096 + 2 * 120 * 32 * 128 * 2) / 819e9
-    assert _read("ragged_prefill_roofline", _ctx((SLICE_BEFORE, after))) == \
+    assert _read("ragged_prefill_roofline",
+                 _ctx(in_slice=(SLICE_BEFORE, after))) == \
         pytest.approx(100.0 * least / (0.005 * 8 / 6), rel=1e-9)
-
-
-def test_twins_share_one_reader():
-    for name in EXPECTED:
-        twin = importlib.import_module(
-            f"benchmarks.layer_metrics.docqa_{name}")
-        base = importlib.import_module(f"benchmarks.layer_metrics.{name}")
-        assert twin.read is base.read

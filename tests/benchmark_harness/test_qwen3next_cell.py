@@ -10,7 +10,6 @@ without the counters or the kernel, as the parent commit.
 
 What is asserted of BENCHMARK.json's lists is asserted of PR 47's entries
 and of what stood before them, never of what a later PR appends."""
-import importlib
 import json
 import os
 import signal
@@ -20,12 +19,15 @@ import time
 import uuid
 
 import pytest
-from bh_util import LAST_LINE_KEYS, REPO, in_order, load_json
+from bh_util import (LAST_LINE_KEYS, REPO, declared_pairs, in_order, load_json,
+                     read_metric)
 
 from benchmarks import flops_gdn
 
 CELL = "qwen3next-growing-sessions-1chip"
 CONFIG = "qwen3next-80b-serve-1chip"
+# PR 47's twenty-five; since PR 52 under the readers' own names, each entry
+# listing this cell among others
 GROW = ["gdn_dev_share", "gdn_decode_roofline",
         "full_attn_dev_share", "full_decode_roofline", "moe_ffn_dev_share",
         "moe_ffn_roofline", "moe_load_max_over_mean",
@@ -58,9 +60,7 @@ WORKLOADS_BEFORE = ["docqa-sessions-1chip", "pretrain-4k-1chip",
 MARK = "QWEN3NEXT_CELL_TEST_RUN"
 
 
-def _read(name: str, ctx: dict):
-    return importlib.import_module(
-        f"benchmarks.layer_metrics.grow_{name}").read(ctx)
+_read = read_metric
 
 
 def _alive_with(mark: str) -> list:
@@ -92,7 +92,7 @@ def _command(mark: str, trace: int, **extra):
 
 
 def test_cell_rehearses_and_nothing_of_the_run_outlives_it():
-    """The driver's command with ``--rehearse --trace 1``; the ``grow_*``
+    """The driver's command with ``--rehearse --trace 1``; the cell's
     metrics that need no device are in its line, null; later turns resumed
     from snapshots; and once it has returned nothing it started is alive."""
     mark = uuid.uuid4().hex
@@ -106,9 +106,11 @@ def test_cell_rehearses_and_nothing_of_the_run_outlives_it():
     assert LAST_LINE_KEYS <= set(line)
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0 and line["device"]["platform"] == "cpu"
-    assert {f"grow_{n}" for n in FROM_COUNTERS} <= set(line["metrics"])
+    assert FROM_COUNTERS <= set(line["metrics"])
     assert all(m["value"] is None for m in line["metrics"].values())
-    assert all(n.startswith("grow_") for n in line["metrics"])
+    # and nothing that another cell's entry alone declares
+    assert set(line["metrics"]) <= {n for n, c in declared_pairs()
+                                    if c == CELL}
     window = next(json.loads(ln) for ln in proc.stdout.splitlines()
                   if ln.startswith('{"phase": "window"'))
     counters = window["counters_in_window"]
@@ -275,8 +277,8 @@ def test_cell_config_and_mix_are_what_the_issue_names(bench_root):
     assert mix["request_timeout_s"] == 120 and mix["think_time_s"] == 0
     per_layer = {m["name"]: m for m in bench["per_layer"]}
     for name in GROW:
-        m = per_layer[f"grow_{name}"]
-        assert m["workloads"] == [CELL] and m["moves"] == "out_tok_s"
+        m = per_layer[name]
+        assert CELL in m["workloads"] and m["moves"] == "out_tok_s"
 
 
 def test_the_arithmetic_of_the_configuration_file():

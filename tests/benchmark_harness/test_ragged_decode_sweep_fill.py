@@ -1,15 +1,13 @@
 """PR 48's per-layer metric on recorded counters: the share of the pages a
 decode call's sweeps step through that hold a live sequence's tokens,
-``ragged_decode_sweep_fill`` and its three twins (``docqa_``, ``grow_``,
-``gen_``: one a serving cell whose decode runs the all-heads body). A value
-from two snapshots of the engine's counters; None on a snapshot without
-``decode_swept_pages`` (every commit before PR 48), with no snapshot at
-all, and over a window without a decode dispatch. The three entries close
-BENCHMARK.json's list."""
-import importlib
-
+``ragged_decode_sweep_fill``, once for every cell BENCHMARK.json declares
+it in (one entry a reader since PR 52; one a serving cell whose decode runs
+the all-heads body). A value from two snapshots of the engine's counters;
+None on a snapshot without ``decode_swept_pages`` (every commit before
+PR 48), with no snapshot at all, and over a window without a decode
+dispatch."""
 import pytest
-from bh_util import load_json
+from bh_util import cell_config, declared_pairs, load_json, read_metric
 
 # A window of 200 decode dispatches of doc-QA's shape at 256 keys (16
 # pages) a step: 8 live rows of 190 pages = 12 blocks, 192 pages swept,
@@ -22,65 +20,53 @@ DELTA = {"decode_dispatches": 200, "decode_live_pages": 200 * 8 * 190,
          "decode_swept_pages": 200 * 8 * 192}
 AFTER = dict(BEFORE, **{k: BEFORE[k] + v for k, v in DELTA.items()})
 EXPECTED = 100.0 * 190 / 192
-CELLS = {"docqa_ragged_decode_sweep_fill": "docqa-sessions-1chip",
-         "grow_ragged_decode_sweep_fill": "qwen3next-growing-sessions-1chip",
-         "gen_ragged_decode_sweep_fill": "olmoe-gen-sessions-1chip"}
-NAMES = ["ragged_decode_sweep_fill", *CELLS]
+NAME = "ragged_decode_sweep_fill"
+# the cells PR 48 declared it in, each with the decode roofline that says
+# whether the fill's fall was worth paying
+ROOFLINE = {"docqa-sessions-1chip": "ragged_decode_roofline",
+            "olmoe-gen-sessions-1chip": "ragged_decode_roofline",
+            "qwen3next-growing-sessions-1chip": "full_decode_roofline"}
+PAIRS = declared_pairs(names=(NAME,))
 
 
-def _ctx(before=BEFORE, after=AFTER):
+def _ctx(cell, before=BEFORE, after=AFTER):
     return {"stats_before": before, "stats_after": after, "trace": None,
-            "config": {"engine": {"max_batch_size": 32}}, "rehearse": False}
+            "config": cell_config(cell), "rehearse": False}
 
 
-def _module(name: str):
-    return importlib.import_module(f"benchmarks.layer_metrics.{name}")
-
-
-@pytest.mark.parametrize("name", NAMES)
-def test_reader_gives_the_hand_computed_value(name):
-    got = _module(name).read(_ctx())
+@pytest.mark.parametrize("name,cell", PAIRS)
+def test_reader_gives_the_hand_computed_value(name, cell):
+    got = read_metric(name, _ctx(cell))
     assert got == pytest.approx(EXPECTED, rel=1e-12)
     assert 0 < got <= 100
 
 
-@pytest.mark.parametrize("name", NAMES)
+@pytest.mark.parametrize("name,cell", PAIRS)
 @pytest.mark.parametrize("snapshot", [
     "without_decode_swept_pages", "without_decode_live_pages", "missing",
     "no_decode_in_the_window"])
-def test_reader_gives_none(name, snapshot):
+def test_reader_gives_none(name, cell, snapshot):
     if snapshot.startswith("without_"):
         gone = snapshot[len("without_"):]
-        ctx = _ctx(*({k: v for k, v in s.items() if k != gone}
-                     for s in (BEFORE, AFTER)))
+        ctx = _ctx(cell, *({k: v for k, v in s.items() if k != gone}
+                           for s in (BEFORE, AFTER)))
     elif snapshot == "missing":
-        ctx = _ctx(None, None)
+        ctx = _ctx(cell, None, None)
     else:
-        ctx = _ctx(BEFORE, dict(BEFORE))
-    assert _module(name).read(ctx) is None
+        ctx = _ctx(cell, BEFORE, dict(BEFORE))
+    assert read_metric(name, ctx) is None
 
 
-@pytest.mark.parametrize("name", list(CELLS))
-def test_a_twin_shares_the_readers_code(name):
-    assert _module(name).read is _module("ragged_decode_sweep_fill").read
-
-
-def test_the_three_entries_close_the_list(bench_root):
+def test_the_entry(bench_root):
     bench = load_json(bench_root, "BENCHMARK.json")
-    names = [m["name"] for m in bench["per_layer"]]
     by_name = {m["name"]: m for m in bench["per_layer"]}
-    for name, cell in CELLS.items():
-        assert by_name[name] == {
-            "name": name, "unit": "%", "better": "higher",
-            "source": "program_counter", "layer": "kernels",
-            "moves": "out_tok_s", "workloads": [cell]}
-    at = [names.index(n) for n in ("grow_prefill_wait_us_per_tok", *CELLS)]
-    assert at == list(range(at[0], at[0] + 4))
-    # each in a cell that reports the metric it moves, beside the decode
-    # roofline that says whether the fill's fall was worth paying
+    m = by_name[NAME]
+    assert {k: m[k] for k in m if k != "workloads"} == {
+        "name": NAME, "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "out_tok_s"}
     e2e = {m["name"]: m for m in bench["end_to_end"]}
-    assert set(CELLS.values()) <= set(e2e["out_tok_s"]["workloads"])
-    for name, cell in CELLS.items():
-        roofline = name.replace("sweep_fill", "roofline").replace(
-            "grow_ragged", "grow_full")
-        assert by_name[roofline]["workloads"] == [cell]
+    assert set(ROOFLINE) <= set(m["workloads"]) <= set(
+        e2e["out_tok_s"]["workloads"])
+    for cell, roofline in ROOFLINE.items():
+        assert cell in by_name[roofline]["workloads"]

@@ -85,8 +85,8 @@ def test_decode_prog_dev_ms_is_one_number_whatever_the_mixture(w1, w8):
     trace = xplane.reduce(_decode_slice(w1, w8), chips=1)
     fam = trace["families"]["decode"]
     assert fam["count"] == w1 + w8 and fam["steps"] == w1 + 8 * w8
-    for name in ("decode_prog_dev_ms", "docqa_decode_prog_dev_ms",
-                 "gen_decode_prog_dev_ms", "ldoc_decode_prog_dev_ms"):
+    # the reader of every serving cell, and the pending chat cells' twin
+    for name in ("decode_prog_dev_ms", "chat_decode_prog_dev_ms"):
         read = importlib.import_module(
             f"benchmarks.layer_metrics.{name}").read
         assert read({"trace": trace}) == pytest.approx(0.020, rel=1e-9)

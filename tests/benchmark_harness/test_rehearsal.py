@@ -19,9 +19,12 @@ def _check(line: dict, names: set) -> None:
 
 
 @pytest.mark.parametrize("workload,trace,names", [
-    ("docqa-sessions-1chip", 1, {"prefix_hit_tok_share",
-                                 "docqa_ttft_cold_ms",
-                                 "docqa_decode_tok_per_dispatch"}),
+    # ``router_wait_ms``: the front path's series came through the door
+    # (``serve_child``'s ``stats`` event -> ``ctx["serve_summary"]``) and
+    # their count covers the run's requests
+    ("docqa-sessions-1chip", 1, {"prefix_hit_tok_share", "ttft_cold_ms",
+                                 "decode_tok_per_dispatch",
+                                 "front_overhead_ms", "router_wait_ms"}),
     ("pretrain-4k-1chip", 0, {"train_tok_s", "setup_s"}),
 ])
 def test_cell_rehearses(workload, trace, names):
@@ -36,13 +39,12 @@ def test_existing_cell_rehearses_beside_an_appended_fifth(tmp_path):
     root = copy_benchmark(tmp_path)
     add_fifth_cell(root, also=("docqa-sessions-1chip",))
     line = rehearse("docqa-sessions-1chip", root=root, trace=1)
-    _check(line, {"prefix_hit_tok_share", "docqa_decode_step_ms",
+    _check(line, {"prefix_hit_tok_share", "decode_slot_occupancy",
                   FIFTH_METRIC})
     # a rehearsal has no device trace: a reader that needs the traced
     # slice finds nothing to read and is left out of a well-formed line
-    assert not {"docqa_ragged_decode_roofline",
-                "docqa_ragged_prefill_roofline",
-                "docqa_decode_prog_dev_ms"} & set(line["metrics"])
+    assert not {"ragged_decode_roofline", "ragged_prefill_roofline",
+                "decode_prog_dev_ms"} & set(line["metrics"])
 
 
 def test_pending_chat_cell_rehearses(tmp_path):
@@ -57,5 +59,5 @@ def test_tp4_cell_rehearses_on_four_host_devices(tmp_path):
     add_pending(root, "chat-open-1chip")
     add_pending(root, "chat-open-tp4")
     line = rehearse("chat-open-tp4", root=root, trace=1)
-    _check(line, {"mesh_reshard_bytes", "decode_tok_per_dispatch"})
+    _check(line, {"mesh_reshard_bytes", "chat_decode_tok_per_dispatch"})
     assert line["device"]["count"] >= 4
