@@ -91,6 +91,9 @@ class _Request:
     submit_wall: float = 0.0
     request_id: str = ""
     trace_ctx: Optional[tuple] = None
+    # (app, deployment) where the request came through a proxy of this
+    # host: whose front stages it is observed under (serve/metrics.py)
+    front: Optional[tuple] = None
     event: threading.Event = dataclasses.field(
         default_factory=threading.Event)
 

@@ -297,7 +297,7 @@ LAUNCHES = {
 # (_Request.token_ns).
 STREAM_COUNTERS = ("stream_chunks", "stream_lag_ns", "stream_first_chunks",
                    "stream_first_lag_ns", "stream_cpu_ns", "stream_passes",
-                   "stream_deferred")
+                   "stream_deferred", "stream_write_ns")
 
 
 @dataclasses.dataclass

@@ -21,6 +21,7 @@ import time as _time
 from typing import Optional
 
 from ..util.profiling import phase
+from . import telemetry
 from .engine import SamplingParams
 from .paged_engine import PHASES, PagedEngineConfig, PagedInferenceEngine
 
@@ -113,7 +114,10 @@ class TokenStream:
       ``sink.fail(exc)``; each returns False when the sink cannot take
       it now (the pump keeps the text and tries again on a later pass,
       with whatever has come since in the same chunk) and none may
-      block; a sink whose ``closed()`` is true is dropped. The serve
+      block; a sink whose ``closed()`` is true is dropped. A sink may
+      tell when the write that took its newest item began
+      (``wrote_ns``, perf_counter_ns): the front stage ``first_chunk``
+      ends there. The serve
       replica attaches a sink over the stream's ring
       (serve/controller.py ``_start_stream_channel``) and starts no
       thread;
@@ -444,7 +448,11 @@ class LLMServer:
           all the streams cost the one interpreter;
         - ``stream_passes``: passes in which at least one chunk was
           taken; ``stream_deferred``: puts a sink refused for want of
-          credit, the text kept for a later pass."""
+          credit, the text kept for a later pass;
+        - ``stream_write_ns``: the wall time of every ``sink.put`` (over
+          the ring: the serialisation, the seal and its wake-ups): the
+          part of ``stream_lag_ns`` that is the write itself, beside the
+          part that is the wait for a pass."""
         eng = self.engine
         st, launched = eng.stats, eng.launched
         streams: list[_Open] = []
@@ -507,10 +515,13 @@ class LLMServer:
                 s.text = eng.tokenizer.decode(ids)
         delta = s.text[s.sent:]
         if not s.closing and (delta or s.finish is not None):
+            t0 = _time.perf_counter_ns()
             took = sink.put({
                 "object": "text_completion.chunk", "model": self.model_id,
                 "choices": [{"text": delta, "index": 0,
                              "finish_reason": s.finish}]})
+            now = _time.perf_counter_ns()
+            eng.stats["stream_write_ns"] += now - t0
             if not took:
                 if sink.closed():
                     return True
@@ -519,14 +530,14 @@ class LLMServer:
                 eng.stats["stream_deferred"] += 1
                 return False
             if delta:
-                self._taken(s)
+                self._taken(s, now)
             s.sent = len(s.text)
             s.closing = s.finish is not None
         return s.closing and (sink.end() or sink.closed())
 
-    def _taken(self, s: _Open) -> None:
-        """A sink has taken a chunk that carried text."""
-        now = _time.perf_counter_ns()
+    def _taken(self, s: _Open, now: int) -> None:
+        """A sink has taken a chunk that carried text; its put returned
+        at `now`."""
         st = self.engine.stats
         st["stream_chunks"] += 1
         st["stream_lag_ns"] += now - s.booked
@@ -535,6 +546,11 @@ class LLMServer:
             st["stream_first_chunks"] += 1
             st["stream_first_lag_ns"] += now - s.req.first_token_ns
             s.req.first_chunk_ns = now
+            # the front stage ends where the ring's hop begins: at the
+            # write's stamp, so that a write lies in one stage alone
+            telemetry.on_first_chunk(s.req, (
+                getattr(s.sink, "wrote_ns", 0) or now)
+                - s.req.first_token_ns)
 
     def set_replica_handle(self, handle) -> None:
         """Controller-injected handle to THIS replica's actor: the value

@@ -15,7 +15,15 @@ other. When tracing is enabled each request also emits one
 ``llm.request`` span parented to whatever span submitted it (the serve
 replica's task span when the request came through Serve), so a proxy
 -> replica -> engine request renders as one stitched tree in
-``ray_tpu.timeline()``; under it, with the same ``trace_id`` and
+``ray_tpu.timeline()`` — through the OpenAI router too, whose call to
+the model deployment is made by a stream's drain thread: that thread
+runs in the request's context (serve/controller.py
+``_start_stream_channel``), so ``serve.proxy`` -> the router's task ->
+the model replica's task -> ``llm.request`` is one trace and
+``req.request_id`` is the id the proxy minted. The same context carries
+the proxy's arrival stamp, from which ``on_submit`` observes the front
+stage ``to_submit`` (serve/metrics.py, "the front path's clock"); under
+``llm.request``, with the same ``trace_id`` and
 ``request_id``, three children end to end: ``llm.queue`` (submit ->
 admit), ``llm.prefill`` (admit -> first token) and ``llm.decode`` (first
 token -> retire), with ``prompt_tokens``, ``prefix_tokens_saved`` and
@@ -76,6 +84,10 @@ Metric names (all prefixed ``rtpu_llm_``):
   stream_lag_seconds_total counter  booking of a chunk's newest token ->
       its sink has taken the chunk, summed; over
       stream_chunks_total: a chunk's delivery time inside the replica
+  stream_write_seconds_total counter  wall seconds the pump spent inside
+      its sinks' puts (over the ring: serialise, seal, wake the readers);
+      over stream_chunks_total: the part of a chunk's delivery time that
+      is the write itself
   stream_cpu_seconds_total counter  CPU seconds of the stream pump, the
       one thread that serves every open stream, detokenisation and the
       sinks' writes included; its rate is the share of one core, so of
@@ -135,20 +147,26 @@ def _gauge(name, desc):
     return cached_metric(Gauge, name, desc, tag_keys=("engine", "proc"))
 
 
-_proc_pid = None
 _proc_label = ""
 
 
 def _proc() -> str:
-    """host:pid, re-derived after fork so a worker never inherits the
-    parent's identity."""
-    global _proc_pid, _proc_label
-    pid = os.getpid()
-    if pid != _proc_pid:
+    """host:pid, made once a process: every engine step asks, and
+    ``os.getpid()`` is a system call (6 us under gVisor). A forked child
+    never inherits the parent's identity: ``_forget_proc`` runs in it."""
+    global _proc_label
+    if not _proc_label:
         import socket
-        _proc_pid = pid
-        _proc_label = f"{socket.gethostname()}:{pid}"
+        _proc_label = f"{socket.gethostname()}:{os.getpid()}"
     return _proc_label
+
+
+def _forget_proc() -> None:
+    global _proc_label
+    _proc_label = ""
+
+
+os.register_at_fork(after_in_child=_forget_proc)
 
 
 def _counter(name, desc, tag_keys=("engine",)):
@@ -197,10 +215,31 @@ def on_submit(engine, req) -> None:
         if tracing.tracing_enabled():
             req.trace_ctx = tracing.current_context() or \
                 (tracing.new_trace_id(), None)
-        from ..serve.context import get_request_context
-        req.request_id = get_request_context().request_id
+        from ..serve.context import get_request_context, local_ingress_ns
+        ctx = get_request_context()
+        req.request_id = ctx.request_id
+        ingress_ns = local_ingress_ns()
+        if ingress_ns and req.submit_t:
+            # front stage "to_submit" (serve/metrics.py): the proxy's
+            # arrival stamp -> the instant rtpu_llm_ttft_seconds starts
+            # at. THE way in, in one number, on one host's clock
+            from ..serve.metrics import observe_stage
+            req.front = (ctx.app_name, ctx.deployment)
+            observe_stage("to_submit",
+                          int(req.submit_t * 1e9) - ingress_ns, *req.front)
     except Exception:
         pass  # tracing/request context are optional
+
+
+@_never_raise
+def on_first_chunk(req, lag_ns: int) -> None:
+    """A streamed request's first chunk went to its sink `lag_ns` after
+    its first token's booking (serving's stream pump): the front stage
+    "first_chunk" of a request that came through a proxy of this host."""
+    front = getattr(req, "front", None)
+    if front:
+        from ..serve.metrics import observe_stage
+        observe_stage("first_chunk", lag_ns, *front)
 
 
 @_never_raise
@@ -428,6 +467,8 @@ _STAT_SECONDS = (
      None),
     ("stream_cpu_ns", "rtpu_llm_stream_cpu_seconds_total",
      "CPU seconds of the stream pump thread", None),
+    ("stream_write_ns", "rtpu_llm_stream_write_seconds_total",
+     "wall seconds of the stream pump inside its sinks' puts", None),
 )
 
 

@@ -28,10 +28,14 @@ class _RingSink:
     of them blocks: each returns False, with nothing written, while the
     ring has no credit. The stream is retired — stale slots swept, the
     replica's slot freed — by the write that ends it or the one that
-    finds the consumer's stop flag."""
+    finds the consumer's stop flag. A message goes out as ``(kind, item,
+    write_ns)``: perf_counter_ns() as its write began, which the reading
+    handle's clock — the same host's — takes the ring's lag from;
+    ``wrote_ns`` is the stamp of the newest message the ring took."""
 
     def __init__(self, writer, retire):
         self._writer, self._retire = writer, retire
+        self.wrote_ns = 0
 
     def put(self, item) -> bool:
         return self._write(("i", item), False)
@@ -50,7 +54,10 @@ class _RingSink:
         if self._retire is None:
             return False
         try:
-            took = self._writer.try_write(msg)
+            stamp = time.perf_counter_ns()
+            took = self._writer.try_write((*msg, stamp))
+            if took:
+                self.wrote_ns = stamp
             over = took and last
         except ChannelClosed:
             took, over = False, True    # the consumer cancelled
@@ -216,7 +223,20 @@ class ReplicaActor:
           here: a deployment with many open streams serves them all
           from one (llm/serving.py's stream pump);
         - any other generator, sync or async, is PULLED by a drain
-          thread of this stream's own.
+          thread of this stream's own, which runs in the REQUEST's
+          context: the ``context`` dict set again (``_invoke`` has reset
+          it by now) over a copy of this task's contextvars, taken while
+          the actor task's trace span is active. A handle the generator
+          calls from there — ``llm/openai_api.py`` ``_sse`` calls the
+          model deployment at its first ``next`` — forwards the proxy's
+          request id and arrival stamp and parents to this task's span.
+
+        Every message carries its write stamp (``_RingSink``). Where the
+        generator itself reads a handle's stream, and the request is a
+        proxied one of this host, the drain thread counts each item's
+        relay — the upstream read returned -> its own write's stamp,
+        where the next ring's hop begins — as the front stages
+        "first_relay" and "relay" (serve/metrics.py).
 
         Returns False when this replica can't share a store with the
         caller (own-store node) so the handle falls back to the poll
@@ -265,11 +285,22 @@ class ReplicaActor:
             gen.attach(_RingSink(writer, retire))
             return True
 
+        from . import metrics as sm
+        from .context import local_ingress_ns, set_request_context
+        from .handle import took
+        context = context or {}
+        staged = bool(local_ingress_ns(context))
+        app, dep = context.get("app_name", ""), context.get("deployment", "")
+        now_ns = time.perf_counter_ns
+
         def drain():
+            relays = relay_ns = 0
             try:
+                set_request_context(**context)
                 while True:
                     if writer.closed():
                         break  # consumer cancelled: stop pulling
+                    took.ns = 0
                     try:
                         if is_async:
                             item = _aio.run_coroutine_threadsafe(
@@ -277,21 +308,31 @@ class ReplicaActor:
                         else:
                             item = next(gen)
                     except (StopIteration, StopAsyncIteration):
-                        writer.write(("e", None))
+                        writer.write(("e", None, now_ns()))
                         break
                     except BaseException as e:  # noqa: BLE001 — shipped
-                        writer.write(("x", e))
+                        writer.write(("x", e, now_ns()))
                         break
-                    writer.write(("i", item))
+                    stamp = now_ns()
+                    writer.write(("i", item, stamp))
+                    if staged and took.ns:
+                        lag = stamp - took.ns
+                        relay_ns += lag
+                        relays += 1
+                        if relays == 1:
+                            sm.observe_stage("first_relay", lag, app, dep)
             except ChannelClosed:
                 pass  # consumer cancelled mid-write
             except Exception:
                 import traceback
                 traceback.print_exc()
             finally:
+                sm.add_chunks("relay", relay_ns, relays, app, dep)
                 retire()
 
-        threading.Thread(target=drain, daemon=True,
+        import contextvars
+        threading.Thread(target=contextvars.copy_context().run,
+                         args=(drain,), daemon=True,
                          name=f"serve-stream-chan-{sid}").start()
         return True
 

@@ -97,6 +97,43 @@ class DeploymentResponse:
         return gen()
 
 
+# What a response generator counts for its stream — items, and on the
+# ring transport each item's lag from its write stamp to the read — it
+# keeps in plain ints and adds to the series ONCE, when the stream settles,
+# is cancelled or fails (serve/metrics.py: rtpu_serve_stream_items_total,
+# rtpu_serve_chunk_*_total stage "hop"): a chunk's path takes no lock. A
+# generator that is dropped unsettled leaves its totals in `_orphans` — a
+# finalizer may run inside any lock, the metric registry's among them — and
+# the next stream of the process to open or settle adds them.
+_orphans: deque = deque()
+
+# `ns`: when the newest ring read on this thread returned. A replica's
+# drain thread zeroes it before it pulls its generator: not zero after the
+# pull means the item came off a handle's stream, then (controller.py
+# ``_start_stream_channel``, stages "first_relay" / "relay")
+took = threading.local()
+
+
+def _add_totals(*rows) -> None:
+    """Add to the series the totals — (tags, items, hop_ns, hops) — of
+    the streams given, which are over, and of whatever was orphaned."""
+    rows = list(rows)
+    while _orphans:
+        try:
+            rows.append(_orphans.popleft())
+        except IndexError:      # another thread took the last
+            break
+    try:
+        from . import metrics as sm
+        for tags, items, hop_ns, hops in rows:
+            if items:
+                sm.stream_items().inc(float(items), tags=tags)
+            sm.add_chunks("hop", hop_ns, hops, tags["app"],
+                          tags["deployment"])
+    except Exception:
+        pass  # telemetry must never fail a stream
+
+
 class ChannelResponseGenerator:
     """Iterator over a streaming response served by the STATIC DECODE
     PLAN: the replica seals the stream's items into a ring channel
@@ -115,7 +152,8 @@ class ChannelResponseGenerator:
     # ~30s of silence — still amortized-zero)
     _PROBE_IDLE_SLICES = 60
 
-    def __init__(self, replica, chan: dict, on_done, tags: dict):
+    def __init__(self, replica, chan: dict, on_done, tags: dict,
+                 staged: bool = False):
         from ..core import runtime as rt_mod
         from ..core.ids import ObjectID
         from ..dag.channel import RingReader
@@ -128,6 +166,12 @@ class ChannelResponseGenerator:
                                   int(chan["ring"]))
         self._tags = {**tags, "transport": "chan"}
         self._idle = 0
+        # the stream belongs to a proxied request of this host: its
+        # items' lags are front stages (serve/metrics.py)
+        self._staged = staged
+        self._items = self._hops = self._hop_ns = 0
+        #: perf_counter_ns() as the newest read returned
+        self.take_ns = 0
 
     def __iter__(self):
         return self
@@ -149,18 +193,25 @@ class ChannelResponseGenerator:
         if self._done:
             raise StopIteration
         try:
-            kind, payload = self._reader.read(on_idle=self._probe)
+            msg = self._reader.read(on_idle=self._probe)
         except ChannelClosed:
             self._reader.retire()
             self._settle()
             raise StopIteration from None
+        take = self.take_ns = took.ns = time.perf_counter_ns()
         self._idle = 0
+        # (kind, payload, write_ns); a writer of before the stamp sent two
+        kind, payload = msg[0], msg[1]
         if kind == "i":
-            try:
-                from . import metrics as sm
-                sm.stream_items().inc(1.0, tags=self._tags)
-            except Exception:
-                pass  # telemetry must never fail a stream
+            self._items += 1
+            if self._staged and len(msg) > 2:
+                lag = take - msg[2]
+                self._hop_ns += lag
+                self._hops += 1
+                if self._hops == 1:
+                    from . import metrics as sm
+                    sm.observe_stage("first_hop", lag, self._tags["app"],
+                                     self._tags["deployment"])
             return payload
         self._reader.retire()  # sweep the trailing ack ring (leak-free)
         self._settle()
@@ -168,14 +219,20 @@ class ChannelResponseGenerator:
             raise payload
         raise StopIteration
 
-    def _settle(self):
+    def _settle(self, finalizer: bool = False):
         if not self._done:
             self._done = True
+            row = (self._tags, self._items, self._hop_ns, self._hops)
+            if finalizer:
+                _orphans.append(row)
+            else:
+                _add_totals(row)
             if self._on_done:
                 self._on_done()
                 self._on_done = None
 
-    __del__ = _settle
+    def __del__(self):
+        self._settle(finalizer=True)
 
     def cancel(self):
         if self._done:
@@ -199,9 +256,13 @@ class DeploymentResponseGenerator:
         self._replica = replica
         self._sid = sid
         self._on_done = on_done
-        self._tags = {**(tags or {}), "transport": "poll"}
+        self._tags = {"app": "", "deployment": "", **(tags or {}),
+                      "transport": "poll"}
         self._buf: deque = deque()
         self._done = False
+        self._items = 0
+        #: perf_counter_ns() as the newest stream_next returned
+        self.take_ns = 0
 
     def __iter__(self):
         return self
@@ -211,28 +272,37 @@ class DeploymentResponseGenerator:
         while not self._buf:
             if self._done:
                 raise StopIteration
-            items, done = ray_tpu.get(
-                self._replica.stream_next.remote(self._sid))
+            try:
+                items, done = ray_tpu.get(
+                    self._replica.stream_next.remote(self._sid))
+            except BaseException:
+                self._settle()  # failed: the replica has dropped it
+                raise
+            self.take_ns = time.perf_counter_ns()
             try:
                 from . import metrics as sm
                 sm.stream_dispatches().inc(1.0, tags=self._tags)
-                if items:
-                    sm.stream_items().inc(float(len(items)),
-                                          tags=self._tags)
             except Exception:
                 pass  # telemetry must never fail a stream
+            self._items += len(items)
             self._buf.extend(items)
             if done:
                 self._settle()
         return self._buf.popleft()
 
-    def _settle(self):
+    def _settle(self, finalizer: bool = False):
         self._done = True
         if self._on_done:
+            row = (self._tags, self._items, 0, 0)
+            if finalizer:
+                _orphans.append(row)
+            else:
+                _add_totals(row)
             self._on_done()
             self._on_done = None
 
-    __del__ = _settle
+    def __del__(self):
+        self._settle(finalizer=True)
 
     def cancel(self):
         import ray_tpu
@@ -391,8 +461,9 @@ def _proc() -> str:
     process's gauges by (llm/telemetry.py has the same; importing it here
     would bring jax into the proxy)."""
     import os
-    import socket
-    return f"{socket.gethostname()}:{os.getpid()}"
+
+    from .context import host_name
+    return f"{host_name()}:{os.getpid()}"
 
 
 def _listen_loop_weak(router_ref, app: str, deployment: str):
@@ -603,26 +674,33 @@ class DeploymentHandle:
             with router.lock:
                 rs.inflight[idx] -= 1
 
-        request_id = ""
+        from .context import get_request_context, local_ingress_ns
+        ctx = get_request_context()
+        routed_ns = time.perf_counter_ns()
         try:
             from . import metrics as sm
-            from .context import get_request_context
-            request_id = get_request_context().request_id
             tags = {"app": self.app_name,
                     "deployment": self.deployment_name}
             sm.handle_requests().inc(1.0, tags=tags)
-            sm.router_wait().observe(time.perf_counter() - t0, tags=tags)
+            sm.router_wait().observe(routed_ns * 1e-9 - t0, tags=tags)
         except Exception:
             pass  # telemetry must never fail a request
 
+        # the request's id and arrival stamp ride every hop as the proxy
+        # set them; a call outside a request sends "" and 0
         context = {"app_name": self.app_name,
                    "deployment": self.deployment_name,
                    "multiplexed_model_id": self._model_id,
-                   "request_id": request_id}
+                   "request_id": ctx.request_id,
+                   "ingress_ns": ctx.ingress_ns,
+                   "ingress_host": ctx.ingress_host}
 
         if self._stream:
             import ray_tpu
             tags = {"app": self.app_name, "deployment": self.deployment_name}
+            # front stages are a proxied request's, on this host's clock
+            staged = bool(local_ingress_ns())
+            _add_totals()       # of streams dropped unsettled, if any
             chan = self._make_chan_spec()
             try:
                 resp = ray_tpu.get(replica.handle_request_streaming.remote(
@@ -641,9 +719,17 @@ class DeploymentHandle:
                 # static decode plan engaged: items arrive over the ring
                 # channel, no per-chunk actor calls
                 _fl.evt(_fl.SRV_STREAM_START, int(resp["chan"]), 1)
-                return ChannelResponseGenerator(replica, chan, done, tags)
-            _fl.evt(_fl.SRV_STREAM_START, int(resp), 0)
-            return DeploymentResponseGenerator(replica, resp, done, tags)
+                gen = ChannelResponseGenerator(replica, chan, done, tags,
+                                               staged)
+            else:
+                _fl.evt(_fl.SRV_STREAM_START, int(resp), 0)
+                gen = DeploymentResponseGenerator(replica, resp, done, tags)
+            if staged:
+                # the actor round trip that opens a stream, which
+                # router_wait leaves out
+                sm.observe_stage("open", time.perf_counter_ns() - routed_ns,
+                                 self.app_name, self.deployment_name)
+            return gen
 
         def retry():
             router.refresh(force=True)

@@ -33,7 +33,8 @@ Metric names and label sets:
   rtpu_serve_stream_dispatches_total{app,deployment,transport} counter
       (control-plane dispatches serving streams — the static decode
       plan's "dispatches per token -> ~0" headline reads from this)
-  rtpu_serve_stream_items_total{app,deployment,transport} counter
+  rtpu_serve_stream_items_total{app,deployment,transport} counter (added
+      once a stream, when it settles, is cancelled or fails)
   rtpu_serve_admission_admitted_total{app,deployment}     counter
   rtpu_serve_admission_shed_total{app,deployment,reason}  counter (shed
       429s by reason: queue_full | slo | deadline)
@@ -59,6 +60,50 @@ Metric names and label sets:
   rtpu_serve_prefix_directory_imported_pages_total{model} counter
   rtpu_serve_prefix_directory_publishes_total{model}      counter
   rtpu_serve_prefix_directory_stale_total{model}          counter
+
+The front path's clock — what a proxied request and its chunks wait for
+between the client and the engine, each interval taken where the work
+happens and on one clock: the proxy stamps ``perf_counter_ns()`` at
+``_dispatch``'s entry (``RequestContext.ingress_ns`` / ``ingress_host``,
+forwarded by every handle), a ring message carries its write stamp, and
+perf_counter is one clock for the processes of one host. A request whose
+stamp another host took, and a handle called outside a request (a
+driver's, a test's), record none of these:
+  rtpu_serve_front_stage_seconds{app,deployment,stage}    histogram, one
+      observation a request and stage; on a streamed request's way they
+      lie end to end (a ring write's own duration is in the hop that
+      follows it and nowhere else), so with the engine's TTFT between
+      to_submit and first_chunk they add up to what the client waited
+      for its first token, less the client's two socket stretches:
+        intake      proxy: _dispatch entry -> call() begins on its
+                    executor thread (route table, ingress resolve, body
+                    parse, admission, the wait for a thread)
+        open        the calling handle, tagged with the CALLED deployment:
+                    where router_wait ends -> the stream's generator in
+                    hand (the actor round trip that opens a stream)
+        to_submit   model replica (llm/telemetry.py on_submit): ingress
+                    -> the engine's submit stamp, where
+                    rtpu_llm_ttft_seconds starts
+        first_chunk model replica (llm/serving.py, the stream pump): the
+                    first token's booking, where rtpu_llm_ttft_seconds
+                    ends -> the stamp of the ring write that took the
+                    chunk with it (the wait for the pump's pass, the
+                    stream's turn in it, the detokenisation)
+        first_hop   the reading handle, tagged with the WRITING
+                    deployment: a stream's first ring message, its write
+                    stamp -> read() returned
+        first_relay a replica's drain thread whose generator consumes a
+                    handle's stream: upstream take -> its own ring
+                    write's stamp, first item
+        first_write proxy: the first chunk's take -> stream.write returned
+  rtpu_serve_chunk_seconds_total{app,deployment,stage}    counter
+  rtpu_serve_chunk_events_total{app,deployment,stage}     counter — the
+      intervals of first_hop / first_relay / first_write for EVERY item
+      (stage: hop | relay | write), summed in plain ints on the stream's
+      object and added once, when the stream settles, is cancelled or fails
+  rtpu_serve_proxy_loop_lag_seconds{proxy}                histogram (a
+      task on the proxy's event loop sleeps 100 ms and observes how late
+      it woke: what every await of the proxy waits behind)
 
 ``metrics_summary()`` condenses the merged store into finite p50/p95/p99
 latencies (TTFT, e2e, replica) plus the headline gauges/counters — the
@@ -164,6 +209,58 @@ def stream_items() -> Counter:
     return _metric(Counter, "rtpu_serve_stream_items_total",
                    "items delivered by streaming responses, by transport",
                    tag_keys=("app", "deployment", "transport"))
+
+
+# -- the front path's clock (module docstring) ------------------------ #
+
+def front_stage() -> Histogram:
+    return _metric(Histogram, "rtpu_serve_front_stage_seconds",
+                   "a proxied request's wait by stage of the front path: "
+                   "intake | open | to_submit | first_chunk | first_hop "
+                   "| first_relay | first_write", boundaries=_LAT,
+                   tag_keys=("app", "deployment", "stage"))
+
+
+def chunk_seconds() -> Counter:
+    return _metric(Counter, "rtpu_serve_chunk_seconds_total",
+                   "every streamed item's wait by stage (hop | relay | "
+                   "write), summed; added once a stream",
+                   tag_keys=("app", "deployment", "stage"))
+
+
+def chunk_events() -> Counter:
+    return _metric(Counter, "rtpu_serve_chunk_events_total",
+                   "the items rtpu_serve_chunk_seconds_total sums over",
+                   tag_keys=("app", "deployment", "stage"))
+
+
+def proxy_loop_lag() -> Histogram:
+    return _metric(Histogram, "rtpu_serve_proxy_loop_lag_seconds",
+                   "how late a 100 ms sleep on the proxy's event loop "
+                   "woke", boundaries=_LAT, tag_keys=("proxy",))
+
+
+def observe_stage(stage: str, ns: int, app: str, deployment: str) -> None:
+    """One request's `ns` nanoseconds in a stage. Never raises."""
+    try:
+        front_stage().observe(max(ns, 0) * 1e-9, tags={
+            "app": app, "deployment": deployment, "stage": stage})
+    except Exception:
+        pass  # telemetry must never fail a request
+
+
+def add_chunks(stage: str, ns: int, events: int, app: str,
+               deployment: str) -> None:
+    """A settled stream's `events` items and the `ns` they waited in a
+    stage. Never raises."""
+    if not events:
+        return
+    try:
+        tags = {"app": app, "deployment": deployment, "stage": stage}
+        chunk_seconds().inc(max(ns, 0) * 1e-9, tags=tags)
+        chunk_events().inc(float(events), tags=tags)
+    except Exception:
+        pass  # telemetry must never fail a stream
 
 
 # -- front door: admission control + prefix directory ----------------- #
@@ -326,7 +423,11 @@ def metrics_summary() -> dict:
           resident_adapters} multi-LoRA lifecycle counters
       handles — {routers, refreshes: {cold, ttl, forced}}: live routers
           summed over processes and the replica sets they fetched
-      requests — {proxy, handle, replica, errors} cumulative counts
+      requests — {proxy, handle, replica, errors} cumulative counts,
+          and the front path's clock (module docstring):
+          front — {stage: {deployment: {count, mean, p50, p95, p99}}},
+          chunks — {stage: {deployment: {count, mean}}} (seconds an item),
+          loop_lag — {count, mean, p99} of the proxies' event loops
     Worker-side series ship on a ~2s cadence; a summary taken immediately
     after traffic may trail by one flush tick.
     """
@@ -531,4 +632,39 @@ def metrics_summary() -> dict:
         "llm_preemptions": _counter_total(
             store.get("rtpu_llm_preemptions_total")),
     }
+    front: dict = {}
+    for (stage, dep), rec in _by_labels(
+            store.get("rtpu_serve_front_stage_seconds"),
+            ("stage", "deployment")).items():
+        stats = _hist_stats(rec)
+        if stats is not None:
+            front.setdefault(stage, {})[dep] = stats
+    if front:
+        out["requests"]["front"] = front
+    chunks: dict = {}
+    events = _by_labels(store.get("rtpu_serve_chunk_events_total"),
+                        ("stage", "deployment"))
+    for key, rec in _by_labels(store.get("rtpu_serve_chunk_seconds_total"),
+                               ("stage", "deployment")).items():
+        n = _counter_total(events.get(key))
+        if n:
+            chunks.setdefault(key[0], {})[key[1]] = {
+                "count": n, "mean": _counter_total(rec) / n}
+    if chunks:
+        out["requests"]["chunks"] = chunks
+    lag = _hist_stats(store.get("rtpu_serve_proxy_loop_lag_seconds"))
+    if lag is not None:
+        out["requests"]["loop_lag"] = {
+            k: lag[k] for k in ("count", "mean", "p99")}
+    return out
+
+
+def _by_labels(rec: Optional[dict], labels: tuple) -> dict:
+    """One store record split by the values of `labels`:
+    {values: {"series": {...}}}, each a record the folds above take."""
+    out: dict = {}
+    for key, val in (rec or {}).get("series", {}).items():
+        tags = dict(key)
+        group = tuple(tags.get(k, "") for k in labels)
+        out.setdefault(group, {"series": {}})["series"][key] = val
     return out

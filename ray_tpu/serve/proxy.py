@@ -66,6 +66,7 @@ class ProxyActor:
         from concurrent.futures import ThreadPoolExecutor
         self._stream_pool = ThreadPoolExecutor(
             max_workers=1024, thread_name_prefix="rtpu-proxy-stream")
+        self._lag_task = None
 
     def _handle_for(self, ingress, app_name, stream, model_id,
                     method="__call__"):
@@ -95,7 +96,26 @@ class ProxyActor:
         await self._runner.setup()
         site = web.TCPSite(self._runner, "127.0.0.1", self._port)
         await site.start()
+        self._lag_task = asyncio.ensure_future(self._watch_loop_lag())
         return self._port
+
+    async def _watch_loop_lag(self, period_s: float = 0.1):
+        """How late this loop runs what is due: what every ``await`` of a
+        request's intake and of a chunk's write waits behind when many
+        streams hand their chunks to the one loop
+        (``rtpu_serve_proxy_loop_lag_seconds``)."""
+        import time as _time
+
+        from . import metrics as sm
+        tags = {"proxy": f"proxy-{self._index}"}
+        while True:
+            due = _time.perf_counter() + period_s
+            await asyncio.sleep(period_s)
+            try:
+                sm.proxy_loop_lag().observe(
+                    max(_time.perf_counter() - due, 0.0), tags=tags)
+            except Exception:
+                pass  # telemetry must never end the watcher
 
     async def ping(self) -> dict:
         """Controller liveness probe (frontdoor fleet management); the
@@ -164,8 +184,10 @@ class ProxyActor:
 
     async def _dispatch(self, request):
         """Telemetry shell around _dispatch_inner: mints the request id,
-        opens the request's root trace span, and lands the per-route
-        counters + e2e latency histogram whatever the outcome."""
+        stamps the request's arrival on this host's clock (the front
+        stages' origin, serve/metrics.py), opens the request's root trace
+        span, and lands the per-route counters + e2e latency histogram
+        whatever the outcome."""
         import secrets
         import time as _time
 
@@ -174,15 +196,23 @@ class ProxyActor:
         from . import metrics as sm
         from ..util import tracing
 
+        ingress_ns = _time.perf_counter_ns()
         rid = secrets.token_hex(8)
-        meta = {"app": "", "route": ""}
-        t0 = _time.perf_counter()
+        meta = {"app": "", "route": "", "ingress_ns": ingress_ns}
+        t0 = ingress_ns * 1e-9
         status = 500
         try:
             with tracing.span("serve.proxy", root=True) as span_rec:
                 if span_rec is not None:
                     span_rec["request_id"] = rid
-                resp = await self._dispatch_inner(request, rid, meta)
+                try:
+                    resp = await self._dispatch_inner(request, rid, meta)
+                finally:
+                    if span_rec is not None:
+                        span_rec["args"] = {
+                            k: meta[k] for k in ("intake_ms",
+                                                 "first_write_ms")
+                            if k in meta}
             status = resp.status
             return resp
         except web.HTTPException as e:
@@ -349,7 +379,17 @@ class ProxyActor:
         handle = self._handle_for(ingress, app_name, want_stream, model_id,
                                   method)
 
+        import time as _time
+
+        from . import metrics as sm
+        now_ns = _time.perf_counter_ns
+
         def call():
+            # front stage "intake": everything between the request's
+            # arrival and this thread
+            intake = now_ns() - meta["ingress_ns"]
+            meta["intake_ms"] = intake * 1e-6
+            sm.observe_stage("intake", intake, app_name, ingress)
             # handle.remote() itself may block (replica-set refresh, cold
             # start wait) — keep ALL of it off the proxy's event loop
             resp = (handle.remote(payload) if payload is not None
@@ -363,8 +403,11 @@ class ProxyActor:
         # replica call parents to the proxy span and rides the request id
         import contextvars
 
-        from .context import reset_request_context, set_request_context
-        token = set_request_context(request_id=rid, app_name=app_name)
+        from .context import (host_name, reset_request_context,
+                              set_request_context)
+        token = set_request_context(
+            request_id=rid, app_name=app_name,
+            ingress_ns=meta["ingress_ns"], ingress_host=host_name())
         try:
             call_ctx = contextvars.copy_context()
         finally:
@@ -405,6 +448,11 @@ class ProxyActor:
             stream.headers["Content-Type"] = "text/event-stream"
             await stream.prepare(request)
             it = iter(out)
+            # front stages "first_write" and "write": a chunk's take (the
+            # stream thread's read returned, ``out.take_ns``) -> its
+            # write to the socket returned on this loop; summed in plain
+            # ints, added once as the stream ends
+            writes = write_ns = 0
             try:
                 while True:
                     try:
@@ -427,8 +475,16 @@ class ProxyActor:
                     if isinstance(chunk, str):
                         chunk = chunk.encode()
                     await stream.write(chunk)
+                    lag = now_ns() - out.take_ns
+                    write_ns += lag
+                    writes += 1
+                    if writes == 1:
+                        meta["first_write_ms"] = lag * 1e-6
+                        sm.observe_stage("first_write", lag, app_name,
+                                         ingress)
                 await stream.write_eof()
             finally:
+                sm.add_chunks("write", write_ns, writes, app_name, ingress)
                 # client disconnect / write error: release the
                 # replica-retained generator and its ongoing slot
                 await loop.run_in_executor(None, out.cancel)
@@ -440,6 +496,8 @@ class ProxyActor:
                                 content_type="application/json")
 
     async def stop(self):
+        if self._lag_task is not None:
+            self._lag_task.cancel()
         if self._runner is not None:
             await self._runner.cleanup()
         self._stream_pool.shutdown(wait=False, cancel_futures=True)

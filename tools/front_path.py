@@ -15,8 +15,29 @@ machine), so the lags are differences of stamps taken in two processes:
 - way in: the client's send -> the fake deployment's ``completions_stream``
 - first chunk / later chunks: the pump's ``put`` -> the client's read
 
-and beside them the router replica's thread count and the handles' series
-(``rtpu_serve_handle_routers``, ``rtpu_serve_handle_refreshes_total``).
+and beside them the router replica's thread count, the handles' series
+(``rtpu_serve_handle_routers``, ``rtpu_serve_handle_refreshes_total``) and
+``front_stages_ms``: the program's own split of the same way, from
+``metrics_summary()["requests"]`` (serve/metrics.py, "the front path's
+clock"; PR 53). Which stage is which thread:
+
+- ``intake``: the proxy's event loop, up to the default-executor thread
+  that runs ``call()``; ``loop_lag`` is that loop's lateness
+- ``open``: that thread (tagged ``openai-router``), then the router
+  replica's ``serve-stream-chan-<sid>`` drain thread at ``_sse``'s first
+  ``next`` (tagged ``llm:fake``): the actor round trip that opens a stream
+- ``to_submit``: the fake deployment's executor thread, where the engine's
+  ``submit`` would stamp (``llm/telemetry.py`` ``on_submit`` there)
+- ``first_chunk``: the fake's pump thread, a first chunk falling due ->
+  its ring write's stamp (``LLMServer._pump`` there, from the first
+  token's booking); between the two a real engine's TTFT would lie
+- ``first_hop`` / ``hop``: ring 1 (``llm:fake``), written by the fake's
+  pump and read by the router's drain thread; ring 2 (``openai-router``),
+  written by that drain thread and read by a ``rtpu-proxy-stream`` thread
+- ``first_relay`` / ``relay``: the router's drain thread, ring 1's read ->
+  ring 2's write returned
+- ``first_write`` / ``write``: the proxy's stream thread's read -> the
+  event loop's ``stream.write`` returned
 
     python -m tools.front_path --sessions 64 --prompt-ids 12000 --chunk-s 0.2
     python -m tools.front_path --sessions 12 --prompt-ids 3000 --chunk-s 0.1
@@ -66,8 +87,8 @@ def build_app(sessions: int, chunk_s: float, chunks: int):
         """One open answer; the server's pump feeds whatever sink the
         serve replica attaches (serve/controller.py ``_RingSink``)."""
 
-        def __init__(self, server, t_in):
-            self.server, self.t_in = server, t_in
+        def __init__(self, server, t_in, front=None):
+            self.server, self.t_in, self.front = server, t_in, front
             self.sink, self.sent, self.due = None, 0, t_in + chunk_s
 
         def attach(self, sink):
@@ -94,7 +115,17 @@ def build_app(sessions: int, chunk_s: float, chunks: int):
                              name="fake-llm-pump").start()
 
         def completions_stream(self, body):
-            return Pushed(self, time.perf_counter())
+            t_in = time.perf_counter()
+            # what llm/telemetry.py on_submit records for a real engine
+            from ray_tpu.serve.context import (get_request_context,
+                                               local_ingress_ns)
+            from ray_tpu.serve.metrics import observe_stage
+            ctx, ingress_ns = get_request_context(), local_ingress_ns()
+            if not ingress_ns:
+                return Pushed(self, t_in)
+            front = (ctx.app_name, ctx.deployment)
+            observe_stage("to_submit", int(t_in * 1e9) - ingress_ns, *front)
+            return Pushed(self, t_in, front)
 
         def _pump(self):
             while True:
@@ -122,6 +153,14 @@ def build_app(sessions: int, chunk_s: float, chunks: int):
                      "choices": [{"index": 0, "text": "x", "finish_reason":
                                   "length" if last else None}]}
             if s.sink.put(chunk):
+                if s.sent == 0 and s.front:
+                    # llm/serving.py's pump: the first token's booking
+                    # (here: the chunk fell due) -> the ring write's stamp
+                    from ray_tpu.serve.metrics import observe_stage
+                    wrote = getattr(s.sink, "wrote_ns", 0) or int(
+                        time.perf_counter() * 1e9)
+                    observe_stage("first_chunk", wrote - int(s.due * 1e9),
+                                  *s.front)
                 s.sent += 1
                 s.due += chunk_s
             return False
@@ -203,7 +242,8 @@ def run(sessions: int, prompt_ids: int, chunk_s: float, chunks: int,
         took = time.perf_counter() - t0
         after = threads.remote().result(timeout_s=120)
         time.sleep(2.5)     # one flush tick of the workers' metrics
-        handles = metrics_summary().get("handles")
+        summary = metrics_summary()
+        handles = summary.get("handles")
     finally:
         serve.shutdown()
         ray_tpu.shutdown()
@@ -220,7 +260,27 @@ def run(sessions: int, prompt_ids: int, chunk_s: float, chunks: int,
                            "serve_lp_after": sum(
                                t.startswith("serve-lp-") for t in after)},
         "handles": handles,
+        "front_stages_ms": stage_table(summary["requests"]),
     }
+
+
+def stage_table(requests: dict) -> dict:
+    """``metrics_summary()["requests"]``'s front-path groups in ms:
+    {stage: {deployment: {n, mean, p95}}} of the per-request stages,
+    {stage: {deployment: {n, mean}}} under ``"chunks"`` for every item,
+    and the proxy's ``loop_lag``."""
+    def ms(stats, keys):
+        return {"n": int(stats["count"]), **{
+            k: round(stats[k] * 1e3, 3) for k in keys
+            if stats.get(k) is not None}}
+    out = {stage: {dep: ms(st, ("mean", "p95")) for dep, st in deps.items()}
+           for stage, deps in requests.get("front", {}).items()}
+    out["chunks"] = {
+        stage: {dep: ms(st, ("mean",)) for dep, st in deps.items()}
+        for stage, deps in requests.get("chunks", {}).items()}
+    if "loop_lag" in requests:
+        out["loop_lag"] = ms(requests["loop_lag"], ("mean", "p99"))
+    return out
 
 
 def main() -> None:
