@@ -9,11 +9,20 @@ scalar-prefetched ``tile_expert`` table — no group boundary ever falls
 inside a tile, so there is no in-tile masking (the megablox kernel's
 compact layout needs it):
 
-* grid ``(column tile, row tile)``, row tiles fastest. Consecutive row
-  tiles of one expert name the same weight block, which Pallas does not
-  fetch again, so a column sweep reads each expert's ``[K, tn]`` block
-  once however many rows the expert got: HBM traffic is the weights of
-  the experts hit plus the (small) activations. At serving shapes that is
+* grid ``(row tile,)``; a weight block is the WHOLE expert, ``[K, N]``.
+  The experts lie row-major, so a block is one contiguous run of ``K x
+  N`` values whatever N is, the row tiles are swept once and the ``[tm,
+  N]`` output tile is written once, lane-dense. A column slice ``[K,
+  tn]`` is pieces a row's length apart and one more sweep of the row
+  tiles, re-reading ``x``, a slice: inside OLMoE's decode program the
+  fused call took 911 us as column halves and 737 whole (PERF.md section
+  6, PR 54, has the sweep over all five served widths, blocks by rows of
+  K included: the whole expert is fastest or tied everywhere).
+  `_vmem_scope` says what the pipeline's two buffers of blocks need and
+  refuses an expert they cannot hold;
+* consecutive row tiles of one expert name the same weight block, which
+  Pallas does not fetch again, so HBM traffic is the weights of the
+  experts hit plus the (small) activations. At serving shapes that is
   the whole cost — 64 experts x 3 x [2048, 1024] are 805 MB a layer
   against a few MB of rows — so tiles are small (16-32 rows) and the
   padding rows they bring cost nothing that matters;
@@ -38,10 +47,17 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# weight block budget: K x tn elements a block (2 MiB in bf16, so gate +
-# up, double-buffered, stay under half of the 16 MiB scoped VMEM default)
-_W_BLOCK_ELEMS = 1 << 20
-
+# The compiler's default VMEM scope of a kernel, which a call whose buffers
+# fit it leaves unstated, and the most a call may state. A stated scope is
+# taken from what the program's other operations keep in VMEM around the
+# call (kanana's prefill keeps a layer's expert rows there, 111.5 MiB of
+# the chip's 128: they fit beside 16 MiB and not beside 32), so a call
+# states what its buffers need and no more. 32 MiB holds two buffers of
+# OLMoE's fused expert, 2 x [2048, 1024] bf16 = 8 MiB each, the largest
+# served. `_VMEM_SPARE` is counted in for the compiler's own temporaries.
+_VMEM_DEFAULT = 16 * 2 ** 20
+_VMEM_SCOPE = 32 * 2 ** 20
+_VMEM_SPARE = 2 ** 20
 
 # the largest row tile: a group of this many rows reads its expert's weight
 # block for a full tile of work (what the serving engine sizes a routed
@@ -100,37 +116,41 @@ def group_layout(expert_of, owned, experts: int, tm: int):
             jnp.reshape(n_live, (1,)).astype(jnp.int32))
 
 
-def _col_tile(k: int, n: int) -> int:
-    """Columns of a weight block: the widest multiple of 128 that divides
-    N and keeps K x tn inside the block budget; all of N where N has no
-    such divisor (toy shapes)."""
-    best = None
-    for tn in range(128, n + 1, 128):
-        if n % tn == 0 and k * tn <= _W_BLOCK_ELEMS:
-            best = tn
-    return best or n
+def _vmem_scope(tm: int, k: int, n: int, operands: int,
+                itemsize: int) -> int | None:
+    """The VMEM scope a call states: None where its buffers fit the
+    compiler's default, else what they need. The buffers are two (the
+    pipeline's) of the ``operands`` whole-expert blocks ``[K, N]``, of the
+    row tile ``[tm, K]`` and of the output tile ``[tm, N]``, and the
+    float32 products ``[tm, N]`` an operand."""
+    need = (2 * (operands * k * n + tm * k + tm * n) * itemsize
+            + operands * tm * n * 4 + _VMEM_SPARE)
+    if need > _VMEM_SCOPE:
+        raise ValueError(
+            f"grouped matmul: {operands} expert block(s) [{k}, {n}] of "
+            f"{itemsize}-byte values under {tm}-row tiles need {need} "
+            f"bytes of VMEM, over the {_VMEM_SCOPE} a call may state: an "
+            "expert this large needs blocks by rows of K, which this "
+            "kernel does not have")
+    return None if need <= _VMEM_DEFAULT else need
 
 
-def _gmm_kernel(te_ref, live_ref, layer_ref, x_ref, w_ref, o_ref):
+def _gmm_kernel(te_ref, live_ref, layer_ref, x_ref, *refs):
+    """One row tile: x [tm, K] times each operand's expert [K, N] in
+    float32; ``refs`` are the weight blocks (two: gate and up, fused with
+    the activation) and the output tile."""
     from jax.experimental import pallas as pl
 
-    @pl.when(pl.program_id(1) < live_ref[0])
-    def _():
-        o_ref[...] = jnp.dot(
-            x_ref[...], w_ref[...],
-            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    *w_refs, o_ref = refs
 
-
-def _swiglu_kernel(te_ref, live_ref, layer_ref, x_ref, wg_ref, wu_ref,
-                   o_ref):
-    from jax.experimental import pallas as pl
-
-    @pl.when(pl.program_id(1) < live_ref[0])
+    @pl.when(pl.program_id(0) < live_ref[0])
     def _():
         x = x_ref[...]
-        g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        o_ref[...] = (jax.nn.silu(g) * u).astype(o_ref.dtype)
+        parts = [jnp.dot(x, w[...], preferred_element_type=jnp.float32)
+                 for w in w_refs]
+        out = jax.nn.silu(parts[0]) * parts[1] if len(parts) == 2 \
+            else parts[0]
+        o_ref[...] = out.astype(o_ref.dtype)
 
 
 # Jitted for the reason ops/ragged_paged_attention._ragged_call is: the
@@ -149,29 +169,29 @@ def _gmm_call(x, weights, tile_expert, n_live, layer, *, tm: int,
 
     m, k = x.shape
     n = weights[0].shape[-1]
-    tn = _col_tile(k, n)
+    scope = _vmem_scope(tm, k, n, len(weights), weights[0].dtype.itemsize)
 
     def live(i, live_ref):
         return jnp.minimum(i, jnp.maximum(live_ref[0] - 1, 0))
 
-    w_spec = pl.BlockSpec((None, None, k, tn),
-                          lambda j, i, te, lv, ly: (ly[0], te[i], 0, j))
+    w_spec = pl.BlockSpec((None, None, k, n),
+                          lambda i, te, lv, ly: (ly[0], te[i], 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(n // tn, m // tm),
+        grid=(m // tm,),
         in_specs=[pl.BlockSpec((tm, k),
-                               lambda j, i, te, lv, ly: (live(i, lv), 0)),
+                               lambda i, te, lv, ly: (live(i, lv), 0)),
                   *[w_spec] * len(weights)],
-        out_specs=pl.BlockSpec((tm, tn),
-                               lambda j, i, te, lv, ly: (live(i, lv), j)),
+        out_specs=pl.BlockSpec((tm, n),
+                               lambda i, te, lv, ly: (live(i, lv), 0)),
     )
-    fused = len(weights) == 2
     return pl.pallas_call(
-        _swiglu_kernel if fused else _gmm_kernel,
+        _gmm_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         interpret=interpret,
-        name="grouped_swiglu" if fused else "grouped_matmul",
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=scope),
+        name="grouped_swiglu" if len(weights) == 2 else "grouped_matmul",
     )(tile_expert, n_live, layer, x, *weights)
 
 

@@ -340,25 +340,48 @@ def test_ragged_compiles_at_olmoe_shapes(v5e, rows, q_window):
                      text)
 
 
-@pytest.mark.parametrize("assignments", [512, 4096])
-def test_grouped_ffn_compiles_at_olmoe_widths(v5e, assignments):
-    """The expert SwiGLU's two kernels over 64 groups: 64 decode rows x
-    top-8, and 4 x 128 prefill tokens x top-8, reading one layer of the
-    layers' weight stacks in place as the serving paths do. Their names are
-    what the benchmark's trace reduction finds them by."""
+# (experts held, hidden, one expert's width, top-k, decode rows, prefill
+# chunk-rows of 128 tokens) of the routed serving configurations whose
+# expert blocks PR 54 changed (Qwen3-Next's were whole experts before)
+EXPERT_WIDTHS = {
+    "olmoe": (64, 2048, 1024, 8, 64, 8),
+    "mellum": (64, 2304, 896, 8, 32, 8),
+    "kanana": (128, 2048, 768, 6, 32, 16),
+    "ling": (64, 2560, 768, 8, 128, 16),
+}
+
+
+@pytest.mark.parametrize("shape", ["decode", "prefill"])
+@pytest.mark.parametrize("widths", EXPERT_WIDTHS.values(), ids=EXPERT_WIDTHS)
+def test_grouped_ffn_compiles_at_the_cells_widths(v5e, widths, shape):
+    """The expert SwiGLU's two kernels at each configuration's decode rows
+    x top-k (16- or 32-row tiles) and at its prefill dispatch's tokens x
+    top-k (128-row tiles), reading one layer of the layers' weight stacks
+    in place as the serving paths do: whole-expert blocks, two buffers of
+    them, compile inside the VMEM scope `_vmem_scope` gives the call —
+    none, and so the compiler's default, wherever the buffers fit that.
+    Their names are what the benchmark's trace reduction finds them by."""
     from ray_tpu.ops import grouped_matmul as gmm
     one = SingleDeviceSharding(v5e.devices[0])
-    e, d, f = 64, 2048, 1024
+    e, d, f, top_k, rows, chunk_rows = widths
+    assignments = (rows if shape == "decode" else chunk_rows * 128) * top_k
     tm = gmm.tile_rows(assignments, e)
+    assert tm in ((128,) if shape == "prefill" else (16, 32))
     tiles = gmm.num_tiles(assignments, e, tm)
     text = _compile(
         functools.partial(gmm.grouped_ffn, tm=tm, layer=1, interpret=False),
         ((tiles * tm, d), BF16), ((2, e, d, f), BF16), ((2, e, d, f), BF16),
         ((2, e, f, d), BF16), ((e,), jnp.int32), ((tiles,), jnp.int32),
         ((1,), jnp.int32), sharding=[one] * 7).as_text()
-    assert not re.search(r"= bf16\[(1,)?64,2048,1024\]", text)   # no copy
-    assert re.search(r"%grouped_swiglu[.\d]* = .*tpu_custom_call", text)
-    assert re.search(r"%grouped_matmul[.\d]* = .*tpu_custom_call", text)
+    assert not re.search(rf"= bf16\[(1,)?{e},{d},{f}\]", text)   # no copy
+    for name, k, n, operands in (("grouped_swiglu", d, f, 2),
+                                 ("grouped_matmul", f, d, 1)):
+        call = re.search(rf"%{name}[.\d]* = .*tpu_custom_call.*", text)
+        scope = int(re.search(r'"memory_space":"1","offset":"\d+",'
+                              r'"size":"(\d+)"', call.group(0))[1])
+        stated = gmm._vmem_scope(tm, k, n, operands, 2)
+        # unstated, a call is given what it uses
+        assert scope == stated if stated else scope <= gmm._VMEM_DEFAULT
 
 
 def test_olmoe_decode_layer_compiles_at_64_rows(v5e, monkeypatch):

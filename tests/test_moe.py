@@ -13,6 +13,9 @@ step anywhere (3 significant digits, 1e-2 on such logits) fails it. The
 ROUTED EXPERT SETS must be identical, which the comparisons imply: one
 swapped expert moves a logit by 1e-2 or more.
 """
+import json
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -167,6 +170,78 @@ def test_kernels_in_interpret_mode_match_ragged_dot(experts, top_k):
     for a, b in zip(jax.grad(loss, (0, 1))(h, p["w_down"], True),
                     jax.grad(loss, (0, 1))(h, p["w_down"], False)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **TOL)
+
+
+@pytest.mark.parametrize("tm", [16, 128])
+@pytest.mark.parametrize("d,f", [(256, 128), (384, 256)])
+def test_kernels_in_interpret_mode_match_ragged_dot_over_dead_tiles(d, f,
+                                                                    tm):
+    """The kernels by themselves over a layout with an empty expert, a
+    group of more than one tile and dead tiles past ``n_live``, at the
+    smallest and the largest row tile, one layer of a stack read in place
+    in whole-expert blocks, against ``jax.lax.ragged_dot``."""
+    experts = 4
+    rng = np.random.RandomState(tm + d)
+    # expert 2 gets nothing; expert 1 more than one tile; 3 is a remainder
+    counts = [tm // 2, tm + 3, 0, 5]
+    expert_of = rng.permutation(np.repeat(np.arange(experts), counts))
+    row_of, padded, tile_expert, n_live = gmm.group_layout(
+        jnp.asarray(expert_of, jnp.int32), None, experts, tm)
+    tiles = gmm.num_tiles(len(expert_of), experts, tm)
+    assert np.asarray(padded).tolist() == [tm, 2 * tm, 0, tm]
+    assert int(n_live[0]) == 4 < tiles
+    x = np.zeros((tiles * tm, d), np.float32)
+    x[np.asarray(row_of)] = rng.randn(len(expert_of), d)
+    w = [jnp.asarray(rng.randn(2, experts, *shape) * 0.1, jnp.float32)
+         for shape in ((d, f), (d, f), (f, d))]
+    got = gmm.grouped_ffn(jnp.asarray(x), *w, padded, tile_expert, n_live,
+                          tm, 1, True)
+    want = gmm.grouped_ffn_reference(jnp.asarray(x), *w, padded, 1)
+    live = int(n_live[0]) * tm
+    np.testing.assert_allclose(np.asarray(got)[:live],
+                               np.asarray(want)[:live], **TOL)
+    assert np.abs(np.asarray(want)[np.asarray(row_of)]).min() > 0
+
+
+@pytest.mark.parametrize("name,stated", [
+    ("olmoe7b", True), ("mellum2-12b", True), ("kanana2-30b", False),
+    ("qwen3next-80b", False), ("ling3flash-125b", True)])
+def test_the_vmem_scope_at_the_published_widths(name, stated):
+    """`_vmem_scope` at each serving configuration's (hidden, expert
+    width), both calls, decode and prefill tiles: two buffers of whole
+    experts fit what a call may state; the down call, one operand, fits
+    the compiler's default everywhere and so do both calls of kanana and
+    Qwen3-Next, which therefore state nothing (kanana's prefill keeps a
+    layer's expert rows in VMEM beside the default scope and loses them
+    beside a larger one: PERF.md section 6, PR 54); a fused call that
+    states a scope states what its buffers need, not the ceiling."""
+    cfg = json.loads((pathlib.Path(__file__).parent.parent / "benchmarks"
+                      / "configs" / f"{name}-serve-1chip.json").read_text())
+    d = cfg["hidden_size"]
+    f = cfg.get("moe_intermediate_size", cfg["intermediate_size"])
+    for tm in (16, 32, 128):
+        assert gmm._vmem_scope(tm, f, d, 1, 2) is None
+    fused = gmm._vmem_scope(128, d, f, 2, 2)
+    assert (fused is not None) == stated
+    if stated:
+        assert 4 * d * f * 2 < fused <= gmm._VMEM_SCOPE
+        assert fused < 4 * d * f * 2 + 4 * 2 ** 20
+
+
+def test_an_expert_beyond_the_vmem_scope_is_refused_by_name():
+    """Two buffers of Mixtral's fused pair, 2 x [4096, 14336], are 448
+    MiB: the call says so with its shapes where it is traced, before the
+    chip's compiler could fail on an allocation."""
+    with pytest.raises(ValueError, match=r"\[4096, 14336\].*rows of K"):
+        gmm._vmem_scope(16, 4096, 14336, 2, 2)
+    x = jnp.zeros((16, 4096), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((1, 2, 4096, 14336), jnp.bfloat16)
+    with pytest.raises(ValueError, match="grouped matmul"):
+        jax.eval_shape(
+            lambda x, w: gmm._gmm_call(
+                x, (w, w), jnp.zeros((1,), jnp.int32),
+                jnp.ones((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
+                tm=16, interpret=True), x, w)
 
 
 def test_group_layout_is_tile_aligned_and_stable():
